@@ -65,8 +65,8 @@ class ModelConfig:
     # materializes the full f32 logits array (models.gpt._chunked_ce_loss)
     # — at GPT-2 vocab that array is the step's largest HBM tenant. With
     # loss_chunk on, forward(targets=...) returns (None, loss): callers
-    # that need logits keep the default. Opt-in until the hardware A/B
-    # (tools/hw_validate.py ce_chunk_off/ce_chunk_on) sizes the win.
+    # that need logits keep the default. Opt-in until a chip A/B sizes
+    # the win (ROADMAP A5).
     decode_cache_layout: str = "heads"
     # KV-cache memory layout for decode: 'heads' = (L, B, H, S, D) (the
     # original layout), 'packed' = (L, B, S, C) with heads as static lane
@@ -75,8 +75,8 @@ class ModelConfig:
     # cache bytes per decode step — the packed layout stores fully-packed
     # (S, C) rows and reads them through ops/decode_pallas.py's
     # packed_decode_attention kernel (the packed-flash lane-slice trick
-    # applied to decode). 'heads' stays the default until the layout A/B
-    # validates on hardware (tools/hw_validate.py decode_sweep_packed).
+    # applied to decode). 'heads' stays the default until a chip A/B of
+    # the two layouts says otherwise (ROADMAP A5).
     act_quant: str = "none"
     # W8A8 serving: 'int8' quantizes the ACTIVATION rows feeding the
     # already-int8-quantized weight matmuls of the cached decode paths

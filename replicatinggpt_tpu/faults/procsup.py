@@ -183,6 +183,10 @@ class WorkerHandle:
     probe_failures: int = 0
     intentional_stop: bool = False
     retiring: bool = False     # scale-down in progress: exit → RETIRED
+    #: the device this worker registered with ({"platform", "kind",
+    #: "count"}) — how a parent that holds no chip (one process per
+    #: chip) learns what its fleet runs on
+    device: Optional[dict] = None
     events: List[str] = field(default_factory=list)
 
 
@@ -366,6 +370,7 @@ class ProcSupervisor:
             router.replicas[idx].restarts = h.restarts
             h.state = RUNNING
             h.pid = pid
+            h.device = doc.get("device")
             h.probe_failures = 0
             self._event(f"worker {idx} registered+attached "
                         f"(gen {gen}, host {peer_host}, "

@@ -435,15 +435,21 @@ def _fused_decode_backend_ok() -> bool:
 
 
 def _all_single_device(tree) -> bool:
-    """True when every array leaf lives on one device (no NamedSharding
-    over a mesh) — the GSPMD-safety answer the decode kernels' gate
-    needs: a bare pallas_call cannot be partitioned, so the kernels are
-    only safe when the program cannot be mesh-sharded. Only meaningful
-    on CONCRETE arrays (tracers carry no committed sharding)."""
-    from jax.sharding import SingleDeviceSharding
+    """True when every array leaf lives on one device (no sharding over
+    a multi-device mesh) — the GSPMD-safety answer the decode kernels'
+    gate needs: a bare pallas_call cannot be partitioned, so the kernels
+    are only safe when the program cannot be mesh-sharded. Concrete
+    arrays answer with their committed sharding; inside a trace the
+    tracer's type carries the mesh of the array it stands for (empty
+    for a single-device input)."""
     for leaf in jax.tree_util.tree_leaves(tree):
+        if isinstance(leaf, jax.core.Tracer):
+            mesh = jax.typeof(leaf).sharding.mesh
+            if not mesh.empty and mesh.size > 1:
+                return False
+            continue
         s = getattr(leaf, "sharding", None)
-        if s is not None and not isinstance(s, SingleDeviceSharding):
+        if s is not None and len(s.device_set) > 1:
             return False
     return True
 
@@ -452,32 +458,22 @@ _PALLAS_GATE_LOGGED = False
 
 
 def _default_allow_pallas(*inputs) -> bool:
-    """Default kernel gate for direct decode_step callers.
-
-    When the inputs are concrete arrays, the answer is precise: inspect
-    their actual shardings (exactly what generate() does eagerly via
-    ``_all_single_device``), so single-device inputs on a multi-device
-    host keep the fused kernels. Inside a trace the shardings are
-    unknowable and the gate falls back to the conservative
-    process-topology guess (device_count()==1); callers that KNOW their
-    traced inputs are single-device pass allow_pallas=True. Logs once
-    per process when the gate turns the kernels off on a backend that
-    would otherwise run them (a silent perf cliff is worse than one
-    stderr line)."""
-    leaves = jax.tree_util.tree_leaves(inputs)
-    if any(isinstance(leaf, jax.core.Tracer) for leaf in leaves):
-        ok = jax.device_count() == 1
-    else:
-        ok = _all_single_device(inputs)
+    """Default kernel gate for direct decode_step callers: the
+    shardings of the inputs themselves (``_all_single_device`` — what
+    generate() computes eagerly), traced or concrete, so single-device
+    inputs on a multi-chip host keep the fused kernels. Logs once per
+    process when the gate turns the kernels off on a backend that would
+    otherwise run them (a silent perf cliff is worse than one stderr
+    line)."""
+    ok = _all_single_device(inputs)
     if not ok and _fused_decode_backend_ok():
         global _PALLAS_GATE_LOGGED
         if not _PALLAS_GATE_LOGGED:
             _PALLAS_GATE_LOGGED = True
             import sys
-            print("note: fused decode kernels gated off (multi-device "
-                  "inputs or traced call on a multi-device process); "
-                  "pass allow_pallas=True to decode_step if the inputs "
-                  "are known single-device", file=sys.stderr)
+            print("note: decode kernels gated off (inputs sharded over "
+                  "a multi-device mesh; a bare pallas_call cannot be "
+                  "partitioned)", file=sys.stderr)
     return ok
 
 

@@ -150,10 +150,7 @@ def packed_decode_attention(q: jnp.ndarray, k_new: jnp.ndarray,
         _packed_attn_kernel, n_head=n_head, head_dim=D, seq_len=S,
         scale=D ** -0.5)
     row = _vmem_spec((None, 1, C), lambda b: (b, 0, 0))
-    kw = {}
-    cp = _compiler_params(1, 1)
-    if cp is not None:
-        kw["compiler_params"] = cp
+    kw = {"compiler_params": _compiler_params(1, 1)}
     out = pl.pallas_call(
         kernel,
         grid=(B,),
@@ -287,10 +284,7 @@ def fused_decode_layers(x0: jnp.ndarray, blocks: Dict[str, jnp.ndarray],
                   if packed else
                   _vmem_spec((None, None, H, S, D),
                              lambda l: (l, 0, 0, 0, 0)))
-    kw = {}
-    cp = _compiler_params(0, 1)
-    if cp is not None:
-        kw["compiler_params"] = cp
+    kw = {"compiler_params": _compiler_params(0, 1)}
     xout, newk, newv = pl.pallas_call(
         kernel,
         grid=(L,),
@@ -311,8 +305,7 @@ def fused_decode_layers(x0: jnp.ndarray, blocks: Dict[str, jnp.ndarray],
             jax.ShapeDtypeStruct((L, 1, C), cd),
             jax.ShapeDtypeStruct((L, 1, C), cd),
         ],
-        scratch_shapes=[pltpu.VMEM((1, C), cd) if pltpu is not None
-                        else None],
+        scratch_shapes=[pltpu.VMEM((1, C), cd)],
         interpret=_interpret_mode(),
         **kw,
     )(jnp.asarray(pos, jnp.int32).reshape(1), x0,
@@ -383,11 +376,11 @@ def fused_paged_decode_supported(cfg, n_slots: int, page_size: int,
         granularity=granularity)
     if not ok:
         return False
-    if pltpu is None:
-        return False
     weights = (C * 3 * C + C * C + 2 * C * 4 * C) * itemsize
     pages = 2 * page_size * C * itemsize
-    scratch = (n_slots + 3) * C * itemsize + C * 4 + 2 * LANES * 4
+    # the (n_slots, 1, C) residual scratch pads every row to a full
+    # sublane tile: 32 bytes per lane whatever the dtype
+    scratch = n_slots * 32 * C + 3 * C * itemsize + C * 4 + 2 * LANES * 4
     return weights + pages + scratch <= FUSED_LAYER_BYTES
 
 
@@ -433,11 +426,11 @@ def _paged_fused_kernel(tables_ref, pos_ref, x0_ref, ln1s_ref, ln1b_ref,
 
     @pl.when((l == 0) & (p == 0))
     def _seed():
-        x_scr[pl.ds(b, 1), :] = x0_ref[...]
+        x_scr[b] = x0_ref[...]
 
     @pl.when(p == 0)
     def _project():
-        x = x_scr[pl.ds(b, 1), :]
+        x = x_scr[b]
         h = _ln_row(x, ln1s_ref[...], ln1b_ref[...], eps)
         qkv = _row_matmul(h, wqkv_ref[...], bqkv_ref[...])   # (1, 3C)
         q_scr[...] = qkv[:, :C]
@@ -479,17 +472,22 @@ def _paged_fused_kernel(tables_ref, pos_ref, x0_ref, ln1s_ref, ln1b_ref,
             s = jnp.sum(kcf * q, axis=-1,
                         keepdims=True) * scale                   # (psz, 1)
             s = jnp.where(kpos < pos, s, NEG_INF)
-            m_prev = m_ref[0, i]
-            m_new = jnp.maximum(m_prev, jnp.max(s))
+            # per-head running max/sum are (1, 1) VECTOR windows of the
+            # (1, LANES) scratch rows (paged_window_attention's layout):
+            # Mosaic has no scalar store to VMEM
+            m_prev = m_ref[:, i:i + 1]                           # (1, 1)
+            m_new = jnp.maximum(m_prev,
+                                jnp.max(s, axis=0, keepdims=True))
             alpha = jnp.exp(m_prev - m_new)
             # masked rows contribute EXACTLY zero (not exp(0)): with a
             # fully-masked page m_new stays NEG_INF and s - m_new == 0
             pexp = jnp.where(s > NEG_INF / 2, jnp.exp(s - m_new), 0.0)
-            l_ref[0, i] = l_ref[0, i] * alpha + jnp.sum(pexp)
+            l_ref[:, i:i + 1] = (l_ref[:, i:i + 1] * alpha
+                                 + jnp.sum(pexp, axis=0, keepdims=True))
             acc_ref[:, sl] = (acc_ref[:, sl] * alpha
                               + jnp.sum(pexp * vcf,
                                         axis=0, keepdims=True))
-            m_ref[0, i] = m_new
+            m_ref[:, i:i + 1] = m_new
 
     @pl.when(p == n_pages_per_slot - 1)
     def _finalize():
@@ -497,16 +495,18 @@ def _paged_fused_kernel(tables_ref, pos_ref, x0_ref, ln1s_ref, ln1b_ref,
         for i in range(H):
             sl = slice(i * D, (i + 1) * D)
             q = q_scr[:, sl].astype(jnp.float32)
-            s_new = jnp.sum(knew_scr[:, sl].astype(jnp.float32)
-                            * q) * scale                         # scalar
-            m2 = jnp.maximum(m_ref[0, i], s_new)
-            alpha = jnp.exp(m_ref[0, i] - m2)
+            s_new = jnp.sum(knew_scr[:, sl].astype(jnp.float32) * q,
+                            axis=-1, keepdims=True) * scale      # (1, 1)
+            m_prev = m_ref[:, i:i + 1]
+            m2 = jnp.maximum(m_prev, s_new)
+            alpha = jnp.exp(m_prev - m2)
             p_new = jnp.exp(s_new - m2)
-            denom = l_ref[0, i] * alpha + p_new   # >= p_new > 0 always
+            denom = (l_ref[:, i:i + 1] * alpha
+                     + p_new)                 # >= p_new > 0 always
             outs.append((acc_ref[:, sl] * alpha
                          + p_new * vnew_scr[:, sl].astype(jnp.float32))
                         / denom)
-        x = x_scr[pl.ds(b, 1), :]
+        x = x_scr[b]
         attn = jnp.concatenate(outs, axis=1).astype(x.dtype)
         attn = _row_matmul(attn, wproj_ref[...], bproj_ref[...])
         x_mid = x + attn
@@ -515,7 +515,7 @@ def _paged_fused_kernel(tables_ref, pos_ref, x0_ref, ln1s_ref, ln1b_ref,
         h = (jax.nn.gelu(h) if activation == "gelu" else jax.nn.relu(h))
         h = _row_matmul(h.astype(x.dtype), wdown_ref[...], bdown_ref[...])
         x_new = x_mid + h
-        x_scr[pl.ds(b, 1), :] = x_new
+        x_scr[b] = x_new
         xout_ref[...] = x_new
 
 
@@ -561,21 +561,14 @@ def fused_paged_decode_layers(x0: jnp.ndarray,
         return (l, tables[b, clamped_live_page(p, pos[b], psz)], 0, 0)
 
     page_spec = _vmem_spec((None, None, psz, C), page_map)
-    if pltpu is None:  # pragma: no cover — gated by
-        # fused_paged_decode_supported; explicit error over a pallas
-        # internals traceback
-        raise RuntimeError("fused_paged_decode_layers needs pallas TPU "
-                           "memory spaces "
-                           "(jax.experimental.pallas.tpu)")
-    scratch = [pltpu.VMEM((B, C), cd), pltpu.VMEM((1, C), cd),
+    # residual rows as (B, 1, C): the slot index is dynamic, and Mosaic
+    # only takes a dynamic index on an untiled (leading) dimension
+    scratch = [pltpu.VMEM((B, 1, C), cd), pltpu.VMEM((1, C), cd),
                pltpu.VMEM((1, C), cd), pltpu.VMEM((1, C), cd),
                pltpu.VMEM((1, C), jnp.float32),
                pltpu.VMEM((1, LANES), jnp.float32),
                pltpu.VMEM((1, LANES), jnp.float32)]
-    kw = {}
-    cp = _compiler_params(0, 3)
-    if cp is not None:
-        kw["compiler_params"] = cp
+    kw = {"compiler_params": _compiler_params(0, 3)}
     in_specs = [brow,
                 lrow(C), lrow(C), lmat(C, 3 * C), lrow(3 * C),
                 lmat(C, C), lrow(C), lrow(C), lrow(C),

@@ -59,7 +59,8 @@ def packed_envelope_ok(qkv: jnp.ndarray, n_head: int) -> bool:
     itemsize = jnp.dtype(qkv.dtype).itemsize
     # group_stream joins the envelope only behind its hardware-validation
     # gate (fp.GROUP_STREAM_AUTOROUTE) — read dynamically so flipping the
-    # gate (hw_validate passing, or a test) takes effect here too
+    # gate (a chip run validating the family, or a test) takes effect
+    # here too
     return (fp.packed_supported(T, C3 // 3, n_head, itemsize)
             or fp.packed_group_supported(T, C3 // 3, n_head, itemsize)
             or (fp.GROUP_STREAM_AUTOROUTE

@@ -38,6 +38,7 @@ interpret mode.
 from __future__ import annotations
 
 import functools
+import logging
 from typing import Optional
 
 import jax
@@ -45,12 +46,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-try:  # TPU memory spaces; absent on pure-CPU installs
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
+
+log = logging.getLogger(__name__)
 
 BLOCK = 128
 LANES = 128  # trailing width for per-row stats (Mosaic lane alignment)
@@ -58,13 +56,11 @@ NEG_INF = -1e30
 
 
 def _vmem_spec(block_shape, index_map):
-    kw = {"memory_space": _VMEM} if _VMEM is not None else {}
-    return pl.BlockSpec(block_shape, index_map, **kw)
+    return pl.BlockSpec(block_shape, index_map, memory_space=pltpu.VMEM)
 
 
 def _smem_spec():
-    kw = {"memory_space": pltpu.SMEM} if pltpu is not None else {}
-    return pl.BlockSpec(**kw)
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 # ---------------------------------------------------------------------------
@@ -425,10 +421,7 @@ def _fused_kv_major_bwd(scale, causal, block_q, block_k, dropout_rate,
     spec_q = _vmem_spec((None, Tq, D), lambda i, kb: (i, 0, 0))
     spec_kv = _vmem_spec((None, block_k, D), lambda i, kb: (i, kb, 0))
     spec_tl = _vmem_spec((None, Tq, LANES), lambda i, kb: (i, 0, 0))
-    kw = {}
-    cp = _compiler_params(1, 2)
-    if cp is not None:
-        kw["compiler_params"] = cp
+    kw = {"compiler_params": _compiler_params(1, 2)}
     return pl.pallas_call(
         kernel,
         grid=(BH, Tk // block_k),
@@ -470,12 +463,11 @@ def _flash_bwd(scale, causal, block_q, block_k, dropout_rate, residuals, g):
         return (dq.reshape(shape), dk.reshape(shape), dv.reshape(shape),
                 None)
 
-    if pltpu is not None and T * D * 4 <= FUSED_DQ_SCRATCH_BYTES:
+    if T * D * 4 <= FUSED_DQ_SCRATCH_BYTES:
         # multi-tile but the (T, D) f32 dq scratch fits VMEM: kv-major
         # fully-fused backward — one launch and one p/ds recompute per
         # tile instead of two of each (split kernels below remain for
-        # longer resident sequences, and for pure-CPU installs where
-        # pltpu — and so VMEM scratch — is unavailable)
+        # longer resident sequences)
         dq, dk, dv = _fused_kv_major_bwd(
             scale, causal, block_q, block_k, dropout_rate,
             seed, jnp.zeros((3,), jnp.int32), qf, kf, vf, gf, lse, delta,
@@ -552,18 +544,13 @@ def _flash_bwd(scale, causal, block_q, block_k, dropout_rate, residuals, g):
 
 def _compiler_params(n_parallel: int, n_total: int):
     """Mark leading grid dims parallel, trailing (carry) dims arbitrary."""
-    if pltpu is None:
-        return None
-    try:
-        sem = (("parallel",) * n_parallel
-               + ("arbitrary",) * (n_total - n_parallel))
-        return pltpu.CompilerParams(dimension_semantics=sem)
-    except Exception:  # pragma: no cover — older/newer param spelling
-        return None
+    sem = (("parallel",) * n_parallel
+           + ("arbitrary",) * (n_total - n_parallel))
+    return pltpu.CompilerParams(dimension_semantics=sem)
 
 
 def _scratch(shape):
-    return pltpu.VMEM(shape, jnp.float32) if pltpu is not None else None
+    return pltpu.VMEM(shape, jnp.float32)
 
 
 def _fwd_kernel_stream(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
@@ -614,10 +601,7 @@ def _flash_fwd_stream(q, k, v, seed, scale, causal, block_q, block_k,
     kernel = functools.partial(
         _fwd_kernel_stream, scale=scale, causal=causal, seq_len=T,
         block_q=block_q, block_k=block_k, dropout_rate=dropout_rate)
-    kw = {}
-    cp = _compiler_params(2, 3)
-    if cp is not None:
-        kw["compiler_params"] = cp
+    kw = {"compiler_params": _compiler_params(2, 3)}
     o, lse = pl.pallas_call(
         kernel,
         grid=grid,
@@ -720,10 +704,7 @@ def _flash_bwd_stream(scale, causal, block_q, block_k, dropout_rate,
     lse = jnp.broadcast_to(lse[:, :, None], (BH, T, LANES))
     qf, kf, vf = (t.reshape(BH, T, D) for t in (q, k, v))
     gf = g.reshape(BH, T, D)
-    kw = {}
-    cp = _compiler_params(2, 3)
-    if cp is not None:
-        kw["compiler_params"] = cp
+    kw = {"compiler_params": _compiler_params(2, 3)}
 
     dq_kernel = functools.partial(
         _bwd_dq_kernel_stream, scale=scale, causal=causal, seq_len=T,
@@ -843,10 +824,7 @@ def _flash_fwd_tri(q, k, v, seed, scale, block, dropout_rate):
     qf, kf, vf = (t.reshape(BH, T, D) for t in (q, k, v))
     kernel = functools.partial(_fwd_kernel_tri, scale=scale, block=block,
                                dropout_rate=dropout_rate)
-    kw = {}
-    cp = _compiler_params(1, 2)
-    if cp is not None:
-        kw["compiler_params"] = cp
+    kw = {"compiler_params": _compiler_params(1, 2)}
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(BH, tmap.shape[1]),
@@ -941,10 +919,7 @@ def _flash_bwd_tri(scale, block, dropout_rate, residuals, g):
     lse = jnp.broadcast_to(lse[:, :, None], (BH, T, LANES))
     qf, kf, vf = (t.reshape(BH, T, D) for t in (q, k, v))
     gf = g.reshape(BH, T, D)
-    kw = {}
-    cp = _compiler_params(1, 2)
-    if cp is not None:
-        kw["compiler_params"] = cp
+    kw = {"compiler_params": _compiler_params(1, 2)}
 
     tmap_q = jnp.asarray(_tri_tile_map(n, kv_major=False))
     dq_kernel = functools.partial(_bwd_dq_kernel_tri, scale=scale,
@@ -1061,14 +1036,25 @@ _flash_stream.defvjp(_flash_stream_fwd_rule, _flash_stream_bwd_rule)
 # ---------------------------------------------------------------------------
 
 _INTERPRET = False
+_ROUTE_LOGGED = None
 
 
 def _interpret_mode() -> bool:
-    return _INTERPRET or jax.default_backend() != "tpu"
+    """Whether the Pallas kernels run interpreted. Only ever because
+    someone asked (``set_interpret(True)``: tests/conftest.py, bench.py
+    --platform cpu) — never because of the backend. A kernel reached
+    on a backend that cannot lower it fails at compile, loudly. The
+    route is logged once per process (and again if it flips)."""
+    global _ROUTE_LOGGED
+    if _ROUTE_LOGGED != _INTERPRET:
+        _ROUTE_LOGGED = _INTERPRET
+        log.info("pallas kernels: %s", "INTERPRET mode (set_interpret)"
+                 if _INTERPRET else "compiled (Mosaic)")
+    return _INTERPRET
 
 
 def set_interpret(flag: bool) -> None:
-    """Force interpreter mode (CPU testing)."""
+    """Run every Pallas kernel in interpreter mode (CPU testing)."""
     global _INTERPRET
     _INTERPRET = flag
 
@@ -1428,11 +1414,7 @@ def _chunk_bwd_dkv_kernel_stream(seed_ref, off_ref, q_ref, k_ref, v_ref,
 def _chunk_streaming(Tq, Tk, D, itemsize) -> bool:
     """Route a chunk call to the streamed kernels when either side's
     resident arrays (K/V for fwd/dq, q-side for dkv) exceed the measured
-    resident-compile bound. pltpu-less installs keep the resident
-    kernels at any size (their scratch-free fori_loop bodies need no
-    TPU memory spaces), mirroring pallas_flash_attention's degrade."""
-    if pltpu is None:
-        return False
+    resident-compile bound."""
     return _should_stream(max(Tq, Tk), D, itemsize)
 
 
@@ -1447,10 +1429,7 @@ def _chunk_fwd_stream(q, k, v, seed, offs, scale, causal, block_q, block_k,
     kernel = functools.partial(
         _chunk_fwd_kernel_stream, scale=scale, causal=causal, seq_len_k=Tk,
         block_q=block_q, block_k=block_k, dropout_rate=dropout_rate)
-    kw = {}
-    cp = _compiler_params(2, 3)
-    if cp is not None:
-        kw["compiler_params"] = cp
+    kw = {"compiler_params": _compiler_params(2, 3)}
     o, lse = pl.pallas_call(
         kernel,
         grid=(BH, Tq // block_q, Tk // block_k),
@@ -1480,10 +1459,7 @@ def _chunk_fwd_stream(q, k, v, seed, offs, scale, causal, block_q, block_k,
 def _chunk_bwd_stream(scale, causal, block_q, block_k, dropout_rate,
                       seed, offs, qf, kf, vf, gf, lse_b, deltap,
                       BH, Tq, Tk, D, dtype):
-    kw = {}
-    cp = _compiler_params(2, 3)
-    if cp is not None:
-        kw["compiler_params"] = cp
+    kw = {"compiler_params": _compiler_params(2, 3)}
     dq = pl.pallas_call(
         functools.partial(
             _chunk_bwd_dq_kernel_stream, scale=scale, causal=causal,
@@ -1676,9 +1652,9 @@ def _flash_chunk_bwd_rule(scale, causal, block_q, block_k, dropout_rate,
         return (dq.reshape(B, H, Tq, D), dk.reshape(B, H, Tk, D),
                 dv.reshape(B, H, Tk, D), None, None)
 
-    if pltpu is not None and Tq * D * 4 <= FUSED_DQ_SCRATCH_BYTES:
+    if Tq * D * 4 <= FUSED_DQ_SCRATCH_BYTES:
         # one fused kv-major launch (see _chunk_bwd_fused_kernel); the
-        # split kernels below remain for long chunks and pltpu-less runs
+        # split kernels below remain for long chunks
         dq, dk, dv = _fused_kv_major_bwd(
             scale, causal, block_q, block_k, dropout_rate,
             seed, offs, qf, kf, vf, gf, lse_b, deltap,
@@ -1784,11 +1760,6 @@ def pallas_flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     block_k = _block_for(T, block_k)
     if stream is None:
         stream = _should_stream(T, D, jnp.dtype(q.dtype).itemsize)
-    if pltpu is None:
-        # the streamed grids need pltpu (VMEM scratch, scalar prefetch);
-        # on installs without it degrade to the resident kernels, which
-        # run everywhere via interpret mode
-        stream = False
     fn = _flash_stream if stream else _flash
     return fn(q, k, v, seed, scale, bool(causal), block_q,
               block_k, rate)
@@ -2138,10 +2109,7 @@ def _group_fwd(qkv, seed, scale, causal, n_head, block_q, block_k,
         _fwd_kernel_group, scale=scale, causal=causal, n_head=n_head,
         head_dim=D, heads_per_group=hpg, seq_len=T, block_q=block_q,
         block_k=block_k, dropout_rate=dropout_rate)
-    kw = {}
-    cp = _compiler_params(3, 3)
-    if cp is not None:
-        kw["compiler_params"] = cp
+    kw = {"compiler_params": _compiler_params(3, 3)}
     o, lse = pl.pallas_call(
         kernel,
         grid=(B, G, T // block_q),
@@ -2231,10 +2199,7 @@ def _group_bwd(qkv, do, lse_c, delta_c, seed, scale, causal, n_head,
         _bwd_kernel_group, scale=scale, causal=causal, n_head=n_head,
         head_dim=D, heads_per_group=hpg, seq_len=T, block_q=block_q,
         block_k=block_k, dropout_rate=dropout_rate)
-    kw = {}
-    cp = _compiler_params(2, 2)
-    if cp is not None:
-        kw["compiler_params"] = cp
+    kw = {"compiler_params": _compiler_params(2, 2)}
     strip = lambda blk: _vmem_spec((None, T, W), lambda b, g: (b, 0, blk(g)))
     stat = _vmem_spec((None, None, T, hpg), lambda b, g: (b, g, 0, 0))
     dq, dk, dv = pl.pallas_call(
@@ -2319,13 +2284,12 @@ _flash_packed_group.defvjp(_flash_packed_group_fwd_rule,
 # Auto-route gate for the streamed head-group family. False keeps the
 # family OPT-IN (family="group_stream") and off the production routing —
 # both the family=None dispatch below and ops.flash_attention's
-# packed_envelope_ok read it. Flip to True only once hw_validate's
-# compile4k / compile32k / parity4k phases PASS under real Mosaic
-# lowering: this codebase has already shipped a (T,)-stats layout that
-# interpret mode accepted and Mosaic rejected, so interpret-mode proof
-# alone must not put a kernel family on the default path (long-context
-# runs would trade the proven unpacked streamed family for a possible
-# compile failure at merge).
+# packed_envelope_ok read it. Flip to True only once a chip run shows
+# the family bit-equal to the unpacked streamed family at T=4096 and
+# T=32768 (it compiles for a described v5e; it has never RUN on one):
+# this codebase has already shipped a (T,)-stats layout that interpret
+# mode accepted and Mosaic rejected, so interpret-mode proof alone must
+# not put a kernel family on the default path.
 GROUP_STREAM_AUTOROUTE = False
 
 
@@ -2396,10 +2360,7 @@ def _group_fwd_stream(qkv, seed, scale, causal, n_head, block_q, block_k,
         _fwd_kernel_group_stream, scale=scale, causal=causal,
         n_head=n_head, head_dim=D, heads_per_group=hpg, seq_len=T,
         block_q=block_q, block_k=block_k, dropout_rate=dropout_rate)
-    kw = {}
-    cp = _compiler_params(3, 4)
-    if cp is not None:
-        kw["compiler_params"] = cp
+    kw = {"compiler_params": _compiler_params(3, 4)}
     o, lse = pl.pallas_call(
         kernel,
         grid=(B, G, T // block_q, T // block_k),
@@ -2518,10 +2479,7 @@ def _group_bwd_stream(qkv, do, lse_c, delta_c, seed, scale, causal, n_head,
     common = dict(scale=scale, causal=causal, n_head=n_head, head_dim=D,
                   heads_per_group=hpg, seq_len=T, block_q=block_q,
                   block_k=block_k, dropout_rate=dropout_rate)
-    kw = {}
-    cp = _compiler_params(3, 4)
-    if cp is not None:
-        kw["compiler_params"] = cp
+    kw = {"compiler_params": _compiler_params(3, 4)}
     qs = lambda blk: _vmem_spec((None, block_q, W),
                                 lambda b, g, j, kb: (b, j, blk(g)))
     ks = lambda blk: _vmem_spec((None, block_k, W),
@@ -2620,10 +2578,7 @@ def _group_fwd_tri(qkv, seed, scale, n_head, block, dropout_rate):
     kernel = functools.partial(
         _fwd_kernel_group_tri, scale=scale, n_head=n_head, head_dim=D,
         heads_per_group=hpg, block=block, dropout_rate=dropout_rate)
-    kw = {}
-    cp = _compiler_params(2, 3)
-    if cp is not None:
-        kw["compiler_params"] = cp
+    kw = {"compiler_params": _compiler_params(2, 3)}
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, G, tmap.shape[1]),
@@ -2732,10 +2687,7 @@ def _group_bwd_tri(qkv, do, lse_c, delta_c, seed, scale, n_head, block,
     common = dict(scale=scale, n_head=n_head, head_dim=D,
                   heads_per_group=hpg, block=block,
                   dropout_rate=dropout_rate)
-    kw = {}
-    cp = _compiler_params(2, 3)
-    if cp is not None:
-        kw["compiler_params"] = cp
+    kw = {"compiler_params": _compiler_params(2, 3)}
 
     tmap_q = jnp.asarray(_tri_tile_map(n, kv_major=False))
     # tm[0] = q-block (carried), tm[1] = kv-block

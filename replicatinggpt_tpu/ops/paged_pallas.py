@@ -31,9 +31,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.experimental import pallas as pl
 
-from ..parallel.compat import shard_map
 from .flash_pallas import (LANES, NEG_INF, _compiler_params,
                            _interpret_mode, _vmem_spec, pltpu)
 
@@ -148,8 +148,6 @@ def paged_attention_envelope(n_head: int, head_dim: int, page_size: int,
         reasons.append("n_head_gt_lanes")
     if page_size % 8 != 0:
         reasons.append("page_align")
-    if pltpu is None and not _interpret_mode():
-        reasons.append("no_pltpu")
     C = n_head * head_dim
     if 2 * page_size * C * itemsize > PAGED_DECODE_BYTES:
         reasons.append("vmem_budget")
@@ -359,16 +357,8 @@ def paged_window_attention(q: jnp.ndarray, k_new: jnp.ndarray,
         # index skips the DMA (the fetch-skip trick)
         return (tables[b, p], 0, 0)
 
-    if pltpu is None:  # pragma: no cover — pltpu-less installs are
-        # gated out by the envelope; kept so an explicit call errors
-        # with a clear message instead of a pallas internals traceback
-        raise RuntimeError("paged_window_attention needs pallas TPU "
-                           "memory spaces (jax.experimental.pallas.tpu)")
     row = _vmem_spec((None, W, C), row_map)
-    kw = {}
-    cp = _compiler_params(0, 2)
-    if cp is not None:
-        kw["compiler_params"] = cp
+    kw = {"compiler_params": _compiler_params(0, 2)}
     scratch = [pltpu.VMEM((W, C), jnp.float32),
                pltpu.VMEM((W, LANES), jnp.float32),
                pltpu.VMEM((W, LANES), jnp.float32)]

@@ -38,10 +38,10 @@ from typing import Any, Callable, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
+from jax import set_mesh
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..config import MeshConfig, ModelConfig
-from .compat import set_mesh
 
 # param-name → (tp_dim or None); dims are indices into the *unstacked* shape
 # (block params carry a leading layer dim handled by offset)
@@ -131,6 +131,16 @@ def _leaf_spec(path, shape: Tuple[int, ...], mesh_cfg: MeshConfig) -> P:
                     and shape[d] >= mesh_cfg.data:
                 spec[d] = "data"
                 break
+    # size-1 axes dropped and trailing Nones trimmed: jit NORMALIZES
+    # output specs this way and keys its cache on the spec's
+    # REPRESENTATION, so a state that goes in as P(None, 'model', None)
+    # and comes back as P(None, 'model') — or names a 'model' axis of
+    # size 1 — compiles the step a second time (CompileGuard caught it
+    # on the runner's K-step mesh path); page_pool_pspec does the same
+    spec = [None if ax is not None and getattr(mesh_cfg, ax) == 1
+            else ax for ax in spec]
+    while spec and spec[-1] is None:
+        spec.pop()
     return P(*spec)
 
 
@@ -224,20 +234,17 @@ def parse_mesh_shape(text: str) -> Tuple[int, int]:
     return d, m
 
 
-def resolve_mesh_shape(text: str, n_devices: int,
-                       warn=None) -> Tuple[int, int]:
-    """``parse_mesh_shape`` + the device-count downgrade rule — ONE
-    definition (message included) for the CLI
-    (`engine_config_from_args`) and bench: a mesh the process cannot
-    satisfy resolves to (1, 1) (degrade, not die — the
-    `_build_mesh_if_needed` convention), with the downgrade reported
-    through ``warn`` (a callable taking the message; None = silent)."""
+def resolve_mesh_shape(text: str, n_devices: int) -> Tuple[int, int]:
+    """``parse_mesh_shape`` + the device-count check — ONE definition
+    (message included) for the CLI (`engine_config_from_args`) and
+    bench: a mesh the process cannot satisfy is an error. Running
+    unsharded under a flag that asked for a mesh would report one
+    chip's behaviour under four chips' name."""
     d, m = parse_mesh_shape(text)
     if d * m > max(n_devices, 1):
-        if warn is not None:
-            warn(f"serve mesh {text} wants {d * m} devices, have "
-                 f"{n_devices}; running unsharded")
-        return 1, 1
+        raise ValueError(
+            f"serve mesh {text} wants {d * m} devices, this process "
+            f"has {n_devices}")
     return d, m
 
 
@@ -299,19 +306,18 @@ class ServeShardings:
     unchanged; ``rep`` pins the per-slot step state and the sampled
     token block to full replication (the host fetch stays local).
 
-    ``rep2`` is the same full replication in the RANK-2 spec
-    representation ``P(None, None)``: the jit cache key is
-    representational (``P() != P(None, None)`` even though both mean
-    replicated), a no-op with_sharding_constraint does not rewrite the
-    propagated representation, and the window program's (B, 2) rng
-    streams propagate out rank-matched — so the engine's bootstrap
-    commit of the rng state must use this representation or the first
-    steady-state dispatch after it compiles the same program twice
-    (caught by CompileGuard, pinned in tests/test_serve_mesh.py)."""
+    Replication is spelled ``P()`` everywhere, whatever the rank: the
+    jit cache key is representational (``P() != P(None, None)`` even
+    though both mean replicated), and jit hands a replicated OUTPUT the
+    spelling of the first replicated INPUT it finds. The params come
+    first and their specs are normalized (``_leaf_spec`` trims trailing
+    Nones), so every replicated output — the (B, 2) rng streams
+    included — comes back as ``P()``; engine state that is committed
+    any other way compiles its program twice (caught by CompileGuard,
+    pinned in tests/test_serve_mesh.py)."""
 
     cache: NamedSharding
     rep: NamedSharding
-    rep2: NamedSharding
     #: quantized-pool scale arrays (``ks``/``vs`` — page axis over
     #: 'data' via page_scale_pspec); present on every plan so the
     #: static bundle's hash does not depend on whether quantization is
@@ -325,7 +331,6 @@ def serve_shardings(mesh: Mesh, cfg: ModelConfig, n_pages: int,
         cache=NamedSharding(mesh, page_pool_pspec(cfg, n_pages, data,
                                                   model)),
         rep=NamedSharding(mesh, P()),
-        rep2=NamedSharding(mesh, P(None, None)),
         scale=NamedSharding(mesh, page_scale_pspec(n_pages, data)))
 
 

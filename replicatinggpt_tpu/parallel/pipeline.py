@@ -38,9 +38,9 @@ from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
+from jax.lax import axis_size
 from jax.sharding import Mesh, PartitionSpec as P
-
-from .compat import axis_size, shard_map
 
 from ..config import MeshConfig, ModelConfig
 

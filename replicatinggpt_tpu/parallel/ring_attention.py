@@ -47,9 +47,9 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
+from jax.lax import axis_size
 from jax.sharding import Mesh, PartitionSpec as P
-
-from .compat import axis_size, shard_map
 
 from ..ops.attention import NEG_INF, uint8_inverted_dropout
 

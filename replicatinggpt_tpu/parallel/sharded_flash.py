@@ -25,9 +25,9 @@ import functools
 from typing import Optional
 
 import jax
+from jax import shard_map
+from jax.lax import axis_size
 from jax.sharding import Mesh, PartitionSpec as P
-
-from .compat import axis_size, shard_map
 
 from ..ops.attention import full_causal_attention
 from ..ops.flash_attention import FLASH_MIN_T

@@ -396,6 +396,19 @@ class _InFlight:
     pf_done: List = field(default_factory=list)
 
 
+def _upload(mirror: np.ndarray) -> jax.Array:
+    """Device copy of a LIVE host mirror for an async dispatch. The
+    copy is the point: ``jnp.asarray`` of a numpy array may alias its
+    memory (the CPU backend does, zero-copy), and the windowed engine
+    keeps mutating its mirrors at later boundaries while the window
+    that was handed them is still running — an admission's
+    ``_active[slot] = True`` then reached back into the in-flight
+    window, whose slot decoded one garbage token before its prefill
+    (found rehearsing chip_smoke.py: a 7-token stream under
+    max_new_tokens=6, timing-dependent)."""
+    return jnp.array(mirror)          # copy=True, unlike jnp.asarray
+
+
 def _merge_lifecycle(tok, pos, active, budget, life, shardings):
     """Fold the boundary's host-side lifecycle deltas into the donated
     device step state AT THE TOP of a window dispatch — the mechanism
@@ -878,14 +891,10 @@ class Engine:
         # CachePool.cache (the array becomes a committed jit output
         # after the first step)
         from .cache_pool import commit_default
-        # rng streams are (P, 2): their bootstrap commit must use the
-        # rank-2 replicated REPRESENTATION (ServeShardings.rep2) — the
-        # jit cache key is representational, and the window programs
-        # propagate the rng state out rank-matched
+        # (replication is spelled P() for every rank — ServeShardings)
         self._rngs = commit_default(
             jnp.stack([jax.random.PRNGKey(i) for i in range(P)]),
-            sharding=(self._plan.rep2 if self._plan is not None
-                      else None))
+            sharding=self._rep)
         self._slots: Dict[int, _Active] = {}
         self._pending: List[RequestResult] = []  # cancellations between steps
         self.n_steps = 0
@@ -1767,12 +1776,12 @@ class Engine:
         a window dispatch re-uses them with zero device_put calls,
         which is most of the host tax the window amortizes."""
         if self._li is None:
-            self._li = (jnp.asarray(self._eos),
-                        jnp.asarray(self.pool.tables),
-                        jnp.asarray(self._temp),
-                        jnp.asarray(self._top_k),
-                        jnp.asarray(self._top_p),
-                        jnp.asarray(self._greedy))
+            # COPIES of the mirrors (see _upload): the host rewrites a
+            # slot's row at the next admission while a window that
+            # reads these may still be in flight
+            self._li = tuple(_upload(a) for a in (
+                self._eos, self.pool.tables, self._temp, self._top_k,
+                self._top_p, self._greedy))
         return self._li
 
     def _launch(self, k: int, kill: Optional[np.ndarray] = None
@@ -1812,7 +1821,7 @@ class Engine:
             # means replicated over every device (the constrained
             # window output's placement), not one chip
             from .cache_pool import commit_default
-            state = tuple(commit_default(jnp.asarray(a),
+            state = tuple(commit_default(_upload(a),
                                          sharding=self._rep) for a in
                           (self._tok, self._pos, self._active,
                            self._budget))

@@ -112,7 +112,8 @@ def run_replay(params, mcfg: ModelConfig, rcfg: ReplayConfig,
                profile_steps: int = 5,
                trace: Optional[List[Tuple[float, Request]]] = None,
                cancels: Optional[List[Tuple[float, str]]] = None,
-               deadlines: Optional[dict] = None) -> dict:
+               deadlines: Optional[dict] = None,
+               inspect=None) -> dict:
     """Replay the trace in wall-clock time; returns the summary dict.
 
     ``warmup`` first pushes one tiny request through a throwaway engine
@@ -153,6 +154,11 @@ def run_replay(params, mcfg: ModelConfig, rcfg: ReplayConfig,
     request that already finished is a no-op), and ``deadlines`` maps
     request ids to RELATIVE deadlines applied at submit (per-request,
     where ``rcfg.deadline_s`` is uniform).
+
+    ``inspect(engine, results)`` is called once after the replay, with
+    the live engine and every RequestResult — for callers that check
+    what the summary does not carry (token streams against a
+    reference, where the pool's shards live: chip_smoke.py).
     """
     def drafter():
         return make_drafter(rcfg.spec, rcfg.spec_k, rcfg.spec_ngram,
@@ -243,6 +249,8 @@ def run_replay(params, mcfg: ModelConfig, rcfg: ReplayConfig,
         if timeline is not None:
             timeline.close(step=engine.n_steps)  # forced end-of-run point
     wall_s = time.monotonic() - t0
+    if inspect is not None:
+        inspect(engine, results)
 
     done = compile_counts()
     ok = [r for r in results if r.ok]

@@ -583,8 +583,16 @@ async def _run_async(worker: WorkerServer, host: str, port: int,
         # answers the retry from its reply cache (one episode = one
         # logical attach; _reregister_loop refreshes the suffix per
         # silence episode)
+        import jax
+        devs = jax.devices()
         reg_doc = {"port": bound[1], "pid": os.getpid(), "gen": gen,
                    "worker_idx": worker_idx,
+                   # the parent never initializes a backend (one
+                   # process per chip): this is how it learns the
+                   # fleet's device
+                   "device": {"platform": devs[0].platform,
+                              "kind": devs[0].device_kind,
+                              "count": len(devs)},
                    "replayed": worker.n_replayed,
                    "proto": PROTO_VERSION, "shape_hash": shape_hash,
                    "tier": tier,
@@ -629,8 +637,10 @@ def run_worker(args) -> int:
     """The serve-worker subcommand body (see cli.py for the flags)."""
     from ..config import config_from_args
     from ..train.state import create_train_state
+    from ..utils.compile_cache import enable_compile_cache
     import jax
 
+    enable_compile_cache()
     cfg = config_from_args(args)
     state = create_train_state(jax.random.PRNGKey(cfg.train.seed),
                                cfg.model, cfg.train)

@@ -262,8 +262,15 @@ class ByteBPETokenizer:
         return np.asarray(self.encode(s), np.int32)
 
     def decode(self, ids: Sequence[int]) -> str:
-        text = "".join(self.id_to_token[int(i)] for i in ids)
-        data = bytes(_BYTE_DECODER[ch] for ch in text)
+        # an id past this vocabulary decodes to U+FFFD, like an
+        # undecodable byte: a model's vocab may be padded beyond the
+        # tokenizer's (gpt2-small's 50257 over the 1024-token corpus
+        # BPE) and an untrained model samples from all of it — `cli
+        # generate` from random init died here with a KeyError
+        unknown = "\ufffd".encode("utf-8")
+        data = b"".join(
+            bytes(_BYTE_DECODER[ch] for ch in self.id_to_token[int(i)])
+            if int(i) in self.id_to_token else unknown for i in ids)
         return data.decode("utf-8", errors="replace")
 
     def save(self, path: str) -> None:

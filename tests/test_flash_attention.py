@@ -917,7 +917,7 @@ def test_group_stream_envelope_and_routing(monkeypatch):
 
 
 def test_group_stream_gated_out_of_autoroute_by_default(monkeypatch):
-    """Until hw_validate's compile/parity phases pass on real Mosaic,
+    """Until a chip run proves the family's parity on real Mosaic,
     group_stream must stay opt-in: with the gate at its shipped default
     the envelope excludes group_stream-only shapes (callers fall back to
     the hardware-proven unpacked streamed family) and the family=None

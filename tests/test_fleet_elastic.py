@@ -458,7 +458,12 @@ def test_router_ledger_torn_finish_requeues_exactly_once(tmp_path):
             stream.extend(r.take_new_tokens(b.id))
         # a finished long ago: NOT resurrected. b: exactly once.
         assert set(results) == {b.id}
-        want = _offline(b.prompt, 5)
+        # the reference decodes with THIS router's weights (_params,
+        # not _offline's train-state init: two different models)
+        from replicatinggpt_tpu.sample import GenerateConfig, generate
+        want = np.asarray(generate(
+            _params(), np.asarray(b.prompt, np.int32)[None, :], CFG,
+            GenerateConfig(max_new_tokens=5, greedy=True)))[0].tolist()
         assert results[b.id].tokens == want
         assert stream == want
         total_admitted = sum(

@@ -209,9 +209,12 @@ def test_rpc_client_server_roundtrip_over_socket():
             return c
 
         c = await loop.run_in_executor(None, client_side)
+        # client first: since Python 3.12 wait_closed() waits for every
+        # open connection, so closing the server under a live client
+        # deadlocks (this hung the whole tier-1 run into its time limit)
+        c.close()
         server.close()
         await server.wait_closed()
-        c.close()
 
         def after_close():
             # reconnect against the closed listener: RpcDown, not hang
@@ -1259,7 +1262,7 @@ def test_bench_fleet_multiproc_emits_tagged_artifact(tmp_path, capsys):
         fleet_prefix_groups=2, fleet_prefix_len=8, fleet_kill_at=8,
         fleet_journal_dir=str(tmp_path), trace_out=None,
         metrics_timeline=None, metrics_out=None, multiproc=True,
-        fleet_load_step=False, fleet_host_loss=False, net_chaos=False)
+        platform="cpu", fleet_load_step=False, fleet_host_loss=False, net_chaos=False)
     bench.bench_fleet(args)
     lines = [ln for ln in capsys.readouterr().out.splitlines()
              if ln.startswith("{")]
@@ -1301,7 +1304,7 @@ def test_bench_fleet_net_chaos_emits_tagged_artifact(tmp_path, capsys):
         fleet_prefix_groups=2, fleet_prefix_len=8, fleet_kill_at=-1,
         fleet_journal_dir=str(tmp_path), trace_out=None,
         metrics_timeline=None, metrics_out=None, multiproc=True,
-        fleet_load_step=False, fleet_host_loss=False, net_chaos=True)
+        platform="cpu", fleet_load_step=False, fleet_host_loss=False, net_chaos=True)
     bench.bench_fleet(args)
     lines = [ln for ln in capsys.readouterr().out.splitlines()
              if ln.startswith("{")]

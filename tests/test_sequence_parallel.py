@@ -10,7 +10,7 @@ import pytest
 
 from replicatinggpt_tpu.config import MeshConfig, ModelConfig, TrainConfig
 from replicatinggpt_tpu.ops.attention import full_causal_attention
-from replicatinggpt_tpu.parallel.compat import shard_map
+from jax import shard_map
 from replicatinggpt_tpu.parallel import (make_ring_attention_fn,
                                          make_ulysses_attention_fn,
                                          select_attention_fn)

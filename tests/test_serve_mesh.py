@@ -291,19 +291,44 @@ def test_engine_forward_args_round_trips_mesh_shape():
         assert engine_config_from_args(parent).mesh_shape == (2, 2)
 
 
-def test_mesh_shape_downgrades_past_device_count(capsys):
-    """A mesh the process cannot satisfy runs unsharded with a warning
-    (the _build_mesh_if_needed convention), never crashes."""
+def _engine_args(argv):
     import argparse
 
-    from replicatinggpt_tpu.cli import (add_engine_flags,
-                                        engine_config_from_args)
+    from replicatinggpt_tpu.cli import add_engine_flags
     p = argparse.ArgumentParser()
     add_engine_flags(p)
-    args = p.parse_args(["--mesh-shape", "64x64"])
-    ecfg = engine_config_from_args(args)
-    assert ecfg.mesh_shape == (1, 1)
-    assert "running unsharded" in capsys.readouterr().err
+    return p.parse_args(argv)
+
+
+def test_serve_mesh_past_device_count_is_an_error():
+    """A serve mesh the process cannot satisfy is an error — never a
+    quiet 1x1 run under the mesh's name."""
+    from replicatinggpt_tpu.cli import engine_config_from_args
+    with pytest.raises(ValueError, match="wants 4096 devices"):
+        engine_config_from_args(_engine_args(["--mesh-shape", "64x64"]))
+
+
+def test_forwarding_parent_keeps_mesh_shape_unchecked():
+    """The `serve --multiproc` parent only forwards the shape (its
+    workers own the devices and validate it): check_devices=False
+    neither downgrades nor raises, so the fleet's shape hash is the
+    operator's shape."""
+    from replicatinggpt_tpu.cli import engine_config_from_args
+    ecfg = engine_config_from_args(_engine_args(["--mesh-shape", "64x64"]),
+                                   check_devices=False)
+    assert ecfg.mesh_shape == (64, 64)
+
+
+def test_train_mesh_past_device_count_is_an_error():
+    """`--preset gpt2-small` carries MeshConfig(data=8); a process with
+    fewer devices must say so, not train unsharded."""
+    from replicatinggpt_tpu.cli import _build_mesh_if_needed
+    from replicatinggpt_tpu.config import MeshConfig, get_config
+    cfg = get_config("test-tiny").replace(mesh=MeshConfig(data=64))
+    with pytest.raises(SystemExit, match="mesh wants 64 devices"):
+        _build_mesh_if_needed(cfg)
+    assert _build_mesh_if_needed(
+        cfg.replace(mesh=MeshConfig(data=1))) is None
 
 
 def test_parse_mesh_shape_formats():

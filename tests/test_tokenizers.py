@@ -42,6 +42,10 @@ def test_bpe_handles_unseen_text(tiny_corpus):
     tok = ByteBPETokenizer.train(tiny_corpus, vocab_size=300)
     s = "zyx 12345 éüß unseen!"
     assert tok.decode(tok.encode(s)) == s
+    # ids past the vocabulary (a model vocab padded beyond the
+    # tokenizer's; an untrained model samples them) decode to U+FFFD
+    ids = tok.encode("ab")
+    assert tok.decode(ids[:1] + [tok.vocab_size + 7] + ids[1:]) == "a\ufffdb"
 
 
 def test_bpe_save_load(tmp_path, tiny_corpus):
