@@ -1,0 +1,6 @@
+"""Reads ``decode_sample_ms`` as ``decode_sample_ms.json`` beside this file says
+(``chipbench/trace_stats.py`` ``read_spec``)."""
+
+from chipbench import trace_stats
+
+read = trace_stats.reader(__file__)

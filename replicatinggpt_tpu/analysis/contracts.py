@@ -789,7 +789,8 @@ def _local_name_arg_reads(fn: ast.AST, ns: Set[str]) -> Dict[str, Set[str]]:
 # --------------------------------------------------------------------------
 
 _TRACE_PINS_NAME = "TRACE_VALIDATED_NAMES"
-_EMIT_ATTRS = {"begin", "end", "instant", "complete", "span", "name_track"}
+_EMIT_ATTRS = {"begin", "end", "instant", "complete", "span", "phase",
+               "name_track"}
 
 
 def _emitted_names(idx: ProjectIndex) -> Set[str]:

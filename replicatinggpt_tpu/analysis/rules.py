@@ -1908,7 +1908,7 @@ _register(Rule(
         "trace or (worse) the validation goes dead and the soak "
         "gate stops checking anything. The rule collects every "
         "literal or constant-resolvable name passed to "
-        "``begin/end/instant/complete/span/name_track`` and every "
+        "``begin/end/instant/complete/span/phase/name_track`` and every "
         "``{\"ph\": ..., \"name\": ...}`` event literal, and flags "
         "pinned names with no emission site. Skipped when the "
         "linted project has no pins tuple."),

@@ -156,7 +156,9 @@ def _wmm(h: jnp.ndarray, lp: Dict[str, jnp.ndarray], name: str,
             preferred_element_type=jnp.int32)
         return (y.astype(jnp.float32) * s_act
                 * s.astype(jnp.float32)).astype(cd)
-    y = h @ lp[name].astype(cd)
+    with jax.named_scope("cast_params"):
+        w = lp[name].astype(cd)
+    y = h @ w
     if s is not None:
         y = y * s.astype(cd)
     return y
@@ -183,6 +185,26 @@ def _block(x: jnp.ndarray, lp: Dict[str, jnp.ndarray], cfg: ModelConfig, *,
     cd = x.dtype
     r_attn, r_drop1, r_drop2 = (jax.random.split(rng, 3)
                                 if rng is not None else (None, None, None))
+    attn = _block_attention(x, lp, cfg, r_attn, train, attention_fn)
+    # Projection dropout: declared-but-unapplied in the reference
+    # (GPT1.py:132,136, SURVEY.md §8-Q2); correct-by-default here.
+    x = x + _dropout(attn, cfg.dropout, r_drop1, train)
+    with jax.named_scope("mlp"):
+        h = _layer_norm(x, lp["ln2_scale"], lp["ln2_bias"],
+                        cfg.layernorm_eps)
+        h = _activation(_wmm(h, lp, "mlp_up_kernel", cd)
+                        + lp["mlp_up_bias"].astype(cd), cfg.activation)
+        h = (_wmm(h, lp, "mlp_down_kernel", cd)
+             + lp["mlp_down_bias"].astype(cd))
+    return x + _dropout(h, cfg.dropout, r_drop2, train)
+
+
+@jax.named_scope("attn")
+def _block_attention(x, lp, cfg: ModelConfig, r_attn, train: bool,
+                     attention_fn):
+    """ln1, the fused QKV projection, the attention core and the output
+    projection of ``_block``: what it runs under the ``attn`` scope."""
+    cd = x.dtype
     h = _layer_norm(x, lp["ln1_scale"], lp["ln1_bias"], cfg.layernorm_eps)
     qkv = _wmm(h, lp, "qkv_kernel", cd) + lp["qkv_bias"].astype(cd)
     attn = None
@@ -231,15 +253,8 @@ def _block(x: jnp.ndarray, lp: Dict[str, jnp.ndarray], cfg: ModelConfig, *,
                 q, k, v, dropout_rate=cfg.attn_dropout, rng=r_attn,
                 train=train, impl=impl)
         attn = _merge_heads(attn)
-    attn = _wmm(attn, lp, "attn_out_kernel", cd) + lp["attn_out_bias"].astype(cd)
-    # Projection dropout: declared-but-unapplied in the reference
-    # (GPT1.py:132,136, SURVEY.md §8-Q2); correct-by-default here.
-    x = x + _dropout(attn, cfg.dropout, r_drop1, train)
-    h = _layer_norm(x, lp["ln2_scale"], lp["ln2_bias"], cfg.layernorm_eps)
-    h = _activation(_wmm(h, lp, "mlp_up_kernel", cd)
-                    + lp["mlp_up_bias"].astype(cd), cfg.activation)
-    h = _wmm(h, lp, "mlp_down_kernel", cd) + lp["mlp_down_bias"].astype(cd)
-    return x + _dropout(h, cfg.dropout, r_drop2, train)
+    return (_wmm(attn, lp, "attn_out_kernel", cd)
+            + lp["attn_out_bias"].astype(cd))
 
 
 def _remat_policy(name: str):
@@ -308,16 +323,18 @@ def forward(params: Params, idx: jnp.ndarray, cfg: ModelConfig, *,
     # Out-of-range ids would silently clamp on TPU gathers; the reference
     # instead crashed (SURVEY.md §8-B1/B5). Config and tokenizer are
     # validated host-side in the pipeline instead.
-    x = params["wte"].astype(cd)[idx] + params["wpe"].astype(cd)[:T]
+    with jax.named_scope("embed"):
+        x = params["wte"].astype(cd)[idx] + params["wpe"].astype(cd)[:T]
     if blocks_fn is not None:
         x = blocks_fn(x, params["blocks"], cfg, rng=rng, train=train)
     else:
         x = _run_blocks(x, params["blocks"], cfg, rng=rng, train=train,
                         attention_fn=attention_fn)
-    x = _layer_norm(x, params["ln_f_scale"], params["ln_f_bias"],
-                    cfg.layernorm_eps)
-    head = (params["wte"].astype(cd).T if cfg.tied_head
-            else params["lm_head"].astype(cd))
+    with jax.named_scope("head"):
+        x = _layer_norm(x, params["ln_f_scale"], params["ln_f_bias"],
+                        cfg.layernorm_eps)
+        head = (params["wte"].astype(cd).T if cfg.tied_head
+                else params["lm_head"].astype(cd))
     if targets is not None and cfg.loss_chunk:
         if (B * T) % cfg.loss_chunk != 0:
             # a silent fallback here would let an A/B arm measure the
@@ -327,15 +344,18 @@ def forward(params: Params, idx: jnp.ndarray, cfg: ModelConfig, *,
                 f"loss_chunk={cfg.loss_chunk} must divide B*T="
                 f"{B * T}; pick a divisor or set loss_chunk=0")
         return None, _chunked_ce_loss(x, head, targets, cfg.loss_chunk)
-    logits = (x @ head).astype(jnp.float32)
+    with jax.named_scope("head"):
+        logits = (x @ head).astype(jnp.float32)
     if targets is None:
         return logits, None
     import optax
-    loss = optax.softmax_cross_entropy_with_integer_labels(
-        logits.reshape(B * T, -1), targets.reshape(B * T)).mean()
+    with jax.named_scope("loss"):
+        loss = optax.softmax_cross_entropy_with_integer_labels(
+            logits.reshape(B * T, -1), targets.reshape(B * T)).mean()
     return logits, loss
 
 
+@jax.named_scope("loss")
 def _chunked_ce_loss(x, head, targets, chunk: int) -> jnp.ndarray:
     """Cross-entropy without materializing the full (B*T, V) f32 logits:
     a lax.scan over ``chunk``-row slices computes each chunk's logits +
@@ -371,6 +391,7 @@ def _chunked_ce_loss(x, head, targets, chunk: int) -> jnp.ndarray:
 # KV-cache decode path (shared weights, single-position block body)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("attn")
 def _cached_qkv_merged(h_in, lp, cfg: ModelConfig, cd):
     """ln1 + fused QKV projection, heads still merged — the cache-path
     front half of a block as (B, T, C) q/k/v rows (one source of truth
@@ -395,15 +416,17 @@ def _cached_block_tail(h_in, attn_merged, lp, cfg: ModelConfig, cd):
     cache-path back half of a block, shared by decode_step and prefill
     (no dropout: decode paths never train)."""
     aq = getattr(cfg, "act_quant", "none") == "int8"
-    attn = (_wmm(attn_merged, lp, "attn_out_kernel", cd, aq=aq)
-            + lp["attn_out_bias"].astype(cd))
+    with jax.named_scope("attn"):
+        attn = (_wmm(attn_merged, lp, "attn_out_kernel", cd, aq=aq)
+                + lp["attn_out_bias"].astype(cd))
     h_mid = h_in + attn
-    h = _layer_norm(h_mid, lp["ln2_scale"], lp["ln2_bias"],
-                    cfg.layernorm_eps)
-    h = _activation(_wmm(h, lp, "mlp_up_kernel", cd, aq=aq)
-                    + lp["mlp_up_bias"].astype(cd), cfg.activation)
-    h = (_wmm(h, lp, "mlp_down_kernel", cd, aq=aq)
-         + lp["mlp_down_bias"].astype(cd))
+    with jax.named_scope("mlp"):
+        h = _layer_norm(h_mid, lp["ln2_scale"], lp["ln2_bias"],
+                        cfg.layernorm_eps)
+        h = _activation(_wmm(h, lp, "mlp_up_kernel", cd, aq=aq)
+                        + lp["mlp_up_bias"].astype(cd), cfg.activation)
+        h = (_wmm(h, lp, "mlp_down_kernel", cd, aq=aq)
+             + lp["mlp_down_bias"].astype(cd))
     return h_mid + h
 
 
@@ -501,7 +524,8 @@ def decode_step(params: Params, idx_t: jnp.ndarray, pos: jnp.ndarray,
     """
     cd = _dtype(cfg.dtype)
     B = idx_t.shape[0]
-    x = params["wte"].astype(cd)[idx_t] + params["wpe"].astype(cd)[pos]
+    with jax.named_scope("embed"):
+        x = params["wte"].astype(cd)[idx_t] + params["wpe"].astype(cd)[pos]
     x = x[:, None, :]  # (B, 1, C)
 
     if allow_pallas is None:
@@ -657,6 +681,7 @@ def _decode_step_packed(params: Params, x, pos, cache, cfg: ModelConfig,
     return _decode_head(x, params, cfg, cd), {"k": new_k, "v": new_v}
 
 
+@jax.named_scope("head")
 def _decode_head(x, params: Params, cfg: ModelConfig, cd) -> jnp.ndarray:
     """Final layernorm + (tied/untied) head over a (B, 1, C) decode
     state — one source of truth for the fused and XLA decode tails."""
@@ -689,7 +714,8 @@ def prefill(params: Params, idx: jnp.ndarray,
     # longer than the cache buffer would clamp-corrupt the tail
     check_in_bounds(0, P, cache["k"].shape[cache_seq_axis(cfg)],
                     what="prefill prompt write")
-    x = params["wte"].astype(cd)[idx] + params["wpe"].astype(cd)[:P]
+    with jax.named_scope("embed"):
+        x = params["wte"].astype(cd)[idx] + params["wpe"].astype(cd)[:P]
 
     packed = cfg.decode_cache_layout == "packed"
 
@@ -763,7 +789,8 @@ def decode_step_multi(params: Params, idx_t: jnp.ndarray, pos: jnp.ndarray,
     cd = _dtype(cfg.dtype)
     B = idx_t.shape[0]
     bidx = jnp.arange(B)
-    x = params["wte"].astype(cd)[idx_t] + params["wpe"].astype(cd)[pos]
+    with jax.named_scope("embed"):
+        x = params["wte"].astype(cd)[idx_t] + params["wpe"].astype(cd)[pos]
     x = x[:, None, :]  # (B, 1, C)
     packed = cfg.decode_cache_layout == "packed"
     H = cfg.n_head
@@ -849,8 +876,9 @@ def verify_step_multi(params: Params, window: jnp.ndarray, pos: jnp.ndarray,
     abs_pos = pos[:, None] + offs                       # (B, W)
     # wpe gather clamps out-of-bounds rows (padding only — real window
     # positions are bounded host-side: pos + n_valid <= S - 1)
-    x = (params["wte"].astype(cd)[window]
-         + params["wpe"].astype(cd)[jnp.minimum(abs_pos, S - 1)])  # (B, W, C)
+    with jax.named_scope("embed"):
+        x = (params["wte"].astype(cd)[window]                    # (B, W, C)
+             + params["wpe"].astype(cd)[jnp.minimum(abs_pos, S - 1)])
     # padding writes go to S where the scatter drops them
     wpos = jnp.where(offs <= n_valid[:, None], abs_pos, S)
     packed = cfg.decode_cache_layout == "packed"
@@ -1021,6 +1049,7 @@ def _gather_pages(c_layer: jnp.ndarray, tables: jnp.ndarray,
     return g.transpose(0, 2, 1, 3, 4).reshape(B, H, mp * psz, D)
 
 
+@jax.named_scope("kv_scatter")
 def _scatter_kv(cc: Dict[str, jnp.ndarray], layer_idx, phys, woff,
                 k_m: jnp.ndarray, v_m: jnp.ndarray, packed: bool,
                 n_head: int) -> Dict[str, jnp.ndarray]:
@@ -1089,18 +1118,27 @@ def _scatter_kv(cc: Dict[str, jnp.ndarray], layer_idx, phys, woff,
     return {**cc, "k": ck, "v": cv, "ks": cks, "vs": cvs}
 
 
+@jax.named_scope("kv_gather")
+def _pool_layer(cc: Dict[str, jnp.ndarray], layer_idx):
+    """Layer ``layer_idx`` of the stacked pool: ``(k, v, k_scales,
+    v_scales)``, the scales None on an unquantized pool. What the paged
+    kernel is handed, and what ``_gather_kv`` gathers pages from."""
+    k_l = jax.lax.dynamic_index_in_dim(cc["k"], layer_idx, 0, False)
+    v_l = jax.lax.dynamic_index_in_dim(cc["v"], layer_idx, 0, False)
+    ks_l = vs_l = None
+    if "ks" in cc:
+        ks_l = jax.lax.dynamic_index_in_dim(cc["ks"], layer_idx, 0, False)
+        vs_l = jax.lax.dynamic_index_in_dim(cc["vs"], layer_idx, 0, False)
+    return k_l, v_l, ks_l, vs_l
+
+
+@jax.named_scope("kv_gather")
 def _gather_kv(cc: Dict[str, jnp.ndarray], layer_idx, tables,
                packed: bool, n_head: int, cd):
     """Per-layer logical K/V views through ``_gather_pages``, with the
     scale layers threaded for quantized pools (dequant at the gather —
     the XLA fallback's half of the in-kernel dequant contract)."""
-    quantized = "ks" in cc
-    k_l = jax.lax.dynamic_index_in_dim(cc["k"], layer_idx, 0, False)
-    v_l = jax.lax.dynamic_index_in_dim(cc["v"], layer_idx, 0, False)
-    ks_l = vs_l = None
-    if quantized:
-        ks_l = jax.lax.dynamic_index_in_dim(cc["ks"], layer_idx, 0, False)
-        vs_l = jax.lax.dynamic_index_in_dim(cc["vs"], layer_idx, 0, False)
+    k_l, v_l, ks_l, vs_l = _pool_layer(cc, layer_idx)
     return (_gather_pages(k_l, tables, packed, n_head, s_layer=ks_l,
                           cd=cd),
             _gather_pages(v_l, tables, packed, n_head, s_layer=vs_l,
@@ -1119,6 +1157,7 @@ def _serve_kernel_mesh(shardings):
     return mesh if mesh.size > 1 else None
 
 
+@jax.named_scope("attn")
 def _paged_window_attn(q_w, k_w, v_w, k_layer, v_layer, tables, pos_eff,
                        n_head, ks_layer, vs_layer, mesh):
     """One layer of windowed paged attention through the unified Pallas
@@ -1170,7 +1209,8 @@ def decode_step_paged(params: Params, idx_t: jnp.ndarray, pos: jnp.ndarray,
     pos_eff = jnp.where(active, pos, 0)
     # eager calls assert; the engine bounds pos host-side at admission
     check_in_bounds(pos_eff, 1, mp * psz, what="paged decode write")
-    x = params["wte"].astype(cd)[idx_t] + params["wpe"].astype(cd)[pos_eff]
+    with jax.named_scope("embed"):
+        x = params["wte"].astype(cd)[idx_t] + params["wpe"].astype(cd)[pos_eff]
     x = x[:, None, :]  # (B, 1, C)
     phys = tables[bidx, jnp.minimum(pos_eff // psz, mp - 1)]
     woff = jnp.where(active, pos_eff % psz, psz)   # inactive -> dropped
@@ -1226,12 +1266,9 @@ def decode_step_paged(params: Params, idx_t: jnp.ndarray, pos: jnp.ndarray,
                 # column pre-quantize-dequantized to the exact value
                 # the scatter below stores. On a >1 serve mesh the
                 # shard_map wrapper runs the same kernel per chip.
-                k_layer = jax.lax.dynamic_index_in_dim(cc["k"], layer_idx,
-                                                       0, keepdims=False)
-                v_layer = jax.lax.dynamic_index_in_dim(cc["v"], layer_idx,
-                                                       0, keepdims=False)
+                k_layer, v_layer, ks_layer, vs_layer = _pool_layer(
+                    cc, layer_idx)
                 k_new, v_new = k_m, v_m                      # (B, 1, C)
-                ks_layer = vs_layer = None
                 if quantized:
                     from ..quant.kv import (fake_quantize_rows,
                                             pool_quant_mode)
@@ -1240,10 +1277,6 @@ def decode_step_paged(params: Params, idx_t: jnp.ndarray, pos: jnp.ndarray,
                                                gran).astype(cd)
                     v_new = fake_quantize_rows(v_new, kv_dtype, H,
                                                gran).astype(cd)
-                    ks_layer = jax.lax.dynamic_index_in_dim(
-                        cc["ks"], layer_idx, 0, keepdims=False)
-                    vs_layer = jax.lax.dynamic_index_in_dim(
-                        cc["vs"], layer_idx, 0, keepdims=False)
                 attn_merged = _paged_window_attn(
                     q_m, k_new, v_new, k_layer, v_layer, tables,
                     pos_eff, H, ks_layer, vs_layer, mesh)
@@ -1492,9 +1525,10 @@ def verify_step_paged(params: Params, window: jnp.ndarray, pos: jnp.ndarray,
     abs_pos = pos_eff[:, None] + offs                   # (B, W)
     # wpe gather clamps padding rows (real window positions are bounded
     # host-side: pos + n_valid <= block_size - 1)
-    x = (params["wte"].astype(cd)[window]
-         + params["wpe"].astype(cd)[jnp.minimum(abs_pos,
-                                                cfg.block_size - 1)])
+    with jax.named_scope("embed"):
+        x = (params["wte"].astype(cd)[window]
+             + params["wpe"].astype(cd)[jnp.minimum(abs_pos,
+                                                    cfg.block_size - 1)])
     valid = (offs <= m_eff[:, None]) & active[:, None]
     lpage = jnp.minimum(abs_pos // psz, mp - 1)
     phys = jnp.take_along_axis(tables, lpage, axis=1)   # (B, W)
@@ -1509,12 +1543,9 @@ def verify_step_paged(params: Params, window: jnp.ndarray, pos: jnp.ndarray,
         if use_kernel:
             # attend stale pool + causal fresh window in-kernel, then
             # scatter (write-then-attend equivalence, see docstring)
-            k_layer = jax.lax.dynamic_index_in_dim(cc["k"], layer_idx,
-                                                   0, keepdims=False)
-            v_layer = jax.lax.dynamic_index_in_dim(cc["v"], layer_idx,
-                                                   0, keepdims=False)
+            k_layer, v_layer, ks_layer, vs_layer = _pool_layer(
+                cc, layer_idx)
             k_w, v_w = k_m, v_m
-            ks_layer = vs_layer = None
             if quantized:
                 from ..quant.kv import (fake_quantize_rows,
                                         pool_quant_mode)
@@ -1523,10 +1554,6 @@ def verify_step_paged(params: Params, window: jnp.ndarray, pos: jnp.ndarray,
                                          gran).astype(cd)
                 v_w = fake_quantize_rows(v_m, kv_dtype, H,
                                          gran).astype(cd)
-                ks_layer = jax.lax.dynamic_index_in_dim(
-                    cc["ks"], layer_idx, 0, keepdims=False)
-                vs_layer = jax.lax.dynamic_index_in_dim(
-                    cc["vs"], layer_idx, 0, keepdims=False)
             attn_merged = _paged_window_attn(
                 q_m, k_w, v_w, k_layer, v_layer, tables, pos_eff, H,
                 ks_layer, vs_layer, mesh)
@@ -1597,9 +1624,10 @@ def prefill_chunk_paged(params: Params, idx: jnp.ndarray,
     positions = offset + jnp.arange(Pc, dtype=jnp.int32)   # (Pc,)
     # eager calls assert; the engine bounds [offset, limit) at admission
     check_in_bounds(offset, 1, cfg.block_size, what="paged prefill chunk")
-    x = (params["wte"].astype(cd)[idx]
-         + params["wpe"].astype(cd)[jnp.minimum(positions,
-                                                cfg.block_size - 1)][None])
+    with jax.named_scope("embed"):
+        x = (params["wte"].astype(cd)[idx]
+             + params["wpe"].astype(cd)[jnp.minimum(positions,
+                                                    cfg.block_size - 1)][None])
     lpage = jnp.minimum(positions // psz, mp - 1)
     phys = table_row[lpage]                                # (Pc,)
     woff = jnp.where((positions < limit) & (positions < Smax),
@@ -1666,9 +1694,10 @@ def prefill_chunk_into_slot(params: Params, idx: jnp.ndarray,
     check_in_bounds(offset, Pc, S, what="prefill chunk write")
     check_in_bounds(slot, 1, cache["k"].shape[1], what="prefill slot index")
     scale = cfg.head_dim ** -0.5
-    x = (params["wte"].astype(cd)[idx]
-         + jax.lax.dynamic_slice_in_dim(params["wpe"].astype(cd), offset,
-                                        Pc, axis=0))
+    with jax.named_scope("embed"):
+        x = (params["wte"].astype(cd)[idx]
+             + jax.lax.dynamic_slice_in_dim(params["wpe"].astype(cd), offset,
+                                            Pc, axis=0))
     packed = cfg.decode_cache_layout == "packed"
     from ..ops.attention import NEG_INF
 

@@ -162,6 +162,7 @@ def packed_decode_attention(q: jnp.ndarray, k_new: jnp.ndarray,
         ],
         out_specs=row,
         out_shape=jax.ShapeDtypeStruct((B, 1, C), q.dtype),
+        name="decode_attention",
         interpret=_interpret_mode(),
         **kw,
     )(jnp.asarray(pos, jnp.int32).reshape(1), q[:, None, :],
@@ -306,6 +307,7 @@ def fused_decode_layers(x0: jnp.ndarray, blocks: Dict[str, jnp.ndarray],
             jax.ShapeDtypeStruct((L, 1, C), cd),
         ],
         scratch_shapes=[pltpu.VMEM((1, C), cd)],
+        name="fused_decode_layers",
         interpret=_interpret_mode(),
         **kw,
     )(jnp.asarray(pos, jnp.int32).reshape(1), x0,
@@ -606,6 +608,7 @@ def fused_paged_decode_layers(x0: jnp.ndarray,
         out_shape=[jax.ShapeDtypeStruct((B, 1, C), cd),
                    jax.ShapeDtypeStruct((L, B, 1, C), cd),
                    jax.ShapeDtypeStruct((L, B, 1, C), cd)],
+        name="fused_paged_decode_layers",
         interpret=_interpret_mode(), **kw,
     )(jnp.asarray(tables, jnp.int32), jnp.asarray(pos, jnp.int32),
       *inputs)

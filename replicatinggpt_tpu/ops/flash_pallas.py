@@ -280,6 +280,7 @@ def _flash_fwd(q, k, v, seed, scale, causal, block_q, block_k,
             jax.ShapeDtypeStruct((BH, T, D), q.dtype),
             jax.ShapeDtypeStruct((BH, T, LANES), jnp.float32),
         ],
+        name="flash_fwd",
         interpret=_interpret_mode(),
     )(seed, qf, kf, vf)
     return o.reshape(B, H, T, D), lse
@@ -393,6 +394,7 @@ def _flash_bwd_fused(scale, causal, block_q, block_k, dropout_rate,
                   spec_tl, spec_tl],
         out_specs=[spec_td, spec_td, spec_td],
         out_shape=[jax.ShapeDtypeStruct((BH, T, D), dtype)] * 3,
+        name="flash_bwd",
         interpret=_interpret_mode(),
     )(seed, qf, kf, vf, gf, lse, delta)
 
@@ -434,6 +436,7 @@ def _fused_kv_major_bwd(scale, causal, block_q, block_k, dropout_rate,
             jax.ShapeDtypeStruct((BH, Tk, D), dtype),
         ],
         scratch_shapes=[_scratch((Tq, D))],
+        name="flash_kvmajor_bwd",
         interpret=_interpret_mode(),
         **kw,
     )(seed, offs, qf, kf, vf, gf, lse, delta)
@@ -493,6 +496,7 @@ def _flash_bwd(scale, causal, block_q, block_k, dropout_rate, residuals, g):
         ],
         out_specs=_vmem_spec((None, block_q, D), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, T, D), q.dtype),
+        name="flash_bwd_dq",
         interpret=_interpret_mode(),
     )(seed, qf, kf, vf, gf, lse, delta)
 
@@ -519,6 +523,7 @@ def _flash_bwd(scale, causal, block_q, block_k, dropout_rate, residuals, g):
             jax.ShapeDtypeStruct((BH, T, D), q.dtype),
             jax.ShapeDtypeStruct((BH, T, D), q.dtype),
         ],
+        name="flash_bwd_dkv",
         interpret=_interpret_mode(),
     )(seed, qf, kf, vf, gf, lse, delta)
 
@@ -621,6 +626,7 @@ def _flash_fwd_stream(q, k, v, seed, scale, causal, block_q, block_k,
         ],
         scratch_shapes=[_scratch((block_q, D)), _scratch((block_q, LANES)),
                         _scratch((block_q, LANES))],
+        name="flash_stream_fwd",
         interpret=_interpret_mode(),
         **kw,
     )(seed, qf, kf, vf)
@@ -724,6 +730,7 @@ def _flash_bwd_stream(scale, causal, block_q, block_k, dropout_rate,
         out_specs=_vmem_spec((None, block_q, D), lambda i, j, kb: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, T, D), q.dtype),
         scratch_shapes=[_scratch((block_q, D))],
+        name="flash_stream_bwd_dq",
         interpret=_interpret_mode(),
         **kw,
     )(seed, qf, kf, vf, gf, lse, delta)
@@ -752,6 +759,7 @@ def _flash_bwd_stream(scale, causal, block_q, block_k, dropout_rate,
             jax.ShapeDtypeStruct((BH, T, D), q.dtype),
         ],
         scratch_shapes=[_scratch((block_k, D)), _scratch((block_k, D))],
+        name="flash_stream_bwd_dkv",
         interpret=_interpret_mode(),
         **kw,
     )(seed, qf, kf, vf, gf, lse, delta)
@@ -848,6 +856,7 @@ def _flash_fwd_tri(q, k, v, seed, scale, block, dropout_rate):
             jax.ShapeDtypeStruct((BH, T, D), q.dtype),
             jax.ShapeDtypeStruct((BH, T, LANES), jnp.float32),
         ],
+        name="flash_tri_fwd",
         interpret=_interpret_mode(),
         **kw,
     )(tmap, seed, qf, kf, vf)
@@ -948,6 +957,7 @@ def _flash_bwd_tri(scale, block, dropout_rate, residuals, g):
             scratch_shapes=[_scratch((block, D))],
         ),
         out_shape=jax.ShapeDtypeStruct((BH, T, D), q.dtype),
+        name="flash_tri_bwd_dq",
         interpret=_interpret_mode(),
         **kw,
     )(tmap_q, seed, qf, kf, vf, gf, lse, delta)
@@ -987,6 +997,7 @@ def _flash_bwd_tri(scale, block, dropout_rate, residuals, g):
             jax.ShapeDtypeStruct((BH, T, D), q.dtype),
             jax.ShapeDtypeStruct((BH, T, D), q.dtype),
         ],
+        name="flash_tri_bwd_dkv",
         interpret=_interpret_mode(),
         **kw,
     )(tmap_kv, seed, qf, kf, vf, gf, lse, delta)
@@ -1450,6 +1461,7 @@ def _chunk_fwd_stream(q, k, v, seed, offs, scale, causal, block_q, block_k,
         ],
         scratch_shapes=[_scratch((block_q, D)), _scratch((block_q, LANES)),
                         _scratch((block_q, LANES))],
+        name="flash_chunk_stream_fwd",
         interpret=_interpret_mode(),
         **kw,
     )(seed, offs, qf, kf, vf)
@@ -1479,6 +1491,7 @@ def _chunk_bwd_stream(scale, causal, block_q, block_k, dropout_rate,
         out_specs=_vmem_spec((None, block_q, D), lambda i, j, kb: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, Tq, D), dtype),
         scratch_shapes=[_scratch((block_q, D))],
+        name="flash_chunk_stream_bwd_dq",
         interpret=_interpret_mode(),
         **kw,
     )(seed, offs, qf, kf, vf, gf, lse_b, deltap)
@@ -1508,6 +1521,7 @@ def _chunk_bwd_stream(scale, causal, block_q, block_k, dropout_rate,
             jax.ShapeDtypeStruct((BH, Tk, D), dtype),
         ],
         scratch_shapes=[_scratch((block_k, D)), _scratch((block_k, D))],
+        name="flash_chunk_stream_bwd_dkv",
         interpret=_interpret_mode(),
         **kw,
     )(seed, offs, qf, kf, vf, gf, lse_b, deltap)
@@ -1546,6 +1560,7 @@ def _chunk_fwd(q, k, v, seed, offs, scale, causal, block_q, block_k,
             jax.ShapeDtypeStruct((BH, Tq, D), q.dtype),
             jax.ShapeDtypeStruct((BH, Tq, LANES), jnp.float32),
         ],
+        name="flash_chunk_fwd",
         interpret=_interpret_mode(),
     )(seed, offs, qf, kf, vf)
     return o.reshape(B, H, Tq, D), lse[..., 0].reshape(B, H, Tq)
@@ -1681,6 +1696,7 @@ def _flash_chunk_bwd_rule(scale, causal, block_q, block_k, dropout_rate,
         ],
         out_specs=_vmem_spec((None, block_q, D), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, Tq, D), q.dtype),
+        name="flash_chunk_bwd_dq",
         interpret=_interpret_mode(),
     )(seed, offs, qf, kf, vf, gf, lse_b, deltap)
 
@@ -1707,6 +1723,7 @@ def _flash_chunk_bwd_rule(scale, causal, block_q, block_k, dropout_rate,
             jax.ShapeDtypeStruct((BH, Tk, D), q.dtype),
             jax.ShapeDtypeStruct((BH, Tk, D), q.dtype),
         ],
+        name="flash_chunk_bwd_dkv",
         interpret=_interpret_mode(),
     )(seed, offs, qf, kf, vf, gf, lse_b, deltap)
 
@@ -1876,6 +1893,7 @@ def _packed_fwd(qkv, seed, scale, causal, n_head, block_q, block_k,
             jax.ShapeDtypeStruct((B, T, C), qkv.dtype),
             jax.ShapeDtypeStruct((B, T, n_head), jnp.float32),
         ],
+        name="flash_packed_fwd",
         interpret=_interpret_mode(),
     )(seed, qkv)
     return o, lse
@@ -1947,6 +1965,7 @@ def _packed_bwd(qkv, do, lse, delta, seed, scale, causal, n_head, block_q,
         out_specs=spec_full(C3),
         out_shape=jax.ShapeDtypeStruct((B, T, C3), qkv.dtype),
         scratch_shapes=[_scratch((T, C))],
+        name="flash_packed_bwd",
         interpret=_interpret_mode(),
     )(seed, qkv, do, lse, delta)
 
@@ -2131,6 +2150,7 @@ def _group_fwd(qkv, seed, scale, causal, n_head, block_q, block_k,
             jax.ShapeDtypeStruct((B, T, C), qkv.dtype),
             jax.ShapeDtypeStruct((B, G, T, hpg), jnp.float32),
         ],
+        name="flash_group_fwd",
         interpret=_interpret_mode(),
         **kw,
     )(seed, qkv, qkv, qkv)
@@ -2212,6 +2232,7 @@ def _group_bwd(qkv, do, lse_c, delta_c, seed, scale, causal, n_head,
         out_specs=[strip(lambda g: g)] * 3,
         out_shape=[jax.ShapeDtypeStruct((B, T, C), qkv.dtype)] * 3,
         scratch_shapes=[_scratch((T, W))],
+        name="flash_group_bwd",
         interpret=_interpret_mode(),
         **kw,
     )(seed, qkv, qkv, qkv, do, lse4, delta4)
@@ -2383,6 +2404,7 @@ def _group_fwd_stream(qkv, seed, scale, causal, n_head, block_q, block_k,
         ],
         scratch_shapes=[_scratch((block_q, W)), _scratch((block_q, W)),
                         _scratch((block_q, W))],
+        name="flash_group_stream_fwd",
         interpret=_interpret_mode(),
         **kw,
     )(seed, qkv, qkv, qkv)
@@ -2494,6 +2516,7 @@ def _group_bwd_stream(qkv, do, lse_c, delta_c, seed, scale, causal, n_head,
         out_specs=qs(lambda g: g),
         out_shape=jax.ShapeDtypeStruct((B, T, C), qkv.dtype),
         scratch_shapes=[_scratch((block_q, W))],
+        name="flash_group_stream_bwd_dq",
         interpret=_interpret_mode(),
         **kw,
     )(seed, qkv, qkv, qkv, do, lse4, delta4)
@@ -2514,6 +2537,7 @@ def _group_bwd_stream(qkv, do, lse_c, delta_c, seed, scale, causal, n_head,
         out_specs=[ks2(lambda g: g), ks2(lambda g: g)],
         out_shape=[jax.ShapeDtypeStruct((B, T, C), qkv.dtype)] * 2,
         scratch_shapes=[_scratch((block_k, W)), _scratch((block_k, W))],
+        name="flash_group_stream_bwd_dkv",
         interpret=_interpret_mode(),
         **kw,
     )(seed, qkv, qkv, qkv, do, lse4, delta4)
@@ -2606,6 +2630,7 @@ def _group_fwd_tri(qkv, seed, scale, n_head, block, dropout_rate):
             jax.ShapeDtypeStruct((B, T, C), qkv.dtype),
             jax.ShapeDtypeStruct((B, G, T, hpg), jnp.float32),
         ],
+        name="flash_group_tri_fwd",
         interpret=_interpret_mode(),
         **kw,
     )(tmap, seed, qkv, qkv, qkv)
@@ -2715,6 +2740,7 @@ def _group_bwd_tri(qkv, do, lse_c, delta_c, seed, scale, n_head, block,
             scratch_shapes=[_scratch((block, W))],
         ),
         out_shape=jax.ShapeDtypeStruct((B, T, C), qkv.dtype),
+        name="flash_group_tri_bwd_dq",
         interpret=_interpret_mode(),
         **kw,
     )(tmap_q, seed, qkv, qkv, qkv, do, lse4, delta4)
@@ -2749,6 +2775,7 @@ def _group_bwd_tri(qkv, do, lse_c, delta_c, seed, scale, n_head, block,
             scratch_shapes=[_scratch((block, W)), _scratch((block, W))],
         ),
         out_shape=[jax.ShapeDtypeStruct((B, T, C), qkv.dtype)] * 2,
+        name="flash_group_tri_bwd_dkv",
         interpret=_interpret_mode(),
         **kw,
     )(tmap_kv, seed, qkv, qkv, qkv, do, lse4, delta4)

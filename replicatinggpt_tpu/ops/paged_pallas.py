@@ -390,6 +390,7 @@ def paged_window_attention(q: jnp.ndarray, k_new: jnp.ndarray,
     )
     return pl.pallas_call(
         kernel, grid_spec=grid_spec, out_shape=out_shape,
+        name="paged_window_attention",
         interpret=_interpret_mode(), **kw,
     )(jnp.asarray(tables, jnp.int32), jnp.asarray(pos, jnp.int32),
       jnp.asarray(owned, jnp.int32), *inputs)

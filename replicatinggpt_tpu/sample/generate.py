@@ -206,6 +206,7 @@ def filter_logits_batched(logits: jnp.ndarray, temperature: jnp.ndarray,
     return batched_top_p_filter(f, top_p)
 
 
+@jax.named_scope("sample")
 def sample_tokens_batched(rngs: jnp.ndarray, logits: jnp.ndarray,
                           temperature: jnp.ndarray, top_k: jnp.ndarray,
                           top_p: jnp.ndarray, greedy: jnp.ndarray

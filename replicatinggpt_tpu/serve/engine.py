@@ -81,8 +81,14 @@ mesh-agnostic; greedy streams are token-identical across mesh shapes
 Observability: per-request TTFT / decode tok/s / queue wait, engine
 counters (admissions, rejections, completions, tokens), slot-occupancy
 and queue-depth gauges, batch-fill-ratio and step-latency histograms —
-through ``utils.logging.Metrics`` and ``utils.profiling.StepTimer``,
-with ``annotate()`` spans around the prefill and decode phases.
+through ``utils.logging.Metrics`` and ``utils.profiling.StepTimer``.
+Every step marks its host phases through ``self.tel.phase`` (a
+``jax.profiler.TraceAnnotation`` whether or not a recorder is attached:
+``serve/step`` around ``serve/expire_shed``, ``serve/admit``,
+``serve/prefill``, and ``serve/decode`` | ``serve/verify`` around
+``serve/launch``, ``serve/fetch``, ``serve/commit``), with the live
+context (``live_tokens``, ``live_kv_bytes``, from the host mirrors) as
+stats of ``serve/launch``; docs/observability.md has the vocabulary.
 """
 
 from __future__ import annotations
@@ -105,7 +111,7 @@ from ..models.gpt import (decode_window_paged, mixed_window_paged,
                           prefill_chunk_paged, verify_step_paged)
 from ..sample.generate import sample_tokens_batched
 from ..utils.logging import Metrics
-from ..utils.profiling import StepTimer, annotate
+from ..utils.profiling import StepTimer
 from ..utils.sanitize import CompileGuard, check_in_bounds, sanitize_enabled
 from ..utils.telemetry import ENGINE_TRACK, NULL, SLOT_TRACK_BASE
 from .pages import PagedCachePool
@@ -485,6 +491,7 @@ def _engine_decode_window(params, tok, pos, active, budget, eos, life,
     tok, pos, active, budget = _merge_lifecycle(
         tok, pos, active, budget, life, shardings)
 
+    @jax.named_scope("sample")
     def sample_fn(rngs, logits):
         splits = jax.vmap(lambda r: jax.random.split(r, 2))(rngs)
         nxt = sample_tokens_batched(splits[:, 0], logits, temp, top_k,
@@ -527,6 +534,7 @@ def _engine_mixed_window(params, tok, pos, active, budget, eos, life,
     tok, pos, active, budget = _merge_lifecycle(
         tok, pos, active, budget, life, shardings)
 
+    @jax.named_scope("sample")
     def sample_fn(rngs, logits):
         splits = jax.vmap(lambda r: jax.random.split(r, 2))(rngs)
         nxt = sample_tokens_batched(splits[:, 0], logits, temp, top_k,
@@ -805,6 +813,12 @@ class Engine:
                                    clock=clock)
         self.metrics = Metrics()
         self.step_timer = StepTimer()
+        # bytes ONE context token holds in the pool across layers, from
+        # the pool's own arrays (K, V and a quantized pool's scales):
+        # what ``serve/launch`` multiplies the live tokens by
+        self._kv_token_bytes = (
+            sum(a.nbytes for a in self.pool.cache.values())
+            // (self.pool.n_pages * self.pool.page_size))
         P = ecfg.pool_size
         self._chunk = ecfg.chunk(cfg.block_size)
         self._window = max(int(ecfg.decode_window), 1)
@@ -1137,33 +1151,42 @@ class Engine:
         drains the window and leaves the path (counted in the
         ``window_breaks_*`` counters); queued-deadline expiry and
         overload shedding are host-only and never touch it."""
+        with self.tel.phase("serve/step", self._tb + ENGINE_TRACK,
+                            step=self.n_steps,
+                            queue_depth=self.scheduler.depth,
+                            n_active=int(self._active.sum())):
+            return self._step()
+
+    def _step(self) -> List[RequestResult]:
         finished: List[RequestResult] = self._pending
         self._pending = []
         now = self.clock()
         t_wall = time.perf_counter()
-        t_step_us = self.tel.now_us() if self.tel.enabled else 0.0
 
-        for req, t_submit, reason in self.scheduler.drain_expired(now):
-            finished.append(self._finish_unstarted(req, t_submit, reason,
-                                                   now))
-        if self._shedder is not None:
-            n_shed = self._shedder.observe(self.scheduler.depth,
-                                           self.ecfg.max_queue)
-            if n_shed:
-                for req, t_submit in self.scheduler.shed(n_shed):
-                    finished.append(self._finish_unstarted(
-                        req, t_submit, FINISH_SHED, now))
-                self.metrics.inc("shed_requests", n_shed)
-                self._event(f"step {self.n_steps}: shed {n_shed} "
-                                   f"queued request(s) under sustained "
-                                   f"overload")
+        with self.tel.phase("serve/expire_shed", self._tb + ENGINE_TRACK):
+            for req, t_submit, reason in self.scheduler.drain_expired(now):
+                finished.append(self._finish_unstarted(req, t_submit,
+                                                       reason, now))
+            if self._shedder is not None:
+                n_shed = self._shedder.observe(self.scheduler.depth,
+                                               self.ecfg.max_queue)
+                if n_shed:
+                    for req, t_submit in self.scheduler.shed(n_shed):
+                        finished.append(self._finish_unstarted(
+                            req, t_submit, FINISH_SHED, now))
+                    self.metrics.inc("shed_requests", n_shed)
+                    self._event(f"step {self.n_steps}: shed {n_shed} "
+                                f"queued request(s) under sustained "
+                                f"overload")
 
-        # active-deadline expiry against the per-slot deadline mirror
-        # precomputed at admission (one vectorized compare, no dict
-        # walk). On the windowed path these become lifecycle-mask kills.
-        expired = [int(s) for s in
-                   np.flatnonzero(self._active & (self._deadline <= now))
-                   if int(s) in self._slots]
+            # active-deadline expiry against the per-slot deadline
+            # mirror precomputed at admission (one vectorized compare,
+            # no dict walk). On the windowed path these become
+            # lifecycle-mask kills.
+            expired = [int(s) for s in
+                       np.flatnonzero(self._active
+                                      & (self._deadline <= now))
+                       if int(s) in self._slots]
 
         # speculative re-probe countdown while degraded (auto-disabled
         # only: an operator pin via set_spec_active(False) must stick)
@@ -1233,7 +1256,7 @@ class Engine:
 
         ran_decode = False
         if windowed:
-            with annotate("serve/decode"):
+            with self.tel.phase("serve/decode", self._tb + ENGINE_TRACK):
                 self._window_step(now, finished)
             ran_decode = True
         elif self._active.any():
@@ -1253,14 +1276,6 @@ class Engine:
                 self._event(f"step {self.n_steps}: stall — "
                                    f"{dur * 1e3:.1f} ms step against "
                                    f"a p99-derived budget")
-        if self.tel.enabled:
-            self.tel.complete("engine_step", self._tb + ENGINE_TRACK,
-                              t_step_us,
-                              self.tel.now_us() - t_step_us,
-                              step=self.n_steps,
-                              queue_depth=self.scheduler.depth,
-                              n_active=int(self._active.sum()),
-                              n_finished=len(finished))
         return finished
 
     def _window_step(self, now: float, finished: List[RequestResult]
@@ -1523,7 +1538,9 @@ class Engine:
             padded[:P - claimed] = req.prompt[claimed:]
             table_row = jnp.asarray(self.pool.tables[slot])
             cache = self.pool.cache
-            with annotate("serve/prefill"):
+            with self.tel.phase("serve/prefill", self._tb + ENGINE_TRACK,
+                                tokens=P - claimed, chunks=n_chunks,
+                                cached_tokens=claimed):
                 for c in range(n_chunks):
                     tc_us = (self.tel.now_us() if self.tel.enabled
                              else 0.0)
@@ -1537,7 +1554,7 @@ class Engine:
                     if self.tel.enabled:
                         # host dispatch time (the device runs async);
                         # a jax.profiler capture of the same run shows
-                        # the device-side cost under serve/prefill
+                        # the device-side cost under this serve/prefill
                         self.tel.complete(
                             "prefill_chunk", tid, tc_us,
                             self.tel.now_us() - tc_us, chunk=c,
@@ -1678,7 +1695,8 @@ class Engine:
             if not admitted:
                 break
             req, t_submit = admitted[0]
-            admit_fn(req, t_submit, now)
+            with self.tel.phase("serve/admit", self._tb + ENGINE_TRACK):
+                admit_fn(req, t_submit, now)
 
     def _flush_prefill(self) -> None:
         """Complete any still-pending in-window prefill through the
@@ -1699,7 +1717,9 @@ class Engine:
             limit = int(self._pf_limit[slot])
             table_row = jnp.asarray(self.pool.tables[slot])
             cache = self.pool.cache
-            with annotate("serve/prefill"):
+            with self.tel.phase("serve/prefill", self._tb + ENGINE_TRACK,
+                                tokens=limit - off, chunks=n,
+                                cached_tokens=off):
                 for c in range(n):
                     cache = self._prefill_guard(
                         self.params,
@@ -1797,13 +1817,31 @@ class Engine:
         mask path forces a mirror re-upload. The token block's
         device->host copy starts immediately (``copy_to_host_async``),
         so by the time ``_drain_window`` reads it the transfer has been
-        overlapping device compute."""
+        overlapping device compute.
+
+        The whole of it is the ``serve/launch`` phase, whose stats carry
+        the context this dispatch attends: ``live_tokens``, the sum of
+        ``pos + 1`` over the live slots from the host mirrors (no device
+        read), and ``live_kv_bytes``, what those tokens hold in the pool
+        across layers."""
         t0_us = self.tel.now_us() if self.tel.enabled else 0.0
         t_wall = time.perf_counter()
-        P = self.ecfg.pool_size
         if kill is None:
-            kill = np.zeros((P,), bool)
-        n_active = int((self._active & ~kill).sum())
+            kill = np.zeros((self.ecfg.pool_size,), bool)
+        live = self._active & ~kill
+        n_active = int(live.sum())
+        live_tokens = int(self._pos[live].sum()) + n_active
+        with self.tel.phase("serve/launch", self._tb + ENGINE_TRACK, k=k,
+                            n_active=n_active, live_tokens=live_tokens,
+                            live_kv_bytes=live_tokens
+                            * self._kv_token_bytes):
+            return self._dispatch(k, kill, n_active, t0_us, t_wall)
+
+    def _dispatch(self, k: int, kill: np.ndarray, n_active: int,
+                  t0_us: float, t_wall: float) -> _InFlight:
+        """``_launch``'s body: upload what the boundary dirtied, enqueue
+        the window program, start the token block's copy home."""
+        P = self.ecfg.pool_size
         if self._dev_state is None:
             # host-side bound for the traced window writes: every REAL
             # write position (bounded by the per-slot budget — the
@@ -1956,13 +1994,24 @@ class Engine:
         the host bookkeeping: append tokens, advance the mirrors,
         finish slots whose budget ran out or whose eos landed. Slots
         that finished mid-window already idled on device; their pages
-        and slot free HERE, at the window boundary."""
-        toks = np.asarray(w.toks)
-        emitted = np.asarray(w.emitted)
+        and slot free HERE, at the window boundary. Two phases: the
+        wait for the device is ``serve/fetch``, the bookkeeping
+        ``serve/commit``."""
+        with self.tel.phase("serve/fetch", self._tb + ENGINE_TRACK):
+            toks = np.asarray(w.toks)
+            emitted = np.asarray(w.emitted)
         now = self.clock()
         self.n_steps += 1
         self.step_timer.laps.append(time.perf_counter() - w.t_wall)
         n_tok = int(emitted.sum())
+        with self.tel.phase("serve/commit", self._tb + ENGINE_TRACK,
+                            step=self.n_steps, k=w.k, tokens=n_tok):
+            return self._commit_window(w, toks, emitted, now, n_tok)
+
+    def _commit_window(self, w: _InFlight, toks: np.ndarray,
+                       emitted: np.ndarray, now: float, n_tok: int
+                       ) -> List[RequestResult]:
+        """``_drain_window``'s host half, after the fetch."""
         if self._sanitize:
             # GRAFT_SANITIZE: sampled ids must be valid vocab entries
             # (an out-of-range id would clamp in the next embedding
@@ -1988,10 +2037,6 @@ class Engine:
         # path stamps on a request's E event, so a slot's last decode
         # span never spills past its request envelope
         dur_us = (self.tel.ts_us(now) - w.t0_us) if tel_on else 0.0
-        if tel_on:
-            self.tel.complete("decode_step", self._tb + ENGINE_TRACK,
-                              w.t0_us, dur_us, step=self.n_steps,
-                              n_active=w.n_active, k=w.k, tokens=n_tok)
         # windowed-admission radix registration: a slot whose in-window
         # prefill COMPLETED in this dispatch has verifiably written its
         # prompt pages — they become claimable from this boundary on
@@ -2075,7 +2120,7 @@ class Engine:
         """Blocked k=1 decode: dispatch one step and immediately fetch
         it — the fallback around host-side state mutations (admission,
         deadline, cancel, speculative transitions)."""
-        with annotate("serve/decode"):
+        with self.tel.phase("serve/decode", self._tb + ENGINE_TRACK):
             return self._drain_window(self._launch(1))
 
     def _histories(self) -> List[Optional[np.ndarray]]:
@@ -2133,7 +2178,9 @@ class Engine:
         # runs those slots at position 0 anyway.
         check_in_bounds(np.where(self._active, self._pos + m, 0), 1, S,
                         what="speculative verify window")
-        with annotate("serve/verify"):
+        with self.tel.phase("serve/verify", self._tb + ENGINE_TRACK,
+                            k=k, n_active=int(self._active.sum()),
+                            drafted=int(m.sum())):
             self.step_timer.start()
             n_acc, out, cache, rngs = self._verify_guard(
                 self.params, jnp.asarray(window), jnp.asarray(self._pos),
@@ -2176,11 +2223,6 @@ class Engine:
         self.metrics.observe("tokens_per_slot_step", emitted / n_active)
         tel_on = self.tel.enabled
         dur_us = (self.tel.ts_us(now) - t0_us) if tel_on else 0.0
-        if tel_on:
-            self.tel.complete("verify_step", self._tb + ENGINE_TRACK,
-                              t0_us, dur_us,
-                              step=self.n_steps, n_active=n_active,
-                              drafted=drafted, accepted=accepted)
         if self._spec_health is not None:
             if self._spec_health.observe(drafted, accepted):
                 # the drafter is a pure tax at this accept rate: fall
@@ -2202,32 +2244,35 @@ class Engine:
                 self._event(f"step {self.n_steps}: speculative "
                                    f"re-probe healthy; backoff reset")
         finished: List[RequestResult] = []
-        for slot in list(self._slots):
-            if not self._active[slot]:
-                continue
-            st = self._slots[slot]
-            n_emit = int(n_acc_h[slot]) + 1
-            committed = [int(t) for t in out_h[slot, :n_emit]]
-            eos = int(self._eos[slot])
-            if eos >= 0 and eos in committed:
-                # a drafted/accepted eos ends the stream there — drop
-                # whatever the verify window committed past it
-                n_emit = committed.index(eos) + 1
-                committed = committed[:n_emit]
-            if tel_on:
-                self.tel.complete("verify",
-                                  self._tb + SLOT_TRACK_BASE + slot,
-                                  t0_us, dur_us, step=self.n_steps,
-                                  request=st.req.id, drafted=int(m[slot]),
-                                  committed=n_emit)
-            self._commit_tokens(slot, st, committed, now, t0_us, dur_us)
-            if eos >= 0 and st.tokens[-1] == eos:
-                finished.append(self._finish_slot(slot, FINISH_EOS, now))
-            elif len(st.tokens) >= st.cap:
-                reason = (FINISH_LENGTH_CAP if st.capped
-                          else FINISH_MAX_TOKENS)
-                finished.append(self._finish_slot(slot, reason, now))
-        self.pool.flush_pending()
+        with self.tel.phase("serve/commit", self._tb + ENGINE_TRACK,
+                            step=self.n_steps, tokens=emitted,
+                            accepted=accepted):
+            for slot in list(self._slots):
+                if not self._active[slot]:
+                    continue
+                st = self._slots[slot]
+                n_emit = int(n_acc_h[slot]) + 1
+                committed = [int(t) for t in out_h[slot, :n_emit]]
+                eos = int(self._eos[slot])
+                if eos >= 0 and eos in committed:
+                    # a drafted/accepted eos ends the stream there — drop
+                    # whatever the verify window committed past it
+                    n_emit = committed.index(eos) + 1
+                    committed = committed[:n_emit]
+                if tel_on:
+                    self.tel.complete("verify",
+                                      self._tb + SLOT_TRACK_BASE + slot,
+                                      t0_us, dur_us, step=self.n_steps,
+                                      request=st.req.id, drafted=int(m[slot]),
+                                      committed=n_emit)
+                self._commit_tokens(slot, st, committed, now, t0_us, dur_us)
+                if eos >= 0 and st.tokens[-1] == eos:
+                    finished.append(self._finish_slot(slot, FINISH_EOS, now))
+                elif len(st.tokens) >= st.cap:
+                    reason = (FINISH_LENGTH_CAP if st.capped
+                              else FINISH_MAX_TOKENS)
+                    finished.append(self._finish_slot(slot, reason, now))
+            self.pool.flush_pending()
         return finished
 
     def _finish_slot(self, slot: int, reason: str, now: float,

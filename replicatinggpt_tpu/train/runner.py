@@ -27,7 +27,7 @@ from ..tokenizers import get_tokenizer
 from ..utils.logging import StepLogger
 from ..utils.sanitize import (CompileGuard, check_finite, sanitize_enabled,
                               sanitized)
-from ..utils.telemetry import ENGINE_TRACK, NULL
+from ..utils.telemetry import NULL
 from .state import TrainState, create_train_state
 from .steps import estimate_loss, make_eval_step, make_train_step
 
@@ -90,13 +90,15 @@ def train(cfg: Config, *, mesh=None, logger: Optional[StepLogger] = None,
     advances the data cursor that many optimizer steps after restore,
     stepping past a data window that keeps blowing the loss up.
 
-    ``telemetry`` (utils.telemetry.Telemetry) records the training
-    timeline: one span per dispatch (host dispatch time — the device
-    runs async; pair with ``profile_dir`` for the device-side view),
-    spans around eval passes, and instants at checkpoint saves — the
-    host half of a step-time attribution, exportable to Perfetto next
-    to the ``jax.profiler`` capture. None means the zero-cost NULL
-    recorder."""
+    The loop marks its host phases through ``tel.phase``:
+    ``train/data`` (the next batch off the prefetch queue),
+    ``train/dispatch`` (enqueueing the step; the device runs async),
+    ``train/fetch_loss`` (the one sync per log boundary),
+    ``train/eval`` and ``train/checkpoint``. Each is a
+    ``jax.profiler.TraceAnnotation``, so a ``profile_dir`` capture
+    shows them on the device's clock with no recorder attached;
+    ``telemetry`` (utils.telemetry.Telemetry) also keeps them as spans,
+    exportable to Perfetto. None means the NULL recorder."""
     logger = logger or StepLogger()
     tel = telemetry or NULL
     text = load_corpus(cfg.dataset)
@@ -446,7 +448,7 @@ def train(cfg: Config, *, mesh=None, logger: Optional[StepLogger] = None,
                     checkpoint_manager.save(state, cursor)
                 break
             if (tcfg.eval_interval and it % tcfg.eval_interval == 0):
-                with tel.span("train/eval", step=it):
+                with tel.phase("train/eval", step=it):
                     losses = estimate_loss(state.params, eval_batchers,
                                            eval_step, tcfg.eval_iters,
                                            device_put=dput,
@@ -461,18 +463,16 @@ def train(cfg: Config, *, mesh=None, logger: Optional[StepLogger] = None,
             # cadences behave exactly as in the single-step loop; the feed
             # producer assembled this dispatch's batch to the same schedule
             chunk = chunk_at(it)
-            t_disp_us = tel.now_us() if tel.enabled else 0.0
-            if chunk > 1:
-                state, metrics = train_scan(state, next(batches))
-            else:
-                state, metrics = train_step(state, next(batches))
-            if tel.enabled:
-                # host dispatch time only: the device runs this chunk
-                # asynchronously (profile_dir's XLA capture carries the
-                # device-side cost; annotate-linked via span names)
-                tel.complete("train/dispatch", ENGINE_TRACK, t_disp_us,
-                             tel.now_us() - t_disp_us, step=it,
-                             chunk=chunk)
+            with tel.phase("train/data"):
+                batch = next(batches)
+            # host dispatch time only: the device runs this chunk
+            # asynchronously (a profile_dir capture shows the device's
+            # side on the same clock)
+            with tel.phase("train/dispatch", step=it, chunk=chunk):
+                if chunk > 1:
+                    state, metrics = train_scan(state, batch)
+                else:
+                    state, metrics = train_step(state, batch)
             prev_it, it = it, it + chunk
             tokens_seen += tokens_per_batch * chunk
             tokens_since_log += tokens_per_batch * chunk
@@ -482,8 +482,9 @@ def train(cfg: Config, *, mesh=None, logger: Optional[StepLogger] = None,
                 losses_arr = metrics["loss"]
                 # one reviewed sync per supervised dispatch — detection
                 # latency is what supervision buys with it
-                sup_loss = float(losses_arr if chunk == 1    # graftlint: disable=GL004
-                                 else losses_arr[-1])
+                with tel.phase("train/fetch_loss"):
+                    sup_loss = float(  # graftlint: disable=GL004
+                        losses_arr if chunk == 1 else losses_arr[-1])
                 flt = fault_fire("train/loss", index=it - 1)
                 if flt is not None:
                     sup_loss = apply_loss_fault(flt, sup_loss)
@@ -498,7 +499,8 @@ def train(cfg: Config, *, mesh=None, logger: Optional[StepLogger] = None,
                               else losses_arr[b - prev_it - 1])
                     # one reviewed sync per LOG boundary, not per step;
                     # the fetch is also the NaN tripwire under sanitize
-                    loss_val = float(loss_b)  # graftlint: disable=GL004
+                    with tel.phase("train/fetch_loss"):
+                        loss_val = float(loss_b)  # graftlint: disable=GL004
                     if sanitize_enabled():
                         check_finite(loss_val, f"train loss at step {b - 1}")
                     if not np.isfinite(loss_val):
@@ -513,8 +515,8 @@ def train(cfg: Config, *, mesh=None, logger: Optional[StepLogger] = None,
                     tokens_since_log = 0
             if (checkpoint_manager is not None and tcfg.checkpoint_every
                     and it % tcfg.checkpoint_every == 0):
-                tel.instant("train/checkpoint", step=it)
-                checkpoint_manager.save(state, cursor)
+                with tel.phase("train/checkpoint", step=it):
+                    checkpoint_manager.save(state, cursor)
     finally:
         profiler.close()
         sanitizer.close()

@@ -52,16 +52,18 @@ def _accum_grads(params, batch, *, mcfg: ModelConfig, rng, train,
                      rng=None if rng is None else jax.random.fold_in(rng, j),
                      train=train, attention_fn=attention_fn,
                      blocks_fn=blocks_fn)
-        return (loss_sum + loss,
-                jax.tree_util.tree_map(jnp.add, gsum, g)), None
+        with jax.named_scope("grad_accum"):
+            return (loss_sum + loss,
+                    jax.tree_util.tree_map(jnp.add, gsum, g)), None
 
     zeros = jax.tree_util.tree_map(jnp.zeros_like, params)
     (loss_sum, gsum), _ = jax.lax.scan(
         body, (jnp.zeros((), jnp.float32), zeros),
         (batch, jnp.arange(accum)), length=accum)
     inv = 1.0 / accum
-    return (loss_sum * inv,
-            jax.tree_util.tree_map(lambda g: g * inv, gsum))
+    with jax.named_scope("grad_accum"):
+        return (loss_sum * inv,
+                jax.tree_util.tree_map(lambda g: g * inv, gsum))
 
 
 def _one_step(state: TrainState, batch, *, mcfg: ModelConfig, optimizer,
@@ -81,10 +83,11 @@ def _one_step(state: TrainState, batch, *, mcfg: ModelConfig, optimizer,
         loss, grads = jax.value_and_grad(loss_fn)(
             state.params, batch, mcfg, rng=rng, train=train,
             attention_fn=attention_fn, blocks_fn=blocks_fn)
-    updates, opt_state = optimizer.update(grads, state.opt_state,
-                                          state.params)
-    params = jax.tree_util.tree_map(
-        lambda p, u: (p + u.astype(p.dtype)), state.params, updates)
+    with jax.named_scope("optimizer"):
+        updates, opt_state = optimizer.update(grads, state.opt_state,
+                                              state.params)
+        params = jax.tree_util.tree_map(
+            lambda p, u: (p + u.astype(p.dtype)), state.params, updates)
     new_state = TrainState(step=state.step + 1, params=params,
                            opt_state=opt_state, rng=state.rng)
     metrics = {"loss": loss}

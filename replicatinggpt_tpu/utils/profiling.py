@@ -3,25 +3,25 @@
 The reference has no profiler, timers, or even per-step timing (SURVEY.md §5
 row 1 — ABSENT). The TPU-native equivalent supplied here:
 
-- ``trace(logdir)``: context manager around ``jax.profiler`` emitting an XLA
-  trace viewable in TensorBoard / Perfetto (device timelines, HLO op costs,
-  HBM usage).
-- ``trace_window``: step-triggered tracing for the hot loop — capture steps
-  [start, start+n) of a training run without paying trace overhead elsewhere.
+- ``trace_window``: step-triggered ``jax.profiler`` capture for the hot
+  loop — steps [start, start+n) of a run, viewable in TensorBoard /
+  Perfetto and reduced to numbers by ``chipbench/trace_reduce.py``; no
+  trace overhead elsewhere.
 - ``start_server``: on-demand profiling of a live job from TensorBoard.
-- ``annotate``: named host-side regions that show up on the trace timeline.
+- ``annotate``: a named host-side region on the profiler's timeline, with
+  optional counters as its stats. The program marks its phases through
+  ``utils.telemetry``'s ``phase()``, which enters one of these; the
+  profiler's clock is the one the device ops are on.
 - ``StepTimer``: blocking per-step latency statistics (p50/p90/mean,
-  tokens/sec) — used by the latency benchmarks (``bench.py --mode
-  generate``, the BASELINE.json "p50 generate latency" metric); every lap
-  calls ``jax.block_until_ready`` so async dispatch can't hide device
-  time. Throughput benchmarks deliberately time an unsynchronized span
-  that ends in one sync instead, since a per-step device sync would
-  dominate small step times.
+  tokens/sec) — the serving engine's ``step_latency`` and the generate
+  path's per-token latency; every lap fetches what it is given, so async
+  dispatch can't hide device time. Throughput measurements deliberately
+  time an unsynchronized span that ends in one sync instead, since a
+  per-step device sync would dominate small step times.
 """
 
 from __future__ import annotations
 
-import contextlib
 import time
 from typing import Any, Dict, List, Optional
 
@@ -33,19 +33,11 @@ def start_server(port: int = 9012):
     return jax.profiler.start_server(port)
 
 
-@contextlib.contextmanager
-def trace(logdir: str):
-    """Trace everything inside the block into ``logdir``."""
-    jax.profiler.start_trace(logdir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
-
-
-def annotate(name: str):
-    """Named region on the profiler timeline (host + linked device ops)."""
-    return jax.profiler.TraceAnnotation(name)
+def annotate(name: str, **stats):
+    """Named region on the profiler timeline; ``stats`` (numbers or
+    strings) ride on the event, where a trace reader finds them by
+    name. Costs an enter and an exit when no capture is running."""
+    return jax.profiler.TraceAnnotation(name, **stats)
 
 
 class trace_window:

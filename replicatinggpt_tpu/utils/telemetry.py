@@ -11,11 +11,17 @@ zero-cost-when-disabled event/span recorder plus three exporters.
   bounded ring buffer (a soak run must not grow host memory without
   bound — the ``Metrics`` reservoir rationale), with an optional
   append-only JSONL sink whose reader tolerates a torn tail (the crash
-  window lands mid-write, exactly like ``serve.journal``). Spans taken
-  through :meth:`Telemetry.span` also enter ``profiling.annotate``, so
-  the same host region shows up on the XLA device timeline a
-  ``jax.profiler`` capture of the run produces — the two traces line
-  up by region name.
+  window lands mid-write, exactly like ``serve.journal``).
+- ``phase(name, **counters)`` — the ONE way the program marks a host
+  phase (``serve/launch``, ``train/dispatch``, ...). Both recorders
+  enter ``profiling.annotate(name, **counters)``, a
+  ``jax.profiler.TraceAnnotation``: the phase is on the profiler's
+  timeline, the clock the device ops are on, whether or not a recorder
+  is attached, and its counters ride on the event as stats. The enabled
+  recorder also keeps the phase as one X event with the counters as
+  args. The xplane's clock is neither ``time.monotonic`` nor
+  ``time.time``, so the two views are never converted into each other:
+  to share the device's clock a phase IS a ``TraceAnnotation``.
 - Chrome trace-event JSON (:meth:`Telemetry.export_chrome_trace` /
   :func:`chrome_trace_from_jsonl`) — load the file straight into
   Perfetto (ui.perfetto.dev) or ``chrome://tracing``. The serving
@@ -33,7 +39,8 @@ zero-cost-when-disabled event/span recorder plus three exporters.
 Zero-cost-when-disabled is load-bearing: the :data:`NULL` recorder is
 what every instrumented subsystem holds by default, its methods are
 no-ops, its ``span()`` returns one shared reusable null context (no
-per-call allocation), and nothing in this module performs a
+per-call allocation), its ``phase()`` is the bare profiler annotation
+and nothing else, and nothing in this module performs a
 device->host sync — graftlint GL004-clean with zero pragmas (pinned in
 tests/test_telemetry.py, along with the no-buffer-growth property).
 """
@@ -131,6 +138,10 @@ class NullTelemetry:
 
     def span(self, name: str, track: int = ENGINE_TRACK, **args):
         return _NULL_SPAN
+
+    def phase(self, name: str, track: int = ENGINE_TRACK, **args):
+        from .profiling import annotate    # lazy: see Telemetry.phase
+        return annotate(name, **args)
 
     def begin(self, name, track=ENGINE_TRACK, ts_us=None, **args) -> None:
         pass
@@ -268,18 +279,23 @@ class Telemetry:
         self._emit(ev)
 
     @contextlib.contextmanager
-    def span(self, name: str, track: int = ENGINE_TRACK,
-             **args) -> Iterator[None]:
-        """Timed region recorded as one X event on exit, wrapped in
-        ``profiling.annotate`` so the same region appears on the XLA
-        device timeline of a concurrent ``jax.profiler`` capture."""
+    def phase(self, name: str, track: int = ENGINE_TRACK,
+              **args) -> Iterator[None]:
+        """A host phase: ``profiling.annotate(name, **args)`` entered
+        around the block, so it is on the profiler's timeline with
+        ``args`` as its stats, and one X event with ``args`` recorded
+        on exit."""
         from .profiling import annotate    # lazy: keep module import
         t0 = self.now_us()                 # jax-free for the exporters
         try:
-            with annotate(name):
+            with annotate(name, **args):
                 yield
         finally:
             self.complete(name, track, t0, self.now_us() - t0, **args)
+
+    #: the older name of ``phase`` (NullTelemetry's ``span`` is the shared
+    #: null context: a disabled ``span`` is not on the profiler's timeline)
+    span = phase
 
     # ------------------------------------------------------------ export
 

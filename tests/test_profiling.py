@@ -9,34 +9,36 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from replicatinggpt_tpu.utils.profiling import (StepTimer, annotate, trace,
+from replicatinggpt_tpu.utils.profiling import (StepTimer, annotate,
                                                 trace_window)
 
 
-def test_trace_writes_artifacts(tmp_path):
-    logdir = str(tmp_path / "trace")
-    f = jax.jit(lambda x: (x * 2.0).sum())
-    with trace(logdir):
-        with annotate("hot-region"):
-            jax.block_until_ready(f(jnp.ones((64, 64))))
-    hits = glob.glob(os.path.join(logdir, "**", "*.xplane.pb"),
-                     recursive=True)
-    assert hits, f"no trace artifacts under {logdir}"
-
-
-def test_trace_window_covers_requested_steps(tmp_path):
+@pytest.mark.parametrize("start,n_steps", [(2, 2), (0, 6)],
+                         ids=["inner-steps", "whole-loop"])
+def test_trace_window_covers_requested_steps(tmp_path, start, n_steps):
+    """The capture opens and closes at the requested steps, writes its
+    artifact, and holds the ``annotate`` regions entered while it was
+    open, with their stats, and no other."""
     logdir = str(tmp_path / "win")
-    win = trace_window(logdir, start=2, n_steps=2)
+    win = trace_window(logdir, start=start, n_steps=n_steps)
     f = jax.jit(lambda x: x + 1)
     x = jnp.zeros(8)
     for it in range(6):
         win.step(it)
-        assert win._active == (2 <= it < 4)
-        x = f(x)
+        assert win._active == (start <= it < start + n_steps)
+        with annotate("hot-region", it=it):
+            x = jax.block_until_ready(f(x))
     win.close()
     assert not win._active
-    assert glob.glob(os.path.join(logdir, "**", "*.xplane.pb"),
+    hits = glob.glob(os.path.join(logdir, "**", "*.xplane.pb"),
                      recursive=True)
+    assert hits, f"no trace artifacts under {logdir}"
+    seen = sorted(dict(e.stats)["it"]
+                  for plane in jax.profiler.ProfileData.from_file(
+                      hits[-1]).planes
+                  for line in plane.lines for e in line.events
+                  if e.name == "hot-region")
+    assert seen == list(range(start, min(start + n_steps, 6)))
 
 
 def test_trace_window_disabled_without_logdir():

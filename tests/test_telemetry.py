@@ -85,6 +85,130 @@ def test_null_telemetry_is_stateless_and_allocation_free():
     NULL.close()
 
 
+def test_null_phase_is_the_bare_annotation_and_records_nothing():
+    """``NULL.phase`` is ``profiling.annotate`` and nothing else: a
+    ``TraceAnnotation`` a profiler capture would see, no event, no
+    buffer that could grow."""
+    ph = NULL.phase("serve/launch", 7, k=1, live_tokens=12)
+    assert isinstance(ph, jax.profiler.TraceAnnotation)
+    for _ in range(100):
+        with NULL.phase("serve/step", step=3):
+            with NULL.phase("serve/fetch"):
+                pass
+    assert NULL.events == () and not NULL.enabled
+    assert not hasattr(NULL, "__dict__") or not vars(NULL)
+
+
+def test_phase_records_one_x_event_with_its_args():
+    """``Telemetry.phase`` enters the same annotation and keeps ONE X
+    event with the counters as args; ``span`` is its older name."""
+    t = [0.0]
+    tel = Telemetry(clock=lambda: t[0])
+    with tel.phase("serve/launch", 5, k=2, live_tokens=40):
+        t[0] = 0.004
+    assert list(tel.events) == [
+        {"ph": "X", "name": "serve/launch", "tid": 5, "ts": 0.0,
+         "dur": pytest.approx(4000.0),
+         "args": {"k": 2, "live_tokens": 40}}]
+    assert Telemetry.span is Telemetry.phase
+    with tel.phase("train/data"):
+        pass
+    assert tel.events[-1]["name"] == "train/data"
+    assert tel.events[-1]["tid"] == ENGINE_TRACK
+    assert "args" not in tel.events[-1]
+
+
+def _profiler_phases(logdir):
+    """``(name, start, end, stats)`` of every ``serve/*`` / ``train/*``
+    event of the capture under ``logdir``, by start."""
+    import glob
+    path = sorted(glob.glob(str(Path(logdir) / "plugins" / "profile" / "*"
+                                / "*.xplane.pb")))[-1]
+    out = []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(("serve/", "train/")):
+                    out.append((e.name, e.start_ns,
+                                e.start_ns + e.duration_ns, dict(e.stats)))
+    return sorted(out, key=lambda e: e[1])
+
+
+def test_profiler_capture_holds_engine_phases_and_live_context(params,
+                                                               tmp_path):
+    """With NO recorder attached, a ``jax.profiler`` capture of a tiny
+    engine run holds the phases on its own clock: every ``serve/launch``
+    carries ``live_tokens`` equal to the host mirrors' sum at that launch
+    (and ``live_kv_bytes`` = that x the pool's bytes a token), and
+    ``serve/launch``, ``serve/fetch``, ``serve/commit`` nest inside a
+    ``serve/step``."""
+    eng = Engine(params, CFG, EngineConfig(pool_size=3, max_queue=8,
+                                           page_size=4))
+    assert eng.tel is NULL
+    for i in range(4):
+        eng.submit(_greedy(f"w{i}", [1 + i, 2, 3], max_new=3))
+    eng.drain()                                   # every program compiled
+    want = []
+    dispatch = eng._dispatch
+
+    def spy(k, kill, n_active, *a):
+        want.append(int((eng._pos + 1)[eng._active & ~kill].sum()))
+        return dispatch(k, kill, n_active, *a)
+
+    eng._dispatch = spy
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for i in range(5):
+            eng.submit(_greedy(f"r{i}", 10 * i + np.arange(1, 6 + i),
+                               max_new=4))      # no shared prefix
+        eng.drain()
+    finally:
+        jax.profiler.stop_trace()
+    ev = _profiler_phases(tmp_path)
+    launches = [e for e in ev if e[0] == "serve/launch"]
+    assert want and [e[3]["live_tokens"] for e in launches] == want
+    per_token = (sum(a.nbytes for a in eng.pool.cache.values())
+                 // (eng.pool.n_pages * eng.pool.page_size))
+    assert per_token == 2 * CFG.n_layer * CFG.n_embd * 4      # K and V, f32
+    assert all(e[3]["live_kv_bytes"] == e[3]["live_tokens"] * per_token
+               and e[3]["k"] == 1 for e in launches)
+    steps = [e for e in ev if e[0] == "serve/step"]
+    for name in ("serve/launch", "serve/fetch", "serve/commit",
+                 "serve/expire_shed", "serve/admit", "serve/prefill",
+                 "serve/decode"):
+        inner = [e for e in ev if e[0] == name]
+        assert inner, name
+        assert all(any(s[1] <= e[1] and e[2] <= s[2] for s in steps)
+                   for e in inner), name
+    assert len([e for e in ev if e[0] == "serve/fetch"]) == len(launches)
+    pre = [e for e in ev if e[0] == "serve/prefill"]
+    assert sorted(e[3]["tokens"] for e in pre) == [5, 6, 7, 8, 9]
+    assert all(e[3]["cached_tokens"] == 0 and e[3]["chunks"] >= 1
+               for e in pre)
+
+
+def test_profiler_capture_holds_train_phases(tmp_path):
+    """A tiny train run with ``profile_dir`` and no recorder: the
+    capture holds ``train/data``, ``train/dispatch`` (with its step) and
+    ``train/fetch_loss`` of the traced steps."""
+    import dataclasses as dc
+    from replicatinggpt_tpu.config import get_config
+    from replicatinggpt_tpu.train.runner import train
+    from replicatinggpt_tpu.utils.logging import StepLogger
+    cfg = get_config("test-tiny")
+    cfg = cfg.replace(tokenizer="char", train=dc.replace(
+        cfg.train, max_iters=4, eval_interval=0, eval_iters=1,
+        log_interval=1, batch_size=2))
+    train(cfg, logger=StepLogger(quiet=True), profile_dir=str(tmp_path),
+          profile_start=1, profile_steps=2)
+    ev = _profiler_phases(tmp_path)
+    names = [e[0] for e in ev]
+    assert names.count("train/dispatch") == 2
+    assert [e[3]["step"] for e in ev if e[0] == "train/dispatch"] == [1, 2]
+    assert names.count("train/data") == 2
+    assert names.count("train/fetch_loss") == 2
+
+
 def test_engine_without_telemetry_holds_null_and_records_nothing(params):
     """Default engine construction wires the NULL recorder end to end
     (engine, paged pool, allocator) and a full replay leaves no
@@ -344,8 +468,11 @@ def test_engine_trace_has_request_tree_prefix_hit_and_cow(params, tmp_path):
     assert eng.pool.alloc.cow_copies == 1         # scenario sanity
     names = _names(tel)
     assert {"request", "queue", "admit", "prefill_chunk", "decode",
-            "decode_step", "engine_step", "prefix_hit",
+            "serve/step", "serve/admit", "serve/prefill", "serve/decode",
+            "serve/launch", "serve/fetch", "serve/commit", "prefix_hit",
             "cow_split"} <= names
+    # one name a region: the engine track's old envelopes are gone
+    assert not {"engine_step", "decode_step"} & names
     out = tmp_path / "trace.json"
     tel.export_chrome_trace(str(out))
     tc = _trace_check()
@@ -450,9 +577,16 @@ def test_decode_window_spans_and_token_instants(params, tmp_path):
     assert tc.check_trace(str(out), min_requests=6) == []
     doc = json.loads(out.read_text())
     evs = doc["traceEvents"]
-    # engine-track window spans carry k + tokens; some are real windows
+    # engine-track phases: a launch carries k and the live context, the
+    # commit of its window k and the tokens it emitted; some are real
+    # windows
+    launches = [e for e in evs if e.get("ph") == "X"
+                and e.get("name") == "serve/launch"]
+    assert launches and all(
+        {"k", "n_active", "live_tokens", "live_kv_bytes"} <= set(e["args"])
+        for e in launches)
     steps = [e for e in evs if e.get("ph") == "X"
-             and e.get("name") == "decode_step"]
+             and e.get("name") == "serve/commit"]
     assert steps and all("k" in e["args"] and "tokens" in e["args"]
                          for e in steps)
     assert any(e["args"]["k"] == 4 and e["args"]["tokens"] > 1

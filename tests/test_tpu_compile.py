@@ -15,6 +15,7 @@ could land on another worker, whose fixture would then skip.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -194,3 +195,158 @@ def test_fused_paged_decode_layers_char(mosaic, one_chip):
         _s((B, C), BF16, one_chip), _char_blocks(cfg, one_chip),
         _s((B,), jnp.int32, one_chip), _s((B, mp), jnp.int32, one_chip),
         pool, pool)
+
+
+# ---------------------------------------------------------------------------
+# names: a kernel's name= is its HLO instruction's name, a named_scope is
+# in the op's metadata (what the profiler's trace and chipbench read)
+# ---------------------------------------------------------------------------
+
+_CUSTOM_CALL = re.compile(
+    r'%([\w.\-]+) = [^\n]*custom-call\([^\n]*'
+    r'custom_call_target="tpu_custom_call"')
+
+
+def _kernel_names(text):
+    return _CUSTOM_CALL.findall(text)
+
+
+def _flash_split(one_chip):
+    q = _s((2, 12, 1024, 64), BF16, one_chip)
+    return (jax.grad(lambda q, k, v: jnp.sum(
+        flash_pallas.pallas_flash_attention(q, k, v)
+        .astype(jnp.float32) ** 2), argnums=(0, 1, 2)), (q, q, q))
+
+
+def _flash_group(one_chip):
+    qkv = _s((2, 1024, 3 * 768), BF16, one_chip)
+    return (jax.grad(lambda x: jnp.sum(
+        flash_pallas.pallas_flash_attention_packed(x, 12)
+        .astype(jnp.float32) ** 2)), (qkv,))
+
+
+def _flash_group_remat(one_chip):
+    """Forward under jax.checkpoint, as the trainer runs it: the remat
+    replay of the forward kernel keeps the forward's name."""
+    qkv = _s((2, 1024, 3 * 768), BF16, one_chip)
+    fwd = jax.checkpoint(lambda x: flash_pallas
+                         .pallas_flash_attention_packed(x, 12))
+    return (jax.grad(lambda x: jnp.sum(
+        fwd(x * 2).astype(jnp.float32) ** 2)), (qkv,))
+
+
+def _paged(one_chip):
+    from replicatinggpt_tpu.ops.paged_pallas import paged_window_attention
+    sh = {"row": one_chip, "pool": one_chip, "rep": one_chip}
+    return (lambda *a: paged_window_attention(*a, n_head=12),
+            _paged_args(sh, 8, 1))
+
+
+def _packed_decode(one_chip):
+    from replicatinggpt_tpu.ops.decode_pallas import packed_decode_attention
+    row = _s((8, 768), BF16, one_chip)
+    cache = _s((8, 1024, 768), BF16, one_chip)
+    return (lambda q, kn, vn, kc, vc, p: packed_decode_attention(
+        q, kn, vn, kc, vc, p, n_head=12),
+        (row, row, row, cache, cache, _s((), jnp.int32, one_chip)))
+
+
+def _fused_decode(one_chip):
+    import dataclasses
+    from replicatinggpt_tpu.ops.decode_pallas import fused_decode_layers
+    cfg = dataclasses.replace(get_config("char-gpt").model,
+                              decode_cache_layout="packed")
+    L, S, C = cfg.n_layer, cfg.block_size, cfg.n_embd
+    kv = _s((L, 1, S, C), BF16, one_chip)
+    return (lambda x, b, p, k, v: fused_decode_layers(
+        x, b, p, {"k": k, "v": v}, cfg),
+        (_s((1, C), BF16, one_chip), _char_blocks(cfg, one_chip),
+         _s((), jnp.int32, one_chip), kv, kv))
+
+
+def _fused_paged_decode(one_chip):
+    import dataclasses
+    from replicatinggpt_tpu.ops.decode_pallas import (
+        fused_paged_decode_layers)
+    cfg = dataclasses.replace(get_config("char-gpt").model,
+                              decode_cache_layout="packed")
+    B, psz, mp = 8, 16, 16
+    L, C = cfg.n_layer, cfg.n_embd
+    pool = _s((L, B * mp, psz, C), BF16, one_chip)
+    return (lambda x, b, p, t, k, v: fused_paged_decode_layers(
+        x, b, p, t, {"k": k, "v": v}, cfg),
+        (_s((B, C), BF16, one_chip), _char_blocks(cfg, one_chip),
+         _s((B,), jnp.int32, one_chip), _s((B, mp), jnp.int32, one_chip),
+         pool, pool))
+
+
+@pytest.mark.parametrize("build,fwd,bwd", [
+    (_flash_split, r"flash_\w*fwd", r"flash_\w*bwd"),
+    (_flash_group, r"flash_group\w*_fwd", r"flash_group\w*_bwd"),
+    (_flash_group_remat, r"flash_group\w*_fwd", r"flash_group\w*_bwd"),
+    (_paged, r"^paged_window_attention\.", None),
+    (_packed_decode, r"^decode_attention\.", None),
+    (_fused_decode, r"^fused_decode_layers\.", None),
+    (_fused_paged_decode, r"^fused_paged_decode_layers\.", None),
+], ids=["flash", "flash-group", "flash-group-remat", "paged-window",
+        "packed-decode", "fused-decode", "fused-paged-decode"])
+def test_kernel_name_is_the_hlo_instruction_name(mosaic, one_chip, build,
+                                                 fwd, bwd):
+    """Compiled for the described v5e, every ``tpu_custom_call`` of a
+    main-path kernel is an instruction named after the kernel's
+    ``name=`` (``%paged_window_attention.3``), which is the name the
+    profiler's trace prints and ``chipbench``'s ``trace_sum`` patterns
+    match: none is left under the enclosing function's name
+    (``closed_call``, ``checkpoint``, ``rematted_computation``). The
+    name arrives through the name stack, so a kernel traced under
+    autodiff wears the transform: ``jvp_flash_group_fwd_`` for the
+    forward of a differentiated call, ``transpose_jvp_flash_group_bwd__``
+    for its backward, the bare ``flash_group_fwd`` / ``flash_group_bwd``
+    under ``jax.checkpoint``. Patterns over the flash family therefore
+    search for the name and do not anchor it."""
+    fn, shapes = build(one_chip)
+    names = _kernel_names(_compile(fn, *shapes))
+    assert names
+    wanted = [rx for rx in (fwd, bwd) if rx]
+    assert all(any(re.search(rx, n) for rx in wanted) for n in names), names
+    for rx in wanted:
+        assert any(re.search(rx, n) for n in names), (rx, names)
+    if build is _flash_group_remat:      # forward + its replay
+        assert sum(bool(re.search(fwd, n)) for n in names) == 2, names
+
+
+def test_decode_step_hlo_carries_the_phase_scopes(mosaic, one_chip):
+    """The engine's jitted decode step, compiled for the described v5e
+    on the Pallas route: ``jax.named_scope`` reaches no instruction NAME
+    (they stay ``%sort.N``, ``%fusion.N``) but is in the ops'
+    ``op_name`` metadata, where the trace's scope stat comes from."""
+    import dataclasses
+    from replicatinggpt_tpu.models.gpt import (init_paged_kv_pool,
+                                               init_params)
+    from replicatinggpt_tpu.serve.engine import _engine_decode_window
+    cfg = dataclasses.replace(
+        get_config("gpt2-small").model, n_layer=2, scan_layers=False,
+        decode_cache_layout="packed")
+    B, psz, mp = 8, 16, 8
+    shaped = lambda tree: jax.tree_util.tree_map(
+        lambda a: _s(a.shape, a.dtype, one_chip), tree)
+    params = shaped(jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    cache = shaped(jax.eval_shape(
+        lambda: init_paged_kv_pool(cfg, B * mp, psz)))
+    vec = lambda dt: _s((B,), dt, one_chip)
+    text = _engine_decode_window.lower(
+        params, vec(jnp.int32), vec(jnp.int32), vec(jnp.bool_),
+        vec(jnp.int32), vec(jnp.int32), _s((5, B), jnp.int32, one_chip),
+        _s((B, mp), jnp.int32, one_chip), cache,
+        _s((B, 2), jnp.uint32, one_chip), vec(jnp.float32),
+        vec(jnp.int32), vec(jnp.float32), vec(jnp.bool_), cfg, k=1,
+        use_pallas=True).compile().as_text()
+    assert _kernel_names(text) and all(
+        n.startswith("paged_window_attention") for n in _kernel_names(text))
+    op_names = re.findall(r'op_name="([^"]*)"', text)
+    for scope in ("sample", "kv_gather", "kv_scatter", "attn", "mlp",
+                  "head", "embed"):
+        assert any(re.search(rf"(^|/){scope}(/|$)", n) for n in op_names), \
+            scope
+    assert not re.search(r"%(sample|kv_gather)[\w.]* = ", text)
