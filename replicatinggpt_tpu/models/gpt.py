@@ -1329,12 +1329,14 @@ def decode_window_paged(params: Params, tok: jnp.ndarray, pos: jnp.ndarray,
     tok/pos/active: the per-slot step state ``decode_step_paged`` takes;
     budget: (B,) int32 tokens each slot may still emit; eos: (B,) int32
     per-slot stop token (< 0 = disabled); rngs: (B, key) sampling
-    streams; ``sample_fn(rngs, logits) -> (tokens, new_rngs)`` is the
-    caller's sampler (injected so this module does not depend on
-    sample.generate). Per step every ACTIVE slot decodes exactly as a
-    standalone ``decode_step_paged`` + sample would — per-row math,
-    masking and RNG stream advance are identical, which is what keeps a
-    windowed greedy stream byte-identical to the step-at-a-time one —
+    streams; ``sample_fn(rngs, logits, live) -> (tokens, new_rngs)`` is
+    the caller's sampler (injected so this module does not depend on
+    sample.generate; ``live`` is the step's ``active`` mask, so that a
+    dead slot's stale parameters cost nothing). Per step every ACTIVE
+    slot decodes exactly as a standalone ``decode_step_paged`` + sample
+    would — per-row math, masking and RNG stream advance are identical,
+    which is what keeps a windowed greedy stream byte-identical to the
+    step-at-a-time one —
     then the slot's budget decrements and its on-device active flag
     drops when the budget hits zero or the sampled token == eos. A slot
     that finishes mid-window therefore IDLES inside the window (writes
@@ -1366,7 +1368,7 @@ def decode_window_paged(params: Params, tok: jnp.ndarray, pos: jnp.ndarray,
             params, tok, pos, active, tables, cache, cfg,
             use_pallas=use_pallas, use_fused=use_fused,
             shardings=shardings)
-        nxt, rngs = sample_fn(rngs, logits)
+        nxt, rngs = sample_fn(rngs, logits, active)
         nxt = jnp.where(active, nxt, 0)
         emitted = active
         budget = jnp.where(active, budget - 1, budget)
@@ -1454,7 +1456,7 @@ def mixed_window_paged(params: Params, tok: jnp.ndarray, pos: jnp.ndarray,
             params, window, base, n_tok - 1, active, tables, cache, cfg,
             shardings=shardings, logits_rows=1, use_kernel=use_kernel)
         decoding = active & ~prefilling
-        nxt, new_rngs = sample_fn(rngs, logits[:, 0, :])
+        nxt, new_rngs = sample_fn(rngs, logits[:, 0, :], decoding)
         rngs = jnp.where(decoding[:, None], new_rngs, rngs)
         nxt = jnp.where(decoding, nxt, 0)
         emitted = decoding

@@ -192,35 +192,73 @@ def batched_top_p_filter(logits: jnp.ndarray, p: jnp.ndarray) -> jnp.ndarray:
 
 
 def filter_logits_batched(logits: jnp.ndarray, temperature: jnp.ndarray,
-                          top_k: jnp.ndarray, top_p: jnp.ndarray
+                          top_k: jnp.ndarray, top_p: jnp.ndarray,
+                          rows: Optional[jnp.ndarray] = None
                           ) -> jnp.ndarray:
     """The per-row stochastic filter pipeline — temperature -> top-k ->
     top-p, each (B,)-parameterized — factored out of
     ``sample_tokens_batched`` so the speculative verifier
     (serve/speculative.py) scores drafted tokens against EXACTLY the
     distribution the engine would have sampled from (rejection sampling
-    is only target-preserving if both sides use the same filters)."""
+    is only target-preserving if both sides use the same filters).
+
+    ``rows`` ((B,) bool, None = all) marks the rows whose result the
+    caller READS: live and not greedy. Each filter runs only if one of
+    them has it switched on — ONE scalar predicate over the batch behind
+    a ``lax.cond``, decided on the device from the parameters the
+    program already receives, so an all-greedy launch pays for no radix
+    select and no sort/cumsum/scatter (94 ms of a 96-slot, V=50257
+    decode step on v5e). A marked row reads what the unconditional
+    pipeline gives it, bit for bit: a filter is skipped only when every
+    marked row has it off, and an off row passes through it unchanged.
+    The other rows' values are unspecified. The predicates are never
+    ``vmap``ped: a batched ``cond`` lowers to a ``select`` that runs
+    both sides."""
+    V = logits.shape[-1]
+    top_k = jnp.asarray(top_k, jnp.int32)
+    top_p = jnp.asarray(top_p, jnp.float32)
+    rows = True if rows is None else jnp.asarray(rows, bool)
+    need_top_k = jnp.any(rows & (top_k > 0) & (top_k < V))
+    need_top_p = jnp.any(rows & (top_p > 0.0) & (top_p < 1.0))
     scaled = logits / jnp.maximum(
         jnp.asarray(temperature, jnp.float32), 1e-6)[:, None]
-    f = batched_top_k_filter(scaled, top_k)
-    return batched_top_p_filter(f, top_p)
+    f = jax.lax.cond(need_top_k,
+                     lambda x: batched_top_k_filter(x, top_k),
+                     lambda x: x, scaled)
+    return jax.lax.cond(need_top_p,
+                        lambda x: batched_top_p_filter(x, top_p),
+                        lambda x: x, f)
 
 
 @jax.named_scope("sample")
 def sample_tokens_batched(rngs: jnp.ndarray, logits: jnp.ndarray,
                           temperature: jnp.ndarray, top_k: jnp.ndarray,
-                          top_p: jnp.ndarray, greedy: jnp.ndarray
+                          top_p: jnp.ndarray, greedy: jnp.ndarray,
+                          live: Optional[jnp.ndarray] = None
                           ) -> jnp.ndarray:
     """Per-row sampling: (B,) params, (B, key) rngs, (B, V) f32 logits
     -> (B,) int32. Greedy rows take argmax of the RAW logits (exactly
     ``_sample_token``'s greedy mode, so a greedy slot in a mixed batch
     is token-identical to a scalar greedy decode); stochastic rows get
     temperature -> top-k -> top-p, each per-row, then a per-row
-    categorical draw from the row's own key."""
+    categorical draw from the row's own key.
+
+    The draw and everything before it run only when a LIVE row is
+    stochastic (``live``: (B,) bool, None = every row): the serving
+    engine's idle slots carry whatever parameters their last request
+    left (a never-used slot reads "not greedy"), so without the mask a
+    half-empty all-greedy pool would pay for the machinery on every
+    step. A dead row's token is unspecified (the callers mask it)."""
+    greedy = jnp.asarray(greedy, bool)
     greedy_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    f = filter_logits_batched(logits, temperature, top_k, top_p)
-    sampled = jax.vmap(jax.random.categorical)(rngs, f).astype(jnp.int32)
-    return jnp.where(jnp.asarray(greedy, bool), greedy_tok, sampled)
+    rows = ~greedy if live is None else jnp.asarray(live, bool) & ~greedy
+
+    def draw():
+        f = filter_logits_batched(logits, temperature, top_k, top_p, rows)
+        return jax.vmap(jax.random.categorical)(rngs, f).astype(jnp.int32)
+
+    sampled = jax.lax.cond(jnp.any(rows), draw, lambda: greedy_tok)
+    return jnp.where(greedy, greedy_tok, sampled)
 
 
 def _decode_chunks(P_pad: int, n_new: int, S: int, g: int):

@@ -87,8 +87,9 @@ Every step marks its host phases through ``self.tel.phase`` (a
 ``serve/step`` around ``serve/expire_shed``, ``serve/admit``,
 ``serve/prefill``, and ``serve/decode`` | ``serve/verify`` around
 ``serve/launch``, ``serve/fetch``, ``serve/commit``), with the live
-context (``live_tokens``, ``live_kv_bytes``, from the host mirrors) as
-stats of ``serve/launch``; docs/observability.md has the vocabulary.
+context (``live_tokens``, ``live_kv_bytes``, ``stochastic_rows``, from
+the host mirrors) as stats of ``serve/launch``; docs/observability.md
+has the vocabulary.
 """
 
 from __future__ import annotations
@@ -452,6 +453,24 @@ def _merge_lifecycle(tok, pos, active, budget, life, shardings):
     return tok, pos, active, budget
 
 
+def _sampler(temp, top_k, top_p, greedy):
+    """The window programs' ``sample_fn(rngs, logits, live)``: advance
+    every slot's stream, then sample what the LIVE rows asked for
+    (``sample_tokens_batched``: argmax alone when they are all greedy).
+    The split stays outside the sampler's ``cond``s: a sampled row's
+    stream must not depend on whether its neighbours were greedy, and a
+    greedy row's key advances all the same."""
+
+    @jax.named_scope("sample")
+    def sample_fn(rngs, logits, live):
+        splits = jax.vmap(lambda r: jax.random.split(r, 2))(rngs)
+        nxt = sample_tokens_batched(splits[:, 0], logits, temp, top_k,
+                                    top_p, greedy, live)
+        return nxt, splits[:, 1]
+
+    return sample_fn
+
+
 @partial(jax.jit, static_argnames=("cfg", "k", "use_pallas", "use_fused",
                                    "shardings"),
          donate_argnames=("tok", "pos", "active", "budget", "cache",
@@ -491,16 +510,10 @@ def _engine_decode_window(params, tok, pos, active, budget, eos, life,
     tok, pos, active, budget = _merge_lifecycle(
         tok, pos, active, budget, life, shardings)
 
-    @jax.named_scope("sample")
-    def sample_fn(rngs, logits):
-        splits = jax.vmap(lambda r: jax.random.split(r, 2))(rngs)
-        nxt = sample_tokens_batched(splits[:, 0], logits, temp, top_k,
-                                    top_p, greedy)
-        return nxt, splits[:, 1]
-
     return decode_window_paged(params, tok, pos, active, budget, eos,
                                tables, cache, rngs, cfg,
-                               sample_fn=sample_fn, length=k,
+                               sample_fn=_sampler(temp, top_k, top_p, greedy),
+                               length=k,
                                use_pallas=use_pallas, use_fused=use_fused,
                                shardings=shardings)
 
@@ -534,17 +547,11 @@ def _engine_mixed_window(params, tok, pos, active, budget, eos, life,
     tok, pos, active, budget = _merge_lifecycle(
         tok, pos, active, budget, life, shardings)
 
-    @jax.named_scope("sample")
-    def sample_fn(rngs, logits):
-        splits = jax.vmap(lambda r: jax.random.split(r, 2))(rngs)
-        nxt = sample_tokens_batched(splits[:, 0], logits, temp, top_k,
-                                    top_p, greedy)
-        return nxt, splits[:, 1]
-
     return mixed_window_paged(params, tok, pos, active, budget, eos,
                               pfc[0], pfc[1], pfc[2], pf_toks,
                               tables, cache, rngs, cfg,
-                              sample_fn=sample_fn, length=k,
+                              sample_fn=_sampler(temp, top_k, top_p, greedy),
+                              length=k,
                               shardings=shardings, use_kernel=use_kernel)
 
 
@@ -579,7 +586,8 @@ def _engine_verify(params, window, pos, m, active, tables, cache, rngs,
                                       use_kernel=use_kernel)
     m_eff = jnp.where(active, m, 0)
     n_acc, out, rngs = spec_accept_and_sample(rngs, logits, window, m_eff,
-                                              temp, top_k, top_p, greedy)
+                                              temp, top_k, top_p, greedy,
+                                              active)
     n_acc = jnp.where(active, n_acc, 0)
     out = jnp.where(active[:, None], out, 0)
     if shardings is not None:
@@ -1804,6 +1812,17 @@ class Engine:
                 self._top_p, self._greedy))
         return self._li
 
+    def _count_stochastic_rows(self, sampling: np.ndarray) -> int:
+        """How many of the slots that sample in the launch being built
+        are not greedy, from the host mirrors: what the program's own
+        predicate (``sample_tokens_batched``: any live stochastic row)
+        will find on the device. A launch where it is not 0 runs the
+        draw and whichever filters those rows switched on, for every
+        slot, and counts in ``sample_filter_launches``."""
+        n = int((sampling & ~self._greedy).sum())
+        self.metrics.inc("sample_filter_launches", int(n > 0))
+        return n
+
     def _launch(self, k: int, kill: Optional[np.ndarray] = None
                 ) -> _InFlight:
         """Dispatch one ``k``-step window WITHOUT fetching its results
@@ -1823,7 +1842,9 @@ class Engine:
         the context this dispatch attends: ``live_tokens``, the sum of
         ``pos + 1`` over the live slots from the host mirrors (no device
         read), and ``live_kv_bytes``, what those tokens hold in the pool
-        across layers."""
+        across layers, and ``stochastic_rows``, the live slots that
+        sample in this window (a slot that prefills all of it does
+        not)."""
         t0_us = self.tel.now_us() if self.tel.enabled else 0.0
         t_wall = time.perf_counter()
         if kill is None:
@@ -1831,10 +1852,12 @@ class Engine:
         live = self._active & ~kill
         n_active = int(live.sum())
         live_tokens = int(self._pos[live].sum()) + n_active
+        stochastic = self._count_stochastic_rows(live & (self._pf_left < k))
         with self.tel.phase("serve/launch", self._tb + ENGINE_TRACK, k=k,
                             n_active=n_active, live_tokens=live_tokens,
                             live_kv_bytes=live_tokens
-                            * self._kv_token_bytes):
+                            * self._kv_token_bytes,
+                            stochastic_rows=stochastic):
             return self._dispatch(k, kill, n_active, t0_us, t_wall)
 
     def _dispatch(self, k: int, kill: np.ndarray, n_active: int,
@@ -2180,7 +2203,9 @@ class Engine:
                         what="speculative verify window")
         with self.tel.phase("serve/verify", self._tb + ENGINE_TRACK,
                             k=k, n_active=int(self._active.sum()),
-                            drafted=int(m.sum())):
+                            drafted=int(m.sum()),
+                            stochastic_rows=self._count_stochastic_rows(
+                                self._active)):
             self.step_timer.start()
             n_acc, out, cache, rngs = self._verify_guard(
                 self.params, jnp.asarray(window), jnp.asarray(self._pos),

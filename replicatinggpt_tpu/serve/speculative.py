@@ -67,7 +67,8 @@ from .cache_pool import commit_default, prefill_chunk_size
 def spec_accept_and_sample(rngs: jnp.ndarray, logits: jnp.ndarray,
                            window: jnp.ndarray, n_valid: jnp.ndarray,
                            temperature: jnp.ndarray, top_k: jnp.ndarray,
-                           top_p: jnp.ndarray, greedy: jnp.ndarray
+                           top_p: jnp.ndarray, greedy: jnp.ndarray,
+                           live: Optional[jnp.ndarray] = None
                            ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Per-slot speculative acceptance + the committed-token layout.
 
@@ -84,6 +85,9 @@ def spec_accept_and_sample(rngs: jnp.ndarray, logits: jnp.ndarray,
     Greedy rows use raw-logits argmax for acceptance AND for the
     correction/bonus token, exactly ``sample_tokens_batched``'s greedy
     mode — so a greedy slot's stream is the non-speculative stream.
+    ``live`` ((B,) bool, None = every row): only a live stochastic row
+    reads the filtered distribution, so the filters run only where one
+    asks for them (``filter_logits_batched``'s ``rows``).
     """
     B, W, V = logits.shape
     offs = jnp.arange(W, dtype=jnp.int32)[None, :]          # (1, W)
@@ -94,8 +98,10 @@ def spec_accept_and_sample(rngs: jnp.ndarray, logits: jnp.ndarray,
 
     flat = logits.reshape(B * W, V)
     rep = lambda a: jnp.repeat(jnp.asarray(a), W)           # noqa: E731
+    greedy = jnp.asarray(greedy, bool)
+    rows = ~greedy if live is None else jnp.asarray(live, bool) & ~greedy
     f = filter_logits_batched(flat, rep(temperature), rep(top_k),
-                              rep(top_p)).reshape(B, W, V)
+                              rep(top_p), rep(rows)).reshape(B, W, V)
     logp = jax.nn.log_softmax(f, axis=-1)
     p_acc = jnp.exp(jnp.take_along_axis(
         logp, cand[..., None].astype(jnp.int32), axis=-1))[..., 0]
@@ -105,8 +111,7 @@ def spec_accept_and_sample(rngs: jnp.ndarray, logits: jnp.ndarray,
         return jax.random.uniform(ku, (W,)), kc, kb, knext
 
     u, ckeys, bkeys, new_rngs = jax.vmap(per_slot)(rngs)
-    greedy_b = jnp.asarray(greedy, bool)[:, None]
-    accept = jnp.where(greedy_b, next_raw == cand, u < p_acc)
+    accept = jnp.where(greedy[:, None], next_raw == cand, u < p_acc)
     valid = offs < n_valid[:, None]
     chain = jnp.cumprod((accept & valid).astype(jnp.int32), axis=1)
     n_acc = jnp.sum(chain, axis=1).astype(jnp.int32)
@@ -127,7 +132,7 @@ def spec_accept_and_sample(rngs: jnp.ndarray, logits: jnp.ndarray,
     cat = jax.vmap(jax.random.categorical)
     corr = cat(ckeys, masked_r).astype(jnp.int32)
     bonus = cat(bkeys, f_r).astype(jnp.int32)
-    final = jnp.where(jnp.asarray(greedy, bool), raw_r,
+    final = jnp.where(greedy, raw_r,
                       jnp.where(n_acc < n_valid, corr, bonus))
     out = jnp.where(offs < n_acc[:, None], cand,
                     jnp.where(offs == n_acc[:, None], final[:, None], 0)

@@ -413,6 +413,119 @@ def test_batched_filters_match_scalar_filters():
     np.testing.assert_array_equal(got[3], np.asarray(logits[3]))
 
 
+def _sample_unconditionally(rngs, logits, temperature, top_k, top_p,
+                            greedy):
+    """The sampler as it was before it asked what its rows want: argmax,
+    the three batched filters, ``vmap(categorical)``, ``where``. Kept
+    here as the reference of ``sample_tokens_batched``."""
+    from replicatinggpt_tpu.sample.generate import (batched_top_k_filter,
+                                                    batched_top_p_filter)
+    greedy_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    scaled = logits / jnp.maximum(temperature, 1e-6)[:, None]
+    f = batched_top_p_filter(batched_top_k_filter(scaled, top_k), top_p)
+    sampled = jax.vmap(jax.random.categorical)(rngs, f).astype(jnp.int32)
+    return jnp.where(greedy, greedy_tok, sampled)
+
+
+_B, _V = 6, 300
+_SAMPLER_CASES = {
+    # name: (greedy, top_k, top_p, live) per row
+    "all_greedy": ([1] * 6, [0, 5, 0, 40, 0, 0], [0, .9, 0, .5, 1, 0],
+                   [1] * 6),
+    "sampled_top_k_only": ([0] * 6, [5, 40, 1, 299, 7, 12], [0, 1] * 3,
+                           [1] * 6),
+    "sampled_top_p_only": ([0] * 6, [0, 300] * 3,
+                           [.3, .9, .5, .95, .1, .7], [1] * 6),
+    "sampled_top_k_and_top_p": ([0] * 6, [5, 40, 0, 299, 7, 0],
+                                [.3, .9, .5, 0, 1, 0], [1] * 6),
+    "sampled_no_filter": ([0] * 6, [0, 300, 0, 400, 0, 0],
+                          [0, 1, 0, 1.5, 0, 1], [1] * 6),
+    "greedy_and_sampled_mixed": ([1, 0, 1, 0, 0, 1], [0, 5, 9, 0, 40, 0],
+                                 [0, 0, .9, .8, .5, 0], [1] * 6),
+    "sampled_rows_some_dead": ([0, 0, 1, 0, 0, 1], [5, 0, 0, 40, 0, 0],
+                               [0, .9, 0, .5, .3, 0], [1, 1, 1, 0, 0, 0]),
+    # the stale mirror: idle slots start at greedy=False and a finished
+    # request's parameters stay in its slot
+    "live_greedy_dead_rows_stale": ([1, 1, 0, 0, 1, 0], [0, 0, 0, 7, 0, 0],
+                                    [0, 0, .9, .9, 0, .9],
+                                    [1, 1, 0, 0, 1, 0]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_SAMPLER_CASES))
+def test_sampler_matches_unconditional_composition(case):
+    """``sample_tokens_batched`` runs the draw and each filter only when
+    a live row asks for it; every live row still gets, bit for bit, the
+    token of the unconditional composition, whatever the other rows'
+    parameters say."""
+    from replicatinggpt_tpu.sample.generate import sample_tokens_batched
+    greedy, top_k, top_p, live = _SAMPLER_CASES[case]
+    greedy, live = np.asarray(greedy, bool), np.asarray(live, bool)
+    rng = np.random.default_rng(7)
+    logits = jnp.asarray(rng.normal(size=(_B, _V)) * 3.0, jnp.float32)
+    temp = jnp.asarray(rng.uniform(0.5, 1.7, size=_B), jnp.float32)
+    args = (logits, temp, jnp.asarray(top_k, jnp.int32),
+            jnp.asarray(top_p, jnp.float32), jnp.asarray(greedy))
+    for seed in range(3):
+        rngs = jax.random.split(jax.random.PRNGKey(seed), _B)
+        want = np.asarray(jax.jit(_sample_unconditionally)(rngs, *args))
+        got = np.asarray(jax.jit(sample_tokens_batched)(
+            rngs, *args, jnp.asarray(live)))
+        np.testing.assert_array_equal(got[live], want[live])
+        if live.all():                   # no mask = every row is live
+            np.testing.assert_array_equal(
+                np.asarray(sample_tokens_batched(rngs, *args)), want)
+        if not (live & ~greedy).any():
+            # nothing was drawn: a dead stochastic row reads the argmax
+            np.testing.assert_array_equal(
+                got, np.asarray(jnp.argmax(logits, axis=-1)))
+
+
+@pytest.mark.parametrize("window", [1, 4])
+def test_sampled_neighbour_switches_the_filters_on_and_off(params, window):
+    """A greedy stream is token-identical when a sampled request
+    (temperature 0.8, top_p 0.9) is admitted beside it mid-stream and
+    after that request has finished; ``sample_filter_launches`` counts
+    exactly the launches in between, and ``stochastic_rows`` on
+    ``serve/launch`` is back at 0 once the sampled slot is released
+    (its mirrors still read "not greedy": the live mask keeps them
+    out)."""
+    from replicatinggpt_tpu.utils.telemetry import Telemetry
+    g = Request(id="g", prompt=np.array([3, 1, 4], np.int32),
+                max_new_tokens=28, sampling=SamplingParams(greedy=True))
+    want = _offline_greedy(params, [g])["g"]
+    tel = Telemetry()
+    eng = Engine(params, CFG, EngineConfig(pool_size=3, max_queue=8,
+                                           decode_window=window),
+                 telemetry=tel)
+    assert eng.submit(g) is None
+    done = []
+    for _ in range(3):
+        done += eng.step()
+    assert eng.metrics.counters["sample_filter_launches"] == 0
+    s = Request(id="s", prompt=np.array([2, 7], np.int32), max_new_tokens=5,
+                sampling=SamplingParams(temperature=0.8, top_p=0.9),
+                rng_seed=11)
+    assert eng.submit(s) is None
+    done += eng.drain()
+    got = {r.id: r.tokens for r in done}
+    assert got["g"] == want and len(got["s"]) == 5
+    rows = [e["args"]["stochastic_rows"] for e in tel.events
+            if e.get("ph") == "X" and e.get("name") == "serve/launch"]
+    on = [i for i, n in enumerate(rows) if n]
+    assert on and set(rows) == {0, 1}
+    assert on == list(range(on[0], on[-1] + 1))       # one run of launches
+    assert on[0] > 0 and on[-1] < len(rows) - 1       # g alone before, after
+    # s's five tokens: a launch each at k=1; at k=4 the mixed window
+    # that prefills it (3 tokens), the next (2), and one more that the
+    # host, a window ahead of the device, sends before it has seen the end
+    assert len(on) == {1: 5, 4: 3}[window]
+    assert eng.metrics_summary()["counters"]["sample_filter_launches"] \
+        == len(on)
+    slot_s = int(np.flatnonzero(~eng._greedy & (eng._top_p > 0))[0])
+    assert not eng._active[slot_s]                    # released, not cleaned
+
+
 # ---------------------------------------------------------------------------
 # steady state: zero recompiles + metrics (acceptance criterion)
 # ---------------------------------------------------------------------------

@@ -315,33 +315,49 @@ def test_kernel_name_is_the_hlo_instruction_name(mosaic, one_chip, build,
         assert sum(bool(re.search(fwd, n)) for n in names) == 2, names
 
 
-def test_decode_step_hlo_carries_the_phase_scopes(mosaic, one_chip):
-    """The engine's jitted decode step, compiled for the described v5e
-    on the Pallas route: ``jax.named_scope`` reaches no instruction NAME
-    (they stay ``%sort.N``, ``%fusion.N``) but is in the ops'
-    ``op_name`` metadata, where the trace's scope stat comes from."""
+def _window_hlo(program, sharding, **route):
+    """Optimized HLO of the engine's decode-window (``"decode"``) or
+    mixed-window (``"mixed"``) program at k=1, gpt2-small's widths and
+    two layers, compiled for ``sharding``'s device (None: this
+    process's CPU)."""
     import dataclasses
     from replicatinggpt_tpu.models.gpt import (init_paged_kv_pool,
                                                init_params)
-    from replicatinggpt_tpu.serve.engine import _engine_decode_window
+    from replicatinggpt_tpu.serve import engine
     cfg = dataclasses.replace(
         get_config("gpt2-small").model, n_layer=2, scan_layers=False,
         decode_cache_layout="packed")
-    B, psz, mp = 8, 16, 8
+    B, psz, mp, chunk = 8, 16, 8, 16
     shaped = lambda tree: jax.tree_util.tree_map(
-        lambda a: _s(a.shape, a.dtype, one_chip), tree)
+        lambda a: _s(a.shape, a.dtype, sharding), tree)
     params = shaped(jax.eval_shape(
         lambda: init_params(jax.random.PRNGKey(0), cfg)))
     cache = shaped(jax.eval_shape(
         lambda: init_paged_kv_pool(cfg, B * mp, psz)))
-    vec = lambda dt: _s((B,), dt, one_chip)
-    text = _engine_decode_window.lower(
-        params, vec(jnp.int32), vec(jnp.int32), vec(jnp.bool_),
-        vec(jnp.int32), vec(jnp.int32), _s((5, B), jnp.int32, one_chip),
-        _s((B, mp), jnp.int32, one_chip), cache,
-        _s((B, 2), jnp.uint32, one_chip), vec(jnp.float32),
-        vec(jnp.int32), vec(jnp.float32), vec(jnp.bool_), cfg, k=1,
-        use_pallas=True).compile().as_text()
+    vec = lambda dt: _s((B,), dt, sharding)
+    state = (params, vec(jnp.int32), vec(jnp.int32), vec(jnp.bool_),
+             vec(jnp.int32), vec(jnp.int32),
+             _s((5, B), jnp.int32, sharding))
+    prefill = (_s((3, B), jnp.int32, sharding),
+               _s((1, B, chunk), jnp.int32, sharding))
+    rest = (_s((B, mp), jnp.int32, sharding), cache,
+            _s((B, 2), jnp.uint32, sharding), vec(jnp.float32),
+            vec(jnp.int32), vec(jnp.float32), vec(jnp.bool_), cfg)
+    if program == "decode":
+        low = engine._engine_decode_window.lower(*state, *rest, k=1, **route)
+    else:
+        low = engine._engine_mixed_window.lower(*state, *prefill, *rest,
+                                                k=1, **route)
+    return low.compile().as_text()
+
+
+def test_decode_step_hlo_carries_the_phase_scopes(mosaic, one_chip):
+    """The engine's jitted decode step, compiled for the described v5e
+    on the Pallas route: ``jax.named_scope`` reaches no instruction NAME
+    (they stay ``%sort.N``, ``%fusion.N``; only the PARAMETER of a
+    ``cond`` branch is called after its scope) but is in the ops'
+    ``op_name`` metadata, where the trace's scope stat comes from."""
+    text = _window_hlo("decode", one_chip, use_pallas=True)
     assert _kernel_names(text) and all(
         n.startswith("paged_window_attention") for n in _kernel_names(text))
     op_names = re.findall(r'op_name="([^"]*)"', text)
@@ -349,4 +365,74 @@ def test_decode_step_hlo_carries_the_phase_scopes(mosaic, one_chip):
                   "head", "embed"):
         assert any(re.search(rf"(^|/){scope}(/|$)", n) for n in op_names), \
             scope
-    assert not re.search(r"%(sample|kv_gather)[\w.]* = ", text)
+    assert not re.search(r"%(sample|kv_gather)[\w.]* = (?!.* parameter\()",
+                         text)
+
+
+_HLO_CALLS = re.compile(
+    r"(?:calls|to_apply|body|condition|true_computation|false_computation"
+    r")=%?([\w.\-]+)|(?:branch_computations|called_computations)=\{([^}]*)\}")
+
+
+def _sorts_by_branch(text):
+    """``(n_conditionals, sorts, sorts_always)`` of an optimized HLO
+    module: how many ``conditional`` instructions it holds, how many
+    ``sort``s, and how many of those the entry computation reaches
+    WITHOUT going through a conditional's taken-when-true branch (the
+    last of ``lax.cond``'s two ``branch_computations``)."""
+    comps, entry, cur = {}, None, None
+    for line in text.splitlines():
+        m = re.match(r"^(ENTRY\s+)?%?([\w.\-]+) \(.*\) -> .*\{\s*$", line)
+        if m:
+            cur = comps.setdefault(m.group(2), [])
+            entry = m.group(2) if m.group(1) else entry
+        elif cur is not None and line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            cur.append(line)
+    names = lambda blob: [n.strip().lstrip("%") for n in blob.split(",")]
+    n_cond, n_sorts, always = 0, {}, {}
+    for comp, lines in comps.items():
+        n_sorts[comp] = sum(bool(re.search(r"\bsort\(", ln)) for ln in lines)
+        always[comp] = set()     # what it calls whatever a predicate says
+        for ln in lines:
+            called = [n for one, many in _HLO_CALLS.findall(ln)
+                      for n in ([one] if one else names(many))]
+            if " conditional(" in ln:
+                n_cond += 1
+                true = re.search(r"true_computation=%?([\w.\-]+)", ln)
+                called.remove(true.group(1) if true else names(re.search(
+                    r"branch_computations=\{([^}]*)\}", ln).group(1))[-1])
+            always[comp].update(called)
+    seen, todo = set(), [entry]
+    while todo:
+        comp = todo.pop()
+        if comp not in seen:
+            seen.add(comp)
+            todo.extend(always[comp])
+    return (n_cond, sum(n_sorts.values()),
+            sum(n for comp, n in n_sorts.items() if comp in seen))
+
+
+@pytest.mark.parametrize("program,target", [
+    ("decode", "v5e"), ("mixed", "v5e"), ("decode", "cpu"), ("mixed", "cpu")])
+def test_window_program_sorts_only_inside_a_taken_branch(request, program,
+                                                         target):
+    """The sampler's ``lax.cond``s survive optimization as real
+    ``conditional`` instructions, in the decode window and in the mixed
+    window, for the described v5e and for the CPU: the top-p filter's
+    ``sort``s sit in a taken-when-true branch and nowhere else, so an
+    all-greedy launch never runs them. A ``cond`` that a ``vmap`` or
+    the compiler turned into a ``select`` puts them back on every
+    step's path, and fails here."""
+    if target == "v5e":
+        request.getfixturevalue("mosaic")
+        sharding = request.getfixturevalue("one_chip")
+        route = ({"use_pallas": True} if program == "decode"
+                 else {"use_kernel": True})
+    else:
+        sharding, route = None, {}
+    n_cond, sorts, sorts_always = _sorts_by_branch(
+        _window_hlo(program, sharding, **route))
+    assert n_cond >= 3             # draw, top-k, top-p: one scalar each
+    assert sorts >= 1 and sorts_always == 0
