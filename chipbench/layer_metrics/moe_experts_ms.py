@@ -1,0 +1,6 @@
+"""Reads ``moe_experts_ms`` as ``moe_experts_ms.json`` beside this file says
+(``chipbench/trace_stats.py`` ``read_spec``)."""
+
+from chipbench import trace_stats
+
+read = trace_stats.reader(__file__)

@@ -403,8 +403,20 @@ def cmd_serve_replay(args) -> int:
     from .serve import EngineConfig, ReplayConfig, format_summary, run_replay
     from .train.state import create_train_state
     cfg = config_from_args(args)
-    state = create_train_state(jax.random.PRNGKey(cfg.train.seed),
-                               cfg.model, cfg.train)
+    if cfg.model.family != "gpt":
+        # a serve-only family: seeded weights from its own initialiser (no
+        # optimizer state beside 12 GB of parameters, no checkpoint format)
+        from collections import namedtuple
+
+        from .models.families import family
+        if args.checkpoint_dir:
+            raise SystemExit(f"--checkpoint-dir: the {cfg.model.family} "
+                             f"family is served from seeded weights")
+        state = namedtuple("Served", "params")(family(cfg.model).init_params(
+            jax.random.PRNGKey(cfg.train.seed), cfg.model))
+    else:
+        state = create_train_state(jax.random.PRNGKey(cfg.train.seed),
+                                   cfg.model, cfg.train)
     if args.checkpoint_dir:
         from .train.checkpoint import CheckpointManager
         restored = CheckpointManager(args.checkpoint_dir).restore_latest(state)

@@ -95,6 +95,62 @@ class ModelConfig:
     # +28% step time). Params stay stacked (L, ...) either way, so
     # shardings/checkpoints are unaffected.
 
+    # --- family (models/families.py picks the body by this) ---------------
+    # Everything below is DATA of an architecture other than GPT-2's; the
+    # defaults are GPT-2's, so every older preset is unchanged. The one
+    # family beside it, 'exaone_moe' (models/exaone_moe.py), is serve-only.
+    # What the family alone decides (RMSNorm, QK-norm, rotate-half RoPE on
+    # the window layers only, sigmoid scores normalised over the chosen) is
+    # that module's, not a field here: no caller can vary it.
+    family: str = "gpt"           # 'gpt' | 'exaone_moe'
+    n_kv_head: int = 0            # KV heads (GQA); 0 = n_head
+    attn_head_dim: int = 0        # head size; 0 = n_embd // n_head
+    rope_theta: float = 10000.0   # rotary base, where the family rotates
+    layer_types: Tuple[str, ...] = ()
+    # per layer 'sliding_attention' | 'full_attention'; () = all full
+    sliding_window: int = 0       # token i attends j, i - window < j <= i
+    mlp_layer_types: Tuple[str, ...] = ()
+    # per layer 'dense' | 'sparse'; () = all dense
+    intermediate_size: int = 0    # gated (SwiGLU) dense MLP width
+    n_experts: int = 0            # routed experts the ROUTER scores
+    experts_held: Tuple[int, ...] = ()
+    # ids of the routed experts THIS program holds (expert parallelism's
+    # share); the layer routes over all n_experts and sums its own
+    experts_per_token: int = 0
+    moe_intermediate_size: int = 0
+    routed_scaling: float = 1.0
+    shared_intermediate_size: int = 0   # one shared expert; 0 = none
+
+    @property
+    def kv_heads(self) -> int:
+        return self.n_kv_head or self.n_head
+
+    @property
+    def kv_channels(self) -> int:
+        """Width of one cached K (or V) row: KV heads x head size."""
+        return self.kv_heads * self.head_dim
+
+    @property
+    def paged_layers(self) -> Tuple[int, ...]:
+        """Layers whose K/V history lives in pool pages (all of GPT-2's;
+        the full-attention layers of a windowed family)."""
+        return tuple(i for i in range(self.n_layer)
+                     if not self.is_window_layer(i))
+
+    @property
+    def window_layers(self) -> Tuple[int, ...]:
+        """Layers that keep a bounded ring of K/V a slot instead."""
+        return tuple(i for i in range(self.n_layer)
+                     if self.is_window_layer(i))
+
+    def is_window_layer(self, i: int) -> bool:
+        return bool(self.layer_types) and \
+            self.layer_types[i] == "sliding_attention"
+
+    def is_sparse_layer(self, i: int) -> bool:
+        return bool(self.mlp_layer_types) and \
+            self.mlp_layer_types[i] == "sparse"
+
     @property
     def use_layer_scan(self) -> bool:
         if self.scan_layers is not None:
@@ -106,6 +162,8 @@ class ModelConfig:
 
     @property
     def head_dim(self) -> int:
+        if self.attn_head_dim:
+            return self.attn_head_dim
         assert self.n_embd % self.n_head == 0, (
             f"n_embd={self.n_embd} not divisible by n_head={self.n_head}"
         )
@@ -113,13 +171,57 @@ class ModelConfig:
 
     def validate(self) -> "ModelConfig":
         _ = self.head_dim
-        assert self.activation in ("gelu", "relu"), self.activation
+        assert self.family in ("gpt", "exaone_moe"), self.family
+        if self.family == "gpt":
+            assert self.activation in ("gelu", "relu"), self.activation
+            for name in ("n_kv_head", "attn_head_dim", "layer_types",
+                         "sliding_window", "mlp_layer_types",
+                         "intermediate_size", "n_experts", "experts_held",
+                         "experts_per_token", "moe_intermediate_size",
+                         "shared_intermediate_size"):
+                assert not getattr(self, name), (
+                    f"{name} is not GPT-2's: models/gpt.py has one head "
+                    f"count, learned positions and a dense MLP")
+        else:
+            self._validate_exaone_moe()
         assert self.attention_impl in ("auto", "einsum", "flash", "ring",
                                        "ulysses")
         assert self.remat_policy in ("full", "dots", "dots_no_batch"), (
             self.remat_policy)
         assert self.act_quant in ("none", "int8"), self.act_quant
         return self
+
+    def _validate_exaone_moe(self) -> None:
+        """What models/exaone_moe.py computes, and nothing near it."""
+        L = self.n_layer
+        assert self.activation == "swiglu", self.activation
+        assert self.n_head % self.kv_heads == 0, (
+            f"{self.n_head} query heads do not group over "
+            f"{self.kv_heads} KV heads")
+        assert self.head_dim % 2 == 0, "rotate-half needs an even head"
+        assert not self.tied_head, "the family's head is untied"
+        assert len(self.layer_types) == L and all(
+            t in ("sliding_attention", "full_attention")
+            for t in self.layer_types), f"layer_types={self.layer_types}"
+        assert len(self.mlp_layer_types) == L and all(
+            t in ("dense", "sparse") for t in self.mlp_layer_types), (
+            f"mlp_layer_types={self.mlp_layer_types}")
+        if self.window_layers:
+            assert self.sliding_window > 0, "window layers need a window"
+        if "dense" in self.mlp_layer_types:
+            assert self.intermediate_size > 0
+        if "sparse" in self.mlp_layer_types:
+            assert 0 < self.experts_per_token <= self.n_experts
+            assert self.moe_intermediate_size > 0
+            held = self.experts_held
+            assert held and len(set(held)) == len(held) and all(
+                0 <= e < self.n_experts for e in held), (
+                f"experts_held={held} of {self.n_experts}")
+        assert self.dropout == 0.0 and self.attn_dropout == 0.0, (
+            "serve-only family: no dropout")
+        assert self.decode_cache_layout == "packed", (
+            "the family's pool is packed (KV heads as lane slices)")
+        assert self.act_quant == "none", "no W8A8 path for this family"
 
 
 @dataclass(frozen=True)
@@ -227,6 +329,22 @@ def _gpt2_ladder(n_layer: int, n_head: int, n_embd: int,
     )
 
 
+def _exaone_moe(n_layer: int, **kw) -> ModelConfig:
+    """The exaone_moe family's constants (K-EXAONE config.json): RMSNorm
+    eps 1e-5, QK-norm, RoPE theta 1e6 on the window layers only, three
+    window layers to one full layer, a dense SwiGLU MLP in layer 0 and
+    sigmoid-routed experts (scaling 2.5, normalised top-k) beside one
+    shared expert after it, untied head."""
+    pattern = ("sliding_attention",) * 3 + ("full_attention",)
+    return ModelConfig(
+        family="exaone_moe", n_layer=n_layer, dropout=0.0, attn_dropout=0.0,
+        tied_head=False, activation="swiglu", rope_theta=1e6,
+        layer_types=tuple(pattern[i % 4] for i in range(n_layer)),
+        mlp_layer_types=("dense",) + ("sparse",) * (n_layer - 1),
+        routed_scaling=2.5,
+        decode_cache_layout="packed", scan_layers=False, **kw)
+
+
 PRESETS = {
     # BASELINE.json config 1/2: canonical char-GPT (n_embd=384 per
     # BASELINE.md; GPT1.py semantics: untied head, ReLU, dropout 0.2).
@@ -320,6 +438,37 @@ PRESETS = {
                           eval_interval=200, eval_iters=200, seed=1337,
                           sampling="random"),
         tokenizer="tiktoken:o200k_base",
+    ),
+    # K-EXAONE-236B-A23B (LGAI-EXAONE, config.json on the Hugging Face hub),
+    # ONE CHIP'S SHARE of an 8-way expert-parallel deployment, serve-only:
+    # every width as published; published layers 0-7 (two periods of three
+    # window-128 layers and one full layer; layer 0's MLP dense, 1-7
+    # sparse); experts 0-15 of a router that keeps its 128 outputs and 8 a
+    # token; vocabulary rows 0-19,199 of 153,600. bf16 parameters as
+    # published. chipbench/configs/k-exaone-236b-a23b.json states the cut.
+    "k-exaone-236b-a23b": Config(
+        name="k-exaone-236b-a23b",
+        model=_exaone_moe(
+            vocab_size=19_200, block_size=8192, n_layer=8, n_head=64,
+            n_kv_head=8, attn_head_dim=128, n_embd=6144,
+            sliding_window=128, intermediate_size=18_432, n_experts=128,
+            experts_held=tuple(range(16)), experts_per_token=8,
+            moe_intermediate_size=2048, shared_intermediate_size=2048,
+            dtype="bfloat16", param_dtype="bfloat16"),
+        tokenizer="char",
+    ),
+    # the same family at test widths (CPU tests, chipbench/rehearse.py):
+    # window 8 of a 64-token context, 2 of 8 experts held, 2 a token
+    "exaone-moe-tiny": Config(
+        name="exaone-moe-tiny",
+        model=_exaone_moe(
+            vocab_size=96, block_size=64, n_layer=4, n_head=4, n_kv_head=2,
+            attn_head_dim=32, n_embd=64, sliding_window=8,
+            intermediate_size=96, n_experts=8, experts_held=(0, 1),
+            experts_per_token=2, moe_intermediate_size=48,
+            shared_intermediate_size=48, dtype="float32",
+            param_dtype="float32"),
+        tokenizer="char",
     ),
     # Tiny config for tests / smoke runs.
     "test-tiny": Config(

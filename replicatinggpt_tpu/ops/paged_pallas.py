@@ -118,7 +118,7 @@ def paged_attention_envelope(n_head: int, head_dim: int, page_size: int,
                              *, itemsize: int = 2, mesh=None,
                              kv_quant: str = "none",
                              granularity: str = "page",
-                             n_pages=None) -> tuple:
+                             n_pages=None, n_kv_head=None) -> tuple:
     """THE shared kernel envelope — one set of gate checks consumed by
     every route predicate (``paged_decode_supported``,
     ``mixed_step_kernel_ok`` here; ``fused_paged_decode_supported`` in
@@ -134,6 +134,16 @@ def paged_attention_envelope(n_head: int, head_dim: int, page_size: int,
     (data, model) meshes through the shard_map wrapper when the pool
     geometry divides (``paged_kernel_mesh_ok``)."""
     reasons = []
+    n_kv_head = n_kv_head or n_head
+    if n_kv_head != n_head:
+        # grouped queries run ``paged_gqa_attention``: one chip, a plain
+        # pool, whole groups of query heads to a KV head
+        if mesh is not None and mesh.size > 1:
+            reasons.append("gqa_mesh")
+        if kv_quant != "none":
+            reasons.append("gqa_kv_quant")
+        if n_head % n_kv_head:
+            reasons.append("gqa_group")
     if not paged_kernel_mesh_ok(mesh, n_pages=n_pages,
                                 n_embd=n_head * head_dim,
                                 n_head=n_head):
@@ -148,7 +158,7 @@ def paged_attention_envelope(n_head: int, head_dim: int, page_size: int,
         reasons.append("n_head_gt_lanes")
     if page_size % 8 != 0:
         reasons.append("page_align")
-    C = n_head * head_dim
+    C = n_kv_head * head_dim          # a page's row is KV heads wide
     if 2 * page_size * C * itemsize > PAGED_DECODE_BYTES:
         reasons.append("vmem_budget")
     return (not reasons), tuple(reasons)
@@ -516,3 +526,181 @@ def sharded_paged_window_attention(q: jnp.ndarray, k_new: jnp.ndarray,
         args += [k_scales, v_scales]
     return shard_map(local_fn, mesh=mesh, in_specs=tuple(in_specs),
                      out_specs=qspec, check_vma=False)(*args)
+
+
+# ---------------------------------------------------------------------------
+# grouped queries, and a lower bound on the positions read
+#
+# The family above has one head count on both sides (C = n_head * D for q
+# and for a page's row). A grouped-query model's page row is n_kv_head * D
+# wide and G = n_head // n_kv_head query heads read each KV head, so the
+# kernel below stacks a group's G * W query rows into ONE (G*W, D) block a
+# KV head: a page costs n_kv_head small matmuls, not n_head. ``attn_window``
+# bounds the positions a row reads from below (row j at position pos + j
+# attends k with pos + j - window < k <= pos + j): pages wholly behind the
+# bound are unowned and skipped exactly like pages past the frontier, the
+# fetch-skip trick at the other end. ``page0`` gives the absolute logical
+# page of a slot's first table entry, so the same walk serves a per-slot
+# RING of pages (a window layer's bounded state) as well as the pool.
+# Both are static: gpt2's programs never reach this function.
+
+
+def _paged_gqa_kernel(tables_ref, pos_ref, owned_ref, page0_ref, q_ref,
+                      knew_ref, vnew_ref, kp_ref, vp_ref, out_ref,
+                      acc_ref, m_ref, l_ref, *, n_kv_head, head_dim,
+                      page_size, n_pages_per_slot, window, attn_window,
+                      scale):
+    b = pl.program_id(0)
+    p = pl.program_id(1)
+    D, psz, W = head_dim, page_size, window
+    GW = q_ref.shape[1]                       # G * W rows a KV head
+    pos = pos_ref[b]
+    # row r of a group's block is query row j = r % W of the window
+    row_j = jax.lax.broadcasted_iota(jnp.int32, (GW, 1), 0) % W
+
+    @pl.when(p == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    @pl.when(owned_ref[b, p] > 0)
+    def _accumulate():
+        kpos = (jax.lax.broadcasted_iota(jnp.int32, (1, psz), 1)
+                + (page0_ref[b] + p) * psz)
+        live = kpos < pos                                       # (1, psz)
+        if attn_window:
+            live = live & (kpos > pos + row_j - attn_window)    # (GW, psz)
+        for g in range(n_kv_head):
+            sl = slice(g * D, (g + 1) * D)
+            q = q_ref[g].astype(jnp.float32)                    # (GW, D)
+            kcf = kp_ref[:, sl].astype(jnp.float32)             # (psz, D)
+            vcf = vp_ref[:, sl].astype(jnp.float32)
+            s = jax.lax.dot_general(
+                q, kcf, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale     # (GW, psz)
+            s = jnp.where(live, s, NEG_INF)
+            m_prev = m_ref[g]                                   # (GW, 1)
+            m_new = jnp.maximum(m_prev,
+                                jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            pexp = jnp.where(s > NEG_INF / 2, jnp.exp(s - m_new), 0.0)
+            l_ref[g] = l_ref[g] * alpha + jnp.sum(pexp, axis=1,
+                                                  keepdims=True)
+            acc_ref[g] = (acc_ref[g] * alpha
+                          + jax.lax.dot_general(
+                              pexp, vcf, (((1,), (0,)), ((), ())),
+                              preferred_element_type=jnp.float32))
+            m_ref[g] = m_new
+
+    @pl.when(p == n_pages_per_slot - 1)
+    def _finalize():
+        col = jax.lax.broadcasted_iota(jnp.int32, (GW, W), 1)
+        fresh = col <= row_j           # row j attends fresh rows 0..j
+        if attn_window:
+            fresh = fresh & (col > row_j - attn_window)
+        for g in range(n_kv_head):
+            sl = slice(g * D, (g + 1) * D)
+            q = q_ref[g].astype(jnp.float32)
+            kn = knew_ref[:, sl].astype(jnp.float32)            # (W, D)
+            vn = vnew_ref[:, sl].astype(jnp.float32)
+            s_new = jax.lax.dot_general(
+                q, kn, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale     # (GW, W)
+            s_new = jnp.where(fresh, s_new, NEG_INF)
+            m_prev = m_ref[g]
+            m2 = jnp.maximum(m_prev,
+                             jnp.max(s_new, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m2)
+            p_new = jnp.where(fresh, jnp.exp(s_new - m2), 0.0)
+            # denom >= diagonal term > 0 always (row j attends itself)
+            denom = l_ref[g] * alpha + jnp.sum(p_new, axis=1,
+                                               keepdims=True)
+            out = (acc_ref[g] * alpha
+                   + jax.lax.dot_general(
+                       p_new, vn, (((1,), (0,)), ((), ())),
+                       preferred_element_type=jnp.float32)) / denom
+            out_ref[g] = out.astype(out_ref.dtype)
+
+
+def gqa_owned_pages(pos: jnp.ndarray, page0: jnp.ndarray, n_table: int,
+                    page_size: int, attn_window: int) -> jnp.ndarray:
+    """(B, n_table) bool: the table entries whose page holds a position
+    the window's rows read from the STALE state: some k < pos and, under
+    a window, some k > pos - attn_window (row 0's bound, the lowest)."""
+    a = page0[:, None] + jnp.arange(n_table, dtype=jnp.int32)[None, :]
+    owned = a * page_size < pos[:, None]
+    if attn_window:
+        owned &= (a + 1) * page_size - 1 > pos[:, None] - attn_window
+    return owned & (a >= 0)
+
+
+def paged_gqa_attention(q: jnp.ndarray, k_new: jnp.ndarray,
+                        v_new: jnp.ndarray, k_pages: jnp.ndarray,
+                        v_pages: jnp.ndarray, tables: jnp.ndarray,
+                        pos: jnp.ndarray, *, n_head: int, n_kv_head: int,
+                        attn_window: int = 0, page0=None,
+                        name: str = "paged_window_attention"):
+    """``paged_window_attention`` for grouped queries: q (B, W, n_head*D),
+    k_new/v_new (B, W, n_kv_head*D), pages (N, page, n_kv_head*D); query
+    head n reads KV head ``n // (n_head // n_kv_head)``. Same contract:
+    attends the STALE pages masked to positions < pos and folds the fresh
+    causal window, so the caller scatters afterwards.
+
+    ``attn_window`` > 0 keeps row j to positions > pos + j - attn_window;
+    pages wholly behind it are never fetched. ``page0`` (B,) is the
+    absolute logical page of ``tables[:, 0]`` (default 0: the table
+    starts at the sequence's first page); a window layer's ring passes
+    the first page of its walk. ``name`` is the kernel's name in the
+    HLO and the trace: full layers keep ``paged_window_attention``, a
+    window layer's call says ``swa_...``."""
+    N, psz, Ckv = k_pages.shape
+    B, W, Cq = q.shape
+    mp = tables.shape[1]
+    D = Ckv // n_kv_head
+    G = n_head // n_kv_head
+    assert Cq == n_head * D and n_head == G * n_kv_head, (q.shape,
+                                                          k_pages.shape)
+    pos = jnp.asarray(pos, jnp.int32)
+    page0 = (jnp.zeros((B,), jnp.int32) if page0 is None
+             else jnp.asarray(page0, jnp.int32))
+    owned = gqa_owned_pages(pos, page0, mp, psz, attn_window)
+    eff = _fill_last_owned(jnp.asarray(tables, jnp.int32), owned)
+    # a KV head's G query heads as G*W rows of one block
+    qg = (q.reshape(B, W, n_kv_head, G, D).transpose(0, 2, 3, 1, 4)
+          .reshape(B, n_kv_head, G * W, D))
+    kernel = functools.partial(
+        _paged_gqa_kernel, n_kv_head=n_kv_head, head_dim=D, page_size=psz,
+        n_pages_per_slot=mp, window=W, attn_window=int(attn_window),
+        scale=D ** -0.5)
+
+    def q_map(b, p, tables, pos, owned, page0):
+        return (b, 0, 0, 0)
+
+    def row_map(b, p, tables, pos, owned, page0):
+        return (b, 0, 0)
+
+    def page_map(b, p, tables, pos, owned, page0):
+        return (tables[b, p], 0, 0)
+
+    qspec = _vmem_spec((None, n_kv_head, G * W, D), q_map)
+    row = _vmem_spec((None, W, Ckv), row_map)
+    page = _vmem_spec((None, psz, Ckv), page_map)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(B, mp),
+        in_specs=[qspec, row, row, page, page],
+        out_specs=qspec,
+        scratch_shapes=[pltpu.VMEM((n_kv_head, G * W, D), jnp.float32),
+                        pltpu.VMEM((n_kv_head, G * W, 1), jnp.float32),
+                        pltpu.VMEM((n_kv_head, G * W, 1), jnp.float32)],
+    )
+    out = pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, n_kv_head, G * W, D), q.dtype),
+        name=name, interpret=_interpret_mode(),
+        compiler_params=_compiler_params(0, 2),
+    )(eff, pos, owned.astype(jnp.int32), page0, qg, k_new, v_new,
+      k_pages, v_pages)
+    return (out.reshape(B, n_kv_head, G, W, D).transpose(0, 3, 1, 2, 4)
+            .reshape(B, W, Cq))

@@ -108,8 +108,7 @@ from ..config import ModelConfig
 from ..faults.inject import fire as fault_fire
 from ..faults.watchdog import (LoadShedder, ResilienceConfig, SpecHealth,
                                StepWatchdog)
-from ..models.gpt import (decode_window_paged, mixed_window_paged,
-                          prefill_chunk_paged, verify_step_paged)
+from ..models.families import family, serve_refusals
 from ..sample.generate import sample_tokens_batched
 from ..utils.logging import Metrics
 from ..utils.profiling import StepTimer
@@ -290,7 +289,8 @@ class KernelRoute:
 
     route: str                    # "pallas" | "xla"
     decode: str                   # "fused" | "pallas" | "xla"
-    window: str                   # mixed/verify windowed steps
+    window: str                   # mixed/verify windowed steps ("none":
+                                  # the family refuses both)
     sharded: bool                 # kernels run under shard_map
     mesh: tuple                   # (data, model)
     kv_quant: str
@@ -328,7 +328,7 @@ def decide_kernel_route(cfg: ModelConfig, ecfg: EngineConfig, qcfg,
     kernel keeps its extra gates (packed weights streamed in-kernel:
     1x1 mesh only, unquantized weights, VMEM weight budget) and wins
     over the per-layer kernel when both fit."""
-    from ..ops import decode_pallas, paged_pallas
+    from ..ops import paged_pallas
     reasons = []
     if not ecfg.paged_kernel:
         reasons.append("paged_kernel_off")
@@ -339,21 +339,20 @@ def decide_kernel_route(cfg: ModelConfig, ecfg: EngineConfig, qcfg,
     ok_env, env_reasons = paged_pallas.paged_attention_envelope(
         cfg.n_head, cfg.head_dim, page_size, itemsize=itemsize,
         mesh=mesh, kv_quant=qcfg.kv_dtype, granularity=qcfg.granularity,
-        n_pages=n_pages)
+        n_pages=n_pages, n_kv_head=cfg.kv_heads)
     reasons.extend(env_reasons)
     base_ok = not reasons
-    use_fused = bool(
-        base_ok and not qcfg.weight_enabled
-        and decode_pallas.fused_paged_decode_supported(
-            cfg, n_slots, page_size, itemsize, mesh=mesh,
-            kv_quant=qcfg.kv_dtype, granularity=qcfg.granularity))
-    use_window = bool(base_ok and paged_pallas.mixed_step_kernel_ok(
-        cfg.n_head, cfg.head_dim, page_size, itemsize, mesh=mesh,
-        kv_quant=qcfg.kv_dtype, granularity=qcfg.granularity,
-        n_pages=n_pages))
+    fam = family(cfg)
+    use_fused = bool(base_ok and fam.fused_decode_ok(
+        cfg, n_slots, page_size, itemsize, mesh, qcfg))
+    # None: the family has no mixed or verify step to route, and the
+    # headline follows its decode kernel alone
+    windowed_ok = fam.window_kernel_ok(cfg, page_size, n_pages, itemsize,
+                                       mesh, qcfg)
     decode = ("fused" if use_fused
               else "pallas" if base_ok else "xla")
-    window = "pallas" if use_window else "xla"
+    window = ("none" if windowed_ok is None
+              else "pallas" if (base_ok and windowed_ok) else "xla")
     route = "pallas" if (decode != "xla" and window != "xla") else "xla"
     return KernelRoute(
         route=route, decode=decode, window=window,
@@ -510,7 +509,8 @@ def _engine_decode_window(params, tok, pos, active, budget, eos, life,
     tok, pos, active, budget = _merge_lifecycle(
         tok, pos, active, budget, life, shardings)
 
-    return decode_window_paged(params, tok, pos, active, budget, eos,
+    return family(cfg).decode_window_paged(
+                               params, tok, pos, active, budget, eos,
                                tables, cache, rngs, cfg,
                                sample_fn=_sampler(temp, top_k, top_p, greedy),
                                length=k,
@@ -547,7 +547,8 @@ def _engine_mixed_window(params, tok, pos, active, budget, eos, life,
     tok, pos, active, budget = _merge_lifecycle(
         tok, pos, active, budget, life, shardings)
 
-    return mixed_window_paged(params, tok, pos, active, budget, eos,
+    return family(cfg).mixed_window_paged(
+                              params, tok, pos, active, budget, eos,
                               pfc[0], pfc[1], pfc[2], pf_toks,
                               tables, cache, rngs, cfg,
                               sample_fn=_sampler(temp, top_k, top_p, greedy),
@@ -557,10 +558,17 @@ def _engine_mixed_window(params, tok, pos, active, budget, eos, life,
 
 @partial(jax.jit, static_argnames=("cfg", "shardings"),
          donate_argnames=("cache",))
-def _engine_prefill(params, chunk, offset, limit, table_row, cache,
+def _engine_prefill(params, chunk, offset, limit, table_row, slot, cache,
                     cfg: ModelConfig, shardings=None):
-    return prefill_chunk_paged(params, chunk, offset, limit, table_row,
-                               cache, cfg, shardings=shardings)
+    """One prefill chunk of one slot through ``cfg``'s family. ``slot``
+    names the slot for a family that keeps per-slot state beside the
+    pages; GPT-2's program never reads it, and jit drops an argument
+    nothing reads BEFORE it is put on the device: callers hand it over as
+    a numpy scalar, never as a device array made for the call (one
+    upload a chunk for nothing)."""
+    return family(cfg).prefill_chunk_paged(
+        params, chunk, offset, limit, table_row, slot, cache, cfg,
+        shardings=shardings)
 
 
 @partial(jax.jit, static_argnames=("cfg", "use_kernel", "shardings"),
@@ -580,7 +588,8 @@ def _engine_verify(params, window, pos, m, active, tables, cache, rngs,
     the verify forward on the serving mesh (pool pinned per layer) with
     the acceptance outputs replicated for the host commit.
     """
-    logits, cache = verify_step_paged(params, window, pos, m, active,
+    logits, cache = family(cfg).verify_step_paged(
+                                      params, window, pos, m, active,
                                       tables, cache, cfg,
                                       shardings=shardings,
                                       use_kernel=use_kernel)
@@ -744,6 +753,12 @@ class Engine:
         replicas share one recorder without colliding tracks
         (``track_label`` prefixes the human-readable track names)."""
         cfg.validate()
+        refused = serve_refusals(cfg, ecfg, drafter)
+        if refused:
+            raise ValueError(f"the {cfg.family} family cannot be served "
+                             f"under this engine configuration: "
+                             + "; ".join(refused))
+        self._fam = family(cfg)
         self.params = params
         # quantization (replicatinggpt_tpu/quant/): weight-side params
         # quantize HERE, before any mesh placement, unless the caller
@@ -825,8 +840,11 @@ class Engine:
         # the pool's own arrays (K, V and a quantized pool's scales):
         # what ``serve/launch`` multiplies the live tokens by
         self._kv_token_bytes = (
-            sum(a.nbytes for a in self.pool.cache.values())
+            sum(a.nbytes for a in self.pool.pages.values())
             // (self.pool.n_pages * self.pool.page_size))
+        # what a family with window layers and experts adds to the stats
+        # of ``serve/launch`` (None: GPT-2, whose stats are unchanged)
+        self._launch_extra = self._family_launch_stats()
         P = ecfg.pool_size
         self._chunk = ecfg.chunk(cfg.block_size)
         self._window = max(int(ecfg.decode_window), 1)
@@ -849,10 +867,10 @@ class Engine:
         # FUSED all-layers kernel is preferred for pure decode; the
         # per-layer windowed kernel (and its shard_map wrapper on a >1
         # mesh) carries everything else.
-        itemsize = jnp.dtype(self.pool.cache["k"].dtype).itemsize
+        itemsize = jnp.dtype(self.pool.kv_array.dtype).itemsize
         self.kernel_route = decide_kernel_route(
             cfg, ecfg, self.qcfg, self.pool.page_size,
-            self.pool.cache["k"].shape[1], itemsize, P, self.mesh)
+            self.pool.kv_array.shape[1], itemsize, P, self.mesh)
         self._use_fused = self.kernel_route.decode == "fused"
         self._use_pallas = self.kernel_route.decode == "pallas"
         self._use_window_kernel = self.kernel_route.window == "pallas"
@@ -947,7 +965,7 @@ class Engine:
         # warm the COW program NOW (page 0 onto itself — a value no-op):
         # the first real copy-on-write happens mid-replay, where a
         # compile would break the pinned-flat compile_counts invariant
-        self.pool.cache = self._copy_guard(self.pool.cache, jnp.int32(0),
+        self.pool.pages = self._copy_guard(self.pool.pages, jnp.int32(0),
                                            jnp.int32(0),
                                            shardings=self._plan)
         # warm the disaggregated-transfer pair the same way: export page
@@ -957,10 +975,10 @@ class Engine:
         # page 0. A value no-op; the first real transfer lands
         # mid-traffic on either tier.
         blocks = {name: np.asarray(arr) for name, arr in
-                  self._export_guard(self.pool.cache,
+                  self._export_guard(self.pool.pages,
                                      jnp.int32(0)).items()}
-        self.pool.cache = self._install_guard(
-            self.pool.cache, jnp.int32(0),
+        self.pool.pages = self._install_guard(
+            self.pool.pages, jnp.int32(0),
             {name: jnp.asarray(arr) for name, arr in blocks.items()},
             shardings=self._plan)
         if self._window > 1:
@@ -1105,7 +1123,7 @@ class Engine:
         for p in pages:
             check_in_bounds(int(p), 1, self.pool.n_pages,
                             what="page export")
-            out.append(self._export_guard(self.pool.cache, jnp.int32(p)))
+            out.append(self._export_guard(self.pool.pages, jnp.int32(p)))
         self.pool.pages_exported += len(pages)
         return jax.device_get(out)
 
@@ -1117,7 +1135,7 @@ class Engine:
         traffic. Shapes/dtypes must match this pool's entries exactly;
         the engine-shape hash both tiers agreed on at registration
         guarantees that, and the assert keeps a codec bug loud."""
-        cache = self.pool.cache
+        cache = self.pool.pages
         for p, blk in zip(pages, blocks):
             check_in_bounds(int(p), 1, self.pool.n_pages,
                             what="page install")
@@ -1131,7 +1149,7 @@ class Engine:
                 dev[name] = jnp.asarray(b)
             cache = self._install_guard(cache, jnp.int32(p), dev,
                                         shardings=self._plan)
-        self.pool.cache = cache
+        self.pool.pages = cache
 
     @property
     def idle(self) -> bool:
@@ -1422,6 +1440,10 @@ class Engine:
         # paged-pool health: bench dashboards key on this block (schema
         # pinned in tests/test_pages.py)
         s["pages"] = self.pool.stats()
+        # the pool's bytes by kind of KV state: pages that admission
+        # reserves, and per-slot window rings (0 for GPT-2)
+        s["kv_global_bytes"], s["kv_window_bytes"] = \
+            self.pool.bytes_by_kind()
         # dispatch amortization: the host tax per dispatch vs per token
         # (the serve-side analogue of the train bench's dispatch split;
         # BENCH_r03 measured 77.4 ms blocked vs 12.1 ms/step amortized)
@@ -1526,7 +1548,7 @@ class Engine:
             check_in_bounds(dst, 1, self.pool.n_pages, what="COW page")
             self.tel.instant("cow_split", tid, src=src, dst=dst,
                              request=req.id)
-            self.pool.cache = self._copy_guard(self.pool.cache,
+            self.pool.pages = self._copy_guard(self.pool.pages,
                                                jnp.int32(src),
                                                jnp.int32(dst),
                                                shardings=self._plan)
@@ -1557,7 +1579,7 @@ class Engine:
                         jnp.asarray(padded[None,
                                            c * chunk:(c + 1) * chunk]),
                         jnp.int32(claimed + c * chunk), jnp.int32(P),
-                        table_row, cache, self.cfg,
+                        table_row, np.int32(slot), cache, self.cfg,
                         shardings=self._plan)
                     if self.tel.enabled:
                         # host dispatch time (the device runs async);
@@ -1612,7 +1634,7 @@ class Engine:
             check_in_bounds(dst, 1, self.pool.n_pages, what="COW page")
             self.tel.instant("cow_split", tid, src=src, dst=dst,
                              request=req.id)
-            self.pool.cache = self._copy_guard(self.pool.cache,
+            self.pool.pages = self._copy_guard(self.pool.pages,
                                                jnp.int32(src),
                                                jnp.int32(dst),
                                                shardings=self._plan)
@@ -1734,7 +1756,7 @@ class Engine:
                         jnp.asarray(tail[None,
                                          c * chunk:(c + 1) * chunk]),
                         jnp.int32(off + c * chunk), jnp.int32(limit),
-                        table_row, cache, self.cfg,
+                        table_row, np.int32(slot), cache, self.cfg,
                         shardings=self._plan)
             self.pool.cache = cache
             self._pf_left[slot] = 0
@@ -1853,12 +1875,39 @@ class Engine:
         n_active = int(live.sum())
         live_tokens = int(self._pos[live].sum()) + n_active
         stochastic = self._count_stochastic_rows(live & (self._pf_left < k))
+        extra = {}
+        if self._launch_extra is not None:
+            window, swa_token_bytes, expert_bytes = self._launch_extra
+            extra = dict(
+                swa_kv_bytes=int(np.minimum(self._pos[live] + 1,
+                                            window).sum()) * swa_token_bytes,
+                expert_weight_bytes=expert_bytes * k,
+                moe_rows=n_active * k)
         with self.tel.phase("serve/launch", self._tb + ENGINE_TRACK, k=k,
                             n_active=n_active, live_tokens=live_tokens,
                             live_kv_bytes=live_tokens
                             * self._kv_token_bytes,
-                            stochastic_rows=stochastic):
+                            stochastic_rows=stochastic, **extra):
             return self._dispatch(k, kill, n_active, t0_us, t_wall)
+
+    def _family_launch_stats(self) -> Optional[tuple]:
+        """``(window, bytes a ring token holds over the window layers,
+        bytes of the held experts over the sparse layers)`` for a family
+        that has them: what ``swa_kv_bytes`` and ``expert_weight_bytes``
+        of ``serve/launch`` multiply. A decode step streams every held
+        expert once (at 64 live rows 98% of them get a token), so the
+        second is per step whatever was routed."""
+        cfg = self.cfg
+        if not (cfg.window_layers or cfg.n_experts):
+            return None
+        rings = [a for n, a in self.pool.cache.items()
+                 if n not in self.pool.pages]
+        swa_token = (sum(a.nbytes for a in rings)
+                     // max(rings[0].shape[1] * rings[0].shape[2], 1)
+                     if rings else 0)
+        experts = sum(a.nbytes for lp in self.params["layers"]
+                      for n, a in lp.items() if n.startswith("e_"))
+        return cfg.sliding_window, swa_token, experts
 
     def _dispatch(self, k: int, kill: np.ndarray, n_active: int,
                   t0_us: float, t_wall: float) -> _InFlight:
@@ -2023,6 +2072,13 @@ class Engine:
         with self.tel.phase("serve/fetch", self._tb + ENGINE_TRACK):
             toks = np.asarray(w.toks)
             emitted = np.asarray(w.emitted)
+        if self._fam.step_counters:
+            # the family's per-step counters ride the token block as
+            # trailing columns: one fetch, then the block is the tokens'
+            P = self.ecfg.pool_size
+            for j, name in enumerate(self._fam.step_counters):
+                self.metrics.inc(name, int(toks[:, P + j].sum()))
+            toks = toks[:, :P]
         now = self.clock()
         self.n_steps += 1
         self.step_timer.laps.append(time.perf_counter() - w.t_wall)

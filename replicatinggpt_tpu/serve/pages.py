@@ -44,7 +44,7 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 from ..config import ModelConfig
-from ..models.gpt import init_paged_kv_pool
+from ..models.families import family
 from ..utils.telemetry import NULL
 from .cache_pool import commit_default
 
@@ -82,10 +82,11 @@ def page_bytes(cfg: ModelConfig, page_size: int, kv_quant: str = "none",
     -> C + 8/page_size... the scale overhead is 8 bytes/token/layer at
     page granularity), roughly doubling the pool."""
     from ..quant.kv import kv_itemsize, scale_bytes_per_token
-    per_tok = (2 * cfg.n_embd * kv_itemsize(kv_quant, cfg)
+    per_tok = (2 * cfg.kv_channels * kv_itemsize(kv_quant, cfg)
                + scale_bytes_per_token(kv_quant, granularity,
                                        cfg.n_head))
-    return cfg.n_layer * page_size * per_tok
+    # a page holds the layers that keep paged history (all of GPT-2's)
+    return len(cfg.paged_layers) * page_size * per_tok
 
 
 def n_pages_for_hbm(hbm_bytes: int, cfg: ModelConfig, page_size: int,
@@ -428,6 +429,11 @@ class PagedCachePool:
         invariant (allocator, radix, COW planning) is byte-for-byte
         unchanged — a page is its rows plus their scales."""
         assert n_slots >= 1, n_slots
+        if prefix_cache and cfg.window_layers:
+            raise ValueError(
+                "prefix_cache: the radix cache shares pages of layers that "
+                "keep paged history; a window layer's state is a ring a "
+                "slot and cannot be restored from it")
         self.cfg = cfg
         self.n_slots = n_slots
         self.quant = quant
@@ -451,8 +457,11 @@ class PagedCachePool:
         self.alloc = PageAllocator(self.n_pages, self.page_size,
                                    prefix_cache=prefix_cache,
                                    telemetry=telemetry)
-        pool = init_paged_kv_pool(cfg, self.n_pages, self.page_size,
-                                  dtype=dtype, quant=quant)
+        fam = family(cfg)
+        self._slot_prefix = fam.slot_entry_prefix
+        pool = fam.init_paged_kv_pool(cfg, self.n_pages, self.page_size,
+                                      dtype=dtype, quant=quant,
+                                      n_slots=n_slots)
         # per-entry placement: K/V take the pool spec, scale arrays
         # (different rank) their own page-axis spec
         self.cache: Dict = {
@@ -484,6 +493,32 @@ class PagedCachePool:
         self.pages_installed = 0
 
     # ---------------------------------------------------------- geometry
+
+    @property
+    def pages(self) -> Dict:
+        """The entries of ``cache`` that are pool PAGES (what admission
+        reserves, what a page copy, export or install walks): all of
+        GPT-2's; a family with per-slot state beside them (window rings)
+        keeps that under names with its ``slot_entry_prefix``."""
+        if not any(n.startswith(self._slot_prefix) for n in self.cache):
+            return self.cache
+        return {n: a for n, a in self.cache.items()
+                if not n.startswith(self._slot_prefix)}
+
+    @pages.setter
+    def pages(self, new: Dict) -> None:
+        self.cache = new if new.keys() == self.cache.keys() \
+            else {**self.cache, **new}
+
+    @property
+    def kv_array(self):
+        """One page array of the pool: its dtype and page count."""
+        return next(iter(self.pages.values()))
+
+    def bytes_by_kind(self) -> Tuple[int, int]:
+        """``(bytes in pool pages, bytes in per-slot window state)``."""
+        paged = sum(a.nbytes for a in self.pages.values())
+        return paged, sum(a.nbytes for a in self.cache.values()) - paged
 
     @property
     def seq_len(self) -> int:
@@ -714,7 +749,7 @@ class PagedCachePool:
             "quant_granularity": gran,
             "bytes_per_page": page_bytes(self.cfg, self.page_size,
                                          kv_quant, gran),
-            "kv_quant_bits": 8 * self.cache["k"].dtype.itemsize,
+            "kv_quant_bits": 8 * self.kv_array.dtype.itemsize,
             "pages_in_use": a.pages_in_use,
             "pages_free": a.pages_free,
             "page_utilization": round(a.pages_in_use / self.n_pages, 4),

@@ -1,0 +1,662 @@
+"""The exaone_moe family (K-EXAONE-236B-A23B) against its plain reference,
+at test widths on the CPU: the whole-sequence forward, chunked prefill and
+decode through both kinds of KV state, the share of an expert-parallel
+deployment, routing and its near ties, the grouped-query kernel and its
+lower bound (interpret mode), the allocator, the precision the tolerance
+tells apart, and every refusal. GPT-2's pool, program and route are held
+to what they were.
+"""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from replicatinggpt_tpu import reference_exaone_moe as ref
+from replicatinggpt_tpu.config import get_config
+from replicatinggpt_tpu.models import exaone_moe as xm
+from replicatinggpt_tpu.models.families import family, serve_refusals
+from replicatinggpt_tpu.ops import paged_pallas
+from replicatinggpt_tpu.serve import Engine, EngineConfig
+from replicatinggpt_tpu.serve.pages import PagedCachePool, page_bytes
+from replicatinggpt_tpu.serve.requests import Request, SamplingParams
+
+#: float32 program against the float32 reference at test widths: rounding
+#: of different summation orders reads 1e-6; a wrong mask, position, page or
+#: expert moves a logit by 1e-2 and more. Test 7 holds that bfloat16 routing
+#: and 8-bit weights both land above it.
+LOGIT_TOL = 2e-4
+
+CFG = get_config("exaone-moe-tiny").model       # window 8, block 64
+PSZ = 8
+
+
+@pytest.fixture(scope="module")
+def params():
+    return xm.init_params(jax.random.PRNGKey(7), CFG)
+
+
+@pytest.fixture(autouse=True)
+def small_blocks(monkeypatch):
+    monkeypatch.setattr(xm, "FORWARD_BLOCK", 16)
+    monkeypatch.setattr(xm, "PREFILL_KV_BLOCK", 16)
+
+
+def _ids(seed, *shape):
+    return np.asarray(jax.random.randint(jax.random.PRNGKey(seed), shape, 0,
+                                         CFG.vocab_size), np.int32)
+
+
+def _ref_logits(params, seq, cfg=CFG, **kw):
+    # in blocks of rows where the length allows, whole otherwise
+    out, counts = ref.logits(params, jnp.asarray(seq), ref.spec_of(cfg),
+                             row_block=16 if len(seq) % 16 == 0 else 1024,
+                             **kw)
+    return np.asarray(out), counts
+
+
+# ------------------------------------------------------- 1. whole sequence
+
+def test_forward_matches_reference_past_the_window(params):
+    idx = _ids(1, 2, 48)                       # 6 windows long
+    got = np.asarray(xm.forward(params, jnp.asarray(idx), CFG))
+    for b in range(2):
+        want, _ = _ref_logits(params, idx[b])
+        assert np.abs(got[b] - want).max() < LOGIT_TOL
+
+
+# ------------------------------------- 2. chunked prefill, then paged decode
+
+def _serve_by_hand(params, cfg, prompts, n_new, use_pallas, chunk=8):
+    """Prefill each prompt in chunks into its own slot, then decode all
+    slots together teacher-forced with seeded tokens: logits (B, n_new, V)
+    at positions P-1 .. P+n_new-2 of each row, and the sequences."""
+    B = len(prompts)
+    mp = cfg.block_size // PSZ
+    cache = xm.init_paged_kv_pool(cfg, B * mp, PSZ, n_slots=B)
+    tables = np.arange(B * mp, dtype=np.int32).reshape(B, mp)
+    rng = np.random.default_rng(5)
+    perm = rng.permutation(B * mp).astype(np.int32)     # scattered pages
+    tables = perm[tables]
+    prefill = jax.jit(lambda *a: xm.prefill_chunk_paged(*a, cfg))
+    for b, p in enumerate(prompts):
+        n = -(-len(p) // chunk)
+        padded = np.zeros((n * chunk,), np.int32)
+        padded[:len(p)] = p
+        for c in range(n):
+            cache = prefill(params, jnp.asarray(padded[None, c * chunk:
+                                                       (c + 1) * chunk]),
+                            jnp.int32(c * chunk), jnp.int32(len(p)),
+                            jnp.asarray(tables[b]), jnp.int32(b), cache)
+    step = jax.jit(lambda *a: xm.decode_step_paged(
+        *a, cfg, use_pallas=use_pallas))
+    seqs = [list(p) for p in prompts]
+    pos = np.array([len(p) - 1 for p in prompts], np.int32)
+    tok = np.array([p[-1] for p in prompts], np.int32)
+    out = []
+    for t in range(n_new):
+        logits, cache, pairs = step(params, jnp.asarray(tok),
+                                    jnp.asarray(pos), jnp.ones((B,), bool),
+                                    jnp.asarray(tables), cache)
+        out.append(np.asarray(logits))
+        tok = rng.integers(0, cfg.vocab_size, (B,)).astype(np.int32)
+        for b in range(B):
+            seqs[b].append(int(tok[b]))
+        pos = pos + 1
+    return np.stack(out, 1), seqs, int(pairs)
+
+
+@pytest.mark.parametrize("window", [8, 12], ids=["window-1-page",
+                                                 "window-1.5-pages"])
+@pytest.mark.parametrize("use_pallas", [False, True], ids=["xla", "kernel"])
+def test_prefill_then_decode_matches_reference(params, use_pallas, window):
+    """Unequal slots in one batch, over page (8), chunk (8) and window
+    boundaries: one prompt inside its first window, one ending on a page
+    boundary, one several windows long."""
+    cfg = dataclasses.replace(CFG, sliding_window=window)
+    prompts = [_ids(11, 3), _ids(12, 16), _ids(13, 29)]
+    n_new = 20
+    got, seqs, pairs = _serve_by_hand(params, cfg, prompts, n_new,
+                                      use_pallas)
+    assert pairs >= 0
+    for b, p in enumerate(prompts):
+        want, _ = _ref_logits(params, np.asarray(seqs[b][:-1], np.int32),
+                              cfg)
+        rows = want[len(p) - 1:len(p) - 1 + n_new]
+        assert np.abs(got[b] - rows).max() < LOGIT_TOL, (b, use_pallas)
+
+
+def test_decode_window_is_the_step_repeated(params):
+    """``decode_window_paged`` (W steps in one program) emits what W single
+    steps emit, and its token block carries the held pairs in its last
+    column."""
+    B, mp = 2, CFG.block_size // PSZ
+    cache = xm.init_paged_kv_pool(CFG, B * mp, PSZ, n_slots=B)
+    tables = jnp.arange(B * mp, dtype=jnp.int32).reshape(B, mp)
+    tok, pos = jnp.asarray([5, 9], jnp.int32), jnp.zeros((B,), jnp.int32)
+    active = jnp.ones((B,), bool)
+    rngs = jnp.stack([jax.random.PRNGKey(i) for i in range(B)])
+    greedy = lambda r, logits, live: (jnp.argmax(logits, -1)
+                                      .astype(jnp.int32), r)
+    toks, emitted, *_ = xm.decode_window_paged(
+        params, tok, pos, active, jnp.full((B,), 9, jnp.int32),
+        jnp.full((B,), -1, jnp.int32), tables, cache, rngs, CFG,
+        sample_fn=greedy, length=4)
+    assert toks.shape == (4, B + 1) and bool(emitted.all())
+    c, t, p = cache, tok, pos
+    for s in range(4):
+        logits, c, pairs = xm.decode_step_paged(params, t, p, active,
+                                                tables, c, CFG)
+        t = jnp.argmax(logits, -1).astype(jnp.int32)
+        p = p + 1
+        assert np.array_equal(np.asarray(toks[s, :B]), np.asarray(t))
+        assert int(toks[s, B]) == int(pairs)
+
+
+# --------------------------------------------------------------- 3. the share
+
+def _one_sparse_layer(cfg, seed=3):
+    p = xm.init_params(jax.random.PRNGKey(seed), cfg)
+    return p["layers"][1]
+
+
+def test_eight_shares_and_the_shared_expert_once_add_up():
+    whole = dataclasses.replace(CFG, experts_held=tuple(range(8)))
+    lp = _one_sparse_layer(whole)
+    m = jax.random.normal(jax.random.PRNGKey(4), (12, CFG.n_embd))
+    y_whole, top, pairs = xm.moe(m, lp, whole)
+    assert int(pairs) == 12 * CFG.experts_per_token     # every pair is held
+    shared = xm._swiglu(m, lp["s_gate"], lp["s_up"], lp["s_down"])
+    total = shared
+    for e in range(8):
+        share = dataclasses.replace(CFG, experts_held=(e,))
+        lp_e = {**lp, **{n: lp[n][e:e + 1]
+                         for n in ("e_gate", "e_up", "e_down")}}
+        y_e, top_e, _ = xm.moe(m, lp_e, share)
+        assert np.array_equal(np.asarray(top_e), np.asarray(top))
+        total = total + (y_e - shared)      # its routed part alone
+    assert np.abs(np.asarray(total - y_whole)).max() < 1e-5
+
+
+def test_the_share_is_the_uncut_reference_layer_less_the_absent_experts():
+    """The reference, given the same share, leaves out the same part."""
+    whole = dataclasses.replace(CFG, experts_held=tuple(range(8)))
+    p = xm.init_params(jax.random.PRNGKey(3), whole)
+    idx = _ids(6, 24)
+    full, _ = _ref_logits(p, idx, whole)
+    cut = {**p, "layers": [
+        {n: (a[:2] if n.startswith("e_") else a) for n, a in lp.items()}
+        for lp in p["layers"]]}
+    want, _ = _ref_logits(cut, idx, CFG)
+    got = np.asarray(xm.forward(cut, jnp.asarray(idx[None]), CFG))[0]
+    assert np.abs(got - want).max() < LOGIT_TOL
+    assert np.abs(full - want).max() > 10 * LOGIT_TOL    # they DO differ
+
+
+def test_sliced_head_gives_the_uncut_heads_columns(params):
+    idx = _ids(8, 1, 16) % 48
+    cut_cfg = dataclasses.replace(CFG, vocab_size=48)
+    cut = {**params, "wte": params["wte"][:48],
+           "lm_head": params["lm_head"][:, :48]}
+    full = np.asarray(xm.forward(params, jnp.asarray(idx), CFG))
+    got = np.asarray(xm.forward(cut, jnp.asarray(idx), cut_cfg))
+    assert np.array_equal(got, full[..., :48])
+
+
+# ----------------------------------------------------------------- 4. routing
+
+def test_selection_by_s_plus_b_weights_from_s_alone(params):
+    lp = dict(params["layers"][1])
+    # expert 5 is pushed into every token's choice by its bias alone
+    lp["router_bias"] = jnp.zeros((8,)).at[5].set(10.0)
+    m = jax.random.normal(jax.random.PRNGKey(2), (9, CFG.n_embd))
+    w, top = xm.route(m, lp, CFG)
+    s = np.asarray(jax.nn.sigmoid(
+        jnp.dot(m, lp["router"], precision=jax.lax.Precision.HIGHEST)))
+    w, top = np.asarray(w), np.asarray(top)
+    for r in range(9):
+        chosen = set(top[r].tolist())
+        assert 5 in chosen and len(chosen) == CFG.experts_per_token
+        other = max((e for e in range(8) if e != 5), key=lambda e: s[r, e])
+        assert chosen == {5, other}
+        denom = sum(s[r, e] for e in chosen)
+        for e in range(8):
+            want = 2.5 * s[r, e] / denom if e in chosen else 0.0
+            assert abs(w[r, e] - want) < 1e-6       # the bias is not in w
+        assert abs(w[r].sum() - 2.5) < 1e-5         # normalised, times 2.5
+
+
+def test_near_ties_are_counted_inside_the_margin_and_never_taken(params):
+    """Choices from a program whose router was nudged: where the chosen
+    sets differ the reference counts a near tie inside the margin and a
+    mismatch beyond it, and routes by its own scores either way."""
+    idx = _ids(9, 40)
+    nudged = {**params, "layers": [
+        ({**lp, "router": lp["router"] + 2e-3 * jax.random.normal(
+            jax.random.PRNGKey(i), lp["router"].shape)}
+         if "router" in lp else lp)
+        for i, lp in enumerate(params["layers"])]}
+    _, theirs = xm.forward(nudged, jnp.asarray(idx[None]), CFG,
+                           return_routing=True)
+    _, own = xm.forward(params, jnp.asarray(idx[None]), CFG,
+                        return_routing=True)
+    differ = int((np.sort(np.asarray(theirs), -1)
+                  != np.sort(np.asarray(own), -1)).any(-1).sum())
+    assert differ > 0, "the nudge flipped nothing: no tie to test"
+    plain, _ = _ref_logits(params, idx)
+    strict, c0 = _ref_logits(params, idx, choices=theirs[:, 0], margin=0.0)
+    assert int(c0["near_ties"]) == 0 and int(c0["mismatches"]) >= differ
+    wide, c1 = _ref_logits(params, idx, choices=theirs[:, 0], margin=1.0)
+    assert int(c1["mismatches"]) == 0
+    assert int(c1["near_ties"]) == int(c0["mismatches"])
+    # its own routing whatever it was handed
+    assert np.array_equal(strict, plain) and np.array_equal(wide, plain)
+    # the program's own choices: the chosen sets are equal everywhere
+    _, c2 = _ref_logits(params, idx, choices=own[:, 0], margin=0.0)
+    assert int(c2["near_ties"]) == 0 and int(c2["mismatches"]) == 0
+
+
+# ------------------------------------------------------------------ 5. kernel
+
+def _kernel_case(seed, B, W, Hq, Hkv, D, psz, mp, pos, window, page0=None):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    N = B * mp
+    q = jax.random.normal(ks[0], (B, W, Hq * D))
+    kn = jax.random.normal(ks[1], (B, W, Hkv * D))
+    vn = jax.random.normal(ks[2], (B, W, Hkv * D))
+    kp = jax.random.normal(ks[3], (N, psz, Hkv * D))
+    vp = jax.random.normal(ks[4], (N, psz, Hkv * D))
+    tables = jnp.asarray(np.random.default_rng(seed).permutation(N)
+                         .astype(np.int32).reshape(B, mp))
+    return q, kn, vn, kp, vp, tables, jnp.asarray(pos, jnp.int32)
+
+
+def _einsum_attention(q, kn, vn, kp, vp, tables, pos, Hq, Hkv, window,
+                      page0):
+    """Scatter-then-attend in plain einsum: row j of slot b at pos + j
+    attends stale positions < pos of its table and fresh rows 0..j, all
+    above pos + j - window."""
+    B, W, _ = q.shape
+    psz = kp.shape[1]
+    mp = tables.shape[1]
+    D = kp.shape[-1] // Hkv
+    G = Hq // Hkv
+    out = np.zeros((B, W, Hq * D), np.float32)
+    for b in range(B):
+        p0 = 0 if page0 is None else int(page0[b])
+        kpos = p0 * psz + np.arange(mp * psz)
+        ks = np.concatenate([np.asarray(kp)[np.asarray(tables[b])]
+                             .reshape(mp * psz, Hkv, D),
+                             np.asarray(kn[b]).reshape(W, Hkv, D)])
+        vs = np.concatenate([np.asarray(vp)[np.asarray(tables[b])]
+                             .reshape(mp * psz, Hkv, D),
+                             np.asarray(vn[b]).reshape(W, Hkv, D)])
+        kpos = np.concatenate([kpos, int(pos[b]) + np.arange(W)])
+        stale = np.concatenate([np.ones(mp * psz, bool),
+                                np.zeros(W, bool)])
+        for j in range(W):
+            qp = int(pos[b]) + j
+            ok = np.where(stale, kpos < int(pos[b]), kpos <= qp)
+            if window:
+                ok &= kpos > qp - window
+            for n in range(Hq):
+                qv = np.asarray(q[b, j]).reshape(Hq, D)[n]
+                s = ks[:, n // G] @ qv * D ** -0.5
+                s = np.where(ok, s, -np.inf)
+                w = np.exp(s - s.max())
+                w /= w.sum()
+                out[b, j, n * D:(n + 1) * D] = w @ vs[:, n // G]
+    return out
+
+
+@pytest.mark.parametrize("W,window,pos", [
+    (1, 0, [0, 5, 37]),          # full layer: idle slot, mid page, deep
+    (1, 8, [3, 8, 41]),          # window = 1 page; inside the first window
+    (1, 12, [3, 20, 47]),        # window not a multiple of the page
+    (2, 12, [1, 16, 30]),        # two fresh rows under the band
+], ids=["full", "window-1-page", "window-1.5-pages", "two-rows"])
+def test_gqa_kernel_and_its_lower_bound_against_einsum(W, window, pos):
+    Hq, Hkv, D, psz, mp = 4, 2, 32, 8, 6
+    q, kn, vn, kp, vp, tables, pos = _kernel_case(3, 3, W, Hq, Hkv, D, psz,
+                                                  mp, pos, window)
+    got = paged_pallas.paged_gqa_attention(
+        q, kn, vn, kp, vp, tables, pos, n_head=Hq, n_kv_head=Hkv,
+        attn_window=window, name="swa_test" if window else "full_test")
+    want = _einsum_attention(q, kn, vn, kp, vp, tables, pos, Hq, Hkv,
+                             window, None)
+    assert np.abs(np.asarray(got) - want).max() < 1e-5
+
+
+def test_gqa_kernel_walks_a_ring_from_page0():
+    """A window layer's ring: the table's first entry is absolute page
+    ``page0`` of the slot, not page 0."""
+    Hq, Hkv, D, psz, mp, window = 4, 2, 32, 8, 3, 12
+    pos = [45, 18]
+    page0 = [(p - window + 1) // psz for p in pos]
+    q, kn, vn, kp, vp, tables, pos = _kernel_case(4, 2, 1, Hq, Hkv, D, psz,
+                                                  mp, pos, window)
+    got = paged_pallas.paged_gqa_attention(
+        q, kn, vn, kp, vp, tables, pos, n_head=Hq, n_kv_head=Hkv,
+        attn_window=window, page0=jnp.asarray(page0, jnp.int32),
+        name="swa_test")
+    want = _einsum_attention(q, kn, vn, kp, vp, tables, pos, Hq, Hkv,
+                             window, page0)
+    assert np.abs(np.asarray(got) - want).max() < 1e-5
+
+
+def test_pages_behind_the_window_are_not_owned():
+    owned = np.asarray(paged_pallas.gqa_owned_pages(
+        jnp.asarray([41, 3], jnp.int32), jnp.zeros((2,), jnp.int32), 8, 8,
+        12))
+    # pos 41, window 12: rows read 30..40 -> pages 3, 4, 5 (40 is in 5)
+    assert owned[0].tolist() == [False] * 3 + [True] * 3 + [False] * 2
+    assert owned[1].tolist() == [True] + [False] * 7
+
+
+# --------------------------------------------------------------- 6. allocator
+
+def _pool(n_slots=3, n_pages=0):
+    return PagedCachePool(CFG, n_slots, page_size=PSZ, n_pages=n_pages,
+                          prefix_cache=False)
+
+
+def test_window_state_does_not_grow_with_context():
+    pool = _pool()
+    ring = xm.ring_pages(CFG, PSZ)
+    assert ring * PSZ <= CFG.sliding_window + PSZ       # 128 + W to pages
+    for j in range(len(CFG.window_layers)):
+        assert pool.cache[f"wk{j}"].shape == (1, 3 * ring, PSZ,
+                                              CFG.kv_channels)
+    paged, window = pool.bytes_by_kind()
+    before = {n: a.shape for n, a in pool.cache.items()}
+    short = pool.acquire("a", _ids(1, 4), 4)
+    long_ = pool.acquire("b", _ids(2, 40), 20)
+    assert {n: a.shape for n, a in pool.cache.items()} == before
+    assert pool.bytes_by_kind() == (paged, window)
+    held = lambda adm: int((pool.tables[adm.slot] != 0).sum())
+    assert held(long_) > held(short)        # pages follow the context
+
+
+def test_a_finished_request_gives_back_both_kinds():
+    pool = _pool()
+    free0, slots0 = pool.alloc.pages_free, pool.n_free
+    adm = pool.acquire("a", _ids(1, 30), 10)
+    assert pool.alloc.pages_free == free0 - 5 and pool.n_free == slots0 - 1
+    pool.release(adm.slot)
+    assert pool.alloc.pages_free == free0 and pool.n_free == slots0
+    again = pool.acquire("b", _ids(2, 3), 2)        # the ring with the slot
+    assert again.slot == adm.slot
+
+
+def test_admission_counts_global_pages_only():
+    pool = _pool(n_slots=4, n_pages=8)      # one slot's worst case
+    assert pool.alloc.n_pages_for(30, 10) == 5
+    assert page_bytes(CFG, PSZ) == (len(CFG.paged_layers) * PSZ * 2
+                                    * CFG.kv_channels * 4)
+    assert pool.can_admit(_ids(1, 30), 10)          # 5 of 8 pages
+    pool.acquire("a", _ids(1, 30), 10)
+    assert not pool.can_admit(_ids(2, 30), 10)      # pages, not rings
+    assert pool.can_admit(_ids(3, 10), 6)           # 2 pages still fit
+
+
+# --------------------------------------------------------------- 7. precision
+
+def _worst(params, idx, cfg=CFG):
+    got = np.asarray(xm.forward(params, jnp.asarray(idx[None]), cfg))[0]
+    want, _ = _ref_logits(params, idx, cfg)
+    return np.abs(got - want).max()
+
+
+def test_tolerance_fails_under_bfloat16_routing(params, monkeypatch):
+    idx = _ids(21, 48)
+    assert _worst(params, idx) < LOGIT_TOL
+    exact = xm.route
+
+    def bf16_route(m, lp, cfg):
+        lp = {**lp, "router": lp["router"].astype(jnp.bfloat16)
+              .astype(jnp.float32)}
+        return exact(m.astype(jnp.bfloat16).astype(jnp.float32), lp, cfg)
+
+    monkeypatch.setattr(xm, "route", bf16_route)
+    assert _worst(params, idx) > LOGIT_TOL
+
+
+def test_tolerance_fails_under_8_bit_weights(params):
+    def to8(a):
+        if a.ndim < 2:
+            return a
+        scale = jnp.abs(a).max() / 127.0
+        return jnp.round(a / scale) * scale
+
+    idx = _ids(22, 48)
+    rounded = jax.tree_util.tree_map(to8, params)
+    got = np.asarray(xm.forward(rounded, jnp.asarray(idx[None]), CFG))[0]
+    want, _ = _ref_logits(params, idx)
+    assert np.abs(got - want).max() > LOGIT_TOL
+
+
+# ---------------------------------------------------------------- the engine
+
+@pytest.fixture()
+def kernel_on_cpu(monkeypatch):
+    monkeypatch.setattr(paged_pallas, "_paged_attn_backend_ok",
+                        lambda: True)
+
+
+ECFG = EngineConfig(pool_size=3, page_size=PSZ, prefill_chunk=16,
+                    paged_kernel=True, prefix_cache=False, max_queue=16)
+
+
+def test_engine_serves_the_family_through_submit_and_step(params,
+                                                          kernel_on_cpu):
+    eng = Engine(params, CFG, ECFG)
+    rng = np.random.default_rng(0)
+    reqs = [Request(id=f"r{i}", prompt=rng.integers(
+        0, CFG.vocab_size, (n,), dtype=np.int32), max_new_tokens=m,
+        sampling=SamplingParams(greedy=True))
+        for i, (n, m) in enumerate([(3, 12), (17, 9), (30, 20), (9, 5),
+                                    (24, 11)])]
+    for r in reqs:
+        assert eng.submit(r) is None
+    done = {r.id: r for r in eng.drain()}
+    assert len(done) == 5 and all(r.ok for r in done.values())
+    spec = ref.spec_of(CFG)
+    gaps, mean_gap, _ = ref.stream_gaps(
+        params, spec, CFG.block_size, [r.prompt for r in reqs],
+        [np.asarray(done[r.id].tokens, np.int32) for r in reqs],
+        row_block=16)
+    assert max(gaps) < LOGIT_TOL and mean_gap <= max(gaps), gaps
+    s = eng.metrics_summary()
+    assert s["kernel_route"]["route"] == "pallas"
+    assert s["kernel_route"]["reasons"] == []
+    assert s["kernel_route"]["decode"] == "pallas"
+    assert s["kernel_route"]["window"] == "none"    # no mixed, no verify
+    assert s["counters"]["moe_pairs_held"] > 0
+    paged, window = eng.pool.bytes_by_kind()
+    assert (s["kv_global_bytes"], s["kv_window_bytes"]) == (paged, window)
+    assert window == (len(CFG.window_layers) * 2 * 3
+                      * xm.ring_pages(CFG, PSZ) * PSZ * CFG.kv_channels * 4)
+    assert eng.pool.alloc.pages_free == eng.pool.n_pages
+
+
+def test_launch_stats_tell_the_kinds_of_state_apart(params, kernel_on_cpu):
+    eng = Engine(params, CFG, ECFG)
+    window, swa_token, experts = eng._launch_extra
+    assert window == CFG.sliding_window
+    assert swa_token == len(CFG.window_layers) * 2 * CFG.kv_channels * 4
+    held = sum(int(np.prod(lp[n].shape)) * 4 for lp in params["layers"]
+               for n in ("e_gate", "e_up", "e_down") if n in lp)
+    assert experts == held
+    assert eng._kv_token_bytes == (len(CFG.paged_layers) * 2
+                                   * CFG.kv_channels * 4)
+
+
+# ---------------------------------------------------------------- 8. refusals
+
+class _Drafter:
+    name, k, pool_size = "stub", 2, 3
+
+
+@pytest.mark.parametrize("change,drafter,word", [
+    (dict(decode_window=4), None, "mixed"),
+    (dict(), _Drafter(), "speculative"),
+    (dict(prefix_cache=True), None, "prefix_cache"),
+    (dict(mesh_model=2), None, "mesh"),
+    (dict(kv_quant="int8"), None, "quantised"),
+    (dict(weight_quant="int8"), None, "quantised"),
+], ids=["mixed-window", "verify", "prefix-cache", "mesh", "kv-quant",
+        "weight-quant"])
+def test_engine_refuses_what_the_family_lacks(params, change, drafter, word):
+    ecfg = dataclasses.replace(ECFG, **change)
+    assert any(word in r for r in serve_refusals(CFG, ecfg, drafter))
+    with pytest.raises(ValueError, match=word):
+        Engine(params, CFG, ecfg, drafter=drafter)
+
+
+@pytest.mark.parametrize("name", ["mixed_window_paged", "verify_step_paged"])
+def test_programs_the_family_lacks_refuse_by_name(name):
+    with pytest.raises(NotImplementedError, match="exaone_moe"):
+        getattr(family(CFG), name)()
+
+
+def test_the_pool_refuses_the_radix_cache_over_window_layers():
+    with pytest.raises(ValueError, match="prefix_cache"):
+        PagedCachePool(CFG, 2, page_size=PSZ, prefix_cache=True)
+
+
+def test_fused_kernel_and_quantised_pool_are_not_routed(params,
+                                                       kernel_on_cpu):
+    assert Engine(params, CFG, ECFG).kernel_route.decode == "pallas"
+    ok, why = paged_pallas.paged_attention_envelope(
+        4, 32, PSZ, n_kv_head=2, kv_quant="int8")
+    assert not ok and "gqa_kv_quant" in why
+
+
+@pytest.mark.parametrize("bad,word", [
+    (dict(n_kv_head=3), "group"),
+    (dict(layer_types=("full_attention",) * 3), "layer_types"),
+    (dict(experts_held=(9,)), "experts_held"),
+    (dict(tied_head=True), "untied"),
+    (dict(sliding_window=0), "window"),
+], ids=["kv-heads", "layer-types", "experts-held", "tied-head", "window"])
+def test_validate_names_what_is_wrong(bad, word):
+    with pytest.raises(AssertionError, match=word):
+        dataclasses.replace(CFG, **bad).validate()
+
+
+def test_gpt2_fields_are_refused_on_the_gpt_family():
+    gpt = get_config("test-tiny").model
+    with pytest.raises(AssertionError, match="n_kv_head"):
+        dataclasses.replace(gpt, n_kv_head=1).validate()
+
+
+# --------------------------------------------- GPT-2 is what it was (test 8)
+
+def test_gpt2_pool_program_names_and_route_unchanged(kernel_on_cpu):
+    from replicatinggpt_tpu.models import gpt
+    from replicatinggpt_tpu.serve import engine as E
+    cfg = dataclasses.replace(get_config("test-tiny").model, n_embd=64,
+                              decode_cache_layout="packed")
+    p = gpt.init_params(jax.random.PRNGKey(0), cfg)
+    eng = Engine(p, cfg, EngineConfig(pool_size=2, page_size=8,
+                                      paged_kernel=True))
+    assert set(eng.pool.cache) == {"k", "v"}
+    assert eng.pool.cache["k"].shape == (cfg.n_layer, eng.pool.n_pages, 8,
+                                         cfg.n_embd)
+    assert eng.pool.pages is eng.pool.cache and eng._launch_extra is None
+    assert eng.pool.bytes_by_kind()[1] == 0
+    r = eng.kernel_route.summary()
+    assert (r["route"], r["window"], r["reasons"]) == ("pallas", "pallas",
+                                                       [])
+    li = eng._launch_inputs()
+    state = [jnp.asarray(a) for a in (eng._tok, eng._pos, eng._active,
+                                      eng._budget)]
+    text = str(E._engine_decode_window.trace(
+        p, *state, li[0], eng._z_life, li[1], eng.pool.cache, eng._rngs,
+        *li[2:], cfg, k=1, use_pallas=True).jaxpr)
+    assert "paged_window_attention" in text and "swa_" not in text
+    assert E._engine_decode_window.__name__ == "_engine_decode_window"
+    assert family(cfg).step_counters == ()
+
+
+# ------------------------------------- more requests than slots, and kills
+
+def _drive(eng, reqs, hook=None):
+    for r in reqs:
+        assert eng.submit(r) is None
+    done, steps = {}, 0
+    while not eng.idle:
+        for r in eng.step():
+            done[r.id] = r
+        steps += 1
+        if hook is not None:
+            hook(eng, steps, done)
+        assert steps < 2000
+    return done
+
+
+def _requests(seed, sizes):
+    rng = np.random.default_rng(seed)
+    return [Request(id=f"q{i}", prompt=rng.integers(
+        0, CFG.vocab_size, (n,), dtype=np.int32), max_new_tokens=m,
+        sampling=SamplingParams(greedy=True))
+        for i, (n, m) in enumerate(sizes)]
+
+
+def test_a_reused_slot_emits_what_the_request_emits_alone(params,
+                                                          kernel_on_cpu):
+    """More requests than slots, so later ones take a slot, its pages and
+    its window rings after another request: token for token what each
+    emits alone in a fresh engine, and every page and slot comes back."""
+    sizes = [(5, 9), (17, 14), (30, 6), (9, 11), (24, 8), (3, 13), (12, 7)]
+    eng = Engine(params, CFG, ECFG)
+    got = _drive(eng, _requests(3, sizes))
+    assert all(got[f"q{i}"].ok and len(got[f"q{i}"].tokens) == m
+               for i, (_, m) in enumerate(sizes))
+    alone = Engine(params, CFG, ECFG)
+    for r in _requests(3, sizes)[3:]:          # those that waited for a slot
+        assert _drive(alone, [r])[r.id].tokens == got[r.id].tokens
+    assert eng.pool.alloc.pages_free == eng.pool.n_pages
+    assert eng.pool.n_free == ECFG.pool_size
+    assert eng.metrics.counters["decode_tokens"] == sum(
+        len(r.tokens) for r in got.values())
+
+
+def test_cancel_and_deadline_give_back_both_kinds_of_state(params,
+                                                           kernel_on_cpu):
+    clock = [0.0]
+    eng = Engine(params, CFG, ECFG, clock=lambda: clock[0])
+    reqs = _requests(4, [(6, 40), (11, 40), (20, 40)])
+    reqs[2] = dataclasses.replace(reqs[2], deadline=5.0)
+
+    def hook(e, n, done):
+        if n == 6:
+            assert e.cancel("q0")
+        if n == 10:
+            clock[0] = 10.0                    # q2's deadline passes
+
+    done = _drive(eng, reqs, hook)
+    assert done["q0"].finish_reason == "cancelled" and done["q0"].tokens
+    assert done["q2"].finish_reason == "deadline"
+    assert done["q1"].ok and len(done["q1"].tokens) == 40
+    assert eng.pool.alloc.pages_free == eng.pool.n_pages
+    assert eng.pool.n_free == ECFG.pool_size
+
+
+def test_the_route_asks_the_family_and_names_no_family():
+    """``decide_kernel_route`` reads the fused kernel's and the windowed
+    steps' fitness off ``family(cfg)``: GPT-2 answers both, this family
+    has no windowed step (None) and no fused kernel."""
+    import inspect
+    from replicatinggpt_tpu.serve import engine as E
+    assert "cfg.family" not in inspect.getsource(E.decide_kernel_route)
+    qcfg = EngineConfig().quant()
+    fam = family(CFG)
+    assert fam.window_kernel_ok(CFG, PSZ, 16, 4, None, qcfg) is None
+    assert fam.fused_decode_ok(CFG, 3, PSZ, 4, None, qcfg) is False
+    gcfg = dataclasses.replace(get_config("test-tiny").model, n_embd=64,
+                               decode_cache_layout="packed")
+    assert family(gcfg).window_kernel_ok(gcfg, 8, 16, 4, None,
+                                         qcfg) in (True, False)

@@ -131,6 +131,28 @@ def test_paged_window_attention_124m(mosaic, one_chip, window, quant):
         _compile(lambda *a: paged_window_attention(*a, n_head=12), *args)
 
 
+@pytest.mark.parametrize("window,name", [
+    (0, "paged_window_attention"), (128, "swa_window_attention")],
+    ids=["full-layer", "window-layer-ring"])
+def test_paged_gqa_attention_kexaone_widths(mosaic, one_chip, window, name):
+    """The grouped-query kernel at K-EXAONE's published widths (64 query
+    heads on 8 KV heads of 128, pages of 16) for the described v5e: a full
+    layer over a slot's 512-entry table, a window layer over its 9-page
+    ring from ``page0``; the instruction wears the ``name=`` it was given."""
+    from replicatinggpt_tpu.ops.paged_pallas import paged_gqa_attention
+    B, psz, mp = 64, 16, (9 if window else 512)
+    q = _s((B, 1, 64 * 128), BF16, one_chip)
+    kv = _s((B, 1, 8 * 128), BF16, one_chip)
+    pages = _s((B * mp if window else 10240, psz, 8 * 128), BF16, one_chip)
+    vec = _s((B,), jnp.int32, one_chip)
+    text = _compile(
+        lambda q, k, v, kp, vp, t, p, p0: paged_gqa_attention(
+            q, k, v, kp, vp, t, p, n_head=64, n_kv_head=8,
+            attn_window=window, page0=p0 if window else None, name=name),
+        q, kv, kv, pages, pages, _s((B, mp), jnp.int32, one_chip), vec, vec)
+    assert re.search(rf"%{name}[\w.]* = [^\n]*tpu_custom_call", text)
+
+
 def test_sharded_paged_window_attention_2x2(mosaic, mesh2x2):
     from replicatinggpt_tpu.ops.paged_pallas import (
         sharded_paged_window_attention)
