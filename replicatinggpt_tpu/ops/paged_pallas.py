@@ -1,28 +1,41 @@
-"""Paged decode attention: one Pallas kernel whose scalar-prefetched
-page table streams ONLY a slot's mapped pages.
+"""Paged decode attention: one Pallas kernel family whose scalar-
+prefetched page table streams ONLY a slot's mapped pages, a BLOCK of
+pages a grid step.
 
 The XLA paged decode path (models/gpt.py decode_step_paged) gathers
 every slot's full (max_pages, page, C) view each layer each step —
 simple and parity-exact, but it fetches max_pages pages per slot
 regardless of how short the slot's sequence actually is. This kernel
-puts the page table in scalar-prefetch SMEM and lets the BLOCK INDEX
-MAP translate (slot, logical page) -> physical page right before the
-DMA: grid (B, max_pages), page minor, and logical pages past the slot's
-live frontier map to the SAME physical page as the previous grid step —
-Pallas skips the re-fetch for a repeated block index (the exact trick
-the streamed flash kernels' triangular tile map uses for fully-masked
-tiles), so a slot at position p streams ceil(p/page) pages, not
-max_pages. Accumulation is online softmax across page steps (f32
-running max / denominator per head in VMEM scratch); the fresh K/V
-column rides separately and folds in at the final page step, so the
-kernel attends the STALE pool bit-equivalently to write-then-attend
-(cache[pos] would hold exactly the fresh k/v) — the caller scatters the
-fresh row afterwards, mirroring ops/decode_pallas.py's packed kernel.
+puts the page table in scalar-prefetch SMEM, leaves the pool in HBM and
+FETCHES FOR ITSELF: a grid step covers ``P`` consecutive logical pages
+of a slot (``block_pages``: ``P * page`` >= 128 tokens, 8 pages of 16),
+grid (B, ceil(max_pages / P)), block minor, and copies the block's
+pages, scattered in the pool, into one half of a (2, P * page, C) VMEM
+double buffer (``_fetch_block``), so the body reads them as ONE tile:
+each head's pass runs once over the block's columns, not once a page.
+A grid step costs its turn whether or not it has work (0.12 us on a
+v5e with a page a step, 221k of them a gpt2-large launch; more for
+every operand the pipeline has to look at), so the walk is as short as
+the tile allows and only three small rows ride the pipeline. Logical
+pages past the slot's live frontier (or behind a window's lower bound,
+or on another shard) are NOT OWNED: they are not fetched and their
+columns are masked, a block with no owned page does nothing, and the
+live blocks send for one another's pages a step ahead, across slots
+(``_blocked_walk``), so a slot at position p streams ceil(p/page)
+pages, not max_pages. Accumulation is online softmax across block
+steps (f32 running max / denominator / accumulator in VMEM scratch);
+the fresh K/V window rides separately and folds in at the final block
+step, so the kernel attends the STALE pool bit-equivalently to
+write-then-attend (cache[pos] would hold exactly the fresh k/v) — the
+caller scatters the fresh row afterwards, mirroring
+ops/decode_pallas.py's packed kernel.
 
-Packed (page, C) layout only: heads are static D-wide lane slices of
-the fully-packed row (no D-minor tile padding in the stream). Gated to
-TPU (`_paged_attn_backend_ok`, monkeypatched by tests to exercise the
-interpreter on CPU) and to shapes inside `paged_decode_supported`.
+Packed (page, C) layout only: heads are static lane slices of the
+fully-packed row (no D-minor tile padding in the stream), taken as
+many to a 128-lane slab as divide the head count (``_heads_per_slab``).
+Gated to TPU (`_paged_attn_backend_ok`, monkeypatched by tests to
+exercise the interpreter on CPU) and to shapes inside
+`paged_decode_supported`.
 """
 
 from __future__ import annotations
@@ -37,10 +50,17 @@ from jax.experimental import pallas as pl
 from .flash_pallas import (LANES, NEG_INF, _compiler_params,
                            _interpret_mode, _vmem_spec, pltpu)
 
-# VMEM budget: one (page, C) K and V block per grid step, double-
-# buffered, plus the (1, C) rows and f32 accumulators. 4 MiB covers
-# C=768 pages of 1024 tokens bf16 with margin.
+# VMEM budget: the K and the V tile of ONE BLOCK of pages (P x (page, C)
+# each; the double buffer holds two of each), beside the (W, C) rows and
+# the f32 accumulators. ``block_pages`` keeps a block inside it and the
+# envelope refuses a page that alone is over it. 4 MiB: a block of 8
+# gpt2-large pages of 16 tokens bf16 takes 0.64 MiB, and one C=768 page
+# of 1024 tokens (a block of one) 3 MiB.
 PAGED_DECODE_BYTES = 4 * 1024 * 1024
+
+# a grid step walks at least this many tokens of a slot (table and
+# budget allowing): the lane width of a score tile
+BLOCK_TOKENS = LANES
 
 
 def _paged_attn_backend_ok() -> bool:
@@ -102,14 +122,16 @@ def mixed_step_kernel_ok(n_head: int, head_dim: int, page_size: int,
 
 
 def clamped_live_page(p, pos, page_size: int):
-    """The fetch-skip trick, shared by every paged block index map
-    (this file's per-layer kernel and the fused all-layers kernel in
-    ops/decode_pallas.py): logical pages past a slot's live frontier
-    map to the SAME logical page as the previous grid step, and Pallas
-    skips the DMA for a repeated block index — so a slot at position
-    ``pos`` streams ceil(pos/page) pages regardless of max_pages. An
-    idle slot (pos == 0) clamps to page 0; its zero live pages are
-    never read (the accumulation loop is gated on ``p < live``)."""
+    """The fetch-skip trick for a walk of ONE page a grid step through
+    a block index map (the fused all-layers kernel in
+    ops/decode_pallas.py; this file's kernels walk a block of pages a
+    step and copy the owned ones themselves, ``_fetch_block``): logical
+    pages past a slot's live frontier map to the SAME logical page as
+    the previous grid step, and Pallas skips the DMA for a repeated
+    block index — so a slot at position ``pos`` streams ceil(pos/page)
+    pages regardless of max_pages. An idle slot (pos == 0) clamps to
+    page 0; its zero live pages are never read (the accumulation loop
+    is gated on ``p < live``)."""
     live = (pos + page_size - 1) // page_size
     return jnp.where(p < live, p, jnp.maximum(live - 1, 0))
 
@@ -159,8 +181,8 @@ def paged_attention_envelope(n_head: int, head_dim: int, page_size: int,
     if page_size % 8 != 0:
         reasons.append("page_align")
     C = n_kv_head * head_dim          # a page's row is KV heads wide
-    if 2 * page_size * C * itemsize > PAGED_DECODE_BYTES:
-        reasons.append("vmem_budget")
+    if not block_pages(page_size, 1, C * itemsize):
+        reasons.append("vmem_budget")     # a block of ONE page is over it
     return (not reasons), tuple(reasons)
 
 
@@ -177,141 +199,283 @@ def paged_decode_supported(n_head: int, head_dim: int, page_size: int,
     return ok
 
 
-def _fill_last_owned(phys: jnp.ndarray, owned: jnp.ndarray) -> jnp.ndarray:
-    """Localize a page table for the kernel's fetch-skip contract:
-    positions the kernel must not read (``~owned``) repeat the LAST
-    owned physical index to their left (a repeated block index skips
-    the DMA — the generalization of ``clamped_live_page`` to the
-    sharded case, where a shard's owned pages can be any subset of the
-    logical walk, not just a prefix). Slots with no owned page at all
-    clamp to physical 0 (never accumulated — the kernel gates on the
-    owned mask)."""
-    marked = jnp.where(owned, phys, -1)
-    filled = jax.lax.associative_scan(
-        lambda a, b: jnp.where(b >= 0, b, a), marked, axis=1)
-    return jnp.maximum(filled, 0).astype(jnp.int32)
+def block_pages(page_size: int, n_table: int, row_bytes: int) -> int:
+    """``P``: how many consecutive logical pages of a slot one grid step
+    covers. Enough for ``BLOCK_TOKENS`` tokens, no more than the table
+    has, and no more than keeps the block's K and V tiles (``row_bytes``
+    a token each) inside ``PAGED_DECODE_BYTES``; 0 where one page alone
+    is over it. Follows from shapes: nothing configures it."""
+    want = -(-BLOCK_TOKENS // page_size)
+    fit = PAGED_DECODE_BYTES // (2 * page_size * row_bytes)
+    return min(want, n_table, fit)
 
 
-def effective_tables(tables: jnp.ndarray, pos: jnp.ndarray,
-                     page_size: int) -> tuple:
-    """(effective table, owned mask) for the UNSHARDED kernel call:
-    owned = the prefix of pages holding positions < pos, effective
-    table = ``clamped_live_page`` materialized host^Wtrace-side so the
-    kernel's index map is a plain (B, max_pages) lookup shared with the
-    sharded wrapper's localized tables."""
-    mp = tables.shape[1]
-    live = (pos + page_size - 1) // page_size
-    p_idx = jnp.arange(mp, dtype=jnp.int32)[None, :]
-    owned = p_idx < live[:, None]
-    return (_fill_last_owned(jnp.asarray(tables, jnp.int32), owned),
-            owned)
+def live_blocks(pos, page_size: int, n_block: int):
+    """Blocks of ``n_block`` pages that hold a position < ``pos``, a
+    slot: the grid steps of the unsharded walk that do work (numpy or
+    jnp; the engine's ``kv_blocks_live`` sums it over the live slots)."""
+    return -(-pos // (page_size * n_block))
 
 
-def _paged_window_kernel(tables_ref, pos_ref, owned_ref, q_ref, knew_ref,
-                         vnew_ref, kp_ref, vp_ref, *rest, n_head,
-                         head_dim, page_size, n_pages_per_slot, window,
-                         scale, quantized, head_gran, fold):
+def _blocked_walk(tables: jnp.ndarray, owned: jnp.ndarray, page_size: int,
+                  row_bytes: int) -> tuple:
+    """The walk both kernels make, from a (B, max_pages) table and the
+    mask of the entries a slot's rows read: ``(P, n_blocks, prefetch)``,
+    ``prefetch`` the scalar operands ``_fetch_block`` reads.
+
+    Grid step ``t = b * n_blocks + p`` covers logical pages p*P .. p*P +
+    P - 1 of slot b. The table and the mask are padded to whole blocks
+    with unowned entries (a length P does not divide ends in a short
+    block). A step is LIVE if its block holds an owned page; the live
+    steps fetch for one another, so each carries its rank among them
+    (its parity picks the half of the double buffer) and the next live
+    step (-1: none), which may be another slot's."""
+    B, mp = tables.shape
+    P = block_pages(page_size, mp, row_bytes)
+    nb = -(-mp // P)
+    pad = ((0, 0), (0, nb * P - mp))
+    owned = jnp.pad(owned, pad)
+    table = jnp.where(owned, jnp.pad(jnp.asarray(tables, jnp.int32), pad), 0)
+    live = owned.reshape(B * nb, P).any(axis=1)
+    step = jnp.arange(B * nb, dtype=jnp.int32)
+    later = jax.lax.cummin(jnp.where(live, step, B * nb), reverse=True)
+    nxt = jnp.concatenate([later[1:], jnp.full((1,), B * nb, jnp.int32)])
+    return P, nb, (table, owned.astype(jnp.int32), live.astype(jnp.int32),
+                   jnp.cumsum(live, dtype=jnp.int32) - 1,
+                   jnp.where(nxt < B * nb, nxt, -1))
+
+
+def _heads_per_slab(n_head: int, head_dim: int) -> int:
+    """Heads taken together in one lane slab of the packed row: as many
+    as fill 128 lanes and divide the head count (2 of gpt2's 64-wide
+    heads; 1 where the count is odd or a head is 128 wide or more)."""
+    return max(h for h in range(1, max(LANES // head_dim, 1) + 1)
+               if n_head % h == 0)
+
+
+# -- what a block step does, shared by both kernels --------------------------
+
+def _fetch_block(walk, pools, bufs, sem, n_block: int, n_blocks: int,
+                 page_size: int):
+    """This grid step's block, fetched by the kernel itself: ``(live,
+    half, cols)``.
+
+    ``pools`` are the pool's arrays left in HBM, ``bufs`` their (2, P *
+    page, width) VMEM halves. A live step finds its owned pages already
+    on their way into half ``rank % 2`` (the live step before it sent
+    for them; the first sends for its own), sends for the NEXT live
+    step's into the other half, then waits for its own: the copies of
+    block t + 1 run under the arithmetic of block t, across slots.
+    Unowned pages are not fetched, and ``cols()`` masks their columns:
+    what a half holds there is an older page or the zeros of the
+    call's first step. A step that is not live reads three scalars
+    here and copies nothing."""
+    table_ref, owned_ref, live_ref, rank_ref, next_ref = walk
+    P, psz = n_block, page_size
+    b0, p0 = pl.program_id(0), pl.program_id(1)
+    t = b0 * n_blocks + p0
+
+    @pl.when(t == 0)
+    def _finite():
+        for buf in bufs:
+            buf[...] = jnp.zeros_like(buf)
+
+    def copies(step, half, act):
+        b, p = step // n_blocks, step % n_blocks
+
+        def page(j, _):
+            @pl.when(owned_ref[b, p * P + j] > 0)
+            def _owned():
+                for a, (pool, buf) in enumerate(zip(pools, bufs)):
+                    act(pltpu.make_async_copy(
+                        pool.at[table_ref[b, p * P + j]],
+                        buf.at[half, pl.ds(pl.multiple_of(j * psz, psz),
+                                           psz)],
+                        sem.at[half, a]))
+
+        # a loop, not P copies of the body: the program is traced and
+        # lowered at every process start, and these are most of its size
+        jax.lax.fori_loop(0, P, page, None)
+
+    live = live_ref[t] > 0
+    half = rank_ref[t] % 2
+
+    @pl.when(live)
+    def _fetch():
+        @pl.when(rank_ref[t] == 0)
+        def _first():
+            copies(t, half, lambda c: c.start())
+
+        @pl.when(next_ref[t] >= 0)
+        def _ahead():
+            copies(next_ref[t], 1 - half, lambda c: c.start())
+
+        copies(t, half, lambda c: c.wait())
+
+    def cols():
+        page = jax.lax.broadcasted_iota(jnp.int32, (1, P * psz), 1) // psz
+        own = jnp.zeros_like(page)
+        for j in range(P):
+            own = jnp.where(page == j, owned_ref[b0, p0 * P + j], own)
+        return own > 0
+
+    return live, half, cols
+
+
+def _scores(q, k, mask, scale):
+    """(R, T) float32 scores of q rows (R, L) on k rows (T, L), NEG_INF
+    where ``mask`` is off. Operands of one dtype go to the MXU as they
+    are (bf16 products are exact in the float32 accumulator)."""
+    if q.dtype != k.dtype:
+        q, k = q.astype(jnp.float32), k.astype(jnp.float32)
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    return jnp.where(mask, s, NEG_INF)
+
+
+def _online_update(s, v, acc_ref, m_ref, l_ref, i: int):
+    """One online-softmax step of state ``i``: fold masked scores ``s``
+    (R, T) and values ``v`` (T, L) into acc (R, L) and the running max
+    and denominator (R, LANES; every lane the same)."""
+    m_prev = m_ref[i]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    # masked columns contribute EXACTLY zero (not exp(0)): with a
+    # fully-masked row m_new stays NEG_INF and s - m_new == 0
+    pexp = jnp.where(s > NEG_INF / 2, jnp.exp(s - m_new[:, :1]), 0.0)
+    l_ref[i] = l_ref[i] * alpha + jnp.sum(pexp, axis=1, keepdims=True)
+    acc_ref[i] = (acc_ref[i] * alpha[:, :1]
+                  + jax.lax.dot_general(
+                      pexp, v.astype(jnp.float32),
+                      (((1,), (0,)), ((), ())),
+                      preferred_element_type=jnp.float32))
+    m_ref[i] = m_new
+
+
+def _paged_window_kernel(*refs, n_head, head_dim, page_size, n_block,
+                         n_blocks, window, scale, quantized, head_gran,
+                         fold):
     """ONE kernel body for the whole paged-attention family.
 
     W = ``window`` query rows per slot (W=1 is plain decode; W>1 is the
     mixed prefill+decode / speculative-verify step, where row j sits at
-    logical position pos+j). Stale pool pages accumulate online-softmax
-    gated on the scalar-prefetched OWNED mask (per-slot page prefix
-    unsharded; an arbitrary owned subset under the shard_map wrapper),
-    masked to positions < pos — identical for every query row, since
-    rows 0..W-1 attend the fresh window via the causal fold. Quantized
-    pools stream (psz, 1) page-granularity or (psz, H) head-granularity
-    scale blocks through the same fetch-skip index map; the per-head
-    lane column dequants in the accumulation loop (int8 AND fp8 — the
-    e4m3 block ``astype``s to f32 like any other storage dtype).
+    logical position pos+j). A grid step holds a BLOCK of ``n_block``
+    stale pool pages as one (n_block * page, C) tile (``_fetch_block``).
+    Blocks accumulate online-softmax gated on the scalar-prefetched
+    OWNED mask (per-slot page prefix unsharded; an arbitrary owned
+    subset under the shard_map wrapper), their columns masked page by
+    page by it and to positions < pos — identical for every query row,
+    since rows 0..W-1 attend the fresh window via the causal fold.
+    Heads go ``_heads_per_slab`` to a lane slab: a slab's heads stack
+    their W rows into one (heads * W, slab) query block, each head's
+    rows zero outside its own lanes, so ONE product scores the slab's
+    heads over the block's columns and one more weighs its values; of a
+    row's accumulator only its own head's lanes are ever read.
+    Quantized pools bring the block's (P * page, 1) page-granularity or
+    (P * page, H) head-granularity scales as one more row operand; the
+    per-head column dequants the tile before the product (int8 AND fp8
+    — the e4m3 block ``astype``s to f32 like any other storage dtype).
 
-    ``fold=True`` folds the fresh causal (W, W) block per head at the
-    last page step and writes normalized output; ``fold=False`` emits
-    the raw (acc, m, l) partials instead — the shard_map wrapper merges
-    them across the 'data' axis (pmax/psum softmax merge) and folds the
+    ``fold=True`` folds the fresh causal (W, W) block at the last block
+    step and writes normalized output; ``fold=False`` emits the raw
+    (acc, m, l) partials instead — the shard_map wrapper merges them
+    across the 'data' axis (pmax/psum softmax merge) and folds the
     fresh window outside, where the collective lives."""
+    walk = refs[:5]
+    pos_ref, q_ref, knew_ref, vnew_ref, *refs = refs[5:]
     if quantized:
-        ksp_ref, vsp_ref, *rest = rest
-    if fold:
-        out_ref, acc_ref, m_ref, l_ref = rest
-    else:
-        accout_ref, mout_ref, lout_ref, acc_ref, m_ref, l_ref = rest
+        ksc_ref, vsc_ref, *refs = refs
+    k_hbm, v_hbm, *refs = refs
+    *outs, qs_ref, acc_ref, m_ref, l_ref, k_buf, v_buf, sem = refs
+    pools, bufs = (k_hbm, v_hbm), (k_buf, v_buf)
     b = pl.program_id(0)
     p = pl.program_id(1)
-    D, psz, W = head_dim, page_size, window
+    D, psz, W, P = head_dim, page_size, window, n_block
+    hps = _heads_per_slab(n_head, D)
+    SL = hps * D                              # lanes of a slab
+    slabs = [slice(i * SL, (i + 1) * SL) for i in range(n_head // hps)]
     pos = pos_ref[b]
+    # one dtype on both sides: the MXU takes the stored bf16 as it is
+    native = not quantized and q_ref.dtype == bufs[0].dtype
+    q_dtype = q_ref.dtype if native else jnp.float32
+
+    def lane_head(rows):                      # the head a lane belongs to
+        return jax.lax.broadcasted_iota(jnp.int32, (rows, SL), 1) // D
 
     @pl.when(p == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
+        for i, sl in enumerate(slabs):
+            q = q_ref[:, sl].astype(jnp.float32)                 # (W, SL)
+            for r in range(hps):
+                qs_ref[i, r * W:(r + 1) * W, :] = (
+                    q if hps == 1 else
+                    jnp.where(lane_head(W) == r, q, 0.0))
 
-    @pl.when(owned_ref[b, p] > 0)
+    live, half, owned_cols = _fetch_block(walk, pools, bufs, sem, P,
+                                          n_blocks, psz)
+
+    @pl.when(live)
     def _accumulate():
-        kpos = jax.lax.broadcasted_iota(jnp.int32, (1, psz), 1) + p * psz
-        if quantized:
-            ksc = ksp_ref[...]           # (psz, 1) page / (psz, H) head
-            vsc = vsp_ref[...]
-        for i in range(n_head):
-            sl = slice(i * D, (i + 1) * D)
-            q = q_ref[:, sl].astype(jnp.float32)                 # (W, D)
-            kcf = kp_ref[:, sl].astype(jnp.float32)              # (psz, D)
-            vcf = vp_ref[:, sl].astype(jnp.float32)
+        kpos = (jax.lax.broadcasted_iota(jnp.int32, (1, P * psz), 1)
+                + p * (P * psz))
+        mask = owned_cols() & (kpos < pos)                   # (1, P * psz)
+        if quantized:                    # (P * psz, 1) page / (P * psz, H)
+            ksc, vsc = ksc_ref[...], vsc_ref[...]
+        for i, sl in enumerate(slabs):
+            k, v = bufs[0][half, :, sl], bufs[1][half, :, sl]
             if quantized:
-                kcf = kcf * (ksc[:, i:i + 1] if head_gran else ksc)
-                vcf = vcf * (vsc[:, i:i + 1] if head_gran else vsc)
-            s = jax.lax.dot_general(
-                q, kcf, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale      # (W, psz)
-            s = jnp.where(kpos < pos, s, NEG_INF)
-            m_prev = m_ref[:, i:i + 1]                           # (W, 1)
-            m_new = jnp.maximum(m_prev,
-                                jnp.max(s, axis=1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            # masked rows contribute EXACTLY zero (not exp(0)): with a
-            # fully-masked page m_new stays NEG_INF and s - m_new == 0
-            pexp = jnp.where(s > NEG_INF / 2, jnp.exp(s - m_new), 0.0)
-            l_ref[:, i:i + 1] = (l_ref[:, i:i + 1] * alpha
-                                 + jnp.sum(pexp, axis=1, keepdims=True))
-            acc_ref[:, sl] = (acc_ref[:, sl] * alpha
-                              + jax.lax.dot_general(
-                                  pexp, vcf, (((1,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.float32))
-            m_ref[:, i:i + 1] = m_new
+                def col(sc):             # a lane's own head's scale
+                    if not head_gran:
+                        return sc
+                    return functools.reduce(
+                        lambda a, r: jnp.where(
+                            lane_head(P * psz) == r,
+                            sc[:, i * hps + r:i * hps + r + 1], a),
+                        range(1, hps), sc[:, i * hps:i * hps + 1])
+                k = k.astype(jnp.float32) * col(ksc)
+                v = v.astype(jnp.float32) * col(vsc)
+            s = _scores(qs_ref[i].astype(q_dtype), k, mask, scale)
+            _online_update(s, v, acc_ref, m_ref, l_ref, i)
 
-    @pl.when(p == n_pages_per_slot - 1)
+    @pl.when(p == n_blocks - 1)
     def _finalize():
-        if not fold:
-            accout_ref[...] = acc_ref[...]
-            mout_ref[...] = m_ref[...]
-            lout_ref[...] = l_ref[...]
-            return
-        row = jax.lax.broadcasted_iota(jnp.int32, (W, W), 0)
-        col = jax.lax.broadcasted_iota(jnp.int32, (W, W), 1)
-        causal = col <= row            # fresh row j attends rows 0..j
-        for i in range(n_head):
-            sl = slice(i * D, (i + 1) * D)
-            q = q_ref[:, sl].astype(jnp.float32)
-            kn = knew_ref[:, sl].astype(jnp.float32)
-            vn = vnew_ref[:, sl].astype(jnp.float32)
-            s_new = jax.lax.dot_general(
-                q, kn, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale      # (W, W)
-            s_new = jnp.where(causal, s_new, NEG_INF)
-            m_prev = m_ref[:, i:i + 1]
-            m2 = jnp.maximum(m_prev,
-                             jnp.max(s_new, axis=1, keepdims=True))
-            alpha = jnp.exp(m_prev - m2)
-            p_new = jnp.where(causal, jnp.exp(s_new - m2), 0.0)
-            # denom >= diagonal term > 0 always (row j attends itself)
-            denom = (l_ref[:, i:i + 1] * alpha
-                     + jnp.sum(p_new, axis=1, keepdims=True))
-            out = (acc_ref[:, sl] * alpha
-                   + jax.lax.dot_general(
-                       p_new, vn, (((1,), (0,)), ((), ())),
-                       preferred_element_type=jnp.float32)) / denom
-            out_ref[:, sl] = out.astype(out_ref.dtype)
+        R = hps * W
+        row_j = jax.lax.broadcasted_iota(jnp.int32, (R, W), 0) % W
+        col = jax.lax.broadcasted_iota(jnp.int32, (R, W), 1)
+        causal = col <= row_j          # fresh row j attends rows 0..j
+        for i, sl in enumerate(slabs):
+            if fold:
+                # denominator >= the diagonal term > 0 always (row j
+                # attends itself)
+                s_new = _scores(qs_ref[i], knew_ref[:, sl], causal, scale)
+                _online_update(s_new, vnew_ref[:, sl], acc_ref, m_ref,
+                               l_ref, i)
+            for r in range(hps):       # a head's rows, its own lanes
+                h = i * hps + r
+                rows, lanes = slice(r * W, (r + 1) * W), slice(h * D,
+                                                               (h + 1) * D)
+                acc = acc_ref[i, rows, r * D:(r + 1) * D]
+                if fold:
+                    outs[0][:, lanes] = (acc / l_ref[i, rows, :1]).astype(
+                        outs[0].dtype)
+                else:
+                    outs[0][:, lanes] = acc
+                    outs[1][:, h:h + 1] = m_ref[i, rows, h:h + 1]
+                    outs[2][:, h:h + 1] = l_ref[i, rows, h:h + 1]
+
+
+def _pool_operands(arrays, n_block: int) -> tuple:
+    """``(in_specs, scratch_shapes)`` for pool arrays (n_pages, page,
+    width) the kernel fetches itself: left in HBM, a (2, P * page,
+    width) VMEM double buffer each, and a DMA semaphore a half an
+    array."""
+    return ([pl.BlockSpec(memory_space=pltpu.HBM)] * len(arrays),
+            [pltpu.VMEM((2, n_block * a.shape[1], a.shape[2]), a.dtype)
+             for a in arrays]
+            + [pltpu.SemaphoreType.DMA((2, len(arrays)))])
 
 
 def paged_window_attention(q: jnp.ndarray, k_new: jnp.ndarray,
@@ -341,47 +505,48 @@ def paged_window_attention(q: jnp.ndarray, k_new: jnp.ndarray,
     passes window rows already fake-quantized so the fresh fold attends
     exactly what the post-kernel scatter stores.
 
-    ``owned``/pre-localized ``tables`` are the shard_map wrapper's
-    seam (with ``fold=False`` it returns raw (acc, m, l) partials for
-    the cross-'data' softmax merge); plain callers leave both unset and
-    get the ``effective_tables`` prefix mask."""
+    ``owned`` (B, max_pages) bool is the shard_map wrapper's seam: the
+    table entries THIS call may read (with ``fold=False`` it returns
+    raw (acc, m, l) partials for the cross-'data' softmax merge); plain
+    callers leave it unset and get the prefix of pages that hold a
+    position < pos."""
     N, psz, C = k_pages.shape
     B, W, _ = q.shape
-    mp = tables.shape[1]
     D = C // n_head
     quantized = k_scales is not None
     head_gran = quantized and k_scales.ndim == 3
+    pos = jnp.asarray(pos, jnp.int32)
     if owned is None:
-        tables, owned = effective_tables(tables, pos, psz)
+        owned = gqa_owned_pages(pos, jnp.zeros_like(pos), tables.shape[1],
+                                psz, 0)
+    P, nb, walk = _blocked_walk(tables, owned, psz,
+                                C * k_pages.dtype.itemsize)
     kernel = functools.partial(
         _paged_window_kernel, n_head=n_head, head_dim=D, page_size=psz,
-        n_pages_per_slot=mp, window=W, scale=D ** -0.5,
+        n_block=P, n_blocks=nb, window=W, scale=D ** -0.5,
         quantized=quantized, head_gran=head_gran, fold=fold)
 
-    def row_map(b, p, tables, pos, owned):
+    def row_map(b, p, *_):
         return (b, 0, 0)
 
-    def page_map(b, p, tables, pos, owned):
-        # unowned steps repeat an already-fetched physical page (the
-        # table is pre-filled by _fill_last_owned) — a repeated block
-        # index skips the DMA (the fetch-skip trick)
-        return (tables[b, p], 0, 0)
-
     row = _vmem_spec((None, W, C), row_map)
-    kw = {"compiler_params": _compiler_params(0, 2)}
-    scratch = [pltpu.VMEM((W, C), jnp.float32),
-               pltpu.VMEM((W, LANES), jnp.float32),
-               pltpu.VMEM((W, LANES), jnp.float32)]
-    in_specs = [row, row, row,
-                _vmem_spec((None, psz, C), page_map),
-                _vmem_spec((None, psz, C), page_map)]
-    inputs = [q, k_new, v_new, k_pages, v_pages]
+    in_specs, inputs = [row, row, row], [q, k_new, v_new]
     if quantized:
+        # a pool's scales are a 64th of its bytes or less: XLA gathers
+        # each slot's in the order of its (padded) table, and a block's
+        # arrive as one (P * page, width) tile like the rows
         swidth = n_head if head_gran else 1
-        in_specs += [_vmem_spec((None, psz, swidth), page_map),
-                     _vmem_spec((None, psz, swidth), page_map)]
-        inputs += [k_scales.reshape(N, psz, swidth),
-                   v_scales.reshape(N, psz, swidth)]
+        in_specs += [_vmem_spec((None, None, P * psz, swidth),
+                                lambda b, p, *_: (b, p, 0, 0))] * 2
+        inputs += [sc.reshape(N, psz, swidth)[walk[0]].reshape(
+            B, nb, P * psz, swidth) for sc in (k_scales, v_scales)]
+    pool_specs, pool_scratch = _pool_operands([k_pages, v_pages], P)
+    hps = _heads_per_slab(n_head, D)
+    state = (n_head // hps, hps * W)
+    scratch = [pltpu.VMEM((*state, hps * D), jnp.float32),    # q slabs
+               pltpu.VMEM((*state, hps * D), jnp.float32),    # acc
+               pltpu.VMEM((*state, LANES), jnp.float32),      # m
+               pltpu.VMEM((*state, LANES), jnp.float32)]      # l
     if fold:
         out_specs = row
         out_shape = jax.ShapeDtypeStruct((B, W, C), q.dtype)
@@ -392,18 +557,18 @@ def paged_window_attention(q: jnp.ndarray, k_new: jnp.ndarray,
                      jax.ShapeDtypeStruct((B, W, LANES), jnp.float32),
                      jax.ShapeDtypeStruct((B, W, LANES), jnp.float32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B, mp),
-        in_specs=in_specs,
+        num_scalar_prefetch=len(walk) + 1,
+        grid=(B, nb),
+        in_specs=in_specs + pool_specs,
         out_specs=out_specs,
-        scratch_shapes=scratch,
+        scratch_shapes=scratch + pool_scratch,
     )
     return pl.pallas_call(
         kernel, grid_spec=grid_spec, out_shape=out_shape,
         name="paged_window_attention",
-        interpret=_interpret_mode(), **kw,
-    )(jnp.asarray(tables, jnp.int32), jnp.asarray(pos, jnp.int32),
-      jnp.asarray(owned, jnp.int32), *inputs)
+        interpret=_interpret_mode(),
+        compiler_params=_compiler_params(0, 2),
+    )(*walk, pos, *inputs, k_pages, v_pages)
 
 
 def paged_decode_attention(q: jnp.ndarray, k_new: jnp.ndarray,
@@ -471,12 +636,12 @@ def sharded_paged_window_attention(q: jnp.ndarray, k_new: jnp.ndarray,
     (heads are whole per shard — ``paged_kernel_mesh_ok`` gates on
     that), and the replicated page table is LOCALIZED per shard — a
     shard owns a logical page iff its physical index lands in the
-    shard's block, the owned mask gates accumulation, and
-    ``_fill_last_owned`` rewrites unowned steps to repeat an owned
-    block index so the fetch-skip contract survives arbitrary owned
-    subsets (a slot's pages interleave across shards under allocation
-    churn). Each shard emits raw (acc, m, l) partials (``fold=False``);
-    the online-softmax merge across 'data' is exact — pmax the maxima,
+    shard's block; the owned mask says which pages of a block this
+    shard copies and masks the other pages' columns, so the contract
+    survives arbitrary owned subsets (a slot's pages interleave across
+    shards under allocation churn). Each shard emits raw (acc, m, l)
+    partials (``fold=False``); the online-softmax merge across 'data'
+    is exact — pmax the maxima,
     rescale, psum — and the fresh causal window folds once afterwards
     on the merged state ('model' needs no collective: heads are fully
     local). Output matches the unsharded kernel to f32 merge order."""
@@ -503,9 +668,8 @@ def sharded_paged_window_attention(q: jnp.ndarray, k_new: jnp.ndarray,
         tab = jnp.asarray(tab, jnp.int32)
         owned = ((p_idx < live[:, None]) & (tab >= lo)
                  & (tab < lo + N_loc))
-        eff = _fill_last_owned(tab - lo, owned)
         acc, m_, l_ = paged_window_attention(
-            q_l, kn_l, vn_l, kp_l, vp_l, eff, pos_l, n_head=H_loc,
+            q_l, kn_l, vn_l, kp_l, vp_l, tab - lo, pos_l, n_head=H_loc,
             k_scales=ks_l, v_scales=vs_l, owned=owned, fold=False)
         # exact cross-shard online-softmax merge: max, rescale, sum
         m_g = jax.lax.pmax(m_, "data")
@@ -535,26 +699,29 @@ def sharded_paged_window_attention(q: jnp.ndarray, k_new: jnp.ndarray,
 # and for a page's row). A grouped-query model's page row is n_kv_head * D
 # wide and G = n_head // n_kv_head query heads read each KV head, so the
 # kernel below stacks a group's G * W query rows into ONE (G*W, D) block a
-# KV head: a page costs n_kv_head small matmuls, not n_head. ``attn_window``
+# KV head: a block costs n_kv_head products, not n_head. ``attn_window``
 # bounds the positions a row reads from below (row j at position pos + j
 # attends k with pos + j - window < k <= pos + j): pages wholly behind the
-# bound are unowned and skipped exactly like pages past the frontier, the
-# fetch-skip trick at the other end. ``page0`` gives the absolute logical
+# bound are unowned and skipped exactly like pages past the frontier, at
+# the walk's other end. ``page0`` gives the absolute logical
 # page of a slot's first table entry, so the same walk serves a per-slot
 # RING of pages (a window layer's bounded state) as well as the pool.
-# Both are static: gpt2's programs never reach this function.
+# Both are static: gpt2's programs never reach this kernel (their owned
+# prefix is ``gqa_owned_pages`` with neither).
 
 
-def _paged_gqa_kernel(tables_ref, pos_ref, owned_ref, page0_ref, q_ref,
-                      knew_ref, vnew_ref, kp_ref, vp_ref, out_ref,
-                      acc_ref, m_ref, l_ref, *, n_kv_head, head_dim,
-                      page_size, n_pages_per_slot, window, attn_window,
-                      scale):
+def _paged_gqa_kernel(*refs, n_kv_head, head_dim, page_size, n_block,
+                      n_blocks, window, attn_window, scale):
+    walk = refs[:5]
+    (pos_ref, page0_ref, q_ref, knew_ref, vnew_ref, k_hbm, v_hbm, out_ref,
+     acc_ref, m_ref, l_ref, k_buf, v_buf, sem) = refs[5:]
+    pools, bufs = (k_hbm, v_hbm), (k_buf, v_buf)
     b = pl.program_id(0)
     p = pl.program_id(1)
-    D, psz, W = head_dim, page_size, window
+    D, psz, W, P = head_dim, page_size, window, n_block
     GW = q_ref.shape[1]                       # G * W rows a KV head
     pos = pos_ref[b]
+    heads = [slice(g * D, (g + 1) * D) for g in range(n_kv_head)]
     # row r of a group's block is query row j = r % W of the window
     row_j = jax.lax.broadcasted_iota(jnp.int32, (GW, 1), 0) % W
 
@@ -564,63 +731,35 @@ def _paged_gqa_kernel(tables_ref, pos_ref, owned_ref, page0_ref, q_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    @pl.when(owned_ref[b, p] > 0)
-    def _accumulate():
-        kpos = (jax.lax.broadcasted_iota(jnp.int32, (1, psz), 1)
-                + (page0_ref[b] + p) * psz)
-        live = kpos < pos                                       # (1, psz)
-        if attn_window:
-            live = live & (kpos > pos + row_j - attn_window)    # (GW, psz)
-        for g in range(n_kv_head):
-            sl = slice(g * D, (g + 1) * D)
-            q = q_ref[g].astype(jnp.float32)                    # (GW, D)
-            kcf = kp_ref[:, sl].astype(jnp.float32)             # (psz, D)
-            vcf = vp_ref[:, sl].astype(jnp.float32)
-            s = jax.lax.dot_general(
-                q, kcf, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale     # (GW, psz)
-            s = jnp.where(live, s, NEG_INF)
-            m_prev = m_ref[g]                                   # (GW, 1)
-            m_new = jnp.maximum(m_prev,
-                                jnp.max(s, axis=1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            pexp = jnp.where(s > NEG_INF / 2, jnp.exp(s - m_new), 0.0)
-            l_ref[g] = l_ref[g] * alpha + jnp.sum(pexp, axis=1,
-                                                  keepdims=True)
-            acc_ref[g] = (acc_ref[g] * alpha
-                          + jax.lax.dot_general(
-                              pexp, vcf, (((1,), (0,)), ((), ())),
-                              preferred_element_type=jnp.float32))
-            m_ref[g] = m_new
+    live, half, owned_cols = _fetch_block(walk, pools, bufs, sem, P,
+                                          n_blocks, psz)
 
-    @pl.when(p == n_pages_per_slot - 1)
+    @pl.when(live)
+    def _accumulate():
+        kpos = (jax.lax.broadcasted_iota(jnp.int32, (1, P * psz), 1)
+                + (page0_ref[b] + p * P) * psz)
+        mask = owned_cols() & (kpos < pos)                   # (1, P * psz)
+        if attn_window:
+            mask = mask & (kpos > pos + row_j - attn_window)  # (GW, P * psz)
+        for g, sl in enumerate(heads):
+            s = _scores(q_ref[g], bufs[0][half, :, sl], mask, scale)
+            _online_update(s, bufs[1][half, :, sl], acc_ref, m_ref, l_ref,
+                           g)
+
+    @pl.when(p == n_blocks - 1)
     def _finalize():
         col = jax.lax.broadcasted_iota(jnp.int32, (GW, W), 1)
         fresh = col <= row_j           # row j attends fresh rows 0..j
         if attn_window:
             fresh = fresh & (col > row_j - attn_window)
-        for g in range(n_kv_head):
-            sl = slice(g * D, (g + 1) * D)
-            q = q_ref[g].astype(jnp.float32)
-            kn = knew_ref[:, sl].astype(jnp.float32)            # (W, D)
-            vn = vnew_ref[:, sl].astype(jnp.float32)
-            s_new = jax.lax.dot_general(
-                q, kn, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale     # (GW, W)
-            s_new = jnp.where(fresh, s_new, NEG_INF)
-            m_prev = m_ref[g]
-            m2 = jnp.maximum(m_prev,
-                             jnp.max(s_new, axis=1, keepdims=True))
-            alpha = jnp.exp(m_prev - m2)
-            p_new = jnp.where(fresh, jnp.exp(s_new - m2), 0.0)
-            # denom >= diagonal term > 0 always (row j attends itself)
-            denom = l_ref[g] * alpha + jnp.sum(p_new, axis=1,
-                                               keepdims=True)
-            out = (acc_ref[g] * alpha
-                   + jax.lax.dot_general(
-                       p_new, vn, (((1,), (0,)), ((), ())),
-                       preferred_element_type=jnp.float32)) / denom
-            out_ref[g] = out.astype(out_ref.dtype)
+        for g, sl in enumerate(heads):
+            # denominator >= the diagonal term > 0 always (row j
+            # attends itself)
+            s_new = _scores(q_ref[g].astype(jnp.float32), knew_ref[:, sl],
+                            fresh, scale)
+            _online_update(s_new, vnew_ref[:, sl], acc_ref, m_ref, l_ref, g)
+            out_ref[g] = (acc_ref[g] / l_ref[g][:, :1]).astype(
+                out_ref.dtype)
 
 
 def gqa_owned_pages(pos: jnp.ndarray, page0: jnp.ndarray, n_table: int,
@@ -656,7 +795,6 @@ def paged_gqa_attention(q: jnp.ndarray, k_new: jnp.ndarray,
     window layer's call says ``swa_...``."""
     N, psz, Ckv = k_pages.shape
     B, W, Cq = q.shape
-    mp = tables.shape[1]
     D = Ckv // n_kv_head
     G = n_head // n_kv_head
     assert Cq == n_head * D and n_head == G * n_kv_head, (q.shape,
@@ -664,43 +802,43 @@ def paged_gqa_attention(q: jnp.ndarray, k_new: jnp.ndarray,
     pos = jnp.asarray(pos, jnp.int32)
     page0 = (jnp.zeros((B,), jnp.int32) if page0 is None
              else jnp.asarray(page0, jnp.int32))
-    owned = gqa_owned_pages(pos, page0, mp, psz, attn_window)
-    eff = _fill_last_owned(jnp.asarray(tables, jnp.int32), owned)
+    P, nb, walk = _blocked_walk(
+        tables, gqa_owned_pages(pos, page0, tables.shape[1], psz,
+                                attn_window),
+        psz, Ckv * k_pages.dtype.itemsize)
     # a KV head's G query heads as G*W rows of one block
     qg = (q.reshape(B, W, n_kv_head, G, D).transpose(0, 2, 3, 1, 4)
           .reshape(B, n_kv_head, G * W, D))
     kernel = functools.partial(
         _paged_gqa_kernel, n_kv_head=n_kv_head, head_dim=D, page_size=psz,
-        n_pages_per_slot=mp, window=W, attn_window=int(attn_window),
+        n_block=P, n_blocks=nb, window=W, attn_window=int(attn_window),
         scale=D ** -0.5)
 
-    def q_map(b, p, tables, pos, owned, page0):
+    def q_map(b, p, *_):
         return (b, 0, 0, 0)
 
-    def row_map(b, p, tables, pos, owned, page0):
+    def row_map(b, p, *_):
         return (b, 0, 0)
-
-    def page_map(b, p, tables, pos, owned, page0):
-        return (tables[b, p], 0, 0)
 
     qspec = _vmem_spec((None, n_kv_head, G * W, D), q_map)
     row = _vmem_spec((None, W, Ckv), row_map)
-    page = _vmem_spec((None, psz, Ckv), page_map)
+    pool_specs, pool_scratch = _pool_operands([k_pages, v_pages], P)
+    state = (n_kv_head, G * W)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(B, mp),
-        in_specs=[qspec, row, row, page, page],
+        num_scalar_prefetch=len(walk) + 2,
+        grid=(B, nb),
+        in_specs=[qspec, row, row, *pool_specs],
         out_specs=qspec,
-        scratch_shapes=[pltpu.VMEM((n_kv_head, G * W, D), jnp.float32),
-                        pltpu.VMEM((n_kv_head, G * W, 1), jnp.float32),
-                        pltpu.VMEM((n_kv_head, G * W, 1), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((*state, D), jnp.float32),
+                        pltpu.VMEM((*state, LANES), jnp.float32),
+                        pltpu.VMEM((*state, LANES), jnp.float32)]
+        + pool_scratch,
     )
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, n_kv_head, G * W, D), q.dtype),
         name=name, interpret=_interpret_mode(),
         compiler_params=_compiler_params(0, 2),
-    )(eff, pos, owned.astype(jnp.int32), page0, qg, k_new, v_new,
-      k_pages, v_pages)
+    )(*walk, pos, page0, qg, k_new, v_new, k_pages, v_pages)
     return (out.reshape(B, n_kv_head, G, W, D).transpose(0, 3, 1, 2, 4)
             .reshape(B, W, Cq))

@@ -87,8 +87,10 @@ Every step marks its host phases through ``self.tel.phase`` (a
 ``serve/step`` around ``serve/expire_shed``, ``serve/admit``,
 ``serve/prefill``, and ``serve/decode`` | ``serve/verify`` around
 ``serve/launch``, ``serve/fetch``, ``serve/commit``), with the live
-context (``live_tokens``, ``live_kv_bytes``, ``stochastic_rows``, from
-the host mirrors) as stats of ``serve/launch``; docs/observability.md
+context (``live_tokens``, ``live_kv_bytes``, ``stochastic_rows``, the
+paged kernel's ``kv_block_pages`` / ``kv_blocks_live`` /
+``kv_blocks_grid``, from the host mirrors) as stats of ``serve/launch``;
+docs/observability.md
 has the vocabulary.
 """
 
@@ -877,6 +879,16 @@ class Engine:
         self.metrics.gauge("kernel_route_pallas",
                            1.0 if self.kernel_route.route == "pallas"
                            else 0.0)
+        # the per-layer kernel's walk over the pool's tables, for the
+        # stats of ``serve/launch``: pages a grid step covers (0: the
+        # decode step runs no such kernel, or the fused one, which
+        # walks a page a step)
+        self._kv_block_pages = 0
+        if self._use_pallas:
+            from ..ops.paged_pallas import block_pages
+            self._kv_block_pages = block_pages(
+                self.pool.page_size, self.pool.max_pages,
+                self.pool.kv_array.shape[-1] * itemsize)
         log.info("kernel route: %s (decode=%s window=%s sharded=%s%s)",
                  self.kernel_route.route, self.kernel_route.decode,
                  self.kernel_route.window, self.kernel_route.sharded,
@@ -1866,7 +1878,8 @@ class Engine:
         read), and ``live_kv_bytes``, what those tokens hold in the pool
         across layers, and ``stochastic_rows``, the live slots that
         sample in this window (a slot that prefills all of it does
-        not)."""
+        not); on the per-layer Pallas route also how the kernel's walk
+        engages (``_kv_walk_stats``)."""
         t0_us = self.tel.now_us() if self.tel.enabled else 0.0
         t_wall = time.perf_counter()
         if kill is None:
@@ -1883,12 +1896,31 @@ class Engine:
                                             window).sum()) * swa_token_bytes,
                 expert_weight_bytes=expert_bytes * k,
                 moe_rows=n_active * k)
+        if self._kv_block_pages:
+            extra.update(self._kv_walk_stats(live))
         with self.tel.phase("serve/launch", self._tb + ENGINE_TRACK, k=k,
                             n_active=n_active, live_tokens=live_tokens,
                             live_kv_bytes=live_tokens
                             * self._kv_token_bytes,
                             stochastic_rows=stochastic, **extra):
             return self._dispatch(k, kill, n_active, t0_us, t_wall)
+
+    def _kv_walk_stats(self, live: np.ndarray) -> dict:
+        """How the paged kernel's walk engages in the launch being
+        built, from the host mirrors: ``kv_block_pages`` (P, the pages a
+        grid step covers), ``kv_blocks_live`` (grid steps with work: the
+        sum over the live slots of the blocks that hold a position under
+        the slot's, as the kernel's owned mask will have it on the
+        device; one step of one pool layer) and ``kv_blocks_grid``
+        (slots x blocks a table: every step the grid takes there)."""
+        from ..ops.paged_pallas import live_blocks
+        P = self._kv_block_pages
+        return dict(
+            kv_block_pages=P,
+            kv_blocks_live=int(live_blocks(
+                self._pos[live], self.pool.page_size, P).sum()),
+            kv_blocks_grid=self.ecfg.pool_size * -(-self.pool.max_pages
+                                                   // P))
 
     def _family_launch_stats(self) -> Optional[tuple]:
         """``(window, bytes a ring token holds over the window layers,

@@ -311,14 +311,22 @@ def _einsum_attention(q, kn, vn, kp, vp, tables, pos, Hq, Hkv, window,
     return out
 
 
-@pytest.mark.parametrize("W,window,pos", [
-    (1, 0, [0, 5, 37]),          # full layer: idle slot, mid page, deep
-    (1, 8, [3, 8, 41]),          # window = 1 page; inside the first window
-    (1, 12, [3, 20, 47]),        # window not a multiple of the page
-    (2, 12, [1, 16, 30]),        # two fresh rows under the band
-], ids=["full", "window-1-page", "window-1.5-pages", "two-rows"])
-def test_gqa_kernel_and_its_lower_bound_against_einsum(W, window, pos):
-    Hq, Hkv, D, psz, mp = 4, 2, 32, 8, 6
+@pytest.mark.parametrize("W,window,pos,mp", [
+    (1, 0, [0, 5, 37], 6),       # full layer: idle slot, mid page, deep
+    (1, 8, [3, 8, 41], 6),       # window = 1 page; inside the first window
+    (1, 12, [3, 20, 47], 6),     # window not a multiple of the page
+    (2, 12, [1, 16, 30], 6),     # two fresh rows under the band
+    # a table of 20 pages of 8 is walked in blocks of 16 (128 tokens) and
+    # ends in a short one: an idle slot, the frontier on the second
+    # block's first position and on its last
+    (1, 0, [0, 129, 159], 20),
+    (1, 12, [3, 133, 150], 20),  # the band's lower bound inside a block,
+    (2, 12, [1, 128, 140], 20),  # and across the two blocks' edge
+], ids=["full", "window-1-page", "window-1.5-pages", "two-rows",
+        "blocks-full", "blocks-window", "blocks-two-rows"])
+def test_gqa_kernel_and_its_lower_bound_against_einsum(W, window, pos, mp):
+    Hq, Hkv, D, psz = 4, 2, 32, 8
+    assert (paged_pallas.block_pages(psz, mp, Hkv * D * 4) < mp) == (mp > 16)
     q, kn, vn, kp, vp, tables, pos = _kernel_case(3, 3, W, Hq, Hkv, D, psz,
                                                   mp, pos, window)
     got = paged_pallas.paged_gqa_attention(
@@ -329,21 +337,32 @@ def test_gqa_kernel_and_its_lower_bound_against_einsum(W, window, pos):
     assert np.abs(np.asarray(got) - want).max() < 1e-5
 
 
-def test_gqa_kernel_walks_a_ring_from_page0():
+@pytest.mark.parametrize("psz,mp,window,pos,dtype,tol", [
+    (8, 3, 12, [45, 18], "float32", 1e-5),
+    # K-EXAONE's ring: 9 pages of 16 under a window of 128 are a block of
+    # 8 and a short one; the lower bound falls inside the first block
+    (16, 9, 128, [300, 140, 1000], "float32", 1e-5),
+    # the cell's storage: bf16 K goes to the MXU as it is; what is left
+    # is the output's own rounding
+    (16, 9, 128, [300, 140, 1000], "bfloat16", 1.6e-2),
+], ids=["ring-of-3", "ring-of-9-in-two-blocks", "ring-of-9-bf16"])
+def test_gqa_kernel_walks_a_ring_from_page0(psz, mp, window, pos, dtype,
+                                            tol):
     """A window layer's ring: the table's first entry is absolute page
     ``page0`` of the slot, not page 0."""
-    Hq, Hkv, D, psz, mp, window = 4, 2, 32, 8, 3, 12
-    pos = [45, 18]
+    Hq, Hkv, D = 4, 2, 32
     page0 = [(p - window + 1) // psz for p in pos]
-    q, kn, vn, kp, vp, tables, pos = _kernel_case(4, 2, 1, Hq, Hkv, D, psz,
-                                                  mp, pos, window)
+    *rows, tables, pos = _kernel_case(4, len(pos), 1, Hq, Hkv, D, psz, mp,
+                                      pos, window)
+    rows = [a.astype(dtype) for a in rows]
     got = paged_pallas.paged_gqa_attention(
-        q, kn, vn, kp, vp, tables, pos, n_head=Hq, n_kv_head=Hkv,
+        *rows, tables, pos, n_head=Hq, n_kv_head=Hkv,
         attn_window=window, page0=jnp.asarray(page0, jnp.int32),
         name="swa_test")
-    want = _einsum_attention(q, kn, vn, kp, vp, tables, pos, Hq, Hkv,
-                             window, page0)
-    assert np.abs(np.asarray(got) - want).max() < 1e-5
+    want = _einsum_attention(*(np.asarray(a, np.float32) for a in rows),
+                             tables, pos, Hq, Hkv, window, page0)
+    assert got.dtype == jnp.dtype(dtype)
+    assert np.abs(np.asarray(got, np.float32) - want).max() < tol
 
 
 def test_pages_behind_the_window_are_not_owned():

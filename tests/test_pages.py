@@ -423,20 +423,27 @@ def test_verify_step_paged_matches_multi(params, layout):
 # Pallas fast path (interpret mode on CPU)
 # ---------------------------------------------------------------------------
 
-def test_paged_pallas_kernel_matches_gather_reference():
+@pytest.mark.parametrize("psz,mp,pos", [
+    (8, 4, [17, 9, 0]),            # one block a slot
+    (16, 9, [130, 9, 0]),          # a block of 8 pages and a short one
+    (8, 24, [128, 127, 191]),      # blocks of 16: frontier on their edges
+], ids=["one-block", "table-of-9", "block-edges"])
+def test_paged_pallas_kernel_matches_gather_reference(psz, mp, pos):
     from replicatinggpt_tpu.ops import paged_pallas
     from replicatinggpt_tpu.ops.attention import cached_attention
     rng = np.random.default_rng(0)
-    B, H, D, psz, mp, N = 3, 2, 32, 8, 4, 10
+    B, H, D = 3, 2, 32
+    N = B * mp + 1
     C = H * D
     kp = jnp.asarray(rng.normal(size=(N, psz, C)), jnp.float32)
     vp = jnp.asarray(rng.normal(size=(N, psz, C)), jnp.float32)
+    pos = np.array(pos, np.int32)          # incl. the pos=0 fresh-only row
+    # mapped up to the page the fresh row lands in; the rest stale 0s
     tables = np.zeros((B, mp), np.int32)
     perm = rng.permutation(N)
-    tables[0, :4] = perm[:4]
-    tables[1, :2] = perm[4:6]
-    tables[2, :3] = perm[6:9]
-    pos = np.array([17, 9, 0], np.int32)   # incl. the pos=0 fresh-only row
+    for b in range(B):
+        n = pos[b] // psz + 1
+        tables[b, :n] = perm[b * mp:b * mp + n]
     q = jnp.asarray(rng.normal(size=(B, C)), jnp.float32)
     kn = jnp.asarray(rng.normal(size=(B, C)), jnp.float32)
     vn = jnp.asarray(rng.normal(size=(B, C)), jnp.float32)

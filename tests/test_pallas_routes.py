@@ -143,27 +143,88 @@ def _window_ref(q, kn, vn, kp, vp, tables, pos, n_head):
     return out
 
 
-def _window_inputs(seed=0):
+# geometries of the walk: (W, page, table length, positions). "one-block"
+# is a table the kernel covers in ONE grid step a slot; the others make it
+# walk blocks of P pages (``paged_pallas.block_pages``: 8 pages of 16, 16
+# of 8), with the frontier in the middle of a block, on a block's first
+# and last position, an idle slot, and a table P does not divide (9, 20)
+WALKS = {
+    "one-block": (4, 8, 4, [17, 9, 0]),
+    "mid-block": (1, 16, 24, [200, 70, 0]),
+    "block-edges": (1, 16, 24, [128, 129, 255, 256]),
+    "table-of-9": (1, 16, 9, [143, 130, 127, 5]),
+    "w8-short-last-block": (8, 8, 20, [150, 128, 3, 0]),
+}
+
+
+def _window_inputs(seed=0, walk="one-block"):
     rng = np.random.default_rng(seed)
-    B, W, psz, mp, N, C = 3, 4, 8, 4, 12, 64
-    pos = np.array([17, 9, 0], np.int32)   # incl. the fresh-only row
-    tables = rng.permutation(N)[: B * mp].reshape(B, mp).astype(np.int32)
+    W, psz, mp, pos = WALKS[walk]
+    pos = np.array(pos, np.int32)          # incl. the fresh-only row
+    B, C = len(pos), 64
+    N = B * mp
+    tables = rng.permutation(N).reshape(B, mp).astype(np.int32)
     mk = lambda *s: rng.standard_normal(s).astype(np.float32)  # noqa: E731
     return (mk(B, W, C), mk(B, W, C), mk(B, W, C), mk(N, psz, C),
             mk(N, psz, C), tables, pos)
 
 
-@pytest.mark.parametrize("kv_dtype,gran", [
-    ("int8", "head"), ("fp8", "page"), ("fp8", "head")])
-def test_windowed_kernel_quantized_parity(kv_dtype, gran):
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-5),
+                                       ("bfloat16", 1.6e-2)])
+@pytest.mark.parametrize("walk_name", list(WALKS))
+def test_blocked_walk_matches_gather_reference(walk_name, dtype, tol):
+    """The walk in blocks of pages against the XLA-free gather
+    reference: every geometry of ``WALKS``, W = 1 and W = 8, a float32
+    pool and a bf16 one (the cells': K goes to the MXU as stored; the
+    output's own rounding is the tolerance); and the scalars the kernel fetches by: a whole
+    number of blocks a slot, the owned mask padded with unowned entries,
+    each live step's rank and successor."""
+    from replicatinggpt_tpu.ops import paged_pallas as pp
+    q, kn, vn, kp, vp, tables, pos = _window_inputs(5, walk_name)
+    q, kn, vn, kp, vp = (np.asarray(jnp.asarray(a, dtype), np.float32)
+                         for a in (q, kn, vn, kp, vp))
+    out = pp.paged_window_attention(
+        *(jnp.asarray(a, dtype) for a in (q, kn, vn, kp, vp)),
+        jnp.array(tables), jnp.array(pos), n_head=2)
+    assert out.dtype == jnp.dtype(dtype)
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32),
+        _window_ref(q, kn, vn, kp, vp, tables, pos, 2), atol=tol, rtol=tol)
+    psz, mp = kp.shape[1], tables.shape[1]
+    owned = pp.gqa_owned_pages(jnp.array(pos), jnp.zeros_like(pos), mp,
+                               psz, 0)
+    P, nb, walk = pp._blocked_walk(jnp.array(tables), owned, psz,
+                                   kp.shape[2] * 4)
+    assert P == min(128 // psz, mp) and nb == -(-mp // P)
+    assert (nb > 1) == (walk_name != "one-block")
+    table, own, live, rank, nxt = (np.asarray(a) for a in walk)
+    own = own.astype(bool)
+    assert table.shape == own.shape == (len(pos), nb * P)
+    assert (own[:, :mp] == np.asarray(owned)).all() and not own[:, mp:].any()
+    assert (table[:, :mp][own[:, :mp]] == tables[own[:, :mp]]).all()
+    # a step is live if its block owns a page; the live steps hand the
+    # double buffer on to one another, slot after slot
+    steps = np.flatnonzero(own.reshape(-1, P).any(-1))
+    assert (np.flatnonzero(live) == steps).all()
+    assert (rank[steps] == np.arange(len(steps))).all()
+    assert nxt[steps].tolist() == steps[1:].tolist() + [-1]
+    assert int(pp.live_blocks(pos, psz, P).sum()) == len(steps)
+
+
+@pytest.mark.parametrize("kv_dtype,gran,walk", [
+    ("int8", "head", "one-block"), ("fp8", "page", "one-block"),
+    ("fp8", "head", "one-block"), ("int8", "head", "w8-short-last-block"),
+    ("int8", "head", "mid-block"), ("fp8", "page", "table-of-9")])
+def test_windowed_kernel_quantized_parity(kv_dtype, gran, walk):
     """fp8 KV and head-granularity scales were the documented XLA
     seams — the per-head scale-lane selection and the saturating e4m3
     fake-quant now run inside the accumulation loop, parity-pinned
-    against the dequantized gather reference."""
+    against the dequantized gather reference, over one block and over
+    a walk of several."""
     from replicatinggpt_tpu.ops import paged_pallas as pp
     from replicatinggpt_tpu.quant.kv import (fake_quantize_rows,
                                              quantize_rows)
-    q, kn, vn, kp, vp, tables, pos = _window_inputs()
+    q, kn, vn, kp, vp, tables, pos = _window_inputs(walk=walk)
     H, D = 2, 32
     kq, ks = quantize_rows(jnp.array(kp), kv_dtype, H, gran)
     vq, vs = quantize_rows(jnp.array(vp), kv_dtype, H, gran)
@@ -182,17 +243,21 @@ def test_windowed_kernel_quantized_parity(kv_dtype, gran):
                                rtol=1e-4)
 
 
-def test_sharded_window_kernel_matches_reference():
+@pytest.mark.parametrize("walk", ["one-block", "mid-block",
+                                  "w8-short-last-block"])
+def test_sharded_window_kernel_matches_reference(walk):
     """The shard_map wrapper on a 2x2 (data, model) mesh: per-shard
-    table localization + cross-shard online-softmax merge must match
-    the unsharded reference bit-for-float — plain AND fp8/head pools
-    (forced 8-device CPU mesh from conftest)."""
+    table localization + cross-shard online-softmax merge of the
+    kernel's ``fold=False`` partials must match the unsharded reference
+    bit-for-float — plain AND fp8/head pools (forced 8-device CPU mesh
+    from conftest), over one block and over a walk of several, where a
+    block's pages lie on both 'data' shards."""
     from replicatinggpt_tpu.ops import paged_pallas as pp
     from replicatinggpt_tpu.parallel.mesh import make_serve_mesh
     from replicatinggpt_tpu.quant.kv import (fake_quantize_rows,
                                              quantize_rows)
     mesh = make_serve_mesh(2, 2)
-    q, kn, vn, kp, vp, tables, pos = _window_inputs(seed=3)
+    q, kn, vn, kp, vp, tables, pos = _window_inputs(seed=3, walk=walk)
     H, D = 2, 32
     ref = _window_ref(q, kn, vn, kp, vp, tables, pos, H)
     out = pp.sharded_paged_window_attention(
@@ -215,6 +280,50 @@ def test_sharded_window_kernel_matches_reference():
         k_scales=ks, v_scales=vs)
     np.testing.assert_allclose(np.asarray(out_q), ref_q, atol=1e-4,
                                rtol=1e-4)
+
+
+def test_launch_stats_count_the_blocks_the_device_mask_owns(kernel_backend):
+    """``serve/launch`` says how the kernel's walk engages, from the host
+    mirrors: ``kv_block_pages`` = P, ``kv_blocks_grid`` = slots x blocks
+    a table, and ``kv_blocks_live`` = the blocks that hold an owned page
+    in the mask the kernel is handed on the device (rebuilt here from
+    what the launch uploads: tables, positions, the live slots)."""
+    from replicatinggpt_tpu.ops import paged_pallas as pp
+    from replicatinggpt_tpu.utils.telemetry import Telemetry
+    cfg = dataclasses.replace(CFG, block_size=256)
+    params = init_params(jax.random.PRNGKey(2), cfg)
+    tel = Telemetry()
+    eng = Engine(params, cfg, EngineConfig(
+        pool_size=3, max_queue=8, page_size=8, prefill_chunk=64,
+        paged_kernel=True, prefix_cache=False, weight_quant="int8"),
+        telemetry=tel)          # quantized weights: not the fused kernel
+    assert eng.kernel_route.decode == "pallas"
+    psz, mp = eng.pool.page_size, eng.pool.max_pages
+    P = pp.block_pages(psz, mp, cfg.n_embd * 4)
+    assert (psz, mp, P) == (8, 32, 16)
+    want, dispatch = [], eng._dispatch
+
+    def spy(k, kill, *a):
+        pos = jnp.asarray(np.where(eng._active & ~kill, eng._pos, 0),
+                          jnp.int32)
+        owned = pp.gqa_owned_pages(pos, jnp.zeros_like(pos), mp, psz, 0)
+        _, _, walk = pp._blocked_walk(
+            jnp.asarray(eng.pool.tables), owned, psz, cfg.n_embd * 4)
+        want.append(int(np.asarray(walk[2]).sum()))     # the live steps
+        return dispatch(k, kill, *a)
+
+    eng._dispatch = spy
+    rng = np.random.default_rng(0)
+    for i, n in enumerate([5, 126, 140, 3]):    # one block, its edge, two
+        assert eng.submit(_greedy(f"b{i}", rng.integers(0, 65, (n,)),
+                                  max_new=4)) is None
+    eng.drain()
+    stats = [e["args"] for e in tel.events
+             if e.get("ph") == "X" and e.get("name") == "serve/launch"]
+    assert want and [a["kv_blocks_live"] for a in stats] == want
+    assert {1, 2, 3, 4} & set(want) and max(want) >= 4    # 1 + 1 + 2
+    assert all(a["kv_block_pages"] == P and a["kv_blocks_grid"] == 3 * 2
+               for a in stats)
 
 
 # ---------------------------------------------------------------------------

@@ -113,22 +113,34 @@ def _paged_args(sh, B, W, C=768, psz=16, mp=64, pool_dtype=BF16):
             _s((B,), jnp.int32, sh["rep"]))
 
 
-@pytest.mark.parametrize("window,quant", [(1, False), (8, False),
-                                          (1, True)],
-                         ids=["w1", "w8", "w1-int8"])
-def test_paged_window_attention_124m(mosaic, one_chip, window, quant):
-    from replicatinggpt_tpu.ops.paged_pallas import paged_window_attention
+@pytest.mark.parametrize("window,quant,B,C,H", [
+    (1, False, 8, 768, 12), (8, False, 8, 768, 12), (1, True, 8, 768, 12),
+    # the serve cells' engine: gpt2-large, 96 slots, 64 pages of 16 a slot
+    (1, False, 96, 1280, 20), (8, False, 96, 1280, 20),
+    (1, True, 96, 1280, 20)],
+    ids=["w1", "w8", "w1-int8", "large-w1", "large-w8", "large-w1-int8"])
+def test_paged_window_attention_124m(mosaic, one_chip, window, quant, B, C,
+                                     H):
+    """The walk in blocks of 8 pages (``block_pages``) at gpt2-small's
+    and gpt2-large's widths: 16 pool operands a call (32 with a quantized
+    pool's scales), two heads of 64 to a 128-lane slab."""
+    from replicatinggpt_tpu.ops.paged_pallas import (block_pages,
+                                                     paged_window_attention)
     sh = {"row": one_chip, "pool": one_chip, "rep": one_chip}
-    args = _paged_args(sh, 8, window,
+    args = _paged_args(sh, B, window, C=C,
                        pool_dtype=jnp.int8 if quant else BF16)
+    assert block_pages(16, 64, C * (1 if quant else 2)) == 8
     if quant:
-        sc = _s((8 * 64, 16), jnp.float32, one_chip)
+        sc = _s((B * 64, 16), jnp.float32, one_chip)
         fn = lambda q, kn, vn, kp, vp, t, p, ks, vs: (
-            paged_window_attention(q, kn, vn, kp, vp, t, p, n_head=12,
+            paged_window_attention(q, kn, vn, kp, vp, t, p, n_head=H,
                                    k_scales=ks, v_scales=vs))
-        _compile(fn, *args, sc, sc)
+        text = _compile(fn, *args, sc, sc)
     else:
-        _compile(lambda *a: paged_window_attention(*a, n_head=12), *args)
+        text = _compile(lambda *a: paged_window_attention(*a, n_head=H),
+                        *args)
+    assert all(n.startswith("paged_window_attention")
+               for n in _kernel_names(text)) and _kernel_names(text)
 
 
 @pytest.mark.parametrize("window,name", [
@@ -139,8 +151,11 @@ def test_paged_gqa_attention_kexaone_widths(mosaic, one_chip, window, name):
     heads on 8 KV heads of 128, pages of 16) for the described v5e: a full
     layer over a slot's 512-entry table, a window layer over its 9-page
     ring from ``page0``; the instruction wears the ``name=`` it was given."""
-    from replicatinggpt_tpu.ops.paged_pallas import paged_gqa_attention
+    from replicatinggpt_tpu.ops.paged_pallas import (block_pages,
+                                                     paged_gqa_attention)
     B, psz, mp = 64, 16, (9 if window else 512)
+    # blocks of 8 pages: 64 steps a slot, and the ring a block and a short
+    assert block_pages(psz, mp, 8 * 128 * 2) == 8
     q = _s((B, 1, 64 * 128), BF16, one_chip)
     kv = _s((B, 1, 8 * 128), BF16, one_chip)
     pages = _s((B * mp if window else 10240, psz, 8 * 128), BF16, one_chip)
