@@ -41,8 +41,6 @@ SEED = 1337
 #: the one-chip model of every phase: GPT-2 124M as published
 #: (12L/12H/768C, T=1024, vocab 50257, bf16), its mesh sized explicitly
 LM = ["--preset", "gpt2-small", "--dataset", DATASET]
-#: the model inside the fused all-layers kernels' VMEM envelope
-CHAR = ["--preset", "char-gpt", "--dataset", DATASET]
 
 _COMPILE = {"s": 0.0, "hits": 0, "misses": 0}
 
@@ -400,60 +398,48 @@ def _say_replay(phase: str, out: dict) -> None:
         raise AssertionError(f"{phase}: {out['problems']}")
 
 
-def phase_serve(lm=LM, char=CHAR, n_requests: int = 24, n_new: int = 16,
-                window: int = 8, want_routes=("pallas", "fused")) -> None:
+def phase_serve(lm=LM, n_requests: int = 24, n_new: int = 16,
+                window: int = 8, want_route="pallas") -> None:
     mcfg, params = _serve_setup(lm)
     for w in (1, window):
         _say_replay("serve", _replay(
             mcfg, params, window=w, n_requests=n_requests, n_new=n_new,
-            want_route=want_routes[0], generate_parity=True))
-    # the fused all-layers route only fits the small model's weights
-    mcfg, params = _serve_setup(char)
-    _say_replay("serve_fused", _replay(
-        mcfg, params, window=1, n_requests=8, n_new=n_new,
-        want_route=want_routes[1], generate_parity=True))
+            want_route=want_route, generate_parity=True))
 
 
-def phase_generate(lm=LM, char=CHAR, n_tokens: int = 64,
+def phase_generate(lm=LM, n_tokens: int = 64,
                    want_kernel: bool = True) -> None:
-    """`cli generate` (the offline decode stack): the B=1 fused
-    all-layers kernel at char widths, the packed per-layer decode
-    kernel at 124M."""
+    """`cli generate` (the offline decode stack) on the packed
+    per-layer decode kernel at 124M."""
     import jax
     import jax.numpy as jnp
     from replicatinggpt_tpu import cli
     from replicatinggpt_tpu.models import gpt
     from replicatinggpt_tpu.ops import decode_pallas as dp
-    for name, base in (("char", char), ("lm", lm)):
-        argv = base + ["--decode-cache-layout", "packed"]
-        mcfg = _cfg(argv).model
-        if dp.fused_decode_supported(mcfg, 1):
-            route = "fused_decode_layers"
-        elif dp.packed_decode_supported(mcfg):
-            route = "packed_decode_attention"
-        else:
-            route = "xla"
-        out = {"model": name, "route": route, "tokens": n_tokens}
-        if want_kernel:
-            params = jax.eval_shape(
-                lambda: gpt.init_params(jax.random.PRNGKey(0), mcfg))
-            cache = jax.eval_shape(lambda: gpt.init_kv_cache(mcfg, 1))
-            text = jax.jit(functools.partial(
-                gpt.decode_step, cfg=mcfg, allow_pallas=True)).lower(
-                params, jax.ShapeDtypeStruct((1,), jnp.int32),
-                jax.ShapeDtypeStruct((), jnp.int32), cache).as_text()
-            if route != "xla" and "tpu_custom_call" not in text:
-                raise AssertionError(f"decode_step at {name} widths "
-                                     f"lowered without its kernel")
-            out["kernel_in_lowered_step"] = "tpu_custom_call" in text
-        # the sample's text is not a result line: keep it off stdout
-        with compiles(out), contextlib.redirect_stdout(sys.stderr):
-            rc = cli.main(["generate", *argv, "--dp", "1",
-                           "--sample-tokens", str(n_tokens),
-                           "--top-k", "50", "--seed", str(SEED)])
-        if rc != 0:
-            raise AssertionError(f"cli generate returned {rc}")
-        say("generate", **out)
+    argv = lm + ["--decode-cache-layout", "packed"]
+    mcfg = _cfg(argv).model
+    route = ("packed_decode_attention" if dp.packed_decode_supported(mcfg)
+             else "xla")
+    out = {"route": route, "tokens": n_tokens}
+    if want_kernel:
+        params = jax.eval_shape(
+            lambda: gpt.init_params(jax.random.PRNGKey(0), mcfg))
+        cache = jax.eval_shape(lambda: gpt.init_kv_cache(mcfg, 1))
+        text = jax.jit(functools.partial(
+            gpt.decode_step, cfg=mcfg, allow_pallas=True)).lower(
+            params, jax.ShapeDtypeStruct((1,), jnp.int32),
+            jax.ShapeDtypeStruct((), jnp.int32), cache).as_text()
+        if route != "xla" and "tpu_custom_call" not in text:
+            raise AssertionError("decode_step lowered without its kernel")
+        out["kernel_in_lowered_step"] = "tpu_custom_call" in text
+    # the sample's text is not a result line: keep it off stdout
+    with compiles(out), contextlib.redirect_stdout(sys.stderr):
+        rc = cli.main(["generate", *argv, "--dp", "1",
+                       "--sample-tokens", str(n_tokens),
+                       "--top-k", "50", "--seed", str(SEED)])
+    if rc != 0:
+        raise AssertionError(f"cli generate returned {rc}")
+    say("generate", **out)
 
 
 # ------------------------------------------------------------ four chips

@@ -369,16 +369,14 @@ def _kinds(cfg: ModelConfig) -> List[Tuple[bool, int]]:
 
 def decode_step_paged(params: Params, idx_t, pos, active, tables,
                       cache: Dict[str, jnp.ndarray], cfg: ModelConfig, *,
-                      use_pallas: bool = False, use_fused: bool = False,
-                      shardings=None):
+                      use_pallas: bool = False, shardings=None):
     """One token a slot through both kinds of state: ``(logits (B, V)
     f32, cache, routed pairs on held experts)``. Full layers walk the
     slot's page table, window layers their slot's ring from the first page
     the window still reaches; both attend the STALE state plus the fresh
     row and scatter afterwards (``ops.paged_pallas.paged_gqa_attention``).
     Inactive rows run at position 0 and their writes are dropped."""
-    assert shardings is None and not use_fused, (
-        "exaone_moe serves on one chip through the per-layer kernel")
+    assert shardings is None, "exaone_moe serves on one chip"
     cd = _dtype(cfg.dtype)
     B = idx_t.shape[0]
     psz = paged_page_size(cache)
@@ -462,7 +460,7 @@ def _xla_paged_attention(q, k_new, v_new, k_pages, v_pages, tables, pos,
 def decode_window_paged(params: Params, tok, pos, active, budget, eos,
                         tables, cache, rngs, cfg: ModelConfig, *, sample_fn,
                         length: int, use_pallas: bool = False,
-                        use_fused: bool = False, shardings=None):
+                        shardings=None):
     """``models.gpt.decode_window_paged`` for this family: ``length``
     decode + sample steps in one program, the same carry and the same
     returned tuple. The token block gains ONE trailing column, the routed
@@ -472,7 +470,7 @@ def decode_window_paged(params: Params, tok, pos, active, budget, eos,
         tok, pos, active, budget, cache, rngs = carry
         logits, cache, pairs = decode_step_paged(
             params, tok, pos, active, tables, cache, cfg,
-            use_pallas=use_pallas, use_fused=use_fused, shardings=shardings)
+            use_pallas=use_pallas, shardings=shardings)
         nxt, rngs = sample_fn(rngs, logits, active)
         nxt = jnp.where(active, nxt, 0)
         emitted = active

@@ -6,7 +6,7 @@ paged entry points, the pool constructor and the parameter initialiser of
 lacks a program refuses it from inside (``models/exaone_moe.py``),
 ``serve_refusals`` says at the engine's constructor which engine options a
 family cannot run under, and why, and ``decide_kernel_route`` asks the
-family whether the fused kernel and the windowed steps fit.
+family whether the kernel fits its windowed steps.
 """
 
 from __future__ import annotations
@@ -29,9 +29,6 @@ class Family(NamedTuple):
     step_counters: Tuple[str, ...]
     #: pool-dict entries with this prefix are per-slot state, not pool pages
     slot_entry_prefix: str
-    #: the fused all-layers decode kernel can carry this engine:
-    #: ``(cfg, n_slots, page_size, itemsize, mesh, qcfg) -> bool``
-    fused_decode_ok: Callable
     #: the kernel fits the family's mixed and verify steps, or None where
     #: the family has no such step to route:
     #: ``(cfg, page_size, n_pages, itemsize, mesh, qcfg) -> Optional[bool]``
@@ -58,15 +55,6 @@ def _family(name: str) -> Family:
                                            table_row, cache, cfg,
                                            shardings=shardings)
 
-        def fused_ok(cfg, n_slots, page_size, itemsize, mesh, qcfg):
-            # it streams GPT-2's stacked block weights in-kernel
-            from ..ops import decode_pallas
-            return (not qcfg.weight_enabled
-                    and decode_pallas.fused_paged_decode_supported(
-                        cfg, n_slots, page_size, itemsize, mesh=mesh,
-                        kv_quant=qcfg.kv_dtype,
-                        granularity=qcfg.granularity))
-
         def window_ok(cfg, page_size, n_pages, itemsize, mesh, qcfg):
             from ..ops import paged_pallas
             return paged_pallas.mixed_step_kernel_ok(
@@ -76,14 +64,14 @@ def _family(name: str) -> Family:
 
         return Family("gpt", gpt.init_params, pool, prefill,
                       gpt.decode_window_paged, gpt.mixed_window_paged,
-                      gpt.verify_step_paged, (), "\0", fused_ok, window_ok)
+                      gpt.verify_step_paged, (), "\0", window_ok)
     if name == "exaone_moe":
         from . import exaone_moe as m
         return Family("exaone_moe", m.init_params, m.init_paged_kv_pool,
                       m.prefill_chunk_paged, m.decode_window_paged,
                       m.mixed_window_paged, m.verify_step_paged,
                       m.STEP_COUNTERS, m.WINDOW_ENTRY_PREFIX,
-                      lambda *a: False, lambda *a: None)
+                      lambda *a: None)
     raise KeyError(f"no model family {name!r}")
 
 

@@ -451,12 +451,6 @@ def init_kv_cache(cfg: ModelConfig, batch: int, max_len: Optional[int] = None,
     return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
 
 
-def _fused_decode_backend_ok() -> bool:
-    """Pallas lowering gate for the fused decode kernel (tests
-    monkeypatch this to exercise the interpret-mode kernel on CPU)."""
-    return jax.default_backend() == "tpu"
-
-
 def _all_single_device(tree) -> bool:
     """True when every array leaf lives on one device (no sharding over
     a multi-device mesh) — the GSPMD-safety answer the decode kernels'
@@ -484,12 +478,13 @@ def _default_allow_pallas(*inputs) -> bool:
     """Default kernel gate for direct decode_step callers: the
     shardings of the inputs themselves (``_all_single_device`` — what
     generate() computes eagerly), traced or concrete, so single-device
-    inputs on a multi-chip host keep the fused kernels. Logs once per
+    inputs on a multi-chip host keep the decode kernel. Logs once per
     process when the gate turns the kernels off on a backend that would
     otherwise run them (a silent perf cliff is worse than one stderr
     line)."""
+    from ..ops.decode_pallas import _packed_attn_backend_ok
     ok = _all_single_device(inputs)
-    if not ok and _fused_decode_backend_ok():
+    if not ok and _packed_attn_backend_ok():
         global _PALLAS_GATE_LOGGED
         if not _PALLAS_GATE_LOGGED:
             _PALLAS_GATE_LOGGED = True
@@ -508,10 +503,7 @@ def decode_step(params: Params, idx_t: jnp.ndarray, pos: jnp.ndarray,
     int32 position. Returns (logits (B, V) float32, updated cache).
 
     Replaces the reference's full re-forward per generated token
-    (GPT1.py:200-202) with O(T) work per token. Single-stream (B=1)
-    steps on TPU route the whole layer loop through the fused Pallas
-    decode kernel (ops/decode_pallas.py) when the per-layer weights fit
-    its VMEM envelope — one launch instead of ~125 op dispatches.
+    (GPT1.py:200-202) with O(T) work per token.
 
     The cache may be shorter than cfg.block_size (``init_kv_cache``'s
     max_len): every step streams the whole buffer, so callers that know
@@ -523,41 +515,19 @@ def decode_step(params: Params, idx_t: jnp.ndarray, pos: jnp.ndarray,
     every step).
     """
     cd = _dtype(cfg.dtype)
-    B = idx_t.shape[0]
     with jax.named_scope("embed"):
         x = params["wte"].astype(cd)[idx_t] + params["wpe"].astype(cd)[pos]
     x = x[:, None, :]  # (B, 1, C)
 
-    if allow_pallas is None:
-        allow_pallas = _default_allow_pallas(params, idx_t, cache)
-    S_actual = cache["k"].shape[cache_seq_axis(cfg)]
     # a past-the-end pos would CLAMP in the cache write below and
     # overwrite the last valid K/V (lint GL006); concrete (eager) calls
     # assert here, traced callers bound pos host-side (generate's
     # window refresh, the serve engine's admission room check)
-    check_in_bounds(pos, 1, S_actual, what="decode_step cache write")
-    from ..ops.decode_pallas import fused_decode_layers, fused_decode_supported
-    # the envelope gates on the CACHE actually handed in (its length and
-    # dtype may differ from cfg.block_size / the compute dtype via
-    # init_kv_cache's max_len/dtype overrides)
-    # the fused all-layers kernel handles BOTH cache layouts (heads
-    # blocks or packed lane-sliced rows), so B=1 keeps its one-launch
-    # path if the packed layout becomes the default
-    use_fused = (allow_pallas
-                 and _fused_decode_backend_ok()
-                 and cache["k"].dtype == cd
-                 # quantized params carry per-channel scales the fused
-                 # kernel's weight stream does not consume — the XLA
-                 # path below applies them via _wmm
-                 and "qkv_kernel_scale" not in params["blocks"]
-                 and fused_decode_supported(
-                     cfg, B, jnp.dtype(cd).itemsize, seq_len=S_actual))
-    if use_fused:
-        x_row, cache = fused_decode_layers(x[:, 0, :], params["blocks"],
-                                           pos, cache, cfg)
-        return _decode_head(x_row[:, None, :], params, cfg, cd), cache
-
+    check_in_bounds(pos, 1, cache["k"].shape[cache_seq_axis(cfg)],
+                    what="decode_step cache write")
     if cfg.decode_cache_layout == "packed":
+        if allow_pallas is None:
+            allow_pallas = _default_allow_pallas(params, idx_t, cache)
         return _decode_step_packed(params, x, pos, cache, cfg, cd,
                                    allow_pallas)
 
@@ -623,9 +593,9 @@ def _decode_step_packed(params: Params, x, pos, cache, cfg: ModelConfig,
     H = cfg.n_head
     S = cache["k"].shape[2]
     check_in_bounds(pos, 1, S, what="packed decode cache write")
-    # same cache-dtype gate as the fused path: the kernel attends the
-    # fresh column at compute precision, so write-then-attend
-    # bit-equivalence needs the stored value to round-trip losslessly
+    # the kernel attends the fresh column at compute precision, so
+    # write-then-attend bit-equivalence needs the stored value to
+    # round-trip losslessly
     use_kernel = (allow_pallas
                   and _packed_attn_backend_ok()
                   and cache["k"].dtype == cd
@@ -684,7 +654,7 @@ def _decode_step_packed(params: Params, x, pos, cache, cfg: ModelConfig,
 @jax.named_scope("head")
 def _decode_head(x, params: Params, cfg: ModelConfig, cd) -> jnp.ndarray:
     """Final layernorm + (tied/untied) head over a (B, 1, C) decode
-    state — one source of truth for the fused and XLA decode tails."""
+    state — one source of truth for every decode step's tail."""
     x = _layer_norm(x, params["ln_f_scale"], params["ln_f_bias"],
                     cfg.layernorm_eps)
     head = (params["wte"].astype(cd).T if cfg.tied_head
@@ -782,8 +752,8 @@ def decode_step_multi(params: Params, idx_t: jnp.ndarray, pos: jnp.ndarray,
     per-row math is identical to the scalar-pos XLA path — rows are
     independent through every op — which is what makes the engine's
     greedy output token-identical to offline ``generate`` (pinned in
-    tests/test_serve.py). No Pallas route: the fused/packed decode
-    kernels assume one shared position; the serving engine is a
+    tests/test_serve.py). No Pallas route: the packed decode
+    kernel assumes one shared position; the serving engine is a
     steady-state multi-slot batch where the XLA path is the right tool.
     """
     cd = _dtype(cfg.dtype)
@@ -1180,8 +1150,7 @@ def _paged_window_attn(q_w, k_w, v_w, k_layer, v_layer, tables, pos_eff,
 def decode_step_paged(params: Params, idx_t: jnp.ndarray, pos: jnp.ndarray,
                       active: jnp.ndarray, tables: jnp.ndarray,
                       cache: Dict[str, jnp.ndarray], cfg: ModelConfig, *,
-                      use_pallas: bool = False, use_fused: bool = False,
-                      shardings=None
+                      use_pallas: bool = False, shardings=None
                       ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """``decode_step_multi`` over a PAGED pool: per-slot positions are
     logical, and each slot's K/V is gathered through its page table.
@@ -1217,80 +1186,36 @@ def decode_step_paged(params: Params, idx_t: jnp.ndarray, pos: jnp.ndarray,
 
     quantized = "ks" in cache
     mesh = _serve_kernel_mesh(shardings)
-    if use_fused:
-        # ONE Pallas launch for the whole layer stack: the page table
-        # rides scalar-prefetch SMEM so each (layer, slot) grid step
-        # streams only the slot's LIVE pages (ops/decode_pallas.py,
-        # fused_paged_decode_layers). Packed layout only; the caller
-        # gates on fused_paged_decode_supported. The kernel attends the
-        # STALE pool + fresh column (bit-equivalent to write-then-
-        # attend; on a quantized pool it dequants pages in-kernel and
-        # fake-quantizes the fresh column to exactly what the store
-        # below will dequant to), so every layer's fresh K/V row
-        # scatters afterwards — drop-routed exactly like the XLA
-        # path's per-layer writes, quantize-on-write included.
-        from ..ops.decode_pallas import fused_paged_decode_layers
-        x_row, newk, newv = fused_paged_decode_layers(
-            x[:, 0, :], params["blocks"], pos_eff, tables, cache, cfg)
-        cc = dict(cache)
-        if quantized:
-            from ..quant.kv import pool_quant_mode, quantize_rows
-            kv_dtype, gran = pool_quant_mode(cache)
-            kq, ksc = quantize_rows(newk, kv_dtype, H, gran)
-            vq, vsc = quantize_rows(newv, kv_dtype, H, gran)
-            cc["k"] = cc["k"].at[:, phys, woff, :].set(
-                kq.astype(cc["k"].dtype), mode="drop")
-            cc["v"] = cc["v"].at[:, phys, woff, :].set(
-                vq.astype(cc["v"].dtype), mode="drop")
-            cc["ks"] = cc["ks"].at[:, phys, woff].set(
-                ksc.astype(cc["ks"].dtype), mode="drop")
-            cc["vs"] = cc["vs"].at[:, phys, woff].set(
-                vsc.astype(cc["vs"].dtype), mode="drop")
-        else:
-            cc["k"] = cc["k"].at[:, phys, woff, :].set(
-                newk.astype(cc["k"].dtype), mode="drop")
-            cc["v"] = cc["v"].at[:, phys, woff, :].set(
-                newv.astype(cc["v"].dtype), mode="drop")
-        return _decode_head(x_row[:, None, :], params, cfg, cd), cc
 
     def body(carry, inputs):
         h_in, cc = carry
         lp, layer_idx = inputs
-        if packed:
-            q_m, k_m, v_m = _cached_qkv_merged(h_in, lp, cfg, cd)
-            if use_pallas:
-                # kernel attends the STALE pages + fresh column (bit-
-                # equivalent to write-then-attend); write lands after.
-                # Quantized pools hand the kernel their scale layers
-                # (dequant inside the accumulation loop) and a fresh
-                # column pre-quantize-dequantized to the exact value
-                # the scatter below stores. On a >1 serve mesh the
-                # shard_map wrapper runs the same kernel per chip.
-                k_layer, v_layer, ks_layer, vs_layer = _pool_layer(
-                    cc, layer_idx)
-                k_new, v_new = k_m, v_m                      # (B, 1, C)
-                if quantized:
-                    from ..quant.kv import (fake_quantize_rows,
-                                            pool_quant_mode)
-                    kv_dtype, gran = pool_quant_mode(cc)
-                    k_new = fake_quantize_rows(k_new, kv_dtype, H,
-                                               gran).astype(cd)
-                    v_new = fake_quantize_rows(v_new, kv_dtype, H,
-                                               gran).astype(cd)
-                attn_merged = _paged_window_attn(
-                    q_m, k_new, v_new, k_layer, v_layer, tables,
-                    pos_eff, H, ks_layer, vs_layer, mesh)
-                cc = _scatter_kv(cc, layer_idx, phys, woff,
-                                 k_m[:, 0, :], v_m[:, 0, :], packed, H)
-            else:
-                cc = _scatter_kv(cc, layer_idx, phys, woff,
-                                 k_m[:, 0, :], v_m[:, 0, :], packed, H)
-                k_all, v_all = _gather_kv(cc, layer_idx, tables, packed,
-                                          H, cd)
-                attn_merged = _merge_heads(cached_attention(
-                    _split_heads(q_m, H), k_all, v_all, pos_eff))
+        q_m, k_m, v_m = _cached_qkv_merged(h_in, lp, cfg, cd)
+        if packed and use_pallas:
+            # kernel attends the STALE pages + fresh column (bit-
+            # equivalent to write-then-attend); write lands after.
+            # Quantized pools hand the kernel their scale layers
+            # (dequant inside the accumulation loop) and a fresh
+            # column pre-quantize-dequantized to the exact value
+            # the scatter below stores. On a >1 serve mesh the
+            # shard_map wrapper runs the same kernel per chip.
+            k_layer, v_layer, ks_layer, vs_layer = _pool_layer(
+                cc, layer_idx)
+            k_new, v_new = k_m, v_m                      # (B, 1, C)
+            if quantized:
+                from ..quant.kv import (fake_quantize_rows,
+                                        pool_quant_mode)
+                kv_dtype, gran = pool_quant_mode(cc)
+                k_new = fake_quantize_rows(k_new, kv_dtype, H,
+                                           gran).astype(cd)
+                v_new = fake_quantize_rows(v_new, kv_dtype, H,
+                                           gran).astype(cd)
+            attn_merged = _paged_window_attn(
+                q_m, k_new, v_new, k_layer, v_layer, tables,
+                pos_eff, H, ks_layer, vs_layer, mesh)
+            cc = _scatter_kv(cc, layer_idx, phys, woff,
+                             k_m[:, 0, :], v_m[:, 0, :], packed, H)
         else:
-            q_m, k_m, v_m = _cached_qkv_merged(h_in, lp, cfg, cd)
             cc = _scatter_kv(cc, layer_idx, phys, woff,
                              k_m[:, 0, :], v_m[:, 0, :], packed, H)
             k_all, v_all = _gather_kv(cc, layer_idx, tables, packed,
@@ -1318,13 +1243,11 @@ def decode_window_paged(params: Params, tok: jnp.ndarray, pos: jnp.ndarray,
                         eos: jnp.ndarray, tables: jnp.ndarray,
                         cache: Dict[str, jnp.ndarray], rngs: jnp.ndarray,
                         cfg: ModelConfig, *, sample_fn, length: int,
-                        use_pallas: bool = False, use_fused: bool = False,
-                        shardings=None):
+                        use_pallas: bool = False, shardings=None):
     """``length`` decode steps over the paged pool in ONE traced program
     — the device-resident loop the async serving engine dispatches once
     per WINDOW instead of once per token (the lax.scan analogue of the
-    training loop's steps-per-dispatch amortization; BENCH_r03 measured
-    the per-dispatch host tax this removes at 65 ms/step on TPU).
+    training loop's steps-per-dispatch amortization).
 
     tok/pos/active: the per-slot step state ``decode_step_paged`` takes;
     budget: (B,) int32 tokens each slot may still emit; eos: (B,) int32
@@ -1366,8 +1289,7 @@ def decode_window_paged(params: Params, tok: jnp.ndarray, pos: jnp.ndarray,
         tok, pos, active, budget, cache, rngs = carry
         logits, cache = decode_step_paged(
             params, tok, pos, active, tables, cache, cfg,
-            use_pallas=use_pallas, use_fused=use_fused,
-            shardings=shardings)
+            use_pallas=use_pallas, shardings=shardings)
         nxt, rngs = sample_fn(rngs, logits, active)
         nxt = jnp.where(active, nxt, 0)
         emitted = active

@@ -84,8 +84,7 @@ def paged_kernel_mesh_ok(mesh, n_pages=None, n_embd=None,
     divide over 'model' (the same divisibility-drop rule
     parallel.mesh.page_pool_pspec applies to the pool specs). Callers
     that cannot supply the geometry get the conservative answer for a
-    >1 mesh. The FUSED all-layers kernel stays 1x1-only — it streams
-    whole weight matrices per layer step, which TP shards."""
+    >1 mesh."""
     if mesh is None or mesh.size == 1:
         return True
     if n_pages is None or n_embd is None or n_head is None:
@@ -121,21 +120,6 @@ def mixed_step_kernel_ok(n_head: int, head_dim: int, page_size: int,
     return ok
 
 
-def clamped_live_page(p, pos, page_size: int):
-    """The fetch-skip trick for a walk of ONE page a grid step through
-    a block index map (the fused all-layers kernel in
-    ops/decode_pallas.py; this file's kernels walk a block of pages a
-    step and copy the owned ones themselves, ``_fetch_block``): logical
-    pages past a slot's live frontier map to the SAME logical page as
-    the previous grid step, and Pallas skips the DMA for a repeated
-    block index — so a slot at position ``pos`` streams ceil(pos/page)
-    pages regardless of max_pages. An idle slot (pos == 0) clamps to
-    page 0; its zero live pages are never read (the accumulation loop
-    is gated on ``p < live``)."""
-    live = (pos + page_size - 1) // page_size
-    return jnp.where(p < live, p, jnp.maximum(live - 1, 0))
-
-
 def paged_attention_envelope(n_head: int, head_dim: int, page_size: int,
                              *, itemsize: int = 2, mesh=None,
                              kv_quant: str = "none",
@@ -143,10 +127,9 @@ def paged_attention_envelope(n_head: int, head_dim: int, page_size: int,
                              n_pages=None, n_kv_head=None) -> tuple:
     """THE shared kernel envelope — one set of gate checks consumed by
     every route predicate (``paged_decode_supported``,
-    ``mixed_step_kernel_ok`` here; ``fused_paged_decode_supported`` in
-    ops/decode_pallas.py layers its VMEM/weight checks on top), so the
-    mesh/quant/shape logic cannot drift between the fused and per-layer
-    kernels. Returns ``(ok, reasons)`` — ``reasons`` names every failed
+    ``mixed_step_kernel_ok``), so the mesh/quant/shape logic cannot
+    drift between the decode and the windowed steps. Returns
+    ``(ok, reasons)`` — ``reasons`` names every failed
     check (the engine's kernel-route export surfaces them, so a silent
     XLA fallback is observable, not asserted).
 

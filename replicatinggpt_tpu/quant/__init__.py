@@ -19,7 +19,7 @@ Two halves, one config:
   as the K/V writes, so scales flow through copy-on-write splits, LRU
   eviction and radix prefix hits with zero extra bookkeeping — a page
   IS its rows plus their scales. Dequant happens inside the paged
-  Pallas kernels (ops/paged_pallas.py, ops/decode_pallas.py) and in
+  Pallas kernels (ops/paged_pallas.py) and in
   the XLA gather fallback (models.gpt._gather_pages), so every decode
   route reads quantized pages natively.
 - :mod:`~.weights` — absmax-per-output-channel weight quantization

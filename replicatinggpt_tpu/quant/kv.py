@@ -13,10 +13,10 @@ Numerics contract (what the parity tests pin): quantization math runs
 in float32 regardless of the compute dtype — ``scale = max(amax/qmax,
 eps)``, ``q = clip(round(x/scale))`` for int8 or a saturating e4m3
 cast for fp8 — and dequant is ``q * scale`` cast back to the compute
-dtype. Every route (XLA gather, per-layer kernel, fused kernel) uses
-exactly this formula, so kernel-vs-XLA greedy streams stay
-token-identical (the in-kernel fake-quant of the fresh column in
-ops/decode_pallas.py reproduces it bit-for-bit at f32).
+dtype. Both routes (XLA gather, per-layer kernel) use exactly this
+formula, so kernel-vs-XLA greedy streams stay token-identical (the
+kernel route attends a fresh column that ``fake_quantize_rows`` has
+already taken through the store's round trip).
 """
 
 from __future__ import annotations
@@ -127,60 +127,6 @@ def fake_quantize_rows(rows: jnp.ndarray, kv_dtype: str, n_head: int,
             q.shape[:-1] + (n_head, q.shape[-1] // n_head))
         return (qh * scale[..., None]).reshape(q.shape)
     return q.astype(jnp.float32) * scale[..., None]
-
-
-def fake_quantize_row_f32(row: jnp.ndarray, qmax: float,
-                          eps: float = SCALE_EPS) -> jnp.ndarray:
-    """quantize -> dequantize ONE row in pure f32 — the Pallas-kernel-
-    body form of :func:`fake_quantize_rows` at page granularity (the
-    fused decode kernel fake-quantizes its fresh column in-kernel and
-    cannot cheaply materialize int8 there). Quantized values are
-    integers within ±qmax, exact in f32, so skipping the int cast is
-    value-identical to the batched helper — pinned against it in
-    tests/test_quant.py; change the math HERE and both routes move
-    together."""
-    f = row.astype(jnp.float32)
-    s = jnp.maximum(jnp.max(jnp.abs(f)) / qmax, eps)
-    return jnp.clip(jnp.round(f / s), -qmax, qmax) * s
-
-
-def _fake_quantize_span_f32(f: jnp.ndarray, kv_dtype: str,
-                            eps: float = SCALE_EPS) -> jnp.ndarray:
-    """One scale span (a whole row at page granularity, one head's
-    lanes at head granularity) through quantize -> dequantize in f32.
-    int8 values are integers within ±qmax — exact in f32, so the int
-    cast is skipped (value-identical, pinned in tests/test_quant.py);
-    fp8 keeps the ACTUAL saturating e4m3 cast round-trip, because e4m3
-    mantissa rounding is not representable as a round()/clip() in f32.
-    """
-    qmax = kv_qmax(kv_dtype)
-    s = jnp.maximum(jnp.max(jnp.abs(f)) / qmax, eps)
-    if kv_dtype == "int8":
-        return jnp.clip(jnp.round(f / s), -qmax, qmax) * s
-    q = jnp.clip(f / s, -qmax, qmax).astype(jnp.float8_e4m3fn)
-    return q.astype(jnp.float32) * s
-
-
-def fake_quantize_row_body(row: jnp.ndarray, kv_dtype: str, n_head: int,
-                           granularity: str,
-                           eps: float = SCALE_EPS) -> jnp.ndarray:
-    """Kernel-body form of :func:`fake_quantize_rows` for ONE (1, C)
-    row, any dtype x granularity — what the fused decode kernel applies
-    to its fresh K/V column in-kernel so the column attends exactly the
-    value the caller's quantize-on-write scatter will store. Head
-    granularity runs the span math per static head lane slice (the
-    kernels address heads as D-wide lane slices, so the python loop
-    unrolls to the same slices). Math is :func:`quantize_rows`'s at
-    f32 — pinned value-identical in tests/test_quant.py; change it
-    THERE and HERE together."""
-    f = row.astype(jnp.float32)
-    if granularity == "head":
-        D = f.shape[-1] // n_head
-        return jnp.concatenate(
-            [_fake_quantize_span_f32(f[:, i * D:(i + 1) * D], kv_dtype,
-                                     eps)
-             for i in range(n_head)], axis=-1)
-    return _fake_quantize_span_f32(f, kv_dtype, eps)
 
 
 def dequant_gathered(g: jnp.ndarray, s: jnp.ndarray, packed: bool,

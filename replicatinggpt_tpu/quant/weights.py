@@ -2,8 +2,7 @@
 with dequant fused into the matmuls, behind a calibration pass.
 
 The four block kernels (qkv / attn_out / mlp_up / mlp_down) carry
-~all of a decode step's parameter bytes — the stream the fused decode
-roofline showed the step is bound by. Each is quantized symmetrically
+~all of a decode step's parameter bytes. Each is quantized symmetrically
 per OUTPUT channel: ``scale[c] = absmax(W[:, c]) / qmax``, stored as a
 ``<name>_scale`` float32 vector next to the int8/fp8 kernel in the
 params pytree. Per-output-channel scales commute through the matmul,

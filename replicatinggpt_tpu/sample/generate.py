@@ -467,7 +467,7 @@ def generate(params, prompt: jnp.ndarray, cfg: ModelConfig,
     import dataclasses as _dc
     gcfg = _dc.replace(gcfg, max_new_tokens=0)
 
-    # decode kernels (fused / packed attention) only where GSPMD cannot
+    # the packed decode-attention kernel only where GSPMD cannot
     # shard the segment — decided on the REAL params, outside jit
     allow_pallas = _all_single_device(params) and _all_single_device(prompt)
 

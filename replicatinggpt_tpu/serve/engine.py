@@ -30,7 +30,7 @@ Per step the engine:
    cache, and the host runs AHEAD of the device: window N+1 is
    dispatched before window N's token block is fetched (one async
    ``copy_to_host_async`` + ``np.asarray`` per window, not one
-   blocking snapshot per token — the BENCH_r03 dispatch-tax fix).
+   blocking snapshot per token).
    The window cadence is CONTINUOUS (ROADMAP item 4): an admission
    lands at a window boundary as host bookkeeping while window N-1 is
    still in flight, and the prompt's uncached tail prefills INSIDE
@@ -155,9 +155,7 @@ class EngineConfig:
     prefix_cache: bool = True  # radix prefix reuse (False: pages only)
     paged_kernel: bool = False  # opt-in Pallas paged decode fast path
                                 # (TPU, packed cache layout only):
-                                # prefers the fused all-layers kernel
-                                # (ops/decode_pallas.py), falls back to
-                                # the per-layer one (ops/paged_pallas)
+                                # the per-layer kernel (ops/paged_pallas)
     decode_window: int = 1      # decode steps rolled into one dispatch
                                 # at steady state (the --decode-window
                                 # knob): 1 = the blocked step-per-
@@ -285,12 +283,12 @@ class KernelRoute:
     verify) runs the unified Pallas kernel family; "xla" otherwise,
     with ``reasons`` naming each failed envelope check (the shared
     ``ops.paged_pallas.paged_attention_envelope`` vocabulary plus the
-    engine-level gates below). ``decode`` distinguishes which decode
-    kernel won: "fused" (all layers, one launch per step) vs "pallas"
-    (per-layer windowed kernel) vs "xla"."""
+    engine-level gates below). ``decode`` is the decode step's own
+    route: "pallas" (the per-layer windowed kernel) or "xla" (the
+    gather)."""
 
     route: str                    # "pallas" | "xla"
-    decode: str                   # "fused" | "pallas" | "xla"
+    decode: str                   # "pallas" | "xla"
     window: str                   # mixed/verify windowed steps ("none":
                                   # the family refuses both)
     sharded: bool                 # kernels run under shard_map
@@ -319,17 +317,14 @@ class KernelRoute:
 
 def decide_kernel_route(cfg: ModelConfig, ecfg: EngineConfig, qcfg,
                         page_size: int, n_pages: int, itemsize: int,
-                        n_slots: int, mesh) -> KernelRoute:
+                        mesh) -> KernelRoute:
     """Route every engine step family onto the unified Pallas kernel
     family, once, statically. The ONLY gates left are real envelope
     limits (shape/VMEM/backend) and the explicit ``paged_kernel`` knob
     — mixed windows, fp8/head-granularity pools, weight-quantized
     params and >1 (data, model) meshes all route Pallas now (ISSUE 20;
     the shard_map wrapper covers sharded engines when the pool
-    geometry divides, ``paged_kernel_mesh_ok``). The fused all-layers
-    kernel keeps its extra gates (packed weights streamed in-kernel:
-    1x1 mesh only, unquantized weights, VMEM weight budget) and wins
-    over the per-layer kernel when both fit."""
+    geometry divides, ``paged_kernel_mesh_ok``)."""
     from ..ops import paged_pallas
     reasons = []
     if not ecfg.paged_kernel:
@@ -344,15 +339,11 @@ def decide_kernel_route(cfg: ModelConfig, ecfg: EngineConfig, qcfg,
         n_pages=n_pages, n_kv_head=cfg.kv_heads)
     reasons.extend(env_reasons)
     base_ok = not reasons
-    fam = family(cfg)
-    use_fused = bool(base_ok and fam.fused_decode_ok(
-        cfg, n_slots, page_size, itemsize, mesh, qcfg))
     # None: the family has no mixed or verify step to route, and the
     # headline follows its decode kernel alone
-    windowed_ok = fam.window_kernel_ok(cfg, page_size, n_pages, itemsize,
-                                       mesh, qcfg)
-    decode = ("fused" if use_fused
-              else "pallas" if base_ok else "xla")
+    windowed_ok = family(cfg).window_kernel_ok(
+        cfg, page_size, n_pages, itemsize, mesh, qcfg)
+    decode = "pallas" if base_ok else "xla"
     window = ("none" if windowed_ok is None
               else "pallas" if (base_ok and windowed_ok) else "xla")
     route = "pallas" if (decode != "xla" and window != "xla") else "xla"
@@ -472,15 +463,13 @@ def _sampler(temp, top_k, top_p, greedy):
     return sample_fn
 
 
-@partial(jax.jit, static_argnames=("cfg", "k", "use_pallas", "use_fused",
-                                   "shardings"),
+@partial(jax.jit, static_argnames=("cfg", "k", "use_pallas", "shardings"),
          donate_argnames=("tok", "pos", "active", "budget", "cache",
                           "rngs"))
 def _engine_decode_window(params, tok, pos, active, budget, eos, life,
                           tables, cache, rngs, temp, top_k, top_p,
                           greedy, cfg: ModelConfig, k: int,
-                          use_pallas: bool = False,
-                          use_fused: bool = False, shardings=None):
+                          use_pallas: bool = False, shardings=None):
     """The steady-state program: ``k`` multi-slot PAGED decode + batched
     sample steps in ONE dispatch (``models.gpt.decode_window_paged``),
     with the whole per-slot step state ``(tok, pos, active, budget,
@@ -516,8 +505,7 @@ def _engine_decode_window(params, tok, pos, active, budget, eos, life,
                                tables, cache, rngs, cfg,
                                sample_fn=_sampler(temp, top_k, top_p, greedy),
                                length=k,
-                               use_pallas=use_pallas, use_fused=use_fused,
-                               shardings=shardings)
+                               use_pallas=use_pallas, shardings=shardings)
 
 
 @partial(jax.jit, static_argnames=("cfg", "k", "use_kernel", "shardings"),
@@ -865,15 +853,13 @@ class Engine:
         # (decode windows, mixed prefill+decode windows, speculative
         # verify) — decide_kernel_route() above; the decision is logged,
         # exported through metrics_summary()["kernel_route"], and
-        # mirrored as the kernel_route_pallas Prometheus gauge. The
-        # FUSED all-layers kernel is preferred for pure decode; the
-        # per-layer windowed kernel (and its shard_map wrapper on a >1
-        # mesh) carries everything else.
+        # mirrored as the kernel_route_pallas Prometheus gauge. One
+        # per-layer windowed kernel (its shard_map wrapper on a >1
+        # mesh) carries every step.
         itemsize = jnp.dtype(self.pool.kv_array.dtype).itemsize
         self.kernel_route = decide_kernel_route(
             cfg, ecfg, self.qcfg, self.pool.page_size,
-            self.pool.kv_array.shape[1], itemsize, P, self.mesh)
-        self._use_fused = self.kernel_route.decode == "fused"
+            self.pool.kv_array.shape[1], itemsize, self.mesh)
         self._use_pallas = self.kernel_route.decode == "pallas"
         self._use_window_kernel = self.kernel_route.window == "pallas"
         self.metrics.gauge("kernel_route_pallas",
@@ -881,8 +867,7 @@ class Engine:
                            else 0.0)
         # the per-layer kernel's walk over the pool's tables, for the
         # stats of ``serve/launch``: pages a grid step covers (0: the
-        # decode step runs no such kernel, or the fused one, which
-        # walks a page a step)
+        # decode step runs no such kernel)
         self._kv_block_pages = 0
         if self._use_pallas:
             from ..ops.paged_pallas import block_pages
@@ -1457,8 +1442,7 @@ class Engine:
         s["kv_global_bytes"], s["kv_window_bytes"] = \
             self.pool.bytes_by_kind()
         # dispatch amortization: the host tax per dispatch vs per token
-        # (the serve-side analogue of the train bench's dispatch split;
-        # BENCH_r03 measured 77.4 ms blocked vs 12.1 ms/step amortized)
+        # (the serve-side analogue of the train bench's dispatch split)
         c = self.metrics.counters
         disp = self.metrics.hist_summary("decode_dispatch_s")
         n_disp = int(c.get("decode_dispatches", 0))
@@ -1814,7 +1798,7 @@ class Engine:
                 self.params, *state, eos_d, self._z_life,
                 tables_d, cache, rngs, *sample,
                 self.cfg, k=k, use_pallas=self._use_pallas,
-                use_fused=self._use_fused, shardings=self._plan)
+                shardings=self._plan)
             _, _, t_, p_, a_, b_, cache, rngs = out
             state = (t_, p_, a_, b_)
             out = self._mixed_guard(
@@ -2029,8 +2013,7 @@ class Engine:
                 self.params, tok, pos, active, budget, eos_d, life,
                 tables_d, self.pool.cache, self._rngs,
                 temp_d, top_k_d, top_p_d, greedy_d, self.cfg, k=k,
-                use_pallas=self._use_pallas, use_fused=self._use_fused,
-                shardings=self._plan)
+                use_pallas=self._use_pallas, shardings=self._plan)
         toks, emitted, tok, pos, active, budget, cache, rngs = out
         self.pool.cache = cache
         self._rngs = rngs

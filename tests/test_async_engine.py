@@ -1,6 +1,6 @@
 """Async engine core (ISSUE 10) + continuous windows (ISSUE 13):
 multi-token decode windows, donated device-resident step state,
-double-buffered dispatch, the paged fused-decode kernel — and the
+double-buffered dispatch, the paged decode kernel — and the
 continuous-window upgrades: admissions riding MIXED prefill+decode
 windows instead of breaking to blocked k=1, deadlines/cancels landing
 as on-device lifecycle masks, and the bounded k-autotuner walking
@@ -704,12 +704,12 @@ def test_spec_transitions_still_count_window_breaks(params):
 
 
 # ---------------------------------------------------------------------------
-# fused paged kernel composes with windows
+# the paged kernel composes with windows
 # ---------------------------------------------------------------------------
 
-def test_fused_kernel_with_decode_window(params, monkeypatch):
-    """The fused all-layers paged kernel inside the window scan:
-    parity with the XLA window path (interpret mode on CPU)."""
+def test_paged_kernel_with_decode_window(params, monkeypatch):
+    """The per-layer paged kernel inside the k = 2 window scan: parity
+    with offline generate() (interpret mode on CPU)."""
     from replicatinggpt_tpu.ops import paged_pallas
     monkeypatch.setattr(paged_pallas, "_paged_attn_backend_ok",
                         lambda: True)
@@ -723,7 +723,7 @@ def test_fused_kernel_with_decode_window(params, monkeypatch):
     eng = Engine(p64, cfg, EngineConfig(pool_size=2, max_queue=4,
                                         page_size=8, paged_kernel=True,
                                         decode_window=2))
-    assert eng._use_fused
+    assert eng._use_pallas and eng.kernel_route.decode == "pallas"
     for r in reqs:
         assert eng.submit(r) is None
     got = {r.id: r.tokens for r in eng.drain()}

@@ -545,8 +545,8 @@ def test_the_pool_refuses_the_radix_cache_over_window_layers():
         PagedCachePool(CFG, 2, page_size=PSZ, prefix_cache=True)
 
 
-def test_fused_kernel_and_quantised_pool_are_not_routed(params,
-                                                       kernel_on_cpu):
+def test_the_decode_kernel_is_routed_and_a_quantised_pool_is_not(
+        params, kernel_on_cpu):
     assert Engine(params, CFG, ECFG).kernel_route.decode == "pallas"
     ok, why = paged_pallas.paged_attention_envelope(
         4, 32, PSZ, n_kv_head=2, kv_quant="int8")
@@ -665,16 +665,15 @@ def test_cancel_and_deadline_give_back_both_kinds_of_state(params,
 
 
 def test_the_route_asks_the_family_and_names_no_family():
-    """``decide_kernel_route`` reads the fused kernel's and the windowed
-    steps' fitness off ``family(cfg)``: GPT-2 answers both, this family
-    has no windowed step (None) and no fused kernel."""
+    """``decide_kernel_route`` reads the windowed steps' fitness off
+    ``family(cfg)``: GPT-2 answers it, this family has no windowed step
+    (None)."""
     import inspect
     from replicatinggpt_tpu.serve import engine as E
     assert "cfg.family" not in inspect.getsource(E.decide_kernel_route)
     qcfg = EngineConfig().quant()
     fam = family(CFG)
     assert fam.window_kernel_ok(CFG, PSZ, 16, 4, None, qcfg) is None
-    assert fam.fused_decode_ok(CFG, 3, PSZ, 4, None, qcfg) is False
     gcfg = dataclasses.replace(get_config("test-tiny").model, n_embd=64,
                                decode_cache_layout="packed")
     assert family(gcfg).window_kernel_ok(gcfg, 8, 16, 4, None,

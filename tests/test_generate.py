@@ -427,55 +427,6 @@ def test_decode_step_short_cache_parity():
             np.asarray(cache_b[key]))
 
 
-def test_fused_decode_step_matches_unfused(monkeypatch):
-    """The fused Pallas decode kernel (interpret mode on CPU) must match
-    the XLA layer-loop decode_step: logits and cache, across positions
-    including pos=0 and a mid-sequence pos with a warm cache."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    import replicatinggpt_tpu.models.gpt as gpt
-    from replicatinggpt_tpu.config import get_config
-    from replicatinggpt_tpu.models.gpt import decode_step, init_kv_cache
-    from replicatinggpt_tpu.ops.decode_pallas import fused_decode_supported
-    from replicatinggpt_tpu.train.state import create_train_state
-
-    from replicatinggpt_tpu.config import ModelConfig
-
-    m = ModelConfig(vocab_size=64, block_size=64, n_layer=2, n_head=2,
-                    n_embd=128, dropout=0.0, attn_dropout=0.0,
-                    dtype="float32")
-    assert fused_decode_supported(m, 1, 4)
-    assert not fused_decode_supported(m, 2, 4)          # B != 1
-    assert not fused_decode_supported(
-        get_config("test-tiny").model, 1, 4)            # D=16 unsupported
-    state = create_train_state(jax.random.PRNGKey(0), m,
-                               get_config("test-tiny").train)
-    toks = jax.random.randint(jax.random.PRNGKey(1), (6,), 0, m.vocab_size)
-
-    def run(fused):
-        monkeypatch.setattr(gpt, "_fused_decode_backend_ok", lambda: fused)
-        cache = init_kv_cache(m, 1)
-        outs = []
-        for pos in range(toks.shape[0]):
-            # allow_pallas=True: the conftest's 8-device CPU mesh makes
-            # the direct-call default conservative-False
-            logits, cache = decode_step(state.params, toks[pos:pos + 1],
-                                        jnp.int32(pos), cache, m,
-                                        allow_pallas=True)
-            outs.append(logits)
-        return jnp.stack(outs), cache
-
-    lf, cf = run(True)
-    lu, cu = run(False)
-    np.testing.assert_allclose(np.asarray(lf), np.asarray(lu), atol=2e-5,
-                               rtol=2e-5)
-    for key in ("k", "v"):
-        np.testing.assert_allclose(np.asarray(cf[key], np.float32),
-                                   np.asarray(cu[key], np.float32),
-                                   atol=2e-5, rtol=2e-5)
-
-
 PACKED_CFG = ModelConfig(vocab_size=65, block_size=32, n_layer=2, n_head=2,
                          n_embd=64, dropout=0.0, attn_dropout=0.0,
                          dtype="float32")  # D=32: packed-kernel envelope
@@ -586,48 +537,3 @@ def test_packed_decode_attention_kernel_unit():
         ref = ref.transpose(0, 2, 1, 3).reshape(B, C)
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    atol=2e-6, rtol=2e-6)
-
-
-@pytest.mark.slow
-def test_fused_decode_packed_cache_matches_xla(monkeypatch):
-    """The fused all-layers decode kernel on the PACKED (L,1,S,C) cache
-    (lane-sliced heads) must match the packed XLA path and the heads
-    layout — B=1 keeps its one-launch path under either cache layout."""
-    import dataclasses
-
-    import replicatinggpt_tpu.models.gpt as gpt
-    from replicatinggpt_tpu.config import get_config
-    from replicatinggpt_tpu.models.gpt import decode_step, init_kv_cache
-    from replicatinggpt_tpu.train.state import create_train_state
-
-    m = ModelConfig(vocab_size=64, block_size=64, n_layer=2, n_head=2,
-                    n_embd=128, dropout=0.0, attn_dropout=0.0,
-                    dtype="float32")
-    mp = dataclasses.replace(m, decode_cache_layout="packed")
-    state = create_train_state(jax.random.PRNGKey(0), m,
-                               get_config("test-tiny").train)
-    toks = jax.random.randint(jax.random.PRNGKey(1), (6,), 0, m.vocab_size)
-
-    def run(cfg, fused):
-        monkeypatch.setattr(gpt, "_fused_decode_backend_ok",
-                            lambda: fused)
-        cache = init_kv_cache(cfg, 1)
-        outs = []
-        for pos in range(toks.shape[0]):
-            logits, cache = decode_step(state.params, toks[pos:pos + 1],
-                                        jnp.int32(pos), cache, cfg,
-                                        allow_pallas=True)
-            outs.append(logits)
-        return np.asarray(jnp.stack(outs)), cache
-
-    heads_ref, _ = run(m, False)
-    fused_packed, cf = run(mp, True)
-    xla_packed, cu = run(mp, False)
-    np.testing.assert_allclose(fused_packed, heads_ref, atol=2e-5,
-                               rtol=2e-5)
-    np.testing.assert_array_equal(xla_packed, heads_ref)
-    # caches agree between the packed arms (same rows, same layout)
-    for key in ("k", "v"):
-        np.testing.assert_allclose(np.asarray(cf[key]),
-                                   np.asarray(cu[key]), atol=2e-6,
-                                   rtol=2e-6)

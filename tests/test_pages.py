@@ -465,13 +465,11 @@ def test_paged_pallas_kernel_matches_gather_reference(psz, mp, pos):
 
 
 def test_paged_kernel_engine_greedy_parity(params, monkeypatch):
-    """The engine's opt-in Pallas paged decode routes must keep exact
-    greedy parity with offline generate(). ``paged_kernel=True`` now
-    prefers the FUSED all-layers kernel (one launch per decode step,
-    ops/decode_pallas.fused_paged_decode_layers) and falls back to the
-    per-layer kernel (ops/paged_pallas) when the fused envelope says
-    no — both routes are pinned here."""
-    from replicatinggpt_tpu.ops import decode_pallas, paged_pallas
+    """The engine's opt-in Pallas paged decode route (the per-layer
+    kernel, ops/paged_pallas) must keep exact greedy parity with
+    offline generate(). Only the backend gate is opened: the envelope
+    decides for itself at the test's widths."""
+    from replicatinggpt_tpu.ops import paged_pallas
     monkeypatch.setattr(paged_pallas, "_paged_attn_backend_ok",
                         lambda: True)
     cfg = ModelConfig(vocab_size=65, block_size=32, n_layer=2, n_head=2,
@@ -485,44 +483,44 @@ def test_paged_kernel_engine_greedy_parity(params, monkeypatch):
     ecfg = EngineConfig(pool_size=2, max_queue=4, page_size=8,
                         paged_kernel=True)
     eng = Engine(p64, cfg, ecfg)
-    assert eng._use_fused, "fused kernel route should be on under the patch"
-    assert not eng._use_pallas
+    assert eng._use_pallas, "the kernel route should be on under the patch"
+    assert eng.kernel_route.decode == "pallas"
+    assert eng.kernel_route.reasons == ()
     for r in reqs:
         assert eng.submit(r) is None
     got = {r.id: r.tokens for r in eng.drain()}
     assert got == want
 
-    # per-layer fallback: force the fused envelope shut
-    monkeypatch.setattr(decode_pallas, "fused_paged_decode_supported",
-                        lambda *a, **kw: False)
-    eng2 = Engine(p64, cfg, ecfg)
-    assert eng2._use_pallas and not eng2._use_fused, \
-        "per-layer kernel route should be the fallback"
-    for r in reqs:
-        assert eng2.submit(r) is None
-    got2 = {r.id: r.tokens for r in eng2.drain()}
-    assert got2 == want
 
-
-def test_fused_paged_kernel_matches_xla_reference():
-    """Interpret-mode parity of the fused all-layers paged kernel
-    against the XLA gather path: logits and the post-write page pools
-    must match on mixed active/inactive slots at ragged positions."""
-    from replicatinggpt_tpu.models.gpt import (decode_step_paged,
-                                               init_paged_kv_pool)
-    from replicatinggpt_tpu.ops.decode_pallas import (
-        fused_paged_decode_supported)
+@pytest.mark.parametrize("kv_quant,granularity",
+                         [("none", "page"), ("int8", "page"),
+                          ("fp8", "head")],
+                         ids=["float32", "int8-page", "fp8-head"])
+def test_decode_step_paged_kernel_matches_xla_reference(kv_quant,
+                                                        granularity):
+    """Interpret-mode parity of ``decode_step_paged``'s kernel route
+    against its XLA gather route on one step: logits of the live rows
+    and the post-write page pools, on mixed active/inactive slots at
+    ragged positions. The kernel attends the STALE pool plus a fresh
+    column and scatters afterwards, the gather route writes first; an
+    inactive slot's write is dropped on both."""
+    from replicatinggpt_tpu.models.gpt import decode_step_paged
+    from replicatinggpt_tpu.quant.kv import quantize_rows
     cfg = ModelConfig(vocab_size=97, block_size=64, n_layer=3, n_head=2,
                       n_embd=64, dropout=0.0, attn_dropout=0.0,
                       dtype="float32", decode_cache_layout="packed")
     p = init_params(jax.random.PRNGKey(0), cfg)
     B, psz, N, mp = 4, 8, 32, 8
-    assert fused_paged_decode_supported(cfg, B, psz, 4)
     rng = np.random.default_rng(0)
-    cache = {"k": jnp.asarray(rng.normal(size=(cfg.n_layer, N, psz,
-                                               cfg.n_embd)), jnp.float32),
-             "v": jnp.asarray(rng.normal(size=(cfg.n_layer, N, psz,
-                                               cfg.n_embd)), jnp.float32)}
+    cache = {}
+    for name in ("k", "v"):
+        pages = jnp.asarray(rng.normal(size=(cfg.n_layer, N, psz,
+                                             cfg.n_embd)), jnp.float32)
+        if kv_quant == "none":
+            cache[name] = pages
+        else:
+            cache[name], cache[name + "s"] = quantize_rows(
+                pages, kv_quant, cfg.n_head, granularity)
     tables = jnp.asarray(rng.permutation(N)[:B * mp]
                          .reshape(B, mp).astype(np.int32))
     pos = jnp.asarray(np.array([5, 0, 17, 23], np.int32))
@@ -530,16 +528,25 @@ def test_fused_paged_kernel_matches_xla_reference():
     tok = jnp.asarray(np.array([3, 0, 9, 50], np.int32))
     ref_lg, ref_c = decode_step_paged(p, tok, pos, active, tables,
                                       cache, cfg)
-    fus_lg, fus_c = decode_step_paged(p, tok, pos, active, tables,
-                                      cache, cfg, use_fused=True)
+    ker_lg, ker_c = decode_step_paged(p, tok, pos, active, tables,
+                                      cache, cfg, use_pallas=True)
     am = np.asarray(active)
-    np.testing.assert_allclose(np.asarray(fus_lg)[am],
+    np.testing.assert_allclose(np.asarray(ker_lg)[am],
                                np.asarray(ref_lg)[am],
                                atol=1e-5, rtol=1e-5)
-    for name in ("k", "v"):
-        np.testing.assert_allclose(np.asarray(fus_c[name]),
-                                   np.asarray(ref_c[name]),
+    assert set(ker_c) == set(ref_c) == set(cache)
+    for name in cache:
+        np.testing.assert_allclose(np.asarray(ker_c[name], np.float32),
+                                   np.asarray(ref_c[name], np.float32),
                                    atol=1e-5, rtol=1e-5)
+    # the step wrote one row a layer for each LIVE slot and nothing else
+    changed = np.asarray(ker_c["k"], np.float32) != np.asarray(
+        cache["k"], np.float32)
+    rows = changed.any(axis=-1)                       # (L, N, psz)
+    assert rows.sum() == cfg.n_layer * int(am.sum())
+    for b in np.flatnonzero(am):
+        page = int(tables[b, int(pos[b]) // psz])
+        assert rows[:, page, int(pos[b]) % psz].all()
 
 
 # ---------------------------------------------------------------------------

@@ -92,27 +92,23 @@ def test_kernel_route_matrix_every_shipped_config(kernel_backend):
     for ecfg, mesh in matrix:
         route = decide_kernel_route(CFG, ecfg, ecfg.quant(),
                                     page_size=8, n_pages=16, itemsize=4,
-                                    n_slots=ecfg.pool_size, mesh=mesh)
+                                    mesh=mesh)
         assert route.route == "pallas", (ecfg, route)
         assert route.reasons == (), (ecfg, route)
         assert route.window == "pallas", (ecfg, route)
-        assert route.decode in ("fused", "pallas"), (ecfg, route)
-        # the fused all-layers kernel keeps its documented gates:
-        # unquantized weights + 1x1 mesh only
-        if ecfg.quant().weight_enabled or mesh is not None:
-            assert route.decode == "pallas", (ecfg, route)
+        assert route.decode == "pallas", (ecfg, route)
         assert route.sharded == (mesh is not None), (ecfg, route)
     # the knob still exists, and an off-route is attributable
     off = decide_kernel_route(CFG, EngineConfig(), EngineConfig().quant(),
                               page_size=8, n_pages=16, itemsize=4,
-                              n_slots=8, mesh=None)
-    assert off.route == "xla"
+                              mesh=None)
+    assert off.route == "xla" and off.decode == "xla"
     assert "paged_kernel_off" in off.reasons
     # indivisible mesh geometry names itself
     odd = decide_kernel_route(
         CFG, EngineConfig(paged_kernel=True, mesh_data=2, mesh_model=2),
         EngineConfig().quant(), page_size=8, n_pages=15, itemsize=4,
-        n_slots=8, mesh=mesh22)
+        mesh=mesh22)
     assert odd.route == "xla" and "mesh_indivisible" in odd.reasons
 
 
@@ -295,8 +291,7 @@ def test_launch_stats_count_the_blocks_the_device_mask_owns(kernel_backend):
     tel = Telemetry()
     eng = Engine(params, cfg, EngineConfig(
         pool_size=3, max_queue=8, page_size=8, prefill_chunk=64,
-        paged_kernel=True, prefix_cache=False, weight_quant="int8"),
-        telemetry=tel)          # quantized weights: not the fused kernel
+        paged_kernel=True, prefix_cache=False), telemetry=tel)
     assert eng.kernel_route.decode == "pallas"
     psz, mp = eng.pool.page_size, eng.pool.max_pages
     P = pp.block_pages(psz, mp, cfg.n_embd * 4)
@@ -379,7 +374,7 @@ def test_sharded_engine_kernel_greedy_parity(p64, kernel_backend):
                     reqs())
     assert eng.kernel_route.route == "pallas"
     assert eng.kernel_route.sharded
-    assert eng.kernel_route.decode == "pallas"   # fused is 1x1-only
+    assert eng.kernel_route.decode == "pallas"
     assert got == want
 
 

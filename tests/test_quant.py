@@ -376,11 +376,11 @@ def test_speculative_verify_quantized_parity(params):
 # ---------------------------------------------------------------------------
 
 def test_quantized_kernel_routes_greedy_parity(params, monkeypatch):
-    """Both Pallas routes (fused all-layers + per-layer paged
-    attention) dequant int8 pages IN-KERNEL and fake-quantize the
-    fresh column — greedy streams stay identical to the quantized XLA
-    gather route, which is itself parity-pinned against bf16 above."""
-    from replicatinggpt_tpu.ops import decode_pallas, paged_pallas
+    """The Pallas route (per-layer paged attention) dequants int8
+    pages IN-KERNEL and attends a fake-quantized fresh column — greedy
+    streams stay identical to the quantized XLA gather route, which is
+    itself parity-pinned against bf16 above."""
+    from replicatinggpt_tpu.ops import paged_pallas
     monkeypatch.setattr(paged_pallas, "_paged_attn_backend_ok",
                         lambda: True)
     cfg = dataclasses.replace(CFG, n_embd=64, vocab_size=65,
@@ -399,13 +399,8 @@ def test_quantized_kernel_routes_greedy_parity(params, monkeypatch):
                         kv_quant="int8")
     want, _ = run(ecfg)
     got, eng = run(dataclasses.replace(ecfg, paged_kernel=True))
-    assert eng._use_fused and not eng._use_pallas
+    assert eng._use_pallas and eng.kernel_route.decode == "pallas"
     assert got == want
-    monkeypatch.setattr(decode_pallas, "fused_paged_decode_supported",
-                        lambda *a, **kw: False)
-    got2, eng2 = run(dataclasses.replace(ecfg, paged_kernel=True))
-    assert eng2._use_pallas and not eng2._use_fused
-    assert got2 == want
 
 
 def test_kernel_envelopes_accept_every_quant_mode(params):
@@ -413,23 +408,14 @@ def test_kernel_envelopes_accept_every_quant_mode(params):
     scales dequant INSIDE the unified kernel family now, so the
     envelopes accept every shipped (kv_quant, granularity) cell —
     decided once per engine and exported via kernel_route."""
-    from replicatinggpt_tpu.ops.decode_pallas import (
-        fused_paged_decode_supported)
     from replicatinggpt_tpu.ops.paged_pallas import paged_decode_supported
-    cfg = dataclasses.replace(CFG, n_embd=64,
-                              decode_cache_layout="packed")
     for kvq in ("none", "int8", "fp8"):
         for gran in ("page", "head"):
-            assert fused_paged_decode_supported(cfg, 2, 8, 1,
-                                                kv_quant=kvq,
-                                                granularity=gran), \
-                (kvq, gran)
             assert paged_decode_supported(2, 32, 8, 1, kv_quant=kvq,
                                           granularity=gran), (kvq, gran)
     # unknown modes still gate (the reasons vocabulary stays honest)
     assert not paged_decode_supported(2, 32, 8, 1, kv_quant="int4")
-    assert not fused_paged_decode_supported(cfg, 2, 8, 1,
-                                            granularity="token")
+    assert not paged_decode_supported(2, 32, 8, 1, granularity="token")
 
 
 # ---------------------------------------------------------------------------
@@ -455,22 +441,6 @@ def test_calibration_roundtrip_and_budget(params, tmp_path):
                                       np.asarray(qp2["blocks"][name],
                                                  np.float32))
     assert load_calibration(str(tmp_path / "missing")) == (None, None)
-
-
-def test_fake_quant_row_matches_batched_helper():
-    """The fused kernel's in-body fake-quant (fake_quantize_row_f32 —
-    pure f32, no int8 materialization) must stay value-identical to
-    the batched quantize/dequantize helper the scatter path uses: this
-    equality IS the fused-vs-XLA token-identical contract."""
-    from replicatinggpt_tpu.quant.kv import (fake_quantize_row_f32,
-                                             fake_quantize_rows, kv_qmax)
-    rng = np.random.default_rng(0)
-    rows = jnp.asarray(rng.normal(size=(5, 1, 64)) * 3.0, jnp.float32)
-    batched = fake_quantize_rows(rows.reshape(5, 64), "int8", 2, "page")
-    for i in range(5):
-        np.testing.assert_array_equal(
-            np.asarray(fake_quantize_row_f32(rows[i], kv_qmax("int8"))),
-            np.asarray(batched[i])[None])
 
 
 def test_load_calibration_tolerates_corrupt_artifact(params, tmp_path):

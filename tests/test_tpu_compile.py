@@ -188,52 +188,6 @@ def test_packed_decode_attention_124m(mosaic, one_chip):
         row, row, row, cache, cache, _s((), jnp.int32, one_chip))
 
 
-def _char_blocks(cfg, sharding):
-    from replicatinggpt_tpu.models.gpt import init_params
-    shapes = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0),
-                                                cfg))["blocks"]
-    return jax.tree_util.tree_map(
-        lambda a: _s(a.shape, a.dtype, sharding), shapes)
-
-
-@pytest.mark.parametrize("layout", ["heads", "packed"])
-def test_fused_decode_layers_char(mosaic, one_chip, layout):
-    import dataclasses
-    from replicatinggpt_tpu.ops.decode_pallas import (
-        fused_decode_layers, fused_decode_supported)
-    cfg = dataclasses.replace(get_config("char-gpt").model,
-                              decode_cache_layout=layout)
-    assert fused_decode_supported(cfg, 1)
-    L, H, S, C = cfg.n_layer, cfg.n_head, cfg.block_size, cfg.n_embd
-    shape = ((L, 1, S, C) if layout == "packed"
-             else (L, 1, H, S, C // H))
-    kv = _s(shape, BF16, one_chip)
-    _compile(lambda x, b, p, k, v: fused_decode_layers(
-        x, b, p, {"k": k, "v": v}, cfg),
-        _s((1, C), BF16, one_chip), _char_blocks(cfg, one_chip),
-        _s((), jnp.int32, one_chip), kv, kv)
-
-
-def test_fused_paged_decode_layers_char(mosaic, one_chip):
-    """The kernel the engine's decode == "fused" route runs — refused
-    by the TPU compiler ("Cannot store scalars to VMEM") until its
-    per-head running max/sum became (1, 1) vector-row updates."""
-    import dataclasses
-    from replicatinggpt_tpu.ops.decode_pallas import (
-        fused_paged_decode_layers, fused_paged_decode_supported)
-    cfg = dataclasses.replace(get_config("char-gpt").model,
-                              decode_cache_layout="packed")
-    B, psz, mp = 8, 16, 16
-    assert fused_paged_decode_supported(cfg, B, psz)
-    L, C = cfg.n_layer, cfg.n_embd
-    pool = _s((L, B * mp, psz, C), BF16, one_chip)
-    _compile(lambda x, b, p, t, k, v: fused_paged_decode_layers(
-        x, b, p, t, {"k": k, "v": v}, cfg),
-        _s((B, C), BF16, one_chip), _char_blocks(cfg, one_chip),
-        _s((B,), jnp.int32, one_chip), _s((B, mp), jnp.int32, one_chip),
-        pool, pool)
-
-
 # ---------------------------------------------------------------------------
 # names: a kernel's name= is its HLO instruction's name, a named_scope is
 # in the op's metadata (what the profiler's trace and chipbench read)
@@ -288,45 +242,14 @@ def _packed_decode(one_chip):
         (row, row, row, cache, cache, _s((), jnp.int32, one_chip)))
 
 
-def _fused_decode(one_chip):
-    import dataclasses
-    from replicatinggpt_tpu.ops.decode_pallas import fused_decode_layers
-    cfg = dataclasses.replace(get_config("char-gpt").model,
-                              decode_cache_layout="packed")
-    L, S, C = cfg.n_layer, cfg.block_size, cfg.n_embd
-    kv = _s((L, 1, S, C), BF16, one_chip)
-    return (lambda x, b, p, k, v: fused_decode_layers(
-        x, b, p, {"k": k, "v": v}, cfg),
-        (_s((1, C), BF16, one_chip), _char_blocks(cfg, one_chip),
-         _s((), jnp.int32, one_chip), kv, kv))
-
-
-def _fused_paged_decode(one_chip):
-    import dataclasses
-    from replicatinggpt_tpu.ops.decode_pallas import (
-        fused_paged_decode_layers)
-    cfg = dataclasses.replace(get_config("char-gpt").model,
-                              decode_cache_layout="packed")
-    B, psz, mp = 8, 16, 16
-    L, C = cfg.n_layer, cfg.n_embd
-    pool = _s((L, B * mp, psz, C), BF16, one_chip)
-    return (lambda x, b, p, t, k, v: fused_paged_decode_layers(
-        x, b, p, t, {"k": k, "v": v}, cfg),
-        (_s((B, C), BF16, one_chip), _char_blocks(cfg, one_chip),
-         _s((B,), jnp.int32, one_chip), _s((B, mp), jnp.int32, one_chip),
-         pool, pool))
-
-
 @pytest.mark.parametrize("build,fwd,bwd", [
     (_flash_split, r"flash_\w*fwd", r"flash_\w*bwd"),
     (_flash_group, r"flash_group\w*_fwd", r"flash_group\w*_bwd"),
     (_flash_group_remat, r"flash_group\w*_fwd", r"flash_group\w*_bwd"),
     (_paged, r"^paged_window_attention\.", None),
     (_packed_decode, r"^decode_attention\.", None),
-    (_fused_decode, r"^fused_decode_layers\.", None),
-    (_fused_paged_decode, r"^fused_paged_decode_layers\.", None),
 ], ids=["flash", "flash-group", "flash-group-remat", "paged-window",
-        "packed-decode", "fused-decode", "fused-paged-decode"])
+        "packed-decode"])
 def test_kernel_name_is_the_hlo_instruction_name(mosaic, one_chip, build,
                                                  fwd, bwd):
     """Compiled for the described v5e, every ``tpu_custom_call`` of a
