@@ -401,21 +401,21 @@ def decode_step_paged(params: Params, idx_t, pos, active, tables,
         with jax.named_scope("attn_swa" if win else "attn_global"):
             q, k, v = _qkv(x, lp, cfg, pos_eff[:, None], win)
             kn, vn = (f"wk{j}", f"wv{j}") if win else (f"k{j}", f"v{j}")
-            with jax.named_scope("kv_gather"):
-                k_pages, v_pages = cc[kn][0], cc[vn][0]
             if use_pallas:
+                # the kernel addresses (layer, page): these arrays have
+                # one layer
                 from ..ops.paged_pallas import paged_gqa_attention
                 att = paged_gqa_attention(
-                    q, k, v, k_pages, v_pages,
+                    q, k, v, cc[kn], cc[vn],
                     ring_tables if win else tables, pos_eff,
-                    n_head=cfg.n_head, n_kv_head=cfg.kv_heads,
+                    n_head=cfg.n_head, n_kv_head=cfg.kv_heads, layer=0,
                     attn_window=W if win else 0,
                     page0=first if win else None,
                     name=("swa_window_attention" if win
                           else "paged_window_attention"))
             else:
                 att = _xla_paged_attention(
-                    q, k, v, k_pages, v_pages,
+                    q, k, v, cc[kn][0], cc[vn][0],
                     ring_tables if win else tables, pos_eff,
                     first if win else jnp.zeros_like(first),
                     W if win else 0, cfg)

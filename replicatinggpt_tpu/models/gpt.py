@@ -989,12 +989,15 @@ def paged_page_size(cfg: ModelConfig, cache: Dict[str, jnp.ndarray]) -> int:
         2 if cfg.decode_cache_layout == "packed" else 3])
 
 
-def _gather_pages(c_layer: jnp.ndarray, tables: jnp.ndarray,
-                  packed: bool, n_head: int, s_layer=None,
+def _gather_pages(pool: jnp.ndarray, layer_idx, tables: jnp.ndarray,
+                  packed: bool, n_head: int, scales=None,
                   cd=None) -> jnp.ndarray:
-    """Assemble per-slot logical K or V from one layer's page pool.
+    """Assemble per-slot logical K or V from layer ``layer_idx`` of the
+    stacked page pool, addressed IN PLACE: one gather whose index
+    carries the layer, so only the table's pages are read and no layer
+    of the pool is sliced out first.
 
-    c_layer: (N, page, C) packed or (N, H, page, D) heads; tables:
+    pool: (L, N, page, C) packed or (L, N, H, page, D) heads; tables:
     (B, max_pages) int32 physical-page ids (unmapped entries clamp to 0
     — the positions they cover are beyond every query's mask, so the
     garbage rows get exactly zero softmax weight). Returns the
@@ -1003,15 +1006,16 @@ def _gather_pages(c_layer: jnp.ndarray, tables: jnp.ndarray,
     contiguous (B, S, ...) slot read; the Pallas fast path
     (ops/paged_pallas.py) is the route that skips unmapped pages.
 
-    ``s_layer`` (one layer of a quantized pool's ``ks``/``vs`` scale
-    arrays) dequantizes the gathered view to ``cd`` right here — the
-    XLA half of the in-kernel dequant contract: every route reads
+    ``scales`` (a quantized pool's stacked ``ks``/``vs`` array, indexed
+    the same way) dequantizes the gathered view to ``cd`` right here —
+    the XLA half of the in-kernel dequant contract: every route reads
     quantized pages natively and multiplies scales at the gather, never
     materializing a full-precision pool."""
-    g = c_layer[tables]
-    if s_layer is not None:
+    g = pool[layer_idx, tables]
+    if scales is not None:
         from ..quant.kv import dequant_gathered
-        g = dequant_gathered(g, s_layer[tables], packed, n_head, cd)
+        g = dequant_gathered(g, scales[layer_idx, tables], packed, n_head,
+                             cd)
     if packed:
         B, mp, psz, C = g.shape
         return _split_heads(g.reshape(B, mp * psz, C), n_head)
@@ -1089,30 +1093,14 @@ def _scatter_kv(cc: Dict[str, jnp.ndarray], layer_idx, phys, woff,
 
 
 @jax.named_scope("kv_gather")
-def _pool_layer(cc: Dict[str, jnp.ndarray], layer_idx):
-    """Layer ``layer_idx`` of the stacked pool: ``(k, v, k_scales,
-    v_scales)``, the scales None on an unquantized pool. What the paged
-    kernel is handed, and what ``_gather_kv`` gathers pages from."""
-    k_l = jax.lax.dynamic_index_in_dim(cc["k"], layer_idx, 0, False)
-    v_l = jax.lax.dynamic_index_in_dim(cc["v"], layer_idx, 0, False)
-    ks_l = vs_l = None
-    if "ks" in cc:
-        ks_l = jax.lax.dynamic_index_in_dim(cc["ks"], layer_idx, 0, False)
-        vs_l = jax.lax.dynamic_index_in_dim(cc["vs"], layer_idx, 0, False)
-    return k_l, v_l, ks_l, vs_l
-
-
-@jax.named_scope("kv_gather")
 def _gather_kv(cc: Dict[str, jnp.ndarray], layer_idx, tables,
                packed: bool, n_head: int, cd):
     """Per-layer logical K/V views through ``_gather_pages``, with the
-    scale layers threaded for quantized pools (dequant at the gather —
+    stacked scales threaded for quantized pools (dequant at the gather —
     the XLA fallback's half of the in-kernel dequant contract)."""
-    k_l, v_l, ks_l, vs_l = _pool_layer(cc, layer_idx)
-    return (_gather_pages(k_l, tables, packed, n_head, s_layer=ks_l,
-                          cd=cd),
-            _gather_pages(v_l, tables, packed, n_head, s_layer=vs_l,
-                          cd=cd))
+    return tuple(_gather_pages(cc[n], layer_idx, tables, packed, n_head,
+                               scales=cc.get(n + "s"), cd=cd)
+                 for n in ("k", "v"))
 
 
 def _serve_kernel_mesh(shardings):
@@ -1128,23 +1116,38 @@ def _serve_kernel_mesh(shardings):
 
 
 @jax.named_scope("attn")
-def _paged_window_attn(q_w, k_w, v_w, k_layer, v_layer, tables, pos_eff,
-                       n_head, ks_layer, vs_layer, mesh):
+def _kernel_walk(cache: Dict[str, jnp.ndarray], tables, pos_eff, mesh):
+    """The paged kernel's walk of this step's tables and positions
+    (``ops.paged_pallas.window_walk``), built ONCE outside the layer
+    scan: every layer walks the same pages. None on a >1 mesh, where
+    each shard builds its own inside the ``shard_map``."""
+    if mesh is not None:
+        return None
+    from ..ops.paged_pallas import window_walk
+    k = cache["k"]
+    return window_walk(tables, pos_eff, k.shape[2],
+                       k.shape[3] * k.dtype.itemsize)
+
+
+@jax.named_scope("attn")
+def _paged_window_attn(q_w, k_w, v_w, cc, layer_idx, tables, pos_eff,
+                       n_head, mesh, walk):
     """One layer of windowed paged attention through the unified Pallas
     kernel family (ops/paged_pallas.py): the bare kernel on a single
-    device, the ``shard_map`` wrapper on a >1 (data, model) mesh. All
-    (B, W, C) in, (B, W, C) out, attending STALE pool + causal fresh
-    window — callers scatter the window rows afterwards."""
+    device, the ``shard_map`` wrapper on a >1 (data, model) mesh. Both
+    take the stacked pool ``cc`` whole and read layer ``layer_idx`` of
+    it in place, on the step's ``_kernel_walk``. All (B, W, C) in,
+    (B, W, C) out, attending STALE pool + causal fresh window — callers
+    scatter the window rows afterwards."""
+    from ..ops import paged_pallas
+    kw = dict(n_head=n_head, layer=layer_idx, k_scales=cc.get("ks"),
+              v_scales=cc.get("vs"))
     if mesh is not None:
-        from ..ops.paged_pallas import sharded_paged_window_attention
-        return sharded_paged_window_attention(
-            q_w, k_w, v_w, k_layer, v_layer, tables, pos_eff,
-            n_head=n_head, mesh=mesh, k_scales=ks_layer,
-            v_scales=vs_layer)
-    from ..ops.paged_pallas import paged_window_attention
-    return paged_window_attention(
-        q_w, k_w, v_w, k_layer, v_layer, tables, pos_eff,
-        n_head=n_head, k_scales=ks_layer, v_scales=vs_layer)
+        return paged_pallas.sharded_paged_window_attention(
+            q_w, k_w, v_w, cc["k"], cc["v"], tables, pos_eff, mesh=mesh,
+            **kw)
+    return paged_pallas.paged_window_attention(
+        q_w, k_w, v_w, cc["k"], cc["v"], tables, pos_eff, walk=walk, **kw)
 
 
 def decode_step_paged(params: Params, idx_t: jnp.ndarray, pos: jnp.ndarray,
@@ -1186,21 +1189,22 @@ def decode_step_paged(params: Params, idx_t: jnp.ndarray, pos: jnp.ndarray,
 
     quantized = "ks" in cache
     mesh = _serve_kernel_mesh(shardings)
+    use_pallas = packed and use_pallas
+    walk = (_kernel_walk(cache, tables, pos_eff, mesh) if use_pallas
+            else None)
 
     def body(carry, inputs):
         h_in, cc = carry
         lp, layer_idx = inputs
         q_m, k_m, v_m = _cached_qkv_merged(h_in, lp, cfg, cd)
-        if packed and use_pallas:
+        if use_pallas:
             # kernel attends the STALE pages + fresh column (bit-
             # equivalent to write-then-attend); write lands after.
-            # Quantized pools hand the kernel their scale layers
+            # Quantized pools hand the kernel their stacked scales
             # (dequant inside the accumulation loop) and a fresh
             # column pre-quantize-dequantized to the exact value
             # the scatter below stores. On a >1 serve mesh the
             # shard_map wrapper runs the same kernel per chip.
-            k_layer, v_layer, ks_layer, vs_layer = _pool_layer(
-                cc, layer_idx)
             k_new, v_new = k_m, v_m                      # (B, 1, C)
             if quantized:
                 from ..quant.kv import (fake_quantize_rows,
@@ -1211,8 +1215,8 @@ def decode_step_paged(params: Params, idx_t: jnp.ndarray, pos: jnp.ndarray,
                 v_new = fake_quantize_rows(v_new, kv_dtype, H,
                                            gran).astype(cd)
             attn_merged = _paged_window_attn(
-                q_m, k_new, v_new, k_layer, v_layer, tables,
-                pos_eff, H, ks_layer, vs_layer, mesh)
+                q_m, k_new, v_new, cc, layer_idx, tables, pos_eff, H,
+                mesh, walk)
             cc = _scatter_kv(cc, layer_idx, phys, woff,
                              k_m[:, 0, :], v_m[:, 0, :], packed, H)
         else:
@@ -1459,6 +1463,8 @@ def verify_step_paged(params: Params, window: jnp.ndarray, pos: jnp.ndarray,
     woff = jnp.where(valid & (abs_pos < Smax), abs_pos % psz, psz)
     quantized = "ks" in cache
     mesh = _serve_kernel_mesh(shardings)
+    walk = (_kernel_walk(cache, tables, pos_eff, mesh) if use_kernel
+            else None)
 
     def body(carry, inputs):
         h_in, cc = carry
@@ -1467,8 +1473,6 @@ def verify_step_paged(params: Params, window: jnp.ndarray, pos: jnp.ndarray,
         if use_kernel:
             # attend stale pool + causal fresh window in-kernel, then
             # scatter (write-then-attend equivalence, see docstring)
-            k_layer, v_layer, ks_layer, vs_layer = _pool_layer(
-                cc, layer_idx)
             k_w, v_w = k_m, v_m
             if quantized:
                 from ..quant.kv import (fake_quantize_rows,
@@ -1479,8 +1483,8 @@ def verify_step_paged(params: Params, window: jnp.ndarray, pos: jnp.ndarray,
                 v_w = fake_quantize_rows(v_m, kv_dtype, H,
                                          gran).astype(cd)
             attn_merged = _paged_window_attn(
-                q_m, k_w, v_w, k_layer, v_layer, tables, pos_eff, H,
-                ks_layer, vs_layer, mesh)
+                q_m, k_w, v_w, cc, layer_idx, tables, pos_eff, H, mesh,
+                walk)
             cc = _scatter_kv(cc, layer_idx, phys, woff, k_m, v_m,
                              packed, H)
         else:

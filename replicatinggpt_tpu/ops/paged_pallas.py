@@ -6,8 +6,10 @@ The XLA paged decode path (models/gpt.py decode_step_paged) gathers
 every slot's full (max_pages, page, C) view each layer each step —
 simple and parity-exact, but it fetches max_pages pages per slot
 regardless of how short the slot's sequence actually is. This kernel
-puts the page table in scalar-prefetch SMEM, leaves the pool in HBM and
-FETCHES FOR ITSELF: a grid step covers ``P`` consecutive logical pages
+puts the page table in scalar-prefetch SMEM, leaves the WHOLE STACKED
+pool (layers, n_pages, page, C) in HBM and FETCHES FOR ITSELF by
+``(layer, page)``, so no layer of the pool is sliced out for it: a grid
+step covers ``P`` consecutive logical pages
 of a slot (``block_pages``: ``P * page`` >= 128 tokens, 8 pages of 16),
 grid (B, ceil(max_pages / P)), block minor, and copies the block's
 pages, scattered in the pool, into one half of a (2, P * page, C) VMEM
@@ -200,11 +202,19 @@ def live_blocks(pos, page_size: int, n_block: int):
     return -(-pos // (page_size * n_block))
 
 
+# scalar-prefetch operands ``_fetch_block`` reads: ``_blocked_walk``'s five
+# and the pool's layer (``_at_layer``)
+N_WALK = 6
+
+
+@jax.named_scope("kv_gather")
 def _blocked_walk(tables: jnp.ndarray, owned: jnp.ndarray, page_size: int,
                   row_bytes: int) -> tuple:
     """The walk both kernels make, from a (B, max_pages) table and the
-    mask of the entries a slot's rows read: ``(P, n_blocks, prefetch)``,
-    ``prefetch`` the scalar operands ``_fetch_block`` reads.
+    mask of the entries a slot's rows read: ``(P, n_blocks, scalars)``,
+    ``scalars`` the operands ``_fetch_block`` reads beside the layer.
+    What is left OUTSIDE the kernel of addressing the pool's pages,
+    hence the scope; the same for every layer of a step.
 
     Grid step ``t = b * n_blocks + p`` covers logical pages p*P .. p*P +
     P - 1 of slot b. The table and the mask are padded to whole blocks
@@ -228,6 +238,27 @@ def _blocked_walk(tables: jnp.ndarray, owned: jnp.ndarray, page_size: int,
                    jnp.where(nxt < B * nb, nxt, -1))
 
 
+def _at_layer(scalars: tuple, layer) -> tuple:
+    """A walk's scalars and the layer of the stacked pool it reads:
+    ``_fetch_block``'s ``N_WALK`` operands."""
+    return (*scalars, jnp.asarray(layer, jnp.int32).reshape(1))
+
+
+def window_walk(tables: jnp.ndarray, pos: jnp.ndarray, page_size: int,
+                row_bytes: int, owned=None) -> tuple:
+    """``paged_window_attention``'s walk over ``tables`` at positions
+    ``pos`` (``_blocked_walk``'s triple), of pages ``row_bytes`` a token
+    wide. It depends on no layer: a caller whose layers run in one scan
+    builds it ONCE a step outside the scan and hands it to every
+    layer's call (``walk=``), where XLA would rebuild it a layer.
+    ``owned`` as in ``paged_window_attention``."""
+    pos = jnp.asarray(pos, jnp.int32)
+    if owned is None:
+        owned = gqa_owned_pages(pos, jnp.zeros_like(pos), tables.shape[1],
+                                page_size, 0)
+    return _blocked_walk(tables, owned, page_size, row_bytes)
+
+
 def _heads_per_slab(n_head: int, head_dim: int) -> int:
     """Heads taken together in one lane slab of the packed row: as many
     as fill 128 lanes and divide the head count (2 of gpt2's 64-wide
@@ -243,17 +274,19 @@ def _fetch_block(walk, pools, bufs, sem, n_block: int, n_blocks: int,
     """This grid step's block, fetched by the kernel itself: ``(live,
     half, cols)``.
 
-    ``pools`` are the pool's arrays left in HBM, ``bufs`` their (2, P *
-    page, width) VMEM halves. A live step finds its owned pages already
-    on their way into half ``rank % 2`` (the live step before it sent
-    for them; the first sends for its own), sends for the NEXT live
-    step's into the other half, then waits for its own: the copies of
-    block t + 1 run under the arithmetic of block t, across slots.
+    ``pools`` are the pool's stacked (layers, n_pages, page, width)
+    arrays left in HBM, addressed in place by ``(layer, page)``; ``bufs``
+    their (2, P * page, width) VMEM halves. A live step finds its owned
+    pages already on their way into half ``rank % 2`` (the live step
+    before it sent for them; the first sends for its own), sends for the
+    NEXT live step's into the other half, then waits for its own: the
+    copies of block t + 1 run under the arithmetic of block t, across
+    slots.
     Unowned pages are not fetched, and ``cols()`` masks their columns:
     what a half holds there is an older page or the zeros of the
     call's first step. A step that is not live reads three scalars
     here and copies nothing."""
-    table_ref, owned_ref, live_ref, rank_ref, next_ref = walk
+    table_ref, owned_ref, live_ref, rank_ref, next_ref, layer_ref = walk
     P, psz = n_block, page_size
     b0, p0 = pl.program_id(0), pl.program_id(1)
     t = b0 * n_blocks + p0
@@ -271,7 +304,7 @@ def _fetch_block(walk, pools, bufs, sem, n_block: int, n_blocks: int,
             def _owned():
                 for a, (pool, buf) in enumerate(zip(pools, bufs)):
                     act(pltpu.make_async_copy(
-                        pool.at[table_ref[b, p * P + j]],
+                        pool.at[layer_ref[0], table_ref[b, p * P + j]],
                         buf.at[half, pl.ds(pl.multiple_of(j * psz, psz),
                                            psz)],
                         sem.at[half, a]))
@@ -364,8 +397,8 @@ def _paged_window_kernel(*refs, n_head, head_dim, page_size, n_block,
     (acc, m, l) partials instead — the shard_map wrapper merges them
     across the 'data' axis (pmax/psum softmax merge) and folds the
     fresh window outside, where the collective lives."""
-    walk = refs[:5]
-    pos_ref, q_ref, knew_ref, vnew_ref, *refs = refs[5:]
+    walk = refs[:N_WALK]
+    pos_ref, q_ref, knew_ref, vnew_ref, *refs = refs[N_WALK:]
     if quantized:
         ksc_ref, vsc_ref, *refs = refs
     k_hbm, v_hbm, *refs = refs
@@ -451,12 +484,12 @@ def _paged_window_kernel(*refs, n_head, head_dim, page_size, n_block,
 
 
 def _pool_operands(arrays, n_block: int) -> tuple:
-    """``(in_specs, scratch_shapes)`` for pool arrays (n_pages, page,
-    width) the kernel fetches itself: left in HBM, a (2, P * page,
-    width) VMEM double buffer each, and a DMA semaphore a half an
-    array."""
+    """``(in_specs, scratch_shapes)`` for stacked pool arrays (layers,
+    n_pages, page, width) the kernel fetches itself: left in HBM, a (2,
+    P * page, width) VMEM double buffer each, and a DMA semaphore a half
+    an array."""
     return ([pl.BlockSpec(memory_space=pltpu.HBM)] * len(arrays),
-            [pltpu.VMEM((2, n_block * a.shape[1], a.shape[2]), a.dtype)
+            [pltpu.VMEM((2, n_block * a.shape[2], a.shape[3]), a.dtype)
              for a in arrays]
             + [pltpu.SemaphoreType.DMA((2, len(arrays)))])
 
@@ -464,25 +497,29 @@ def _pool_operands(arrays, n_block: int) -> tuple:
 def paged_window_attention(q: jnp.ndarray, k_new: jnp.ndarray,
                            v_new: jnp.ndarray, k_pages: jnp.ndarray,
                            v_pages: jnp.ndarray, tables: jnp.ndarray,
-                           pos: jnp.ndarray, *, n_head: int,
+                           pos: jnp.ndarray, *, n_head: int, layer,
                            k_scales=None, v_scales=None, owned=None,
-                           fold: bool = True):
+                           fold: bool = True, walk=None):
     """Windowed paged attention for one layer of a packed pool — the
     SINGLE entry point behind every per-layer engine route.
 
     q, k_new, v_new: (B, W, C) fresh merged window rows (row j of slot
     b sits at logical position ``pos[b] + j``; callers pad dead rows —
     garbage-in-garbage-out, the diagonal fold keeps them NaN-free);
-    k_pages/v_pages: (n_pages, page, C) STALE pool (positions >= pos
-    not yet written); tables: (B, max_pages) int32; pos: (B,) int32.
+    k_pages/v_pages: the WHOLE stacked (layers, n_pages, page, C) STALE
+    pool (positions >= pos not yet written) and ``layer`` the scalar
+    (traced inside a layer scan) that says which of its layers to read:
+    the kernel copies page ``(layer, table entry)``, nothing is sliced
+    out first; tables: (B, max_pages) int32; pos: (B,) int32.
     Returns (B, W, C) — bit-equivalent to scattering the window rows at
     pos..pos+W-1 and attending causally, because stale-pool history is
     masked to positions < pos and the in-window positions are covered
     by the causal fresh fold (write-then-attend == attend-stale-then-
     fold, the same contract the W=1 decode kernel always had).
 
-    ``k_scales``/``v_scales`` mark a QUANTIZED pool — (n_pages, page)
-    f32 at page granularity or (n_pages, page, H) at head granularity
+    ``k_scales``/``v_scales`` mark a QUANTIZED pool — stacked like the
+    pages, (layers, n_pages, page) f32 at page granularity or (layers,
+    n_pages, page, H) at head granularity
     (int8 or fp8 storage; the kernel only ever sees f32 scale blocks
     and ``astype``s the e4m3 pages like any storage dtype). The caller
     passes window rows already fake-quantized so the fresh fold attends
@@ -492,18 +529,18 @@ def paged_window_attention(q: jnp.ndarray, k_new: jnp.ndarray,
     table entries THIS call may read (with ``fold=False`` it returns
     raw (acc, m, l) partials for the cross-'data' softmax merge); plain
     callers leave it unset and get the prefix of pages that hold a
-    position < pos."""
-    N, psz, C = k_pages.shape
+    position < pos. ``walk`` is ``window_walk`` of the same tables,
+    positions and pool, built by a caller that runs many layers on it;
+    unset, it is built here."""
+    _, _, psz, C = k_pages.shape
     B, W, _ = q.shape
     D = C // n_head
     quantized = k_scales is not None
-    head_gran = quantized and k_scales.ndim == 3
+    head_gran = quantized and k_scales.ndim == 4
     pos = jnp.asarray(pos, jnp.int32)
-    if owned is None:
-        owned = gqa_owned_pages(pos, jnp.zeros_like(pos), tables.shape[1],
-                                psz, 0)
-    P, nb, walk = _blocked_walk(tables, owned, psz,
-                                C * k_pages.dtype.itemsize)
+    P, nb, walk = walk or window_walk(
+        tables, pos, psz, C * k_pages.dtype.itemsize, owned)
+    walk = _at_layer(walk, layer)
     kernel = functools.partial(
         _paged_window_kernel, n_head=n_head, head_dim=D, page_size=psz,
         n_block=P, n_blocks=nb, window=W, scale=D ** -0.5,
@@ -516,13 +553,15 @@ def paged_window_attention(q: jnp.ndarray, k_new: jnp.ndarray,
     in_specs, inputs = [row, row, row], [q, k_new, v_new]
     if quantized:
         # a pool's scales are a 64th of its bytes or less: XLA gathers
-        # each slot's in the order of its (padded) table, and a block's
-        # arrive as one (P * page, width) tile like the rows
+        # each slot's by (layer, page) in the order of its (padded)
+        # table, and a block's arrive as one (P * page, width) tile like
+        # the rows
         swidth = n_head if head_gran else 1
         in_specs += [_vmem_spec((None, None, P * psz, swidth),
                                 lambda b, p, *_: (b, p, 0, 0))] * 2
-        inputs += [sc.reshape(N, psz, swidth)[walk[0]].reshape(
-            B, nb, P * psz, swidth) for sc in (k_scales, v_scales)]
+        with jax.named_scope("kv_gather"):
+            inputs += [sc[layer, walk[0]].reshape(
+                B, nb, P * psz, swidth) for sc in (k_scales, v_scales)]
     pool_specs, pool_scratch = _pool_operands([k_pages, v_pages], P)
     hps = _heads_per_slab(n_head, D)
     state = (n_head // hps, hps * W)
@@ -557,7 +596,7 @@ def paged_window_attention(q: jnp.ndarray, k_new: jnp.ndarray,
 def paged_decode_attention(q: jnp.ndarray, k_new: jnp.ndarray,
                            v_new: jnp.ndarray, k_pages: jnp.ndarray,
                            v_pages: jnp.ndarray, tables: jnp.ndarray,
-                           pos: jnp.ndarray, *, n_head: int,
+                           pos: jnp.ndarray, *, n_head: int, layer,
                            k_scales=None, v_scales=None) -> jnp.ndarray:
     """Decode attention for one layer of a paged packed pool — the
     W=1 view of :func:`paged_window_attention` (kept as the named
@@ -569,7 +608,7 @@ def paged_decode_attention(q: jnp.ndarray, k_new: jnp.ndarray,
     positions <= pos; the caller scatters afterwards."""
     return paged_window_attention(
         q[:, None, :], k_new[:, None, :], v_new[:, None, :],
-        k_pages, v_pages, tables, pos, n_head=n_head,
+        k_pages, v_pages, tables, pos, n_head=n_head, layer=layer,
         k_scales=k_scales, v_scales=v_scales)[:, 0, :]
 
 
@@ -609,9 +648,13 @@ def sharded_paged_window_attention(q: jnp.ndarray, k_new: jnp.ndarray,
                                    k_pages: jnp.ndarray,
                                    v_pages: jnp.ndarray,
                                    tables: jnp.ndarray, pos: jnp.ndarray,
-                                   *, n_head: int, mesh,
+                                   *, n_head: int, mesh, layer,
                                    k_scales=None, v_scales=None):
-    """:func:`paged_window_attention` over a (data, model) serve mesh.
+    """:func:`paged_window_attention` over a (data, model) serve mesh,
+    the same operands: the stacked pool and the ``layer`` scalar, which
+    every shard gets whole (the layer axis is never sharded, so a
+    shard's block is addressed ``(layer, local page)`` like the bare
+    call's).
 
     ``shard_map`` runs the kernel per chip: the pool's page axis splits
     over 'data' (each shard holds a contiguous physical block of
@@ -632,18 +675,18 @@ def sharded_paged_window_attention(q: jnp.ndarray, k_new: jnp.ndarray,
     shape = dict(mesh.shape)
     data = int(shape.get("data", 1))
     model = int(shape.get("model", 1))
-    N, psz, C = k_pages.shape
+    _, N, psz, C = k_pages.shape
     mp = tables.shape[1]
     N_loc = N // data
     H_loc = n_head // model
     quantized = k_scales is not None
-    head_gran = quantized and k_scales.ndim == 3
+    head_gran = quantized and k_scales.ndim == 4
     d_ax = "data" if data > 1 else None
     m_ax = "model" if model > 1 else None
     qspec = P(None, None, m_ax)
-    pspec = P(d_ax, None, m_ax)
+    pspec = P(None, d_ax, None, m_ax)
 
-    def local_fn(q_l, kn_l, vn_l, kp_l, vp_l, tab, pos_l, *scales):
+    def local_fn(q_l, kn_l, vn_l, kp_l, vp_l, tab, pos_l, layer_l, *scales):
         ks_l, vs_l = scales if scales else (None, None)
         lo = jax.lax.axis_index("data") * N_loc
         live = (pos_l + psz - 1) // psz
@@ -653,7 +696,8 @@ def sharded_paged_window_attention(q: jnp.ndarray, k_new: jnp.ndarray,
                  & (tab < lo + N_loc))
         acc, m_, l_ = paged_window_attention(
             q_l, kn_l, vn_l, kp_l, vp_l, tab - lo, pos_l, n_head=H_loc,
-            k_scales=ks_l, v_scales=vs_l, owned=owned, fold=False)
+            layer=layer_l, k_scales=ks_l, v_scales=vs_l, owned=owned,
+            fold=False)
         # exact cross-shard online-softmax merge: max, rescale, sum
         m_g = jax.lax.pmax(m_, "data")
         corr = jnp.exp(m_ - m_g)      # 1 where both stayed NEG_INF
@@ -664,11 +708,13 @@ def sharded_paged_window_attention(q: jnp.ndarray, k_new: jnp.ndarray,
         return _fold_fresh_window(acc_g, m_g, l_g, q_l, kn_l, vn_l,
                                   H_loc).astype(q_l.dtype)
 
-    in_specs = [qspec, qspec, qspec, pspec, pspec, P(), P()]
+    in_specs = [qspec, qspec, qspec, pspec, pspec, P(), P(), P()]
     args = [q, k_new, v_new, k_pages, v_pages,
-            jnp.asarray(tables, jnp.int32), jnp.asarray(pos, jnp.int32)]
+            jnp.asarray(tables, jnp.int32), jnp.asarray(pos, jnp.int32),
+            jnp.asarray(layer, jnp.int32)]
     if quantized:
-        sspec = P(d_ax, None, m_ax) if head_gran else P(d_ax, None)
+        sspec = (P(None, d_ax, None, m_ax) if head_gran
+                 else P(None, d_ax, None))
         in_specs += [sspec, sspec]
         args += [k_scales, v_scales]
     return shard_map(local_fn, mesh=mesh, in_specs=tuple(in_specs),
@@ -695,9 +741,9 @@ def sharded_paged_window_attention(q: jnp.ndarray, k_new: jnp.ndarray,
 
 def _paged_gqa_kernel(*refs, n_kv_head, head_dim, page_size, n_block,
                       n_blocks, window, attn_window, scale):
-    walk = refs[:5]
+    walk = refs[:N_WALK]
     (pos_ref, page0_ref, q_ref, knew_ref, vnew_ref, k_hbm, v_hbm, out_ref,
-     acc_ref, m_ref, l_ref, k_buf, v_buf, sem) = refs[5:]
+     acc_ref, m_ref, l_ref, k_buf, v_buf, sem) = refs[N_WALK:]
     pools, bufs = (k_hbm, v_hbm), (k_buf, v_buf)
     b = pl.program_id(0)
     p = pl.program_id(1)
@@ -745,6 +791,7 @@ def _paged_gqa_kernel(*refs, n_kv_head, head_dim, page_size, n_block,
                 out_ref.dtype)
 
 
+@jax.named_scope("kv_gather")
 def gqa_owned_pages(pos: jnp.ndarray, page0: jnp.ndarray, n_table: int,
                     page_size: int, attn_window: int) -> jnp.ndarray:
     """(B, n_table) bool: the table entries whose page holds a position
@@ -761,11 +808,12 @@ def paged_gqa_attention(q: jnp.ndarray, k_new: jnp.ndarray,
                         v_new: jnp.ndarray, k_pages: jnp.ndarray,
                         v_pages: jnp.ndarray, tables: jnp.ndarray,
                         pos: jnp.ndarray, *, n_head: int, n_kv_head: int,
-                        attn_window: int = 0, page0=None,
+                        layer, attn_window: int = 0, page0=None,
                         name: str = "paged_window_attention"):
     """``paged_window_attention`` for grouped queries: q (B, W, n_head*D),
-    k_new/v_new (B, W, n_kv_head*D), pages (N, page, n_kv_head*D); query
-    head n reads KV head ``n // (n_head // n_kv_head)``. Same contract:
+    k_new/v_new (B, W, n_kv_head*D), pages (layers, N, page,
+    n_kv_head*D) read at ``layer``; query head n reads KV head
+    ``n // (n_head // n_kv_head)``. Same contract:
     attends the STALE pages masked to positions < pos and folds the fresh
     causal window, so the caller scatters afterwards.
 
@@ -776,7 +824,7 @@ def paged_gqa_attention(q: jnp.ndarray, k_new: jnp.ndarray,
     the first page of its walk. ``name`` is the kernel's name in the
     HLO and the trace: full layers keep ``paged_window_attention``, a
     window layer's call says ``swa_...``."""
-    N, psz, Ckv = k_pages.shape
+    _, _, psz, Ckv = k_pages.shape
     B, W, Cq = q.shape
     D = Ckv // n_kv_head
     G = n_head // n_kv_head
@@ -789,6 +837,7 @@ def paged_gqa_attention(q: jnp.ndarray, k_new: jnp.ndarray,
         tables, gqa_owned_pages(pos, page0, tables.shape[1], psz,
                                 attn_window),
         psz, Ckv * k_pages.dtype.itemsize)
+    walk = _at_layer(walk, layer)
     # a KV head's G query heads as G*W rows of one block
     qg = (q.reshape(B, W, n_kv_head, G, D).transpose(0, 2, 3, 1, 4)
           .reshape(B, n_kv_head, G * W, D))

@@ -260,14 +260,20 @@ def test_near_ties_are_counted_inside_the_margin_and_never_taken(params):
 
 # ------------------------------------------------------------------ 5. kernel
 
+# the kernel addresses (layer, page) of a stacked pool: the cases stack
+# three layers of different values and read the middle one, which alone
+# the einsum reference is handed
+KERNEL_LAYER = 1
+
+
 def _kernel_case(seed, B, W, Hq, Hkv, D, psz, mp, pos, window, page0=None):
     ks = jax.random.split(jax.random.PRNGKey(seed), 5)
     N = B * mp
     q = jax.random.normal(ks[0], (B, W, Hq * D))
     kn = jax.random.normal(ks[1], (B, W, Hkv * D))
     vn = jax.random.normal(ks[2], (B, W, Hkv * D))
-    kp = jax.random.normal(ks[3], (N, psz, Hkv * D))
-    vp = jax.random.normal(ks[4], (N, psz, Hkv * D))
+    kp = jax.random.normal(ks[3], (3, N, psz, Hkv * D))
+    vp = jax.random.normal(ks[4], (3, N, psz, Hkv * D))
     tables = jnp.asarray(np.random.default_rng(seed).permutation(N)
                          .astype(np.int32).reshape(B, mp))
     return q, kn, vn, kp, vp, tables, jnp.asarray(pos, jnp.int32)
@@ -279,6 +285,7 @@ def _einsum_attention(q, kn, vn, kp, vp, tables, pos, Hq, Hkv, window,
     attends stale positions < pos of its table and fresh rows 0..j, all
     above pos + j - window."""
     B, W, _ = q.shape
+    kp, vp = kp[KERNEL_LAYER], vp[KERNEL_LAYER]
     psz = kp.shape[1]
     mp = tables.shape[1]
     D = kp.shape[-1] // Hkv
@@ -331,7 +338,8 @@ def test_gqa_kernel_and_its_lower_bound_against_einsum(W, window, pos, mp):
                                                   mp, pos, window)
     got = paged_pallas.paged_gqa_attention(
         q, kn, vn, kp, vp, tables, pos, n_head=Hq, n_kv_head=Hkv,
-        attn_window=window, name="swa_test" if window else "full_test")
+        layer=KERNEL_LAYER, attn_window=window,
+        name="swa_test" if window else "full_test")
     want = _einsum_attention(q, kn, vn, kp, vp, tables, pos, Hq, Hkv,
                              window, None)
     assert np.abs(np.asarray(got) - want).max() < 1e-5
@@ -357,7 +365,8 @@ def test_gqa_kernel_walks_a_ring_from_page0(psz, mp, window, pos, dtype,
     rows = [a.astype(dtype) for a in rows]
     got = paged_pallas.paged_gqa_attention(
         *rows, tables, pos, n_head=Hq, n_kv_head=Hkv,
-        attn_window=window, page0=jnp.asarray(page0, jnp.int32),
+        layer=KERNEL_LAYER, attn_window=window,
+        page0=jnp.asarray(page0, jnp.int32),
         name="swa_test")
     want = _einsum_attention(*(np.asarray(a, np.float32) for a in rows),
                              tables, pos, Hq, Hkv, window, page0)

@@ -20,7 +20,9 @@ from replicatinggpt_tpu.serve import (Engine, EngineConfig, PageAllocator,
                                       Scheduler, compile_counts, run_replay)
 from replicatinggpt_tpu.serve.requests import FINISH_MAX_TOKENS
 
-CFG = ModelConfig(vocab_size=65, block_size=32, n_layer=2, n_head=2,
+# three layers: the paged programs address the stacked pool by (layer,
+# page), and every parity below has a middle layer to get wrong
+CFG = ModelConfig(vocab_size=65, block_size=32, n_layer=3, n_head=2,
                   n_embd=32, dropout=0.0, attn_dropout=0.0, dtype="float32")
 
 
@@ -419,6 +421,74 @@ def test_verify_step_paged_matches_multi(params, layout):
                                    atol=1e-5, rtol=1e-5)
 
 
+@pytest.mark.parametrize("layout", ["heads", "packed"])
+def test_prefill_chunk_paged_addresses_its_own_layer(params, layout):
+    """``prefill_chunk_paged`` over a stacked pool of three layers whose
+    every row starts as noise: a prompt in two chunks through a scrambled
+    table. THE PAGES IT WRITES: each layer's pages of the table hold the
+    plain forward's K/V of that layer (``prefill``), position by
+    position, and every other row of the pool is the noise it was. THE
+    ROWS IT ATTENDS: the second chunk reads the first's rows through the
+    table, layer by layer, so a deeper layer's K/V already say whether
+    the layer before it gathered its own pages; the last layer's gather
+    shows in the logits of the next decode step against the plain
+    forward's. A gather that read layer 0's pages for every layer, or a
+    slice that lost the layer, fails both."""
+    from replicatinggpt_tpu.models.gpt import (decode_step_paged, forward,
+                                               init_kv_cache, prefill,
+                                               prefill_chunk_paged)
+    cfg = dataclasses.replace(CFG, decode_cache_layout=layout)
+    assert cfg.n_layer >= 3
+    psz, Pc, n_prompt = 8, 8, 13
+    mp = cfg.block_size // psz
+    N = 2 * mp
+    rng = np.random.default_rng(7)
+    prompt = rng.integers(0, cfg.vocab_size, (1, n_prompt + 1)).astype(
+        np.int32)
+    shape = ((cfg.n_layer, N, psz, cfg.n_embd) if layout == "packed"
+             else (cfg.n_layer, N, cfg.n_head, psz, cfg.head_dim))
+    noise = {n: rng.normal(size=shape).astype(np.float32) for n in "kv"}
+    pool = {n: jnp.asarray(a) for n, a in noise.items()}
+    table = rng.permutation(N)[:mp].astype(np.int32)
+    for off in range(0, n_prompt, Pc):
+        chunk = np.zeros((1, Pc), np.int32)
+        n = min(Pc, n_prompt - off)
+        chunk[0, :n] = prompt[0, off:off + n]
+        pool = prefill_chunk_paged(
+            params, jnp.asarray(chunk), jnp.int32(off), jnp.int32(n_prompt),
+            jnp.asarray(table), pool, cfg)
+    want = prefill(params, jnp.asarray(prompt[:, :n_prompt]),
+                   init_kv_cache(cfg, 1), cfg)
+    for name in "kv":
+        got, ref = np.asarray(pool[name]), np.asarray(want[name])
+        written = np.zeros(got.shape[:2] + (psz,), bool)   # (L, N, psz)
+        for pos in range(n_prompt):
+            page, o = int(table[pos // psz]), pos % psz
+            written[:, page, o] = True
+            for l in range(cfg.n_layer):
+                g = got[l, page, o] if layout == "packed" else got[
+                    l, page, :, o]
+                r = ref[l, 0, pos] if layout == "packed" else ref[
+                    l, 0, :, pos]
+                np.testing.assert_allclose(g, r, atol=1e-5, rtol=1e-5,
+                                           err_msg=f"{name} L{l} p{pos}")
+        same = got == noise[name]
+        rows = (same.all(-1) if layout == "packed"
+                else same.all(-1).all(-2))                 # (L, N, psz)
+        assert (rows == ~written).all()
+    # the layers' K differ, so layer 0's rows would not pass for layer 1's
+    assert np.abs(np.asarray(want["k"])[1] - np.asarray(want["k"])[0]
+                  ).max() > 1e-2
+    lg, _ = decode_step_paged(
+        params, jnp.asarray(prompt[:, n_prompt]),
+        jnp.asarray([n_prompt], jnp.int32), jnp.asarray([True]),
+        jnp.asarray(table[None]), pool, cfg)
+    ref_lg, _ = forward(params, jnp.asarray(prompt), cfg)
+    np.testing.assert_allclose(np.asarray(lg)[0],
+                               np.asarray(ref_lg)[0, n_prompt],
+                               atol=1e-4, rtol=1e-4)
+
+
 # ---------------------------------------------------------------------------
 # Pallas fast path (interpret mode on CPU)
 # ---------------------------------------------------------------------------
@@ -435,8 +505,9 @@ def test_paged_pallas_kernel_matches_gather_reference(psz, mp, pos):
     B, H, D = 3, 2, 32
     N = B * mp + 1
     C = H * D
-    kp = jnp.asarray(rng.normal(size=(N, psz, C)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(N, psz, C)), jnp.float32)
+    # a stacked pool of three layers; the kernel reads the middle one
+    kp = jnp.asarray(rng.normal(size=(3, N, psz, C)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(3, N, psz, C)), jnp.float32)
     pos = np.array(pos, np.int32)          # incl. the pos=0 fresh-only row
     # mapped up to the page the fresh row lands in; the rest stale 0s
     tables = np.zeros((B, mp), np.int32)
@@ -448,9 +519,10 @@ def test_paged_pallas_kernel_matches_gather_reference(psz, mp, pos):
     kn = jnp.asarray(rng.normal(size=(B, C)), jnp.float32)
     vn = jnp.asarray(rng.normal(size=(B, C)), jnp.float32)
     out = paged_pallas.paged_decode_attention(
-        q, kn, vn, kp, vp, jnp.asarray(tables), jnp.asarray(pos), n_head=H)
-    ka = np.asarray(kp)[tables].reshape(B, mp * psz, C).copy()
-    va = np.asarray(vp)[tables].reshape(B, mp * psz, C).copy()
+        q, kn, vn, kp, vp, jnp.asarray(tables), jnp.asarray(pos), n_head=H,
+        layer=1)
+    ka = np.asarray(kp)[1][tables].reshape(B, mp * psz, C).copy()
+    va = np.asarray(vp)[1][tables].reshape(B, mp * psz, C).copy()
     for b in range(B):
         ka[b, pos[b]] = np.asarray(kn)[b]
         va[b, pos[b]] = np.asarray(vn)[b]
@@ -472,7 +544,7 @@ def test_paged_kernel_engine_greedy_parity(params, monkeypatch):
     from replicatinggpt_tpu.ops import paged_pallas
     monkeypatch.setattr(paged_pallas, "_paged_attn_backend_ok",
                         lambda: True)
-    cfg = ModelConfig(vocab_size=65, block_size=32, n_layer=2, n_head=2,
+    cfg = ModelConfig(vocab_size=65, block_size=32, n_layer=3, n_head=2,
                       n_embd=64, dropout=0.0, attn_dropout=0.0,
                       dtype="float32", decode_cache_layout="packed")
     p64 = init_params(jax.random.PRNGKey(1), cfg)

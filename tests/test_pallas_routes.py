@@ -28,7 +28,10 @@ from replicatinggpt_tpu.models.gpt import init_params
 from replicatinggpt_tpu.serve import (Engine, EngineConfig, ReplayConfig,
                                       Request, SamplingParams, run_replay)
 
-CFG = ModelConfig(vocab_size=65, block_size=32, n_layer=2, n_head=2,
+# three layers: the kernel and the gather address the stacked pool by
+# (layer, page), so a program that read layer 0's pages (or scales) for
+# every layer would stream other tokens than the XLA route
+CFG = ModelConfig(vocab_size=65, block_size=32, n_layer=3, n_head=2,
                   n_embd=64, dropout=0.0, attn_dropout=0.0,
                   dtype="float32", decode_cache_layout="packed")
 
@@ -153,6 +156,12 @@ WALKS = {
 }
 
 
+# the pools are STACKED, three layers of different values, and every
+# parity below reads the middle one: the reference is handed that layer
+# alone, so a kernel that read layer 0 (or its scales) would miss it
+N_LAYERS, LAYER = 3, 1
+
+
 def _window_inputs(seed=0, walk="one-block"):
     rng = np.random.default_rng(seed)
     W, psz, mp, pos = WALKS[walk]
@@ -161,8 +170,8 @@ def _window_inputs(seed=0, walk="one-block"):
     N = B * mp
     tables = rng.permutation(N).reshape(B, mp).astype(np.int32)
     mk = lambda *s: rng.standard_normal(s).astype(np.float32)  # noqa: E731
-    return (mk(B, W, C), mk(B, W, C), mk(B, W, C), mk(N, psz, C),
-            mk(N, psz, C), tables, pos)
+    return (mk(B, W, C), mk(B, W, C), mk(B, W, C),
+            mk(N_LAYERS, N, psz, C), mk(N_LAYERS, N, psz, C), tables, pos)
 
 
 @pytest.mark.parametrize("dtype,tol", [("float32", 1e-5),
@@ -181,18 +190,31 @@ def test_blocked_walk_matches_gather_reference(walk_name, dtype, tol):
                          for a in (q, kn, vn, kp, vp))
     out = pp.paged_window_attention(
         *(jnp.asarray(a, dtype) for a in (q, kn, vn, kp, vp)),
-        jnp.array(tables), jnp.array(pos), n_head=2)
+        jnp.array(tables), jnp.array(pos), n_head=2, layer=LAYER)
     assert out.dtype == jnp.dtype(dtype)
-    np.testing.assert_allclose(
-        np.asarray(out, np.float32),
-        _window_ref(q, kn, vn, kp, vp, tables, pos, 2), atol=tol, rtol=tol)
-    psz, mp = kp.shape[1], tables.shape[1]
+    ref = lambda l: _window_ref(q, kn, vn, kp[l], vp[l], tables,  # noqa: E731
+                                pos, 2)
+    np.testing.assert_allclose(np.asarray(out, np.float32), ref(LAYER),
+                               atol=tol, rtol=tol)
+    # the layers differ by far more than the tolerance: layer 0 would fail
+    assert np.abs(ref(LAYER) - ref(0)).max() > 0.1
+    psz, mp = kp.shape[2], tables.shape[1]
     owned = pp.gqa_owned_pages(jnp.array(pos), jnp.zeros_like(pos), mp,
                                psz, 0)
-    P, nb, walk = pp._blocked_walk(jnp.array(tables), owned, psz,
-                                   kp.shape[2] * 4)
+    P, nb, walk = pp.window_walk(jnp.array(tables), jnp.array(pos), psz,
+                                 kp.shape[3] * 4)
     assert P == min(128 // psz, mp) and nb == -(-mp // P)
     assert (nb > 1) == (walk_name != "one-block")
+    # one walk for every layer of a step: handed in at another layer, it
+    # gives that layer's output
+    handed = pp.paged_window_attention(
+        *(jnp.asarray(a, dtype) for a in (q, kn, vn, kp, vp)),
+        jnp.array(tables), jnp.array(pos), n_head=2, layer=2,
+        walk=(P, nb, walk))
+    np.testing.assert_allclose(np.asarray(handed, np.float32), ref(2),
+                               atol=tol, rtol=tol)
+    scalars = pp._at_layer(walk, LAYER)
+    assert len(scalars) == pp.N_WALK and scalars[-1].tolist() == [LAYER]
     table, own, live, rank, nxt = (np.asarray(a) for a in walk)
     own = own.astype(bool)
     assert table.shape == own.shape == (len(pos), nb * P)
@@ -230,13 +252,19 @@ def test_windowed_kernel_quantized_parity(kv_dtype, gran, walk):
     vpf = np.asarray(vq, np.float32).astype(np.float32) * expand(vs)
     knf = np.asarray(fake_quantize_rows(jnp.array(kn), kv_dtype, H, gran))
     vnf = np.asarray(fake_quantize_rows(jnp.array(vn), kv_dtype, H, gran))
-    ref = _window_ref(q, knf, vnf, kpf, vpf, tables, pos, H)
+    ref = _window_ref(q, knf, vnf, kpf[LAYER], vpf[LAYER], tables, pos, H)
     out = pp.paged_window_attention(
         jnp.array(q), jnp.array(knf), jnp.array(vnf), kq, vq,
-        jnp.array(tables), jnp.array(pos), n_head=H,
+        jnp.array(tables), jnp.array(pos), n_head=H, layer=LAYER,
         k_scales=ks, v_scales=vs)
     np.testing.assert_allclose(np.asarray(out), ref, atol=1e-4,
                                rtol=1e-4)
+    # the right pages under another layer's scales are told apart
+    wrong = _window_ref(q, knf, vnf,
+                        np.asarray(kq[LAYER], np.float32) * expand(ks)[0],
+                        vpf[LAYER], tables, pos, H)
+    if (pos > 0).any():
+        assert np.abs(wrong - ref).max() > 1e-3
 
 
 @pytest.mark.parametrize("walk", ["one-block", "mid-block",
@@ -255,11 +283,11 @@ def test_sharded_window_kernel_matches_reference(walk):
     mesh = make_serve_mesh(2, 2)
     q, kn, vn, kp, vp, tables, pos = _window_inputs(seed=3, walk=walk)
     H, D = 2, 32
-    ref = _window_ref(q, kn, vn, kp, vp, tables, pos, H)
+    ref = _window_ref(q, kn, vn, kp[LAYER], vp[LAYER], tables, pos, H)
     out = pp.sharded_paged_window_attention(
         jnp.array(q), jnp.array(kn), jnp.array(vn), jnp.array(kp),
         jnp.array(vp), jnp.array(tables), jnp.array(pos), n_head=H,
-        mesh=mesh)
+        mesh=mesh, layer=LAYER)
     np.testing.assert_allclose(np.asarray(out), ref, atol=1e-5,
                                rtol=1e-5)
     kq, ks = quantize_rows(jnp.array(kp), "fp8", H, "head")
@@ -269,11 +297,12 @@ def test_sharded_window_kernel_matches_reference(walk):
     vpf = np.asarray(vq, np.float32) * rep(vs)
     knf = np.asarray(fake_quantize_rows(jnp.array(kn), "fp8", H, "head"))
     vnf = np.asarray(fake_quantize_rows(jnp.array(vn), "fp8", H, "head"))
-    ref_q = _window_ref(q, knf, vnf, kpf, vpf, tables, pos, H)
+    ref_q = _window_ref(q, knf, vnf, kpf[LAYER], vpf[LAYER], tables, pos,
+                        H)
     out_q = pp.sharded_paged_window_attention(
         jnp.array(q), jnp.array(knf), jnp.array(vnf), kq, vq,
         jnp.array(tables), jnp.array(pos), n_head=H, mesh=mesh,
-        k_scales=ks, v_scales=vs)
+        layer=LAYER, k_scales=ks, v_scales=vs)
     np.testing.assert_allclose(np.asarray(out_q), ref_q, atol=1e-4,
                                rtol=1e-4)
 

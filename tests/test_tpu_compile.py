@@ -104,13 +104,17 @@ def test_flash_packed_char_dropout(mosaic, one_chip):
         .astype(jnp.float32) ** 2)), qkv, key)
 
 
+N_LAYERS = 3    # the kernels take the STACKED pool and a traced layer
+
+
 def _paged_args(sh, B, W, C=768, psz=16, mp=64, pool_dtype=BF16):
+    """``(q, k_new, v_new, k_pool, v_pool, tables, pos, layer)``."""
     N = B * mp
     row = _s((B, W, C), BF16, sh["row"])
-    pages = _s((N, psz, C), pool_dtype, sh["pool"])
+    pages = _s((N_LAYERS, N, psz, C), pool_dtype, sh["pool"])
     return (row, row, row, pages, pages,
             _s((B, mp), jnp.int32, sh["rep"]),
-            _s((B,), jnp.int32, sh["rep"]))
+            _s((B,), jnp.int32, sh["rep"]), _s((), jnp.int32, sh["rep"]))
 
 
 @pytest.mark.parametrize("window,quant,B,C,H", [
@@ -131,14 +135,14 @@ def test_paged_window_attention_124m(mosaic, one_chip, window, quant, B, C,
                        pool_dtype=jnp.int8 if quant else BF16)
     assert block_pages(16, 64, C * (1 if quant else 2)) == 8
     if quant:
-        sc = _s((B * 64, 16), jnp.float32, one_chip)
-        fn = lambda q, kn, vn, kp, vp, t, p, ks, vs: (
+        sc = _s((N_LAYERS, B * 64, 16), jnp.float32, one_chip)
+        fn = lambda q, kn, vn, kp, vp, t, p, l, ks, vs: (
             paged_window_attention(q, kn, vn, kp, vp, t, p, n_head=H,
-                                   k_scales=ks, v_scales=vs))
+                                   layer=l, k_scales=ks, v_scales=vs))
         text = _compile(fn, *args, sc, sc)
     else:
-        text = _compile(lambda *a: paged_window_attention(*a, n_head=H),
-                        *args)
+        text = _compile(lambda *a: paged_window_attention(
+            *a[:-1], n_head=H, layer=a[-1]), *args)
     assert all(n.startswith("paged_window_attention")
                for n in _kernel_names(text)) and _kernel_names(text)
 
@@ -158,11 +162,13 @@ def test_paged_gqa_attention_kexaone_widths(mosaic, one_chip, window, name):
     assert block_pages(psz, mp, 8 * 128 * 2) == 8
     q = _s((B, 1, 64 * 128), BF16, one_chip)
     kv = _s((B, 1, 8 * 128), BF16, one_chip)
-    pages = _s((B * mp if window else 10240, psz, 8 * 128), BF16, one_chip)
+    # the family's pools: one layer an array, read at layer 0
+    pages = _s((1, B * mp if window else 10240, psz, 8 * 128), BF16,
+               one_chip)
     vec = _s((B,), jnp.int32, one_chip)
     text = _compile(
         lambda q, k, v, kp, vp, t, p, p0: paged_gqa_attention(
-            q, k, v, kp, vp, t, p, n_head=64, n_kv_head=8,
+            q, k, v, kp, vp, t, p, n_head=64, n_kv_head=8, layer=0,
             attn_window=window, page0=p0 if window else None, name=name),
         q, kv, kv, pages, pages, _s((B, mp), jnp.int32, one_chip), vec, vec)
     assert re.search(rf"%{name}[\w.]* = [^\n]*tpu_custom_call", text)
@@ -172,10 +178,11 @@ def test_sharded_paged_window_attention_2x2(mosaic, mesh2x2):
     from replicatinggpt_tpu.ops.paged_pallas import (
         sharded_paged_window_attention)
     sh = {"row": NamedSharding(mesh2x2, P(None, None, "model")),
-          "pool": NamedSharding(mesh2x2, P("data", None, "model")),
+          "pool": NamedSharding(mesh2x2, P(None, "data", None, "model")),
           "rep": NamedSharding(mesh2x2, P())}
     text = _compile(lambda *a: sharded_paged_window_attention(
-        *a, n_head=12, mesh=mesh2x2), *_paged_args(sh, 8, 8))
+        *a[:-1], n_head=12, mesh=mesh2x2, layer=a[-1]),
+        *_paged_args(sh, 8, 8))
     assert "all-reduce" in text          # the cross-'data' softmax merge
 
 
@@ -229,7 +236,8 @@ def _flash_group_remat(one_chip):
 def _paged(one_chip):
     from replicatinggpt_tpu.ops.paged_pallas import paged_window_attention
     sh = {"row": one_chip, "pool": one_chip, "rep": one_chip}
-    return (lambda *a: paged_window_attention(*a, n_head=12),
+    return (lambda *a: paged_window_attention(*a[:-1], n_head=12,
+                                              layer=a[-1]),
             _paged_args(sh, 8, 1))
 
 
@@ -275,19 +283,25 @@ def test_kernel_name_is_the_hlo_instruction_name(mosaic, one_chip, build,
         assert sum(bool(re.search(fwd, n)) for n in names) == 2, names
 
 
-def _window_hlo(program, sharding, **route):
-    """Optimized HLO of the engine's decode-window (``"decode"``) or
-    mixed-window (``"mixed"``) program at k=1, gpt2-small's widths and
-    two layers, compiled for ``sharding``'s device (None: this
-    process's CPU)."""
+POOL_LAYER = (64, 16, 768)      # one layer of ``_window_hlo``'s pool
+
+
+def _window_hlo(program, sharding, n_layer=2, scan_layers=False, **route):
+    """Optimized HLO of the engine's decode-window (``"decode"``),
+    mixed-window (``"mixed"``) program at k=1 or prefill-chunk
+    (``"prefill"``) program, gpt2-small's widths and ``n_layer`` layers
+    (``scan_layers``: one traced layer index, as gpt2-large's 36 layers
+    run), compiled for ``sharding``'s device (None: this process's
+    CPU). The pool is ``(n_layer,) + POOL_LAYER``."""
     import dataclasses
     from replicatinggpt_tpu.models.gpt import (init_paged_kv_pool,
                                                init_params)
     from replicatinggpt_tpu.serve import engine
     cfg = dataclasses.replace(
-        get_config("gpt2-small").model, n_layer=2, scan_layers=False,
-        decode_cache_layout="packed")
+        get_config("gpt2-small").model, n_layer=n_layer,
+        scan_layers=scan_layers, decode_cache_layout="packed")
     B, psz, mp, chunk = 8, 16, 8, 16
+    assert (B * mp, psz, cfg.n_embd) == POOL_LAYER
     shaped = lambda tree: jax.tree_util.tree_map(
         lambda a: _s(a.shape, a.dtype, sharding), tree)
     params = shaped(jax.eval_shape(
@@ -305,6 +319,11 @@ def _window_hlo(program, sharding, **route):
             vec(jnp.int32), vec(jnp.float32), vec(jnp.bool_), cfg)
     if program == "decode":
         low = engine._engine_decode_window.lower(*state, *rest, k=1, **route)
+    elif program == "prefill":
+        scalar = _s((), jnp.int32, sharding)
+        low = engine._engine_prefill.lower(
+            params, _s((1, chunk), jnp.int32, sharding), scalar, scalar,
+            _s((mp,), jnp.int32, sharding), np.int32(0), cache, cfg)
     else:
         low = engine._engine_mixed_window.lower(*state, *prefill, *rest,
                                                 k=1, **route)
@@ -327,6 +346,59 @@ def test_decode_step_hlo_carries_the_phase_scopes(mosaic, one_chip):
             scope
     assert not re.search(r"%(sample|kv_gather)[\w.]* = (?!.* parameter\()",
                          text)
+    # what is left under ``kv_gather`` on this route is the kernel's walk
+    # (``_blocked_walk``: integer work on the tables and positions), inside
+    # ``attn``; the kernel itself is not under it, or
+    # ``decode_kv_gather_ms`` would read the kernel's time
+    under = [n for n in op_names if re.search(r"(^|/)kv_gather(/|$)", n)]
+    assert under and all(re.search(r"(^|/)attn/kv_gather(/|$)", n)
+                         for n in under), under
+    assert not any(re.search(r"kv_gather/.*paged_window_attention", n)
+                   for n in op_names)
+
+
+_HLO_RESULT = re.compile(
+    r"^\s*(?:ROOT )?%?([\w.\-]+) = \w+\[([\d,]*)\]\S* ([\w\-]+)\(")
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_paged_programs_address_the_stacked_pool_in_place(mosaic, one_chip,
+                                                          program):
+    """The decode window on the Pallas route and the prefill chunk,
+    compiled for the described v5e with the layers in ONE scan (a traced
+    layer index, as gpt2-large runs): no instruction's result has a
+    layer's shape ``[n_pages, page, C]`` (the slice the kernel and the
+    gather used to be handed: the whole pool read and written once a
+    step), and the only instructions with the pool's whole shape are the
+    program's parameters, the loop's carries and the in-place
+    ``scatter`` of the fresh rows, bare or as the root of its fusion. No
+    ``copy``, no ``dynamic-slice``: the kernel and the gather read the
+    carried pool in place and XLA orders them before the write."""
+    L = 3
+    text = _window_hlo(program, one_chip, n_layer=L, scan_layers=True,
+                       **({"use_pallas": True} if program == "decode"
+                          else {}))
+    layer = ",".join(map(str, POOL_LAYER))
+    roots, found = {}, []
+    comp = None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY\s+)?%?([\w.\-]+) \(.*\) -> .*\{\s*$", line)
+        if head:
+            comp = head.group(1)
+        m = _HLO_RESULT.match(line)
+        if m:
+            if "ROOT " in line:
+                roots[comp] = m.group(3)
+            found.append((m.group(1), m.group(2), m.group(3), line))
+    assert any(dims == f"{L},{layer}" for _, dims, _, _ in found)
+    for name, dims, opcode, line in found:
+        assert dims != layer, f"a layer of the pool is materialised: {line}"
+        if dims == f"{L},{layer}":
+            if opcode == "fusion":
+                opcode = roots[re.search(r"calls=%?([\w.\-]+)",
+                                         line).group(1)]
+            assert opcode in ("parameter", "get-tuple-element",
+                              "scatter"), line
 
 
 _HLO_CALLS = re.compile(
