@@ -329,7 +329,7 @@ def _replay(mcfg, params, *, window: int, mesh=(1, 1), n_requests: int,
     def inspect(engine, results):
         seen["results"] = {r.id: r for r in results}
         seen["pool_on"] = _device_sets(engine.pool.cache)
-        seen["params_on"] = _device_sets(engine.params)
+        seen["params_on"] = _device_sets(engine.served_params)
 
     out = {}
     with compiles(out):

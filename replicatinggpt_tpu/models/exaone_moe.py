@@ -55,6 +55,16 @@ Params = Dict[str, object]
 #: pool-dict entries that are per-slot window rings, not pool pages
 WINDOW_ENTRY_PREFIX = "w"
 
+#: the leaves the serving entry points read ONLY in the compute dtype
+#: (``_mm`` and the expert products cast a weight to its rows' dtype, the
+#: embedding is cast before the lookup): what an engine may hand over cast
+#: once (``models/gpt.py`` ``SERVE_CAST_LEAVES``). The norm gains, the
+#: router and its bias are read as float32 and are not here.
+SERVE_CAST_LEAVES = ("wte", "lm_head", "wq", "wk", "wv", "wo",
+                     "e_gate", "e_up", "e_down",
+                     "s_gate", "s_up", "s_down",
+                     "w_gate", "w_up", "w_down")
+
 #: rows of a whole-sequence forward that go through attention / the MLP at
 #: a time (``forward`` is the program's plain path: tests, the benchmark's
 #: routing choices; the serving programs never exceed a prefill chunk)

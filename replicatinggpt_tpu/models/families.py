@@ -25,6 +25,9 @@ class Family(NamedTuple):
     decode_window_paged: Callable
     mixed_window_paged: Callable
     verify_step_paged: Callable
+    #: leaf names the entry points read only as ``leaf.astype(compute
+    #: dtype)``: the engine serves a tree that holds them cast once
+    serve_cast_leaves: Tuple[str, ...]
     #: what a decode window's token block counts in its trailing columns
     step_counters: Tuple[str, ...]
     #: pool-dict entries with this prefix are per-slot state, not pool pages
@@ -64,14 +67,15 @@ def _family(name: str) -> Family:
 
         return Family("gpt", gpt.init_params, pool, prefill,
                       gpt.decode_window_paged, gpt.mixed_window_paged,
-                      gpt.verify_step_paged, (), "\0", window_ok)
+                      gpt.verify_step_paged, gpt.SERVE_CAST_LEAVES, (),
+                      "\0", window_ok)
     if name == "exaone_moe":
         from . import exaone_moe as m
         return Family("exaone_moe", m.init_params, m.init_paged_kv_pool,
                       m.prefill_chunk_paged, m.decode_window_paged,
                       m.mixed_window_paged, m.verify_step_paged,
-                      m.STEP_COUNTERS, m.WINDOW_ENTRY_PREFIX,
-                      lambda *a: None)
+                      m.SERVE_CAST_LEAVES, m.STEP_COUNTERS,
+                      m.WINDOW_ENTRY_PREFIX, lambda *a: None)
     raise KeyError(f"no model family {name!r}")
 
 

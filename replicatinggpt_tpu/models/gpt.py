@@ -123,6 +123,20 @@ def _activation(x: jnp.ndarray, kind: str) -> jnp.ndarray:
     return jax.nn.gelu(x) if kind == "gelu" else jax.nn.relu(x)
 
 
+#: the leaves every serving entry point below reads ONLY as
+#: ``leaf.astype(cd)``: the embeddings, the untied head, and in ``blocks``
+#: the four kernels (``_wmm``) and their biases. A caller may hand them over
+#: already in the compute dtype (``serve/engine.py`` casts them once, at its
+#: build): the cast here is then the identity and ``cast_params`` is empty.
+#: NOT here: the LayerNorm leaves (``_layer_norm`` reads them as float32)
+#: and a quantised kernel's ``<name>_scale`` (W8A8 reads it as float32).
+SERVE_CAST_LEAVES = ("wte", "wpe", "lm_head",
+                     "qkv_kernel", "attn_out_kernel",
+                     "mlp_up_kernel", "mlp_down_kernel",
+                     "qkv_bias", "attn_out_bias",
+                     "mlp_up_bias", "mlp_down_bias")
+
+
 def _wmm(h: jnp.ndarray, lp: Dict[str, jnp.ndarray], name: str,
          cd, aq: bool = False) -> jnp.ndarray:
     """``h @ lp[name]`` with weight-quantization dequant fused into the

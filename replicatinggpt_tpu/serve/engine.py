@@ -667,6 +667,42 @@ def _engine_page_install(cache, dst, blocks, shardings=None):
     return out
 
 
+@partial(jax.jit, static_argnames=("dtype",))
+def _cast_leaves(leaves, dtype):
+    return [a.astype(dtype) for a in leaves]
+
+
+def served_tree(params, names, dtype):
+    """The tree the engine's programs take: ``params`` with every leaf
+    NAMED in ``names`` (the family's ``serve_cast_leaves``: what its
+    entry points read only as ``leaf.astype(dtype)``) that is a floating
+    array wider than ``dtype`` replaced by its ``dtype`` copy, made by
+    one jitted cast (the rounding the launch did); every other leaf is
+    the SAME array. The programs' own ``astype`` is then the identity,
+    so no launch converts a weight again. A tree already in the compute
+    dtype, or quantised kernels, come back leaf for leaf."""
+    dtype = jnp.dtype(dtype)
+    flat, treedef = jax.tree_util.tree_flatten_with_path(params)
+    leaves = [a for _, a in flat]
+    wide = [i for i, (path, a) in enumerate(flat)
+            if getattr(path[-1], "key", None) in names
+            and jnp.issubdtype(a.dtype, jnp.floating)
+            and a.dtype.itemsize > dtype.itemsize]
+    if wide:
+        for i, c in zip(wide, _cast_leaves([leaves[i] for i in wide],
+                                           dtype)):
+            leaves[i] = c
+    return treedef.unflatten(leaves)
+
+
+def weight_cast_bytes(params, served) -> int:
+    """Bytes of the copies ``served_tree`` made (arrays or avals)."""
+    return sum(int(s.size) * s.dtype.itemsize
+               for p, s in zip(jax.tree_util.tree_leaves(params),
+                               jax.tree_util.tree_leaves(served))
+               if s.dtype != p.dtype)
+
+
 def engine_summary_block(engine: "Engine") -> dict:
     """The per-replica block of the fleet summary — ONE definition
     consumed by both sides of the process boundary (the in-process
@@ -767,6 +803,13 @@ class Engine:
             from ..quant.weights import quantize_params
             self.params = quantize_params(self.params,
                                           self.qcfg.weight_dtype)
+        # what the programs take: the leaves they would cast in every
+        # launch, cast ONCE here (float32 masters: XLA hoisted those
+        # casts out of the layer scan, a bf16 copy of all the weights
+        # made and dropped a launch). ``self.params`` stays the caller's
+        # tree (masters, or the quantised tree): references read it
+        self.served_params = served_tree(
+            self.params, self._fam.serve_cast_leaves, cfg.dtype)
         self.clock = clock
         self.drafter = drafter
         self.tel = telemetry or NULL
@@ -807,10 +850,10 @@ class Engine:
                 ecfg.n_pages)
             self._plan = serve_shardings(self.mesh, cfg, n_pages_eff,
                                          ecfg.mesh_data, ecfg.mesh_model)
-            self.params = jax.device_put(
-                self.params,
+            self.served_params = jax.device_put(
+                self.served_params,
                 serve_param_shardings(cfg, self.mesh, ecfg.mesh_model,
-                                      params=self.params))
+                                      params=self.served_params))
         self._rep = self._plan.rep if self._plan is not None else None
         self.pool = PagedCachePool(
             cfg, ecfg.pool_size, page_size=ecfg.page_size,
@@ -1441,6 +1484,10 @@ class Engine:
         # reserves, and per-slot window rings (0 for GPT-2)
         s["kv_global_bytes"], s["kv_window_bytes"] = \
             self.pool.bytes_by_kind()
+        # bytes of the compute-dtype copies made at build (0: the tree
+        # came in the compute dtype and is served as it is)
+        s["weight_cast_bytes"] = weight_cast_bytes(self.params,
+                                                   self.served_params)
         # dispatch amortization: the host tax per dispatch vs per token
         # (the serve-side analogue of the train bench's dispatch split)
         c = self.metrics.counters
@@ -1571,7 +1618,7 @@ class Engine:
                     tc_us = (self.tel.now_us() if self.tel.enabled
                              else 0.0)
                     cache = self._prefill_guard(
-                        self.params,
+                        self.served_params,
                         jnp.asarray(padded[None,
                                            c * chunk:(c + 1) * chunk]),
                         jnp.int32(claimed + c * chunk), jnp.int32(P),
@@ -1748,7 +1795,7 @@ class Engine:
                                 cached_tokens=off):
                 for c in range(n):
                     cache = self._prefill_guard(
-                        self.params,
+                        self.served_params,
                         jnp.asarray(tail[None,
                                          c * chunk:(c + 1) * chunk]),
                         jnp.int32(off + c * chunk), jnp.int32(limit),
@@ -1795,14 +1842,14 @@ class Engine:
         eos_d, tables_d, *sample = self._launch_inputs()
         for k in self._buckets:
             out = self._decode_guard(
-                self.params, *state, eos_d, self._z_life,
+                self.served_params, *state, eos_d, self._z_life,
                 tables_d, cache, rngs, *sample,
                 self.cfg, k=k, use_pallas=self._use_pallas,
                 shardings=self._plan)
             _, _, t_, p_, a_, b_, cache, rngs = out
             state = (t_, p_, a_, b_)
             out = self._mixed_guard(
-                self.params, *state, eos_d, self._z_life,
+                self.served_params, *state, eos_d, self._z_life,
                 jnp.zeros((3, P), jnp.int32),
                 jnp.zeros((k, P, self._chunk), jnp.int32),
                 tables_d, cache, rngs, *sample,
@@ -1921,7 +1968,7 @@ class Engine:
         swa_token = (sum(a.nbytes for a in rings)
                      // max(rings[0].shape[1] * rings[0].shape[2], 1)
                      if rings else 0)
-        experts = sum(a.nbytes for lp in self.params["layers"]
+        experts = sum(a.nbytes for lp in self.served_params["layers"]
                       for n, a in lp.items() if n.startswith("e_"))
         return cfg.sliding_window, swa_token, experts
 
@@ -1991,7 +2038,7 @@ class Engine:
                 pf_toks[:n, slot, :] = \
                     self._pf_tail[slot][:n * chunk].reshape(n, chunk)
             out = self._mixed_guard(
-                self.params, tok, pos, active, budget, eos_d, life,
+                self.served_params, tok, pos, active, budget, eos_d, life,
                 jnp.asarray(pfc), jnp.asarray(pf_toks),
                 tables_d, self.pool.cache, self._rngs,
                 temp_d, top_k_d, top_p_d, greedy_d, self.cfg, k=k,
@@ -2010,7 +2057,7 @@ class Engine:
         else:
             pf_done = []
             out = self._decode_guard(
-                self.params, tok, pos, active, budget, eos_d, life,
+                self.served_params, tok, pos, active, budget, eos_d, life,
                 tables_d, self.pool.cache, self._rngs,
                 temp_d, top_k_d, top_p_d, greedy_d, self.cfg, k=k,
                 use_pallas=self._use_pallas, shardings=self._plan)
@@ -2279,7 +2326,8 @@ class Engine:
                                 self._active)):
             self.step_timer.start()
             n_acc, out, cache, rngs = self._verify_guard(
-                self.params, jnp.asarray(window), jnp.asarray(self._pos),
+                self.served_params, jnp.asarray(window),
+                jnp.asarray(self._pos),
                 jnp.asarray(m), jnp.asarray(self._active),
                 jnp.asarray(self.pool.tables), self.pool.cache,
                 self._rngs, jnp.asarray(self._temp),

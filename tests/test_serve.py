@@ -530,6 +530,139 @@ def test_sampled_neighbour_switches_the_filters_on_and_off(params, window):
 # steady state: zero recompiles + metrics (acceptance criterion)
 # ---------------------------------------------------------------------------
 
+# ---------------------------------------------------------------------------
+# the served tree: weights cast once, at the engine's build
+# ---------------------------------------------------------------------------
+
+def _served_case(case):
+    """``(cfg at bfloat16 compute, the tree handed in, engine config,
+    the family's module)`` of one case."""
+    from replicatinggpt_tpu.config import get_config
+    from replicatinggpt_tpu.models import exaone_moe, gpt
+    from replicatinggpt_tpu.quant.weights import quantize_params
+    if case.startswith("exaone"):
+        cfg = dataclasses.replace(
+            get_config("exaone-moe-tiny").model, dtype="bfloat16",
+            param_dtype="bfloat16" if "bf16" in case else "float32")
+        return (cfg, exaone_moe.init_params(jax.random.PRNGKey(7), cfg),
+                EngineConfig(pool_size=2, page_size=8, prefill_chunk=16,
+                             prefix_cache=False), exaone_moe)
+    cfg = dataclasses.replace(CFG, dtype="bfloat16", tied_head="untied"
+                              not in case)
+    tree = init_params(jax.random.PRNGKey(0), cfg)
+    quant = "int8" if "int8" in case else "none"
+    return (cfg, quantize_params(tree, quant),
+            EngineConfig(pool_size=2, page_size=8, weight_quant=quant), gpt)
+
+
+@pytest.mark.parametrize("case", [
+    "float32-masters", "float32-masters-untied-head", "int8-weight-tree",
+    "exaone-moe-tiny-bf16-params", "exaone-moe-tiny-float32-masters"])
+def test_engine_serves_weights_it_cast_once(case):
+    """The tree the engine's programs take holds, in the compute dtype,
+    every leaf the family's entry points read only as ``astype(cd)``, cast
+    ONCE at the build; the chunked prefill, a decode step's logits and a
+    decode window's tokens are bit for bit those of the tree handed in
+    (whose leaves the programs cast in every launch). What the programs
+    read as float32 stays float32, a leaf that needs no cast is the SAME
+    array, ``engine.params`` is the caller's tree, and
+    ``weight_cast_bytes`` counts the copies."""
+    from replicatinggpt_tpu.models.families import family
+    cfg, tree, ecfg, mod = _served_case(case)
+    eng = Engine(tree, cfg, ecfg)
+    served = eng.served_params
+    assert eng.params is tree
+    flat = dict(jax.tree_util.tree_flatten_with_path(tree)[0])
+    copies = 0
+    for path, leaf in jax.tree_util.tree_flatten_with_path(served)[0]:
+        name = path[-1].key
+        if leaf is flat[path]:
+            # nothing a launch would still convert from a wider float
+            assert not (name in family(cfg).serve_cast_leaves
+                        and leaf.dtype == jnp.float32), name
+            continue
+        assert (flat[path].dtype, leaf.dtype) == (jnp.float32,
+                                                  jnp.bfloat16), name
+        assert np.array_equal(np.asarray(leaf),
+                              np.asarray(flat[path].astype(jnp.bfloat16)))
+        copies += leaf.nbytes
+    assert eng.metrics_summary()["weight_cast_bytes"] == copies
+    if "float32" in case:
+        assert copies > 0
+        for path, leaf in jax.tree_util.tree_flatten_with_path(served)[0]:
+            if path[-1].key.startswith(("ln", "norm", "router")):
+                assert leaf.dtype == jnp.float32 and leaf is flat[path]
+    elif "bf16-params" in case:
+        assert copies == 0            # every leaf the same array
+    else:                             # int8 kernels, float32 scales kept
+        b = served["blocks"]
+        assert b["qkv_kernel"].dtype == jnp.int8
+        assert b["qkv_kernel_scale"].dtype == jnp.float32
+        assert b["qkv_bias"].dtype == served["wte"].dtype == jnp.bfloat16
+
+    fam = family(cfg)
+    B, psz = 2, 8
+    mp = cfg.block_size // psz
+    tables = jnp.asarray(np.random.default_rng(5).permutation(B * mp)
+                         .astype(np.int32).reshape(B, mp))
+    prompts = [np.arange(3, 3 + n, dtype=np.int32) % cfg.vocab_size
+               for n in (5, 13)]
+    greedy = lambda r, logits, live: (jnp.argmax(logits, -1)
+                                      .astype(jnp.int32), r)
+    prefill = jax.jit(lambda *a: fam.prefill_chunk_paged(*a, cfg))
+    step = jax.jit(lambda *a: mod.decode_step_paged(*a, cfg)[:2])
+    window = jax.jit(lambda *a: fam.decode_window_paged(
+        *a, cfg, sample_fn=greedy, length=3))
+
+    def run(params):
+        cache = fam.init_paged_kv_pool(cfg, B * mp, psz, n_slots=B)
+        for b, p in enumerate(prompts):
+            for c in range(-(-len(p) // 8)):
+                chunk = np.zeros((8,), np.int32)
+                chunk[:len(p[c * 8:(c + 1) * 8])] = p[c * 8:(c + 1) * 8]
+                cache = prefill(params, jnp.asarray(chunk[None]),
+                                jnp.int32(c * 8), jnp.int32(len(p)),
+                                tables[b], jnp.int32(b), cache)
+        filled = {n: np.asarray(a).copy() for n, a in cache.items()}
+        tok = jnp.asarray([p[-1] for p in prompts], jnp.int32)
+        pos = jnp.asarray([len(p) - 1 for p in prompts], jnp.int32)
+        live = jnp.ones((B,), bool)
+        logits, cache = step(params, tok, pos, live, tables, cache)
+        toks, *_ = window(
+            params, tok, pos, live, jnp.full((B,), 9, jnp.int32),
+            jnp.full((B,), -1, jnp.int32), tables, cache,
+            jnp.stack([jax.random.PRNGKey(i) for i in range(B)]))
+        return filled, np.asarray(logits), np.asarray(toks)
+
+    pool_a, logits_a, toks_a = run(tree)
+    pool_b, logits_b, toks_b = run(served)
+    assert logits_a.dtype == np.float32 and np.abs(logits_a).max() > 0
+    assert np.array_equal(logits_a, logits_b)
+    assert np.array_equal(toks_a, toks_b)
+    for n in pool_a:
+        assert np.array_equal(pool_a[n], pool_b[n]), n
+
+
+@pytest.mark.parametrize("preset,want", [("gpt2-large", 1_547_686_400),
+                                         ("k-exaone-236b-a23b", 0)])
+def test_weight_cast_bytes_of_the_published_presets(preset, want):
+    """By shapes alone (``jax.eval_shape``; nothing of this size is made
+    here): gpt2-large's float32 masters are served beside 1.548 GB of
+    bfloat16 copies (kernels, biases, ``wte``, ``wpe``), K-EXAONE's
+    bfloat16 parameters beside none."""
+    from replicatinggpt_tpu.config import get_config
+    from replicatinggpt_tpu.models.families import family
+    from replicatinggpt_tpu.serve.engine import (served_tree,
+                                                 weight_cast_bytes)
+    cfg = get_config(preset).model
+    fam = family(cfg)
+    tree = jax.eval_shape(lambda: fam.init_params(jax.random.PRNGKey(0),
+                                                  cfg))
+    served = jax.eval_shape(
+        lambda t: served_tree(t, fam.serve_cast_leaves, cfg.dtype), tree)
+    assert weight_cast_bytes(tree, served) == want
+
+
 def test_steady_state_64_requests_zero_recompiles(params):
     """>= 64 requests through a pool of 8 (smaller than the request
     count): completes, reports TTFT/tok-s/occupancy, and compiles ZERO

@@ -286,16 +286,20 @@ def test_kernel_name_is_the_hlo_instruction_name(mosaic, one_chip, build,
 POOL_LAYER = (64, 16, 768)      # one layer of ``_window_hlo``'s pool
 
 
-def _window_hlo(program, sharding, n_layer=2, scan_layers=False, **route):
+def _window_hlo(program, sharding, n_layer=2, scan_layers=False,
+                served=False, **route):
     """Optimized HLO of the engine's decode-window (``"decode"``),
     mixed-window (``"mixed"``) program at k=1 or prefill-chunk
     (``"prefill"``) program, gpt2-small's widths and ``n_layer`` layers
     (``scan_layers``: one traced layer index, as gpt2-large's 36 layers
     run), compiled for ``sharding``'s device (None: this process's
-    CPU). The pool is ``(n_layer,) + POOL_LAYER``."""
+    CPU). The pool is ``(n_layer,) + POOL_LAYER``. ``served``: the
+    parameters' avals are those of the tree the engine serves
+    (``engine.served_tree``), not of the float32 masters."""
     import dataclasses
-    from replicatinggpt_tpu.models.gpt import (init_paged_kv_pool,
-                                               init_params)
+    from replicatinggpt_tpu.models.gpt import (
+        SERVE_CAST_LEAVES as gpt_serve_cast_leaves, init_paged_kv_pool,
+        init_params)
     from replicatinggpt_tpu.serve import engine
     cfg = dataclasses.replace(
         get_config("gpt2-small").model, n_layer=n_layer,
@@ -304,8 +308,12 @@ def _window_hlo(program, sharding, n_layer=2, scan_layers=False, **route):
     assert (B * mp, psz, cfg.n_embd) == POOL_LAYER
     shaped = lambda tree: jax.tree_util.tree_map(
         lambda a: _s(a.shape, a.dtype, sharding), tree)
-    params = shaped(jax.eval_shape(
-        lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    if served:
+        params = jax.eval_shape(
+            lambda t: engine.served_tree(t, gpt_serve_cast_leaves, cfg.dtype),
+            params)
+    params = shaped(params)
     cache = shaped(jax.eval_shape(
         lambda: init_paged_kv_pool(cfg, B * mp, psz)))
     vec = lambda dt: _s((B,), dt, sharding)
@@ -399,6 +407,39 @@ def test_paged_programs_address_the_stacked_pool_in_place(mosaic, one_chip,
                                          line).group(1)]
             assert opcode in ("parameter", "get-tuple-element",
                               "scatter"), line
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_served_tree_leaves_no_weight_cast_in_a_launch(mosaic, one_chip,
+                                                       program):
+    """The decode window and the prefill chunk compiled for the described
+    v5e with the layers in one scan: given the avals of the tree the
+    engine SERVES (kernels, biases, ``wte``, ``wpe`` in bfloat16) the
+    optimised HLO converts no float32 operand of a kernel's, ``wte``'s or
+    ``wpe``'s shape to bfloat16; given the float32 masters' avals it
+    does, for each of them, OUTSIDE the layer loop and for all layers at
+    once: the copy of the weights every launch made."""
+    L, C = 3, 768
+    weights = {f"{L},{C},{3 * C}", f"{L},{C},{C}", f"{L},{C},{4 * C}",
+               f"{L},{4 * C},{C}", f"50257,{C}", f"1024,{C}"}
+
+    def weight_casts(text):
+        """Shapes, among ``weights``, that a ``convert`` to bfloat16
+        produces (XLA leaves these bare: one whole-array instruction
+        each, hoisted out of the layer loop)."""
+        return {dims for dims in re.findall(
+            r"= bf16\[([\d,]*)\]\S* convert\(", text) if dims in weights}
+
+    route = {"use_pallas": True} if program == "decode" else {}
+    masters = _window_hlo(program, one_chip, n_layer=L, scan_layers=True,
+                          **route)
+    served = _window_hlo(program, one_chip, n_layer=L, scan_layers=True,
+                         served=True, **route)
+    assert weight_casts(masters) == weights, weight_casts(masters)
+    assert weight_casts(served) == set()
+    # and nothing float32 of a weight's shape is left in the program at all
+    assert not [d for d in re.findall(r"f32\[([\d,]*)\]", served)
+                if d in weights]
 
 
 _HLO_CALLS = re.compile(
