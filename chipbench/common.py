@@ -41,6 +41,18 @@ def note(what: str, **fields) -> None:
     print(json.dumps({"note": what, **fields}), flush=True)
 
 
+#: every number a run's check compared, beside its limit: ``run.py`` prints
+#: them as the run's last lines on stderr and last in the result line
+COMPARED = {}
+
+
+def compare(name: str, value, limit) -> bool:
+    """Record ``value`` beside ``limit`` and say whether it is within it
+    (a value that is missing is not)."""
+    COMPARED[name] = {"value": value, "limit": limit}
+    return value is not None and value <= limit
+
+
 COMPILES = {"s": 0.0, "n": 0, "hits": 0, "misses": 0}
 
 

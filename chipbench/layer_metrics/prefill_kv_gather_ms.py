@@ -1,4 +1,4 @@
-"""Reads ``decode_kv_gather_ms`` as ``decode_kv_gather_ms.json`` beside this file says
+"""Reads ``prefill_kv_gather_ms`` as ``prefill_kv_gather_ms.json`` beside this file says
 (``chipbench/trace_stats.py`` ``read_spec``)."""
 
 from chipbench import trace_stats

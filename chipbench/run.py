@@ -9,7 +9,9 @@ that ``peaks.json`` does not know, keeps JAX's persistent compilation cache at
 a fixed place inside the checkout, warms the cell's own shapes, measures for
 ``--seconds``, checks the outputs against the plain reference outside the
 window, and prints as the LAST line of stdout one JSON object: ``correct``,
-``attempted``, ``failed``, ``metrics``, ``device`` and, traced, ``breakdown``.
+``attempted``, ``failed``, ``metrics``, ``device``, traced ``breakdown``, and
+last ``compared``: every number the check compared beside its limit (also the
+last lines of stderr).
 Earlier lines (``{"note": ...}``) carry sample counts, the generator's
 lateness, losses, the kernel route and the cache traffic. With ``--trace 0``
 the metrics are the cell's end-to-end metrics, with ``--trace 1`` its
@@ -128,6 +130,12 @@ def main(argv=None) -> int:
         with open(os.path.join(trace_dir, "reduced.json"), "w") as f:
             json.dump({"describe": trace_reduce.describe(trace),
                        "cut": trace_reduce.cut(trace)}, f)
+    # what the check compared, each number beside its limit: the last lines
+    # of stderr and the last key of the line
+    out["compared"] = dict(common.COMPARED)
+    for name, c in common.COMPARED.items():
+        print(f"chipbench: compared {name} {c['value']} limit {c['limit']}",
+              file=sys.stderr, flush=True)
     print(json.dumps(out), flush=True)
     return 0
 

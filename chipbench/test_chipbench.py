@@ -14,6 +14,7 @@ import re
 import shutil
 import subprocess
 import sys
+import time
 
 import numpy as np
 import pytest
@@ -49,6 +50,14 @@ def test_manifest_names_units_and_moves():
         assert len(MAN.metrics("end_to_end", cell)) >= 2
         assert MAN.metrics("per_layer", cell)
     assert sum(w["chips"] == 4 for w in doc["workloads"]) <= 1
+    # a why, a layer, a source: 1 to 200 characters on one line, no tab
+    lines = ([w["why"] for w in doc["workloads"]]
+             + [c[k] for c in doc["configs"] for k in ("why", "source")]
+             + [m["layer"] for m in doc["per_layer"]])
+    assert all(1 <= len(t) <= 200 and "\n" not in t and "\t" not in t
+               for t in lines), [t for t in lines if len(t) > 200]
+    for m in doc["end_to_end"]:
+        assert 0.01 <= m["bound"] <= 0.1, m
 
 
 def test_everything_is_found_by_name():
@@ -106,6 +115,27 @@ def test_yardstick():
     assert flops.train_flops_per_token(24, 1024, 1024, 50257) == \
         2271713280.0
     assert flops.peaks("TPU v5 lite")["bf16_flops_per_s"] == 197e12
+    # serving: one row through one layer of width 2 is 2 * 12 * 4 FLOPs, its
+    # logits over 10 classes 2 * 2 * 10, a context position 4 * 2 a layer
+    assert flops.gpt_serve_flops(1, 2, 10, tokens=1, emitted=0,
+                                 context_pairs=0) == 96.0
+    assert flops.gpt_serve_flops(3, 2, 10, tokens=5, emitted=2,
+                                 context_pairs=7) == \
+        5 * 3 * 96.0 + 2 * 40.0 + 7 * 3 * 8.0
+    # the second family, from its configuration file: a row's attention
+    # projections, dense MLP, shared experts and routers; a held pair's
+    # three matrices; this chip's slice of the head; both kinds of layer
+    cfg = MAN.cell("serve-decode-kexaone")["config"]
+    h, q, kv, w = 6144, 64 * 128, 8 * 128, 2048
+    row = (8 * 2 * (h * (q + 2 * kv) + q * h) + 2 * 3 * h * 18432
+           + 7 * 2 * (3 * h * w + h * 128))
+    none = dict(tokens=0, emitted=0, pairs_held=0, full_context_pairs=0,
+                window_context_pairs=0)
+    f = lambda **kw: flops.exaone_moe_serve_flops(cfg, **{**none, **kw})
+    assert f(tokens=1) == row and f(pairs_held=1) == 2 * 3 * h * w
+    assert f(emitted=1) == 2 * h * 19200
+    assert f(full_context_pairs=1) == 4 * q * 2
+    assert f(window_context_pairs=1) == 4 * q * 6
     with pytest.raises(KeyError):
         flops.peaks("cpu")
 
@@ -198,6 +228,106 @@ def test_stalled_requests_fail_and_cut_ones_read_whole_gaps():
     assert times[5]["cut"] and times[5]["tpot_ms"] == pytest.approx(200.0)
     assert serving.stalled(times) == [False] * 6 + [True]
     assert serving.request_times(sent(0, FINISH_CANCELLED, 0, 2.0)) is None
+
+
+class _HandMadeEngine:
+    """What ``serving.drive`` needs of an engine: every request in it gets
+    one token a step of ``step_s``; a cancel surfaces from the next step."""
+
+    def __init__(self, step_s):
+        from replicatinggpt_tpu.serve import requests
+        self.requests, self.step_s = requests, step_s
+        self.live, self.cancelled = {}, []
+
+    @property
+    def idle(self):
+        return not self.live and not self.cancelled
+
+    def submit(self, req):
+        self.live[req.id] = [req, [], time.monotonic(), None]
+
+    def cancel(self, rid):
+        self.cancelled.append(self.live.pop(rid))
+
+    def _result(self, rec, reason):
+        req, tokens, t_submit, t_first = rec
+        return self.requests.RequestResult(
+            id=req.id, tokens=tokens, finish_reason=reason,
+            ttft_s=(t_first or t_submit) - t_submit,
+            total_s=time.monotonic() - t_submit)
+
+    def step(self):
+        time.sleep(self.step_s)
+        out = [self._result(rec, self.requests.FINISH_CANCELLED)
+               for rec in self.cancelled]
+        self.cancelled = []
+        for rid, rec in list(self.live.items()):
+            rec[1].append(0)
+            rec[3] = rec[3] or time.monotonic()
+            if len(rec[1]) >= rec[0].max_new_tokens:
+                out.append(self._result(self.live.pop(rid),
+                                        self.requests.FINISH_MAX_TOKENS))
+        return out
+
+
+class _SlowTracer:
+    """What ``serving.drive`` needs of a tracer, with a profiler that takes
+    ``stop_s`` to stop, as the real one takes 3.5 s on the chip's host; it
+    notes when it was asked to."""
+
+    def __init__(self, span_s, stop_s):
+        self.span_s, self.stop_s = span_s, stop_s
+        self.state, self.stopped_at = "idle", None
+
+    def tick(self, since_close):
+        if self.state == "idle" and since_close >= 0:
+            self.state = "on"
+        elif self.state == "on" and since_close >= self.span_s:
+            self.state = "spanned"
+
+    def close(self):
+        if self.state in ("on", "spanned"):
+            self.stopped_at = time.monotonic()
+            time.sleep(self.stop_s)
+        self.state = "done"
+
+
+@pytest.mark.parametrize("traced", [False, True])
+def test_the_profilers_stop_stalls_no_request_of_a_hand_made_schedule(traced):
+    """An open loop on a hand-made schedule: five short requests that finish
+    and four long ones still decoding when the callers hang up, at 2 ms a
+    token. The trace ends 0.15 s before the callers hang up and its profiler
+    takes 0.4 s to stop, 200 tokens' time of every request still decoding:
+    it is stopped AFTER the hang-up, so ``cut_at`` is the instant the last
+    step ended and nobody counts as stalled (with the stop inside the loop a
+    traced chat run counted 2 of 41, PERF.md PR 28, and this test 4 of 9)."""
+    from replicatinggpt_tpu.serve.requests import Request
+    req = lambda i, n: Request(id=f"r{i}", prompt=np.zeros(4, np.int32),
+                               max_new_tokens=n)
+    due = ([serving.Sent(req=req(i, 20), due=0.02 * i) for i in range(5)]
+           + [serving.Sent(req=req(5 + i, 10_000), due=0.1 + 0.01 * i)
+              for i in range(4)])
+    tracer = (_SlowTracer(0.05, 0.4) if traced
+              else serving.Tracer(None, 0.0))
+    marks = {}
+    t0 = time.monotonic()
+    sent = serving.drive(
+        _HandMadeEngine(0.002), due=due, on_finish=lambda s: None,
+        t_open=0.0, t_close=0.2, t_give_up=0.4, tracer=tracer,
+        at_open=lambda: marks.setdefault("open", time.monotonic()),
+        at_close=lambda: marks.setdefault("close", time.monotonic()),
+        hang_up=True)
+    assert len(sent) == 9 and set(marks) == {"open", "close"}
+    times = [serving.request_times(s) for s in sent]
+    assert all(times) and sum(x["cut"] for x in times) == 4
+    assert serving.stalled(times) == [False] * 9
+    for s, x in zip(sent[5:], times[5:]):
+        # every long request decoded from its first token to the hang-up:
+        # its gaps are the engine's 2 ms and some, not the profiler's stop
+        assert x["tpot_ms"] < 20.0, x
+    if traced:
+        assert tracer.state == "done" and tracer.stopped_at >= t0 + 0.4
+        assert all(t0 + s.cut_at <= tracer.stopped_at for s in sent[5:])
 
 
 def test_exponential_gaps_fill_the_span():

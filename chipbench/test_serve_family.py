@@ -127,7 +127,7 @@ def test_accepted_readers_on_the_new_family(kexaone):
                                   ["paged_attention_ms"])
     assert _read("pallas_kernel_ms.serve", st, kind) == pytest.approx(
         paged + _read("swa_attention_ms", st, kind))
-    assert _read("decode_kv_gather_ms", st, kind) is None
+    assert _read("prefill_kv_gather_ms", st, kind) is None
 
 
 # ------------------------------------------------- configuration, mix, cells
@@ -234,7 +234,7 @@ def test_new_cell_reports_what_it_must():
     assert e2e == {"tpot_p80_ms", "serve_tokens_per_s", "setup_s"}
     layer = {m["name"] for m in MAN.metrics("per_layer",
                                             "serve-decode-kexaone")}
-    assert set(NEW) <= layer and "decode_kv_gather_ms" not in layer
+    assert set(NEW) <= layer and "prefill_kv_gather_ms" not in layer
     for m in MAN.doc["per_layer"]:
         if m["name"] in NEW:
             assert m["workloads"] == ["serve-decode-kexaone"]

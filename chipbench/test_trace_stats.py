@@ -144,9 +144,28 @@ def test_decode_sample_ms_on_a_hand_made_case():
         t, "sample", r"^jit__engine_prefill\(") == pytest.approx(0.009)
 
 
-def test_decode_kv_gather_ms_on_a_hand_made_case():
+def test_prefill_kv_gather_ms_on_a_hand_made_case():
     t = _hand_made()
-    assert _read("decode_kv_gather_ms", t) == pytest.approx(0.020)
+    # the two slices of the ONE prefill launch, 10 us each; the decode
+    # launches' slices, which have the same names and scopes, are not its
+    assert _read("prefill_kv_gather_ms", t) == pytest.approx(0.020)
+    assert trace_stats.scope_self_ms_per_launch(
+        t, "kv_gather", r"^jit__engine_decode_window\(") == \
+        pytest.approx(0.020)
+    # two chunks of one admission: twice the time over twice the launches
+    two = _hand_made()
+    dev = two["devices"]["/device:TPU:0"]
+    dev[trace_reduce.OPS] += [[n, s + 400e3, d, sc]
+                              for n, s, d, sc in dev[trace_reduce.OPS]
+                              if 200e3 <= s < 300e3]
+    dev[trace_reduce.MODULES].append(["jit__engine_prefill(9)", 600e3, 100e3])
+    assert _read("prefill_kv_gather_ms", two) == pytest.approx(0.020)
+    # a trace in which nothing was admitted gives nothing to read
+    none = _hand_made()
+    dev = none["devices"]["/device:TPU:0"]
+    dev[trace_reduce.MODULES] = [m for m in dev[trace_reduce.MODULES]
+                                 if m[0] == DECODE]
+    assert _read("prefill_kv_gather_ms", none) is None
     # the while that holds the layer counts for its own 0 us, not for the
     # 90 us of its body: nothing is left outside the scopes
     by = dict(trace_stats.by_scope(t, ["kv_gather", "attn", "mlp",
@@ -183,7 +202,7 @@ def test_host_serial_ms_per_step_on_a_hand_made_case():
 
 
 @pytest.mark.parametrize("metric", [
-    "decode_sample_ms", "decode_kv_gather_ms", "paged_attention_hbm_pct",
+    "decode_sample_ms", "prefill_kv_gather_ms", "paged_attention_hbm_pct",
     "host_serial_ms_per_step"])
 def test_readers_find_nothing_in_a_program_without_the_names(metric):
     """The parent of the PR that added the names: one coarse span, no
@@ -268,7 +287,8 @@ def test_readers_on_a_trace_recorded_on_the_chip(name):
     assert trace_stats.names_only(t) == doc["trace"]
     ops = next(iter(t["devices"].values()))[trace_reduce.OPS]
     kernels = [e[0] for e in ops if e[0].endswith("tpu_custom_call")]
-    assert kernels and not [
+    # a prefill chunk runs no Pallas kernel: its cut says so
+    assert bool(kernels) == want.get("kernels", True) and not [
         k for k in kernels if k.startswith(("closed_call", "checkpoint",
                                             "rematted_computation"))]
     assert trace_stats.has_scopes(t) == want["has_scopes"]
@@ -280,3 +300,30 @@ def test_readers_on_a_trace_recorded_on_the_chip(name):
         kw = {"stats": t, "device_kind": V5E} if own else {}
         got = MAN.reader(metric)(want.get("counters", {}), doc["trace"], **kw)
         assert got == pytest.approx(value, rel=1e-9), metric
+    # a scope that no metric reads in this launch any more, held to what it
+    # read when the trace was recorded (``decode_kv_gather_ms`` until PR 36)
+    for scope, w in want.get("scope_ms", {}).items():
+        assert trace_stats.scope_self_ms_per_launch(
+            t, scope, w["per"]) == pytest.approx(w["ms"], rel=1e-9), scope
+
+
+def test_scopes_name_the_second_familys_launch():
+    """``python3 -m chipbench.trace_stats`` prints a launch by ``SCOPES``:
+    with PR 29's scopes in it K-EXAONE's decode launch shows its experts,
+    router and shared expert and its two kinds of attention layer, and
+    leaves next to nothing under ``(other)``."""
+    with open(os.path.join(DATA, "serve_decode_kexaone_v5e.json")) as f:
+        t = json.load(f)["stats_trace"]
+    family = {"moe_experts", "moe_router", "moe_shared", "attn_swa",
+              "attn_global"}
+    by = dict(trace_stats.by_scope(t, list(trace_stats.SCOPES)))
+    for scope in sorted(family):
+        assert scope in trace_stats.SCOPES and by.get(scope, 0.0) > 0, scope
+    total = sum(by.values())
+    assert by["moe_experts"] > 0.3 * total         # the step's first cost
+    assert by.get("(other)", 0.0) < 0.1 * total
+    # GPT-2's launch has none of them and reads as it did
+    with open(os.path.join(DATA, "serve_decode_large_v5e_named.json")) as f:
+        gpt = json.load(f)["stats_trace"]
+    assert not family & set(dict(trace_stats.by_scope(
+        gpt, list(trace_stats.SCOPES))))

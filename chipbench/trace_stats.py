@@ -398,9 +398,13 @@ def reader(py_path: str):
     return functools.partial(read_spec, spec)
 
 
-#: the ``jax.named_scope`` vocabulary of the program (docs/observability.md)
+#: the ``jax.named_scope`` vocabulary of the program (docs/observability.md);
+#: the second row is the ``exaone_moe`` family's (PR 29): its expert layer and
+#: its two kinds of attention layer (``by_scope`` takes the innermost scope
+#: of a path, so the order here does not matter)
 SCOPES = ("embed", "attn", "kv_gather", "kv_scatter", "mlp", "head", "sample",
-          "cast_params", "loss", "grad_accum", "optimizer")
+          "cast_params", "loss", "grad_accum", "optimizer",
+          "moe_router", "moe_experts", "moe_shared", "attn_swa", "attn_global")
 
 
 def main(argv: Optional[List[str]] = None) -> int:

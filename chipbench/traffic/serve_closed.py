@@ -93,6 +93,7 @@ def run(ctx: common.Ctx, sizes=None) -> dict:
         each_step=window.sample)
     mem = common.memory_peak_bytes()
     win = window.counters()
+    win["step_mfu_pct"] = serving.gpt_step_mfu_pct(mcfg, win, sizes)
     in_window = [s for s, inside in finished_at if inside]
     rejected = [s for s in sent if s.result is not None
                 and not s.result.ok]

@@ -145,7 +145,8 @@ def check(engine, mcfg, finished, window: dict, seed: int, traffic: dict,
     if route["route"] != "pallas" or route["reasons"]:
         problems.append(f"kernel route is {route['route']} "
                         f"{route['reasons']}, not pallas")
-    if window["backend_compiles"] or window["engine_programs_added"]:
+    if not common.compare("compiled_in_window", window["backend_compiles"]
+                          + window["engine_programs_added"], 0):
         problems.append(
             f"compiled inside the window: {window['backend_compiles']} "
             f"backend compiles, {window['engine_programs_added']} programs")
@@ -200,21 +201,42 @@ def check(engine, mcfg, finished, window: dict, seed: int, traffic: dict,
                 route_why=traffic["route_mismatch_tol_why"],
                 tie_margin=margin, tie_margin_why=traffic["tie_margin_why"],
                 control=control, check_s=common.now() - t0)
+    worst_ok = common.compare("worst_logit_gap", max(gaps, default=None), tol)
+    mean_ok = common.compare("mean_logit_gap", mean_gap if gaps else None,
+                             mean_tol)
     if not gaps:
         problems.append("no finished stream to hold to the reference")
-    elif max(gaps) > tol:
+    elif not worst_ok:
         problems.append(f"a stream's token sits {max(gaps)} below the "
                         f"reference's best logit, tolerance {tol}")
-    elif mean_gap > mean_tol:
+    elif not mean_ok:
         problems.append(f"the streams' tokens sit {mean_gap} below the "
                         f"reference's best logit on average, tolerance "
                         f"{mean_tol}")
-    if mismatch_share > route_tol:
+    if not common.compare("route_mismatch_share", mismatch_share, route_tol):
         problems.append(f"the program's router chose other experts than "
                         f"the reference's, beyond the tie margin, at "
                         f"{mismatch_share:.4f} of its decisions, tolerance "
                         f"{route_tol}")
     return problems
+
+
+def step_mfu_pct(config: dict, mcfg, win: dict, sizes=None):
+    """``serving.step_mfu_pct`` of the FLOPs the configuration file's share
+    of the model needs for the window (``flops.exaone_moe_serve_flops``).
+    The engine counts the routed pairs that landed on held experts in its
+    decode steps (``moe_pairs_held``); a prefilled row is taken to land as
+    many. A window layer's row reads ``sliding_window`` positions (a row
+    younger than the window fewer: under 1% of the rows of this mix)."""
+    from chipbench import flops
+    decoded, prefilled = win["tokens"], win["prefill_tokens"]
+    pairs = win["counter_deltas"].get("moe_pairs_held", 0)
+    return serving.step_mfu_pct(getattr(flops, mcfg.family + "_serve_flops")(
+        config, tokens=decoded + prefilled, emitted=decoded,
+        pairs_held=pairs * (decoded + prefilled) / max(decoded, 1),
+        full_context_pairs=win["live_token_steps"],
+        window_context_pairs=config["sliding_window"] * win["active_steps"]),
+        win["seconds"], sizes)
 
 
 def run(ctx: common.Ctx, sizes=None, control: bool = False) -> dict:
@@ -242,6 +264,7 @@ def run(ctx: common.Ctx, sizes=None, control: bool = False) -> dict:
         each_step=window.sample)
     mem = common.memory_peak_bytes()
     win = window.counters()
+    win["step_mfu_pct"] = step_mfu_pct(ctx.cell["config"], mcfg, win, sizes)
     in_window = [s for s, inside in finished_at if inside]
     rejected = [s for s in sent if s.result is not None
                 and not s.result.ok]

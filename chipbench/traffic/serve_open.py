@@ -11,8 +11,10 @@ see ``standing``; absent or 0: the run starts on an empty pool), ``ramp_s``
 (the standing requests are sent at its start and arrivals run through it;
 set-up), ``tail_s`` (arrivals go on that long after the window, not counted,
 so that the window's last requests get their first tokens under the same
-load and not in an emptying engine; a traced run profiles ``trace_s`` of
-them; then the callers of whatever is still decoding hang up), ``logit_tol``.
+load and not in an emptying engine; then the callers of whatever is still
+decoding hang up; a traced run profiles ``trace_s`` of them and its callers
+hang up there, before the profiler stops, which stalls the host for
+seconds), ``logit_tol``.
 
 A request due in the window counts with the times it had when it finished or
 when its caller hung up (``serving.request_times``). It has FAILED, and
@@ -95,10 +97,13 @@ def run(ctx: common.Ctx, sizes=None) -> dict:
     serving.warm_up(engine, mcfg, ecfg, np.random.default_rng(ctx.seed31))
     sched = schedule(t, ctx.seed31, ctx.seconds, mcfg.vocab_size)
     ramp = float(t["ramp_s"])
-    give_up = ramp + ctx.seconds + float(t["tail_s"])
     window = serving.Window(engine, ctx)
     tracer = serving.Tracer(ctx.trace_dir if ctx.trace else None,
                             float(t["trace_s"]))
+    # a traced run's callers hang up where its trace ends: the profiler is
+    # stopped after them, and its stall is in no request's times
+    give_up = ramp + ctx.seconds + (tracer.span_s if ctx.trace
+                                    else float(t["tail_s"]))
     sent = serving.drive(
         engine, due=sched, on_finish=lambda s: None, t_open=ramp,
         t_close=ramp + ctx.seconds, t_give_up=give_up, tracer=tracer,
@@ -106,6 +111,7 @@ def run(ctx: common.Ctx, sizes=None) -> dict:
         each_step=window.sample, hang_up=True)
     mem = common.memory_peak_bytes()
     win = window.counters()
+    win["step_mfu_pct"] = serving.gpt_step_mfu_pct(mcfg, win, sizes)
     counted = [s for s in sched if s.counted]
     times = [serving.request_times(s) for s in counted]
     stalled = serving.stalled(times)

@@ -128,7 +128,7 @@ def run(ctx: common.Ctx, sizes=None) -> dict:
     bad = [l for l in losses if not math.isfinite(l)]
     if bad:
         problems.append(f"{len(bad)} non-finite losses")
-    if in_window:
+    if not common.compare("compiled_in_window", in_window, 0):
         problems.append(f"{in_window} programs compiled inside the window")
     if not losses[-1] < losses[0]:
         problems.append(f"loss did not fall: {losses[0]} -> {losses[-1]}")
@@ -138,10 +138,11 @@ def run(ctx: common.Ctx, sizes=None) -> dict:
     common.note("reference_step", **gap, loss_tol=tol,
                 loss_tol_why=t["loss_tol_why"], grad_tol=gtol,
                 grad_tol_why=t["grad_tol_why"])
-    if not gap["abs_diff"] <= tol:
+    if not common.compare("loss_abs_diff", gap["abs_diff"], tol):
         problems.append(f"loss at the initial weights is {gap['abs_diff']} "
                         f"from the reference's, tolerance {tol}")
-    if not gap["grad_rel_err_worst"] <= gtol:
+    if not common.compare("grad_rel_err_worst", gap["grad_rel_err_worst"],
+                          gtol):
         problems.append(f"gradient of {gap['grad_worst_leaf']} is "
                         f"{gap['grad_rel_err_worst']} (relative) from the "
                         f"reference's, tolerance {gtol}")
