@@ -97,18 +97,23 @@ class ModelConfig:
 
     # --- family (models/families.py picks the body by this) ---------------
     # Everything below is DATA of an architecture other than GPT-2's; the
-    # defaults are GPT-2's, so every older preset is unchanged. The one
-    # family beside it, 'exaone_moe' (models/exaone_moe.py), is serve-only.
-    # What the family alone decides (RMSNorm, QK-norm, rotate-half RoPE on
-    # the window layers only, sigmoid scores normalised over the chosen) is
-    # that module's, not a field here: no caller can vary it.
-    family: str = "gpt"           # 'gpt' | 'exaone_moe'
+    # defaults are GPT-2's, so every older preset is unchanged. The two
+    # families beside it, 'exaone_moe' (models/exaone_moe.py) and 'lfm2_moe'
+    # (models/lfm2_moe.py), are serve-only. What a family alone decides
+    # (RMSNorm, QK-norm, which layers rotate, sigmoid scores normalised
+    # over the chosen) is its module's, not a field here: no caller can
+    # vary it.
+    family: str = "gpt"           # 'gpt' | 'exaone_moe' | 'lfm2_moe'
     n_kv_head: int = 0            # KV heads (GQA); 0 = n_head
     attn_head_dim: int = 0        # head size; 0 = n_embd // n_head
     rope_theta: float = 10000.0   # rotary base, where the family rotates
     layer_types: Tuple[str, ...] = ()
-    # per layer 'sliding_attention' | 'full_attention'; () = all full
+    # per layer 'sliding_attention' | 'full_attention' | 'conv' (a gated
+    # short convolution, lfm2_moe's); () = all full
     sliding_window: int = 0       # token i attends j, i - window < j <= i
+    conv_reach: int = 0
+    # taps of a conv layer's depthwise causal convolution, and the columns
+    # of its state a slot (config.json conv_L_cache)
     mlp_layer_types: Tuple[str, ...] = ()
     # per layer 'dense' | 'sparse'; () = all dense
     intermediate_size: int = 0    # gated (SwiGLU) dense MLP width
@@ -119,6 +124,8 @@ class ModelConfig:
     experts_per_token: int = 0
     moe_intermediate_size: int = 0
     routed_scaling: float = 1.0
+    router_norm_eps: float = 0.0
+    # added to the chosen scores' sum before they are normalised
     shared_intermediate_size: int = 0   # one shared expert; 0 = none
 
     @property
@@ -133,9 +140,10 @@ class ModelConfig:
     @property
     def paged_layers(self) -> Tuple[int, ...]:
         """Layers whose K/V history lives in pool pages (all of GPT-2's;
-        the full-attention layers of a windowed family)."""
+        the full-attention layers of a family that has other kinds)."""
         return tuple(i for i in range(self.n_layer)
-                     if not self.is_window_layer(i))
+                     if not (self.is_window_layer(i)
+                             or self.is_conv_layer(i)))
 
     @property
     def window_layers(self) -> Tuple[int, ...]:
@@ -143,9 +151,18 @@ class ModelConfig:
         return tuple(i for i in range(self.n_layer)
                      if self.is_window_layer(i))
 
+    @property
+    def conv_layers(self) -> Tuple[int, ...]:
+        """Layers that keep ``conv_reach`` columns of state a slot."""
+        return tuple(i for i in range(self.n_layer)
+                     if self.is_conv_layer(i))
+
     def is_window_layer(self, i: int) -> bool:
         return bool(self.layer_types) and \
             self.layer_types[i] == "sliding_attention"
+
+    def is_conv_layer(self, i: int) -> bool:
+        return bool(self.layer_types) and self.layer_types[i] == "conv"
 
     def is_sparse_layer(self, i: int) -> bool:
         return bool(self.mlp_layer_types) and \
@@ -171,19 +188,19 @@ class ModelConfig:
 
     def validate(self) -> "ModelConfig":
         _ = self.head_dim
-        assert self.family in ("gpt", "exaone_moe"), self.family
+        assert self.family in ("gpt", "exaone_moe", "lfm2_moe"), self.family
         if self.family == "gpt":
             assert self.activation in ("gelu", "relu"), self.activation
             for name in ("n_kv_head", "attn_head_dim", "layer_types",
-                         "sliding_window", "mlp_layer_types",
+                         "sliding_window", "conv_reach", "mlp_layer_types",
                          "intermediate_size", "n_experts", "experts_held",
                          "experts_per_token", "moe_intermediate_size",
-                         "shared_intermediate_size"):
+                         "router_norm_eps", "shared_intermediate_size"):
                 assert not getattr(self, name), (
                     f"{name} is not GPT-2's: models/gpt.py has one head "
                     f"count, learned positions and a dense MLP")
         else:
-            self._validate_exaone_moe()
+            self._validate_served_family()
         assert self.attention_impl in ("auto", "einsum", "flash", "ring",
                                        "ulysses")
         assert self.remat_policy in ("full", "dots", "dots_no_batch"), (
@@ -191,23 +208,43 @@ class ModelConfig:
         assert self.act_quant in ("none", "int8"), self.act_quant
         return self
 
-    def _validate_exaone_moe(self) -> None:
-        """What models/exaone_moe.py computes, and nothing near it."""
+    def _validate_served_family(self) -> None:
+        """What models/exaone_moe.py and models/lfm2_moe.py compute, and
+        nothing near it."""
         L = self.n_layer
         assert self.activation == "swiglu", self.activation
         assert self.n_head % self.kv_heads == 0, (
             f"{self.n_head} query heads do not group over "
             f"{self.kv_heads} KV heads")
         assert self.head_dim % 2 == 0, "rotate-half needs an even head"
-        assert not self.tied_head, "the family's head is untied"
-        assert len(self.layer_types) == L and all(
-            t in ("sliding_attention", "full_attention")
-            for t in self.layer_types), f"layer_types={self.layer_types}"
         assert len(self.mlp_layer_types) == L and all(
             t in ("dense", "sparse") for t in self.mlp_layer_types), (
             f"mlp_layer_types={self.mlp_layer_types}")
-        if self.window_layers:
-            assert self.sliding_window > 0, "window layers need a window"
+        kinds = ("full_attention", "sliding_attention"
+                 if self.family == "exaone_moe" else "conv")
+        assert len(self.layer_types) == L and all(
+            t in kinds for t in self.layer_types), (
+            f"layer_types={self.layer_types}")
+        if self.family == "exaone_moe":
+            assert not self.tied_head, "the family's head is untied"
+            assert not self.conv_reach and not self.router_norm_eps, (
+                "conv_reach and router_norm_eps are lfm2_moe's")
+            if self.window_layers:
+                assert self.sliding_window > 0, "window layers need a window"
+        else:
+            assert self.tied_head, (
+                "the family's head is tied to the embedding")
+            assert self.conv_reach >= 2, (
+                f"conv_reach={self.conv_reach}: a conv layer reaches at "
+                f"least one column back")
+            assert not self.sliding_window, (
+                "sliding_window: the family has no window layer")
+            assert not self.shared_intermediate_size, (
+                "shared_intermediate_size: the family has no shared expert")
+            n_dense = self.mlp_layer_types.count("dense")
+            assert self.mlp_layer_types[:n_dense] == ("dense",) * n_dense, (
+                f"mlp_layer_types={self.mlp_layer_types}: the dense layers "
+                f"lead (num_dense_layers)")
         if "dense" in self.mlp_layer_types:
             assert self.intermediate_size > 0
         if "sparse" in self.mlp_layer_types:
@@ -345,6 +382,26 @@ def _exaone_moe(n_layer: int, **kw) -> ModelConfig:
         decode_cache_layout="packed", scan_layers=False, **kw)
 
 
+def _lfm2_moe(n_layer: int, n_dense: int, **kw) -> ModelConfig:
+    """The lfm2_moe family's constants (LFM2-24B-A2B config.json and
+    ``transformers``' ``lfm2_moe``): RMSNorm eps 1e-5, gated short
+    convolutions of reach 3 with a full-attention layer (QK-norm, RoPE
+    theta 1e6) at every fourth place from the third, ``n_dense`` leading
+    dense SwiGLU layers and sigmoid-routed experts after them (scaling 1,
+    top-k normalised over a sum that has 1e-6 added, no shared expert),
+    head tied to the embedding."""
+    return ModelConfig(
+        family="lfm2_moe", n_layer=n_layer, dropout=0.0, attn_dropout=0.0,
+        tied_head=True, activation="swiglu", rope_theta=1e6,
+        layer_types=tuple("full_attention" if i % 4 == 2 else "conv"
+                          for i in range(n_layer)),
+        conv_reach=3,
+        mlp_layer_types=("dense",) * n_dense
+        + ("sparse",) * (n_layer - n_dense),
+        routed_scaling=1.0, router_norm_eps=1e-6,
+        decode_cache_layout="packed", scan_layers=False, **kw)
+
+
 PRESETS = {
     # BASELINE.json config 1/2: canonical char-GPT (n_embd=384 per
     # BASELINE.md; GPT1.py semantics: untied head, ReLU, dropout 0.2).
@@ -467,6 +524,37 @@ PRESETS = {
             intermediate_size=96, n_experts=8, experts_held=(0, 1),
             experts_per_token=2, moe_intermediate_size=48,
             shared_intermediate_size=48, dtype="float32",
+            param_dtype="float32"),
+        tokenizer="char",
+    ),
+    # LFM2-24B-A2B (LiquidAI, config.json on the Hugging Face hub), THE FIRST
+    # STAGE of a four-stage pipeline on one four-chip host, serve-only:
+    # every width, all 64 experts and 4 a token, the whole vocabulary as
+    # published; published layers 0-9 (conv conv full, conv conv conv full,
+    # conv conv conv: the 2 leading dense layers, then 8 expert layers = two
+    # whole periods). bf16 parameters as published.
+    # chipbench/configs/lfm2-24b-a2b.json states the cut.
+    "lfm2-24b-a2b": Config(
+        name="lfm2-24b-a2b",
+        model=_lfm2_moe(
+            10, 2, vocab_size=65_536, block_size=8192, n_head=32,
+            n_kv_head=8, attn_head_dim=64, n_embd=2048,
+            intermediate_size=11_776, n_experts=64,
+            experts_held=tuple(range(64)), experts_per_token=4,
+            moe_intermediate_size=1536, dtype="bfloat16",
+            param_dtype="bfloat16"),
+        tokenizer="char",
+    ),
+    # the same family at test widths (CPU tests, chipbench/rehearse.py):
+    # 7 layers (conv conv full conv conv conv full), 2 dense then 5 sparse,
+    # all 8 experts held, 2 a token
+    "lfm2-moe-tiny": Config(
+        name="lfm2-moe-tiny",
+        model=_lfm2_moe(
+            7, 2, vocab_size=96, block_size=64, n_head=4, n_kv_head=2,
+            attn_head_dim=32, n_embd=64, intermediate_size=96, n_experts=8,
+            experts_held=tuple(range(8)), experts_per_token=2,
+            moe_intermediate_size=48, dtype="float32",
             param_dtype="float32"),
         tokenizer="char",
     ),

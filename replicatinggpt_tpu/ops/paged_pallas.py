@@ -202,9 +202,9 @@ def live_blocks(pos, page_size: int, n_block: int):
     return -(-pos // (page_size * n_block))
 
 
-# scalar-prefetch operands ``_fetch_block`` reads: ``_blocked_walk``'s five
+# scalar-prefetch operands ``_fetch_block`` reads: ``_blocked_walk``'s four
 # and the pool's layer (``_at_layer``)
-N_WALK = 6
+N_WALK = 5
 
 
 @jax.named_scope("kv_gather")
@@ -219,21 +219,24 @@ def _blocked_walk(tables: jnp.ndarray, owned: jnp.ndarray, page_size: int,
     Grid step ``t = b * n_blocks + p`` covers logical pages p*P .. p*P +
     P - 1 of slot b. The table and the mask are padded to whole blocks
     with unowned entries (a length P does not divide ends in a short
-    block). A step is LIVE if its block holds an owned page; the live
-    steps fetch for one another, so each carries its rank among them
-    (its parity picks the half of the double buffer) and the next live
-    step (-1: none), which may be another slot's."""
+    block), and the walk's table says which entries are owned: an unowned
+    one reads -1 (ONE (B, n_blocks * P) array in scalar memory, not a
+    table and a mask: at 256 slots of 512 pages the two were 1.05 MB of a
+    v5e's 1 MB). A step is LIVE if its block holds an owned page; the
+    live steps fetch for one another, so each carries its rank among
+    them (its parity picks the half of the double buffer) and the next
+    live step (-1: none), which may be another slot's."""
     B, mp = tables.shape
     P = block_pages(page_size, mp, row_bytes)
     nb = -(-mp // P)
     pad = ((0, 0), (0, nb * P - mp))
     owned = jnp.pad(owned, pad)
-    table = jnp.where(owned, jnp.pad(jnp.asarray(tables, jnp.int32), pad), 0)
+    table = jnp.where(owned, jnp.pad(jnp.asarray(tables, jnp.int32), pad), -1)
     live = owned.reshape(B * nb, P).any(axis=1)
     step = jnp.arange(B * nb, dtype=jnp.int32)
     later = jax.lax.cummin(jnp.where(live, step, B * nb), reverse=True)
     nxt = jnp.concatenate([later[1:], jnp.full((1,), B * nb, jnp.int32)])
-    return P, nb, (table, owned.astype(jnp.int32), live.astype(jnp.int32),
+    return P, nb, (table, live.astype(jnp.int32),
                    jnp.cumsum(live, dtype=jnp.int32) - 1,
                    jnp.where(nxt < B * nb, nxt, -1))
 
@@ -286,7 +289,7 @@ def _fetch_block(walk, pools, bufs, sem, n_block: int, n_blocks: int,
     what a half holds there is an older page or the zeros of the
     call's first step. A step that is not live reads three scalars
     here and copies nothing."""
-    table_ref, owned_ref, live_ref, rank_ref, next_ref, layer_ref = walk
+    table_ref, live_ref, rank_ref, next_ref, layer_ref = walk
     P, psz = n_block, page_size
     b0, p0 = pl.program_id(0), pl.program_id(1)
     t = b0 * n_blocks + p0
@@ -300,7 +303,7 @@ def _fetch_block(walk, pools, bufs, sem, n_block: int, n_blocks: int,
         b, p = step // n_blocks, step % n_blocks
 
         def page(j, _):
-            @pl.when(owned_ref[b, p * P + j] > 0)
+            @pl.when(table_ref[b, p * P + j] >= 0)
             def _owned():
                 for a, (pool, buf) in enumerate(zip(pools, bufs)):
                     act(pltpu.make_async_copy(
@@ -332,7 +335,7 @@ def _fetch_block(walk, pools, bufs, sem, n_block: int, n_blocks: int,
         page = jax.lax.broadcasted_iota(jnp.int32, (1, P * psz), 1) // psz
         own = jnp.zeros_like(page)
         for j in range(P):
-            own = jnp.where(page == j, owned_ref[b0, p0 * P + j], own)
+            own = jnp.where(page == j, table_ref[b0, p0 * P + j] + 1, own)
         return own > 0
 
     return live, half, cols
@@ -560,7 +563,7 @@ def paged_window_attention(q: jnp.ndarray, k_new: jnp.ndarray,
         in_specs += [_vmem_spec((None, None, P * psz, swidth),
                                 lambda b, p, *_: (b, p, 0, 0))] * 2
         with jax.named_scope("kv_gather"):
-            inputs += [sc[layer, walk[0]].reshape(
+            inputs += [sc[layer, jnp.maximum(walk[0], 0)].reshape(
                 B, nb, P * psz, swidth) for sc in (k_scales, v_scales)]
     pool_specs, pool_scratch = _pool_operands([k_pages, v_pages], P)
     hps = _heads_per_slab(n_head, D)

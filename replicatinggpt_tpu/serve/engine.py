@@ -679,8 +679,11 @@ def served_tree(params, names, dtype):
     array wider than ``dtype`` replaced by its ``dtype`` copy, made by
     one jitted cast (the rounding the launch did); every other leaf is
     the SAME array. The programs' own ``astype`` is then the identity,
-    so no launch converts a weight again. A tree already in the compute
-    dtype, or quantised kernels, come back leaf for leaf."""
+    so no launch converts a weight again. A tree with nothing to cast
+    (already in the compute dtype, or quantised kernels) comes back AS IT
+    IS, the same containers: whoever replaces a leaf of ``params`` in
+    place afterwards (the benchmark's 8-bit control rounds 10 GB leaf by
+    leaf) then frees the old array instead of leaving it alive here."""
     dtype = jnp.dtype(dtype)
     flat, treedef = jax.tree_util.tree_flatten_with_path(params)
     leaves = [a for _, a in flat]
@@ -688,10 +691,10 @@ def served_tree(params, names, dtype):
             if getattr(path[-1], "key", None) in names
             and jnp.issubdtype(a.dtype, jnp.floating)
             and a.dtype.itemsize > dtype.itemsize]
-    if wide:
-        for i, c in zip(wide, _cast_leaves([leaves[i] for i in wide],
-                                           dtype)):
-            leaves[i] = c
+    if not wide:
+        return params
+    for i, c in zip(wide, _cast_leaves([leaves[i] for i in wide], dtype)):
+        leaves[i] = c
     return treedef.unflatten(leaves)
 
 
@@ -1480,10 +1483,12 @@ class Engine:
         # paged-pool health: bench dashboards key on this block (schema
         # pinned in tests/test_pages.py)
         s["pages"] = self.pool.stats()
-        # the pool's bytes by kind of KV state: pages that admission
-        # reserves, and per-slot window rings (0 for GPT-2)
-        s["kv_global_bytes"], s["kv_window_bytes"] = \
-            self.pool.bytes_by_kind()
+        # the pool's bytes by kind of state: pages that admission
+        # reserves, and what a slot keeps beside them (0 for GPT-2)
+        kinds = self.pool.bytes_by_kind()
+        s["kv_global_bytes"] = kinds["pages"]
+        s["kv_window_bytes"] = kinds.get("window", 0)
+        s["conv_state_bytes"] = kinds.get("conv", 0)
         # bytes of the compute-dtype copies made at build (0: the tree
         # came in the compute dtype and is served as it is)
         s["weight_cast_bytes"] = weight_cast_bytes(self.params,
@@ -1920,13 +1925,18 @@ class Engine:
         live_tokens = int(self._pos[live].sum()) + n_active
         stochastic = self._count_stochastic_rows(live & (self._pf_left < k))
         extra = {}
-        if self._launch_extra is not None:
-            window, swa_token_bytes, expert_bytes = self._launch_extra
-            extra = dict(
-                swa_kv_bytes=int(np.minimum(self._pos[live] + 1,
-                                            window).sum()) * swa_token_bytes,
-                expert_weight_bytes=expert_bytes * k,
-                moe_rows=n_active * k)
+        fx = self._launch_extra or {}
+        if "swa_token_bytes" in fx:
+            extra["swa_kv_bytes"] = int(np.minimum(
+                self._pos[live] + 1, fx["window"]).sum()) \
+                * fx["swa_token_bytes"]
+        if "conv_slot_bytes" in fx:
+            state = n_active * fx["conv_slot_bytes"] * k
+            extra.update(conv_state_bytes=state,
+                         short_conv_bytes=fx["conv_weight_bytes"] * k + state)
+        if "expert_bytes" in fx:
+            extra.update(expert_weight_bytes=fx["expert_bytes"] * k,
+                         moe_rows=n_active * k)
         if self._kv_block_pages:
             extra.update(self._kv_walk_stats(live))
         with self.tel.phase("serve/launch", self._tb + ENGINE_TRACK, k=k,
@@ -1953,24 +1963,38 @@ class Engine:
             kv_blocks_grid=self.ecfg.pool_size * -(-self.pool.max_pages
                                                    // P))
 
-    def _family_launch_stats(self) -> Optional[tuple]:
-        """``(window, bytes a ring token holds over the window layers,
-        bytes of the held experts over the sparse layers)`` for a family
-        that has them: what ``swa_kv_bytes`` and ``expert_weight_bytes``
-        of ``serve/launch`` multiply. A decode step streams every held
-        expert once (at 64 live rows 98% of them get a token), so the
-        second is per step whatever was routed."""
+    def _family_launch_stats(self) -> Optional[dict]:
+        """What a family's state beside the pages and its experts add to
+        the stats of ``serve/launch``, as bytes a unit (None: GPT-2, whose
+        stats are unchanged): ``window`` and ``swa_token_bytes`` (a ring
+        token over the window layers) for ``swa_kv_bytes``;
+        ``conv_slot_bytes`` (a slot's state over the conv layers, read and
+        written once a step) and ``conv_weight_bytes`` (the conv layers'
+        projections and taps) for ``conv_state_bytes`` and
+        ``short_conv_bytes``; ``expert_bytes`` (the held experts over the
+        sparse layers) for ``expert_weight_bytes``: a decode step streams
+        every held expert once (at 64 live rows 98% of them get a token),
+        so that is per step whatever was routed."""
         cfg = self.cfg
-        if not (cfg.window_layers or cfg.n_experts):
-            return None
-        rings = [a for n, a in self.pool.cache.items()
-                 if n not in self.pool.pages]
-        swa_token = (sum(a.nbytes for a in rings)
-                     // max(rings[0].shape[1] * rings[0].shape[2], 1)
-                     if rings else 0)
-        experts = sum(a.nbytes for lp in self.served_params["layers"]
-                      for n, a in lp.items() if n.startswith("e_"))
-        return cfg.sliding_window, swa_token, experts
+        out = {}
+        state = self.pool.slot_state()
+        if state.get("window"):
+            rings = state["window"]
+            out.update(window=cfg.sliding_window, swa_token_bytes=(
+                sum(a.nbytes for a in rings)
+                // (rings[0].shape[1] * rings[0].shape[2])))
+        if state.get("conv"):
+            out.update(
+                conv_slot_bytes=(sum(a.nbytes for a in state["conv"])
+                                 // self.pool.n_slots),
+                conv_weight_bytes=sum(
+                    a.nbytes for lp in self.served_params["layers"]
+                    for n, a in lp.items() if n.startswith("conv_")))
+        if cfg.n_experts:
+            out["expert_bytes"] = sum(
+                a.nbytes for lp in self.served_params["layers"]
+                for n, a in lp.items() if n.startswith("e_"))
+        return out or None
 
     def _dispatch(self, k: int, kill: np.ndarray, n_active: int,
                   t0_us: float, t_wall: float) -> _InFlight:

@@ -429,11 +429,14 @@ class PagedCachePool:
         invariant (allocator, radix, COW planning) is byte-for-byte
         unchanged — a page is its rows plus their scales."""
         assert n_slots >= 1, n_slots
-        if prefix_cache and cfg.window_layers:
+        fam = family(cfg)
+        self._slot_entries = fam.slot_entries
+        if prefix_cache and self._slot_entries:
             raise ValueError(
                 "prefix_cache: the radix cache shares pages of layers that "
-                "keep paged history; a window layer's state is a ring a "
-                "slot and cannot be restored from it")
+                "keep paged history; the family's "
+                f"{'/'.join(kind for kind, _ in self._slot_entries)} state "
+                "belongs to a slot and cannot be restored from it")
         self.cfg = cfg
         self.n_slots = n_slots
         self.quant = quant
@@ -457,8 +460,6 @@ class PagedCachePool:
         self.alloc = PageAllocator(self.n_pages, self.page_size,
                                    prefix_cache=prefix_cache,
                                    telemetry=telemetry)
-        fam = family(cfg)
-        self._slot_prefix = fam.slot_entry_prefix
         pool = fam.init_paged_kv_pool(cfg, self.n_pages, self.page_size,
                                       dtype=dtype, quant=quant,
                                       n_slots=n_slots)
@@ -494,16 +495,20 @@ class PagedCachePool:
 
     # ---------------------------------------------------------- geometry
 
+    def _is_slot_entry(self, name: str) -> bool:
+        return any(name.startswith(p) for _, p in self._slot_entries)
+
     @property
     def pages(self) -> Dict:
         """The entries of ``cache`` that are pool PAGES (what admission
         reserves, what a page copy, export or install walks): all of
-        GPT-2's; a family with per-slot state beside them (window rings)
-        keeps that under names with its ``slot_entry_prefix``."""
-        if not any(n.startswith(self._slot_prefix) for n in self.cache):
+        GPT-2's; a family with per-slot state beside them (window rings,
+        conv state) keeps that under names with the prefixes of its
+        ``slot_entries``."""
+        if not self._slot_entries:
             return self.cache
         return {n: a for n, a in self.cache.items()
-                if not n.startswith(self._slot_prefix)}
+                if not self._is_slot_entry(n)}
 
     @pages.setter
     def pages(self, new: Dict) -> None:
@@ -515,10 +520,18 @@ class PagedCachePool:
         """One page array of the pool: its dtype and page count."""
         return next(iter(self.pages.values()))
 
-    def bytes_by_kind(self) -> Tuple[int, int]:
-        """``(bytes in pool pages, bytes in per-slot window state)``."""
-        paged = sum(a.nbytes for a in self.pages.values())
-        return paged, sum(a.nbytes for a in self.cache.values()) - paged
+    def slot_state(self) -> Dict[str, list]:
+        """The per-slot state beside the pages: kind -> its arrays."""
+        return {kind: [a for n, a in self.cache.items()
+                       if n.startswith(prefix)]
+                for kind, prefix in self._slot_entries}
+
+    def bytes_by_kind(self) -> Dict[str, int]:
+        """Bytes of the pool by kind of state: ``pages`` (what admission
+        reserves) and each kind of per-slot state the family keeps."""
+        return {"pages": sum(a.nbytes for a in self.pages.values()),
+                **{kind: sum(a.nbytes for a in arrays)
+                   for kind, arrays in self.slot_state().items()}}
 
     @property
     def seq_len(self) -> int:

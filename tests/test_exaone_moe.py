@@ -16,7 +16,7 @@ import pytest
 
 from replicatinggpt_tpu import reference_exaone_moe as ref
 from replicatinggpt_tpu.config import get_config
-from replicatinggpt_tpu.models import exaone_moe as xm
+from replicatinggpt_tpu.models import exaone_moe as xm, layers
 from replicatinggpt_tpu.models.families import family, serve_refusals
 from replicatinggpt_tpu.ops import paged_pallas
 from replicatinggpt_tpu.serve import Engine, EngineConfig
@@ -128,57 +128,7 @@ def test_prefill_then_decode_matches_reference(params, use_pallas, window):
         assert np.abs(got[b] - rows).max() < LOGIT_TOL, (b, use_pallas)
 
 
-def test_decode_window_is_the_step_repeated(params):
-    """``decode_window_paged`` (W steps in one program) emits what W single
-    steps emit, and its token block carries the held pairs in its last
-    column."""
-    B, mp = 2, CFG.block_size // PSZ
-    cache = xm.init_paged_kv_pool(CFG, B * mp, PSZ, n_slots=B)
-    tables = jnp.arange(B * mp, dtype=jnp.int32).reshape(B, mp)
-    tok, pos = jnp.asarray([5, 9], jnp.int32), jnp.zeros((B,), jnp.int32)
-    active = jnp.ones((B,), bool)
-    rngs = jnp.stack([jax.random.PRNGKey(i) for i in range(B)])
-    greedy = lambda r, logits, live: (jnp.argmax(logits, -1)
-                                      .astype(jnp.int32), r)
-    toks, emitted, *_ = xm.decode_window_paged(
-        params, tok, pos, active, jnp.full((B,), 9, jnp.int32),
-        jnp.full((B,), -1, jnp.int32), tables, cache, rngs, CFG,
-        sample_fn=greedy, length=4)
-    assert toks.shape == (4, B + 1) and bool(emitted.all())
-    c, t, p = cache, tok, pos
-    for s in range(4):
-        logits, c, pairs = xm.decode_step_paged(params, t, p, active,
-                                                tables, c, CFG)
-        t = jnp.argmax(logits, -1).astype(jnp.int32)
-        p = p + 1
-        assert np.array_equal(np.asarray(toks[s, :B]), np.asarray(t))
-        assert int(toks[s, B]) == int(pairs)
-
-
 # --------------------------------------------------------------- 3. the share
-
-def _one_sparse_layer(cfg, seed=3):
-    p = xm.init_params(jax.random.PRNGKey(seed), cfg)
-    return p["layers"][1]
-
-
-def test_eight_shares_and_the_shared_expert_once_add_up():
-    whole = dataclasses.replace(CFG, experts_held=tuple(range(8)))
-    lp = _one_sparse_layer(whole)
-    m = jax.random.normal(jax.random.PRNGKey(4), (12, CFG.n_embd))
-    y_whole, top, pairs = xm.moe(m, lp, whole)
-    assert int(pairs) == 12 * CFG.experts_per_token     # every pair is held
-    shared = xm._swiglu(m, lp["s_gate"], lp["s_up"], lp["s_down"])
-    total = shared
-    for e in range(8):
-        share = dataclasses.replace(CFG, experts_held=(e,))
-        lp_e = {**lp, **{n: lp[n][e:e + 1]
-                         for n in ("e_gate", "e_up", "e_down")}}
-        y_e, top_e, _ = xm.moe(m, lp_e, share)
-        assert np.array_equal(np.asarray(top_e), np.asarray(top))
-        total = total + (y_e - shared)      # its routed part alone
-    assert np.abs(np.asarray(total - y_whole)).max() < 1e-5
-
 
 def test_the_share_is_the_uncut_reference_layer_less_the_absent_experts():
     """The reference, given the same share, leaves out the same part."""
@@ -206,27 +156,6 @@ def test_sliced_head_gives_the_uncut_heads_columns(params):
 
 
 # ----------------------------------------------------------------- 4. routing
-
-def test_selection_by_s_plus_b_weights_from_s_alone(params):
-    lp = dict(params["layers"][1])
-    # expert 5 is pushed into every token's choice by its bias alone
-    lp["router_bias"] = jnp.zeros((8,)).at[5].set(10.0)
-    m = jax.random.normal(jax.random.PRNGKey(2), (9, CFG.n_embd))
-    w, top = xm.route(m, lp, CFG)
-    s = np.asarray(jax.nn.sigmoid(
-        jnp.dot(m, lp["router"], precision=jax.lax.Precision.HIGHEST)))
-    w, top = np.asarray(w), np.asarray(top)
-    for r in range(9):
-        chosen = set(top[r].tolist())
-        assert 5 in chosen and len(chosen) == CFG.experts_per_token
-        other = max((e for e in range(8) if e != 5), key=lambda e: s[r, e])
-        assert chosen == {5, other}
-        denom = sum(s[r, e] for e in chosen)
-        for e in range(8):
-            want = 2.5 * s[r, e] / denom if e in chosen else 0.0
-            assert abs(w[r, e] - want) < 1e-6       # the bias is not in w
-        assert abs(w[r].sum() - 2.5) < 1e-5         # normalised, times 2.5
-
 
 def test_near_ties_are_counted_inside_the_margin_and_never_taken(params):
     """Choices from a program whose router was nudged: where the chosen
@@ -397,73 +326,18 @@ def test_window_state_does_not_grow_with_context():
     for j in range(len(CFG.window_layers)):
         assert pool.cache[f"wk{j}"].shape == (1, 3 * ring, PSZ,
                                               CFG.kv_channels)
-    paged, window = pool.bytes_by_kind()
+    kinds = pool.bytes_by_kind()
     before = {n: a.shape for n, a in pool.cache.items()}
     short = pool.acquire("a", _ids(1, 4), 4)
     long_ = pool.acquire("b", _ids(2, 40), 20)
     assert {n: a.shape for n, a in pool.cache.items()} == before
-    assert pool.bytes_by_kind() == (paged, window)
+    assert pool.bytes_by_kind() == kinds and set(kinds) == {"pages",
+                                                            "window"}
     held = lambda adm: int((pool.tables[adm.slot] != 0).sum())
     assert held(long_) > held(short)        # pages follow the context
 
 
-def test_a_finished_request_gives_back_both_kinds():
-    pool = _pool()
-    free0, slots0 = pool.alloc.pages_free, pool.n_free
-    adm = pool.acquire("a", _ids(1, 30), 10)
-    assert pool.alloc.pages_free == free0 - 5 and pool.n_free == slots0 - 1
-    pool.release(adm.slot)
-    assert pool.alloc.pages_free == free0 and pool.n_free == slots0
-    again = pool.acquire("b", _ids(2, 3), 2)        # the ring with the slot
-    assert again.slot == adm.slot
-
-
-def test_admission_counts_global_pages_only():
-    pool = _pool(n_slots=4, n_pages=8)      # one slot's worst case
-    assert pool.alloc.n_pages_for(30, 10) == 5
-    assert page_bytes(CFG, PSZ) == (len(CFG.paged_layers) * PSZ * 2
-                                    * CFG.kv_channels * 4)
-    assert pool.can_admit(_ids(1, 30), 10)          # 5 of 8 pages
-    pool.acquire("a", _ids(1, 30), 10)
-    assert not pool.can_admit(_ids(2, 30), 10)      # pages, not rings
-    assert pool.can_admit(_ids(3, 10), 6)           # 2 pages still fit
-
-
 # --------------------------------------------------------------- 7. precision
-
-def _worst(params, idx, cfg=CFG):
-    got = np.asarray(xm.forward(params, jnp.asarray(idx[None]), cfg))[0]
-    want, _ = _ref_logits(params, idx, cfg)
-    return np.abs(got - want).max()
-
-
-def test_tolerance_fails_under_bfloat16_routing(params, monkeypatch):
-    idx = _ids(21, 48)
-    assert _worst(params, idx) < LOGIT_TOL
-    exact = xm.route
-
-    def bf16_route(m, lp, cfg):
-        lp = {**lp, "router": lp["router"].astype(jnp.bfloat16)
-              .astype(jnp.float32)}
-        return exact(m.astype(jnp.bfloat16).astype(jnp.float32), lp, cfg)
-
-    monkeypatch.setattr(xm, "route", bf16_route)
-    assert _worst(params, idx) > LOGIT_TOL
-
-
-def test_tolerance_fails_under_8_bit_weights(params):
-    def to8(a):
-        if a.ndim < 2:
-            return a
-        scale = jnp.abs(a).max() / 127.0
-        return jnp.round(a / scale) * scale
-
-    idx = _ids(22, 48)
-    rounded = jax.tree_util.tree_map(to8, params)
-    got = np.asarray(xm.forward(rounded, jnp.asarray(idx[None]), CFG))[0]
-    want, _ = _ref_logits(params, idx)
-    assert np.abs(got - want).max() > LOGIT_TOL
-
 
 # ---------------------------------------------------------------- the engine
 
@@ -477,82 +351,7 @@ ECFG = EngineConfig(pool_size=3, page_size=PSZ, prefill_chunk=16,
                     paged_kernel=True, prefix_cache=False, max_queue=16)
 
 
-def test_engine_serves_the_family_through_submit_and_step(params,
-                                                          kernel_on_cpu):
-    eng = Engine(params, CFG, ECFG)
-    rng = np.random.default_rng(0)
-    reqs = [Request(id=f"r{i}", prompt=rng.integers(
-        0, CFG.vocab_size, (n,), dtype=np.int32), max_new_tokens=m,
-        sampling=SamplingParams(greedy=True))
-        for i, (n, m) in enumerate([(3, 12), (17, 9), (30, 20), (9, 5),
-                                    (24, 11)])]
-    for r in reqs:
-        assert eng.submit(r) is None
-    done = {r.id: r for r in eng.drain()}
-    assert len(done) == 5 and all(r.ok for r in done.values())
-    spec = ref.spec_of(CFG)
-    gaps, mean_gap, _ = ref.stream_gaps(
-        params, spec, CFG.block_size, [r.prompt for r in reqs],
-        [np.asarray(done[r.id].tokens, np.int32) for r in reqs],
-        row_block=16)
-    assert max(gaps) < LOGIT_TOL and mean_gap <= max(gaps), gaps
-    s = eng.metrics_summary()
-    assert s["kernel_route"]["route"] == "pallas"
-    assert s["kernel_route"]["reasons"] == []
-    assert s["kernel_route"]["decode"] == "pallas"
-    assert s["kernel_route"]["window"] == "none"    # no mixed, no verify
-    assert s["counters"]["moe_pairs_held"] > 0
-    paged, window = eng.pool.bytes_by_kind()
-    assert (s["kv_global_bytes"], s["kv_window_bytes"]) == (paged, window)
-    assert window == (len(CFG.window_layers) * 2 * 3
-                      * xm.ring_pages(CFG, PSZ) * PSZ * CFG.kv_channels * 4)
-    assert eng.pool.alloc.pages_free == eng.pool.n_pages
-
-
-def test_launch_stats_tell_the_kinds_of_state_apart(params, kernel_on_cpu):
-    eng = Engine(params, CFG, ECFG)
-    window, swa_token, experts = eng._launch_extra
-    assert window == CFG.sliding_window
-    assert swa_token == len(CFG.window_layers) * 2 * CFG.kv_channels * 4
-    held = sum(int(np.prod(lp[n].shape)) * 4 for lp in params["layers"]
-               for n in ("e_gate", "e_up", "e_down") if n in lp)
-    assert experts == held
-    assert eng._kv_token_bytes == (len(CFG.paged_layers) * 2
-                                   * CFG.kv_channels * 4)
-
-
 # ---------------------------------------------------------------- 8. refusals
-
-class _Drafter:
-    name, k, pool_size = "stub", 2, 3
-
-
-@pytest.mark.parametrize("change,drafter,word", [
-    (dict(decode_window=4), None, "mixed"),
-    (dict(), _Drafter(), "speculative"),
-    (dict(prefix_cache=True), None, "prefix_cache"),
-    (dict(mesh_model=2), None, "mesh"),
-    (dict(kv_quant="int8"), None, "quantised"),
-    (dict(weight_quant="int8"), None, "quantised"),
-], ids=["mixed-window", "verify", "prefix-cache", "mesh", "kv-quant",
-        "weight-quant"])
-def test_engine_refuses_what_the_family_lacks(params, change, drafter, word):
-    ecfg = dataclasses.replace(ECFG, **change)
-    assert any(word in r for r in serve_refusals(CFG, ecfg, drafter))
-    with pytest.raises(ValueError, match=word):
-        Engine(params, CFG, ecfg, drafter=drafter)
-
-
-@pytest.mark.parametrize("name", ["mixed_window_paged", "verify_step_paged"])
-def test_programs_the_family_lacks_refuse_by_name(name):
-    with pytest.raises(NotImplementedError, match="exaone_moe"):
-        getattr(family(CFG), name)()
-
-
-def test_the_pool_refuses_the_radix_cache_over_window_layers():
-    with pytest.raises(ValueError, match="prefix_cache"):
-        PagedCachePool(CFG, 2, page_size=PSZ, prefix_cache=True)
-
 
 def test_the_decode_kernel_is_routed_and_a_quantised_pool_is_not(
         params, kernel_on_cpu):
@@ -594,7 +393,7 @@ def test_gpt2_pool_program_names_and_route_unchanged(kernel_on_cpu):
     assert eng.pool.cache["k"].shape == (cfg.n_layer, eng.pool.n_pages, 8,
                                          cfg.n_embd)
     assert eng.pool.pages is eng.pool.cache and eng._launch_extra is None
-    assert eng.pool.bytes_by_kind()[1] == 0
+    assert set(eng.pool.bytes_by_kind()) == {"pages"}
     r = eng.kernel_route.summary()
     assert (r["route"], r["window"], r["reasons"]) == ("pallas", "pallas",
                                                        [])
@@ -610,68 +409,6 @@ def test_gpt2_pool_program_names_and_route_unchanged(kernel_on_cpu):
 
 
 # ------------------------------------- more requests than slots, and kills
-
-def _drive(eng, reqs, hook=None):
-    for r in reqs:
-        assert eng.submit(r) is None
-    done, steps = {}, 0
-    while not eng.idle:
-        for r in eng.step():
-            done[r.id] = r
-        steps += 1
-        if hook is not None:
-            hook(eng, steps, done)
-        assert steps < 2000
-    return done
-
-
-def _requests(seed, sizes):
-    rng = np.random.default_rng(seed)
-    return [Request(id=f"q{i}", prompt=rng.integers(
-        0, CFG.vocab_size, (n,), dtype=np.int32), max_new_tokens=m,
-        sampling=SamplingParams(greedy=True))
-        for i, (n, m) in enumerate(sizes)]
-
-
-def test_a_reused_slot_emits_what_the_request_emits_alone(params,
-                                                          kernel_on_cpu):
-    """More requests than slots, so later ones take a slot, its pages and
-    its window rings after another request: token for token what each
-    emits alone in a fresh engine, and every page and slot comes back."""
-    sizes = [(5, 9), (17, 14), (30, 6), (9, 11), (24, 8), (3, 13), (12, 7)]
-    eng = Engine(params, CFG, ECFG)
-    got = _drive(eng, _requests(3, sizes))
-    assert all(got[f"q{i}"].ok and len(got[f"q{i}"].tokens) == m
-               for i, (_, m) in enumerate(sizes))
-    alone = Engine(params, CFG, ECFG)
-    for r in _requests(3, sizes)[3:]:          # those that waited for a slot
-        assert _drive(alone, [r])[r.id].tokens == got[r.id].tokens
-    assert eng.pool.alloc.pages_free == eng.pool.n_pages
-    assert eng.pool.n_free == ECFG.pool_size
-    assert eng.metrics.counters["decode_tokens"] == sum(
-        len(r.tokens) for r in got.values())
-
-
-def test_cancel_and_deadline_give_back_both_kinds_of_state(params,
-                                                           kernel_on_cpu):
-    clock = [0.0]
-    eng = Engine(params, CFG, ECFG, clock=lambda: clock[0])
-    reqs = _requests(4, [(6, 40), (11, 40), (20, 40)])
-    reqs[2] = dataclasses.replace(reqs[2], deadline=5.0)
-
-    def hook(e, n, done):
-        if n == 6:
-            assert e.cancel("q0")
-        if n == 10:
-            clock[0] = 10.0                    # q2's deadline passes
-
-    done = _drive(eng, reqs, hook)
-    assert done["q0"].finish_reason == "cancelled" and done["q0"].tokens
-    assert done["q2"].finish_reason == "deadline"
-    assert done["q1"].ok and len(done["q1"].tokens) == 40
-    assert eng.pool.alloc.pages_free == eng.pool.n_pages
-    assert eng.pool.n_free == ECFG.pool_size
-
 
 def test_the_route_asks_the_family_and_names_no_family():
     """``decide_kernel_route`` reads the windowed steps' fitness off

@@ -215,9 +215,9 @@ def test_blocked_walk_matches_gather_reference(walk_name, dtype, tol):
                                atol=tol, rtol=tol)
     scalars = pp._at_layer(walk, LAYER)
     assert len(scalars) == pp.N_WALK and scalars[-1].tolist() == [LAYER]
-    table, own, live, rank, nxt = (np.asarray(a) for a in walk)
-    own = own.astype(bool)
-    assert table.shape == own.shape == (len(pos), nb * P)
+    table, live, rank, nxt = (np.asarray(a) for a in walk)
+    own = table >= 0             # an unowned entry reads -1
+    assert table.shape == (len(pos), nb * P)
     assert (own[:, :mp] == np.asarray(owned)).all() and not own[:, mp:].any()
     assert (table[:, :mp][own[:, :mp]] == tables[own[:, :mp]]).all()
     # a step is live if its block owns a page; the live steps hand the
@@ -333,7 +333,7 @@ def test_launch_stats_count_the_blocks_the_device_mask_owns(kernel_backend):
         owned = pp.gqa_owned_pages(pos, jnp.zeros_like(pos), mp, psz, 0)
         _, _, walk = pp._blocked_walk(
             jnp.asarray(eng.pool.tables), owned, psz, cfg.n_embd * 4)
-        want.append(int(np.asarray(walk[2]).sum()))     # the live steps
+        want.append(int(np.asarray(walk[1]).sum()))     # the live steps
         return dispatch(k, kill, *a)
 
     eng._dispatch = spy

@@ -174,6 +174,70 @@ def test_paged_gqa_attention_kexaone_widths(mosaic, one_chip, window, name):
     assert re.search(rf"%{name}[\w.]* = [^\n]*tpu_custom_call", text)
 
 
+def test_paged_gqa_attention_lfm2_widths(mosaic, one_chip):
+    """The grouped-query kernel at LFM2-24B-A2B's published widths (32
+    query heads on 8 KV heads of 64: a block of 4 x 64 rows a KV head, a
+    pool row of 512 lanes) at the cell's 256 slots of 512 table entries
+    and 40,960 pages: 16,384 grid steps, and a walk whose scalars fit the
+    chip's scalar memory only because the table says which entries are
+    owned (a table AND a mask were 1.19 MB of its 1 MB)."""
+    from replicatinggpt_tpu.ops.paged_pallas import (block_pages,
+                                                     paged_gqa_attention)
+    B, psz, mp = 256, 16, 512
+    assert block_pages(psz, mp, 8 * 64 * 2) == 8
+    q = _s((B, 1, 32 * 64), BF16, one_chip)
+    kv = _s((B, 1, 8 * 64), BF16, one_chip)
+    pages = _s((1, 40_960, psz, 8 * 64), BF16, one_chip)
+    vec = _s((B,), jnp.int32, one_chip)
+    text = _compile(
+        lambda q, k, v, kp, vp, t, p: paged_gqa_attention(
+            q, k, v, kp, vp, t, p, n_head=32, n_kv_head=8, layer=0),
+        q, kv, kv, pages, pages, _s((B, mp), jnp.int32, one_chip), vec)
+    assert re.search(r"%paged_window_attention[\w.]* = [^\n]*tpu_custom_call",
+                     text)
+
+
+def test_lfm2_decode_step_carries_its_scopes(mosaic, one_chip):
+    """The lfm2_moe decode window (published widths, the first three
+    layers: conv conv full, dense dense sparse, 8 experts) compiled for
+    the described v5e on the Pallas route: the scopes the cell's readers
+    read (``short_conv``, ``moe_experts``) are on the ops, the full
+    layer's kernel keeps the accepted readers' name, and the conv state
+    is donated and written in place like the pages."""
+    import dataclasses
+    from replicatinggpt_tpu.models import lfm2_moe
+    from replicatinggpt_tpu.serve import engine
+    base = get_config("lfm2-24b-a2b").model
+    cfg = dataclasses.replace(
+        base, n_layer=3, layer_types=base.layer_types[:3],
+        mlp_layer_types=("dense", "dense", "sparse"), n_experts=8,
+        experts_held=tuple(range(8)), vocab_size=4096)
+    B, psz, mp = 16, 16, 512
+    shaped = lambda tree: jax.tree_util.tree_map(
+        lambda a: _s(a.shape, a.dtype, one_chip), tree)
+    params = shaped(jax.eval_shape(
+        lambda: lfm2_moe.init_params(jax.random.PRNGKey(0), cfg)))
+    cache = shaped(jax.eval_shape(
+        lambda: lfm2_moe.init_paged_kv_pool(cfg, 1024, psz, n_slots=B)))
+    vec = lambda dt: _s((B,), dt, one_chip)
+    text = engine._engine_decode_window.lower(
+        params, vec(jnp.int32), vec(jnp.int32), vec(jnp.bool_),
+        vec(jnp.int32), vec(jnp.int32), _s((5, B), jnp.int32, one_chip),
+        _s((B, mp), jnp.int32, one_chip), cache,
+        _s((B, 2), jnp.uint32, one_chip), vec(jnp.float32), vec(jnp.int32),
+        vec(jnp.float32), vec(jnp.bool_), cfg, k=1,
+        use_pallas=True).compile().as_text()
+    assert _kernel_names(text) and all(
+        n.startswith("paged_window_attention") for n in _kernel_names(text))
+    op_names = re.findall(r'op_name="([^"]*)"', text)
+    for scope in ("short_conv", "attn_global", "kv_scatter", "mlp",
+                  "moe_router", "moe_experts", "head", "embed", "sample"):
+        assert any(re.search(rf"(^|/){scope}(/|$)", n) for n in op_names), \
+            scope
+    assert not any(re.search(r"(^|/)(attn_swa|moe_shared)(/|$)", n)
+                   for n in op_names)
+
+
 def test_sharded_paged_window_attention_2x2(mosaic, mesh2x2):
     from replicatinggpt_tpu.ops.paged_pallas import (
         sharded_paged_window_attention)
@@ -356,8 +420,9 @@ def test_decode_step_hlo_carries_the_phase_scopes(mosaic, one_chip):
                          text)
     # what is left under ``kv_gather`` on this route is the kernel's walk
     # (``_blocked_walk``: integer work on the tables and positions), inside
-    # ``attn``; the kernel itself is not under it, or
-    # ``decode_kv_gather_ms`` would read the kernel's time
+    # ``attn``; the kernel itself is not under it, or a reader of the scope
+    # (``prefill_kv_gather_ms``; ``decode_kv_gather_ms`` until PR 36) would
+    # read the kernel's time
     under = [n for n in op_names if re.search(r"(^|/)kv_gather(/|$)", n)]
     assert under and all(re.search(r"(^|/)attn/kv_gather(/|$)", n)
                          for n in under), under
