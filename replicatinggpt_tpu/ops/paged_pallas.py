@@ -1,6 +1,6 @@
 """Paged decode attention: one Pallas kernel family whose scalar-
 prefetched page table streams ONLY a slot's mapped pages, a BLOCK of
-pages a grid step.
+pages a turn of the slot's own loop.
 
 The XLA paged decode path (models/gpt.py decode_step_paged) gathers
 every slot's full (max_pages, page, C) view each layer each step —
@@ -8,29 +8,39 @@ simple and parity-exact, but it fetches max_pages pages per slot
 regardless of how short the slot's sequence actually is. This kernel
 puts the page table in scalar-prefetch SMEM, leaves the WHOLE STACKED
 pool (layers, n_pages, page, C) in HBM and FETCHES FOR ITSELF by
-``(layer, page)``, so no layer of the pool is sliced out for it: a grid
-step covers ``P`` consecutive logical pages
-of a slot (``block_pages``: ``P * page`` >= 128 tokens, 8 pages of 16),
-grid (B, ceil(max_pages / P)), block minor, and copies the block's
-pages, scattered in the pool, into one half of a (2, P * page, C) VMEM
-double buffer (``_fetch_block``), so the body reads them as ONE tile:
-each head's pass runs once over the block's columns, not once a page.
-A grid step costs its turn whether or not it has work (0.12 us on a
-v5e with a page a step, 221k of them a gpt2-large launch; more for
-every operand the pipeline has to look at), so the walk is as short as
-the tile allows and only three small rows ride the pipeline. Logical
-pages past the slot's live frontier (or behind a window's lower bound,
-or on another shard) are NOT OWNED: they are not fetched and their
-columns are masked, a block with no owned page does nothing, and the
-live blocks send for one another's pages a step ahead, across slots
-(``_blocked_walk``), so a slot at position p streams ceil(p/page)
-pages, not max_pages. Accumulation is online softmax across block
-steps (f32 running max / denominator / accumulator in VMEM scratch);
-the fresh K/V window rides separately and folds in at the final block
-step, so the kernel attends the STALE pool bit-equivalently to
+``(layer, page)``, so no layer of the pool is sliced out for it. The
+grid is ``(B,)``: ONE TURN A SLOT. A slot's pages go in blocks of ``P``
+consecutive logical pages (``block_pages``: ``P * page`` >= 128 tokens,
+8 pages of 16), and the blocks are a LOOP IN THE BODY as long as the
+slot's own live blocks (``_walk_blocks``: one ``fori_loop`` of traced
+length over the list ``_blocked_walk`` compacts for the slot), each
+copied, scattered as its pages lie in the pool, into one half of a
+(2, P * page, C) VMEM double buffer, so the arithmetic reads it as ONE
+tile: each head's pass runs once over the block's columns, not once a
+page. Until PR 39 the block axis was the grid's second (B,
+ceil(max_pages / P)) and a grid step cost its turn whether or not it
+had work: 1.0-1.4 ms of a gpt2-large launch (3.5 ms at no live
+token for 2.3 now; PERF.md section 5) went to the two thirds of the
+steps past the slots' frontiers. Logical pages past the slot's live
+frontier (or behind a window's lower bound, or on another shard) are
+NOT OWNED: they are not fetched and their columns are masked, a block
+with no owned page is in no slot's list, and the live blocks send for
+one another's pages a block ahead, across slots, so a slot at position
+p streams ceil(p/page) pages, not max_pages, and an idle slot's turn
+is an init and a finalize. Accumulation is online softmax across the
+loop (f32 running max / denominator / accumulator in VMEM scratch);
+the fresh K/V window rides separately and folds in after the loop,
+so the kernel attends the STALE pool bit-equivalently to
 write-then-attend (cache[pos] would hold exactly the fresh k/v) — the
 caller scatters the fresh row afterwards, mirroring
 ops/decode_pallas.py's packed kernel.
+
+Every process start traces and lowers its programs, compile cache warm
+or not, so what the body costs to trace is paid in every cell's set-up:
+the body is emitted ONCE (no unrolled loop, no second copy of the block
+step for a first or last block), and each entry point runs under one
+inner ``jax.jit`` (``_window_call``, ``_gqa_call``), so a program whose
+layers are a Python loop holds a kernel once a layer KIND.
 
 Packed (page, C) layout only: heads are static lane slices of the
 fully-packed row (no D-minor tile padding in the stream), taken as
@@ -60,8 +70,8 @@ from .flash_pallas import (LANES, NEG_INF, _compiler_params,
 # of 1024 tokens (a block of one) 3 MiB.
 PAGED_DECODE_BYTES = 4 * 1024 * 1024
 
-# a grid step walks at least this many tokens of a slot (table and
-# budget allowing): the lane width of a score tile
+# a block holds at least this many tokens of a slot (table and budget
+# allowing): the lane width of a score tile
 BLOCK_TOKENS = LANES
 
 
@@ -185,7 +195,7 @@ def paged_decode_supported(n_head: int, head_dim: int, page_size: int,
 
 
 def block_pages(page_size: int, n_table: int, row_bytes: int) -> int:
-    """``P``: how many consecutive logical pages of a slot one grid step
+    """``P``: how many consecutive logical pages of a slot one block
     covers. Enough for ``BLOCK_TOKENS`` tokens, no more than the table
     has, and no more than keeps the block's K and V tiles (``row_bytes``
     a token each) inside ``PAGED_DECODE_BYTES``; 0 where one page alone
@@ -195,62 +205,71 @@ def block_pages(page_size: int, n_table: int, row_bytes: int) -> int:
     return min(want, n_table, fit)
 
 
+def _walk_shape(n_table: int, page_size: int, row_bytes: int) -> tuple:
+    """``(P, n_blocks)``: the pages a block covers and the blocks a
+    slot's table of ``n_table`` entries makes (the last may be short)."""
+    P = block_pages(page_size, n_table, row_bytes)
+    return P, -(-n_table // P)
+
+
 def live_blocks(pos, page_size: int, n_block: int):
     """Blocks of ``n_block`` pages that hold a position < ``pos``, a
-    slot: the grid steps of the unsharded walk that do work (numpy or
-    jnp; the engine's ``kv_blocks_live`` sums it over the live slots)."""
+    slot: the iterations of the unsharded walk's loop (numpy or jnp; the
+    engine's ``kv_blocks_live`` sums it over the live slots)."""
     return -(-pos // (page_size * n_block))
 
 
-# scalar-prefetch operands ``_fetch_block`` reads: ``_blocked_walk``'s four
-# and the pool's layer (``_at_layer``)
-N_WALK = 5
+# scalar-prefetch operands ``_walk_blocks`` reads: ``_blocked_walk``'s five
+# and the pool's layer
+N_WALK = 6
 
 
 @jax.named_scope("kv_gather")
 def _blocked_walk(tables: jnp.ndarray, owned: jnp.ndarray, page_size: int,
                   row_bytes: int) -> tuple:
     """The walk both kernels make, from a (B, max_pages) table and the
-    mask of the entries a slot's rows read: ``(P, n_blocks, scalars)``,
-    ``scalars`` the operands ``_fetch_block`` reads beside the layer.
-    What is left OUTSIDE the kernel of addressing the pool's pages,
-    hence the scope; the same for every layer of a step.
+    mask of the entries a slot's rows read: the scalars ``_walk_blocks``
+    reads beside the layer, ``(table, blocks, count, rank, next)``. What
+    is left OUTSIDE the kernel of addressing the pool's pages, hence the
+    scope; the same for every layer of a step.
 
-    Grid step ``t = b * n_blocks + p`` covers logical pages p*P .. p*P +
-    P - 1 of slot b. The table and the mask are padded to whole blocks
+    Block ``p`` of slot ``b`` covers its logical pages p*P .. p*P + P - 1
+    (``_walk_shape``). The table and the mask are padded to whole blocks
     with unowned entries (a length P does not divide ends in a short
     block), and the walk's table says which entries are owned: an unowned
     one reads -1 (ONE (B, n_blocks * P) array in scalar memory, not a
     table and a mask: at 256 slots of 512 pages the two were 1.05 MB of a
-    v5e's 1 MB). A step is LIVE if its block holds an owned page; the
-    live steps fetch for one another, so each carries its rank among
-    them (its parity picks the half of the double buffer) and the next
-    live step (-1: none), which may be another slot's."""
+    v5e's 1 MB). A block is LIVE if it holds an owned page. ``blocks[b * n_blocks + i]`` is
+    slot b's i-th live block, in rising order, for i < ``count[b]``: a
+    prefix of the table for plain decode, a range behind a window's lower
+    bound, any subset under a shard's mask. The live blocks fetch for one
+    another across slots, so a slot carries the ``rank`` of its first
+    live block among all of the call's (its parity picks the half of the
+    double buffer) and the ``next`` slot that has one (-1: none)."""
     B, mp = tables.shape
-    P = block_pages(page_size, mp, row_bytes)
-    nb = -(-mp // P)
+    P, nb = _walk_shape(mp, page_size, row_bytes)
     pad = ((0, 0), (0, nb * P - mp))
     owned = jnp.pad(owned, pad)
     table = jnp.where(owned, jnp.pad(jnp.asarray(tables, jnp.int32), pad), -1)
-    live = owned.reshape(B * nb, P).any(axis=1)
-    step = jnp.arange(B * nb, dtype=jnp.int32)
-    later = jax.lax.cummin(jnp.where(live, step, B * nb), reverse=True)
-    nxt = jnp.concatenate([later[1:], jnp.full((1,), B * nb, jnp.int32)])
-    return P, nb, (table, live.astype(jnp.int32),
-                   jnp.cumsum(live, dtype=jnp.int32) - 1,
-                   jnp.where(nxt < B * nb, nxt, -1))
-
-
-def _at_layer(scalars: tuple, layer) -> tuple:
-    """A walk's scalars and the layer of the stacked pool it reads:
-    ``_fetch_block``'s ``N_WALK`` operands."""
-    return (*scalars, jnp.asarray(layer, jnp.int32).reshape(1))
+    live = owned.reshape(B, nb, P).any(axis=2)
+    # a slot's i-th live block is the first with i + 1 live blocks up to
+    # and with it: as many blocks as have i or fewer (no sort)
+    upto = jnp.cumsum(live, axis=1, dtype=jnp.int32)
+    blocks = jnp.sum(upto[:, None, :] <= jnp.arange(nb)[None, :, None],
+                     axis=2, dtype=jnp.int32)
+    count = upto[:, -1]
+    slot = jnp.arange(B, dtype=jnp.int32)
+    later = jax.lax.cummin(jnp.where(count > 0, slot, B), reverse=True)
+    nxt = jnp.concatenate([later[1:], jnp.full((1,), B, jnp.int32)])
+    return (table, blocks.reshape(B * nb), count,
+            jnp.cumsum(count, dtype=jnp.int32) - count,
+            jnp.where(nxt < B, nxt, -1))
 
 
 def window_walk(tables: jnp.ndarray, pos: jnp.ndarray, page_size: int,
                 row_bytes: int, owned=None) -> tuple:
     """``paged_window_attention``'s walk over ``tables`` at positions
-    ``pos`` (``_blocked_walk``'s triple), of pages ``row_bytes`` a token
+    ``pos`` (``_blocked_walk``'s scalars), of pages ``row_bytes`` a token
     wide. It depends on no layer: a caller whose layers run in one scan
     builds it ONCE a step outside the scan and hands it to every
     layer's call (``walk=``), where XLA would rebuild it a layer.
@@ -272,42 +291,43 @@ def _heads_per_slab(n_head: int, head_dim: int) -> int:
 
 # -- what a block step does, shared by both kernels --------------------------
 
-def _fetch_block(walk, pools, bufs, sem, n_block: int, n_blocks: int,
-                 page_size: int):
-    """This grid step's block, fetched by the kernel itself: ``(live,
-    half, cols)``.
+def _walk_blocks(walk, pools, bufs, sem, n_block: int, n_blocks: int,
+                 page_size: int, step) -> None:
+    """This grid turn's slot: ONE loop over the slot's own live blocks,
+    each fetched by the kernel itself and handed to ``step(p, half,
+    cols)`` (block ``p`` of the slot's table, in half ``half`` of the
+    buffers).
 
     ``pools`` are the pool's stacked (layers, n_pages, page, width)
     arrays left in HBM, addressed in place by ``(layer, page)``; ``bufs``
-    their (2, P * page, width) VMEM halves. A live step finds its owned
-    pages already on their way into half ``rank % 2`` (the live step
-    before it sent for them; the first sends for its own), sends for the
-    NEXT live step's into the other half, then waits for its own: the
-    copies of block t + 1 run under the arithmetic of block t, across
-    slots.
+    their (2, P * page, width) VMEM halves. A block finds its owned
+    pages already on their way into half ``rank % 2`` (the live block
+    before it sent for them; the call's first sends for its own), sends
+    for the NEXT live block's into the other half, then waits for its
+    own: the copies of block t + 1 run under the arithmetic of block t.
+    A slot's last block sends for the first block of the next slot that
+    has one, so the buffers do not drain at a slot's edge.
     Unowned pages are not fetched, and ``cols()`` masks their columns:
     what a half holds there is an older page or the zeros of the
-    call's first step. A step that is not live reads three scalars
-    here and copies nothing."""
-    table_ref, live_ref, rank_ref, next_ref, layer_ref = walk
+    call's first turn. A slot with no live block reads its count here
+    and copies nothing."""
+    table_ref, blocks_ref, count_ref, rank_ref, next_ref, layer_ref = walk
     P, psz = n_block, page_size
-    b0, p0 = pl.program_id(0), pl.program_id(1)
-    t = b0 * n_blocks + p0
+    b = pl.program_id(0)
+    n = count_ref[b]
 
-    @pl.when(t == 0)
+    @pl.when(b == 0)
     def _finite():
         for buf in bufs:
             buf[...] = jnp.zeros_like(buf)
 
-    def copies(step, half, act):
-        b, p = step // n_blocks, step % n_blocks
-
+    def copies(slot, p, half, act):
         def page(j, _):
-            @pl.when(table_ref[b, p * P + j] >= 0)
+            @pl.when(table_ref[slot, p * P + j] >= 0)
             def _owned():
                 for a, (pool, buf) in enumerate(zip(pools, bufs)):
                     act(pltpu.make_async_copy(
-                        pool.at[layer_ref[0], table_ref[b, p * P + j]],
+                        pool.at[layer_ref[0], table_ref[slot, p * P + j]],
                         buf.at[half, pl.ds(pl.multiple_of(j * psz, psz),
                                            psz)],
                         sem.at[half, a]))
@@ -316,29 +336,36 @@ def _fetch_block(walk, pools, bufs, sem, n_block: int, n_blocks: int,
         # lowered at every process start, and these are most of its size
         jax.lax.fori_loop(0, P, page, None)
 
-    live = live_ref[t] > 0
-    half = rank_ref[t] % 2
+    def block(i, _):
+        p = blocks_ref[b * n_blocks + i]
+        rank = rank_ref[b] + i
+        half = rank % 2
 
-    @pl.when(live)
-    def _fetch():
-        @pl.when(rank_ref[t] == 0)
+        @pl.when(rank == 0)
         def _first():
-            copies(t, half, lambda c: c.start())
+            copies(b, p, half, lambda c: c.start())
 
-        @pl.when(next_ref[t] >= 0)
+        last = i + 1 == n
+        ahead = jnp.where(last, next_ref[b], b)
+
+        @pl.when(ahead >= 0)
         def _ahead():
-            copies(next_ref[t], 1 - half, lambda c: c.start())
+            copies(ahead,
+                   blocks_ref[ahead * n_blocks + jnp.where(last, 0, i + 1)],
+                   1 - half, lambda c: c.start())
 
-        copies(t, half, lambda c: c.wait())
+        copies(b, p, half, lambda c: c.wait())
 
-    def cols():
-        page = jax.lax.broadcasted_iota(jnp.int32, (1, P * psz), 1) // psz
-        own = jnp.zeros_like(page)
-        for j in range(P):
-            own = jnp.where(page == j, table_ref[b0, p0 * P + j] + 1, own)
-        return own > 0
+        def cols():
+            page = jax.lax.broadcasted_iota(jnp.int32, (1, P * psz), 1) // psz
+            own = jnp.zeros_like(page)
+            for j in range(P):
+                own = jnp.where(page == j, table_ref[b, p * P + j] + 1, own)
+            return own > 0
 
-    return live, half, cols
+        step(p, half, cols)
+
+    jax.lax.fori_loop(0, n, block, None)
 
 
 def _scores(q, k, mask, scale):
@@ -378,8 +405,9 @@ def _paged_window_kernel(*refs, n_head, head_dim, page_size, n_block,
 
     W = ``window`` query rows per slot (W=1 is plain decode; W>1 is the
     mixed prefill+decode / speculative-verify step, where row j sits at
-    logical position pos+j). A grid step holds a BLOCK of ``n_block``
-    stale pool pages as one (n_block * page, C) tile (``_fetch_block``).
+    logical position pos+j). A grid turn is a slot, and a turn of its loop
+    holds a BLOCK of ``n_block`` stale pool pages as one (n_block * page,
+    C) tile (``_walk_blocks``).
     Blocks accumulate online-softmax gated on the scalar-prefetched
     OWNED mask (per-slot page prefix unsharded; an arbitrary owned
     subset under the shard_map wrapper), their columns masked page by
@@ -390,13 +418,14 @@ def _paged_window_kernel(*refs, n_head, head_dim, page_size, n_block,
     rows zero outside its own lanes, so ONE product scores the slab's
     heads over the block's columns and one more weighs its values; of a
     row's accumulator only its own head's lanes are ever read.
-    Quantized pools bring the block's (P * page, 1) page-granularity or
-    (P * page, H) head-granularity scales as one more row operand; the
-    per-head column dequants the tile before the product (int8 AND fp8
+    Quantized pools bring the slot's (table entries * page, 1)
+    page-granularity or (.., H) head-granularity scales as one more row
+    operand, cut by block inside the loop; the per-head column dequants
+    the tile before the product (int8 AND fp8
     — the e4m3 block ``astype``s to f32 like any other storage dtype).
 
-    ``fold=True`` folds the fresh causal (W, W) block at the last block
-    step and writes normalized output; ``fold=False`` emits the raw
+    ``fold=True`` folds the fresh causal (W, W) block after the loop and
+    writes normalized output; ``fold=False`` emits the raw
     (acc, m, l) partials instead — the shard_map wrapper merges them
     across the 'data' axis (pmax/psum softmax merge) and folds the
     fresh window outside, where the collective lives."""
@@ -408,7 +437,6 @@ def _paged_window_kernel(*refs, n_head, head_dim, page_size, n_block,
     *outs, qs_ref, acc_ref, m_ref, l_ref, k_buf, v_buf, sem = refs
     pools, bufs = (k_hbm, v_hbm), (k_buf, v_buf)
     b = pl.program_id(0)
-    p = pl.program_id(1)
     D, psz, W, P = head_dim, page_size, window, n_block
     hps = _heads_per_slab(n_head, D)
     SL = hps * D                              # lanes of a slab
@@ -421,28 +449,22 @@ def _paged_window_kernel(*refs, n_head, head_dim, page_size, n_block,
     def lane_head(rows):                      # the head a lane belongs to
         return jax.lax.broadcasted_iota(jnp.int32, (rows, SL), 1) // D
 
-    @pl.when(p == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        for i, sl in enumerate(slabs):
-            q = q_ref[:, sl].astype(jnp.float32)                 # (W, SL)
-            for r in range(hps):
-                qs_ref[i, r * W:(r + 1) * W, :] = (
-                    q if hps == 1 else
-                    jnp.where(lane_head(W) == r, q, 0.0))
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    for i, sl in enumerate(slabs):
+        q = q_ref[:, sl].astype(jnp.float32)                     # (W, SL)
+        for r in range(hps):
+            qs_ref[i, r * W:(r + 1) * W, :] = (
+                q if hps == 1 else jnp.where(lane_head(W) == r, q, 0.0))
 
-    live, half, owned_cols = _fetch_block(walk, pools, bufs, sem, P,
-                                          n_blocks, psz)
-
-    @pl.when(live)
-    def _accumulate():
+    def _accumulate(p, half, owned_cols):
         kpos = (jax.lax.broadcasted_iota(jnp.int32, (1, P * psz), 1)
                 + p * (P * psz))
         mask = owned_cols() & (kpos < pos)                   # (1, P * psz)
-        if quantized:                    # (P * psz, 1) page / (P * psz, H)
-            ksc, vsc = ksc_ref[...], vsc_ref[...]
+        if quantized:   # the block's rows of the slot's: (P * psz, 1 or H)
+            rows = pl.ds(pl.multiple_of(p * (P * psz), P * psz), P * psz)
+            ksc, vsc = ksc_ref[rows, :], vsc_ref[rows, :]
         for i, sl in enumerate(slabs):
             k, v = bufs[0][half, :, sl], bufs[1][half, :, sl]
             if quantized:
@@ -459,31 +481,30 @@ def _paged_window_kernel(*refs, n_head, head_dim, page_size, n_block,
             s = _scores(qs_ref[i].astype(q_dtype), k, mask, scale)
             _online_update(s, v, acc_ref, m_ref, l_ref, i)
 
-    @pl.when(p == n_blocks - 1)
-    def _finalize():
-        R = hps * W
-        row_j = jax.lax.broadcasted_iota(jnp.int32, (R, W), 0) % W
-        col = jax.lax.broadcasted_iota(jnp.int32, (R, W), 1)
-        causal = col <= row_j          # fresh row j attends rows 0..j
-        for i, sl in enumerate(slabs):
+    _walk_blocks(walk, pools, bufs, sem, P, n_blocks, psz, _accumulate)
+
+    R = hps * W
+    row_j = jax.lax.broadcasted_iota(jnp.int32, (R, W), 0) % W
+    col = jax.lax.broadcasted_iota(jnp.int32, (R, W), 1)
+    causal = col <= row_j              # fresh row j attends rows 0..j
+    for i, sl in enumerate(slabs):
+        if fold:
+            # denominator >= the diagonal term > 0 always (row j
+            # attends itself)
+            s_new = _scores(qs_ref[i], knew_ref[:, sl], causal, scale)
+            _online_update(s_new, vnew_ref[:, sl], acc_ref, m_ref, l_ref, i)
+        for r in range(hps):           # a head's rows, its own lanes
+            h = i * hps + r
+            rows, lanes = slice(r * W, (r + 1) * W), slice(h * D,
+                                                           (h + 1) * D)
+            acc = acc_ref[i, rows, r * D:(r + 1) * D]
             if fold:
-                # denominator >= the diagonal term > 0 always (row j
-                # attends itself)
-                s_new = _scores(qs_ref[i], knew_ref[:, sl], causal, scale)
-                _online_update(s_new, vnew_ref[:, sl], acc_ref, m_ref,
-                               l_ref, i)
-            for r in range(hps):       # a head's rows, its own lanes
-                h = i * hps + r
-                rows, lanes = slice(r * W, (r + 1) * W), slice(h * D,
-                                                               (h + 1) * D)
-                acc = acc_ref[i, rows, r * D:(r + 1) * D]
-                if fold:
-                    outs[0][:, lanes] = (acc / l_ref[i, rows, :1]).astype(
-                        outs[0].dtype)
-                else:
-                    outs[0][:, lanes] = acc
-                    outs[1][:, h:h + 1] = m_ref[i, rows, h:h + 1]
-                    outs[2][:, h:h + 1] = l_ref[i, rows, h:h + 1]
+                outs[0][:, lanes] = (acc / l_ref[i, rows, :1]).astype(
+                    outs[0].dtype)
+            else:
+                outs[0][:, lanes] = acc
+                outs[1][:, h:h + 1] = m_ref[i, rows, h:h + 1]
+                outs[2][:, h:h + 1] = l_ref[i, rows, h:h + 1]
 
 
 def _pool_operands(arrays, n_block: int) -> tuple:
@@ -535,21 +556,42 @@ def paged_window_attention(q: jnp.ndarray, k_new: jnp.ndarray,
     position < pos. ``walk`` is ``window_walk`` of the same tables,
     positions and pool, built by a caller that runs many layers on it;
     unset, it is built here."""
+    return _window_call(q, k_new, v_new, k_pages, v_pages, tables, pos,
+                        layer, k_scales, v_scales, owned, walk,
+                        n_head=n_head, fold=fold,
+                        interpret=_interpret_mode())
+
+
+# Both kernels run under ONE inner ``jax.jit`` each, static in what makes
+# a kernel a different kernel (head counts, ``attn_window``, ``name``; the
+# shapes are the trace cache's own key) and traced in ``layer``: a
+# program whose layers are a Python loop traces and lowers a kernel once
+# a layer KIND, and every further layer of the kind is a call of the one
+# private function (XLA inlines it: the compiled program is the same).
+# ``interpret`` is static so that a trace never outlives the mode it was
+# made in.
+
+@functools.partial(jax.jit, static_argnames=("n_head", "fold", "interpret"))
+def _window_call(q, k_new, v_new, k_pages, v_pages, tables, pos, layer,
+                 k_scales, v_scales, owned, walk, *, n_head, fold,
+                 interpret):
     _, _, psz, C = k_pages.shape
     B, W, _ = q.shape
     D = C // n_head
     quantized = k_scales is not None
     head_gran = quantized and k_scales.ndim == 4
     pos = jnp.asarray(pos, jnp.int32)
-    P, nb, walk = walk or window_walk(
-        tables, pos, psz, C * k_pages.dtype.itemsize, owned)
-    walk = _at_layer(walk, layer)
+    layer = jnp.asarray(layer, jnp.int32)
+    row_bytes = C * k_pages.dtype.itemsize
+    P, nb = _walk_shape(tables.shape[1], psz, row_bytes)
+    if walk is None:
+        walk = window_walk(tables, pos, psz, row_bytes, owned)
     kernel = functools.partial(
         _paged_window_kernel, n_head=n_head, head_dim=D, page_size=psz,
         n_block=P, n_blocks=nb, window=W, scale=D ** -0.5,
         quantized=quantized, head_gran=head_gran, fold=fold)
 
-    def row_map(b, p, *_):
+    def row_map(b, *_):
         return (b, 0, 0)
 
     row = _vmem_spec((None, W, C), row_map)
@@ -557,14 +599,13 @@ def paged_window_attention(q: jnp.ndarray, k_new: jnp.ndarray,
     if quantized:
         # a pool's scales are a 64th of its bytes or less: XLA gathers
         # each slot's by (layer, page) in the order of its (padded)
-        # table, and a block's arrive as one (P * page, width) tile like
-        # the rows
+        # table, and they arrive as ONE (table entries * page, width) row
+        # operand a slot, which the loop cuts by block
         swidth = n_head if head_gran else 1
-        in_specs += [_vmem_spec((None, None, P * psz, swidth),
-                                lambda b, p, *_: (b, p, 0, 0))] * 2
+        in_specs += [_vmem_spec((None, nb * P * psz, swidth), row_map)] * 2
         with jax.named_scope("kv_gather"):
             inputs += [sc[layer, jnp.maximum(walk[0], 0)].reshape(
-                B, nb, P * psz, swidth) for sc in (k_scales, v_scales)]
+                B, nb * P * psz, swidth) for sc in (k_scales, v_scales)]
     pool_specs, pool_scratch = _pool_operands([k_pages, v_pages], P)
     hps = _heads_per_slab(n_head, D)
     state = (n_head // hps, hps * W)
@@ -582,18 +623,17 @@ def paged_window_attention(q: jnp.ndarray, k_new: jnp.ndarray,
                      jax.ShapeDtypeStruct((B, W, LANES), jnp.float32),
                      jax.ShapeDtypeStruct((B, W, LANES), jnp.float32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(walk) + 1,
-        grid=(B, nb),
+        num_scalar_prefetch=N_WALK + 1,
+        grid=(B,),
         in_specs=in_specs + pool_specs,
         out_specs=out_specs,
         scratch_shapes=scratch + pool_scratch,
     )
     return pl.pallas_call(
         kernel, grid_spec=grid_spec, out_shape=out_shape,
-        name="paged_window_attention",
-        interpret=_interpret_mode(),
-        compiler_params=_compiler_params(0, 2),
-    )(*walk, pos, *inputs, k_pages, v_pages)
+        name="paged_window_attention", interpret=interpret,
+        compiler_params=_compiler_params(0, 1),
+    )(*walk, layer.reshape(1), pos, *inputs, k_pages, v_pages)
 
 
 def paged_decode_attention(q: jnp.ndarray, k_new: jnp.ndarray,
@@ -749,7 +789,6 @@ def _paged_gqa_kernel(*refs, n_kv_head, head_dim, page_size, n_block,
      acc_ref, m_ref, l_ref, k_buf, v_buf, sem) = refs[N_WALK:]
     pools, bufs = (k_hbm, v_hbm), (k_buf, v_buf)
     b = pl.program_id(0)
-    p = pl.program_id(1)
     D, psz, W, P = head_dim, page_size, window, n_block
     GW = q_ref.shape[1]                       # G * W rows a KV head
     pos = pos_ref[b]
@@ -757,17 +796,11 @@ def _paged_gqa_kernel(*refs, n_kv_head, head_dim, page_size, n_block,
     # row r of a group's block is query row j = r % W of the window
     row_j = jax.lax.broadcasted_iota(jnp.int32, (GW, 1), 0) % W
 
-    @pl.when(p == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
 
-    live, half, owned_cols = _fetch_block(walk, pools, bufs, sem, P,
-                                          n_blocks, psz)
-
-    @pl.when(live)
-    def _accumulate():
+    def _accumulate(p, half, owned_cols):
         kpos = (jax.lax.broadcasted_iota(jnp.int32, (1, P * psz), 1)
                 + (page0_ref[b] + p * P) * psz)
         mask = owned_cols() & (kpos < pos)                   # (1, P * psz)
@@ -778,20 +811,19 @@ def _paged_gqa_kernel(*refs, n_kv_head, head_dim, page_size, n_block,
             _online_update(s, bufs[1][half, :, sl], acc_ref, m_ref, l_ref,
                            g)
 
-    @pl.when(p == n_blocks - 1)
-    def _finalize():
-        col = jax.lax.broadcasted_iota(jnp.int32, (GW, W), 1)
-        fresh = col <= row_j           # row j attends fresh rows 0..j
-        if attn_window:
-            fresh = fresh & (col > row_j - attn_window)
-        for g, sl in enumerate(heads):
-            # denominator >= the diagonal term > 0 always (row j
-            # attends itself)
-            s_new = _scores(q_ref[g].astype(jnp.float32), knew_ref[:, sl],
-                            fresh, scale)
-            _online_update(s_new, vnew_ref[:, sl], acc_ref, m_ref, l_ref, g)
-            out_ref[g] = (acc_ref[g] / l_ref[g][:, :1]).astype(
-                out_ref.dtype)
+    _walk_blocks(walk, pools, bufs, sem, P, n_blocks, psz, _accumulate)
+
+    col = jax.lax.broadcasted_iota(jnp.int32, (GW, W), 1)
+    fresh = col <= row_j               # row j attends fresh rows 0..j
+    if attn_window:
+        fresh = fresh & (col > row_j - attn_window)
+    for g, sl in enumerate(heads):
+        # denominator >= the diagonal term > 0 always (row j attends
+        # itself)
+        s_new = _scores(q_ref[g].astype(jnp.float32), knew_ref[:, sl],
+                        fresh, scale)
+        _online_update(s_new, vnew_ref[:, sl], acc_ref, m_ref, l_ref, g)
+        out_ref[g] = (acc_ref[g] / l_ref[g][:, :1]).astype(out_ref.dtype)
 
 
 @jax.named_scope("kv_gather")
@@ -827,6 +859,16 @@ def paged_gqa_attention(q: jnp.ndarray, k_new: jnp.ndarray,
     the first page of its walk. ``name`` is the kernel's name in the
     HLO and the trace: full layers keep ``paged_window_attention``, a
     window layer's call says ``swa_...``."""
+    return _gqa_call(q, k_new, v_new, k_pages, v_pages, tables, pos, layer,
+                     page0, n_head=n_head, n_kv_head=n_kv_head,
+                     attn_window=int(attn_window), name=name,
+                     interpret=_interpret_mode())
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "n_head", "n_kv_head", "attn_window", "name", "interpret"))
+def _gqa_call(q, k_new, v_new, k_pages, v_pages, tables, pos, layer, page0,
+              *, n_head, n_kv_head, attn_window, name, interpret):
     _, _, psz, Ckv = k_pages.shape
     B, W, Cq = q.shape
     D = Ckv // n_kv_head
@@ -834,25 +876,27 @@ def paged_gqa_attention(q: jnp.ndarray, k_new: jnp.ndarray,
     assert Cq == n_head * D and n_head == G * n_kv_head, (q.shape,
                                                           k_pages.shape)
     pos = jnp.asarray(pos, jnp.int32)
+    layer = jnp.asarray(layer, jnp.int32)
     page0 = (jnp.zeros((B,), jnp.int32) if page0 is None
              else jnp.asarray(page0, jnp.int32))
-    P, nb, walk = _blocked_walk(
+    row_bytes = Ckv * k_pages.dtype.itemsize
+    P, nb = _walk_shape(tables.shape[1], psz, row_bytes)
+    walk = _blocked_walk(
         tables, gqa_owned_pages(pos, page0, tables.shape[1], psz,
                                 attn_window),
-        psz, Ckv * k_pages.dtype.itemsize)
-    walk = _at_layer(walk, layer)
+        psz, row_bytes)
     # a KV head's G query heads as G*W rows of one block
     qg = (q.reshape(B, W, n_kv_head, G, D).transpose(0, 2, 3, 1, 4)
           .reshape(B, n_kv_head, G * W, D))
     kernel = functools.partial(
         _paged_gqa_kernel, n_kv_head=n_kv_head, head_dim=D, page_size=psz,
-        n_block=P, n_blocks=nb, window=W, attn_window=int(attn_window),
+        n_block=P, n_blocks=nb, window=W, attn_window=attn_window,
         scale=D ** -0.5)
 
-    def q_map(b, p, *_):
+    def q_map(b, *_):
         return (b, 0, 0, 0)
 
-    def row_map(b, p, *_):
+    def row_map(b, *_):
         return (b, 0, 0)
 
     qspec = _vmem_spec((None, n_kv_head, G * W, D), q_map)
@@ -860,8 +904,8 @@ def paged_gqa_attention(q: jnp.ndarray, k_new: jnp.ndarray,
     pool_specs, pool_scratch = _pool_operands([k_pages, v_pages], P)
     state = (n_kv_head, G * W)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(walk) + 2,
-        grid=(B, nb),
+        num_scalar_prefetch=N_WALK + 2,
+        grid=(B,),
         in_specs=[qspec, row, row, *pool_specs],
         out_specs=qspec,
         scratch_shapes=[pltpu.VMEM((*state, D), jnp.float32),
@@ -872,8 +916,9 @@ def paged_gqa_attention(q: jnp.ndarray, k_new: jnp.ndarray,
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, n_kv_head, G * W, D), q.dtype),
-        name=name, interpret=_interpret_mode(),
-        compiler_params=_compiler_params(0, 2),
-    )(*walk, pos, page0, qg, k_new, v_new, k_pages, v_pages)
+        name=name, interpret=interpret,
+        compiler_params=_compiler_params(0, 1),
+    )(*walk, layer.reshape(1), pos, page0, qg, k_new, v_new, k_pages,
+      v_pages)
     return (out.reshape(B, n_kv_head, G, W, D).transpose(0, 3, 1, 2, 4)
             .reshape(B, W, Cq))

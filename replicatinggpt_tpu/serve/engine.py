@@ -912,8 +912,8 @@ class Engine:
                            1.0 if self.kernel_route.route == "pallas"
                            else 0.0)
         # the per-layer kernel's walk over the pool's tables, for the
-        # stats of ``serve/launch``: pages a grid step covers (0: the
-        # decode step runs no such kernel)
+        # stats of ``serve/launch``: pages a block of its loop covers (0:
+        # the decode step runs no such kernel)
         self._kv_block_pages = 0
         if self._use_pallas:
             from ..ops.paged_pallas import block_pages
@@ -1949,19 +1949,19 @@ class Engine:
     def _kv_walk_stats(self, live: np.ndarray) -> dict:
         """How the paged kernel's walk engages in the launch being
         built, from the host mirrors: ``kv_block_pages`` (P, the pages a
-        grid step covers), ``kv_blocks_live`` (grid steps with work: the
-        sum over the live slots of the blocks that hold a position under
-        the slot's, as the kernel's owned mask will have it on the
-        device; one step of one pool layer) and ``kv_blocks_grid``
-        (slots x blocks a table: every step the grid takes there)."""
+        block of the kernel's loop covers), ``kv_blocks_live`` (loop
+        iterations with work: the sum over the live slots of the blocks
+        that hold a position under the slot's, as the kernel's owned mask
+        will have it on the device; one step of one pool layer) and
+        ``kv_blocks_grid`` (the turns the kernel's grid takes there: one
+        a slot, so live over grid reads blocks a turn)."""
         from ..ops.paged_pallas import live_blocks
         P = self._kv_block_pages
         return dict(
             kv_block_pages=P,
             kv_blocks_live=int(live_blocks(
                 self._pos[live], self.pool.page_size, P).sum()),
-            kv_blocks_grid=self.ecfg.pool_size * -(-self.pool.max_pages
-                                                   // P))
+            kv_blocks_grid=self.ecfg.pool_size)
 
     def _family_launch_stats(self) -> Optional[dict]:
         """What a family's state beside the pages and its experts add to

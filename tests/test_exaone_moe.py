@@ -258,8 +258,17 @@ def _einsum_attention(q, kn, vn, kp, vp, tables, pos, Hq, Hkv, window,
     (1, 0, [0, 129, 159], 20),
     (1, 12, [3, 133, 150], 20),  # the band's lower bound inside a block,
     (2, 12, [1, 128, 140], 20),  # and across the two blocks' edge
+    # a slot's loop is as long as its own live blocks: none (idle), one
+    # live slot between two idle ones (the fetch-ahead crosses them),
+    # every block of every slot, and a window whose only live block is the
+    # table's SECOND (the loop starts behind the lower bound)
+    (1, 0, [0, 159, 0], 20),
+    (8, 0, [160, 160, 160], 20),
+    (1, 12, [0, 150, 0], 20),
 ], ids=["full", "window-1-page", "window-1.5-pages", "two-rows",
-        "blocks-full", "blocks-window", "blocks-two-rows"])
+        "blocks-full", "blocks-window", "blocks-two-rows",
+        "blocks-live-between-idle", "blocks-all-live-w8",
+        "blocks-window-second-block-only"])
 def test_gqa_kernel_and_its_lower_bound_against_einsum(W, window, pos, mp):
     Hq, Hkv, D, psz = 4, 2, 32, 8
     assert (paged_pallas.block_pages(psz, mp, Hkv * D * 4) < mp) == (mp > 16)
@@ -282,13 +291,16 @@ def test_gqa_kernel_and_its_lower_bound_against_einsum(W, window, pos, mp):
     # the cell's storage: bf16 K goes to the MXU as it is; what is left
     # is the output's own rounding
     (16, 9, 128, [300, 140, 1000], "bfloat16", 1.6e-2),
-], ids=["ring-of-3", "ring-of-9-in-two-blocks", "ring-of-9-bf16"])
+    # idle slots around a live one: their loops take no turn
+    (16, 9, 128, [0, 1000, 0, 0, 140], "float32", 1e-5),
+], ids=["ring-of-3", "ring-of-9-in-two-blocks", "ring-of-9-bf16",
+        "ring-of-9-live-between-idle"])
 def test_gqa_kernel_walks_a_ring_from_page0(psz, mp, window, pos, dtype,
                                             tol):
     """A window layer's ring: the table's first entry is absolute page
     ``page0`` of the slot, not page 0."""
     Hq, Hkv, D = 4, 2, 32
-    page0 = [(p - window + 1) // psz for p in pos]
+    page0 = [max(p - window + 1, 0) // psz for p in pos]
     *rows, tables, pos = _kernel_case(4, len(pos), 1, Hq, Hkv, D, psz, mp,
                                       pos, window)
     rows = [a.astype(dtype) for a in rows]

@@ -143,16 +143,22 @@ def _window_ref(q, kn, vn, kp, vp, tables, pos, n_head):
 
 
 # geometries of the walk: (W, page, table length, positions). "one-block"
-# is a table the kernel covers in ONE grid step a slot; the others make it
-# walk blocks of P pages (``paged_pallas.block_pages``: 8 pages of 16, 16
-# of 8), with the frontier in the middle of a block, on a block's first
-# and last position, an idle slot, and a table P does not divide (9, 20)
+# is a table the kernel covers in ONE block a slot; the others make a
+# slot's loop walk blocks of P pages (``paged_pallas.block_pages``: 8
+# pages of 16, 16 of 8), with the frontier in the middle of a block, on a
+# block's first and last position, an idle slot (no turn of the loop), a
+# table P does not divide (9, 20), slots whose every block is live, and
+# live slots between idle ones: the call's first live block is not slot
+# 0's, and the fetch-ahead crosses one idle slot and two
 WALKS = {
     "one-block": (4, 8, 4, [17, 9, 0]),
     "mid-block": (1, 16, 24, [200, 70, 0]),
     "block-edges": (1, 16, 24, [128, 129, 255, 256]),
     "table-of-9": (1, 16, 9, [143, 130, 127, 5]),
     "w8-short-last-block": (8, 8, 20, [150, 128, 3, 0]),
+    "every-block-live": (1, 16, 24, [384, 383]),
+    "live-between-idle": (1, 16, 24, [0, 300, 0, 0, 40, 0]),
+    "w8-live-between-idle": (8, 8, 20, [0, 0, 152, 0, 9]),
 }
 
 
@@ -181,9 +187,11 @@ def test_blocked_walk_matches_gather_reference(walk_name, dtype, tol):
     """The walk in blocks of pages against the XLA-free gather
     reference: every geometry of ``WALKS``, W = 1 and W = 8, a float32
     pool and a bf16 one (the cells': K goes to the MXU as stored; the
-    output's own rounding is the tolerance); and the scalars the kernel fetches by: a whole
-    number of blocks a slot, the owned mask padded with unowned entries,
-    each live step's rank and successor."""
+    output's own rounding is the tolerance); and the scalars the kernel
+    fetches by: a whole number of blocks a slot, the owned mask padded with
+    unowned entries, each slot's own live blocks in rising order with
+    their count, the rank of its first among the call's and the next
+    slot that has one."""
     from replicatinggpt_tpu.ops import paged_pallas as pp
     q, kn, vn, kp, vp, tables, pos = _window_inputs(5, walk_name)
     q, kn, vn, kp, vp = (np.asarray(jnp.asarray(a, dtype), np.float32)
@@ -201,44 +209,54 @@ def test_blocked_walk_matches_gather_reference(walk_name, dtype, tol):
     psz, mp = kp.shape[2], tables.shape[1]
     owned = pp.gqa_owned_pages(jnp.array(pos), jnp.zeros_like(pos), mp,
                                psz, 0)
-    P, nb, walk = pp.window_walk(jnp.array(tables), jnp.array(pos), psz,
-                                 kp.shape[3] * 4)
+    walk = pp.window_walk(jnp.array(tables), jnp.array(pos), psz,
+                          kp.shape[3] * 4)
+    P, nb = pp._walk_shape(mp, psz, kp.shape[3] * 4)
     assert P == min(128 // psz, mp) and nb == -(-mp // P)
     assert (nb > 1) == (walk_name != "one-block")
     # one walk for every layer of a step: handed in at another layer, it
     # gives that layer's output
     handed = pp.paged_window_attention(
         *(jnp.asarray(a, dtype) for a in (q, kn, vn, kp, vp)),
-        jnp.array(tables), jnp.array(pos), n_head=2, layer=2,
-        walk=(P, nb, walk))
+        jnp.array(tables), jnp.array(pos), n_head=2, layer=2, walk=walk)
     np.testing.assert_allclose(np.asarray(handed, np.float32), ref(2),
                                atol=tol, rtol=tol)
-    scalars = pp._at_layer(walk, LAYER)
-    assert len(scalars) == pp.N_WALK and scalars[-1].tolist() == [LAYER]
-    table, live, rank, nxt = (np.asarray(a) for a in walk)
+    assert len(walk) + 1 == pp.N_WALK          # and the pool's layer
+    table, blocks, count, rank, nxt = (np.asarray(a) for a in walk)
     own = table >= 0             # an unowned entry reads -1
     assert table.shape == (len(pos), nb * P)
     assert (own[:, :mp] == np.asarray(owned)).all() and not own[:, mp:].any()
     assert (table[:, :mp][own[:, :mp]] == tables[own[:, :mp]]).all()
-    # a step is live if its block owns a page; the live steps hand the
-    # double buffer on to one another, slot after slot
-    steps = np.flatnonzero(own.reshape(-1, P).any(-1))
-    assert (np.flatnonzero(live) == steps).all()
-    assert (rank[steps] == np.arange(len(steps))).all()
-    assert nxt[steps].tolist() == steps[1:].tolist() + [-1]
-    assert int(pp.live_blocks(pos, psz, P).sum()) == len(steps)
+    # a block is live if it owns a page: a slot's loop takes its own live
+    # blocks in rising order, and the live blocks hand the double buffer
+    # on to one another, slot after slot
+    live = own.reshape(len(pos), nb, P).any(-1)
+    assert count.tolist() == live.sum(1).tolist()
+    assert count.tolist() == pp.live_blocks(pos, psz, P).tolist()
+    for b, row in enumerate(blocks.reshape(len(pos), nb)):
+        assert row[:count[b]].tolist() == np.flatnonzero(live[b]).tolist()
+    assert rank.tolist() == (np.cumsum(count) - count).tolist()
+    has = np.flatnonzero(count)
+    assert nxt[has].tolist() == has[1:].tolist() + [-1]
+    assert all(nxt[b] == (has[has > b].tolist() + [-1])[0]
+               for b in range(len(pos)))
 
 
 @pytest.mark.parametrize("kv_dtype,gran,walk", [
     ("int8", "head", "one-block"), ("fp8", "page", "one-block"),
     ("fp8", "head", "one-block"), ("int8", "head", "w8-short-last-block"),
-    ("int8", "head", "mid-block"), ("fp8", "page", "table-of-9")])
+    ("int8", "head", "mid-block"), ("fp8", "page", "table-of-9"),
+    ("int8", "page", "mid-block"), ("int8", "page", "live-between-idle"),
+    ("int8", "head", "live-between-idle"),
+    ("int8", "page", "w8-live-between-idle"),
+    ("int8", "head", "every-block-live")])
 def test_windowed_kernel_quantized_parity(kv_dtype, gran, walk):
     """fp8 KV and head-granularity scales were the documented XLA
     seams — the per-head scale-lane selection and the saturating e4m3
     fake-quant now run inside the accumulation loop, parity-pinned
     against the dequantized gather reference, over one block and over
-    a walk of several."""
+    a walk of several: a slot's scales ride as ONE row operand, cut by
+    block inside its loop."""
     from replicatinggpt_tpu.ops import paged_pallas as pp
     from replicatinggpt_tpu.quant.kv import (fake_quantize_rows,
                                              quantize_rows)
@@ -268,7 +286,43 @@ def test_windowed_kernel_quantized_parity(kv_dtype, gran, walk):
 
 
 @pytest.mark.parametrize("walk", ["one-block", "mid-block",
-                                  "w8-short-last-block"])
+                                  "w8-short-last-block",
+                                  "live-between-idle",
+                                  "w8-live-between-idle",
+                                  "every-block-live"])
+def test_owned_subsets_partials_merge_to_the_reference(walk):
+    """``owned`` hands a call an ARBITRARY subset of a slot's pages (a
+    shard's, under the ``shard_map`` wrapper): its loop takes the blocks
+    that hold one, wherever they lie, and ``fold=False`` returns the raw
+    partials. Two calls that split every slot's prefix between them at
+    random (a slot's pages may all fall to one of them: the other's loop
+    takes no turn there) merge, the wrapper's way, to the reference."""
+    from replicatinggpt_tpu.ops import paged_pallas as pp
+    q, kn, vn, kp, vp, tables, pos = _window_inputs(seed=7, walk=walk)
+    H, (B, W, C) = 2, q.shape
+    psz, mp = kp.shape[2], tables.shape[1]
+    prefix = np.asarray(pp.gqa_owned_pages(
+        jnp.array(pos), jnp.zeros_like(pos), mp, psz, 0))
+    mine = np.random.default_rng(11).random((B, mp)) < 0.4
+    mine[np.flatnonzero(pos)[0]] = True        # one slot whole to one call
+    halves = [pp.paged_window_attention(
+        *map(jnp.array, (q, kn, vn, kp, vp, tables, pos)), n_head=H,
+        layer=LAYER, owned=jnp.array(prefix & own), fold=False)
+        for own in (mine, ~mine)]
+    (a0, m0, l0), (a1, m1, l1) = halves
+    m = jnp.maximum(m0, m1)
+    c0, c1 = jnp.exp(m0 - m), jnp.exp(m1 - m)
+    rep = lambda c: jnp.repeat(c[..., :H], C // H, axis=-1)  # noqa: E731
+    out = pp._fold_fresh_window(a0 * rep(c0) + a1 * rep(c1), m,
+                                l0 * c0 + l1 * c1, jnp.array(q),
+                                jnp.array(kn), jnp.array(vn), H)
+    ref = _window_ref(q, kn, vn, kp[LAYER], vp[LAYER], tables, pos, H)
+    np.testing.assert_allclose(np.asarray(out), ref, atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("walk", ["one-block", "mid-block",
+                                  "w8-short-last-block",
+                                  "live-between-idle"])
 def test_sharded_window_kernel_matches_reference(walk):
     """The shard_map wrapper on a 2x2 (data, model) mesh: per-shard
     table localization + cross-shard online-softmax merge of the
@@ -309,10 +363,11 @@ def test_sharded_window_kernel_matches_reference(walk):
 
 def test_launch_stats_count_the_blocks_the_device_mask_owns(kernel_backend):
     """``serve/launch`` says how the kernel's walk engages, from the host
-    mirrors: ``kv_block_pages`` = P, ``kv_blocks_grid`` = slots x blocks
-    a table, and ``kv_blocks_live`` = the blocks that hold an owned page
-    in the mask the kernel is handed on the device (rebuilt here from
-    what the launch uploads: tables, positions, the live slots)."""
+    mirrors: ``kv_block_pages`` = P, ``kv_blocks_grid`` = the turns the
+    kernel's grid takes (one a slot), and ``kv_blocks_live`` = the turns
+    of the slots' loops: the blocks that hold an owned page in the mask
+    the kernel is handed on the device (rebuilt here from what the launch
+    uploads: tables, positions, the live slots)."""
     from replicatinggpt_tpu.ops import paged_pallas as pp
     from replicatinggpt_tpu.utils.telemetry import Telemetry
     cfg = dataclasses.replace(CFG, block_size=256)
@@ -331,9 +386,9 @@ def test_launch_stats_count_the_blocks_the_device_mask_owns(kernel_backend):
         pos = jnp.asarray(np.where(eng._active & ~kill, eng._pos, 0),
                           jnp.int32)
         owned = pp.gqa_owned_pages(pos, jnp.zeros_like(pos), mp, psz, 0)
-        _, _, walk = pp._blocked_walk(
+        _, _, count, _, _ = pp._blocked_walk(
             jnp.asarray(eng.pool.tables), owned, psz, cfg.n_embd * 4)
-        want.append(int(np.asarray(walk[1]).sum()))     # the live steps
+        want.append(int(np.asarray(count).sum()))  # the loops' iterations
         return dispatch(k, kill, *a)
 
     eng._dispatch = spy
@@ -346,7 +401,7 @@ def test_launch_stats_count_the_blocks_the_device_mask_owns(kernel_backend):
              if e.get("ph") == "X" and e.get("name") == "serve/launch"]
     assert want and [a["kv_blocks_live"] for a in stats] == want
     assert {1, 2, 3, 4} & set(want) and max(want) >= 4    # 1 + 1 + 2
-    assert all(a["kv_block_pages"] == P and a["kv_blocks_grid"] == 3 * 2
+    assert all(a["kv_block_pages"] == P and a["kv_blocks_grid"] == 3
                for a in stats)
 
 

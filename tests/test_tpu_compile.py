@@ -126,8 +126,10 @@ def _paged_args(sh, B, W, C=768, psz=16, mp=64, pool_dtype=BF16):
 def test_paged_window_attention_124m(mosaic, one_chip, window, quant, B, C,
                                      H):
     """The walk in blocks of 8 pages (``block_pages``) at gpt2-small's
-    and gpt2-large's widths: 16 pool operands a call (32 with a quantized
-    pool's scales), two heads of 64 to a 128-lane slab."""
+    and gpt2-large's widths, a grid turn a slot and the slot's blocks a
+    loop of traced length in the body: two heads of 64 to a 128-lane
+    slab, and a quantized pool's scales one (1,024, 1) row operand a
+    slot, cut by block inside the loop."""
     from replicatinggpt_tpu.ops.paged_pallas import (block_pages,
                                                      paged_window_attention)
     sh = {"row": one_chip, "pool": one_chip, "rep": one_chip}
@@ -158,7 +160,8 @@ def test_paged_gqa_attention_kexaone_widths(mosaic, one_chip, window, name):
     from replicatinggpt_tpu.ops.paged_pallas import (block_pages,
                                                      paged_gqa_attention)
     B, psz, mp = 64, 16, (9 if window else 512)
-    # blocks of 8 pages: 64 steps a slot, and the ring a block and a short
+    # blocks of 8 pages: up to 64 a slot's loop, and the ring a block and
+    # a short one
     assert block_pages(psz, mp, 8 * 128 * 2) == 8
     q = _s((B, 1, 64 * 128), BF16, one_chip)
     kv = _s((B, 1, 8 * 128), BF16, one_chip)
@@ -178,9 +181,10 @@ def test_paged_gqa_attention_lfm2_widths(mosaic, one_chip):
     """The grouped-query kernel at LFM2-24B-A2B's published widths (32
     query heads on 8 KV heads of 64: a block of 4 x 64 rows a KV head, a
     pool row of 512 lanes) at the cell's 256 slots of 512 table entries
-    and 40,960 pages: 16,384 grid steps, and a walk whose scalars fit the
-    chip's scalar memory only because the table says which entries are
-    owned (a table AND a mask were 1.19 MB of its 1 MB)."""
+    and 40,960 pages: 256 grid turns of up to 64 blocks, and a walk whose
+    scalars fit the chip's scalar memory only because the table says
+    which entries are owned (a table AND a mask were 1.19 MB of its 1 MB;
+    the table and the slots' block lists are 0.59 MB)."""
     from replicatinggpt_tpu.ops.paged_pallas import (block_pages,
                                                      paged_gqa_attention)
     B, psz, mp = 256, 16, 512
@@ -197,6 +201,31 @@ def test_paged_gqa_attention_lfm2_widths(mosaic, one_chip):
                      text)
 
 
+def _lower_decode_window(one_chip, cfg, family, B, n_pages, mp,
+                         served=None):
+    """The engine's decode window (k = 1, the Pallas route, pages of 16)
+    of ``family`` at ``cfg``, ``B`` slots and ``mp`` table entries,
+    lowered for the described v5e (``served``: GPT-2's cast leaves, the
+    avals of the tree its engine serves)."""
+    from replicatinggpt_tpu.serve import engine
+    shaped = lambda tree: jax.tree_util.tree_map(
+        lambda a: _s(a.shape, a.dtype, one_chip), tree)
+    params = jax.eval_shape(
+        lambda: family.init_params(jax.random.PRNGKey(0), cfg))
+    if served:
+        params = jax.eval_shape(
+            lambda t: engine.served_tree(t, served, cfg.dtype), params)
+    cache = jax.eval_shape(lambda: family.init_paged_kv_pool(
+        cfg, n_pages, 16, **({} if served else {"n_slots": B})))
+    vec = lambda dt: _s((B,), dt, one_chip)
+    return engine._engine_decode_window.lower(
+        shaped(params), vec(jnp.int32), vec(jnp.int32), vec(jnp.bool_),
+        vec(jnp.int32), vec(jnp.int32), _s((5, B), jnp.int32, one_chip),
+        _s((B, mp), jnp.int32, one_chip), shaped(cache),
+        _s((B, 2), jnp.uint32, one_chip), vec(jnp.float32), vec(jnp.int32),
+        vec(jnp.float32), vec(jnp.bool_), cfg, k=1, use_pallas=True)
+
+
 def test_lfm2_decode_step_carries_its_scopes(mosaic, one_chip):
     """The lfm2_moe decode window (published widths, the first three
     layers: conv conv full, dense dense sparse, 8 experts) compiled for
@@ -206,27 +235,13 @@ def test_lfm2_decode_step_carries_its_scopes(mosaic, one_chip):
     is donated and written in place like the pages."""
     import dataclasses
     from replicatinggpt_tpu.models import lfm2_moe
-    from replicatinggpt_tpu.serve import engine
     base = get_config("lfm2-24b-a2b").model
     cfg = dataclasses.replace(
         base, n_layer=3, layer_types=base.layer_types[:3],
         mlp_layer_types=("dense", "dense", "sparse"), n_experts=8,
         experts_held=tuple(range(8)), vocab_size=4096)
-    B, psz, mp = 16, 16, 512
-    shaped = lambda tree: jax.tree_util.tree_map(
-        lambda a: _s(a.shape, a.dtype, one_chip), tree)
-    params = shaped(jax.eval_shape(
-        lambda: lfm2_moe.init_params(jax.random.PRNGKey(0), cfg)))
-    cache = shaped(jax.eval_shape(
-        lambda: lfm2_moe.init_paged_kv_pool(cfg, 1024, psz, n_slots=B)))
-    vec = lambda dt: _s((B,), dt, one_chip)
-    text = engine._engine_decode_window.lower(
-        params, vec(jnp.int32), vec(jnp.int32), vec(jnp.bool_),
-        vec(jnp.int32), vec(jnp.int32), _s((5, B), jnp.int32, one_chip),
-        _s((B, mp), jnp.int32, one_chip), cache,
-        _s((B, 2), jnp.uint32, one_chip), vec(jnp.float32), vec(jnp.int32),
-        vec(jnp.float32), vec(jnp.bool_), cfg, k=1,
-        use_pallas=True).compile().as_text()
+    text = _lower_decode_window(one_chip, cfg, lfm2_moe, 16, 1024,
+                                512).compile().as_text()
     assert _kernel_names(text) and all(
         n.startswith("paged_window_attention") for n in _kernel_names(text))
     op_names = re.findall(r'op_name="([^"]*)"', text)
@@ -236,6 +251,49 @@ def test_lfm2_decode_step_carries_its_scopes(mosaic, one_chip):
             scope
     assert not any(re.search(r"(^|/)(attn_swa|moe_shared)(/|$)", n)
                    for n in op_names)
+
+
+@pytest.mark.parametrize("preset,kinds", [
+    ("k-exaone-236b-a23b", {"swa_window_attention": 6,
+                            "paged_window_attention": 2}),
+    ("lfm2-24b-a2b", {"paged_window_attention": 2}),
+    ("gpt2-large", {"paged_window_attention": 3}),
+], ids=["kexaone", "lfm2", "gpt2-layers-unrolled"])
+def test_decode_program_lowers_the_kernel_once_a_layer_kind(mosaic, one_chip,
+                                                            preset, kinds):
+    """Every process start traces and lowers its decode program, and
+    ``setup_s`` carries that whether or not the compile cache is warm
+    (PR 38 was refused for it: a costlier kernel body lowered once a
+    LAYER). The three served families' decode windows at their cells'
+    real widths and slots, lowered for the described v5e (the text
+    ``jit(...).lower()`` leaves, BEFORE the compiler inlines): where the
+    layers are a Python loop the LOWERED module holds each kernel ONCE A
+    KIND (K-EXAONE's 6 window layers and 2 full layers: 2 kernels, not 8;
+    LFM2's 2 full layers: 1; gpt2-large cut to 3 layers and unrolled: 1),
+    and every layer of a kind calls the one private function, which the
+    compiler inlines afterwards."""
+    import dataclasses
+    from replicatinggpt_tpu.models import exaone_moe, gpt, lfm2_moe
+    cfg = get_config(preset).model
+    if preset == "gpt2-large":
+        cfg = dataclasses.replace(cfg, n_layer=3, scan_layers=False,
+                                  decode_cache_layout="packed")
+        low = _lower_decode_window(one_chip, cfg, gpt, 96, 3072, 64,
+                                   served=gpt.SERVE_CAST_LEAVES)
+    elif preset == "lfm2-24b-a2b":
+        low = _lower_decode_window(one_chip, cfg, lfm2_moe, 256, 40_960, 512)
+    else:
+        low = _lower_decode_window(one_chip, cfg, exaone_moe, 64, 10_240,
+                                   512)
+    text = low.as_text()
+    assert len(re.findall(r"stablehlo\.custom_call @tpu_custom_call",
+                          text)) == len(kinds)
+    for name, layers in kinds.items():
+        held = re.findall(rf'kernel_name = "{name}"', text)
+        assert len(held) == 1, (name, len(held))
+    # and the layers of a kind are calls of the kind's one function
+    calls = re.findall(r"call @(_(?:gqa|window)_call\w*)\(", text)
+    assert sorted(map(calls.count, set(calls))) == sorted(kinds.values())
 
 
 def test_sharded_paged_window_attention_2x2(mosaic, mesh2x2):
