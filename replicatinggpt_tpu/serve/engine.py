@@ -88,10 +88,13 @@ Every step marks its host phases through ``self.tel.phase`` (a
 ``serve/prefill``, and ``serve/decode`` | ``serve/verify`` around
 ``serve/launch``, ``serve/fetch``, ``serve/commit``), with the live
 context (``live_tokens``, ``live_kv_bytes``, ``stochastic_rows``, the
-paged kernel's ``kv_block_pages`` / ``kv_blocks_live`` /
-``kv_blocks_grid``, from the host mirrors) as stats of ``serve/launch``;
+paged kernel's ``kv_blocks_live`` / ``kv_blocks_grid``, from the host
+mirrors) as stats of ``serve/launch``;
 docs/observability.md
-has the vocabulary.
+has the vocabulary. Construction is the ``setup/engine`` span of the
+process's set-up record (``utils.telemetry.setup_phase``), and every
+guarded program's first call a build in it; ``metrics_summary()["setup"]``
+and the ``setup_*`` gauges carry its totals.
 """
 
 from __future__ import annotations
@@ -115,7 +118,8 @@ from ..sample.generate import sample_tokens_batched
 from ..utils.logging import Metrics
 from ..utils.profiling import StepTimer
 from ..utils.sanitize import CompileGuard, check_in_bounds, sanitize_enabled
-from ..utils.telemetry import ENGINE_TRACK, NULL, SLOT_TRACK_BASE
+from ..utils.telemetry import (ENGINE_TRACK, NULL, SLOT_TRACK_BASE,
+                               setup_phase, setup_record)
 from .pages import PagedCachePool
 from .requests import (FINISH_CANCELLED, FINISH_DEADLINE, FINISH_EOS,
                        FINISH_LENGTH_CAP, FINISH_MAX_TOKENS,
@@ -672,6 +676,24 @@ def _cast_leaves(leaves, dtype):
     return [a.astype(dtype) for a in leaves]
 
 
+def _wide_leaves(flat, names, dtype) -> List[int]:
+    """Indices of the leaves of ``flat`` (``tree_flatten_with_path``)
+    that ``served_tree`` casts."""
+    return [i for i, (path, a) in enumerate(flat)
+            if getattr(path[-1], "key", None) in names
+            and jnp.issubdtype(a.dtype, jnp.floating)
+            and a.dtype.itemsize > dtype.itemsize]
+
+
+def served_cast_bytes(params, names, dtype) -> int:
+    """Bytes of the copies ``served_tree(params, names, dtype)`` makes,
+    from the leaves' shapes (nothing is cast)."""
+    dtype = jnp.dtype(dtype)
+    flat = jax.tree_util.tree_flatten_with_path(params)[0]
+    return sum(int(flat[i][1].size) * dtype.itemsize
+               for i in _wide_leaves(flat, names, dtype))
+
+
 def served_tree(params, names, dtype):
     """The tree the engine's programs take: ``params`` with every leaf
     NAMED in ``names`` (the family's ``serve_cast_leaves``: what its
@@ -687,23 +709,12 @@ def served_tree(params, names, dtype):
     dtype = jnp.dtype(dtype)
     flat, treedef = jax.tree_util.tree_flatten_with_path(params)
     leaves = [a for _, a in flat]
-    wide = [i for i, (path, a) in enumerate(flat)
-            if getattr(path[-1], "key", None) in names
-            and jnp.issubdtype(a.dtype, jnp.floating)
-            and a.dtype.itemsize > dtype.itemsize]
+    wide = _wide_leaves(flat, names, dtype)
     if not wide:
         return params
     for i, c in zip(wide, _cast_leaves([leaves[i] for i in wide], dtype)):
         leaves[i] = c
     return treedef.unflatten(leaves)
-
-
-def weight_cast_bytes(params, served) -> int:
-    """Bytes of the copies ``served_tree`` made (arrays or avals)."""
-    return sum(int(s.size) * s.dtype.itemsize
-               for p, s in zip(jax.tree_util.tree_leaves(params),
-                               jax.tree_util.tree_leaves(served))
-               if s.dtype != p.dtype)
 
 
 def engine_summary_block(engine: "Engine") -> dict:
@@ -759,6 +770,7 @@ class Engine:
       percentiles.
     """
 
+    @setup_phase("setup/engine")
     def __init__(self, params, cfg: ModelConfig,
                  ecfg: EngineConfig = EngineConfig(),
                  clock: Callable[[], float] = time.monotonic,
@@ -780,7 +792,16 @@ class Engine:
         offsets every track id this engine emits on — the fleet router
         gives replica ``i`` base ``i * REPLICA_TRACK_STRIDE`` so N
         replicas share one recorder without colliding tracks
-        (``track_label`` prefixes the human-readable track names)."""
+        (``track_label`` prefixes the human-readable track names).
+
+        All of it is the ``setup/engine`` span of the set-up record
+        (``utils.telemetry.setup_phase``), with ``setup/weights_ready``,
+        ``setup/served_tree``, ``setup/pool`` and ``setup/warm_programs``
+        inside."""
+        # the caller's weights are dispatched asynchronously: wait for
+        # them HERE, or their device time lands in whatever waits first
+        with setup_phase("setup/weights_ready"):
+            jax.block_until_ready(params)
         cfg.validate()
         refused = serve_refusals(cfg, ecfg, drafter)
         if refused:
@@ -811,8 +832,12 @@ class Engine:
         # casts out of the layer scan, a bf16 copy of all the weights
         # made and dropped a launch). ``self.params`` stays the caller's
         # tree (masters, or the quantised tree): references read it
-        self.served_params = served_tree(
+        self._cast_bytes = served_cast_bytes(
             self.params, self._fam.serve_cast_leaves, cfg.dtype)
+        with setup_phase("setup/served_tree",
+                         weight_cast_bytes=self._cast_bytes):
+            self.served_params = served_tree(
+                self.params, self._fam.serve_cast_leaves, cfg.dtype)
         self.clock = clock
         self.drafter = drafter
         self.tel = telemetry or NULL
@@ -858,16 +883,17 @@ class Engine:
                 serve_param_shardings(cfg, self.mesh, ecfg.mesh_model,
                                       params=self.served_params))
         self._rep = self._plan.rep if self._plan is not None else None
-        self.pool = PagedCachePool(
-            cfg, ecfg.pool_size, page_size=ecfg.page_size,
-            max_pages=ecfg.max_pages, n_pages=ecfg.n_pages,
-            prefix_cache=ecfg.prefix_cache, telemetry=self.tel,
-            sharding=(self._plan.cache if self._plan is not None
-                      else None),
-            scale_sharding=(self._plan.scale if self._plan is not None
-                            else None),
-            mesh_shape=(ecfg.mesh_data, ecfg.mesh_model),
-            quant=(self.qcfg if self.qcfg.kv_enabled else None))
+        with setup_phase("setup/pool"):
+            self.pool = PagedCachePool(
+                cfg, ecfg.pool_size, page_size=ecfg.page_size,
+                max_pages=ecfg.max_pages, n_pages=ecfg.n_pages,
+                prefix_cache=ecfg.prefix_cache, telemetry=self.tel,
+                sharding=(self._plan.cache if self._plan is not None
+                          else None),
+                scale_sharding=(self._plan.scale if self._plan is not None
+                                else None),
+                mesh_shape=(ecfg.mesh_data, ecfg.mesh_model),
+                quant=(self.qcfg if self.qcfg.kv_enabled else None))
         self.scheduler = Scheduler(ecfg.max_queue, cfg.block_size,
                                    clock=clock)
         self.metrics = Metrics()
@@ -1005,30 +1031,8 @@ class Engine:
                                           "serve/page-export")
         self._install_guard = CompileGuard(_engine_page_install,
                                            "serve/page-install")
-        # warm the COW program NOW (page 0 onto itself — a value no-op):
-        # the first real copy-on-write happens mid-replay, where a
-        # compile would break the pinned-flat compile_counts invariant
-        self.pool.pages = self._copy_guard(self.pool.pages, jnp.int32(0),
-                                           jnp.int32(0),
-                                           shardings=self._plan)
-        # warm the disaggregated-transfer pair the same way: export page
-        # 0, round-trip its blocks through host memory (matching the
-        # live path's placement — uncommitted uploads — so the warm
-        # program IS the steady-state program), install them back onto
-        # page 0. A value no-op; the first real transfer lands
-        # mid-traffic on either tier.
-        blocks = {name: np.asarray(arr) for name, arr in
-                  self._export_guard(self.pool.pages,
-                                     jnp.int32(0)).items()}
-        self.pool.pages = self._install_guard(
-            self.pool.pages, jnp.int32(0),
-            {name: jnp.asarray(arr) for name, arr in blocks.items()},
-            shardings=self._plan)
-        if self._window > 1:
-            # compile every bucketed window program up front (masked
-            # no-op dispatches) — admissions, lifecycle masks and
-            # autotune bucket moves then always hit a warm program
-            self._warm_windows()
+        with setup_phase("setup/warm_programs"):
+            self._warm_programs()
         self._sanitize = sanitize_enabled()
         # self-healing (faults.watchdog): all policies opt-in via rcfg.
         # Degraded transitions move between the two already-budgeted
@@ -1491,8 +1495,12 @@ class Engine:
         s["conv_state_bytes"] = kinds.get("conv", 0)
         # bytes of the compute-dtype copies made at build (0: the tree
         # came in the compute dtype and is served as it is)
-        s["weight_cast_bytes"] = weight_cast_bytes(self.params,
-                                                   self.served_params)
+        s["weight_cast_bytes"] = self._cast_bytes
+        # the process's set-up record (utils.telemetry): its time to
+        # ready, also as the tpu_gpt_setup_* gauges
+        s["setup"] = setup_record().summary()
+        for k in ("engine_s", "build_s", "trace_s", "lower_s", "compile_s"):
+            self.metrics.gauge("setup_" + k, s["setup"][k])
         # dispatch amortization: the host tax per dispatch vs per token
         # (the serve-side analogue of the train bench's dispatch split)
         c = self.metrics.counters
@@ -1824,6 +1832,33 @@ class Engine:
         head = self.scheduler.peek()
         return head is not None and self._fits(head[0])
 
+    def _warm_programs(self) -> None:
+        """The programs a request may need mid-traffic, run once now."""
+        # warm the COW program NOW (page 0 onto itself — a value no-op):
+        # the first real copy-on-write happens mid-replay, where a
+        # compile would break the pinned-flat compile_counts invariant
+        self.pool.pages = self._copy_guard(self.pool.pages, jnp.int32(0),
+                                           jnp.int32(0),
+                                           shardings=self._plan)
+        # warm the disaggregated-transfer pair the same way: export page
+        # 0, round-trip its blocks through host memory (matching the
+        # live path's placement — uncommitted uploads — so the warm
+        # program IS the steady-state program), install them back onto
+        # page 0. A value no-op; the first real transfer lands
+        # mid-traffic on either tier.
+        blocks = {name: np.asarray(arr) for name, arr in
+                  self._export_guard(self.pool.pages,
+                                     jnp.int32(0)).items()}
+        self.pool.pages = self._install_guard(
+            self.pool.pages, jnp.int32(0),
+            {name: jnp.asarray(arr) for name, arr in blocks.items()},
+            shardings=self._plan)
+        if self._window > 1:
+            # compile every bucketed window program up front (masked
+            # no-op dispatches) — admissions, lifecycle masks and
+            # autotune bucket moves then always hit a warm program
+            self._warm_windows()
+
     def _warm_windows(self) -> None:
         """Compile every bucketed window program — the pure decode
         window AND the mixed prefill+decode window at each
@@ -1948,19 +1983,18 @@ class Engine:
 
     def _kv_walk_stats(self, live: np.ndarray) -> dict:
         """How the paged kernel's walk engages in the launch being
-        built, from the host mirrors: ``kv_block_pages`` (P, the pages a
-        block of the kernel's loop covers), ``kv_blocks_live`` (loop
-        iterations with work: the sum over the live slots of the blocks
-        that hold a position under the slot's, as the kernel's owned mask
-        will have it on the device; one step of one pool layer) and
-        ``kv_blocks_grid`` (the turns the kernel's grid takes there: one
-        a slot, so live over grid reads blocks a turn)."""
+        built, from the host mirrors: ``kv_blocks_live`` (loop iterations
+        with work: the sum over the live slots of the blocks of
+        ``ops.paged_pallas.block_pages`` pages that hold a position under
+        the slot's, as the kernel's owned mask will have it on the
+        device; one step of one pool layer) and ``kv_blocks_grid`` (the
+        turns the kernel's grid takes there: one a slot, so live over
+        grid reads blocks a turn)."""
         from ..ops.paged_pallas import live_blocks
-        P = self._kv_block_pages
         return dict(
-            kv_block_pages=P,
             kv_blocks_live=int(live_blocks(
-                self._pos[live], self.pool.page_size, P).sum()),
+                self._pos[live], self.pool.page_size,
+                self._kv_block_pages).sum()),
             kv_blocks_grid=self.ecfg.pool_size)
 
     def _family_launch_stats(self) -> Optional[dict]:

@@ -27,7 +27,7 @@ from ..tokenizers import get_tokenizer
 from ..utils.logging import StepLogger
 from ..utils.sanitize import (CompileGuard, check_finite, sanitize_enabled,
                               sanitized)
-from ..utils.telemetry import NULL
+from ..utils.telemetry import NULL, setup_phase
 from .state import TrainState, create_train_state
 from .steps import estimate_loss, make_eval_step, make_train_step
 
@@ -98,7 +98,10 @@ def train(cfg: Config, *, mesh=None, logger: Optional[StepLogger] = None,
     ``jax.profiler.TraceAnnotation``, so a ``profile_dir`` capture
     shows them on the device's clock with no recorder attached;
     ``telemetry`` (utils.telemetry.Telemetry) also keeps them as spans,
-    exportable to Perfetto. None means the NULL recorder."""
+    exportable to Perfetto. None means the NULL recorder. Set-up is in
+    the process's set-up record (``utils.telemetry.setup_phase``):
+    ``setup/train_state`` around the state's init and placement, and the
+    step's first call a build of its ``CompileGuard``."""
     logger = logger or StepLogger()
     tel = telemetry or NULL
     text = load_corpus(cfg.dataset)
@@ -148,22 +151,26 @@ def train(cfg: Config, *, mesh=None, logger: Optional[StepLogger] = None,
     rng = jax.random.PRNGKey(tcfg.seed)
     batch_sharding = None
     n_chips = 1
-    if mesh is not None:
-        from ..parallel.mesh import make_batch_sharding, shard_train_state
-        batch_sharding = make_batch_sharding(mesh)
-        n_chips = mesh.size
-        state = shard_train_state(
-            lambda: create_train_state(rng, mcfg, tcfg), mesh, cfg.mesh)
-    else:
-        # commit the fresh state to an explicit device: jit keys on
-        # placement, and an uncommitted initial state whose successor
-        # comes back committed can split the cache into a throwaway
-        # first program (the serve engine's commit_default rationale —
-        # and the train CompileGuard below would flag it as a
-        # recompile)
-        state = jax.device_put(
-            create_train_state(rng, mcfg, tcfg),
-            jax.config.jax_default_device or jax.local_devices()[0])
+    # the per-seed init program traces and compiles here
+    with setup_phase("setup/train_state"):
+        if mesh is not None:
+            from ..parallel.mesh import (make_batch_sharding,
+                                         shard_train_state)
+            batch_sharding = make_batch_sharding(mesh)
+            n_chips = mesh.size
+            state = shard_train_state(
+                lambda: create_train_state(rng, mcfg, tcfg), mesh,
+                cfg.mesh)
+        else:
+            # commit the fresh state to an explicit device: jit keys on
+            # placement, and an uncommitted initial state whose successor
+            # comes back committed can split the cache into a throwaway
+            # first program (the serve engine's commit_default rationale
+            # — and the train CompileGuard below would flag it as a
+            # recompile)
+            state = jax.device_put(
+                create_train_state(rng, mcfg, tcfg),
+                jax.config.jax_default_device or jax.local_devices()[0])
     logger.log(f"model: {param_count(state.params):,} params "
                f"({mcfg.n_layer}L/{mcfg.n_head}H/{mcfg.n_embd}C, "
                f"dtype={mcfg.dtype})")

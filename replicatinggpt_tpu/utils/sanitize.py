@@ -10,7 +10,8 @@ these guards catch the ones only visible at run time:
   step, so a silent steady-state recompile (shape/dtype drift, a
   weak-type promotion, a committed/uncommitted placement split — the
   exact bug class PR 1 hit) surfaces as an exception naming the
-  program instead of as a 40% throughput mystery.
+  program instead of as a 40% throughput mystery. A call that added a
+  program is a BUILD in the set-up record (``utils.telemetry``).
 - :func:`check_in_bounds` — the sanctioned guard for
   ``dynamic_update_slice`` starts (lint rule GL006): asserts on
   concrete values, no-op on tracers (jit callers must bound the index
@@ -30,7 +31,10 @@ from __future__ import annotations
 
 import contextlib
 import os
+import time
 from typing import Any, Callable, Dict, Iterable, Optional
+
+from .telemetry import setup_record
 
 
 class RecompileError(RuntimeError):
@@ -65,6 +69,7 @@ class CompileGuard:
         self.max_programs = max_programs
         self._compiles = 0
         self.calls = 0
+        setup_record()       # its listener must hear the first build's stages
 
     def _cache_size(self) -> int:
         size = getattr(self._fn, "_cache_size", None)
@@ -97,11 +102,16 @@ class CompileGuard:
 
     def __call__(self, *args, **kwargs):
         before = self._cache_size()
+        t0 = time.perf_counter()
         out = self._fn(*args, **kwargs)
         self.calls += 1
         # growth across THIS call only: programs other owners of the
         # same (module-level) jit compile between our calls are theirs
-        self._compiles += max(self._cache_size() - before, 0)
+        grew = self._cache_size() - before
+        if grew > 0:
+            # a build: the program's first call, kept in the set-up record
+            self._compiles += grew
+            setup_record().record_build(self.name, t0, time.perf_counter())
         self.check()
         return out
 

@@ -35,18 +35,25 @@ zero-cost-when-disabled event/span recorder plus three exporters.
   summary hides the interesting transient.
 - Prometheus text exposition (:func:`prometheus_text`) — the scrape
   format an HTTP front door serves from ``/metrics``.
+- The set-up record (:func:`setup_record`, :func:`setup_phase`) — one
+  process-wide account of the time before the first request:
+  ``setup/*`` spans where the program gets ready, a build for every
+  guarded program's first call (``utils.sanitize.CompileGuard``), and
+  JAX's trace / lower / compile events assigned to the innermost open
+  span or to the build they ran in, summed as a union of intervals.
+  On ``time.perf_counter``, the clock of the benchmark's ``setup_s``.
 
 Zero-cost-when-disabled is load-bearing: the :data:`NULL` recorder is
 what every instrumented subsystem holds by default, its methods are
-no-ops, its ``span()`` returns one shared reusable null context (no
-per-call allocation), its ``phase()`` is the bare profiler annotation
-and nothing else, and nothing in this module performs a
+no-ops, its ``phase()`` is the bare profiler annotation and nothing
+else, and nothing in this module performs a
 device->host sync — graftlint GL004-clean with zero pragmas (pinned in
 tests/test_telemetry.py, along with the no-buffer-growth property).
 """
 
 from __future__ import annotations
 
+import bisect
 import contextlib
 import json
 import re
@@ -61,6 +68,8 @@ __all__ = [
     "ROUTER_TRACK", "ROUTER_TRACK_NAME", "NULL", "NullTelemetry",
     "Telemetry", "MetricsTimeline", "chrome_trace_from_jsonl",
     "load_jsonl", "prometheus_text", "PROM_PINNED_COUNTERS",
+    "SETUP_STAGES", "IntervalUnion", "SetupRecord", "setup_record",
+    "setup_phase",
 ]
 
 #: The fleet-dashboard counter schema: every name a Grafana panel or
@@ -111,33 +120,15 @@ ROUTER_TRACK = 9000
 ROUTER_TRACK_NAME = "router"
 
 
-class _NullSpan:
-    """Reusable, reentrant no-op context manager (shared instance)."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return None
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_SPAN = _NullSpan()
-
-
 class NullTelemetry:
-    """The disabled recorder: every method is a no-op, ``span`` hands
-    back one shared context manager, and no state ever accumulates.
+    """The disabled recorder: every method is a no-op, ``phase`` is the
+    bare profiler annotation, and no state ever accumulates.
     Instrumented hot loops additionally guard whole blocks with
     ``if tel.enabled:`` so the disabled step path pays one attribute
     read, not N method calls."""
 
     enabled = False
     events: tuple = ()
-
-    def span(self, name: str, track: int = ENGINE_TRACK, **args):
-        return _NULL_SPAN
 
     def phase(self, name: str, track: int = ENGINE_TRACK, **args):
         from .profiling import annotate    # lazy: see Telemetry.phase
@@ -293,10 +284,6 @@ class Telemetry:
         finally:
             self.complete(name, track, t0, self.now_us() - t0, **args)
 
-    #: the older name of ``phase`` (NullTelemetry's ``span`` is the shared
-    #: null context: a disabled ``span`` is not on the profiler's timeline)
-    span = phase
-
     # ------------------------------------------------------------ export
 
     def chrome_events(self) -> List[dict]:
@@ -327,6 +314,268 @@ class Telemetry:
         if self._sink is not None:
             self._sink.close()
             self._sink = None
+
+
+# ---------------------------------------------------------------------------
+# set-up record: where the time before the first request goes
+# ---------------------------------------------------------------------------
+
+#: JAX's compile-stage duration events, and the stage each one times
+#: (``backend_compile`` includes a persistent-cache retrieval)
+SETUP_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+
+
+class IntervalUnion:
+    """Seconds covered by intervals, each instant counted once: a jit
+    traced inside its caller's trace fires its own event INSIDE the
+    caller's, so a plain sum counts the inner one twice. Keeps at most
+    ``cap`` disjoint intervals; past that the earliest is folded into a
+    running total (an interval that later arrives over a folded one
+    would be counted again: stage events arrive in the order they END,
+    so only a trace longer than ``cap`` disjoint others could)."""
+
+    __slots__ = ("cap", "folded", "iv")
+
+    def __init__(self, cap: int = 64):
+        self.cap = cap
+        self.folded = 0.0
+        self.iv: List[tuple] = []        # sorted, disjoint (start, end)
+
+    def add(self, a: float, b: float) -> None:
+        iv = self.iv
+        i = bisect.bisect_left(iv, (a,))
+        if i and iv[i - 1][1] >= a:      # the one before reaches into it
+            i -= 1
+            a = iv[i][0]
+        j = i
+        while j < len(iv) and iv[j][0] <= b:
+            b = max(b, iv[j][1])
+            j += 1
+        iv[i:j] = [(a, b)]
+        if len(iv) > self.cap:
+            a0, b0 = iv.pop(0)
+            self.folded += b0 - a0
+
+    @property
+    def seconds(self) -> float:
+        return self.folded + sum(b - a for a, b in self.iv)
+
+
+class SetupRecord:
+    """What a process spends getting ready, on ``time.perf_counter``: the
+    clock the benchmark's ``setup_s`` is read on, so spans and
+    ``setup_s`` add up on one clock.
+
+    - **Spans** (``setup_phase``): ``(name, parent, t0, t1, stats)``,
+      the innermost open span the parent.
+    - **Builds** (``CompileGuard``): a guarded call whose jit cache grew,
+      i.e. a program's first call: trace, lower, compile or cache load,
+      and dispatch. ``(guard, t0, t1, {stage: seconds inside it})``.
+    - **Stages**: JAX's trace / lower / compile duration events, each the
+      interval ``[now - secs, now]``, tagged with its ``fun_name`` and the
+      innermost open span (``stages_by_span``). One that ends inside a
+      set-up span, or inside a build, is set-up: its totals are the union
+      of those intervals (``IntervalUnion``); ``process`` holds the union
+      of every interval of the process, set-up or not (a reference check
+      after the traffic, a harness's own jits).
+
+    Bounded: the totals always, the first ``keep`` spans and builds, and
+    a count of those dropped."""
+
+    def __init__(self, keep: int = 256,
+                 clock: Callable[[], float] = time.perf_counter):
+        self.keep = keep
+        self.clock = clock
+        self.spans: List[tuple] = []
+        self.builds: List[tuple] = []
+        self.dropped = {"spans": 0, "builds": 0}
+        self.open: List[str] = []        # names of the open spans
+        self.span_s: Dict[str, float] = {}
+        self.build_s: Dict[str, float] = {}
+        self.n_builds = 0
+        self.ready_at: Optional[float] = None   # end of the last of them
+        self.setup = {st: IntervalUnion() for st in SETUP_STAGES.values()}
+        self.process = {st: IntervalUnion()
+                        for st in SETUP_STAGES.values()}
+        self.trace_by_fun: Dict[str, float] = {}
+        #: per innermost open span, each stage's union (64 names at most)
+        self.by_span: Dict[str, Dict[str, IntervalUnion]] = {}
+        # stage intervals a build that is still running may own:
+        # [stage, fun, a, b, counted as set-up yet]
+        self._recent: deque = deque(maxlen=512)
+
+    # ------------------------------------------------------------ record
+
+    def on_stage(self, event: str, secs: float, fun_name: str = "",
+                 **_) -> None:
+        """The ``jax.monitoring`` duration listener's body."""
+        stage = SETUP_STAGES.get(event)
+        if stage is None:
+            return
+        b = self.clock()
+        a = b - secs
+        self.process[stage].add(a, b)
+        item = [stage, fun_name, a, b, False]
+        if self.open:
+            self._count(item)
+            span = self.open[-1]
+            if span in self.by_span or len(self.by_span) < 64:
+                self.by_span.setdefault(span, {}).setdefault(
+                    stage, IntervalUnion()).add(a, b)
+        self._recent.append(item)
+
+    def _count(self, item: list) -> None:
+        stage, fun, a, b, _ = item
+        item[4] = True
+        self.setup[stage].add(a, b)
+        if stage == "trace":
+            if fun in self.trace_by_fun or len(self.trace_by_fun) < 1024:
+                self.trace_by_fun[fun] = (self.trace_by_fun.get(fun, 0.0)
+                                          + b - a)
+
+    def _ready(self, t1: float) -> None:
+        self.ready_at = t1 if self.ready_at is None else max(
+            self.ready_at, t1)
+
+    def record_span(self, name: str, parent: Optional[str], t0: float,
+                    t1: float, stats: dict) -> None:
+        self.span_s[name] = self.span_s.get(name, 0.0) + t1 - t0
+        self._ready(t1)
+        if len(self.spans) < self.keep:
+            self.spans.append((name, parent, t0, t1, stats))
+        else:
+            self.dropped["spans"] += 1
+
+    def record_build(self, name: str, t0: float, t1: float) -> None:
+        """A guarded call ``[t0, t1]`` that added a program: the stage
+        intervals inside it are set-up, and its own split by stage."""
+        inside = {st: IntervalUnion() for st in SETUP_STAGES.values()}
+        for item in self._recent:
+            if t0 <= item[2] and item[3] <= t1:
+                inside[item[0]].add(item[2], item[3])
+                if not item[4]:
+                    self._count(item)
+        self.n_builds += 1
+        self.build_s[name] = self.build_s.get(name, 0.0) + t1 - t0
+        self._ready(t1)
+        if len(self.builds) < self.keep:
+            self.builds.append((name, t0, t1, {
+                st + "_s": u.seconds for st, u in inside.items()}))
+        else:
+            self.dropped["builds"] += 1
+
+    # ------------------------------------------------------------- views
+
+    def top_trace(self, n: int = 5) -> List[list]:
+        """The ``n`` functions with the most set-up trace seconds (an
+        inner jit's seconds count for it AND for the function it was
+        traced in)."""
+        return [[f, s] for f, s in sorted(
+            self.trace_by_fun.items(), key=lambda kv: -kv[1])[:n]]
+
+    def breakdown(self, t_start: float,
+                  setup_s: Optional[float] = None) -> dict:
+        """Every span and build as seconds from ``t_start`` (a harness's
+        process start, on this clock), the stage totals, the five
+        functions with the most trace seconds, and how much of set-up
+        the spans and builds cover: ``covered_s`` (the union of the
+        top-level spans and the builds), ``before_first_s`` (``t_start``
+        to the first of them: imports, the backend, the weights'
+        dispatch), ``between_s`` (first to last, outside them: a
+        harness's own work, a warm-up's turns that built nothing) and,
+        given the harness's ``setup_s``, ``after_last_s`` (the last of
+        them to the end of set-up: a ramp, the last warm-up turns). The
+        four add up to ``setup_s``."""
+        at = lambda t: t - t_start
+        covered = IntervalUnion(cap=len(self.spans) + len(self.builds) + 1)
+        for _, parent, t0, t1, _ in self.spans:
+            if parent is None:
+                covered.add(t0, t1)
+        for _, t0, t1, _ in self.builds:
+            covered.add(t0, t1)
+        first = covered.iv[0][0] if covered.iv else None
+        s = self.summary()
+        out = {
+            "spans": [[name, parent, at(t0), at(t1), stats]
+                      for name, parent, t0, t1, stats in self.spans],
+            "builds": [[name, at(t0), at(t1), split]
+                       for name, t0, t1, split in self.builds],
+            "dropped": dict(self.dropped),
+            **{k: s[k] for k in ("trace_s", "lower_s", "compile_s",
+                                 "process", "top_trace")},
+            "covered_s": covered.seconds,
+            "before_first_s": None if first is None else at(first),
+            "between_s": (None if first is None else
+                          self.ready_at - first - covered.seconds),
+            "ready_s": None if self.ready_at is None else at(self.ready_at),
+        }
+        if setup_s is not None and self.ready_at is not None:
+            out["after_last_s"] = setup_s - at(self.ready_at)
+        return out
+
+    def summary(self) -> dict:
+        """The totals: ``engine_s`` (every ``setup/engine`` span),
+        ``build_s`` and its split ``build_s_by_guard``, the set-up
+        stages' unions ``trace_s`` / ``lower_s`` / ``compile_s``, the
+        process's (``process``), and the top functions by trace
+        seconds."""
+        return {
+            "engine_s": self.span_s.get("setup/engine", 0.0),
+            "build_s": sum(self.build_s.values()),
+            "builds": self.n_builds,
+            "build_s_by_guard": dict(self.build_s),
+            **{st + "_s": u.seconds for st, u in self.setup.items()},
+            "process": {st + "_s": u.seconds
+                        for st, u in self.process.items()},
+            "top_trace": self.top_trace(),
+            "stages_by_span": {
+                span: {st + "_s": u.seconds for st, u in d.items()}
+                for span, d in self.by_span.items()},
+            "dropped": dict(self.dropped),
+        }
+
+
+_RECORD: Optional[SetupRecord] = None
+
+
+def _on_duration(event: str, secs: float, **kw) -> None:
+    if _RECORD is not None:
+        _RECORD.on_stage(event, secs, **kw)
+
+
+def setup_record() -> SetupRecord:
+    """The process's set-up record. Its first use registers the ONE
+    ``jax.monitoring`` listener (not at import: this module stays
+    jax-free for the exporters); stages before it are not seen."""
+    global _RECORD
+    if _RECORD is None:
+        from jax import monitoring
+        _RECORD = SetupRecord()
+        monitoring.register_event_duration_secs_listener(_on_duration)
+    return _RECORD
+
+
+@contextlib.contextmanager
+def setup_phase(name: str, **stats) -> Iterator[None]:
+    """A span of set-up (``setup/engine``, ``setup/train_state``, ...):
+    ``profiling.annotate(name, **stats)`` around the block, so a capture
+    started at process start shows it beside the warm-up's ops, and one
+    entry of the set-up record on exit."""
+    from .profiling import annotate
+    rec = setup_record()
+    parent = rec.open[-1] if rec.open else None
+    rec.open.append(name)
+    t0 = rec.clock()
+    try:
+        with annotate(name, **stats):
+            yield
+    finally:
+        rec.open.pop()
+        rec.record_span(name, parent, t0, rec.clock(), stats)
 
 
 # ---------------------------------------------------------------------------

@@ -363,7 +363,7 @@ def test_sharded_window_kernel_matches_reference(walk):
 
 def test_launch_stats_count_the_blocks_the_device_mask_owns(kernel_backend):
     """``serve/launch`` says how the kernel's walk engages, from the host
-    mirrors: ``kv_block_pages`` = P, ``kv_blocks_grid`` = the turns the
+    mirrors: ``kv_blocks_grid`` = the turns the
     kernel's grid takes (one a slot), and ``kv_blocks_live`` = the turns
     of the slots' loops: the blocks that hold an owned page in the mask
     the kernel is handed on the device (rebuilt here from what the launch
@@ -380,6 +380,7 @@ def test_launch_stats_count_the_blocks_the_device_mask_owns(kernel_backend):
     psz, mp = eng.pool.page_size, eng.pool.max_pages
     P = pp.block_pages(psz, mp, cfg.n_embd * 4)
     assert (psz, mp, P) == (8, 32, 16)
+    assert eng._kv_block_pages == P           # the walk's block, not a stat
     want, dispatch = [], eng._dispatch
 
     def spy(k, kill, *a):
@@ -401,7 +402,7 @@ def test_launch_stats_count_the_blocks_the_device_mask_owns(kernel_backend):
              if e.get("ph") == "X" and e.get("name") == "serve/launch"]
     assert want and [a["kv_blocks_live"] for a in stats] == want
     assert {1, 2, 3, 4} & set(want) and max(want) >= 4    # 1 + 1 + 2
-    assert all(a["kv_block_pages"] == P and a["kv_blocks_grid"] == 3
+    assert all(a["kv_blocks_grid"] == 3 and "kv_block_pages" not in a
                for a in stats)
 
 

@@ -652,15 +652,20 @@ def test_weight_cast_bytes_of_the_published_presets(preset, want):
     bfloat16 parameters beside none."""
     from replicatinggpt_tpu.config import get_config
     from replicatinggpt_tpu.models.families import family
-    from replicatinggpt_tpu.serve.engine import (served_tree,
-                                                 weight_cast_bytes)
+    from replicatinggpt_tpu.serve.engine import (served_cast_bytes,
+                                                 served_tree)
     cfg = get_config(preset).model
     fam = family(cfg)
     tree = jax.eval_shape(lambda: fam.init_params(jax.random.PRNGKey(0),
                                                   cfg))
     served = jax.eval_shape(
         lambda t: served_tree(t, fam.serve_cast_leaves, cfg.dtype), tree)
-    assert weight_cast_bytes(tree, served) == want
+    made = sum(int(s.size) * s.dtype.itemsize
+               for p, s in zip(jax.tree_util.tree_leaves(tree),
+                               jax.tree_util.tree_leaves(served))
+               if s.dtype != p.dtype)
+    assert made == served_cast_bytes(tree, fam.serve_cast_leaves,
+                                     cfg.dtype) == want
 
 
 def test_steady_state_64_requests_zero_recompiles(params):

@@ -68,15 +68,14 @@ def _names(tel):
 # ---------------------------------------------------------------------------
 
 def test_null_telemetry_is_stateless_and_allocation_free():
-    """The disabled recorder accumulates nothing and its span() hands
-    back ONE shared context manager — the structural pin behind the
-    'disabled telemetry changes nothing' claim (events is a tuple: it
-    CANNOT grow)."""
+    """The disabled recorder accumulates nothing — the structural pin
+    behind the 'disabled telemetry changes nothing' claim (events is a
+    tuple: it CANNOT grow) — and has no ``span``: ``phase`` is the one
+    way to mark a phase."""
     assert not NULL.enabled
-    s1, s2 = NULL.span("a", 3, x=1), NULL.span("b")
-    assert s1 is s2                       # shared instance, no per-call alloc
-    with s1 as v:
-        assert v is None
+    assert not hasattr(NULL, "span") and not hasattr(Telemetry, "span")
+    with NULL.phase("a", 3, x=1), NULL.phase("b"):
+        pass
     NULL.begin("a"), NULL.end("a"), NULL.instant("m", step=1)
     NULL.complete("x", 0, 0.0, 1.0)
     NULL.name_track(0, "engine")
@@ -101,7 +100,7 @@ def test_null_phase_is_the_bare_annotation_and_records_nothing():
 
 def test_phase_records_one_x_event_with_its_args():
     """``Telemetry.phase`` enters the same annotation and keeps ONE X
-    event with the counters as args; ``span`` is its older name."""
+    event with the counters as args."""
     t = [0.0]
     tel = Telemetry(clock=lambda: t[0])
     with tel.phase("serve/launch", 5, k=2, live_tokens=40):
@@ -110,7 +109,6 @@ def test_phase_records_one_x_event_with_its_args():
         {"ph": "X", "name": "serve/launch", "tid": 5, "ts": 0.0,
          "dur": pytest.approx(4000.0),
          "args": {"k": 2, "live_tokens": 40}}]
-    assert Telemetry.span is Telemetry.phase
     with tel.phase("train/data"):
         pass
     assert tel.events[-1]["name"] == "train/data"
@@ -271,13 +269,13 @@ def test_ring_buffer_bounded():
     assert tel.events[0]["args"]["step"] == 92    # oldest dropped
 
 
-def test_span_nests_and_exports_chrome_trace(tmp_path):
+def test_phase_nests_and_exports_chrome_trace(tmp_path):
     t = [0.0]
     tel = Telemetry(clock=lambda: t[0])
     tel.name_track(0, "engine")
     tel.begin("request", 1, ts_us=0.0, request="r1")
     t[0] = 0.001
-    with tel.span("work", 1, request="r1"):
+    with tel.phase("work", 1, request="r1"):
         t[0] = 0.002
     t[0] = 0.003
     tel.end("request", 1, ts_us=tel.now_us(), request="r1")
@@ -732,3 +730,158 @@ def test_trace_check_cli_smoke(tmp_path):
                        capture_output=True, text=True)
     assert r.returncode == 1
     assert "expected >= 2" in r.stderr
+
+
+# ---------------------------------------------------------------------------
+# the set-up record: spans, builds, stage intervals as a union
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def fresh_record(monkeypatch):
+    """A record of this test's own: the process's ONE listener forwards
+    to whichever record is current."""
+    from replicatinggpt_tpu.utils import telemetry as T
+    T.setup_record()
+    rec = T.SetupRecord()
+    monkeypatch.setattr(T, "_RECORD", rec)
+    return rec
+
+
+def test_interval_union_counts_nested_and_overlapping_once():
+    from replicatinggpt_tpu.utils.telemetry import IntervalUnion
+    u = IntervalUnion()
+    for a, b in [(1.0, 1.4), (0.5, 3.5),        # inner first, then outer
+                 (5.0, 6.0), (5.5, 7.0), (4.0, 4.5), (3.0, 4.2)]:
+        u.add(a, b)
+    assert u.iv == [(0.5, 4.5), (5.0, 7.0)]
+    assert u.seconds == pytest.approx(6.0)
+
+
+def test_a_jit_traced_inside_another_is_counted_once(fresh_record):
+    """An inner ``jax.jit`` traced inside its caller fires its own trace
+    event INSIDE the caller's: each function keeps its own seconds, the
+    set-up total is their union (the outer's interval), not their sum."""
+    import jax.numpy as jnp
+    from replicatinggpt_tpu.utils.telemetry import setup_phase
+
+    @jax.jit
+    def inner_of_setup_test(x):
+        return jnp.sin(x) * 2.0
+
+    @jax.jit
+    def outer_of_setup_test(x):
+        return inner_of_setup_test(x) + jnp.cos(inner_of_setup_test(x + 1))
+
+    x = jnp.ones(8)
+    with setup_phase("setup/test"):
+        outer_of_setup_test(x).block_until_ready()
+    by_fun = fresh_record.trace_by_fun
+    outer, inner = (by_fun["outer_of_setup_test"],
+                    by_fun["inner_of_setup_test"])
+    assert inner > 0 and outer > inner
+    total = fresh_record.summary()["trace_s"]
+    assert outer <= total + 1e-6
+    assert total < outer + inner      # the inner one is not counted twice
+    assert fresh_record.summary()["process"]["trace_s"] >= total
+
+
+def test_a_stage_interval_belongs_to_the_innermost_open_span(fresh_record):
+    """Stage events are assigned to the innermost open set-up span; one
+    outside every span is not set-up unless a build holds it."""
+    from replicatinggpt_tpu.utils import telemetry as T
+    ev = "/jax/core/compile/jaxpr_trace_duration"
+    T._on_duration(ev, 0.001, fun_name="outside")
+    with T.setup_phase("setup/outer"):
+        T._on_duration(ev, 0.001, fun_name="in_outer")
+        with T.setup_phase("setup/inner", n=3):
+            T._on_duration(ev, 0.001, fun_name="in_inner")
+    rec = fresh_record
+    assert set(rec.trace_by_fun) == {"in_outer", "in_inner"}
+    by_span = rec.summary()["stages_by_span"]
+    assert set(by_span) == {"setup/outer", "setup/inner"}
+    assert by_span["setup/inner"]["trace_s"] > 0
+    assert [(n, p, st) for n, p, _, _, st in rec.spans] == [
+        ("setup/inner", "setup/outer", {"n": 3}), ("setup/outer", None, {})]
+    assert rec.open == []
+    # a build claims the stages that ran inside it, in no span
+    t0 = rec.clock()
+    T._on_duration(ev, 0.0, fun_name="in_build")
+    rec.record_build("serve/test", t0, rec.clock())
+    assert "in_build" in rec.trace_by_fun
+    assert rec.builds[0][0] == "serve/test" and rec.n_builds == 1
+
+
+def test_the_setup_record_stays_bounded():
+    """Totals always; the first ``keep`` spans and builds, the rest
+    counted as dropped; the union and the intervals a build may claim
+    held to their caps."""
+    from replicatinggpt_tpu.utils.telemetry import SetupRecord
+    t = [0.0]
+    rec = SetupRecord(keep=8, clock=lambda: t[0])
+    ev = "/jax/core/compile/backend_compile_duration"
+    for i in range(2000):
+        t[0] = 10.0 * i + 1.0
+        rec.open.append("setup/x")
+        rec.on_stage(ev, 0.5, fun_name=f"f{i}")
+        rec.open.pop()
+        rec.record_span("setup/x", None, 10.0 * i, t[0], {})
+        rec.record_build("serve/x", 10.0 * i, t[0])
+    assert len(rec.spans) == len(rec.builds) == 8
+    assert rec.dropped == {"spans": 1992, "builds": 1992}
+    assert len(rec.setup["compile"].iv) <= rec.setup["compile"].cap
+    assert len(rec._recent) <= rec._recent.maxlen
+    s = rec.summary()
+    assert s["compile_s"] == pytest.approx(1000.0)
+    assert s["build_s"] == pytest.approx(2000.0) and s["builds"] == 2000
+
+
+def test_null_phase_leaves_the_setup_record_alone(fresh_record):
+    """``NULL.phase`` is unchanged: the bare annotation, no set-up span."""
+    with NULL.phase("serve/step"):
+        pass
+    assert fresh_record.spans == [] and fresh_record.open == []
+    assert isinstance(NULL.phase("x"), jax.profiler.TraceAnnotation)
+
+
+def test_engine_setup_summary_and_builds(params, fresh_record):
+    """After a drain, ``metrics_summary()["setup"]`` holds the engine's
+    construction and a build of ``serve/decode`` and ``serve/prefill``
+    (shapes no other test uses, so the programs are this engine's to
+    build); a second identical drain adds no build. The totals are also
+    the ``tpu_gpt_setup_*`` gauges."""
+    eng = Engine(params, CFG, EngineConfig(pool_size=5, max_queue=8,
+                                           prefill_chunk=8))
+    for rnd in range(2):
+        for i in range(3):
+            assert eng.submit(_greedy(f"r{rnd}{i}", [1 + i, 2, 3])) is None
+        eng.drain()
+        s = eng.metrics_summary()["setup"]
+        if rnd == 0:
+            first = dict(s["build_s_by_guard"]), s["builds"]
+    assert s["engine_s"] > 0
+    assert {"serve/decode", "serve/prefill"} <= set(first[0])
+    assert (dict(s["build_s_by_guard"]), s["builds"]) == first
+    names = {n for n, *_ in fresh_record.spans}
+    assert {"setup/engine", "setup/weights_ready", "setup/served_tree",
+            "setup/pool", "setup/warm_programs"} <= names
+    txt = prometheus_text(eng.metrics)
+    for k in ("engine_s", "build_s", "trace_s", "lower_s", "compile_s"):
+        assert f"tpu_gpt_setup_{k} " in txt
+
+
+def test_trainer_records_its_state_and_the_steps_build(fresh_record):
+    """``setup/train_state`` around the state's init and placement, and
+    the step's first call a build."""
+    import dataclasses as dc
+    from replicatinggpt_tpu.config import get_config
+    from replicatinggpt_tpu.train.runner import train
+    from replicatinggpt_tpu.utils.logging import StepLogger
+    cfg = get_config("test-tiny")
+    cfg = cfg.replace(tokenizer="char", train=dc.replace(
+        cfg.train, max_iters=3, eval_interval=0, eval_iters=1,
+        log_interval=1, batch_size=3))
+    train(cfg, logger=StepLogger(quiet=True))
+    assert "setup/train_state" in fresh_record.span_s
+    assert {"train/step", "train/scan"} & set(fresh_record.build_s)
+    s = fresh_record.summary()
+    assert s["build_s"] > 0 and s["compile_s"] > 0
