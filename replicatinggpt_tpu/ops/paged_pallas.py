@@ -16,7 +16,8 @@ slot's own live blocks (``_walk_blocks``: one ``fori_loop`` of traced
 length over the list ``_blocked_walk`` compacts for the slot), each
 copied, scattered as its pages lie in the pool, into one half of a
 (2, P * page, C) VMEM double buffer, so the arithmetic reads it as ONE
-tile: each head's pass runs once over the block's columns, not once a
+tile: ONE pass of all of the slot's heads runs once over the block's
+columns, not once a head or a
 page. Until PR 39 the block axis was the grid's second (B,
 ceil(max_pages / P)) and a grid step cost its turn whether or not it
 had work: 1.0-1.4 ms of a gpt2-large launch (3.5 ms at no live
@@ -43,8 +44,12 @@ inner ``jax.jit`` (``_window_call``, ``_gqa_call``), so a program whose
 layers are a Python loop holds a kernel once a layer KIND.
 
 Packed (page, C) layout only: heads are static lane slices of the
-fully-packed row (no D-minor tile padding in the stream), taken as
-many to a 128-lane slab as divide the head count (``_heads_per_slab``).
+fully-packed row (no D-minor tile padding in the stream). A block's
+step is ONE pass for all of a slot's query rows (``heads_per_pass``):
+each head's rows zero outside its own lanes, one score product over
+the whole row, one masked online update, one value product: a block's
+cost follows the number of its passes (each a chain of dependent MXU
+and vector steps), not its bytes or its rows.
 Gated to TPU (`_paged_attn_backend_ok`, monkeypatched by tests to
 exercise the interpreter on CPU) and to shapes inside
 `paged_decode_supported`.
@@ -73,6 +78,13 @@ PAGED_DECODE_BYTES = 4 * 1024 * 1024
 # a block holds at least this many tokens of a slot (table and budget
 # allowing): the lane width of a score tile
 BLOCK_TOKENS = LANES
+
+# VMEM share of a turn's stacked query rows (float32, and again in the
+# MXU's dtype) and float32 accumulator: 12 B a row a lane. A block's
+# step is ONE pass over the whole row where they fit (gpt2-large's 20
+# rows x 1,280 lanes at W = 1 take 0.3 MiB, at W = 8 2.5 MiB: two passes
+# of 10 heads); ``heads_per_pass`` splits a wider window into whole heads.
+PASS_STATE_BYTES = 2 * 1024 * 1024
 
 
 def _paged_attn_backend_ok() -> bool:
@@ -281,12 +293,26 @@ def window_walk(tables: jnp.ndarray, pos: jnp.ndarray, page_size: int,
     return _blocked_walk(tables, owned, page_size, row_bytes)
 
 
-def _heads_per_slab(n_head: int, head_dim: int) -> int:
-    """Heads taken together in one lane slab of the packed row: as many
-    as fill 128 lanes and divide the head count (2 of gpt2's 64-wide
-    heads; 1 where the count is odd or a head is 128 wide or more)."""
-    return max(h for h in range(1, max(LANES // head_dim, 1) + 1)
-               if n_head % h == 0)
+def heads_per_pass(n_head: int, rows: int, head_dim: int) -> int:
+    """Heads one pass over a block takes together: the most that divide
+    ``n_head`` and keep the stacked query rows (float32 and the MXU's
+    dtype) and the float32 accumulator of all passes, ``n_head * rows``
+    rows of a pass's lanes, inside ``PASS_STATE_BYTES`` (``rows``: a
+    head's query rows, W, or G * W for grouped queries). Every head of
+    every cell in one pass; a wide window splits into whole heads.
+    Follows from shapes: nothing configures it."""
+    fit = PASS_STATE_BYTES // (12 * n_head * rows * head_dim)
+    return max(h for h in range(1, n_head + 1)
+               if n_head % h == 0 and (h == 1 or h <= fit))
+
+
+def block_passes(n_head: int, head_dim: int, window: int = 1,
+                 n_kv_head=None) -> int:
+    """Passes a block of a slot's loop takes: one score product, one
+    masked online update and one value product each (the engine's
+    ``kv_block_passes`` is the live blocks times this)."""
+    n_kv = n_kv_head or n_head
+    return n_kv // heads_per_pass(n_kv, n_head // n_kv * window, head_dim)
 
 
 # -- what a block step does, shared by both kernels --------------------------
@@ -370,8 +396,9 @@ def _walk_blocks(walk, pools, bufs, sem, n_block: int, n_blocks: int,
 
 def _scores(q, k, mask, scale):
     """(R, T) float32 scores of q rows (R, L) on k rows (T, L), NEG_INF
-    where ``mask`` is off. Operands of one dtype go to the MXU as they
-    are (bf16 products are exact in the float32 accumulator)."""
+    where ``mask`` is off; ``scale`` a number or a tile that broadcasts
+    to (R, T). Operands of one dtype go to the MXU as they are (bf16
+    products are exact in the float32 accumulator)."""
     if q.dtype != k.dtype:
         q, k = q.astype(jnp.float32), k.astype(jnp.float32)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
@@ -379,10 +406,12 @@ def _scores(q, k, mask, scale):
     return jnp.where(mask, s, NEG_INF)
 
 
-def _online_update(s, v, acc_ref, m_ref, l_ref, i: int):
+def _online_update(s, v, acc_ref, m_ref, l_ref, i: int, v_scale=None):
     """One online-softmax step of state ``i``: fold masked scores ``s``
     (R, T) and values ``v`` (T, L) into acc (R, L) and the running max
-    and denominator (R, LANES; every lane the same)."""
+    and denominator (R, LANES; every lane the same). ``v_scale`` (R, T)
+    or (1, T) weighs the value product's probabilities, not the
+    denominator's: a quantized tile's scale of a row's own head."""
     m_prev = m_ref[i]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
@@ -390,6 +419,8 @@ def _online_update(s, v, acc_ref, m_ref, l_ref, i: int):
     # fully-masked row m_new stays NEG_INF and s - m_new == 0
     pexp = jnp.where(s > NEG_INF / 2, jnp.exp(s - m_new[:, :1]), 0.0)
     l_ref[i] = l_ref[i] * alpha + jnp.sum(pexp, axis=1, keepdims=True)
+    if v_scale is not None:
+        pexp = pexp * v_scale
     acc_ref[i] = (acc_ref[i] * alpha[:, :1]
                   + jax.lax.dot_general(
                       pexp, v.astype(jnp.float32),
@@ -413,16 +444,18 @@ def _paged_window_kernel(*refs, n_head, head_dim, page_size, n_block,
     subset under the shard_map wrapper), their columns masked page by
     page by it and to positions < pos — identical for every query row,
     since rows 0..W-1 attend the fresh window via the causal fold.
-    Heads go ``_heads_per_slab`` to a lane slab: a slab's heads stack
-    their W rows into one (heads * W, slab) query block, each head's
-    rows zero outside its own lanes, so ONE product scores the slab's
-    heads over the block's columns and one more weighs its values; of a
-    row's accumulator only its own head's lanes are ever read.
-    Quantized pools bring the slot's (table entries * page, 1)
-    page-granularity or (.., H) head-granularity scales as one more row
-    operand, cut by block inside the loop; the per-head column dequants
-    the tile before the product (int8 AND fp8
-    — the e4m3 block ``astype``s to f32 like any other storage dtype).
+    The turn stacks the W rows of every head into ONE (heads * W, C)
+    query block, head h's rows zero outside its own lanes, so ONE
+    product scores all of the slot's heads over the block's columns, ONE
+    masked online update folds them and ONE more product weighs the
+    whole value tile; of a row's accumulator only its own head's lanes
+    are ever read. A wide window splits the heads into whole-head passes
+    (``heads_per_pass``), each over its own lanes.
+    Quantized pools bring the slot's scales as one more operand, a
+    block's (1 or H, n_block * page) tile a token a lane: the scores and
+    the value product's probabilities of a row take its own head's
+    scale, the tiles go to the product as stored (int8 AND fp8 — the
+    e4m3 block ``astype``s to f32 like any other storage dtype).
 
     ``fold=True`` folds the fresh causal (W, W) block after the loop and
     writes normalized output; ``fold=False`` emits the raw
@@ -434,77 +467,118 @@ def _paged_window_kernel(*refs, n_head, head_dim, page_size, n_block,
     if quantized:
         ksc_ref, vsc_ref, *refs = refs
     k_hbm, v_hbm, *refs = refs
-    *outs, qs_ref, acc_ref, m_ref, l_ref, k_buf, v_buf, sem = refs
+    *outs, q32_ref, qs_ref, acc_ref, m_ref, l_ref, k_buf, v_buf, sem = refs
     pools, bufs = (k_hbm, v_hbm), (k_buf, v_buf)
     b = pl.program_id(0)
-    D, psz, W, P = head_dim, page_size, window, n_block
-    hps = _heads_per_slab(n_head, D)
-    SL = hps * D                              # lanes of a slab
-    slabs = [slice(i * SL, (i + 1) * SL) for i in range(n_head // hps)]
+    D, psz, W, T = head_dim, page_size, window, n_block * page_size
+    n_pass, R, L = acc_ref.shape         # R = hpp * W rows of L lanes
+    hpp = L // D
+    passes = [slice(i * L, (i + 1) * L) for i in range(n_pass)]
     pos = pos_ref[b]
-    # one dtype on both sides: the MXU takes the stored bf16 as it is
-    native = not quantized and q_ref.dtype == bufs[0].dtype
-    q_dtype = q_ref.dtype if native else jnp.float32
+    _clear_state(acc_ref, m_ref, l_ref)
+    if W == 1:       # a head's one row: the slot's row, its head's lanes
+        mine = (jax.lax.broadcasted_iota(jnp.int32, (R, L), 0)
+                == jax.lax.broadcasted_iota(jnp.int32, (R, L), 1) // D)
+        for i, lanes in enumerate(passes):
+            q = jnp.where(mine, q_ref[:, lanes].astype(jnp.float32), 0.0)
+            q32_ref[i] = q
+            qs_ref[i] = q.astype(qs_ref.dtype)
+    else:
+        _stack_rows(q32_ref, qs_ref, [
+            (i, r * W, r * D, q_ref[:, h * D:(h + 1) * D])
+            for h in range(n_head) for i, r in [divmod(h, hpp)]])
 
-    def lane_head(rows):                      # the head a lane belongs to
-        return jax.lax.broadcasted_iota(jnp.int32, (rows, SL), 1) // D
-
-    acc_ref[...] = jnp.zeros_like(acc_ref)
-    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-    l_ref[...] = jnp.zeros_like(l_ref)
-    for i, sl in enumerate(slabs):
-        q = q_ref[:, sl].astype(jnp.float32)                     # (W, SL)
-        for r in range(hps):
-            qs_ref[i, r * W:(r + 1) * W, :] = (
-                q if hps == 1 else jnp.where(lane_head(W) == r, q, 0.0))
+    def own_head(sc, i):     # scales (1 or H, T) -> a row's own head's
+        if not head_gran:
+            return sc
+        head = jax.lax.broadcasted_iota(jnp.int32, (R, T), 0) // W
+        return functools.reduce(
+            lambda a, r: jnp.where(head == r, sc[i * hpp + r:
+                                                 i * hpp + r + 1], a),
+            range(1, hpp), sc[i * hpp:i * hpp + 1])
 
     def _accumulate(p, half, owned_cols):
-        kpos = (jax.lax.broadcasted_iota(jnp.int32, (1, P * psz), 1)
-                + p * (P * psz))
-        mask = owned_cols() & (kpos < pos)                   # (1, P * psz)
-        if quantized:   # the block's rows of the slot's: (P * psz, 1 or H)
-            rows = pl.ds(pl.multiple_of(p * (P * psz), P * psz), P * psz)
-            ksc, vsc = ksc_ref[rows, :], vsc_ref[rows, :]
-        for i, sl in enumerate(slabs):
-            k, v = bufs[0][half, :, sl], bufs[1][half, :, sl]
-            if quantized:
-                def col(sc):             # a lane's own head's scale
-                    if not head_gran:
-                        return sc
-                    return functools.reduce(
-                        lambda a, r: jnp.where(
-                            lane_head(P * psz) == r,
-                            sc[:, i * hps + r:i * hps + r + 1], a),
-                        range(1, hps), sc[:, i * hps:i * hps + 1])
-                k = k.astype(jnp.float32) * col(ksc)
-                v = v.astype(jnp.float32) * col(vsc)
-            s = _scores(qs_ref[i].astype(q_dtype), k, mask, scale)
-            _online_update(s, v, acc_ref, m_ref, l_ref, i)
+        kpos = jax.lax.broadcasted_iota(jnp.int32, (1, T), 1) + p * T
+        mask = owned_cols() & (kpos < pos)                   # (1, T)
+        for i, lanes in enumerate(passes):
+            k, v = bufs[0][half, :, lanes], bufs[1][half, :, lanes]
+            if quantized:            # the block's scales: (1 or H, T)
+                s = _scores(qs_ref[i], k, mask,
+                            scale * own_head(ksc_ref[p], i))
+                _online_update(s, v, acc_ref, m_ref, l_ref, i,
+                               own_head(vsc_ref[p], i))
+            else:
+                _online_update(_scores(qs_ref[i], k, mask, scale), v,
+                               acc_ref, m_ref, l_ref, i)
 
-    _walk_blocks(walk, pools, bufs, sem, P, n_blocks, psz, _accumulate)
+    _walk_blocks(walk, pools, bufs, sem, n_block, n_blocks, psz,
+                 _accumulate)
 
-    R = hps * W
     row_j = jax.lax.broadcasted_iota(jnp.int32, (R, W), 0) % W
     col = jax.lax.broadcasted_iota(jnp.int32, (R, W), 1)
     causal = col <= row_j              # fresh row j attends rows 0..j
-    for i, sl in enumerate(slabs):
+    for i, lanes in enumerate(passes):
         if fold:
             # denominator >= the diagonal term > 0 always (row j
             # attends itself)
-            s_new = _scores(qs_ref[i], knew_ref[:, sl], causal, scale)
-            _online_update(s_new, vnew_ref[:, sl], acc_ref, m_ref, l_ref, i)
-        for r in range(hps):           # a head's rows, its own lanes
-            h = i * hps + r
-            rows, lanes = slice(r * W, (r + 1) * W), slice(h * D,
-                                                           (h + 1) * D)
+            s_new = _scores(q32_ref[i], knew_ref[:, lanes], causal, scale)
+            _online_update(s_new, vnew_ref[:, lanes], acc_ref, m_ref, l_ref,
+                           i)
+        if W == 1 and fold:  # each lane from its own head's one row: a
+            # masked sum down the rows and ONE store, not a store a head
+            outs[0][:, lanes] = jnp.sum(
+                jnp.where(mine, acc_ref[i] / l_ref[i][:, :1], 0.0), axis=0,
+                keepdims=True).astype(outs[0].dtype)
+            continue
+        for r in range(hpp):           # a head's rows, its own lanes
+            h = i * hpp + r
+            rows, own = slice(r * W, (r + 1) * W), slice(h * D, (h + 1) * D)
             acc = acc_ref[i, rows, r * D:(r + 1) * D]
             if fold:
-                outs[0][:, lanes] = (acc / l_ref[i, rows, :1]).astype(
+                outs[0][:, own] = (acc / l_ref[i, rows, :1]).astype(
                     outs[0].dtype)
             else:
-                outs[0][:, lanes] = acc
+                outs[0][:, own] = acc
                 outs[1][:, h:h + 1] = m_ref[i, rows, h:h + 1]
                 outs[2][:, h:h + 1] = l_ref[i, rows, h:h + 1]
+
+
+def _clear_state(acc_ref, m_ref, l_ref) -> None:
+    """A turn's start: the online-softmax state of every pass cleared."""
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+
+def _stack_rows(q32_ref, qs_ref, pieces) -> None:
+    """The slot's query rows stacked a pass at a time, each head's rows
+    zero outside its own lanes. ``pieces`` are ``(pass, row, lane,
+    rows)``: a head's query rows and where they go. ``q32_ref`` keeps
+    them in float32 for the fresh window's fold, ``qs_ref`` in the dtype
+    the block products take (cast once a turn, not once a block)."""
+    q32_ref[...] = jnp.zeros_like(q32_ref)
+    for i, r0, c0, q in pieces:
+        n, d = q.shape
+        q32_ref[i, r0:r0 + n, c0:c0 + d] = q.astype(jnp.float32)
+    qs_ref[...] = q32_ref[...].astype(qs_ref.dtype)
+
+
+def _state_scratch(n_head: int, rows: int, head_dim: int,
+                   mxu_dtype) -> list:
+    """A turn's state, ``(passes, heads_per_pass * rows, lanes)`` each:
+    the stacked query rows in float32 and in ``mxu_dtype`` (the pool's
+    own dtype where the query has it: the MXU takes the stored bf16 as it
+    is), the float32 accumulator, and the running max and denominator a
+    row (``LANES`` wide, every lane the same). ``n_head`` heads of
+    ``rows`` query rows and ``head_dim`` lanes each."""
+    hpp = heads_per_pass(n_head, rows, head_dim)
+    state = (n_head // hpp, hpp * rows)
+    lanes = hpp * head_dim
+    return [pltpu.VMEM((*state, lanes), jnp.float32),
+            pltpu.VMEM((*state, lanes), mxu_dtype),
+            pltpu.VMEM((*state, lanes), jnp.float32),
+            pltpu.VMEM((*state, LANES), jnp.float32),
+            pltpu.VMEM((*state, LANES), jnp.float32)]
 
 
 def _pool_operands(arrays, n_block: int) -> tuple:
@@ -599,20 +673,18 @@ def _window_call(q, k_new, v_new, k_pages, v_pages, tables, pos, layer,
     if quantized:
         # a pool's scales are a 64th of its bytes or less: XLA gathers
         # each slot's by (layer, page) in the order of its (padded)
-        # table, and they arrive as ONE (table entries * page, width) row
-        # operand a slot, which the loop cuts by block
-        swidth = n_head if head_gran else 1
-        in_specs += [_vmem_spec((None, nb * P * psz, swidth), row_map)] * 2
+        # table, and they arrive as ONE (blocks, width, block tokens)
+        # operand a slot, a token a lane, which the loop indexes by block
+        T, swidth = P * psz, n_head if head_gran else 1
+        in_specs += [_vmem_spec((None, nb, swidth, T),
+                                lambda b, *_: (b, 0, 0, 0))] * 2
         with jax.named_scope("kv_gather"):
-            inputs += [sc[layer, jnp.maximum(walk[0], 0)].reshape(
-                B, nb * P * psz, swidth) for sc in (k_scales, v_scales)]
+            inputs += [jnp.swapaxes(sc[layer, jnp.maximum(walk[0], 0)]
+                                    .reshape(B, nb, T, swidth), 2, 3)
+                       for sc in (k_scales, v_scales)]
     pool_specs, pool_scratch = _pool_operands([k_pages, v_pages], P)
-    hps = _heads_per_slab(n_head, D)
-    state = (n_head // hps, hps * W)
-    scratch = [pltpu.VMEM((*state, hps * D), jnp.float32),    # q slabs
-               pltpu.VMEM((*state, hps * D), jnp.float32),    # acc
-               pltpu.VMEM((*state, LANES), jnp.float32),      # m
-               pltpu.VMEM((*state, LANES), jnp.float32)]      # l
+    scratch = _state_scratch(n_head, W, D, q.dtype if not quantized
+                             and q.dtype == k_pages.dtype else jnp.float32)
     if fold:
         out_specs = row
         out_shape = jax.ShapeDtypeStruct((B, W, C), q.dtype)
@@ -770,8 +842,10 @@ def sharded_paged_window_attention(q: jnp.ndarray, k_new: jnp.ndarray,
 # The family above has one head count on both sides (C = n_head * D for q
 # and for a page's row). A grouped-query model's page row is n_kv_head * D
 # wide and G = n_head // n_kv_head query heads read each KV head, so the
-# kernel below stacks a group's G * W query rows into ONE (G*W, D) block a
-# KV head: a block costs n_kv_head products, not n_head. ``attn_window``
+# kernel below stacks every KV head's G * W query rows into ONE
+# (n_kv_head * G * W, n_kv_head * D) block, a group's rows zero outside
+# its KV head's lanes: a block costs one score product and one value
+# product over the whole row, as in the family above. ``attn_window``
 # bounds the positions a row reads from below (row j at position pos + j
 # attends k with pos + j - window < k <= pos + j): pages wholly behind the
 # bound are unowned and skipped exactly like pages past the frontier, at
@@ -786,44 +860,51 @@ def _paged_gqa_kernel(*refs, n_kv_head, head_dim, page_size, n_block,
                       n_blocks, window, attn_window, scale):
     walk = refs[:N_WALK]
     (pos_ref, page0_ref, q_ref, knew_ref, vnew_ref, k_hbm, v_hbm, out_ref,
-     acc_ref, m_ref, l_ref, k_buf, v_buf, sem) = refs[N_WALK:]
+     q32_ref, qs_ref, acc_ref, m_ref, l_ref, k_buf, v_buf,
+     sem) = refs[N_WALK:]
     pools, bufs = (k_hbm, v_hbm), (k_buf, v_buf)
     b = pl.program_id(0)
-    D, psz, W, P = head_dim, page_size, window, n_block
+    D, psz, W, T = head_dim, page_size, window, n_block * page_size
     GW = q_ref.shape[1]                       # G * W rows a KV head
+    n_pass, R, L = acc_ref.shape              # R = KV heads a pass * GW
+    hk = L // D
+    passes = [slice(i * L, (i + 1) * L) for i in range(n_pass)]
     pos = pos_ref[b]
-    heads = [slice(g * D, (g + 1) * D) for g in range(n_kv_head)]
-    # row r of a group's block is query row j = r % W of the window
-    row_j = jax.lax.broadcasted_iota(jnp.int32, (GW, 1), 0) % W
-
-    acc_ref[...] = jnp.zeros_like(acc_ref)
-    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-    l_ref[...] = jnp.zeros_like(l_ref)
+    # row r of a pass is query row j = r % W of the window
+    row_j = jax.lax.broadcasted_iota(jnp.int32, (R, 1), 0) % W
+    _clear_state(acc_ref, m_ref, l_ref)
+    _stack_rows(q32_ref, qs_ref, [
+        (i, r * GW, r * D, q_ref[g])
+        for g in range(n_kv_head) for i, r in [divmod(g, hk)]])
 
     def _accumulate(p, half, owned_cols):
-        kpos = (jax.lax.broadcasted_iota(jnp.int32, (1, P * psz), 1)
-                + (page0_ref[b] + p * P) * psz)
-        mask = owned_cols() & (kpos < pos)                   # (1, P * psz)
+        kpos = (jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
+                + (page0_ref[b] + p * n_block) * psz)
+        mask = owned_cols() & (kpos < pos)                   # (1, T)
         if attn_window:
-            mask = mask & (kpos > pos + row_j - attn_window)  # (GW, P * psz)
-        for g, sl in enumerate(heads):
-            s = _scores(q_ref[g], bufs[0][half, :, sl], mask, scale)
-            _online_update(s, bufs[1][half, :, sl], acc_ref, m_ref, l_ref,
-                           g)
+            mask = mask & (kpos > pos + row_j - attn_window)  # (R, T)
+        for i, lanes in enumerate(passes):
+            s = _scores(qs_ref[i], bufs[0][half, :, lanes], mask, scale)
+            _online_update(s, bufs[1][half, :, lanes], acc_ref, m_ref,
+                           l_ref, i)
 
-    _walk_blocks(walk, pools, bufs, sem, P, n_blocks, psz, _accumulate)
+    _walk_blocks(walk, pools, bufs, sem, n_block, n_blocks, psz,
+                 _accumulate)
 
-    col = jax.lax.broadcasted_iota(jnp.int32, (GW, W), 1)
+    col = jax.lax.broadcasted_iota(jnp.int32, (R, W), 1)
     fresh = col <= row_j               # row j attends fresh rows 0..j
     if attn_window:
         fresh = fresh & (col > row_j - attn_window)
-    for g, sl in enumerate(heads):
+    for i, lanes in enumerate(passes):
         # denominator >= the diagonal term > 0 always (row j attends
         # itself)
-        s_new = _scores(q_ref[g].astype(jnp.float32), knew_ref[:, sl],
-                        fresh, scale)
-        _online_update(s_new, vnew_ref[:, sl], acc_ref, m_ref, l_ref, g)
-        out_ref[g] = (acc_ref[g] / l_ref[g][:, :1]).astype(out_ref.dtype)
+        s_new = _scores(q32_ref[i], knew_ref[:, lanes], fresh, scale)
+        _online_update(s_new, vnew_ref[:, lanes], acc_ref, m_ref, l_ref, i)
+        for r in range(hk):            # a KV head's rows, its own lanes
+            rows = slice(r * GW, (r + 1) * GW)
+            out_ref[i * hk + r] = (acc_ref[i, rows, r * D:(r + 1) * D]
+                                   / l_ref[i, rows, :1]).astype(
+                                       out_ref.dtype)
 
 
 @jax.named_scope("kv_gather")
@@ -902,16 +983,14 @@ def _gqa_call(q, k_new, v_new, k_pages, v_pages, tables, pos, layer, page0,
     qspec = _vmem_spec((None, n_kv_head, G * W, D), q_map)
     row = _vmem_spec((None, W, Ckv), row_map)
     pool_specs, pool_scratch = _pool_operands([k_pages, v_pages], P)
-    state = (n_kv_head, G * W)
+    scratch = _state_scratch(n_kv_head, G * W, D, q.dtype
+                             if q.dtype == k_pages.dtype else jnp.float32)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=N_WALK + 2,
         grid=(B,),
         in_specs=[qspec, row, row, *pool_specs],
         out_specs=qspec,
-        scratch_shapes=[pltpu.VMEM((*state, D), jnp.float32),
-                        pltpu.VMEM((*state, LANES), jnp.float32),
-                        pltpu.VMEM((*state, LANES), jnp.float32)]
-        + pool_scratch,
+        scratch_shapes=scratch + pool_scratch,
     )
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,

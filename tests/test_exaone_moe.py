@@ -247,6 +247,13 @@ def _einsum_attention(q, kn, vn, kp, vp, tables, pos, Hq, Hkv, window,
     return out
 
 
+# query heads, KV heads and head size: KV heads of 32 lanes, and LFM2's
+# geometry in small (KV heads of 64 at 64-lane offsets, 4 query heads a KV
+# head): a block is ONE pass over the whole row in both
+GQA_HEAD_IDS = {"kv32": (4, 2, 32), "kv64": (16, 4, 64)}
+GQA_HEADS = list(GQA_HEAD_IDS.values())
+
+
 @pytest.mark.parametrize("W,window,pos,mp", [
     (1, 0, [0, 5, 37], 6),       # full layer: idle slot, mid page, deep
     (1, 8, [3, 8, 41], 6),       # window = 1 page; inside the first window
@@ -269,8 +276,11 @@ def _einsum_attention(q, kn, vn, kp, vp, tables, pos, Hq, Hkv, window,
         "blocks-full", "blocks-window", "blocks-two-rows",
         "blocks-live-between-idle", "blocks-all-live-w8",
         "blocks-window-second-block-only"])
-def test_gqa_kernel_and_its_lower_bound_against_einsum(W, window, pos, mp):
-    Hq, Hkv, D, psz = 4, 2, 32, 8
+@pytest.mark.parametrize("heads", GQA_HEADS, ids=list(GQA_HEAD_IDS))
+def test_gqa_kernel_and_its_lower_bound_against_einsum(W, window, pos, mp,
+                                                       heads):
+    Hq, Hkv, D = heads
+    psz = 8
     assert (paged_pallas.block_pages(psz, mp, Hkv * D * 4) < mp) == (mp > 16)
     q, kn, vn, kp, vp, tables, pos = _kernel_case(3, 3, W, Hq, Hkv, D, psz,
                                                   mp, pos, window)
@@ -295,11 +305,12 @@ def test_gqa_kernel_and_its_lower_bound_against_einsum(W, window, pos, mp):
     (16, 9, 128, [0, 1000, 0, 0, 140], "float32", 1e-5),
 ], ids=["ring-of-3", "ring-of-9-in-two-blocks", "ring-of-9-bf16",
         "ring-of-9-live-between-idle"])
+@pytest.mark.parametrize("heads", GQA_HEADS, ids=list(GQA_HEAD_IDS))
 def test_gqa_kernel_walks_a_ring_from_page0(psz, mp, window, pos, dtype,
-                                            tol):
+                                            tol, heads):
     """A window layer's ring: the table's first entry is absolute page
     ``page0`` of the slot, not page 0."""
-    Hq, Hkv, D = 4, 2, 32
+    Hq, Hkv, D = heads
     page0 = [max(p - window + 1, 0) // psz for p in pos]
     *rows, tables, pos = _kernel_case(4, len(pos), 1, Hq, Hkv, D, psz, mp,
                                       pos, window)

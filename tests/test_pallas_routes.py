@@ -159,7 +159,18 @@ WALKS = {
     "every-block-live": (1, 16, 24, [384, 383]),
     "live-between-idle": (1, 16, 24, [0, 300, 0, 0, 40, 0]),
     "w8-live-between-idle": (8, 8, 20, [0, 0, 152, 0, 9]),
+    "six-heads-w1": (1, 16, 24, [200, 70, 0]),
+    "six-heads-w8": (8, 8, 20, [150, 128, 3, 0]),
 }
+
+# heads and head size of a walk's rows: two of 32 (one 64-lane row)
+# unless named here; six of 64 span three 128-lane slabs, which a block
+# takes in ONE pass all the same
+WALK_HEADS = {"six-heads-w1": (6, 64), "six-heads-w8": (6, 64)}
+
+
+def _heads(walk):
+    return WALK_HEADS.get(walk, (2, 32))
 
 
 # the pools are STACKED, three layers of different values, and every
@@ -172,7 +183,8 @@ def _window_inputs(seed=0, walk="one-block"):
     rng = np.random.default_rng(seed)
     W, psz, mp, pos = WALKS[walk]
     pos = np.array(pos, np.int32)          # incl. the fresh-only row
-    B, C = len(pos), 64
+    H, D = _heads(walk)
+    B, C = len(pos), H * D
     N = B * mp
     tables = rng.permutation(N).reshape(B, mp).astype(np.int32)
     mk = lambda *s: rng.standard_normal(s).astype(np.float32)  # noqa: E731
@@ -194,14 +206,15 @@ def test_blocked_walk_matches_gather_reference(walk_name, dtype, tol):
     slot that has one."""
     from replicatinggpt_tpu.ops import paged_pallas as pp
     q, kn, vn, kp, vp, tables, pos = _window_inputs(5, walk_name)
+    H = _heads(walk_name)[0]
     q, kn, vn, kp, vp = (np.asarray(jnp.asarray(a, dtype), np.float32)
                          for a in (q, kn, vn, kp, vp))
     out = pp.paged_window_attention(
         *(jnp.asarray(a, dtype) for a in (q, kn, vn, kp, vp)),
-        jnp.array(tables), jnp.array(pos), n_head=2, layer=LAYER)
+        jnp.array(tables), jnp.array(pos), n_head=H, layer=LAYER)
     assert out.dtype == jnp.dtype(dtype)
     ref = lambda l: _window_ref(q, kn, vn, kp[l], vp[l], tables,  # noqa: E731
-                                pos, 2)
+                                pos, H)
     np.testing.assert_allclose(np.asarray(out, np.float32), ref(LAYER),
                                atol=tol, rtol=tol)
     # the layers differ by far more than the tolerance: layer 0 would fail
@@ -218,7 +231,7 @@ def test_blocked_walk_matches_gather_reference(walk_name, dtype, tol):
     # gives that layer's output
     handed = pp.paged_window_attention(
         *(jnp.asarray(a, dtype) for a in (q, kn, vn, kp, vp)),
-        jnp.array(tables), jnp.array(pos), n_head=2, layer=2, walk=walk)
+        jnp.array(tables), jnp.array(pos), n_head=H, layer=2, walk=walk)
     np.testing.assert_allclose(np.asarray(handed, np.float32), ref(2),
                                atol=tol, rtol=tol)
     assert len(walk) + 1 == pp.N_WALK          # and the pool's layer
@@ -249,7 +262,8 @@ def test_blocked_walk_matches_gather_reference(walk_name, dtype, tol):
     ("int8", "page", "mid-block"), ("int8", "page", "live-between-idle"),
     ("int8", "head", "live-between-idle"),
     ("int8", "page", "w8-live-between-idle"),
-    ("int8", "head", "every-block-live")])
+    ("int8", "head", "every-block-live"), ("int8", "head", "six-heads-w1"),
+    ("fp8", "head", "six-heads-w8"), ("int8", "page", "six-heads-w8")])
 def test_windowed_kernel_quantized_parity(kv_dtype, gran, walk):
     """fp8 KV and head-granularity scales were the documented XLA
     seams — the per-head scale-lane selection and the saturating e4m3
@@ -261,7 +275,7 @@ def test_windowed_kernel_quantized_parity(kv_dtype, gran, walk):
     from replicatinggpt_tpu.quant.kv import (fake_quantize_rows,
                                              quantize_rows)
     q, kn, vn, kp, vp, tables, pos = _window_inputs(walk=walk)
-    H, D = 2, 32
+    H, D = _heads(walk)
     kq, ks = quantize_rows(jnp.array(kp), kv_dtype, H, gran)
     vq, vs = quantize_rows(jnp.array(vp), kv_dtype, H, gran)
     expand = (lambda s: np.asarray(s)[..., None] if gran == "page"
@@ -289,7 +303,8 @@ def test_windowed_kernel_quantized_parity(kv_dtype, gran, walk):
                                   "w8-short-last-block",
                                   "live-between-idle",
                                   "w8-live-between-idle",
-                                  "every-block-live"])
+                                  "every-block-live", "six-heads-w1",
+                                  "six-heads-w8"])
 def test_owned_subsets_partials_merge_to_the_reference(walk):
     """``owned`` hands a call an ARBITRARY subset of a slot's pages (a
     shard's, under the ``shard_map`` wrapper): its loop takes the blocks
@@ -299,7 +314,7 @@ def test_owned_subsets_partials_merge_to_the_reference(walk):
     takes no turn there) merge, the wrapper's way, to the reference."""
     from replicatinggpt_tpu.ops import paged_pallas as pp
     q, kn, vn, kp, vp, tables, pos = _window_inputs(seed=7, walk=walk)
-    H, (B, W, C) = 2, q.shape
+    H, (B, W, C) = _heads(walk)[0], q.shape
     psz, mp = kp.shape[2], tables.shape[1]
     prefix = np.asarray(pp.gqa_owned_pages(
         jnp.array(pos), jnp.zeros_like(pos), mp, psz, 0))
@@ -361,6 +376,114 @@ def test_sharded_window_kernel_matches_reference(walk):
                                rtol=1e-4)
 
 
+def _dots_in_block_loop(fn, *args) -> int:
+    """``dot_general``s inside the paged kernel's loop over a slot's
+    blocks (the top-level ``while`` of the kernel's body: the page
+    copies' loops inside it hold none), from the jaxpr of ``fn``."""
+    def subs(eqn):
+        for v in eqn.params.values():
+            for x in (v if isinstance(v, (tuple, list)) else (v,)):
+                j = getattr(x, "jaxpr", x)
+                if hasattr(j, "eqns"):
+                    yield j
+
+    def count(jaxpr):
+        return sum((e.primitive.name == "dot_general")
+                   + sum(count(j) for j in subs(e)) for e in jaxpr.eqns)
+
+    def kernels(jaxpr):
+        for e in jaxpr.eqns:
+            if e.primitive.name == "pallas_call":
+                yield e.params["jaxpr"]
+            for j in subs(e):
+                yield from kernels(j)
+
+    (body,) = kernels(jax.make_jaxpr(fn)(*args).jaxpr)
+    return sum(count(j) for e in body.eqns if e.primitive.name == "while"
+               for j in subs(e))
+
+
+def _abstract(*shapes_dtypes):
+    return [jax.ShapeDtypeStruct(s, d) for s, d in shapes_dtypes]
+
+
+# (n_head, n_kv_head, head_dim, W, pool dtype, attn_window, passes): the
+# cells' decode steps (gpt2-large, K-EXAONE's full and ring layers,
+# LFM2's full layers), a head-granularity int8 pool, three slabs of
+# heads at W = 8, and gpt2-large's W = 8, whose stacked rows split into
+# two passes of ten heads
+PASS_GEOMETRIES = {
+    "gpt2-large-w1": (20, 20, 64, 1, jnp.bfloat16, 0, 1),
+    "gpt2-large-w1-int8-head": (20, 20, 64, 1, jnp.int8, 0, 1),
+    "six-heads-w8": (6, 6, 64, 8, jnp.bfloat16, 0, 1),
+    "gpt2-large-w8": (20, 20, 64, 8, jnp.bfloat16, 0, 2),
+    "kexaone-full": (64, 8, 128, 1, jnp.bfloat16, 0, 1),
+    "kexaone-ring": (64, 8, 128, 1, jnp.bfloat16, 128, 1),
+    "lfm2-full": (32, 8, 64, 1, jnp.bfloat16, 0, 1),
+}
+
+
+@pytest.mark.parametrize("geometry", list(PASS_GEOMETRIES))
+def test_a_block_is_one_pass_for_all_of_a_slots_heads(geometry):
+    """A block's step is ONE score product and ONE value product over
+    the whole row, whatever the head count (a return to a pass per
+    128-lane slab of heads, 10 at gpt2-large and 8 at the grouped-query
+    cells, fails here): the kernel's block loop, read from the jaxpr,
+    holds two ``dot_general``s a pass, and ``block_passes`` (the
+    engine's ``kv_block_passes`` over ``kv_blocks_live``) is 1 at the
+    cells' decode geometries."""
+    from replicatinggpt_tpu.ops import paged_pallas as pp
+    H, Hkv, D, W, pool_dtype, window, passes = PASS_GEOMETRIES[geometry]
+    B, psz, mp, N = 4, 16, 16, 64
+    assert pp.block_passes(H, D, W, n_kv_head=Hkv) == passes
+    rows = lambda heads: ((B, W, heads * D), jnp.bfloat16)  # noqa: E731
+    pool = ((3, N, psz, Hkv * D), pool_dtype)
+    tail = _abstract(((B, mp), jnp.int32), ((B,), jnp.int32))
+    if Hkv != H:
+        args = _abstract(rows(H), rows(Hkv), rows(Hkv), pool, pool) + tail
+        dots = _dots_in_block_loop(
+            lambda *a: pp.paged_gqa_attention(
+                *a, n_head=H, n_kv_head=Hkv, layer=1, attn_window=window),
+            *args)
+    else:
+        scales = _abstract(*[((3, N, psz, H), jnp.float32)] * 2) \
+            if pool_dtype == jnp.int8 else []
+        args = _abstract(rows(H), rows(H), rows(H), pool, pool) + tail
+        dots = _dots_in_block_loop(
+            lambda *a: pp.paged_window_attention(
+                *a[:7], n_head=H, layer=1,
+                **dict(zip(("k_scales", "v_scales"), a[7:]))),
+            *args, *scales)
+    assert dots == 2 * passes, (geometry, dots)
+
+
+@pytest.mark.parametrize("check", ["gather", "int8-head", "partials"])
+def test_a_wide_window_splits_into_whole_head_passes(monkeypatch, check):
+    """Where the stacked rows and the accumulator would pass the VMEM
+    share (``PASS_STATE_BYTES``, cut here to fit two of six heads at W =
+    8), a block takes whole-head passes, each over its own lanes, and
+    the parities above hold: plain, a head-granularity pool (each pass
+    reads its own heads' scales) and the ``fold=False`` partials (each
+    head's max and denominator in its own column)."""
+    from replicatinggpt_tpu.ops import paged_pallas as pp
+    monkeypatch.setattr(pp, "PASS_STATE_BYTES", 2 * 12 * 6 * 8 * 64)
+    pp._window_call.clear_cache()        # traced under the usual share
+    try:
+        assert pp.heads_per_pass(6, 8, 64) == 2
+        assert pp.block_passes(6, 64, 8) == 3
+        if check == "gather":
+            test_blocked_walk_matches_gather_reference("six-heads-w8",
+                                                       "float32", 1e-5)
+        elif check == "int8-head":
+            test_windowed_kernel_quantized_parity("int8", "head",
+                                                  "six-heads-w8")
+        else:
+            test_owned_subsets_partials_merge_to_the_reference(
+                "six-heads-w8")
+    finally:
+        pp._window_call.clear_cache()
+
+
 def test_launch_stats_count_the_blocks_the_device_mask_owns(kernel_backend):
     """``serve/launch`` says how the kernel's walk engages, from the host
     mirrors: ``kv_blocks_grid`` = the turns the
@@ -401,6 +524,9 @@ def test_launch_stats_count_the_blocks_the_device_mask_owns(kernel_backend):
     stats = [e["args"] for e in tel.events
              if e.get("ph") == "X" and e.get("name") == "serve/launch"]
     assert want and [a["kv_blocks_live"] for a in stats] == want
+    # a block is ONE pass for all of a slot's heads
+    assert eng._kv_block_passes == 1
+    assert [a["kv_block_passes"] for a in stats] == want
     assert {1, 2, 3, 4} & set(want) and max(want) >= 4    # 1 + 1 + 2
     assert all(a["kv_blocks_grid"] == 3 and "kv_block_pages" not in a
                for a in stats)

@@ -121,15 +121,17 @@ def _paged_args(sh, B, W, C=768, psz=16, mp=64, pool_dtype=BF16):
     (1, False, 8, 768, 12), (8, False, 8, 768, 12), (1, True, 8, 768, 12),
     # the serve cells' engine: gpt2-large, 96 slots, 64 pages of 16 a slot
     (1, False, 96, 1280, 20), (8, False, 96, 1280, 20),
-    (1, True, 96, 1280, 20)],
-    ids=["w1", "w8", "w1-int8", "large-w1", "large-w8", "large-w1-int8"])
+    (1, True, 96, 1280, 20), (1, "head", 96, 1280, 20)],
+    ids=["w1", "w8", "w1-int8", "large-w1", "large-w8", "large-w1-int8",
+         "large-w1-int8-head"])
 def test_paged_window_attention_124m(mosaic, one_chip, window, quant, B, C,
                                      H):
     """The walk in blocks of 8 pages (``block_pages``) at gpt2-small's
     and gpt2-large's widths, a grid turn a slot and the slot's blocks a
-    loop of traced length in the body: two heads of 64 to a 128-lane
-    slab, and a quantized pool's scales one (1,024, 1) row operand a
-    slot, cut by block inside the loop."""
+    loop of traced length in the body: a block is ONE pass for all of a
+    slot's heads at W = 1 (two passes of ten at gpt2-large's W = 8), and a
+    quantized pool's page or head scales one (blocks, 1 or H, 128)
+    operand a slot, indexed by block inside the loop."""
     from replicatinggpt_tpu.ops.paged_pallas import (block_pages,
                                                      paged_window_attention)
     sh = {"row": one_chip, "pool": one_chip, "rep": one_chip}
@@ -137,7 +139,8 @@ def test_paged_window_attention_124m(mosaic, one_chip, window, quant, B, C,
                        pool_dtype=jnp.int8 if quant else BF16)
     assert block_pages(16, 64, C * (1 if quant else 2)) == 8
     if quant:
-        sc = _s((N_LAYERS, B * 64, 16), jnp.float32, one_chip)
+        sc = _s((N_LAYERS, B * 64, 16) + ((H,) if quant == "head" else ()),
+                jnp.float32, one_chip)
         fn = lambda q, kn, vn, kp, vp, t, p, l, ks, vs: (
             paged_window_attention(q, kn, vn, kp, vp, t, p, n_head=H,
                                    layer=l, k_scales=ks, v_scales=vs))
