@@ -97,13 +97,15 @@ class ModelConfig:
 
     # --- family (models/families.py picks the body by this) ---------------
     # Everything below is DATA of an architecture other than GPT-2's; the
-    # defaults are GPT-2's, so every older preset is unchanged. The two
-    # families beside it, 'exaone_moe' (models/exaone_moe.py) and 'lfm2_moe'
-    # (models/lfm2_moe.py), are serve-only. What a family alone decides
-    # (RMSNorm, QK-norm, which layers rotate, sigmoid scores normalised
-    # over the chosen) is its module's, not a field here: no caller can
-    # vary it.
-    family: str = "gpt"           # 'gpt' | 'exaone_moe' | 'lfm2_moe'
+    # defaults are GPT-2's, so every older preset is unchanged. The
+    # families beside it, 'exaone_moe' (models/exaone_moe.py), 'lfm2_moe'
+    # (models/lfm2_moe.py) and 'joyai_llm_flash'
+    # (models/joyai_llm_flash.py), are serve-only. What a family alone
+    # decides (RMSNorm, QK-norm, which layers rotate, sigmoid scores
+    # normalised over the chosen) is its module's, not a field here: no
+    # caller can vary it.
+    family: str = "gpt"
+    # 'gpt' | 'exaone_moe' | 'lfm2_moe' | 'joyai_llm_flash'
     n_kv_head: int = 0            # KV heads (GQA); 0 = n_head
     attn_head_dim: int = 0        # head size; 0 = n_embd // n_head
     rope_theta: float = 10000.0   # rotary base, where the family rotates
@@ -127,6 +129,17 @@ class ModelConfig:
     router_norm_eps: float = 0.0
     # added to the chosen scores' sum before they are normalised
     shared_intermediate_size: int = 0   # one shared expert; 0 = none
+    # latent attention (MLA, joyai_llm_flash's): the query goes through a
+    # rank-q_lora_rank bottleneck, keys and values are expanded from ONE
+    # normed kv_lora_rank-wide latent a token, beside a qk_rope_head_dim
+    # rotary key that every head shares; a head scores qk_nope_head_dim +
+    # qk_rope_head_dim wide and weighs v_head_dim wide. 0 = no latent
+    # attention
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     @property
     def kv_heads(self) -> int:
@@ -136,6 +149,18 @@ class ModelConfig:
     def kv_channels(self) -> int:
         """Width of one cached K (or V) row: KV heads x head size."""
         return self.kv_heads * self.head_dim
+
+    @property
+    def kv_token_width(self) -> int:
+        """Values ONE layer caches for a token: a K and a V row of
+        ``kv_channels`` each, or under latent attention one row of the
+        latent and the shared rotary key (no head axis), padded with zeros
+        to whole 128-lane tiles: what the TPU's HBM tiling holds for it
+        anyway, and what the latent kernel's page copies must move."""
+        if self.kv_lora_rank:
+            return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) \
+                * 128
+        return 2 * self.kv_channels
 
     @property
     def paged_layers(self) -> Tuple[int, ...]:
@@ -188,14 +213,16 @@ class ModelConfig:
 
     def validate(self) -> "ModelConfig":
         _ = self.head_dim
-        assert self.family in ("gpt", "exaone_moe", "lfm2_moe"), self.family
+        assert self.family in ("gpt", "exaone_moe", "lfm2_moe",
+                               "joyai_llm_flash"), self.family
         if self.family == "gpt":
             assert self.activation in ("gelu", "relu"), self.activation
             for name in ("n_kv_head", "attn_head_dim", "layer_types",
                          "sliding_window", "conv_reach", "mlp_layer_types",
                          "intermediate_size", "n_experts", "experts_held",
                          "experts_per_token", "moe_intermediate_size",
-                         "router_norm_eps", "shared_intermediate_size"):
+                         "router_norm_eps", "shared_intermediate_size",
+                         *_LATENT_FIELDS):
                 assert not getattr(self, name), (
                     f"{name} is not GPT-2's: models/gpt.py has one head "
                     f"count, learned positions and a dense MLP")
@@ -209,28 +236,42 @@ class ModelConfig:
         return self
 
     def _validate_served_family(self) -> None:
-        """What models/exaone_moe.py and models/lfm2_moe.py compute, and
-        nothing near it."""
+        """What models/exaone_moe.py, models/lfm2_moe.py and
+        models/joyai_llm_flash.py compute, and nothing near it."""
         L = self.n_layer
         assert self.activation == "swiglu", self.activation
-        assert self.n_head % self.kv_heads == 0, (
-            f"{self.n_head} query heads do not group over "
-            f"{self.kv_heads} KV heads")
-        assert self.head_dim % 2 == 0, "rotate-half needs an even head"
+        if self.family == "joyai_llm_flash":
+            self._validate_latent_attention()
+        else:
+            assert not any(getattr(self, n) for n in _LATENT_FIELDS), (
+                f"{', '.join(_LATENT_FIELDS)}: latent attention is "
+                f"joyai_llm_flash's")
+            assert self.n_head % self.kv_heads == 0, (
+                f"{self.n_head} query heads do not group over "
+                f"{self.kv_heads} KV heads")
+            assert self.head_dim % 2 == 0, "rotate-half needs an even head"
         assert len(self.mlp_layer_types) == L and all(
             t in ("dense", "sparse") for t in self.mlp_layer_types), (
             f"mlp_layer_types={self.mlp_layer_types}")
-        kinds = ("full_attention", "sliding_attention"
-                 if self.family == "exaone_moe" else "conv")
-        assert len(self.layer_types) == L and all(
-            t in kinds for t in self.layer_types), (
-            f"layer_types={self.layer_types}")
+        kinds = {"exaone_moe": ("full_attention", "sliding_attention"),
+                 "lfm2_moe": ("full_attention", "conv")}.get(self.family)
+        if kinds:
+            assert len(self.layer_types) == L and all(
+                t in kinds for t in self.layer_types), (
+                f"layer_types={self.layer_types}")
         if self.family == "exaone_moe":
             assert not self.tied_head, "the family's head is untied"
             assert not self.conv_reach and not self.router_norm_eps, (
-                "conv_reach and router_norm_eps are lfm2_moe's")
+                "conv_reach is lfm2_moe's, router_norm_eps lfm2_moe's and "
+                "joyai_llm_flash's")
             if self.window_layers:
                 assert self.sliding_window > 0, "window layers need a window"
+        elif self.family == "joyai_llm_flash":
+            assert not self.tied_head, "the family's head is untied"
+            assert not self.conv_reach and not self.sliding_window, (
+                "conv_reach, sliding_window: every layer of the family is "
+                "latent attention over the whole context")
+            self._validate_dense_lead()
         else:
             assert self.tied_head, (
                 "the family's head is tied to the embedding")
@@ -241,10 +282,7 @@ class ModelConfig:
                 "sliding_window: the family has no window layer")
             assert not self.shared_intermediate_size, (
                 "shared_intermediate_size: the family has no shared expert")
-            n_dense = self.mlp_layer_types.count("dense")
-            assert self.mlp_layer_types[:n_dense] == ("dense",) * n_dense, (
-                f"mlp_layer_types={self.mlp_layer_types}: the dense layers "
-                f"lead (num_dense_layers)")
+            self._validate_dense_lead()
         if "dense" in self.mlp_layer_types:
             assert self.intermediate_size > 0
         if "sparse" in self.mlp_layer_types:
@@ -259,6 +297,31 @@ class ModelConfig:
         assert self.decode_cache_layout == "packed", (
             "the family's pool is packed (KV heads as lane slices)")
         assert self.act_quant == "none", "no W8A8 path for this family"
+
+    def _validate_dense_lead(self) -> None:
+        n_dense = self.mlp_layer_types.count("dense")
+        assert self.mlp_layer_types[:n_dense] == ("dense",) * n_dense, (
+            f"mlp_layer_types={self.mlp_layer_types}: the dense layers "
+            f"lead (num_dense_layers, first_k_dense_replace)")
+
+    def _validate_latent_attention(self) -> None:
+        for name in _LATENT_FIELDS:
+            assert getattr(self, name) > 0, (
+                f"{name}={getattr(self, name)}: latent attention needs it")
+        assert self.qk_rope_head_dim % 2 == 0, (
+            "qk_rope_head_dim: RoPE turns the pairs (2i, 2i+1) of an even "
+            "rotary width")
+        assert not self.n_kv_head and not self.attn_head_dim, (
+            "n_kv_head, attn_head_dim: every head reads the one latent row, "
+            "and the head sizes are qk_nope/qk_rope/v_head_dim")
+        assert all(t == "full_attention" for t in self.layer_types), (
+            f"layer_types={self.layer_types}: every layer is latent "
+            f"attention")
+
+
+#: the ModelConfig fields of latent attention
+_LATENT_FIELDS = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+                  "qk_rope_head_dim", "v_head_dim")
 
 
 @dataclass(frozen=True)
@@ -399,6 +462,23 @@ def _lfm2_moe(n_layer: int, n_dense: int, **kw) -> ModelConfig:
         mlp_layer_types=("dense",) * n_dense
         + ("sparse",) * (n_layer - n_dense),
         routed_scaling=1.0, router_norm_eps=1e-6,
+        decode_cache_layout="packed", scan_layers=False, **kw)
+
+
+def _joyai_llm_flash(n_layer: int, **kw) -> ModelConfig:
+    """The joyai_llm_flash family's constants (JoyAI-LLM-Flash config.json,
+    the DeepSeek-V3 lineage): RMSNorm eps 1e-6, latent attention on every
+    layer with interleaved RoPE of theta 3.2e7 on the rotary part, layer 0
+    a dense SwiGLU (first_k_dense_replace 1) and every later layer
+    sigmoid-routed experts (noaux_tc with one group: selection by score +
+    bias, weights normalised over the chosen with 1e-20 added, times 2.5)
+    beside one shared expert, untied head."""
+    return ModelConfig(
+        family="joyai_llm_flash", n_layer=n_layer, dropout=0.0,
+        attn_dropout=0.0, tied_head=False, activation="swiglu",
+        rope_theta=3.2e7, layernorm_eps=1e-6,
+        mlp_layer_types=("dense",) + ("sparse",) * (n_layer - 1),
+        routed_scaling=2.5, router_norm_eps=1e-20,
         decode_cache_layout="packed", scan_layers=False, **kw)
 
 
@@ -556,6 +636,38 @@ PRESETS = {
             experts_held=tuple(range(8)), experts_per_token=2,
             moe_intermediate_size=48, dtype="float32",
             param_dtype="float32"),
+        tokenizer="char",
+    ),
+    # JoyAI-LLM-Flash (jdopensource, config.json on the Hugging Face hub),
+    # ONE CHIP OF THE FIRST STAGE of a deployment whose stages each divide
+    # their layers over 8 chips by expert parallelism, serve-only: every
+    # width as published; published layers 0-9 (layer 0's MLP dense, 1-9
+    # sparse); experts 0-31 of a router that keeps its 256 outputs and 8 a
+    # token; vocabulary rows 0-16,159 of 129,280. bf16 parameters.
+    # chipbench/configs/joyai-llm-flash.json states the cut.
+    "joyai-llm-flash": Config(
+        name="joyai-llm-flash",
+        model=_joyai_llm_flash(
+            10, vocab_size=16_160, block_size=8192, n_head=32, n_embd=2048,
+            q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128,
+            qk_rope_head_dim=64, v_head_dim=128, intermediate_size=7168,
+            n_experts=256, experts_held=tuple(range(32)),
+            experts_per_token=8, moe_intermediate_size=768,
+            shared_intermediate_size=768, dtype="bfloat16",
+            param_dtype="bfloat16"),
+        tokenizer="char",
+    ),
+    # the same family at test widths (CPU tests, chipbench/rehearse.py):
+    # 4 layers (dense, then 3 sparse), 2 of 16 experts held, 4 a token
+    "joyai-llm-flash-tiny": Config(
+        name="joyai-llm-flash-tiny",
+        model=_joyai_llm_flash(
+            4, vocab_size=96, block_size=64, n_head=4, n_embd=64,
+            q_lora_rank=48, kv_lora_rank=32, qk_nope_head_dim=16,
+            qk_rope_head_dim=8, v_head_dim=16, intermediate_size=96,
+            n_experts=16, experts_held=(0, 1), experts_per_token=4,
+            moe_intermediate_size=24, shared_intermediate_size=24,
+            dtype="float32", param_dtype="float32"),
         tokenizer="char",
     ),
     # Tiny config for tests / smoke runs.

@@ -4,10 +4,10 @@
 paged entry points, the pool constructor and the parameter initialiser of
 ``cfg.family`` instead of importing ``models.gpt``'s by name; a family that
 lacks a program refuses it from inside (``models/exaone_moe.py``,
-``models/lfm2_moe.py``), ``serve_refusals`` says at the engine's constructor
-which engine options a family cannot run under, and why (the family's own
-``refusals``), and ``decide_kernel_route`` asks the family whether the
-kernel fits its windowed steps.
+``models/lfm2_moe.py``, ``models/joyai_llm_flash.py``), ``serve_refusals``
+says at the engine's constructor which engine options a family cannot run
+under, and why (the family's own ``refusals``), and ``decide_kernel_route``
+asks the family whether the kernel fits its windowed steps.
 """
 
 from __future__ import annotations
@@ -54,16 +54,33 @@ REFUSABLE = {
         ecfg.kv_quant, ecfg.weight_quant, ecfg.act_quant) != ("none",) * 3,
 }
 
+#: the paged kernel of the grouped-query families' decode steps
+GQA_KERNEL = "the grouped-query kernel (paged_gqa_attention)"
+
+
 def family(cfg: ModelConfig) -> Family:
     return _family(cfg.family)
 
 
-def _served(m, name: str, slot_entries, *, verify: str,
-            prefix_cache: str) -> Family:
+def _served(m, name: str, slot_entries, *, kernel: str, verify: str,
+            prefix_cache=None) -> Family:
     """A serve-only family built of ``models/layers.py``'s pieces, from
     its module ``m``: one chip, plain pages, one decode step a launch, no
-    windowed step for the kernel to fit (None). ``verify`` /
-    ``prefix_cache``: why ITS per-slot state refuses them."""
+    windowed step for the kernel to fit (None). ``kernel`` names the paged
+    kernel its decode step runs; ``verify`` / ``prefix_cache``: why ITS
+    state refuses them (``prefix_cache`` None: the radix cache serves
+    it)."""
+    refusals = {
+        "mixed_window": "decode_window > 1 admits through the mixed "
+                        f"prefill+decode window, which {name} does not "
+                        "have",
+        "verify": verify,
+        "mesh": "a serving mesh: parallel/mesh.py has no expert axis and "
+                f"{kernel} has no shard_map wrapper",
+        "quant": f"quantised pools or weights: {kernel} reads plain pages "
+                 f"and quant/weights.py knows GPT-2's leaves, not {name}'s"}
+    if prefix_cache is not None:
+        refusals["prefix_cache"] = prefix_cache
     return Family(
         name=name, init_params=m.init_params,
         init_paged_kv_pool=m.init_paged_kv_pool,
@@ -73,17 +90,7 @@ def _served(m, name: str, slot_entries, *, verify: str,
         verify_step_paged=m.verify_step_paged,
         serve_cast_leaves=m.SERVE_CAST_LEAVES,
         step_counters=m.STEP_COUNTERS, slot_entries=slot_entries,
-        window_kernel_ok=lambda *a: None,
-        refusals={
-            "mixed_window": "decode_window > 1 admits through the mixed "
-                            f"prefill+decode window, which {name} does not "
-                            "have",
-            "verify": verify, "prefix_cache": prefix_cache,
-            "mesh": "a serving mesh: parallel/mesh.py has no expert axis "
-                    "and the grouped-query kernel has no shard_map wrapper",
-            "quant": "quantised pools or weights: the grouped-query kernel "
-                     "reads plain pages and quant/weights.py knows GPT-2's "
-                     "leaves"})
+        window_kernel_ok=lambda *a: None, refusals=refusals)
 
 
 @functools.lru_cache(maxsize=None)
@@ -120,7 +127,7 @@ def _family(name: str) -> Family:
     if name == "exaone_moe":
         from . import exaone_moe as m
         return _served(
-            m, name, (("window", m.WINDOW_ENTRY_PREFIX),),
+            m, name, (("window", m.WINDOW_ENTRY_PREFIX),), kernel=GQA_KERNEL,
             verify="speculative verify: a rejected draft's rows cannot be "
                    "taken back out of the window layers' rings",
             prefix_cache="prefix_cache: the radix cache shares full "
@@ -129,13 +136,21 @@ def _family(name: str) -> Family:
     if name == "lfm2_moe":
         from . import lfm2_moe as m
         return _served(
-            m, name, (("conv", m.CONV_ENTRY_PREFIX),),
+            m, name, (("conv", m.CONV_ENTRY_PREFIX),), kernel=GQA_KERNEL,
             verify="speculative verify: a rejected draft's columns cannot "
                    "be rolled back out of the conv layers' state (no "
                    "snapshot of it is kept)",
             prefix_cache="prefix_cache: the radix cache shares full "
                          "layers' pages and cannot restore a conv layer's "
                          "state at a shared prefix's end")
+    if name == "joyai_llm_flash":
+        from . import joyai_llm_flash as m
+        # the latent rows are pool pages and nothing is kept a slot: the
+        # radix cache shares them as it shares GPT-2's
+        return _served(
+            m, name, (), kernel="the latent kernel (paged_mla_attention)",
+            verify="speculative verify: the family has no verify window "
+                   "over its latent pages, and no drafter of its own")
     raise KeyError(f"no model family {name!r}")
 
 

@@ -1001,3 +1001,127 @@ def _gqa_call(q, k_new, v_new, k_pages, v_pages, tables, pos, layer, page0,
       v_pages)
     return (out.reshape(B, n_kv_head, G, W, D).transpose(0, 3, 1, 2, 4)
             .reshape(B, W, Cq))
+
+
+# ---------------------------------------------------------------------------
+# latent attention (MLA)
+#
+# A latent layer caches ONE row a token and no head axis: the normed latent
+# c (``latent`` lanes) beside the rotary key that every head shares, padded
+# to whole 128-lane tiles (what the HBM's tiling would pad it to anyway).
+# Decode reads it with every head's query ABSORBED into the latent space
+# (the caller multiplies q_nope by the head's key expansion), so a head
+# scores a row as one product over the whole row, and the row's first
+# ``latent`` lanes are also the value. All of a slot's heads are ONE block
+# of query rows against one key tile: a block is one score product, one
+# masked online update and one value product, as in the kernels above, and
+# each page is fetched once for every head. What comes back is the latent
+# output a head, sum_j p_j c_j; the caller applies the value expansion.
+
+
+def mla_attention_envelope(n_head: int, row_width: int, page_size: int, *,
+                           itemsize: int = 2, mesh=None,
+                           kv_quant: str = "none") -> tuple:
+    """``paged_attention_envelope`` for ``paged_mla_attention``: ``(ok,
+    reasons)``. One chip, a plain pool, rows of whole lane tiles, a page
+    inside the VMEM budget."""
+    reasons = []
+    if mesh is not None and mesh.size > 1:
+        reasons.append("mla_mesh")
+    if kv_quant != "none":
+        reasons.append("mla_kv_quant")
+    if row_width % LANES:
+        reasons.append("mla_row_lanes")
+    if page_size % 8 != 0:
+        reasons.append("page_align")
+    if not block_pages(page_size, 1, row_width * itemsize):
+        reasons.append("vmem_budget")
+    return (not reasons), tuple(reasons)
+
+
+def _paged_mla_kernel(*refs, page_size, n_block, n_blocks, latent, scale):
+    walk = refs[:N_WALK]
+    (pos_ref, q_ref, new_ref, kv_hbm, out_ref, acc_ref, m_ref, l_ref,
+     kv_buf, sem) = refs[N_WALK:]
+    b = pl.program_id(0)
+    T = n_block * page_size
+    pos = pos_ref[b]
+    _clear_state(acc_ref, m_ref, l_ref)
+
+    def _accumulate(p, half, owned_cols):
+        kpos = jax.lax.broadcasted_iota(jnp.int32, (1, T), 1) + p * T
+        rows = kv_buf[half]                          # (T, row): key AND value
+        s = _scores(q_ref[...], rows, owned_cols() & (kpos < pos), scale)
+        _online_update(s, rows[:, :latent], acc_ref, m_ref, l_ref, 0)
+
+    _walk_blocks(walk, (kv_hbm,), (kv_buf,), sem, n_block, n_blocks,
+                 page_size, _accumulate)
+    # the fresh row, one column: a lane sum and an outer product on the
+    # vector unit (the same float32 sums of the same products as the
+    # MXU's); every head attends it, so the denominator is > 0 always
+    new = new_ref[...].astype(jnp.float32)               # (1, row)
+    s_new = jnp.sum(q_ref[...].astype(jnp.float32) * new, axis=1,
+                    keepdims=True) * scale               # (H, 1)
+    m_prev = m_ref[0]
+    m_new = jnp.maximum(m_prev, s_new)
+    alpha = jnp.exp(m_prev - m_new)
+    p_new = jnp.exp(s_new - m_new[:, :1])
+    l_new = l_ref[0] * alpha + p_new
+    acc = acc_ref[0] * alpha[:, :1] + p_new * new[:, :latent]
+    out_ref[...] = (acc / l_new[:, :1]).astype(out_ref.dtype)
+
+
+def paged_mla_attention(q: jnp.ndarray, kv_new: jnp.ndarray,
+                        pages: jnp.ndarray, tables: jnp.ndarray,
+                        pos: jnp.ndarray, *, layer, latent: int,
+                        scale: float, name: str = "paged_mla_attention"):
+    """Absorbed latent attention of one layer over a paged latent pool.
+
+    q: (B, H, row) every head's absorbed query row (``latent`` lanes of
+    q_nope times the head's key expansion, then the rotated q_rope, zeros
+    in the padding lanes); kv_new: (B, row) the fresh latent row of each
+    slot; pages: the whole stacked (layers, n_pages, page, row) STALE pool,
+    read at ``layer``; tables (B, max_pages) int32; pos (B,) int32.
+    Returns (B, H, latent): each head's softmax over positions < pos and
+    the fresh row of ``scale`` times the row products, weighing the rows'
+    first ``latent`` lanes. The caller scatters the fresh row afterwards
+    and applies the value expansion."""
+    return _mla_call(q, kv_new, pages, tables, pos, layer, latent=latent,
+                     scale=float(scale), name=name,
+                     interpret=_interpret_mode())
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "latent", "scale", "name", "interpret"))
+def _mla_call(q, kv_new, pages, tables, pos, layer, *, latent, scale, name,
+              interpret):
+    _, _, psz, row = pages.shape
+    B, H, _ = q.shape
+    assert q.shape[2] == row == kv_new.shape[1], (q.shape, pages.shape)
+    pos = jnp.asarray(pos, jnp.int32)
+    layer = jnp.asarray(layer, jnp.int32)
+    row_bytes = row * pages.dtype.itemsize
+    P, nb = _walk_shape(tables.shape[1], psz, row_bytes)
+    walk = window_walk(tables, pos, psz, row_bytes)
+    kernel = functools.partial(
+        _paged_mla_kernel, page_size=psz, n_block=P, n_blocks=nb,
+        latent=latent, scale=scale)
+    pool_specs, pool_scratch = _pool_operands([pages], P)
+    state = [pltpu.VMEM((1, H, latent), jnp.float32),
+             pltpu.VMEM((1, H, LANES), jnp.float32),
+             pltpu.VMEM((1, H, LANES), jnp.float32)]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=N_WALK + 1,
+        grid=(B,),
+        in_specs=[_vmem_spec((None, H, row), lambda b, *_: (b, 0, 0)),
+                  _vmem_spec((None, 1, row), lambda b, *_: (b, 0, 0)),
+                  *pool_specs],
+        out_specs=_vmem_spec((None, H, latent), lambda b, *_: (b, 0, 0)),
+        scratch_shapes=state + pool_scratch,
+    )
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, latent), q.dtype),
+        name=name, interpret=interpret,
+        compiler_params=_compiler_params(0, 1),
+    )(*walk, layer.reshape(1), pos, q, kv_new[:, None], pages)

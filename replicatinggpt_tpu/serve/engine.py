@@ -338,10 +338,15 @@ def decide_kernel_route(cfg: ModelConfig, ecfg: EngineConfig, qcfg,
         reasons.append("cache_layout")
     if not paged_pallas._paged_attn_backend_ok():
         reasons.append("backend")
-    ok_env, env_reasons = paged_pallas.paged_attention_envelope(
-        cfg.n_head, cfg.head_dim, page_size, itemsize=itemsize,
-        mesh=mesh, kv_quant=qcfg.kv_dtype, granularity=qcfg.granularity,
-        n_pages=n_pages, n_kv_head=cfg.kv_heads)
+    if cfg.kv_lora_rank:        # latent rows: the latent kernel's own
+        _, env_reasons = paged_pallas.mla_attention_envelope(
+            cfg.n_head, cfg.kv_token_width, page_size, itemsize=itemsize,
+            mesh=mesh, kv_quant=qcfg.kv_dtype)
+    else:
+        _, env_reasons = paged_pallas.paged_attention_envelope(
+            cfg.n_head, cfg.head_dim, page_size, itemsize=itemsize,
+            mesh=mesh, kv_quant=qcfg.kv_dtype, granularity=qcfg.granularity,
+            n_pages=n_pages, n_kv_head=cfg.kv_heads)
     reasons.extend(env_reasons)
     base_ok = not reasons
     # None: the family has no mixed or verify step to route, and the
@@ -1494,7 +1499,10 @@ class Engine:
         # the pool's bytes by kind of state: pages that admission
         # reserves, and what a slot keeps beside them (0 for GPT-2)
         kinds = self.pool.bytes_by_kind()
-        s["kv_global_bytes"] = kinds["pages"]
+        # latent attention's pages hold latent rows, not K/V of heads
+        latent = kinds["pages"] if self.cfg.kv_lora_rank else 0
+        s["kv_global_bytes"] = kinds["pages"] - latent
+        s["kv_latent_bytes"] = latent
         s["kv_window_bytes"] = kinds.get("window", 0)
         s["conv_state_bytes"] = kinds.get("conv", 0)
         # bytes of the compute-dtype copies made at build (0: the tree
@@ -1976,6 +1984,8 @@ class Engine:
         if "expert_bytes" in fx:
             extra.update(expert_weight_bytes=fx["expert_bytes"] * k,
                          moe_rows=n_active * k)
+        if "mla_token_bytes" in fx:
+            extra["mla_kv_bytes"] = live_tokens * fx["mla_token_bytes"]
         if self._kv_block_pages:
             extra.update(self._kv_walk_stats(live))
         with self.tel.phase("serve/launch", self._tb + ENGINE_TRACK, k=k,
@@ -2016,7 +2026,9 @@ class Engine:
         ``short_conv_bytes``; ``expert_bytes`` (the held experts over the
         sparse layers) for ``expert_weight_bytes``: a decode step streams
         every held expert once (at 64 live rows 98% of them get a token),
-        so that is per step whatever was routed."""
+        so that is per step whatever was routed; ``mla_token_bytes`` (a
+        context token's latent rows over the layers: the model's values,
+        not the lanes the rows are padded to) for ``mla_kv_bytes``."""
         cfg = self.cfg
         out = {}
         state = self.pool.slot_state()
@@ -2036,6 +2048,11 @@ class Engine:
             out["expert_bytes"] = sum(
                 a.nbytes for lp in self.served_params["layers"]
                 for n, a in lp.items() if n.startswith("e_"))
+        if cfg.kv_lora_rank:
+            out["mla_token_bytes"] = (
+                len(cfg.paged_layers)
+                * (cfg.kv_lora_rank + cfg.qk_rope_head_dim)
+                * self.pool.kv_array.dtype.itemsize)
         return out or None
 
     def _dispatch(self, k: int, kill: np.ndarray, n_active: int,

@@ -74,7 +74,8 @@ def pool_geometry(cfg: ModelConfig, n_slots: int, page_size: int = 0,
 def page_bytes(cfg: ModelConfig, page_size: int, kv_quant: str = "none",
                granularity: str = "page") -> int:
     """HBM bytes ONE physical page occupies across all layers: K + V
-    rows at the storage dtype, plus the per-row f32 scale metadata a
+    rows (or a latent row, ``ModelConfig.kv_token_width``) at the storage
+    dtype, plus the per-row f32 scale metadata a
     quantized pool carries (quant/kv.py). This is the denominator of
     the admission-capacity claim: page count is the admission currency,
     so at a fixed HBM budget ``n_pages = budget // page_bytes`` — int8
@@ -82,7 +83,7 @@ def page_bytes(cfg: ModelConfig, page_size: int, kv_quant: str = "none",
     -> C + 8/page_size... the scale overhead is 8 bytes/token/layer at
     page granularity), roughly doubling the pool."""
     from ..quant.kv import kv_itemsize, scale_bytes_per_token
-    per_tok = (2 * cfg.kv_channels * kv_itemsize(kv_quant, cfg)
+    per_tok = (cfg.kv_token_width * kv_itemsize(kv_quant, cfg)
                + scale_bytes_per_token(kv_quant, granularity,
                                        cfg.n_head))
     # a page holds the layers that keep paged history (all of GPT-2's)

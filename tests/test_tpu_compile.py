@@ -204,6 +204,52 @@ def test_paged_gqa_attention_lfm2_widths(mosaic, one_chip):
                      text)
 
 
+def test_paged_mla_attention_joyai_widths(mosaic, one_chip):
+    """The latent kernel at JoyAI-LLM-Flash's published widths and the
+    cell's engine: 32 heads' absorbed query rows of 512 latent + 64 rotary
+    lanes, padded to 640 (a 576-wide page is refused: a page copy's slice
+    must be whole 128-lane tiles), 256 slots of 512 table entries over
+    40,960 pages of 16; a block of 8 pages is one (128, 640) tile, key and
+    value at once; the instruction wears ``paged_mla_attention``."""
+    from replicatinggpt_tpu.ops.paged_pallas import (block_pages,
+                                                     paged_mla_attention)
+    B, psz, mp, W = 256, 16, 512, 640
+    assert block_pages(psz, mp, W * 2) == 8
+    text = _compile(
+        lambda q, n, p, t, pos: paged_mla_attention(
+            q, n, p, t, pos, layer=0, latent=512, scale=192 ** -0.5),
+        _s((B, 32, W), BF16, one_chip), _s((B, W), BF16, one_chip),
+        _s((1, 40_960, psz, W), BF16, one_chip),
+        _s((B, mp), jnp.int32, one_chip), _s((B,), jnp.int32, one_chip))
+    assert re.search(r"%paged_mla_attention[\w.]* = [^\n]*tpu_custom_call",
+                     text)
+
+
+def test_joyai_decode_step_carries_its_scopes(mosaic, one_chip):
+    """The joyai_llm_flash decode window (published widths, the first three
+    layers: dense, sparse, sparse, 8 experts held, a shared one) compiled
+    for the described v5e on the Pallas route: the latent kernel is the
+    program's one Pallas kernel, and the scopes its projections,
+    absorption, scatter, experts and head run under are on the ops."""
+    import dataclasses
+    from replicatinggpt_tpu.models import joyai_llm_flash
+    cfg = dataclasses.replace(
+        get_config("joyai-llm-flash").model, n_layer=3,
+        mlp_layer_types=("dense", "sparse", "sparse"), n_experts=256,
+        experts_held=tuple(range(8)), vocab_size=4096)
+    text = _lower_decode_window(one_chip, cfg, joyai_llm_flash, 16, 1024,
+                                512).compile().as_text()
+    assert _kernel_names(text) and all(
+        n.startswith("paged_mla_attention") for n in _kernel_names(text))
+    op_names = re.findall(r'op_name="([^"]*)"', text)
+    for scope in ("attn_mla", "kv_scatter", "mlp", "moe_router",
+                  "moe_experts", "moe_shared", "head", "embed", "sample"):
+        assert any(re.search(rf"(^|/){scope}(/|$)", n) for n in op_names), \
+            scope
+    assert not any(re.search(r"(^|/)(attn_swa|attn_global|short_conv)(/|$)",
+                             n) for n in op_names)
+
+
 def _lower_decode_window(one_chip, cfg, family, B, n_pages, mp,
                          served=None):
     """The engine's decode window (k = 1, the Pallas route, pages of 16)
@@ -261,22 +307,25 @@ def test_lfm2_decode_step_carries_its_scopes(mosaic, one_chip):
                             "paged_window_attention": 2}),
     ("lfm2-24b-a2b", {"paged_window_attention": 2}),
     ("gpt2-large", {"paged_window_attention": 3}),
-], ids=["kexaone", "lfm2", "gpt2-layers-unrolled"])
+    ("joyai-llm-flash", {"paged_mla_attention": 10}),
+], ids=["kexaone", "lfm2", "gpt2-layers-unrolled", "joyai"])
 def test_decode_program_lowers_the_kernel_once_a_layer_kind(mosaic, one_chip,
                                                             preset, kinds):
     """Every process start traces and lowers its decode program, and
     ``setup_s`` carries that whether or not the compile cache is warm
     (PR 38 was refused for it: a costlier kernel body lowered once a
-    LAYER). The three served families' decode windows at their cells'
+    LAYER). The served families' decode windows at their cells'
     real widths and slots, lowered for the described v5e (the text
     ``jit(...).lower()`` leaves, BEFORE the compiler inlines): where the
     layers are a Python loop the LOWERED module holds each kernel ONCE A
     KIND (K-EXAONE's 6 window layers and 2 full layers: 2 kernels, not 8;
-    LFM2's 2 full layers: 1; gpt2-large cut to 3 layers and unrolled: 1),
+    LFM2's 2 full layers: 1; gpt2-large cut to 3 layers and unrolled: 1;
+    JoyAI-LLM-Flash's 10 latent layers: 1),
     and every layer of a kind calls the one private function, which the
     compiler inlines afterwards."""
     import dataclasses
-    from replicatinggpt_tpu.models import exaone_moe, gpt, lfm2_moe
+    from replicatinggpt_tpu.models import (exaone_moe, gpt, joyai_llm_flash,
+                                           lfm2_moe)
     cfg = get_config(preset).model
     if preset == "gpt2-large":
         cfg = dataclasses.replace(cfg, n_layer=3, scan_layers=False,
@@ -285,6 +334,9 @@ def test_decode_program_lowers_the_kernel_once_a_layer_kind(mosaic, one_chip,
                                    served=gpt.SERVE_CAST_LEAVES)
     elif preset == "lfm2-24b-a2b":
         low = _lower_decode_window(one_chip, cfg, lfm2_moe, 256, 40_960, 512)
+    elif preset == "joyai-llm-flash":
+        low = _lower_decode_window(one_chip, cfg, joyai_llm_flash, 256,
+                                   40_960, 512)
     else:
         low = _lower_decode_window(one_chip, cfg, exaone_moe, 64, 10_240,
                                    512)
@@ -295,7 +347,7 @@ def test_decode_program_lowers_the_kernel_once_a_layer_kind(mosaic, one_chip,
         held = re.findall(rf'kernel_name = "{name}"', text)
         assert len(held) == 1, (name, len(held))
     # and the layers of a kind are calls of the kind's one function
-    calls = re.findall(r"call @(_(?:gqa|window)_call\w*)\(", text)
+    calls = re.findall(r"call @(_(?:gqa|window|mla)_call\w*)\(", text)
     assert sorted(map(calls.count, set(calls))) == sorted(kinds.values())
 
 
