@@ -1140,7 +1140,7 @@ def _kernel_walk(cache: Dict[str, jnp.ndarray], tables, pos_eff, mesh):
     from ..ops.paged_pallas import window_walk
     k = cache["k"]
     return window_walk(tables, pos_eff, k.shape[2],
-                       k.shape[3] * k.dtype.itemsize)
+                       k.shape[3] * k.dtype.itemsize, 2)
 
 
 @jax.named_scope("attn")

@@ -10,8 +10,10 @@ puts the page table in scalar-prefetch SMEM, leaves the WHOLE STACKED
 pool (layers, n_pages, page, C) in HBM and FETCHES FOR ITSELF by
 ``(layer, page)``, so no layer of the pool is sliced out for it. The
 grid is ``(B,)``: ONE TURN A SLOT. A slot's pages go in blocks of ``P``
-consecutive logical pages (``block_pages``: ``P * page`` >= 128 tokens,
-8 pages of 16), and the blocks are a LOOP IN THE BODY as long as the
+consecutive logical pages (``block_pages``: whole 128-token lane tiles
+that carry ``BLOCK_BYTES`` of the pool; 8 pages of 16 for gpt2-large and
+K-EXAONE, 16 for LFM2, 32 for a latent row), and the blocks are a LOOP
+IN THE BODY as long as the
 slot's own live blocks (``_walk_blocks``: one ``fori_loop`` of traced
 length over the list ``_blocked_walk`` compacts for the slot), each
 copied, scattered as its pages lie in the pool, into one half of a
@@ -75,9 +77,20 @@ from .flash_pallas import (LANES, NEG_INF, _compiler_params,
 # of 1024 tokens (a block of one) 3 MiB.
 PAGED_DECODE_BYTES = 4 * 1024 * 1024
 
-# a block holds at least this many tokens of a slot (table and budget
-# allowing): the lane width of a score tile
-BLOCK_TOKENS = LANES
+# a block of a slot's walk carries at least this many bytes of the pool
+# (table and budget allowing), in whole ``LANES``-token tiles: a block is
+# ONE dependent chain (score product, masked online update, value
+# product) with a fixed part, about 0.4 us, that a narrow row pays too
+# often at 128 tokens; past it a block's pages (their copies, a slot's
+# half-empty last block) cost more than it saves. The kernels alone on a
+# TPU v5e at the
+# cells' fills (``tools/kernel_fit.py --block-tokens``), ns a live token
+# at blocks of 128 / 256 / 512 / 1024 tokens: LFM2's K and V rows (2,048
+# B a token) 8.09 / 6.94 / 6.81 / 7.30, the latent row (1,280 B) 7.60 /
+# 6.22 / 5.74 / 6.16. Each stops falling by more than 5% past its first
+# block of 512 KiB or more (LFM2's 256 tokens, the latent row's 512);
+# gpt2-large's and K-EXAONE's 128-token blocks carry 640 and 512 KiB.
+BLOCK_BYTES = 512 * 1024
 
 # VMEM share of a turn's stacked query rows (float32, and again in the
 # MXU's dtype) and float32 accumulator: 12 B a row a lane. A block's
@@ -188,7 +201,7 @@ def paged_attention_envelope(n_head: int, head_dim: int, page_size: int,
     if page_size % 8 != 0:
         reasons.append("page_align")
     C = n_kv_head * head_dim          # a page's row is KV heads wide
-    if not block_pages(page_size, 1, C * itemsize):
+    if not block_pages(page_size, 1, C * itemsize, 2):
         reasons.append("vmem_budget")     # a block of ONE page is over it
     return (not reasons), tuple(reasons)
 
@@ -206,22 +219,36 @@ def paged_decode_supported(n_head: int, head_dim: int, page_size: int,
     return ok
 
 
-def block_pages(page_size: int, n_table: int, row_bytes: int) -> int:
+def block_pages(page_size: int, n_table: int, row_bytes: int,
+                tiles: int) -> int:
     """``P``: how many consecutive logical pages of a slot one block
-    covers. Enough for ``BLOCK_TOKENS`` tokens, no more than the table
-    has, and no more than keeps the block's K and V tiles (``row_bytes``
-    a token each) inside ``PAGED_DECODE_BYTES``; 0 where one page alone
-    is over it. Follows from shapes: nothing configures it."""
-    want = -(-BLOCK_TOKENS // page_size)
-    fit = PAGED_DECODE_BYTES // (2 * page_size * row_bytes)
+    covers. Enough whole ``LANES``-token tiles that the block's ``tiles``
+    (K and V: 2; a latent row, key and value at once: 1) of ``row_bytes``
+    a token carry ``BLOCK_BYTES``, no more than the table has, and no
+    more than keeps them inside ``PAGED_DECODE_BYTES``; 0 where one page
+    alone is over it. Follows from shapes: nothing configures it."""
+    token_bytes = tiles * row_bytes
+    lane_tiles = -(-BLOCK_BYTES // (LANES * token_bytes))
+    want = -(-lane_tiles * LANES // page_size)
+    fit = PAGED_DECODE_BYTES // (page_size * token_bytes)
     return min(want, n_table, fit)
 
 
-def _walk_shape(n_table: int, page_size: int, row_bytes: int) -> tuple:
+def _walk_shape(n_table: int, page_size: int, row_bytes: int,
+                tiles: int) -> tuple:
     """``(P, n_blocks)``: the pages a block covers and the blocks a
     slot's table of ``n_table`` entries makes (the last may be short)."""
-    P = block_pages(page_size, n_table, row_bytes)
+    P = block_pages(page_size, n_table, row_bytes, tiles)
     return P, -(-n_table // P)
+
+
+# the owned pages of a block as bits, a word of 32 pages (one at every
+# cell's block): what ``cols()`` expands to the block's columns
+WORD = 32
+
+
+def _mask_words(n_block: int) -> int:
+    return -(-n_block // WORD)
 
 
 def live_blocks(pos, page_size: int, n_block: int):
@@ -231,19 +258,19 @@ def live_blocks(pos, page_size: int, n_block: int):
     return -(-pos // (page_size * n_block))
 
 
-# scalar-prefetch operands ``_walk_blocks`` reads: ``_blocked_walk``'s five
+# scalar-prefetch operands ``_walk_blocks`` reads: ``_blocked_walk``'s six
 # and the pool's layer
-N_WALK = 6
+N_WALK = 7
 
 
 @jax.named_scope("kv_gather")
 def _blocked_walk(tables: jnp.ndarray, owned: jnp.ndarray, page_size: int,
-                  row_bytes: int) -> tuple:
-    """The walk both kernels make, from a (B, max_pages) table and the
+                  row_bytes: int, tiles: int) -> tuple:
+    """The walk every kernel makes, from a (B, max_pages) table and the
     mask of the entries a slot's rows read: the scalars ``_walk_blocks``
-    reads beside the layer, ``(table, blocks, count, rank, next)``. What
-    is left OUTSIDE the kernel of addressing the pool's pages, hence the
-    scope; the same for every layer of a step.
+    reads beside the layer, ``(table, blocks, count, rank, next, bits)``.
+    What is left OUTSIDE the kernel of addressing the pool's pages, hence
+    the scope; the same for every layer of a step.
 
     Block ``p`` of slot ``b`` covers its logical pages p*P .. p*P + P - 1
     (``_walk_shape``). The table and the mask are padded to whole blocks
@@ -257,12 +284,22 @@ def _blocked_walk(tables: jnp.ndarray, owned: jnp.ndarray, page_size: int,
     bound, any subset under a shard's mask. The live blocks fetch for one
     another across slots, so a slot carries the ``rank`` of its first
     live block among all of the call's (its parity picks the half of the
-    double buffer) and the ``next`` slot that has one (-1: none)."""
+    double buffer) and the ``next`` slot that has one (-1: none).
+    ``bits[(b * n_blocks + p) * words + w]`` holds the owned pages
+    ``w * WORD ..`` of block p of slot b, page j in bit j % WORD: the mask
+    of a block's columns in as many words as its pages need, however many
+    pages they are."""
     B, mp = tables.shape
-    P, nb = _walk_shape(mp, page_size, row_bytes)
+    P, nb = _walk_shape(mp, page_size, row_bytes, tiles)
     pad = ((0, 0), (0, nb * P - mp))
     owned = jnp.pad(owned, pad)
     table = jnp.where(owned, jnp.pad(jnp.asarray(tables, jnp.int32), pad), -1)
+    nw = _mask_words(P)
+    words = jnp.pad(owned.reshape(B, nb, P),
+                    ((0, 0), (0, 0), (0, nw * WORD - P)))
+    bit = jnp.left_shift(jnp.uint32(1), jnp.arange(WORD, dtype=jnp.uint32))
+    bits = jnp.sum(jnp.where(words.reshape(B, nb, nw, WORD), bit,
+                             jnp.uint32(0)), axis=3, dtype=jnp.uint32)
     live = owned.reshape(B, nb, P).any(axis=2)
     # a slot's i-th live block is the first with i + 1 live blocks up to
     # and with it: as many blocks as have i or fewer (no sort)
@@ -275,14 +312,16 @@ def _blocked_walk(tables: jnp.ndarray, owned: jnp.ndarray, page_size: int,
     nxt = jnp.concatenate([later[1:], jnp.full((1,), B, jnp.int32)])
     return (table, blocks.reshape(B * nb), count,
             jnp.cumsum(count, dtype=jnp.int32) - count,
-            jnp.where(nxt < B, nxt, -1))
+            jnp.where(nxt < B, nxt, -1),
+            jax.lax.bitcast_convert_type(bits, jnp.int32).reshape(-1))
 
 
 def window_walk(tables: jnp.ndarray, pos: jnp.ndarray, page_size: int,
-                row_bytes: int, owned=None) -> tuple:
-    """``paged_window_attention``'s walk over ``tables`` at positions
-    ``pos`` (``_blocked_walk``'s scalars), of pages ``row_bytes`` a token
-    wide. It depends on no layer: a caller whose layers run in one scan
+                row_bytes: int, tiles: int, owned=None) -> tuple:
+    """The walk over ``tables`` at positions ``pos`` (``_blocked_walk``'s
+    scalars) of a kernel that fetches ``tiles`` arrays of pages
+    ``row_bytes`` a token wide (``paged_window_attention``: K and V). It
+    depends on no layer: a caller whose layers run in one scan
     builds it ONCE a step outside the scan and hands it to every
     layer's call (``walk=``), where XLA would rebuild it a layer.
     ``owned`` as in ``paged_window_attention``."""
@@ -290,7 +329,7 @@ def window_walk(tables: jnp.ndarray, pos: jnp.ndarray, page_size: int,
     if owned is None:
         owned = gqa_owned_pages(pos, jnp.zeros_like(pos), tables.shape[1],
                                 page_size, 0)
-    return _blocked_walk(tables, owned, page_size, row_bytes)
+    return _blocked_walk(tables, owned, page_size, row_bytes, tiles)
 
 
 def heads_per_pass(n_head: int, rows: int, head_dim: int) -> int:
@@ -337,8 +376,9 @@ def _walk_blocks(walk, pools, bufs, sem, n_block: int, n_blocks: int,
     what a half holds there is an older page or the zeros of the
     call's first turn. A slot with no live block reads its count here
     and copies nothing."""
-    table_ref, blocks_ref, count_ref, rank_ref, next_ref, layer_ref = walk
-    P, psz = n_block, page_size
+    (table_ref, blocks_ref, count_ref, rank_ref, next_ref, bits_ref,
+     layer_ref) = walk
+    P, psz, nw = n_block, page_size, _mask_words(n_block)
     b = pl.program_id(0)
     n = count_ref[b]
 
@@ -383,11 +423,19 @@ def _walk_blocks(walk, pools, bufs, sem, n_block: int, n_blocks: int,
         copies(b, p, half, lambda c: c.wait())
 
         def cols():
+            # a column's bit in the block's words: a few vector steps a
+            # word of 32 pages, not one a page, so that neither the
+            # program's size nor a block's work follows P
             page = jax.lax.broadcasted_iota(jnp.int32, (1, P * psz), 1) // psz
-            own = jnp.zeros_like(page)
-            for j in range(P):
-                own = jnp.where(page == j, table_ref[b, p * P + j] + 1, own)
-            return own > 0
+
+            def word(w, own):
+                bits = bits_ref[(b * n_blocks + p) * nw + w]
+                hit = jax.lax.shift_right_logical(
+                    jnp.full_like(page, bits), page % WORD) & 1
+                return jnp.where(page // WORD == w, hit, own)
+
+            return jax.lax.fori_loop(0, nw, word, jnp.zeros_like(page),
+                                     unroll=nw == 1) > 0
 
         step(p, half, cols)
 
@@ -657,9 +705,9 @@ def _window_call(q, k_new, v_new, k_pages, v_pages, tables, pos, layer,
     pos = jnp.asarray(pos, jnp.int32)
     layer = jnp.asarray(layer, jnp.int32)
     row_bytes = C * k_pages.dtype.itemsize
-    P, nb = _walk_shape(tables.shape[1], psz, row_bytes)
+    P, nb = _walk_shape(tables.shape[1], psz, row_bytes, 2)
     if walk is None:
-        walk = window_walk(tables, pos, psz, row_bytes, owned)
+        walk = window_walk(tables, pos, psz, row_bytes, 2, owned)
     kernel = functools.partial(
         _paged_window_kernel, n_head=n_head, head_dim=D, page_size=psz,
         n_block=P, n_blocks=nb, window=W, scale=D ** -0.5,
@@ -961,11 +1009,11 @@ def _gqa_call(q, k_new, v_new, k_pages, v_pages, tables, pos, layer, page0,
     page0 = (jnp.zeros((B,), jnp.int32) if page0 is None
              else jnp.asarray(page0, jnp.int32))
     row_bytes = Ckv * k_pages.dtype.itemsize
-    P, nb = _walk_shape(tables.shape[1], psz, row_bytes)
+    P, nb = _walk_shape(tables.shape[1], psz, row_bytes, 2)
     walk = _blocked_walk(
         tables, gqa_owned_pages(pos, page0, tables.shape[1], psz,
                                 attn_window),
-        psz, row_bytes)
+        psz, row_bytes, 2)
     # a KV head's G query heads as G*W rows of one block
     qg = (q.reshape(B, W, n_kv_head, G, D).transpose(0, 2, 3, 1, 4)
           .reshape(B, n_kv_head, G * W, D))
@@ -1034,7 +1082,7 @@ def mla_attention_envelope(n_head: int, row_width: int, page_size: int, *,
         reasons.append("mla_row_lanes")
     if page_size % 8 != 0:
         reasons.append("page_align")
-    if not block_pages(page_size, 1, row_width * itemsize):
+    if not block_pages(page_size, 1, row_width * itemsize, 1):
         reasons.append("vmem_budget")
     return (not reasons), tuple(reasons)
 
@@ -1101,8 +1149,8 @@ def _mla_call(q, kv_new, pages, tables, pos, layer, *, latent, scale, name,
     pos = jnp.asarray(pos, jnp.int32)
     layer = jnp.asarray(layer, jnp.int32)
     row_bytes = row * pages.dtype.itemsize
-    P, nb = _walk_shape(tables.shape[1], psz, row_bytes)
-    walk = window_walk(tables, pos, psz, row_bytes)
+    P, nb = _walk_shape(tables.shape[1], psz, row_bytes, 1)
+    walk = window_walk(tables, pos, psz, row_bytes, 1)
     kernel = functools.partial(
         _paged_mla_kernel, page_size=psz, n_block=P, n_blocks=nb,
         latent=latent, scale=scale)

@@ -88,8 +88,8 @@ Every step marks its host phases through ``self.tel.phase`` (a
 ``serve/prefill``, and ``serve/decode`` | ``serve/verify`` around
 ``serve/launch``, ``serve/fetch``, ``serve/commit``), with the live
 context (``live_tokens``, ``live_kv_bytes``, ``stochastic_rows``, the
-paged kernel's ``kv_blocks_live`` / ``kv_block_passes`` /
-``kv_blocks_grid``, from the host
+paged kernel's ``kv_blocks_live`` / ``kv_block_tokens`` /
+``kv_block_passes`` / ``kv_blocks_grid``, from the host
 mirrors) as stats of ``serve/launch``;
 docs/observability.md
 has the vocabulary. Construction is the ``setup/engine`` span of the
@@ -945,14 +945,16 @@ class Engine:
                            else 0.0)
         # the per-layer kernel's walk over the pool's tables, for the
         # stats of ``serve/launch``: pages a block of its loop covers (0:
-        # the decode step runs no such kernel) and the passes a block
-        # takes in the decode step (one query row a head)
+        # the decode step runs no such kernel; the tiles it fetches a
+        # block: K and V, or a latent row that is both) and the passes a
+        # block takes in the decode step (one query row a head)
         self._kv_block_pages = self._kv_block_passes = 0
         if self._use_pallas:
             from ..ops.paged_pallas import block_pages, block_passes
             self._kv_block_pages = block_pages(
                 self.pool.page_size, self.pool.max_pages,
-                self.pool.kv_array.shape[-1] * itemsize)
+                self.pool.kv_array.shape[-1] * itemsize,
+                1 if cfg.kv_lora_rank else 2)
             self._kv_block_passes = block_passes(
                 cfg.n_head, cfg.head_dim, n_kv_head=cfg.kv_heads)
         log.info("kernel route: %s (decode=%s window=%s sharded=%s%s)",
@@ -2001,7 +2003,9 @@ class Engine:
         with work: the sum over the live slots of the blocks of
         ``ops.paged_pallas.block_pages`` pages that hold a position under
         the slot's, as the kernel's owned mask will have it on the
-        device; one step of one pool layer), ``kv_block_passes`` (the
+        device; one step of one pool layer), ``kv_block_tokens`` (the
+        tokens such a block covers: its pages times the page, which
+        follows the bytes of the pool's row), ``kv_block_passes`` (the
         passes those iterations make, a score product, an online update
         and a value product each: ``ops.paged_pallas.block_passes`` a
         block, so over ``kv_blocks_live`` it reads 1 where a block is ONE
@@ -2012,6 +2016,8 @@ class Engine:
         n_live = int(live_blocks(self._pos[live], self.pool.page_size,
                                  self._kv_block_pages).sum())
         return dict(kv_blocks_live=n_live,
+                    kv_block_tokens=self._kv_block_pages
+                    * self.pool.page_size,
                     kv_block_passes=n_live * self._kv_block_passes,
                     kv_blocks_grid=self.ecfg.pool_size)
 

@@ -45,3 +45,20 @@ def corpus_text():
 @pytest.fixture(scope="session")
 def tiny_corpus(corpus_text):
     return corpus_text[:50_000]
+
+
+@pytest.fixture()
+def lane_tile_blocks(monkeypatch):
+    """The paged kernels' walk in blocks of ONE 128-token lane tile
+    (``ops.paged_pallas.BLOCK_BYTES`` cut to a byte): at the narrow rows of
+    the tests' pools the bytes rule covers a slot's whole table in one
+    block, and a test of a walk over MANY blocks asks for this. Every jit
+    cache is emptied on both sides: a traced kernel holds its block."""
+    import jax
+
+    from replicatinggpt_tpu.ops import paged_pallas
+
+    monkeypatch.setattr(paged_pallas, "BLOCK_BYTES", 1)
+    jax.clear_caches()
+    yield
+    jax.clear_caches()

@@ -277,11 +277,13 @@ GQA_HEADS = list(GQA_HEAD_IDS.values())
         "blocks-live-between-idle", "blocks-all-live-w8",
         "blocks-window-second-block-only"])
 @pytest.mark.parametrize("heads", GQA_HEADS, ids=list(GQA_HEAD_IDS))
-def test_gqa_kernel_and_its_lower_bound_against_einsum(W, window, pos, mp,
+def test_gqa_kernel_and_its_lower_bound_against_einsum(lane_tile_blocks, W,
+                                                       window, pos, mp,
                                                        heads):
     Hq, Hkv, D = heads
     psz = 8
-    assert (paged_pallas.block_pages(psz, mp, Hkv * D * 4) < mp) == (mp > 16)
+    assert (paged_pallas.block_pages(psz, mp, Hkv * D * 4, 2) < mp) \
+        == (mp > 16)
     q, kn, vn, kp, vp, tables, pos = _kernel_case(3, 3, W, Hq, Hkv, D, psz,
                                                   mp, pos, window)
     got = paged_pallas.paged_gqa_attention(
@@ -306,8 +308,8 @@ def test_gqa_kernel_and_its_lower_bound_against_einsum(W, window, pos, mp,
 ], ids=["ring-of-3", "ring-of-9-in-two-blocks", "ring-of-9-bf16",
         "ring-of-9-live-between-idle"])
 @pytest.mark.parametrize("heads", GQA_HEADS, ids=list(GQA_HEAD_IDS))
-def test_gqa_kernel_walks_a_ring_from_page0(psz, mp, window, pos, dtype,
-                                            tol, heads):
+def test_gqa_kernel_walks_a_ring_from_page0(lane_tile_blocks, psz, mp,
+                                            window, pos, dtype, tol, heads):
     """A window layer's ring: the table's first entry is absolute page
     ``page0`` of the slot, not page 0."""
     Hq, Hkv, D = heads

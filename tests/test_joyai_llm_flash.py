@@ -303,13 +303,19 @@ def _einsum_latent(q, new, pages, tables, pos, latent, scale):
 def test_mla_kernel_against_its_contract_at_published_widths(dtype, tol):
     """``paged_mla_attention`` at the published widths (32 heads, rows of
     512 latent + 64 rotary lanes padded to 640, pages of 16) against the
-    contract by hand AND ``_xla_mla_attention``: a slot at position 0 (no
-    live block: the fresh row alone), one inside its first page, one
-    several blocks of 8 pages deep ending inside a page; the pool stacked
-    two layers deep and read at layer 1."""
-    H, W, R, psz, mp = 32, 640, 512, 16, 20
-    pos = np.array([0, 5, 150, 300], np.int32)
+    contract by hand AND ``_xla_mla_attention``, over a table of 129
+    entries: four blocks of 32 pages (512 tokens: a bf16 row's block;
+    eight of 16 for a float32 row) and a last block of ONE page. Slots at
+    position 0 (no live block: the fresh row alone) and 5 (inside its
+    first page), at exactly one block (512) and one token past it (513: a
+    second block of one owned page), one ending inside a page 1,700 deep
+    (its fourth block), and one in the table's one-page last block
+    (2,060); the pool stacked two layers deep and read at layer 1."""
+    H, W, R, psz, mp = 32, 640, 512, 16, 129
+    pos = np.array([0, 5, 512, 513, 1700, 2060], np.int32)
     B = len(pos)
+    assert paged_pallas.block_pages(psz, mp, W * jnp.dtype(dtype).itemsize,
+                                    1) == (32 if dtype == "bfloat16" else 16)
     r = np.random.default_rng(3)
     f = lambda *s: r.standard_normal(s).astype(np.float32)
     cast = lambda a: np.asarray(jnp.asarray(a, dtype), np.float32)
@@ -502,6 +508,62 @@ def test_engine_serves_the_family_on_the_latent_kernel(params,
         assert kw["live_kv_bytes"] == kw["live_tokens"] * eng._kv_token_bytes
         assert kw["kv_block_passes"] == kw["kv_blocks_live"]   # one pass
     assert eng.pool.alloc.pages_free == eng.pool.n_pages
+
+
+@pytest.mark.parametrize("preset,pages", [
+    ("gpt2-large", 8), ("gpt2-medium", 8), ("k-exaone-236b-a23b", 8),
+    ("lfm2-24b-a2b", 16), ("joyai-llm-flash", 32)])
+def test_a_block_is_as_many_pages_as_its_rows_bytes_ask(request, monkeypatch,
+                                                       preset, pages):
+    """``block_pages`` at the cells' widths (bf16 pools, pages of 16, a
+    table of 512): the tiles a block fetches (K and V, or the latent row
+    that is both) carry ``BLOCK_BYTES`` in whole 128-token lane tiles, so
+    the wide rows keep 8 pages and the narrow ones grow. For the latent
+    family the engine's ``kv_blocks_live`` is the kernel walk's own count
+    over what a launch uploads, ``kv_block_tokens`` its block, in a walk
+    where a count of two tiles would have made blocks half as long
+    (``BLOCK_BYTES`` cut to 128 KiB: 256 tokens of the test preset's
+    512-byte row, 128 had it been K and V)."""
+    cfg = get_config(preset).model
+    latent = bool(cfg.kv_lora_rank)
+    row = (cfg.kv_token_width if latent else cfg.kv_channels) * 2
+    assert paged_pallas.block_pages(16, 512, row, 1 if latent else 2) \
+        == pages
+    if not latent:
+        return
+    params = request.getfixturevalue("params")
+    request.getfixturevalue("kernel_on_cpu")
+    from replicatinggpt_tpu.utils.telemetry import Telemetry
+    monkeypatch.setattr(paged_pallas, "BLOCK_BYTES", 128 * 1024)
+    jax.clear_caches()                  # a traced kernel holds its block
+    try:
+        tel = Telemetry()
+        eng = Engine(params, dataclasses.replace(CFG, block_size=512), ECFG,
+                     telemetry=tel)
+        row_bytes = eng.pool.kv_array.shape[-1] * 4
+        psz, P = PSZ, eng._kv_block_pages
+        assert (row_bytes, P) == (512, 32)
+        want, other, dispatch = [], [], eng._dispatch
+
+        def spy(k, kill, *a):
+            pos = np.where(eng._active & ~kill, eng._pos, 0)
+            walk = paged_pallas.window_walk(
+                jnp.asarray(eng.pool.tables), jnp.asarray(pos, jnp.int32),
+                psz, row_bytes, 1)
+            want.append(int(np.asarray(walk[2]).sum()))
+            other.append(int(paged_pallas.live_blocks(pos, psz, 16).sum()))
+            return dispatch(k, kill, *a)
+
+        eng._dispatch = spy
+        done = _drive(eng, _requests(9, [(300, 4), (20, 4), (130, 4)]))
+        assert all(r.ok for r in done.values())
+        stats = [ev["args"] for ev in tel.events
+                 if ev["name"] == "serve/launch"]
+        assert want and [a["kv_blocks_live"] for a in stats] == want
+        assert all(a["kv_block_tokens"] == P * psz == 256 for a in stats)
+        assert want != other             # two blocks of 256, not three of 128
+    finally:
+        jax.clear_caches()
 
 
 def test_the_radix_prefix_cache_serves_latent_pages_unchanged(

@@ -231,10 +231,14 @@ def _einsum_attention(q, kn, vn, kp, vp, tables, pos, Hq, Hkv):
 def test_gqa_kernel_at_four_query_heads_a_kv_head_of_64(dtype, tol):
     """The grouped-query kernel at this family's group and head size (4
     query heads a KV head of 64: a block of 4 x 64 rows, a pool row of 8 x
-    64 lanes), two blocks of pages deep, an idle slot among them; a bf16
-    pool goes to the MXU as stored."""
-    Hq, Hkv, D, psz, mp, B = 32, 8, 64, 16, 12, 3
-    pos = np.array([0, 37, 150], np.int32)
+    64 lanes), at least two blocks of pages deep (the bytes rule's: 128
+    tokens of a float32 row, 256 of the cell's bf16 one), an idle slot
+    among them; a bf16 pool goes to the MXU as stored."""
+    Hq, Hkv, D, psz, mp, B = 32, 8, 64, 16, 24, 3
+    pos = np.array([0, 37, 300], np.int32)
+    row = Hkv * D * jnp.dtype(dtype).itemsize
+    assert paged_pallas.block_pages(psz, mp, row, 2) * psz \
+        == (128 if dtype == "float32" else 256)
     r = np.random.default_rng(3)
     f = lambda *s: r.standard_normal(s).astype(np.float32)
     q, kn, vn = f(B, 1, Hq * D), f(B, 1, Hkv * D), f(B, 1, Hkv * D)

@@ -144,8 +144,10 @@ def _window_ref(q, kn, vn, kp, vp, tables, pos, n_head):
 
 # geometries of the walk: (W, page, table length, positions). "one-block"
 # is a table the kernel covers in ONE block a slot; the others make a
-# slot's loop walk blocks of P pages (``paged_pallas.block_pages``: 8
-# pages of 16, 16 of 8), with the frontier in the middle of a block, on a
+# slot's loop walk blocks of P pages (``paged_pallas.block_pages`` under
+# ``lane_tile_blocks``: 8 pages of 16, 16 of 8; under the bytes rule these
+# narrow rows' blocks cover a table or most of it), with the frontier in
+# the middle of a block, on a
 # block's first and last position, an idle slot (no turn of the loop), a
 # table P does not divide (9, 20), slots whose every block is live, and
 # live slots between idle ones: the call's first live block is not slot
@@ -195,15 +197,19 @@ def _window_inputs(seed=0, walk="one-block"):
 @pytest.mark.parametrize("dtype,tol", [("float32", 1e-5),
                                        ("bfloat16", 1.6e-2)])
 @pytest.mark.parametrize("walk_name", list(WALKS))
-def test_blocked_walk_matches_gather_reference(walk_name, dtype, tol):
+def test_blocked_walk_matches_gather_reference(lane_tile_blocks, walk_name,
+                                               dtype, tol,
+                                               blocks="lane-tile"):
     """The walk in blocks of pages against the XLA-free gather
     reference: every geometry of ``WALKS``, W = 1 and W = 8, a float32
     pool and a bf16 one (the cells': K goes to the MXU as stored; the
-    output's own rounding is the tolerance); and the scalars the kernel
-    fetches by: a whole number of blocks a slot, the owned mask padded with
-    unowned entries, each slot's own live blocks in rising order with
-    their count, the rank of its first among the call's and the next
-    slot that has one."""
+    output's own rounding is the tolerance), in blocks of one lane tile
+    (``blocks="bytes"``: the program's own); and the scalars the kernel
+    fetches by: a whole number of blocks a slot, the owned mask padded
+    with unowned entries, each slot's own live blocks in rising order
+    with their count, the rank of its first among the call's, the next
+    slot that has one, and a block's owned pages as the bits of its
+    words."""
     from replicatinggpt_tpu.ops import paged_pallas as pp
     q, kn, vn, kp, vp, tables, pos = _window_inputs(5, walk_name)
     H = _heads(walk_name)[0]
@@ -222,11 +228,13 @@ def test_blocked_walk_matches_gather_reference(walk_name, dtype, tol):
     psz, mp = kp.shape[2], tables.shape[1]
     owned = pp.gqa_owned_pages(jnp.array(pos), jnp.zeros_like(pos), mp,
                                psz, 0)
-    walk = pp.window_walk(jnp.array(tables), jnp.array(pos), psz,
-                          kp.shape[3] * 4)
-    P, nb = pp._walk_shape(mp, psz, kp.shape[3] * 4)
-    assert P == min(128 // psz, mp) and nb == -(-mp // P)
-    assert (nb > 1) == (walk_name != "one-block")
+    row = kp.shape[3] * jnp.dtype(dtype).itemsize
+    walk = pp.window_walk(jnp.array(tables), jnp.array(pos), psz, row, 2)
+    P, nb = pp._walk_shape(mp, psz, row, 2)
+    tokens = -(-pp.BLOCK_BYTES // (2 * row * 128)) * 128
+    assert P == min(-(-tokens // psz), mp) and nb == -(-mp // P)
+    if blocks == "lane-tile":
+        assert (nb > 1) == (walk_name != "one-block")
     # one walk for every layer of a step: handed in at another layer, it
     # gives that layer's output
     handed = pp.paged_window_attention(
@@ -235,7 +243,7 @@ def test_blocked_walk_matches_gather_reference(walk_name, dtype, tol):
     np.testing.assert_allclose(np.asarray(handed, np.float32), ref(2),
                                atol=tol, rtol=tol)
     assert len(walk) + 1 == pp.N_WALK          # and the pool's layer
-    table, blocks, count, rank, nxt = (np.asarray(a) for a in walk)
+    table, order, count, rank, nxt, bits = (np.asarray(a) for a in walk)
     own = table >= 0             # an unowned entry reads -1
     assert table.shape == (len(pos), nb * P)
     assert (own[:, :mp] == np.asarray(owned)).all() and not own[:, mp:].any()
@@ -246,8 +254,13 @@ def test_blocked_walk_matches_gather_reference(walk_name, dtype, tol):
     live = own.reshape(len(pos), nb, P).any(-1)
     assert count.tolist() == live.sum(1).tolist()
     assert count.tolist() == pp.live_blocks(pos, psz, P).tolist()
-    for b, row in enumerate(blocks.reshape(len(pos), nb)):
-        assert row[:count[b]].tolist() == np.flatnonzero(live[b]).tolist()
+    for b, got in enumerate(order.reshape(len(pos), nb)):
+        assert got[:count[b]].tolist() == np.flatnonzero(live[b]).tolist()
+    # page j of a block is bit j % 32 of its word j // 32
+    nw = -(-P // 32)
+    words = bits.view(np.uint32).reshape(len(pos), nb, nw)
+    for b, p, j in np.ndindex(len(pos), nb, P):
+        assert (words[b, p, j // 32] >> (j % 32)) & 1 == own[b, p * P + j]
     assert rank.tolist() == (np.cumsum(count) - count).tolist()
     has = np.flatnonzero(count)
     assert nxt[has].tolist() == has[1:].tolist() + [-1]
@@ -255,7 +268,17 @@ def test_blocked_walk_matches_gather_reference(walk_name, dtype, tol):
                for b in range(len(pos)))
 
 
-@pytest.mark.parametrize("kv_dtype,gran,walk", [
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-5),
+                                       ("bfloat16", 1.6e-2)])
+@pytest.mark.parametrize("walk_name", list(WALKS))
+def test_blocked_walk_in_the_programs_own_blocks(walk_name, dtype, tol):
+    """The same walks in the blocks ``BLOCK_BYTES`` gives (at these
+    narrow rows a block covers a slot's table, or most of it)."""
+    test_blocked_walk_matches_gather_reference(None, walk_name, dtype, tol,
+                                               blocks="bytes")
+
+
+QUANT_WALKS = [
     ("int8", "head", "one-block"), ("fp8", "page", "one-block"),
     ("fp8", "head", "one-block"), ("int8", "head", "w8-short-last-block"),
     ("int8", "head", "mid-block"), ("fp8", "page", "table-of-9"),
@@ -263,14 +286,19 @@ def test_blocked_walk_matches_gather_reference(walk_name, dtype, tol):
     ("int8", "head", "live-between-idle"),
     ("int8", "page", "w8-live-between-idle"),
     ("int8", "head", "every-block-live"), ("int8", "head", "six-heads-w1"),
-    ("fp8", "head", "six-heads-w8"), ("int8", "page", "six-heads-w8")])
-def test_windowed_kernel_quantized_parity(kv_dtype, gran, walk):
+    ("fp8", "head", "six-heads-w8"), ("int8", "page", "six-heads-w8")]
+
+
+@pytest.mark.parametrize("kv_dtype,gran,walk", QUANT_WALKS)
+def test_windowed_kernel_quantized_parity(lane_tile_blocks, kv_dtype, gran,
+                                          walk):
     """fp8 KV and head-granularity scales were the documented XLA
     seams — the per-head scale-lane selection and the saturating e4m3
     fake-quant now run inside the accumulation loop, parity-pinned
     against the dequantized gather reference, over one block and over
     a walk of several: a slot's scales ride as ONE row operand, cut by
-    block inside its loop."""
+    block inside its loop (a block of P * page tokens, whichever rule
+    sized it)."""
     from replicatinggpt_tpu.ops import paged_pallas as pp
     from replicatinggpt_tpu.quant.kv import (fake_quantize_rows,
                                              quantize_rows)
@@ -299,13 +327,22 @@ def test_windowed_kernel_quantized_parity(kv_dtype, gran, walk):
         assert np.abs(wrong - ref).max() > 1e-3
 
 
+@pytest.mark.parametrize("kv_dtype,gran,walk", QUANT_WALKS)
+def test_windowed_kernel_quantized_parity_in_the_programs_own_blocks(
+        kv_dtype, gran, walk):
+    """The same, in the blocks ``BLOCK_BYTES`` gives: an int8 or fp8
+    row's block is longer still, and its scales operand follows it."""
+    test_windowed_kernel_quantized_parity(None, kv_dtype, gran, walk)
+
+
 @pytest.mark.parametrize("walk", ["one-block", "mid-block",
                                   "w8-short-last-block",
                                   "live-between-idle",
                                   "w8-live-between-idle",
                                   "every-block-live", "six-heads-w1",
                                   "six-heads-w8"])
-def test_owned_subsets_partials_merge_to_the_reference(walk):
+def test_owned_subsets_partials_merge_to_the_reference(lane_tile_blocks,
+                                                       walk):
     """``owned`` hands a call an ARBITRARY subset of a slot's pages (a
     shard's, under the ``shard_map`` wrapper): its loop takes the blocks
     that hold one, wherever they lie, and ``fold=False`` returns the raw
@@ -335,10 +372,12 @@ def test_owned_subsets_partials_merge_to_the_reference(walk):
     np.testing.assert_allclose(np.asarray(out), ref, atol=1e-5, rtol=1e-5)
 
 
-@pytest.mark.parametrize("walk", ["one-block", "mid-block",
-                                  "w8-short-last-block",
-                                  "live-between-idle"])
-def test_sharded_window_kernel_matches_reference(walk):
+SHARDED_WALKS = ["one-block", "mid-block", "w8-short-last-block",
+                 "live-between-idle"]
+
+
+@pytest.mark.parametrize("walk", SHARDED_WALKS)
+def test_sharded_window_kernel_matches_reference(lane_tile_blocks, walk):
     """The shard_map wrapper on a 2x2 (data, model) mesh: per-shard
     table localization + cross-shard online-softmax merge of the
     kernel's ``fold=False`` partials must match the unsharded reference
@@ -374,6 +413,13 @@ def test_sharded_window_kernel_matches_reference(walk):
         layer=LAYER, k_scales=ks, v_scales=vs)
     np.testing.assert_allclose(np.asarray(out_q), ref_q, atol=1e-4,
                                rtol=1e-4)
+
+
+@pytest.mark.parametrize("walk", SHARDED_WALKS)
+def test_sharded_window_kernel_in_the_programs_own_blocks(walk):
+    """The same, in the blocks ``BLOCK_BYTES`` gives: a shard's row is
+    narrower than the pool's, so its blocks are longer."""
+    test_sharded_window_kernel_matches_reference(None, walk)
 
 
 def _dots_in_block_loop(fn, *args) -> int:
@@ -458,7 +504,9 @@ def test_a_block_is_one_pass_for_all_of_a_slots_heads(geometry):
 
 
 @pytest.mark.parametrize("check", ["gather", "int8-head", "partials"])
-def test_a_wide_window_splits_into_whole_head_passes(monkeypatch, check):
+def test_a_wide_window_splits_into_whole_head_passes(monkeypatch,
+                                                     lane_tile_blocks,
+                                                     check):
     """Where the stacked rows and the accumulator would pass the VMEM
     share (``PASS_STATE_BYTES``, cut here to fit two of six heads at W =
     8), a block takes whole-head passes, each over its own lanes, and
@@ -472,22 +520,24 @@ def test_a_wide_window_splits_into_whole_head_passes(monkeypatch, check):
         assert pp.heads_per_pass(6, 8, 64) == 2
         assert pp.block_passes(6, 64, 8) == 3
         if check == "gather":
-            test_blocked_walk_matches_gather_reference("six-heads-w8",
-                                                       "float32", 1e-5)
+            test_blocked_walk_matches_gather_reference(
+                lane_tile_blocks, "six-heads-w8", "float32", 1e-5)
         elif check == "int8-head":
-            test_windowed_kernel_quantized_parity("int8", "head",
-                                                  "six-heads-w8")
+            test_windowed_kernel_quantized_parity(
+                lane_tile_blocks, "int8", "head", "six-heads-w8")
         else:
             test_owned_subsets_partials_merge_to_the_reference(
-                "six-heads-w8")
+                lane_tile_blocks, "six-heads-w8")
     finally:
         pp._window_call.clear_cache()
 
 
-def test_launch_stats_count_the_blocks_the_device_mask_owns(kernel_backend):
+def test_launch_stats_count_the_blocks_the_device_mask_owns(
+        kernel_backend, lane_tile_blocks):
     """``serve/launch`` says how the kernel's walk engages, from the host
     mirrors: ``kv_blocks_grid`` = the turns the
-    kernel's grid takes (one a slot), and ``kv_blocks_live`` = the turns
+    kernel's grid takes (one a slot), ``kv_block_tokens`` = the tokens a
+    block covers, and ``kv_blocks_live`` = the turns
     of the slots' loops: the blocks that hold an owned page in the mask
     the kernel is handed on the device (rebuilt here from what the launch
     uploads: tables, positions, the live slots)."""
@@ -501,7 +551,7 @@ def test_launch_stats_count_the_blocks_the_device_mask_owns(kernel_backend):
         paged_kernel=True, prefix_cache=False), telemetry=tel)
     assert eng.kernel_route.decode == "pallas"
     psz, mp = eng.pool.page_size, eng.pool.max_pages
-    P = pp.block_pages(psz, mp, cfg.n_embd * 4)
+    P = pp.block_pages(psz, mp, cfg.n_embd * 4, 2)
     assert (psz, mp, P) == (8, 32, 16)
     assert eng._kv_block_pages == P           # the walk's block, not a stat
     want, dispatch = [], eng._dispatch
@@ -510,8 +560,8 @@ def test_launch_stats_count_the_blocks_the_device_mask_owns(kernel_backend):
         pos = jnp.asarray(np.where(eng._active & ~kill, eng._pos, 0),
                           jnp.int32)
         owned = pp.gqa_owned_pages(pos, jnp.zeros_like(pos), mp, psz, 0)
-        _, _, count, _, _ = pp._blocked_walk(
-            jnp.asarray(eng.pool.tables), owned, psz, cfg.n_embd * 4)
+        count = pp._blocked_walk(
+            jnp.asarray(eng.pool.tables), owned, psz, cfg.n_embd * 4, 2)[2]
         want.append(int(np.asarray(count).sum()))  # the loops' iterations
         return dispatch(k, kill, *a)
 
@@ -529,7 +579,7 @@ def test_launch_stats_count_the_blocks_the_device_mask_owns(kernel_backend):
     assert [a["kv_block_passes"] for a in stats] == want
     assert {1, 2, 3, 4} & set(want) and max(want) >= 4    # 1 + 1 + 2
     assert all(a["kv_blocks_grid"] == 3 and "kv_block_pages" not in a
-               for a in stats)
+               and a["kv_block_tokens"] == P * psz == 128 for a in stats)
 
 
 # ---------------------------------------------------------------------------

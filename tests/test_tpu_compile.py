@@ -117,27 +117,30 @@ def _paged_args(sh, B, W, C=768, psz=16, mp=64, pool_dtype=BF16):
             _s((B,), jnp.int32, sh["rep"]), _s((), jnp.int32, sh["rep"]))
 
 
-@pytest.mark.parametrize("window,quant,B,C,H", [
-    (1, False, 8, 768, 12), (8, False, 8, 768, 12), (1, True, 8, 768, 12),
+@pytest.mark.parametrize("window,quant,B,C,H,P", [
+    (1, False, 8, 768, 12, 16), (8, False, 8, 768, 12, 16),
+    (1, True, 8, 768, 12, 24),
     # the serve cells' engine: gpt2-large, 96 slots, 64 pages of 16 a slot
-    (1, False, 96, 1280, 20), (8, False, 96, 1280, 20),
-    (1, True, 96, 1280, 20), (1, "head", 96, 1280, 20)],
+    (1, False, 96, 1280, 20, 8), (8, False, 96, 1280, 20, 8),
+    (1, True, 96, 1280, 20, 16), (1, "head", 96, 1280, 20, 16)],
     ids=["w1", "w8", "w1-int8", "large-w1", "large-w8", "large-w1-int8",
          "large-w1-int8-head"])
 def test_paged_window_attention_124m(mosaic, one_chip, window, quant, B, C,
-                                     H):
-    """The walk in blocks of 8 pages (``block_pages``) at gpt2-small's
-    and gpt2-large's widths, a grid turn a slot and the slot's blocks a
+                                     H, P):
+    """The walk in blocks of ``P`` pages (``block_pages``: the K and V
+    tiles of a block carry 512 KiB, so gpt2-large's bf16 rows take 8
+    pages, gpt2-small's 16 and an int8 pool's more) at gpt2-small's and
+    gpt2-large's widths, a grid turn a slot and the slot's blocks a
     loop of traced length in the body: a block is ONE pass for all of a
     slot's heads at W = 1 (two passes of ten at gpt2-large's W = 8), and a
-    quantized pool's page or head scales one (blocks, 1 or H, 128)
+    quantized pool's page or head scales one (blocks, 1 or H, P * 16)
     operand a slot, indexed by block inside the loop."""
     from replicatinggpt_tpu.ops.paged_pallas import (block_pages,
                                                      paged_window_attention)
     sh = {"row": one_chip, "pool": one_chip, "rep": one_chip}
     args = _paged_args(sh, B, window, C=C,
                        pool_dtype=jnp.int8 if quant else BF16)
-    assert block_pages(16, 64, C * (1 if quant else 2)) == 8
+    assert block_pages(16, 64, C * (1 if quant else 2), 2) == P
     if quant:
         sc = _s((N_LAYERS, B * 64, 16) + ((H,) if quant == "head" else ()),
                 jnp.float32, one_chip)
@@ -163,9 +166,9 @@ def test_paged_gqa_attention_kexaone_widths(mosaic, one_chip, window, name):
     from replicatinggpt_tpu.ops.paged_pallas import (block_pages,
                                                      paged_gqa_attention)
     B, psz, mp = 64, 16, (9 if window else 512)
-    # blocks of 8 pages: up to 64 a slot's loop, and the ring a block and
-    # a short one
-    assert block_pages(psz, mp, 8 * 128 * 2) == 8
+    # blocks of 8 pages (128 tokens of K and V rows of 2,048 B: 512 KiB):
+    # up to 64 a slot's loop, and the ring a block and a short one
+    assert block_pages(psz, mp, 8 * 128 * 2, 2) == 8
     q = _s((B, 1, 64 * 128), BF16, one_chip)
     kv = _s((B, 1, 8 * 128), BF16, one_chip)
     # the family's pools: one layer an array, read at layer 0
@@ -184,14 +187,16 @@ def test_paged_gqa_attention_lfm2_widths(mosaic, one_chip):
     """The grouped-query kernel at LFM2-24B-A2B's published widths (32
     query heads on 8 KV heads of 64: a block of 4 x 64 rows a KV head, a
     pool row of 512 lanes) at the cell's 256 slots of 512 table entries
-    and 40,960 pages: 256 grid turns of up to 64 blocks, and a walk whose
+    and 40,960 pages: 256 grid turns of up to 32 blocks of 16 pages (256
+    tokens of rows of 1,024 B, K and V: 512 KiB), and a walk whose
     scalars fit the chip's scalar memory only because the table says
     which entries are owned (a table AND a mask were 1.19 MB of its 1 MB;
-    the table and the slots' block lists are 0.59 MB)."""
+    the table, the slots' block lists and the blocks' owned bits are 0.59
+    MB)."""
     from replicatinggpt_tpu.ops.paged_pallas import (block_pages,
                                                      paged_gqa_attention)
     B, psz, mp = 256, 16, 512
-    assert block_pages(psz, mp, 8 * 64 * 2) == 8
+    assert block_pages(psz, mp, 8 * 64 * 2, 2) == 16
     q = _s((B, 1, 32 * 64), BF16, one_chip)
     kv = _s((B, 1, 8 * 64), BF16, one_chip)
     pages = _s((1, 40_960, psz, 8 * 64), BF16, one_chip)
@@ -209,12 +214,13 @@ def test_paged_mla_attention_joyai_widths(mosaic, one_chip):
     cell's engine: 32 heads' absorbed query rows of 512 latent + 64 rotary
     lanes, padded to 640 (a 576-wide page is refused: a page copy's slice
     must be whole 128-lane tiles), 256 slots of 512 table entries over
-    40,960 pages of 16; a block of 8 pages is one (128, 640) tile, key and
+    40,960 pages of 16; a block of 32 pages (512 tokens of 1,280 B: the
+    first whole lane tiles past 512 KiB) is one (512, 640) tile, key and
     value at once; the instruction wears ``paged_mla_attention``."""
     from replicatinggpt_tpu.ops.paged_pallas import (block_pages,
                                                      paged_mla_attention)
     B, psz, mp, W = 256, 16, 512, 640
-    assert block_pages(psz, mp, W * 2) == 8
+    assert block_pages(psz, mp, W * 2, 1) == 32
     text = _compile(
         lambda q, n, p, t, pos: paged_mla_attention(
             q, n, p, t, pos, layer=0, latent=512, scale=192 ** -0.5),
