@@ -99,19 +99,20 @@ class ModelConfig:
     # Everything below is DATA of an architecture other than GPT-2's; the
     # defaults are GPT-2's, so every older preset is unchanged. The
     # families beside it, 'exaone_moe' (models/exaone_moe.py), 'lfm2_moe'
-    # (models/lfm2_moe.py) and 'joyai_llm_flash'
-    # (models/joyai_llm_flash.py), are serve-only. What a family alone
-    # decides (RMSNorm, QK-norm, which layers rotate, sigmoid scores
-    # normalised over the chosen) is its module's, not a field here: no
-    # caller can vary it.
+    # (models/lfm2_moe.py), 'joyai_llm_flash' (models/joyai_llm_flash.py)
+    # and 'olmo_hybrid' (models/olmo_hybrid.py), are serve-only. What a
+    # family alone decides (RMSNorm, QK-norm, which layers rotate, if any,
+    # sigmoid scores normalised over the chosen) is its module's, not a
+    # field here: no caller can vary it.
     family: str = "gpt"
-    # 'gpt' | 'exaone_moe' | 'lfm2_moe' | 'joyai_llm_flash'
+    # 'gpt' | 'exaone_moe' | 'lfm2_moe' | 'joyai_llm_flash' | 'olmo_hybrid'
     n_kv_head: int = 0            # KV heads (GQA); 0 = n_head
     attn_head_dim: int = 0        # head size; 0 = n_embd // n_head
     rope_theta: float = 10000.0   # rotary base, where the family rotates
     layer_types: Tuple[str, ...] = ()
     # per layer 'sliding_attention' | 'full_attention' | 'conv' (a gated
-    # short convolution, lfm2_moe's); () = all full
+    # short convolution, lfm2_moe's) | 'linear_attention' (a Gated
+    # DeltaNet layer, olmo_hybrid's); () = all full
     sliding_window: int = 0       # token i attends j, i - window < j <= i
     conv_reach: int = 0
     # taps of a conv layer's depthwise causal convolution, and the columns
@@ -140,6 +141,16 @@ class ModelConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    # Gated DeltaNet (olmo_hybrid's linear_attention layers): heads of a
+    # key_head_dim x value_head_dim float32 state a slot, q / k / v through
+    # a causal depthwise conv of conv_kernel_dim taps; allow_neg_eigval
+    # gives beta in (0, 2). 0 = no linear-attention layer
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 0
+    linear_allow_neg_eigval: bool = False
 
     @property
     def kv_heads(self) -> int:
@@ -168,7 +179,8 @@ class ModelConfig:
         the full-attention layers of a family that has other kinds)."""
         return tuple(i for i in range(self.n_layer)
                      if not (self.is_window_layer(i)
-                             or self.is_conv_layer(i)))
+                             or self.is_conv_layer(i)
+                             or self.is_linear_layer(i)))
 
     @property
     def window_layers(self) -> Tuple[int, ...]:
@@ -181,6 +193,16 @@ class ModelConfig:
         """Layers that keep ``conv_reach`` columns of state a slot."""
         return tuple(i for i in range(self.n_layer)
                      if self.is_conv_layer(i))
+
+    @property
+    def linear_layers(self) -> Tuple[int, ...]:
+        """Layers that keep a delta-rule state and conv columns a slot."""
+        return tuple(i for i in range(self.n_layer)
+                     if self.is_linear_layer(i))
+
+    def is_linear_layer(self, i: int) -> bool:
+        return bool(self.layer_types) and \
+            self.layer_types[i] == "linear_attention"
 
     def is_window_layer(self, i: int) -> bool:
         return bool(self.layer_types) and \
@@ -214,7 +236,7 @@ class ModelConfig:
     def validate(self) -> "ModelConfig":
         _ = self.head_dim
         assert self.family in ("gpt", "exaone_moe", "lfm2_moe",
-                               "joyai_llm_flash"), self.family
+                               "joyai_llm_flash", "olmo_hybrid"), self.family
         if self.family == "gpt":
             assert self.activation in ("gelu", "relu"), self.activation
             for name in ("n_kv_head", "attn_head_dim", "layer_types",
@@ -222,7 +244,7 @@ class ModelConfig:
                          "intermediate_size", "n_experts", "experts_held",
                          "experts_per_token", "moe_intermediate_size",
                          "router_norm_eps", "shared_intermediate_size",
-                         *_LATENT_FIELDS):
+                         *_LATENT_FIELDS, *_LINEAR_FIELDS):
                 assert not getattr(self, name), (
                     f"{name} is not GPT-2's: models/gpt.py has one head "
                     f"count, learned positions and a dense MLP")
@@ -236,10 +258,18 @@ class ModelConfig:
         return self
 
     def _validate_served_family(self) -> None:
-        """What models/exaone_moe.py, models/lfm2_moe.py and
-        models/joyai_llm_flash.py compute, and nothing near it."""
+        """What models/exaone_moe.py, models/lfm2_moe.py,
+        models/joyai_llm_flash.py and models/olmo_hybrid.py compute, and
+        nothing near it."""
         L = self.n_layer
         assert self.activation == "swiglu", self.activation
+        if self.family == "olmo_hybrid":
+            self._validate_linear_attention()
+        else:
+            assert not any(getattr(self, n) for n in _LINEAR_FIELDS), (
+                f"{', '.join(_LINEAR_FIELDS)}: linear attention is "
+                f"olmo_hybrid's")
+
         if self.family == "joyai_llm_flash":
             self._validate_latent_attention()
         else:
@@ -254,7 +284,9 @@ class ModelConfig:
             t in ("dense", "sparse") for t in self.mlp_layer_types), (
             f"mlp_layer_types={self.mlp_layer_types}")
         kinds = {"exaone_moe": ("full_attention", "sliding_attention"),
-                 "lfm2_moe": ("full_attention", "conv")}.get(self.family)
+                 "lfm2_moe": ("full_attention", "conv"),
+                 "olmo_hybrid": ("full_attention", "linear_attention"),
+                 }.get(self.family)
         if kinds:
             assert len(self.layer_types) == L and all(
                 t in kinds for t in self.layer_types), (
@@ -266,6 +298,14 @@ class ModelConfig:
                 "joyai_llm_flash's")
             if self.window_layers:
                 assert self.sliding_window > 0, "window layers need a window"
+        elif self.family == "olmo_hybrid":
+            assert not self.tied_head, "the family's head is untied"
+            assert not (self.conv_reach or self.sliding_window
+                        or self.n_experts or self.router_norm_eps), (
+                "conv_reach, sliding_window, experts: the family has "
+                "Gated DeltaNet and full-attention layers and a dense MLP")
+            assert "sparse" not in self.mlp_layer_types, (
+                "the family's MLP is dense in every layer")
         elif self.family == "joyai_llm_flash":
             assert not self.tied_head, "the family's head is untied"
             assert not self.conv_reach and not self.sliding_window, (
@@ -319,9 +359,26 @@ class ModelConfig:
             f"attention")
 
 
+    def _validate_linear_attention(self) -> None:
+        for name in _LINEAR_FIELDS[:5]:
+            assert getattr(self, name) > 0, (
+                f"{name}={getattr(self, name)}: a Gated DeltaNet layer needs "
+                f"it")
+        assert self.linear_num_key_heads == self.linear_num_value_heads, (
+            "one key head a value head: the family repeats no key head")
+        assert self.linear_conv_kernel_dim >= 2, (
+            "the conv reaches at least one column back")
+        assert self.linear_layers, "a hybrid has linear_attention layers"
+
+
 #: the ModelConfig fields of latent attention
 _LATENT_FIELDS = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
                   "qk_rope_head_dim", "v_head_dim")
+
+#: the ModelConfig fields of Gated DeltaNet layers
+_LINEAR_FIELDS = ("linear_num_key_heads", "linear_num_value_heads",
+                  "linear_key_head_dim", "linear_value_head_dim",
+                  "linear_conv_kernel_dim", "linear_allow_neg_eigval")
 
 
 @dataclass(frozen=True)
@@ -479,6 +536,24 @@ def _joyai_llm_flash(n_layer: int, **kw) -> ModelConfig:
         rope_theta=3.2e7, layernorm_eps=1e-6,
         mlp_layer_types=("dense",) + ("sparse",) * (n_layer - 1),
         routed_scaling=2.5, router_norm_eps=1e-20,
+        decode_cache_layout="packed", scan_layers=False, **kw)
+
+
+def _olmo_hybrid(n_layer: int, **kw) -> ModelConfig:
+    """The olmo_hybrid family's constants (Olmo-Hybrid-7B config.json,
+    OLMo 3's blocks): RMSNorm eps 1e-6 AFTER each mixer and each MLP (no
+    pre-norm), three Gated DeltaNet layers to one full-attention layer
+    (multi-head, QK-norm over the whole projection, no rotary position),
+    conv taps 4 and beta in (0, 2), a dense SwiGLU MLP in every layer,
+    untied head."""
+    pattern = ("linear_attention",) * 3 + ("full_attention",)
+    return ModelConfig(
+        family="olmo_hybrid", n_layer=n_layer, dropout=0.0,
+        attn_dropout=0.0, tied_head=False, activation="swiglu",
+        layernorm_eps=1e-6,
+        layer_types=tuple(pattern[i % 4] for i in range(n_layer)),
+        mlp_layer_types=("dense",) * n_layer, linear_conv_kernel_dim=4,
+        linear_allow_neg_eigval=True,
         decode_cache_layout="packed", scan_layers=False, **kw)
 
 
@@ -667,6 +742,35 @@ PRESETS = {
             qk_rope_head_dim=8, v_head_dim=16, intermediate_size=96,
             n_experts=16, experts_held=(0, 1), experts_per_token=4,
             moe_intermediate_size=24, shared_intermediate_size=24,
+            dtype="float32", param_dtype="float32"),
+        tokenizer="char",
+    ),
+    # Olmo-Hybrid-7B (allenai, config.json on the Hugging Face hub), THE
+    # FIRST STAGE of a four-stage pipeline on one four-chip host, serve-only:
+    # every width, 30 heads of each kind, the whole vocabulary and the
+    # untied head as published; published layers 0-7 (two whole periods of
+    # three Gated DeltaNet layers and one full layer). bf16 parameters.
+    # chipbench/configs/olmo-hybrid-7b.json states the cut.
+    "olmo-hybrid-7b": Config(
+        name="olmo-hybrid-7b",
+        model=_olmo_hybrid(
+            8, vocab_size=100_352, block_size=4096, n_head=30,
+            n_kv_head=30, attn_head_dim=128, n_embd=3840,
+            intermediate_size=11_008, linear_num_key_heads=30,
+            linear_num_value_heads=30, linear_key_head_dim=96,
+            linear_value_head_dim=192, dtype="bfloat16",
+            param_dtype="bfloat16"),
+        tokenizer="char",
+    ),
+    # the same family at test widths (CPU tests, chipbench/rehearse.py):
+    # 4 layers (linear linear linear full), 2 heads of each kind
+    "olmo-hybrid-tiny": Config(
+        name="olmo-hybrid-tiny",
+        model=_olmo_hybrid(
+            4, vocab_size=96, block_size=384, n_head=2, n_kv_head=2,
+            attn_head_dim=32, n_embd=64, intermediate_size=96,
+            linear_num_key_heads=2, linear_num_value_heads=2,
+            linear_key_head_dim=16, linear_value_head_dim=64,
             dtype="float32", param_dtype="float32"),
         tokenizer="char",
     ),

@@ -4,7 +4,8 @@
 paged entry points, the pool constructor and the parameter initialiser of
 ``cfg.family`` instead of importing ``models.gpt``'s by name; a family that
 lacks a program refuses it from inside (``models/exaone_moe.py``,
-``models/lfm2_moe.py``, ``models/joyai_llm_flash.py``), ``serve_refusals``
+``models/lfm2_moe.py``, ``models/joyai_llm_flash.py``,
+``models/olmo_hybrid.py``), ``serve_refusals``
 says at the engine's constructor which engine options a family cannot run
 under, and why (the family's own ``refusals``), and ``decide_kernel_route``
 asks the family whether the kernel fits its windowed steps.
@@ -32,7 +33,8 @@ class Family(NamedTuple):
     #: what a decode window's token block counts in its trailing columns
     step_counters: Tuple[str, ...]
     #: per-slot state the pool dict keeps beside its pages, as ``(kind,
-    #: prefix of its entries' names)``: ``window`` rings, ``conv`` state.
+    #: prefix of its entries' names)``: ``window`` rings, ``conv`` state,
+    #: ``delta`` (a linear layer's delta-rule state).
     #: Admission reserves no pages for it, page copies skip it, and the
     #: radix cache is refused over it
     slot_entries: Tuple[Tuple[str, str], ...]
@@ -143,6 +145,18 @@ def _family(name: str) -> Family:
             prefix_cache="prefix_cache: the radix cache shares full "
                          "layers' pages and cannot restore a conv layer's "
                          "state at a shared prefix's end")
+    if name == "olmo_hybrid":
+        from . import olmo_hybrid as m
+        return _served(
+            m, name, (("conv", m.CONV_ENTRY_PREFIX),
+                      ("delta", m.DELTA_ENTRY_PREFIX)), kernel=GQA_KERNEL,
+            verify="speculative verify: a rejected draft cannot be rolled "
+                   "back out of the linear layers' delta-rule state (no "
+                   "snapshot of it is kept)",
+            prefix_cache="prefix_cache: the radix cache shares full "
+                         "layers' pages and cannot restore a linear "
+                         "layer's delta-rule state at a shared prefix's "
+                         "end (no snapshot of it is kept)")
     if name == "joyai_llm_flash":
         from . import joyai_llm_flash as m
         # the latent rows are pool pages and nothing is kept a slot: the

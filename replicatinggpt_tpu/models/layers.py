@@ -1,10 +1,12 @@
 """The layer pieces the served families beside GPT-2 share
-(``models/exaone_moe.py``, ``models/lfm2_moe.py``): RMSNorm, rows times a
-parameter matrix, rotate-half RoPE, the QK-normed grouped-query projections,
-grouped scores and values, the paged kernel's contract in plain XLA, a
-prefill chunk's attention over its slot's pages, the gated MLP, THE EXPERT
-LAYER (``route`` / ``moe``: one function for every family that has routed
-experts, told which experts it holds) and the decode window's loop.
+(``models/exaone_moe.py``, ``models/lfm2_moe.py``,
+``models/joyai_llm_flash.py``, ``models/olmo_hybrid.py``): RMSNorm, rows
+times a parameter matrix, rotate-half RoPE, the QK-normed grouped-query
+projections, grouped scores and values, the paged kernel's contract in
+plain XLA, a prefill chunk's attention over its slot's pages, the gated
+MLP, THE EXPERT LAYER (``route`` / ``moe``: one function for every family
+that has routed experts, told which experts it holds) and the decode
+window's loop.
 
 Nothing here knows a family: what differs between them (which layers
 rotate, what state a slot keeps, how the head is tied) is data of
@@ -354,7 +356,8 @@ def decode_window_of(decode_step: Callable) -> Callable:
     sample steps in one program, the same carry and the same returned
     tuple. The token block gains ONE trailing column, the routed pairs
     that landed on held experts at each step (``STEP_COUNTERS``): the
-    engine fetches it with the tokens and strips it."""
+    engine fetches it with the tokens and strips it. A step that returns
+    ``pairs`` None (a family without experts) adds no column."""
     def decode_window_paged(params, tok, pos, active, budget, eos, tables,
                             cache, rngs, cfg: ModelConfig, *, sample_fn,
                             length: int, use_pallas: bool = False,
@@ -372,7 +375,8 @@ def decode_window_of(decode_step: Callable) -> Callable:
             pos = jnp.where(emitted, pos + 1, pos)
             tok = jnp.where(emitted, nxt, tok)
             active = active & (budget > 0) & ~hit_eos
-            row = jnp.concatenate([nxt, pairs[None].astype(nxt.dtype)])
+            row = nxt if pairs is None else jnp.concatenate(
+                [nxt, pairs[None].astype(nxt.dtype)])
             return (tok, pos, active, budget, cache, rngs), (row, emitted)
 
         carry = (tok, pos, active, budget, cache, rngs)

@@ -348,6 +348,11 @@ def decide_kernel_route(cfg: ModelConfig, ecfg: EngineConfig, qcfg,
             mesh=mesh, kv_quant=qcfg.kv_dtype, granularity=qcfg.granularity,
             n_pages=n_pages, n_kv_head=cfg.kv_heads)
     reasons.extend(env_reasons)
+    if cfg.linear_layers:       # the delta rule's decode kernel
+        from ..ops import gated_delta
+        reasons.extend(gated_delta.decode_envelope(
+            cfg.linear_num_value_heads, cfg.linear_key_head_dim,
+            cfg.linear_value_head_dim)[1])
     base_ok = not reasons
     # None: the family has no mixed or verify step to route, and the
     # headline follows its decode kernel alone
@@ -1507,6 +1512,7 @@ class Engine:
         s["kv_latent_bytes"] = latent
         s["kv_window_bytes"] = kinds.get("window", 0)
         s["conv_state_bytes"] = kinds.get("conv", 0)
+        s["delta_state_bytes"] = kinds.get("delta", 0)
         # bytes of the compute-dtype copies made at build (0: the tree
         # came in the compute dtype and is served as it is)
         s["weight_cast_bytes"] = self._cast_bytes
@@ -1640,7 +1646,8 @@ class Engine:
             cache = self.pool.cache
             with self.tel.phase("serve/prefill", self._tb + ENGINE_TRACK,
                                 tokens=P - claimed, chunks=n_chunks,
-                                cached_tokens=claimed):
+                                cached_tokens=claimed,
+                                **self._prefill_stats(P - claimed)):
                 for c in range(n_chunks):
                     tc_us = (self.tel.now_us() if self.tel.enabled
                              else 0.0)
@@ -1819,7 +1826,8 @@ class Engine:
             cache = self.pool.cache
             with self.tel.phase("serve/prefill", self._tb + ENGINE_TRACK,
                                 tokens=limit - off, chunks=n,
-                                cached_tokens=off):
+                                cached_tokens=off,
+                                **self._prefill_stats(limit - off)):
                 for c in range(n):
                     cache = self._prefill_guard(
                         self.served_params,
@@ -1981,8 +1989,13 @@ class Engine:
                 * fx["swa_token_bytes"]
         if "conv_slot_bytes" in fx:
             state = n_active * fx["conv_slot_bytes"] * k
-            extra.update(conv_state_bytes=state,
-                         short_conv_bytes=fx["conv_weight_bytes"] * k + state)
+            extra["conv_state_bytes"] = state
+            if fx["conv_weight_bytes"]:
+                extra["short_conv_bytes"] = (fx["conv_weight_bytes"] * k
+                                             + state)
+        if "delta_slot_bytes" in fx:     # read and written once a step
+            extra["delta_state_bytes"] = (2 * n_active * k
+                                          * fx["delta_slot_bytes"])
         if "expert_bytes" in fx:
             extra.update(expert_weight_bytes=fx["expert_bytes"] * k,
                          moe_rows=n_active * k)
@@ -2021,6 +2034,25 @@ class Engine:
                     kv_block_passes=n_live * self._kv_block_passes,
                     kv_blocks_grid=self.ecfg.pool_size)
 
+    def _prefill_stats(self, tokens: int) -> dict:
+        """What a family's chunked scan adds to the stats of
+        ``serve/prefill`` (empty for a family without one):
+        ``gdn_scan_bytes`` / ``gdn_scan_flops``, what the scans of the
+        prompt's ``tokens`` must move and compute over the linear layers,
+        chunk by chunk (``ops.gated_delta.chunk_scan_cost``)."""
+        fx = self._launch_extra or {}
+        if "gdn_scan" not in fx:
+            return {}
+        from ..ops.gated_delta import chunk_scan_cost
+        n_layers, heads, dk, dv = fx["gdn_scan"]
+        moved = work = 0
+        for start in range(0, tokens, self._chunk):
+            b, f = chunk_scan_cost(min(self._chunk, tokens - start), heads,
+                                   dk, dv)
+            moved, work = moved + b, work + f
+        return dict(gdn_scan_bytes=n_layers * moved,
+                    gdn_scan_flops=n_layers * work)
+
     def _family_launch_stats(self) -> Optional[dict]:
         """What a family's state beside the pages and its experts add to
         the stats of ``serve/launch``, as bytes a unit (None: GPT-2, whose
@@ -2029,12 +2061,17 @@ class Engine:
         ``conv_slot_bytes`` (a slot's state over the conv layers, read and
         written once a step) and ``conv_weight_bytes`` (the conv layers'
         projections and taps) for ``conv_state_bytes`` and
-        ``short_conv_bytes``; ``expert_bytes`` (the held experts over the
-        sparse layers) for ``expert_weight_bytes``: a decode step streams
-        every held expert once (at 64 live rows 98% of them get a token),
-        so that is per step whatever was routed; ``mla_token_bytes`` (a
-        context token's latent rows over the layers: the model's values,
-        not the lanes the rows are padded to) for ``mla_kv_bytes``."""
+        ``short_conv_bytes`` (where the conv layers have weights of that
+        name); ``delta_slot_bytes`` (a slot's delta-rule state over the
+        linear layers) for ``delta_state_bytes`` and ``gdn_scan`` (the
+        linear layers, heads, key and value head) for the prefill's
+        ``gdn_scan_bytes`` / ``gdn_scan_flops``; ``expert_bytes`` (the
+        held experts over the sparse layers) for ``expert_weight_bytes``: a
+        decode step streams every held expert once (at 64 live rows 98% of
+        them get a token), so that is per step whatever was routed;
+        ``mla_token_bytes`` (a context token's latent rows over the layers:
+        the model's values, not the lanes the rows are padded to) for
+        ``mla_kv_bytes``."""
         cfg = self.cfg
         out = {}
         state = self.pool.slot_state()
@@ -2050,6 +2087,13 @@ class Engine:
                 conv_weight_bytes=sum(
                     a.nbytes for lp in self.served_params["layers"]
                     for n, a in lp.items() if n.startswith("conv_")))
+        if state.get("delta"):
+            out.update(
+                delta_slot_bytes=(sum(a.nbytes for a in state["delta"])
+                                  // self.pool.n_slots),
+                gdn_scan=(len(cfg.linear_layers), cfg.linear_num_value_heads,
+                          cfg.linear_key_head_dim,
+                          cfg.linear_value_head_dim))
         if cfg.n_experts:
             out["expert_bytes"] = sum(
                 a.nbytes for lp in self.served_params["layers"]

@@ -4,7 +4,8 @@
 names the program emits (kernel ``name=``, ``named_scope``, ``serve/*``
 spans); tier-1 is ``pytest tests/``, so they are imported here, not copied.
 Each function keeps its own module's globals and helpers; a module-scoped
-fixture (``kexaone``, ``lfm2``, ``joyai_config``) comes along by name; no
+fixture (``kexaone``, ``lfm2``, ``joyai_config``, ``olmo_config``,
+``olmo_launches``) comes along by name; no
 two of the modules give a test the same name.
 
 One test is collected in a wrapper: ``test_lfm2_new_cell_reports_what_it_must``
@@ -21,6 +22,7 @@ from chipbench.test_chipbench import *  # noqa: F401,F403
 from chipbench.test_serve_family import *  # noqa: F401,F403
 from chipbench.test_serve_joyai import *  # noqa: F401,F403
 from chipbench.test_serve_lfm2 import *  # noqa: F401,F403
+from chipbench.test_serve_olmo_hybrid import *  # noqa: F401,F403
 from chipbench.test_trace_stats import *  # noqa: F401,F403
 
 _LFM2_LAST = {"configs": "lfm2-24b-a2b", "per_layer": _lfm2.NEW[-1]}

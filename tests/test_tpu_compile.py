@@ -256,6 +256,35 @@ def test_joyai_decode_step_carries_its_scopes(mosaic, one_chip):
                              n) for n in op_names)
 
 
+def test_olmo_hybrid_decode_updates_the_state_in_place(mosaic, one_chip):
+    """The olmo_hybrid decode window (published widths, the first four
+    layers: linear linear linear full) compiled for the described v5e on
+    the Pallas route: the recurrence is the kernel ``gdn_recurrence``, under
+    the scopes the cell's readers and the trace's breakdown read, and every
+    slot's float32 delta-rule state (256 x 15 x 96 x 384, 2.2 MB a slot)
+    is the kernel's aliased output: no copy of it stands in the program."""
+    import dataclasses
+    from replicatinggpt_tpu.models import olmo_hybrid
+    base = get_config("olmo-hybrid-7b").model
+    cfg = dataclasses.replace(base, n_layer=4,
+                              layer_types=base.layer_types[:4],
+                              mlp_layer_types=("dense",) * 4)
+    text = _lower_decode_window(one_chip, cfg, olmo_hybrid, 256, 2048,
+                                256).compile().as_text()
+    assert sorted(set(re.sub(r"\.\d+$", "", n)
+                      for n in _kernel_names(text))) == [
+        "gdn_recurrence", "paged_window_attention"]
+    op_names = re.findall(r'op_name="([^"]*)"', text)
+    for scope in ("gdn", "gdn_recurrence", "attn_global", "kv_scatter",
+                  "mlp", "head", "embed", "sample"):
+        assert any(re.search(rf"(^|/){scope}(/|$)", n) for n in op_names), \
+            scope
+    state = r"f32\[256,15,96,384\]"
+    assert not re.search(rf"= {state}\S* copy(-start)?\(", text)
+    assert len(re.findall(rf"%gdn_recurrence[\w.]* = \(f32\[256,15,384\]"
+                          rf"\S*, {state}", text)) == 3
+
+
 def _lower_decode_window(one_chip, cfg, family, B, n_pages, mp,
                          served=None):
     """The engine's decode window (k = 1, the Pallas route, pages of 16)
@@ -314,7 +343,8 @@ def test_lfm2_decode_step_carries_its_scopes(mosaic, one_chip):
     ("lfm2-24b-a2b", {"paged_window_attention": 2}),
     ("gpt2-large", {"paged_window_attention": 3}),
     ("joyai-llm-flash", {"paged_mla_attention": 10}),
-], ids=["kexaone", "lfm2", "gpt2-layers-unrolled", "joyai"])
+    ("olmo-hybrid-7b", {"gdn_recurrence": 6, "paged_window_attention": 2}),
+], ids=["kexaone", "lfm2", "gpt2-layers-unrolled", "joyai", "olmo-hybrid"])
 def test_decode_program_lowers_the_kernel_once_a_layer_kind(mosaic, one_chip,
                                                             preset, kinds):
     """Every process start traces and lowers its decode program, and
@@ -326,12 +356,13 @@ def test_decode_program_lowers_the_kernel_once_a_layer_kind(mosaic, one_chip,
     layers are a Python loop the LOWERED module holds each kernel ONCE A
     KIND (K-EXAONE's 6 window layers and 2 full layers: 2 kernels, not 8;
     LFM2's 2 full layers: 1; gpt2-large cut to 3 layers and unrolled: 1;
-    JoyAI-LLM-Flash's 10 latent layers: 1),
+    JoyAI-LLM-Flash's 10 latent layers: 1; Olmo-Hybrid's 6 Gated
+    DeltaNet layers and 2 full layers: 2),
     and every layer of a kind calls the one private function, which the
     compiler inlines afterwards."""
     import dataclasses
     from replicatinggpt_tpu.models import (exaone_moe, gpt, joyai_llm_flash,
-                                           lfm2_moe)
+                                           lfm2_moe, olmo_hybrid)
     cfg = get_config(preset).model
     if preset == "gpt2-large":
         cfg = dataclasses.replace(cfg, n_layer=3, scan_layers=False,
@@ -343,6 +374,9 @@ def test_decode_program_lowers_the_kernel_once_a_layer_kind(mosaic, one_chip,
     elif preset == "joyai-llm-flash":
         low = _lower_decode_window(one_chip, cfg, joyai_llm_flash, 256,
                                    40_960, 512)
+    elif preset == "olmo-hybrid-7b":
+        low = _lower_decode_window(one_chip, cfg, olmo_hybrid, 256, 10_240,
+                                   256)
     else:
         low = _lower_decode_window(one_chip, cfg, exaone_moe, 64, 10_240,
                                    512)
@@ -353,7 +387,8 @@ def test_decode_program_lowers_the_kernel_once_a_layer_kind(mosaic, one_chip,
         held = re.findall(rf'kernel_name = "{name}"', text)
         assert len(held) == 1, (name, len(held))
     # and the layers of a kind are calls of the kind's one function
-    calls = re.findall(r"call @(_(?:gqa|window|mla)_call\w*)\(", text)
+    calls = re.findall(
+        r"call @(_(?:gqa|window|mla)_call\w*|gated_delta_decode\w*)\(", text)
     assert sorted(map(calls.count, set(calls))) == sorted(kinds.values())
 
 
