@@ -36,7 +36,10 @@ the fresh K/V window rides separately and folds in after the loop,
 so the kernel attends the STALE pool bit-equivalently to
 write-then-attend (cache[pos] would hold exactly the fresh k/v) — the
 caller scatters the fresh row afterwards, mirroring
-ops/decode_pallas.py's packed kernel.
+ops/decode_pallas.py's packed kernel. A block's copies start a page at a
+time but are waited by their bytes: one wait a pool a set bit of the
+block's owned-page count, so a whole block of 32 latent pages is one
+wait, not 32.
 
 Every process start traces and lowers its programs, compile cache warm
 or not, so what the body costs to trace is paid in every cell's set-up:
@@ -63,6 +66,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import shard_map
 from jax.experimental import pallas as pl
 
@@ -258,9 +262,20 @@ def live_blocks(pos, page_size: int, n_block: int):
     return -(-pos // (page_size * n_block))
 
 
-# scalar-prefetch operands ``_walk_blocks`` reads: ``_blocked_walk``'s six
-# and the pool's layer
-N_WALK = 7
+def block_waits(pos, page_size: int, n_block: int):
+    """The DMA waits a pool that the unsharded walk's loop makes for a
+    slot at ``pos`` (numpy; the engine's ``kv_block_waits`` sums it over
+    the live slots and the pools): a live block waits once a set bit of
+    its owned pages' count (``_walk_blocks``), so a whole block of a
+    power of two is one wait and a slot's last block one to
+    ``n_block.bit_length()``."""
+    full, rest = np.divmod(-(-np.asarray(pos) // page_size), n_block)
+    return full * int(n_block).bit_count() + np.bitwise_count(rest)
+
+
+# scalar-prefetch operands ``_walk_blocks`` reads: ``_blocked_walk``'s
+# seven and the pool's layer
+N_WALK = 8
 
 
 @jax.named_scope("kv_gather")
@@ -268,7 +283,8 @@ def _blocked_walk(tables: jnp.ndarray, owned: jnp.ndarray, page_size: int,
                   row_bytes: int, tiles: int) -> tuple:
     """The walk every kernel makes, from a (B, max_pages) table and the
     mask of the entries a slot's rows read: the scalars ``_walk_blocks``
-    reads beside the layer, ``(table, blocks, count, rank, next, bits)``.
+    reads beside the layer, ``(table, blocks, count, rank, next, bits,
+    pages)``.
     What is left OUTSIDE the kernel of addressing the pool's pages, hence
     the scope; the same for every layer of a step.
 
@@ -288,7 +304,8 @@ def _blocked_walk(tables: jnp.ndarray, owned: jnp.ndarray, page_size: int,
     ``bits[(b * n_blocks + p) * words + w]`` holds the owned pages
     ``w * WORD ..`` of block p of slot b, page j in bit j % WORD: the mask
     of a block's columns in as many words as its pages need, however many
-    pages they are."""
+    pages they are. ``pages[b * n_blocks + p]`` counts them: what the
+    block's DMA waits add up to."""
     B, mp = tables.shape
     P, nb = _walk_shape(mp, page_size, row_bytes, tiles)
     pad = ((0, 0), (0, nb * P - mp))
@@ -300,7 +317,8 @@ def _blocked_walk(tables: jnp.ndarray, owned: jnp.ndarray, page_size: int,
     bit = jnp.left_shift(jnp.uint32(1), jnp.arange(WORD, dtype=jnp.uint32))
     bits = jnp.sum(jnp.where(words.reshape(B, nb, nw, WORD), bit,
                              jnp.uint32(0)), axis=3, dtype=jnp.uint32)
-    live = owned.reshape(B, nb, P).any(axis=2)
+    pages = owned.reshape(B, nb, P).sum(axis=2, dtype=jnp.int32)
+    live = pages > 0
     # a slot's i-th live block is the first with i + 1 live blocks up to
     # and with it: as many blocks as have i or fewer (no sort)
     upto = jnp.cumsum(live, axis=1, dtype=jnp.int32)
@@ -313,7 +331,8 @@ def _blocked_walk(tables: jnp.ndarray, owned: jnp.ndarray, page_size: int,
     return (table, blocks.reshape(B * nb), count,
             jnp.cumsum(count, dtype=jnp.int32) - count,
             jnp.where(nxt < B, nxt, -1),
-            jax.lax.bitcast_convert_type(bits, jnp.int32).reshape(-1))
+            jax.lax.bitcast_convert_type(bits, jnp.int32).reshape(-1),
+            pages.reshape(B * nb))
 
 
 def window_walk(tables: jnp.ndarray, pos: jnp.ndarray, page_size: int,
@@ -372,12 +391,21 @@ def _walk_blocks(walk, pools, bufs, sem, n_block: int, n_blocks: int,
     own: the copies of block t + 1 run under the arithmetic of block t.
     A slot's last block sends for the first block of the next slot that
     has one, so the buffers do not drain at a slot's edge.
+    The copies start one a page, but a block is waited by its bytes, not
+    a page at a time: all of a block's copies into a half signal that
+    half's semaphore of the pool, a DMA wait takes its descriptor's bytes
+    off it, and only the block's own copies are in flight there (the
+    next block's go to the other half), so waits whose rows add up to
+    the block's owned pages (its count in the walk's scalars) release
+    exactly when they have all landed. A size is static, so it is one
+    wait of ``2**k`` pages a set bit ``k`` of the count: one a pool for
+    a whole block of a power of two, ``n_block.bit_length()`` at most.
     Unowned pages are not fetched, and ``cols()`` masks their columns:
     what a half holds there is an older page or the zeros of the
     call's first turn. A slot with no live block reads its count here
     and copies nothing."""
     (table_ref, blocks_ref, count_ref, rank_ref, next_ref, bits_ref,
-     layer_ref) = walk
+     pages_ref, layer_ref) = walk
     P, psz, nw = n_block, page_size, _mask_words(n_block)
     b = pl.program_id(0)
     n = count_ref[b]
@@ -387,20 +415,30 @@ def _walk_blocks(walk, pools, bufs, sem, n_block: int, n_blocks: int,
         for buf in bufs:
             buf[...] = jnp.zeros_like(buf)
 
-    def copies(slot, p, half, act):
+    def start(slot, p, half):
         def page(j, _):
             @pl.when(table_ref[slot, p * P + j] >= 0)
             def _owned():
                 for a, (pool, buf) in enumerate(zip(pools, bufs)):
-                    act(pltpu.make_async_copy(
+                    pltpu.make_async_copy(
                         pool.at[layer_ref[0], table_ref[slot, p * P + j]],
                         buf.at[half, pl.ds(pl.multiple_of(j * psz, psz),
                                            psz)],
-                        sem.at[half, a]))
+                        sem.at[half, a]).start()
 
         # a loop, not P copies of the body: the program is traced and
         # lowered at every process start, and these are most of its size
         jax.lax.fori_loop(0, P, page, None)
+
+    def wait(half, n_pages):
+        # a wait of 2**k pages' rows a set bit k of the owned count: the
+        # rows only size the descriptor, whose bytes come off the semaphore
+        for k in range(P.bit_length()):
+            @pl.when((n_pages >> k) & 1 == 1)
+            def _bit():
+                for a, buf in enumerate(bufs):
+                    rows = buf.at[half, pl.ds(0, psz << k)]
+                    pltpu.make_async_copy(rows, rows, sem.at[half, a]).wait()
 
     def block(i, _):
         p = blocks_ref[b * n_blocks + i]
@@ -409,18 +447,18 @@ def _walk_blocks(walk, pools, bufs, sem, n_block: int, n_blocks: int,
 
         @pl.when(rank == 0)
         def _first():
-            copies(b, p, half, lambda c: c.start())
+            start(b, p, half)
 
         last = i + 1 == n
         ahead = jnp.where(last, next_ref[b], b)
 
         @pl.when(ahead >= 0)
         def _ahead():
-            copies(ahead,
-                   blocks_ref[ahead * n_blocks + jnp.where(last, 0, i + 1)],
-                   1 - half, lambda c: c.start())
+            start(ahead,
+                  blocks_ref[ahead * n_blocks + jnp.where(last, 0, i + 1)],
+                  1 - half)
 
-        copies(b, p, half, lambda c: c.wait())
+        wait(half, pages_ref[b * n_blocks + p])
 
         def cols():
             # a column's bit in the block's words: a few vector steps a

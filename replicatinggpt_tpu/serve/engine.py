@@ -89,7 +89,7 @@ Every step marks its host phases through ``self.tel.phase`` (a
 ``serve/launch``, ``serve/fetch``, ``serve/commit``), with the live
 context (``live_tokens``, ``live_kv_bytes``, ``stochastic_rows``, the
 paged kernel's ``kv_blocks_live`` / ``kv_block_tokens`` /
-``kv_block_passes`` / ``kv_blocks_grid``, from the host
+``kv_block_passes`` / ``kv_block_waits`` / ``kv_blocks_grid``, from the host
 mirrors) as stats of ``serve/launch``;
 docs/observability.md
 has the vocabulary. Construction is the ``setup/engine`` span of the
@@ -954,12 +954,13 @@ class Engine:
         # block: K and V, or a latent row that is both) and the passes a
         # block takes in the decode step (one query row a head)
         self._kv_block_pages = self._kv_block_passes = 0
+        self._kv_block_tiles = 1 if cfg.kv_lora_rank else 2
         if self._use_pallas:
             from ..ops.paged_pallas import block_pages, block_passes
             self._kv_block_pages = block_pages(
                 self.pool.page_size, self.pool.max_pages,
                 self.pool.kv_array.shape[-1] * itemsize,
-                1 if cfg.kv_lora_rank else 2)
+                self._kv_block_tiles)
             self._kv_block_passes = block_passes(
                 cfg.n_head, cfg.head_dim, n_kv_head=cfg.kv_heads)
         log.info("kernel route: %s (decode=%s window=%s sharded=%s%s)",
@@ -2022,16 +2023,21 @@ class Engine:
         passes those iterations make, a score product, an online update
         and a value product each: ``ops.paged_pallas.block_passes`` a
         block, so over ``kv_blocks_live`` it reads 1 where a block is ONE
-        pass for all of a slot's heads) and ``kv_blocks_grid`` (the
+        pass for all of a slot's heads), ``kv_block_waits`` (the DMA
+        waits those iterations make, ``ops.paged_pallas.block_waits`` a
+        pool: over ``kv_blocks_live`` it reads the pools, one wait each,
+        where a slot's blocks are whole) and ``kv_blocks_grid`` (the
         turns the kernel's grid takes there: one a slot, so live over
         grid reads blocks a turn)."""
-        from ..ops.paged_pallas import live_blocks
-        n_live = int(live_blocks(self._pos[live], self.pool.page_size,
-                                 self._kv_block_pages).sum())
+        from ..ops.paged_pallas import block_waits, live_blocks
+        pos, psz, P = self._pos[live], self.pool.page_size, \
+            self._kv_block_pages
+        n_live = int(live_blocks(pos, psz, P).sum())
         return dict(kv_blocks_live=n_live,
-                    kv_block_tokens=self._kv_block_pages
-                    * self.pool.page_size,
+                    kv_block_tokens=P * psz,
                     kv_block_passes=n_live * self._kv_block_passes,
+                    kv_block_waits=int(block_waits(pos, psz, P).sum())
+                    * self._kv_block_tiles,
                     kv_blocks_grid=self.ecfg.pool_size)
 
     def _prefill_stats(self, tokens: int) -> dict:

@@ -62,3 +62,36 @@ def lane_tile_blocks(monkeypatch):
     jax.clear_caches()
     yield
     jax.clear_caches()
+
+
+@pytest.fixture()
+def dma_interpret(monkeypatch):
+    """The Pallas kernels under the TPU interpreter that models each DMA
+    and its semaphore (``pltpu.InterpretParams``), with its race detector
+    on: a copy waited short of its bytes leaves its rows unlanded, which
+    the default interpreter (a copy done when it starts) cannot tell from
+    a whole wait. Fails the test if any kernel call found a race, or if
+    no kernel ran under it. Every jit cache is emptied on both sides."""
+    import jax
+    from jax._src.pallas.mosaic.interpret import \
+        interpret_pallas_call as tpu_interpret
+    from jax.experimental.pallas import tpu as pltpu
+
+    from replicatinggpt_tpu.ops import flash_pallas
+
+    made, real = [], tpu_interpret.RaceDetectionState
+
+    def detector(**kw):
+        made.append(real(**kw))
+        return made[-1]
+
+    monkeypatch.setattr(tpu_interpret, "RaceDetectionState", detector)
+    prev = flash_pallas._INTERPRET
+    flash_pallas.set_interpret(pltpu.InterpretParams(detect_races=True))
+    jax.clear_caches()
+    try:
+        yield
+    finally:
+        flash_pallas.set_interpret(prev)
+        jax.clear_caches()
+    assert made and not any(r.races_found for r in made)

@@ -328,6 +328,22 @@ def test_gqa_kernel_walks_a_ring_from_page0(lane_tile_blocks, psz, mp,
     assert np.abs(np.asarray(got, np.float32) - want).max() < tol
 
 
+@pytest.mark.parametrize("mp,window,pos", [
+    (9, 128, [300, 140, 1000]),       # K-EXAONE's ring: blocks of 8 + 1
+    (16, 200, [400, 1000, 77]),       # 8 + 5, and 5 in a first block
+    (16, 240, [255, 1000]),           # 8 + 7 and 8 + 8
+], ids=["ring-of-9", "window-200", "window-240"])
+@pytest.mark.parametrize("heads", GQA_HEADS, ids=list(GQA_HEAD_IDS))
+def test_a_windows_blocks_wait_for_their_owned_pages_bytes(
+        dma_interpret, lane_tile_blocks, mp, window, pos, heads):
+    """A window layer's ring walk under the interpreter that models each
+    copy and its semaphore: a block is waited by its owned pages' bytes,
+    and the rings' blocks own 1, 5, 7 and 8 of their 8 pages (every bit
+    of the waits); a wait short of them would read unlanded rows."""
+    test_gqa_kernel_walks_a_ring_from_page0(
+        lane_tile_blocks, 16, mp, window, pos, "float32", 1e-5, heads)
+
+
 def test_pages_behind_the_window_are_not_owned():
     owned = np.asarray(paged_pallas.gqa_owned_pages(
         jnp.asarray([41, 3], jnp.int32), jnp.zeros((2,), jnp.int32), 8, 8,

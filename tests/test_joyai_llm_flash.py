@@ -300,7 +300,8 @@ def _einsum_latent(q, new, pages, tables, pos, latent, scale):
 
 @pytest.mark.parametrize("dtype,tol", [("float32", 1e-5),
                                        ("bfloat16", 1.6e-2)])
-def test_mla_kernel_against_its_contract_at_published_widths(dtype, tol):
+def test_mla_kernel_against_its_contract_at_published_widths(
+        dtype, tol, pos=(0, 5, 512, 513, 1700, 2060)):
     """``paged_mla_attention`` at the published widths (32 heads, rows of
     512 latent + 64 rotary lanes padded to 640, pages of 16) against the
     contract by hand AND ``_xla_mla_attention``, over a table of 129
@@ -312,7 +313,7 @@ def test_mla_kernel_against_its_contract_at_published_widths(dtype, tol):
     (its fourth block), and one in the table's one-page last block
     (2,060); the pool stacked two layers deep and read at layer 1."""
     H, W, R, psz, mp = 32, 640, 512, 16, 129
-    pos = np.array([0, 5, 512, 513, 1700, 2060], np.int32)
+    pos = np.array(pos, np.int32)
     B = len(pos)
     assert paged_pallas.block_pages(psz, mp, W * jnp.dtype(dtype).itemsize,
                                     1) == (32 if dtype == "bfloat16" else 16)
@@ -337,6 +338,19 @@ def test_mla_kernel_against_its_contract_at_published_widths(dtype, tol):
     np.testing.assert_allclose(np.asarray(xla), want, atol=1e-4, rtol=1e-4)
     ok, why = paged_pallas.mla_attention_envelope(H, W, psz)
     assert ok and not why
+
+
+@pytest.mark.parametrize("pos", [[496, 16, 1024, 0], [1700, 513, 2060,
+                                                     1535]],
+                         ids=["31-1-32-idle", "11-1-1-32"])
+def test_mla_blocks_wait_for_their_owned_pages_bytes(dma_interpret, pos):
+    """The latent kernel under the interpreter that models each copy and
+    its semaphore, at the published widths: a block of 32 pages is
+    waited by its owned pages' bytes, one wait a set bit of their count,
+    and the slots' last blocks own 31, 1, 32 and 11 pages (every bit of
+    the six); a wait short of them would read unlanded rows."""
+    test_mla_kernel_against_its_contract_at_published_widths(
+        "bfloat16", 1.6e-2, pos)
 
 
 @pytest.mark.parametrize("change,word", [
@@ -543,7 +557,7 @@ def test_a_block_is_as_many_pages_as_its_rows_bytes_ask(request, monkeypatch,
         row_bytes = eng.pool.kv_array.shape[-1] * 4
         psz, P = PSZ, eng._kv_block_pages
         assert (row_bytes, P) == (512, 32)
-        want, other, dispatch = [], [], eng._dispatch
+        want, waits, other, dispatch = [], [], [], eng._dispatch
 
         def spy(k, kill, *a):
             pos = np.where(eng._active & ~kill, eng._pos, 0)
@@ -551,6 +565,8 @@ def test_a_block_is_as_many_pages_as_its_rows_bytes_ask(request, monkeypatch,
                 jnp.asarray(eng.pool.tables), jnp.asarray(pos, jnp.int32),
                 psz, row_bytes, 1)
             want.append(int(np.asarray(walk[2]).sum()))
+            # one pool: a wait a set bit of each block's owned pages
+            waits.append(int(np.bitwise_count(np.asarray(walk[6])).sum()))
             other.append(int(paged_pallas.live_blocks(pos, psz, 16).sum()))
             return dispatch(k, kill, *a)
 
@@ -560,6 +576,7 @@ def test_a_block_is_as_many_pages_as_its_rows_bytes_ask(request, monkeypatch,
         stats = [ev["args"] for ev in tel.events
                  if ev["name"] == "serve/launch"]
         assert want and [a["kv_blocks_live"] for a in stats] == want
+        assert [a["kv_block_waits"] for a in stats] == waits
         assert all(a["kv_block_tokens"] == P * psz == 256 for a in stats)
         assert want != other             # two blocks of 256, not three of 128
     finally:

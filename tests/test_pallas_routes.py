@@ -149,9 +149,10 @@ def _window_ref(q, kn, vn, kp, vp, tables, pos, n_head):
 # narrow rows' blocks cover a table or most of it), with the frontier in
 # the middle of a block, on a
 # block's first and last position, an idle slot (no turn of the loop), a
-# table P does not divide (9, 20), slots whose every block is live, and
-# live slots between idle ones: the call's first live block is not slot
-# 0's, and the fetch-ahead crosses one idle slot and two
+# table P does not divide (9, 20), slots whose every block is live,
+# live slots between idle ones (the call's first live block is not slot
+# 0's, and the fetch-ahead crosses one idle slot and two), and slots whose
+# last block owns 1, P - 1 and P of its 8 pages (7 in a slot's only block)
 WALKS = {
     "one-block": (4, 8, 4, [17, 9, 0]),
     "mid-block": (1, 16, 24, [200, 70, 0]),
@@ -163,6 +164,7 @@ WALKS = {
     "w8-live-between-idle": (8, 8, 20, [0, 0, 152, 0, 9]),
     "six-heads-w1": (1, 16, 24, [200, 70, 0]),
     "six-heads-w8": (8, 8, 20, [150, 128, 3, 0]),
+    "last-block-owns-1-7-8": (1, 16, 24, [100, 240, 256, 129, 0]),
 }
 
 # heads and head size of a walk's rows: two of 32 (one 64-lane row)
@@ -243,11 +245,14 @@ def test_blocked_walk_matches_gather_reference(lane_tile_blocks, walk_name,
     np.testing.assert_allclose(np.asarray(handed, np.float32), ref(2),
                                atol=tol, rtol=tol)
     assert len(walk) + 1 == pp.N_WALK          # and the pool's layer
-    table, order, count, rank, nxt, bits = (np.asarray(a) for a in walk)
+    table, order, count, rank, nxt, bits, n_owned = (np.asarray(a)
+                                                      for a in walk)
     own = table >= 0             # an unowned entry reads -1
     assert table.shape == (len(pos), nb * P)
     assert (own[:, :mp] == np.asarray(owned)).all() and not own[:, mp:].any()
     assert (table[:, :mp][own[:, :mp]] == tables[own[:, :mp]]).all()
+    # a block's owned pages, counted: what its waits add up to
+    assert n_owned.tolist() == own.reshape(-1, P).sum(-1).tolist()
     # a block is live if it owns a page: a slot's loop takes its own live
     # blocks in rising order, and the live blocks hand the double buffer
     # on to one another, slot after slot
@@ -422,31 +427,71 @@ def test_sharded_window_kernel_in_the_programs_own_blocks(walk):
     test_sharded_window_kernel_matches_reference(None, walk)
 
 
-def _dots_in_block_loop(fn, *args) -> int:
-    """``dot_general``s inside the paged kernel's loop over a slot's
-    blocks (the top-level ``while`` of the kernel's body: the page
-    copies' loops inside it hold none), from the jaxpr of ``fn``."""
-    def subs(eqn):
-        for v in eqn.params.values():
-            for x in (v if isinstance(v, (tuple, list)) else (v,)):
-                j = getattr(x, "jaxpr", x)
-                if hasattr(j, "eqns"):
-                    yield j
+# walks under the interpreter that models DMAs: slots whose last block
+# owns 1, 7 and 8 of its 8 pages (every bit of a block's waits), a table P
+# does not divide, a W = 8 walk of 16-page blocks (a last block of 3 and
+# one of 1) and live slots between idle ones (the fetch-ahead across
+# slots: a block's copies started a slot earlier)
+DMA_WALKS = ["last-block-owns-1-7-8", "table-of-9", "w8-short-last-block",
+             "live-between-idle"]
 
-    def count(jaxpr):
-        return sum((e.primitive.name == "dot_general")
-                   + sum(count(j) for j in subs(e)) for e in jaxpr.eqns)
 
+@pytest.mark.parametrize("walk", DMA_WALKS)
+def test_a_block_waits_for_its_owned_pages_bytes(dma_interpret,
+                                                 lane_tile_blocks, walk):
+    """A block's copies are waited by their bytes (``_walk_blocks``): a
+    wait short of them leaves rows unlanded, which only the interpreter
+    that models each copy and its semaphore can see (the default one
+    lands a copy when it starts). Under it, every walk above matches the
+    gather reference and no read races a copy."""
+    test_blocked_walk_matches_gather_reference(lane_tile_blocks, walk,
+                                               "float32", 1e-5)
+
+
+@pytest.mark.parametrize("walk", SHARDED_WALKS)
+def test_a_shards_subset_waits_for_its_owned_pages_bytes(dma_interpret,
+                                                         lane_tile_blocks,
+                                                         walk):
+    """The same for a shard's arbitrary subset of a slot's pages (the
+    owned mask the ``shard_map`` wrapper hands each shard): a block owns
+    any count of its pages, anywhere in it, and is waited for that
+    count's bytes."""
+    test_owned_subsets_partials_merge_to_the_reference(lane_tile_blocks,
+                                                       walk)
+
+
+def _subjaxprs(eqn):
+    for v in eqn.params.values():
+        for x in (v if isinstance(v, (tuple, list)) else (v,)):
+            j = getattr(x, "jaxpr", x)
+            if hasattr(j, "eqns"):
+                yield j
+
+
+def _kernel_body(fn, *args):
+    """The jaxpr of the one paged kernel that ``fn`` calls."""
     def kernels(jaxpr):
         for e in jaxpr.eqns:
             if e.primitive.name == "pallas_call":
                 yield e.params["jaxpr"]
-            for j in subs(e):
+            for j in _subjaxprs(e):
                 yield from kernels(j)
 
     (body,) = kernels(jax.make_jaxpr(fn)(*args).jaxpr)
-    return sum(count(j) for e in body.eqns if e.primitive.name == "while"
-               for j in subs(e))
+    return body
+
+
+def _dots_in_block_loop(fn, *args) -> int:
+    """``dot_general``s inside the paged kernel's loop over a slot's
+    blocks (the top-level ``while`` of the kernel's body: the page
+    copies' loops inside it hold none), from the jaxpr of ``fn``."""
+    def count(jaxpr):
+        return sum((e.primitive.name == "dot_general")
+                   + sum(count(j) for j in _subjaxprs(e))
+                   for e in jaxpr.eqns)
+
+    return sum(count(j) for e in _kernel_body(fn, *args).eqns
+               if e.primitive.name == "while" for j in _subjaxprs(e))
 
 
 def _abstract(*shapes_dtypes):
@@ -503,6 +548,66 @@ def test_a_block_is_one_pass_for_all_of_a_slots_heads(geometry):
     assert dots == 2 * passes, (geometry, dots)
 
 
+# the cells' kernels at their widths, bf16 pools of 16-token pages:
+# (n_head, n_kv_head, head_dim or a latent row's lanes, table entries,
+# attn_window, pools, pages a block)
+WAIT_GEOMETRIES = {
+    "gpt2-large": (20, 20, 64, 64, 0, 2, 8),
+    "kexaone-full": (64, 8, 128, 512, 0, 2, 8),
+    "kexaone-ring": (64, 8, 128, 9, 128, 2, 8),
+    "lfm2-full": (32, 8, 64, 512, 0, 2, 16),
+    "joyai-latent": (32, None, 640, 512, 0, 1, 32),
+}
+
+
+def _dma_ops(jaxpr, loops=0):
+    """``(op, loops)`` for each ``dma_start`` / ``dma_wait`` of a kernel
+    body: ``loops`` the loops around it (a slot's block loop is one, a
+    page loop inside it two)."""
+    for e in jaxpr.eqns:
+        if e.primitive.name in ("dma_start", "dma_wait"):
+            yield e.primitive.name, loops
+        inner = loops + (e.primitive.name in ("while", "scan"))
+        for j in _subjaxprs(e):
+            yield from _dma_ops(j, inner)
+
+
+@pytest.mark.parametrize("geometry", list(WAIT_GEOMETRIES))
+def test_a_block_is_waited_by_its_bytes_not_a_page_at_a_time(geometry):
+    """The kernels' bodies, read from the jaxpr at the cells' widths: no
+    DMA wait inside a page loop (a return to one wait a page, 32 a
+    latent block, fails here); a block's waits sit in the block loop, one
+    a pool a bit of its owned count (``P.bit_length()``, of which a whole
+    block of a power of two takes one); the copies still start in the
+    page loops."""
+    from collections import Counter
+
+    from replicatinggpt_tpu.ops import paged_pallas as pp
+    H, Hkv, D, mp, window, pools, P = WAIT_GEOMETRIES[geometry]
+    B, psz, N, bf = 4, 16, 64, jnp.bfloat16
+    width = D if Hkv is None else Hkv * D
+    assert pp.block_pages(psz, mp, width * 2, pools) == P
+    tail = _abstract(((B, mp), jnp.int32), ((B,), jnp.int32))
+    pool = ((3, N, psz, width), bf)
+    if Hkv is None:
+        fn = lambda *a: pp.paged_mla_attention(  # noqa: E731
+            *a, layer=1, latent=512, scale=0.1)
+        args = _abstract(((B, H, width), bf), ((B, width), bf), pool) + tail
+    elif Hkv == H:
+        fn = lambda *a: pp.paged_window_attention(  # noqa: E731
+            *a, n_head=H, layer=1)
+        row = ((B, 1, width), bf)
+        args = _abstract(row, row, row, pool, pool) + tail
+    else:
+        fn = lambda *a: pp.paged_gqa_attention(  # noqa: E731
+            *a, n_head=H, n_kv_head=Hkv, layer=1, attn_window=window)
+        row = ((B, 1, width), bf)
+        args = _abstract(((B, 1, H * D), bf), row, row, pool, pool) + tail
+    ops = Counter(_dma_ops(_kernel_body(fn, *args)))
+    assert ops == {("dma_wait", 1): pools * P.bit_length(),
+                   ("dma_start", 2): 2 * pools}, (geometry, ops)
+
+
 @pytest.mark.parametrize("check", ["gather", "int8-head", "partials"])
 def test_a_wide_window_splits_into_whole_head_passes(monkeypatch,
                                                      lane_tile_blocks,
@@ -540,7 +645,9 @@ def test_launch_stats_count_the_blocks_the_device_mask_owns(
     block covers, and ``kv_blocks_live`` = the turns
     of the slots' loops: the blocks that hold an owned page in the mask
     the kernel is handed on the device (rebuilt here from what the launch
-    uploads: tables, positions, the live slots)."""
+    uploads: tables, positions, the live slots), and ``kv_block_waits`` =
+    the DMA waits those turns make: one a pool (K and V) a set bit of a
+    block's owned-page count in the same walk."""
     from replicatinggpt_tpu.ops import paged_pallas as pp
     from replicatinggpt_tpu.utils.telemetry import Telemetry
     cfg = dataclasses.replace(CFG, block_size=256)
@@ -554,15 +661,16 @@ def test_launch_stats_count_the_blocks_the_device_mask_owns(
     P = pp.block_pages(psz, mp, cfg.n_embd * 4, 2)
     assert (psz, mp, P) == (8, 32, 16)
     assert eng._kv_block_pages == P           # the walk's block, not a stat
-    want, dispatch = [], eng._dispatch
+    want, waits, dispatch = [], [], eng._dispatch
 
     def spy(k, kill, *a):
         pos = jnp.asarray(np.where(eng._active & ~kill, eng._pos, 0),
                           jnp.int32)
         owned = pp.gqa_owned_pages(pos, jnp.zeros_like(pos), mp, psz, 0)
-        count = pp._blocked_walk(
-            jnp.asarray(eng.pool.tables), owned, psz, cfg.n_embd * 4, 2)[2]
-        want.append(int(np.asarray(count).sum()))  # the loops' iterations
+        walk = pp._blocked_walk(
+            jnp.asarray(eng.pool.tables), owned, psz, cfg.n_embd * 4, 2)
+        want.append(int(np.asarray(walk[2]).sum()))  # the loops' iterations
+        waits.append(2 * int(np.bitwise_count(np.asarray(walk[6])).sum()))
         return dispatch(k, kill, *a)
 
     eng._dispatch = spy
@@ -578,6 +686,9 @@ def test_launch_stats_count_the_blocks_the_device_mask_owns(
     assert eng._kv_block_passes == 1
     assert [a["kv_block_passes"] for a in stats] == want
     assert {1, 2, 3, 4} & set(want) and max(want) >= 4    # 1 + 1 + 2
+    # 16 pages of 8 a block: 1 to 5 waits a pool a block, one when whole
+    assert [a["kv_block_waits"] for a in stats] == waits
+    assert all(2 * n <= w <= 10 * n for n, w in zip(want, waits))
     assert all(a["kv_blocks_grid"] == 3 and "kv_block_pages" not in a
                and a["kv_block_tokens"] == P * psz == 128 for a in stats)
 
