@@ -36,10 +36,12 @@ the fresh K/V window rides separately and folds in after the loop,
 so the kernel attends the STALE pool bit-equivalently to
 write-then-attend (cache[pos] would hold exactly the fresh k/v) — the
 caller scatters the fresh row afterwards, mirroring
-ops/decode_pallas.py's packed kernel. A block's copies start a page at a
-time but are waited by their bytes: one wait a pool a set bit of the
-block's owned-page count, so a whole block of 32 latent pages is one
-wait, not 32.
+ops/decode_pallas.py's packed kernel. A block whose owned pages lie
+together in the pool, in order (a RUN: what ``serve.pages.PageAllocator``
+hands a request's blocks out as), is started as one copy a set bit of its
+owned-page count, so a whole block of 32 latent pages is ONE copy; any
+other block is started a page at a time. Either way a block is waited by
+its bytes: one wait a pool a set bit of the count.
 
 Every process start traces and lowers its programs, compile cache warm
 or not, so what the body costs to trace is paid in every cell's set-up:
@@ -262,6 +264,21 @@ def live_blocks(pos, page_size: int, n_block: int):
     return -(-pos // (page_size * n_block))
 
 
+def block_runs(pages: np.ndarray, n_block: int) -> np.ndarray:
+    """(n_blocks,) int: how many of its first pages each block of
+    ``n_block`` of a slot's table ``pages`` has in ONE run, page i of the
+    block at the pool's page ``pages[first] + i`` (numpy, at admission:
+    the engine's ``kv_block_runs`` reads a live block as a run while its
+    owned pages are no more than this, the rule ``_blocked_walk`` applies
+    on the device)."""
+    pages = np.asarray(pages, np.int64)
+    nb = -(-pages.size // n_block)
+    lag = np.full((nb * n_block,), -1, np.int64)
+    lag[:pages.size] = pages - np.arange(pages.size) % n_block
+    same = lag.reshape(nb, n_block) == lag[::n_block, None]
+    return np.where(same.all(axis=1), n_block, np.argmin(same, axis=1))
+
+
 def block_waits(pos, page_size: int, n_block: int):
     """The DMA waits a pool that the unsharded walk's loop makes for a
     slot at ``pos`` (numpy; the engine's ``kv_block_waits`` sums it over
@@ -276,6 +293,12 @@ def block_waits(pos, page_size: int, n_block: int):
 # scalar-prefetch operands ``_walk_blocks`` reads: ``_blocked_walk``'s
 # seven and the pool's layer
 N_WALK = 8
+
+# a block's word of ``_blocked_walk``'s ``pages``: its owned pages' count
+# in the low bits, and above them, where they are one run, the index in
+# the block of the first plus one (0: not a run)
+RUN_SHIFT = 16
+COUNT_MASK = (1 << RUN_SHIFT) - 1
 
 
 @jax.named_scope("kv_gather")
@@ -304,10 +327,15 @@ def _blocked_walk(tables: jnp.ndarray, owned: jnp.ndarray, page_size: int,
     ``bits[(b * n_blocks + p) * words + w]`` holds the owned pages
     ``w * WORD ..`` of block p of slot b, page j in bit j % WORD: the mask
     of a block's columns in as many words as its pages need, however many
-    pages they are. ``pages[b * n_blocks + p]`` counts them: what the
-    block's DMA waits add up to."""
+    pages they are. ``pages[b * n_blocks + p]`` counts them (its
+    ``COUNT_MASK`` bits: what the block's DMA waits add up to) and says
+    whether they are a RUN: owned pages j0 .. j0 + n - 1 of the block, at
+    table entries ``table[j0] + i``, one slice of the pool; such a block
+    holds ``j0 + 1`` above ``RUN_SHIFT``, and the walk starts it as a few
+    copies, not one a page."""
     B, mp = tables.shape
     P, nb = _walk_shape(mp, page_size, row_bytes, tiles)
+    assert P < 1 << (31 - RUN_SHIFT), P
     pad = ((0, 0), (0, nb * P - mp))
     owned = jnp.pad(owned, pad)
     table = jnp.where(owned, jnp.pad(jnp.asarray(tables, jnp.int32), pad), -1)
@@ -317,8 +345,19 @@ def _blocked_walk(tables: jnp.ndarray, owned: jnp.ndarray, page_size: int,
     bit = jnp.left_shift(jnp.uint32(1), jnp.arange(WORD, dtype=jnp.uint32))
     bits = jnp.sum(jnp.where(words.reshape(B, nb, nw, WORD), bit,
                              jnp.uint32(0)), axis=3, dtype=jnp.uint32)
-    pages = owned.reshape(B, nb, P).sum(axis=2, dtype=jnp.int32)
+    own = owned.reshape(B, nb, P)
+    pages = own.sum(axis=2, dtype=jnp.int32)
     live = pages > 0
+    # a run: its owned pages' entries less their index in the block all
+    # one number, and the first and the last owned page n - 1 apart
+    lag = table.reshape(B, nb, P) - jnp.arange(P, dtype=jnp.int32)
+    big = jnp.iinfo(jnp.int32).max
+    first = jnp.argmax(own, axis=2).astype(jnp.int32)
+    last = P - 1 - jnp.argmax(own[..., ::-1], axis=2).astype(jnp.int32)
+    run = (live & (last - first + 1 == pages)
+           & (jnp.min(jnp.where(own, lag, big), axis=2)
+              == jnp.max(jnp.where(own, lag, -big), axis=2)))
+    word = pages | jnp.where(run, (first + 1) << RUN_SHIFT, 0)
     # a slot's i-th live block is the first with i + 1 live blocks up to
     # and with it: as many blocks as have i or fewer (no sort)
     upto = jnp.cumsum(live, axis=1, dtype=jnp.int32)
@@ -332,7 +371,7 @@ def _blocked_walk(tables: jnp.ndarray, owned: jnp.ndarray, page_size: int,
             jnp.cumsum(count, dtype=jnp.int32) - count,
             jnp.where(nxt < B, nxt, -1),
             jax.lax.bitcast_convert_type(bits, jnp.int32).reshape(-1),
-            pages.reshape(B * nb))
+            word.reshape(B * nb))
 
 
 def window_walk(tables: jnp.ndarray, pos: jnp.ndarray, page_size: int,
@@ -382,24 +421,28 @@ def _walk_blocks(walk, pools, bufs, sem, n_block: int, n_blocks: int,
     cols)`` (block ``p`` of the slot's table, in half ``half`` of the
     buffers).
 
-    ``pools`` are the pool's stacked (layers, n_pages, page, width)
-    arrays left in HBM, addressed in place by ``(layer, page)``; ``bufs``
-    their (2, P * page, width) VMEM halves. A block finds its owned
+    ``pools`` are the pool's stacked arrays left in HBM as rows, (layers,
+    n_pages * page, width) (``_pool_operands``), addressed in place by
+    ``(layer, page * page_size)``; ``bufs`` their (2, P * page, width)
+    VMEM halves. A block finds its owned
     pages already on their way into half ``rank % 2`` (the live block
     before it sent for them; the call's first sends for its own), sends
     for the NEXT live block's into the other half, then waits for its
     own: the copies of block t + 1 run under the arithmetic of block t.
     A slot's last block sends for the first block of the next slot that
     has one, so the buffers do not drain at a slot's edge.
-    The copies start one a page, but a block is waited by its bytes, not
-    a page at a time: all of a block's copies into a half signal that
-    half's semaphore of the pool, a DMA wait takes its descriptor's bytes
-    off it, and only the block's own copies are in flight there (the
-    next block's go to the other half), so waits whose rows add up to
-    the block's owned pages (its count in the walk's scalars) release
-    exactly when they have all landed. A size is static, so it is one
-    wait of ``2**k`` pages a set bit ``k`` of the count: one a pool for
-    a whole block of a power of two, ``n_block.bit_length()`` at most.
+    A block whose owned pages are a RUN (``_blocked_walk``: one slice of
+    the pool, in order) starts one copy of ``2**k`` pages a set bit ``k``
+    of their count, any other block one copy a page; either way a block
+    is waited by its bytes, not a copy at a time: all of a block's copies
+    into a half signal that half's semaphore of the pool, a DMA wait
+    takes its descriptor's bytes off it, and only the block's own copies
+    are in flight there (the next block's go to the other half), so waits
+    whose rows add up to the block's owned pages (its count in the walk's
+    scalars) release exactly when they have all landed. A size is static,
+    so it is one wait of ``2**k`` pages a set bit ``k`` of the count: one
+    a pool for a whole block of a power of two, ``n_block.bit_length()``
+    at most.
     Unowned pages are not fetched, and ``cols()`` masks their columns:
     what a half holds there is an older page or the zeros of the
     call's first turn. A slot with no live block reads its count here
@@ -416,19 +459,41 @@ def _walk_blocks(walk, pools, bufs, sem, n_block: int, n_blocks: int,
             buf[...] = jnp.zeros_like(buf)
 
     def start(slot, p, half):
-        def page(j, _):
-            @pl.when(table_ref[slot, p * P + j] >= 0)
-            def _owned():
-                for a, (pool, buf) in enumerate(zip(pools, bufs)):
-                    pltpu.make_async_copy(
-                        pool.at[layer_ref[0], table_ref[slot, p * P + j]],
-                        buf.at[half, pl.ds(pl.multiple_of(j * psz, psz),
-                                           psz)],
-                        sem.at[half, a]).start()
+        word = pages_ref[slot * n_blocks + p]
+        j0 = (word >> RUN_SHIFT) - 1          # -1: not a run
 
-        # a loop, not P copies of the body: the program is traced and
-        # lowered at every process start, and these are most of its size
-        jax.lax.fori_loop(0, P, page, None)
+        def copy(src, dst, n_pages):
+            # pool pages src .. into the block's pages dst .., a static
+            # number of them
+            rows = n_pages * psz
+            for a, (pool, buf) in enumerate(zip(pools, bufs)):
+                pltpu.make_async_copy(
+                    pool.at[layer_ref[0],
+                            pl.ds(pl.multiple_of(src * psz, psz), rows)],
+                    buf.at[half, pl.ds(pl.multiple_of(dst * psz, psz), rows)],
+                    sem.at[half, a]).start()
+
+        @pl.when(j0 >= 0)
+        def _run():
+            count = word & COUNT_MASK
+            src = table_ref[slot, p * P + j0]
+            for k in range(P.bit_length()):
+                @pl.when((count >> k) & 1 == 1)
+                def _bit():
+                    off = count & ((1 << k) - 1)   # the lower bits' pages
+                    copy(src + off, j0 + off, 1 << k)
+
+        @pl.when(j0 < 0)
+        def _pages():
+            def page(j, _):
+                @pl.when(table_ref[slot, p * P + j] >= 0)
+                def _owned():
+                    copy(table_ref[slot, p * P + j], j, 1)
+
+            # a loop, not P copies of the body: the program is traced and
+            # lowered at every process start, and these are most of its
+            # size
+            jax.lax.fori_loop(0, P, page, None)
 
     def wait(half, n_pages):
         # a wait of 2**k pages' rows a set bit k of the owned count: the
@@ -458,7 +523,7 @@ def _walk_blocks(walk, pools, bufs, sem, n_block: int, n_blocks: int,
                   blocks_ref[ahead * n_blocks + jnp.where(last, 0, i + 1)],
                   1 - half)
 
-        wait(half, pages_ref[b * n_blocks + p])
+        wait(half, pages_ref[b * n_blocks + p] & COUNT_MASK)
 
         def cols():
             # a column's bit in the block's words: a few vector steps a
@@ -668,11 +733,14 @@ def _state_scratch(n_head: int, rows: int, head_dim: int,
 
 
 def _pool_operands(arrays, n_block: int) -> tuple:
-    """``(in_specs, scratch_shapes)`` for stacked pool arrays (layers,
-    n_pages, page, width) the kernel fetches itself: left in HBM, a (2,
-    P * page, width) VMEM double buffer each, and a DMA semaphore a half
-    an array."""
-    return ([pl.BlockSpec(memory_space=pltpu.HBM)] * len(arrays),
+    """``(operands, in_specs, scratch_shapes)`` for stacked pool arrays
+    (layers, n_pages, page, width) the kernel fetches itself: each viewed
+    as rows, (layers, n_pages * page, width), so that a run of pages is
+    one slice of it (a bitcast, not a copy: a page is whole tiles of its
+    rows), left in HBM; a (2, P * page, width) VMEM double buffer each,
+    and a DMA semaphore a half an array."""
+    return ([a.reshape(a.shape[0], -1, a.shape[3]) for a in arrays],
+            [pl.BlockSpec(memory_space=pltpu.HBM)] * len(arrays),
             [pltpu.VMEM((2, n_block * a.shape[2], a.shape[3]), a.dtype)
              for a in arrays]
             + [pltpu.SemaphoreType.DMA((2, len(arrays)))])
@@ -768,7 +836,7 @@ def _window_call(q, k_new, v_new, k_pages, v_pages, tables, pos, layer,
             inputs += [jnp.swapaxes(sc[layer, jnp.maximum(walk[0], 0)]
                                     .reshape(B, nb, T, swidth), 2, 3)
                        for sc in (k_scales, v_scales)]
-    pool_specs, pool_scratch = _pool_operands([k_pages, v_pages], P)
+    pools, pool_specs, pool_scratch = _pool_operands([k_pages, v_pages], P)
     scratch = _state_scratch(n_head, W, D, q.dtype if not quantized
                              and q.dtype == k_pages.dtype else jnp.float32)
     if fold:
@@ -791,7 +859,7 @@ def _window_call(q, k_new, v_new, k_pages, v_pages, tables, pos, layer,
         kernel, grid_spec=grid_spec, out_shape=out_shape,
         name="paged_window_attention", interpret=interpret,
         compiler_params=_compiler_params(0, 1),
-    )(*walk, layer.reshape(1), pos, *inputs, k_pages, v_pages)
+    )(*walk, layer.reshape(1), pos, *inputs, *pools)
 
 
 def paged_decode_attention(q: jnp.ndarray, k_new: jnp.ndarray,
@@ -1068,7 +1136,7 @@ def _gqa_call(q, k_new, v_new, k_pages, v_pages, tables, pos, layer, page0,
 
     qspec = _vmem_spec((None, n_kv_head, G * W, D), q_map)
     row = _vmem_spec((None, W, Ckv), row_map)
-    pool_specs, pool_scratch = _pool_operands([k_pages, v_pages], P)
+    pools, pool_specs, pool_scratch = _pool_operands([k_pages, v_pages], P)
     scratch = _state_scratch(n_kv_head, G * W, D, q.dtype
                              if q.dtype == k_pages.dtype else jnp.float32)
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -1083,8 +1151,7 @@ def _gqa_call(q, k_new, v_new, k_pages, v_pages, tables, pos, layer, page0,
         out_shape=jax.ShapeDtypeStruct((B, n_kv_head, G * W, D), q.dtype),
         name=name, interpret=interpret,
         compiler_params=_compiler_params(0, 1),
-    )(*walk, layer.reshape(1), pos, page0, qg, k_new, v_new, k_pages,
-      v_pages)
+    )(*walk, layer.reshape(1), pos, page0, qg, k_new, v_new, *pools)
     return (out.reshape(B, n_kv_head, G, W, D).transpose(0, 3, 1, 2, 4)
             .reshape(B, W, Cq))
 
@@ -1192,7 +1259,7 @@ def _mla_call(q, kv_new, pages, tables, pos, layer, *, latent, scale, name,
     kernel = functools.partial(
         _paged_mla_kernel, page_size=psz, n_block=P, n_blocks=nb,
         latent=latent, scale=scale)
-    pool_specs, pool_scratch = _pool_operands([pages], P)
+    pools, pool_specs, pool_scratch = _pool_operands([pages], P)
     state = [pltpu.VMEM((1, H, latent), jnp.float32),
              pltpu.VMEM((1, H, LANES), jnp.float32),
              pltpu.VMEM((1, H, LANES), jnp.float32)]
@@ -1210,4 +1277,4 @@ def _mla_call(q, kv_new, pages, tables, pos, layer, *, latent, scale, name,
         out_shape=jax.ShapeDtypeStruct((B, H, latent), q.dtype),
         name=name, interpret=interpret,
         compiler_params=_compiler_params(0, 1),
-    )(*walk, layer.reshape(1), pos, q, kv_new[:, None], pages)
+    )(*walk, layer.reshape(1), pos, q, kv_new[:, None], *pools)

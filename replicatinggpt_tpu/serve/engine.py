@@ -89,8 +89,8 @@ Every step marks its host phases through ``self.tel.phase`` (a
 ``serve/launch``, ``serve/fetch``, ``serve/commit``), with the live
 context (``live_tokens``, ``live_kv_bytes``, ``stochastic_rows``, the
 paged kernel's ``kv_blocks_live`` / ``kv_block_tokens`` /
-``kv_block_passes`` / ``kv_block_waits`` / ``kv_blocks_grid``, from the host
-mirrors) as stats of ``serve/launch``;
+``kv_block_passes`` / ``kv_block_waits`` / ``kv_block_runs`` /
+``kv_blocks_grid``, from the host mirrors) as stats of ``serve/launch``;
 docs/observability.md
 has the vocabulary. Construction is the ``setup/engine`` span of the
 process's set-up record (``utils.telemetry.setup_phase``), and every
@@ -956,11 +956,8 @@ class Engine:
         self._kv_block_pages = self._kv_block_passes = 0
         self._kv_block_tiles = 1 if cfg.kv_lora_rank else 2
         if self._use_pallas:
-            from ..ops.paged_pallas import block_pages, block_passes
-            self._kv_block_pages = block_pages(
-                self.pool.page_size, self.pool.max_pages,
-                self.pool.kv_array.shape[-1] * itemsize,
-                self._kv_block_tiles)
+            from ..ops.paged_pallas import block_passes
+            self._kv_block_pages = self.pool.block_pages
             self._kv_block_passes = block_passes(
                 cfg.n_head, cfg.head_dim, n_kv_head=cfg.kv_heads)
         log.info("kernel route: %s (decode=%s window=%s sharded=%s%s)",
@@ -2026,9 +2023,15 @@ class Engine:
         pass for all of a slot's heads), ``kv_block_waits`` (the DMA
         waits those iterations make, ``ops.paged_pallas.block_waits`` a
         pool: over ``kv_blocks_live`` it reads the pools, one wait each,
-        where a slot's blocks are whole) and ``kv_blocks_grid`` (the
-        turns the kernel's grid takes there: one a slot, so live over
-        grid reads blocks a turn)."""
+        where a slot's blocks are whole), ``kv_block_runs`` (those of
+        the live blocks whose owned pages are ONE run of the pool, in
+        order, which the kernel starts as a copy a set bit of their
+        count, not a copy a page: ``PagedCachePool.live_block_runs``, by
+        the device's rule from the runs found at admission; over
+        ``kv_blocks_live`` it reads the share of blocks the allocator's
+        runs reach) and ``kv_blocks_grid`` (the turns the kernel's grid
+        takes there: one a slot, so live over grid reads blocks a
+        turn)."""
         from ..ops.paged_pallas import block_waits, live_blocks
         pos, psz, P = self._pos[live], self.pool.page_size, \
             self._kv_block_pages
@@ -2038,6 +2041,8 @@ class Engine:
                     kv_block_passes=n_live * self._kv_block_passes,
                     kv_block_waits=int(block_waits(pos, psz, P).sum())
                     * self._kv_block_tiles,
+                    kv_block_runs=self.pool.live_block_runs(
+                        np.flatnonzero(live), pos),
                     kv_blocks_grid=self.ecfg.pool_size)
 
     def _prefill_stats(self, tokens: int) -> dict:

@@ -16,7 +16,9 @@ Three host-side pieces:
 - :class:`PageAllocator` — refcounted acquire/release of physical
   pages. A page's refcount counts SLOT references; pages referenced by
   the radix index alone (refcount 0) are the prefix cache, reclaimed
-  LRU when allocation runs dry.
+  LRU when allocation runs dry. It hands a request's pages out as RUNS:
+  a whole aligned extent of the paged kernel's block for each block of
+  the request, so that the kernel fetches a block as one copy.
 - :class:`RadixIndex` — a prefix tree over FULL pages of prompt tokens
   (node key = (parent, page-token bytes), so lookups are exact, not
   hash-collision-prone). Admission walks it to claim the longest cached
@@ -38,6 +40,7 @@ the shared original stays intact for the next claimer.
 
 from __future__ import annotations
 
+import heapq
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
 
@@ -208,10 +211,25 @@ class PageAllocator:
     """Refcounted physical-page allocator + radix prefix cache + LRU
     eviction. Pure host state — the device pool is the pool's concern —
     which is what makes the fuzz harness (tests/test_pages.py) cheap.
+
+    Where the free pages lie is the paged kernel's concern: it fetches a
+    slot's pages in blocks of ``block_pages`` consecutive logical pages
+    (``ops.paged_pallas.block_pages``), and a block whose pages lie
+    together in the pool, in order, is ONE copy. So the pool is cut into
+    aligned EXTENTS of ``block_pages`` pages, and a request's pages are
+    handed out by logical block: a block the request fills whole takes a
+    whole free extent while one is left, a part of a block (the
+    request's last, or the one its claimed prefix ends in) a run of free
+    pages in the fullest partly used extent that has one (``_take_part``).
+    Which pages is all that placement decides: how many are free,
+    reclaimable and reserved, and so whether an acquire succeeds, does
+    not depend on it. Free pages are kept an extent, so a hand-out never
+    scans the pool.
     """
 
     def __init__(self, n_pages: int, page_size: int,
-                 prefix_cache: bool = True, telemetry=None):
+                 prefix_cache: bool = True, telemetry=None,
+                 block_pages: int = 1):
         assert n_pages >= 1 and page_size >= 1
         self.n_pages = n_pages
         self.page_size = page_size
@@ -219,7 +237,22 @@ class PageAllocator:
         # prefix-hit / eviction instants on the request timeline
         # (utils.telemetry); NULL by default — zero cost, zero state
         self.tel = telemetry or NULL
-        self._free: List[int] = list(range(n_pages - 1, -1, -1))
+        self.block = max(int(block_pages), 1)
+        n_ext = -(-n_pages // self.block)
+        self._is_free = np.ones((n_pages,), bool)
+        self._n_free = n_pages
+        # an extent's free pages as a bitmask (bit i: its page i); the
+        # wholly free extents on a heap (lowest first), the others by
+        # their count of free pages (the pool's last may be short)
+        self._ext_mask = [(1 << min(self.block, n_pages - e * self.block))
+                          - 1 for e in range(n_ext)]
+        self._whole = [e for e, m in enumerate(self._ext_mask)
+                       if m.bit_count() == self.block]
+        self._n_whole = len(self._whole)
+        self._part: List[Dict[int, None]] = [{} for _ in range(self.block)]
+        for e, m in enumerate(self._ext_mask):
+            if m.bit_count() < self.block:
+                self._part[m.bit_count()][e] = None
         self.ref = np.zeros((n_pages,), np.int32)
         self.radix = RadixIndex()
         self.page_node: Dict[int, _RadixNode] = {}   # phys -> radix node
@@ -235,11 +268,11 @@ class PageAllocator:
 
     @property
     def pages_free(self) -> int:
-        return len(self._free)
+        return self._n_free
 
     @property
     def pages_in_use(self) -> int:
-        return self.n_pages - len(self._free)
+        return self.n_pages - self._n_free
 
     def in_use_by_block(self, n_blocks: int) -> List[int]:
         """Pages in use per contiguous block of the physical page axis
@@ -249,11 +282,114 @@ class PageAllocator:
         Prometheus gauges stay meaningful on a mesh. 'In use' matches
         ``pages_in_use``: slot-referenced pages AND radix-held
         refcount-0 prefix pages (both occupy HBM)."""
-        free = np.zeros((self.n_pages,), bool)
-        free[np.fromiter(self._free, np.int64, len(self._free))] = True
         blk = -(-self.n_pages // n_blocks)
-        return [int((~free[i * blk:(i + 1) * blk]).sum())
+        return [int((~self._is_free[i * blk:(i + 1) * blk]).sum())
                 for i in range(n_blocks)]
+
+    # --------------------------------------------------------- placement
+
+    def _recount(self, e: int, old: int, new: int) -> None:
+        """Extent ``e`` went from ``old`` free pages to ``new``."""
+        if old == self.block:
+            self._n_whole -= 1
+        else:
+            del self._part[old][e]
+        if new == self.block:
+            self._n_whole += 1
+            heapq.heappush(self._whole, e)
+        else:
+            self._part[new][e] = None
+
+    def _take_page(self, p: int) -> None:
+        e, i = divmod(p, self.block)
+        self._is_free[p] = False
+        self._n_free -= 1
+        self._ext_mask[e] &= ~(1 << i)
+        n = self._ext_mask[e].bit_count()
+        self._recount(e, n + 1, n)
+
+    def _put(self, p: int) -> None:
+        """Page ``p`` is free again."""
+        assert not self._is_free[p], f"page {p} freed twice"
+        e, i = divmod(p, self.block)
+        self._is_free[p] = True
+        self._n_free += 1
+        self._ext_mask[e] |= 1 << i
+        n = self._ext_mask[e].bit_count()
+        self._recount(e, n - 1, n)
+
+    def _take_run(self, e: int, i: int, n: int) -> List[int]:
+        pages = list(range(e * self.block + i, e * self.block + i + n))
+        for p in pages:
+            self._take_page(p)
+        return pages
+
+    def _pop_whole(self) -> int:
+        """The lowest wholly free extent (there is one). An extent leaves
+        the heap only here, so the heap holds exactly the whole ones."""
+        return heapq.heappop(self._whole)
+
+    # extents a part of a block looks through for a run: the host's cost
+    # of a hand-out stays flat
+    SCAN = 16
+
+    def _take_part(self, n: int) -> List[int]:
+        """``n`` < ``block`` pages, a part of a block: one run in the
+        fullest extent that has one among the first ``SCAN`` partly used
+        ones looked at; else, where the partly used extents hold ``n``
+        free pages between them, those (a whole extent opened for a part
+        of a block is one fewer for the whole blocks of the requests to
+        come); else the start of a whole extent."""
+        scanned = 0
+        for c in range(n, self.block):
+            for e in self._part[c]:
+                m = run = self._ext_mask[e]
+                for i in range(1, n):
+                    run &= m >> i
+                if run:
+                    return self._take_run(e, (run & -run).bit_length() - 1,
+                                          n)
+                scanned += 1
+                if scanned == self.SCAN:
+                    break
+            if scanned == self.SCAN:
+                break
+        if self._n_free - self._n_whole * self.block >= n:
+            return self._take_scattered(n)
+        return self._take_run(self._pop_whole(), 0, n)
+
+    def _take_scattered(self, n: int) -> List[int]:
+        """``n`` free pages wherever they are: the partly used extents'
+        first, the emptiest first (the fewest extents that the pages
+        are spread over), the whole ones last."""
+        out: List[int] = []
+        for c in range(self.block - 1, 0, -1):
+            while len(out) < n and self._part[c]:
+                e = next(iter(self._part[c]))
+                m = self._ext_mask[e]
+                for i in range(self.block):
+                    if m >> i & 1 and len(out) < n:
+                        out.append(e * self.block + i)
+                        self._take_page(out[-1])
+        while len(out) < n:
+            out += self._take_run(self._pop_whole(), 0,
+                                  min(self.block, n - len(out)))
+        return out
+
+    def _take(self, first: int, n: int) -> List[int]:
+        """``n`` free pages for a request's logical pages ``first ..
+        first + n - 1``: a whole extent for each block they fill, one
+        run for each part of a block (``_take_part``)."""
+        out: List[int] = []
+        while len(out) < n:
+            k = min(self.block - (first + len(out)) % self.block,
+                    n - len(out))
+            if k == self.block:
+                out += (self._take_run(self._pop_whole(), 0, k)
+                        if self._n_whole else self._take_scattered(k))
+            else:
+                out += self._take_part(k)
+        return out
 
     def n_pages_for(self, n_prompt: int, cap: int) -> int:
         """Logical pages a request needs END TO END: the last write
@@ -282,7 +418,7 @@ class PageAllocator:
         page, node = best
         self.radix.remove(node)
         del self.page_node[page]
-        self._free.append(page)
+        self._put(page)
         self.evictions += 1
         self.tel.instant("page_evict", page=page)
         return page
@@ -303,7 +439,7 @@ class PageAllocator:
     def can_acquire(self, prompt: np.ndarray, cap: int) -> bool:
         chain, need, _ = self._plan(prompt, cap, touch=False)
         claimed = {n.page for n in chain}
-        return need <= len(self._free) + self._reclaimable(claimed)
+        return need <= self._n_free + self._reclaimable(claimed)
 
     def acquire(self, prompt: np.ndarray, cap: int) -> Optional[PageClaim]:
         """Claim the longest cached prefix + fresh pages for the rest of
@@ -315,7 +451,7 @@ class PageAllocator:
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         chain, need, cow_needed = self._plan(prompt, cap, touch=False)
         protect = {n.page for n in chain}
-        while len(self._free) < need:
+        while self._n_free < need:
             if self._evict_one(protect) is None:
                 return None
         for node in chain:
@@ -325,20 +461,19 @@ class PageAllocator:
         pages = [n.page for n in chain]
         for p in pages:
             self.ref[p] += 1
+        # fresh pages from the first the slot writes: the copy-on-write
+        # split of the last claimed page, or the page after the claim
+        first = len(pages) - cow_needed
+        fresh = self._take(first, self.n_pages_for(int(prompt.size), cap)
+                           - first)
+        self.ref[fresh] = 1
         cow: List[Tuple[int, int]] = []
         if cow_needed:
-            dst = self._free.pop()
-            src = pages[-1]
+            src = pages.pop()
             self.ref[src] -= 1
-            self.ref[dst] = 1
-            pages[-1] = dst
-            cow.append((src, dst))
+            cow.append((src, fresh[0]))
             self.cow_copies += 1
-        n_total = self.n_pages_for(int(prompt.size), cap)
-        for _ in range(n_total - len(pages)):
-            p = self._free.pop()
-            self.ref[p] = 1
-            pages.append(p)
+        pages += fresh
         claimed_tokens = len(chain) * self.page_size
         if chain:
             self.prefix_hits += 1
@@ -386,7 +521,7 @@ class PageAllocator:
             self.ref[p] -= 1
             assert self.ref[p] >= 0, f"page {p} refcount underflow"
             if self.ref[p] == 0 and p not in self.page_node:
-                self._free.append(p)
+                self._put(p)
 
 
 @dataclass
@@ -458,9 +593,6 @@ class PagedCachePool:
                 and sharding.spec[1] is not None:
             self._page_shards = int(
                 sharding.mesh.shape[sharding.spec[1]])
-        self.alloc = PageAllocator(self.n_pages, self.page_size,
-                                   prefix_cache=prefix_cache,
-                                   telemetry=telemetry)
         pool = fam.init_paged_kv_pool(cfg, self.n_pages, self.page_size,
                                       dtype=dtype, quant=quant,
                                       n_slots=n_slots)
@@ -471,9 +603,27 @@ class PagedCachePool:
                 arr, sharding=(scale_sharding if name in ("ks", "vs")
                                else sharding))
             for name, arr in pool.items()}
+        # the paged kernel's block: pages of a slot it fetches together,
+        # which the allocator hands out as runs of the pool (0: a page
+        # alone is over the kernel's budget)
+        from ..ops.paged_pallas import block_pages
+        kv = self.kv_array
+        self.block_pages = block_pages(
+            self.page_size, self.max_pages,
+            kv.shape[-1] * kv.dtype.itemsize, 1 if cfg.kv_lora_rank else 2)
+        self.alloc = PageAllocator(self.n_pages, self.page_size,
+                                   prefix_cache=prefix_cache,
+                                   telemetry=telemetry,
+                                   block_pages=self.block_pages)
         # host-mirrored, device-fed each step (fixed shape: the paged
         # programs never retrace on table contents)
         self.tables = np.zeros((n_slots, self.max_pages), np.int32)
+        # a slot's blocks as runs, set at admission (``live_block_runs``):
+        # the pages of each block in one run from its first, and the
+        # blocks wholly one run before each
+        nb = -(-self.max_pages // max(self.block_pages, 1))
+        self._run_len = np.zeros((n_slots, nb + 1), np.int32)
+        self._run_cum = np.zeros((n_slots, nb + 1), np.int32)
         self.positions = np.zeros((n_slots,), np.int32)
         self._free_slots: List[int] = list(range(n_slots - 1, -1, -1))
         self._owner: Dict[int, str] = {}
@@ -593,6 +743,13 @@ class PagedCachePool:
         row = self.tables[slot]
         row[:] = 0
         row[:len(claim.pages)] = claim.pages
+        if self.block_pages:
+            from ..ops.paged_pallas import block_runs
+            runs = block_runs(claim.pages, self.block_pages)
+            self._run_len[slot] = 0
+            self._run_len[slot, :runs.size] = runs
+            self._run_cum[slot, 1:] = np.cumsum(
+                self._run_len[slot, :-1] == self.block_pages)
         self.positions[slot] = int(prompt.size) - 1
         return Admission(slot=slot, claimed=claim.claimed_tokens,
                          cow=list(claim.cow))
@@ -628,6 +785,19 @@ class PagedCachePool:
         self.alloc.release(self._claims.pop(slot))
         self.tables[slot, :] = 0
         self._free_slots.append(slot)
+
+    def live_block_runs(self, slots: np.ndarray, pos: np.ndarray) -> int:
+        """Of the paged kernel's live blocks for ``slots`` at positions
+        ``pos`` (a block holding a position < pos), how many own pages
+        that are ONE run of the pool, in order, which the kernel fetches
+        as a few copies (``ops.paged_pallas._blocked_walk``'s rule, from
+        the runs ``acquire`` found; nothing scans the tables here)."""
+        P = self.block_pages
+        pages = -(-np.asarray(pos) // self.page_size)
+        full, rest = np.divmod(pages, P)
+        return int(self._run_cum[slots, full].sum()
+                   + ((rest > 0)
+                      & (self._run_len[slots, full] >= rest)).sum())
 
     def owner(self, slot: int) -> Optional[str]:
         return self._owner.get(slot)
@@ -675,7 +845,7 @@ class PagedCachePool:
             self.alloc.ref[p] -= 1
             assert self.alloc.ref[p] >= 0, f"page {p} pin underflow"
             if self.alloc.ref[p] == 0 and p not in self.alloc.page_node:
-                self.alloc._free.append(p)
+                self.alloc._put(p)
 
     def install_prefix(self, key: str, prompt: np.ndarray,
                        from_page: int,
@@ -692,17 +862,11 @@ class PagedCachePool:
         if len(chain) < from_page:
             return None
         protect = {n.page for n in chain}
-        taken: List[int] = []
-        for _ in range(n_pages):
-            if not self.alloc._free and \
-                    self.alloc._evict_one(protect) is None:
-                for p in taken:                      # unwind: no pin
-                    self.alloc.ref[p] = 0
-                    self.alloc._free.append(p)
+        while self.alloc.pages_free < n_pages:
+            if self.alloc._evict_one(protect) is None:
                 return None
-            p = self.alloc._free.pop()
-            self.alloc.ref[p] = 1
-            taken.append(p)
+        taken = self.alloc._take(int(from_page), n_pages)
+        self.alloc.ref[taken] = 1
         self._pins[key] = list(taken)
         self._installs[key] = (prompt.copy(), int(from_page), taken)
         return taken

@@ -95,3 +95,56 @@ def dma_interpret(monkeypatch):
         flash_pallas.set_interpret(prev)
         jax.clear_caches()
     assert made and not any(r.races_found for r in made)
+
+
+def _run_tables(B, mp, P, layout):
+    """(B, mp) page tables over B * mp pages, slot b's pages b * mp ..:
+    in order ("runs": every block of ``P`` entries one run of the pool),
+    each block's in reverse ("none": no block of two pages or more is
+    one), or every other block's ("mixed")."""
+    j = np.arange(mp)
+    start = j // P * P
+    last = np.minimum(start + P, mp) - 1
+    back = start + last - j                   # the block's entries reversed
+    flip = {"runs": j < 0, "none": j >= 0, "mixed": j // P % 2 == 1}[layout]
+    return (np.arange(B)[:, None] * mp + np.where(flip, back, j)[None]
+            ).astype(np.int32)
+
+
+def _scatter_pages(tables, arrays, seed, parts=1):
+    """The same rows at every position of every slot, the pages moved to
+    random places of each stacked (layers, n_pages, ...) array and the
+    tables rewritten to match: few blocks are left as runs. ``parts``:
+    a page stays in its part of that many of the page axis (a shard's)."""
+    n = np.asarray(arrays[0]).shape[1]
+    rng = np.random.default_rng(seed)
+    to = np.concatenate([i * (n // parts) + rng.permutation(n // parts)
+                         for i in range(parts)])
+    back = np.argsort(to)
+    return (to[np.asarray(tables)].astype(np.int32),
+            [np.asarray(a)[:, back] for a in arrays])
+
+
+def _run_words(table, P):
+    """The reference of ``_blocked_walk``'s run field, a block of the
+    padded walk table (-1: unowned): the first owned page's index in the
+    block plus one where the owned pages are consecutive entries holding
+    consecutive pages, else 0."""
+    out = []
+    for blk in np.asarray(table).reshape(-1, P):
+        idx = np.flatnonzero(blk >= 0)
+        run = idx.size and (np.diff(idx) == 1).all() \
+            and (np.diff(blk[idx]) == 1).all()
+        out.append(int(idx[0]) + 1 if run else 0)
+    return out
+
+
+@pytest.fixture()
+def page_runs():
+    """Page tables whose blocks are runs of the pool or not, and the same
+    pages scattered (``tables``, ``scatter``), and the run field the walk
+    should carry (``words``): for tests of the paged kernels' run copies,
+    whose outputs must be bit-equal either way."""
+    import types
+    return types.SimpleNamespace(tables=_run_tables, scatter=_scatter_pages,
+                                 words=_run_words)

@@ -344,6 +344,52 @@ def test_a_windows_blocks_wait_for_their_owned_pages_bytes(
         lane_tile_blocks, 16, mp, window, pos, "float32", 1e-5, heads)
 
 
+@pytest.mark.parametrize("layout", ["runs", "mixed"])
+@pytest.mark.parametrize("psz,mp,W,window,pos,ring", [
+    # a band of 40 behind two fresh rows: owned pages 10-14 of a block of
+    # 16 (a run that starts past the block's first page), 13-18 across
+    # two blocks
+    (8, 20, 2, 40, [1, 120, 150], False),
+    # K-EXAONE's ring: 9 pages of 16 from page0, a block of 8 and one of 1
+    (16, 9, 1, 128, [300, 140, 1000], True),
+], ids=["window-w2", "ring-of-9"])
+def test_gqa_run_blocks_match_their_pages_scattered(
+        lane_tile_blocks, page_runs, psz, mp, W, window, pos, ring, layout):
+    """The grouped-query kernel over tables whose blocks are runs of the
+    pool (``page_runs``): a window behind its lower bound and a window
+    layer's ring from ``page0``, whose owned pages are a run that may
+    start past its block's first page. The output is BIT-equal to that of
+    the same pages scattered, and the walk marks the runs by the rule."""
+    Hq, Hkv, D = GQA_HEADS[0]
+    B = len(pos)
+    q, kn, vn, kp, vp, _, pos = _kernel_case(6, B, W, Hq, Hkv, D, psz, mp,
+                                             pos, window)
+    page0 = jnp.asarray([max(int(p) - window + 1, 0) // psz for p in pos]
+                        if ring else [0] * B, jnp.int32)
+    P, _ = paged_pallas._walk_shape(mp, psz, Hkv * D * 4, 2)
+    tables = page_runs.tables(B, mp, P, layout)
+    moved, (kp2, vp2) = page_runs.scatter(tables, [kp, vp], seed=2)
+    outs = [np.asarray(paged_pallas.paged_gqa_attention(
+        q, kn, vn, jnp.asarray(k), jnp.asarray(v), jnp.asarray(t), pos,
+        n_head=Hq, n_kv_head=Hkv, layer=KERNEL_LAYER, attn_window=window,
+        page0=page0 if ring else None, name="swa_test"))
+        for t, k, v in ((tables, kp, vp), (moved, kp2, vp2))]
+    np.testing.assert_array_equal(*outs)
+    want = _einsum_attention(q, kn, vn, kp, vp, tables, pos, Hq, Hkv,
+                             window, np.asarray(page0) if ring else None)
+    assert np.abs(outs[0] - want).max() < 1e-5
+    walk = paged_pallas._blocked_walk(
+        jnp.asarray(tables), paged_pallas.gqa_owned_pages(
+            pos, page0, mp, psz, window), psz, Hkv * D * 4, 2)
+    word = np.asarray(walk[6])
+    first = word >> paged_pallas.RUN_SHIFT
+    assert first.tolist() == page_runs.words(np.asarray(walk[0]), P)
+    if layout == "runs":
+        live = (word & paged_pallas.COUNT_MASK) > 0
+        assert (first[live] > 0).all()
+        assert (first > 1).any() != ring    # the band's run past a first
+
+
 def test_pages_behind_the_window_are_not_owned():
     owned = np.asarray(paged_pallas.gqa_owned_pages(
         jnp.asarray([41, 3], jnp.int32), jnp.zeros((2,), jnp.int32), 8, 8,

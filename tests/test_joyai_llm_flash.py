@@ -353,6 +353,42 @@ def test_mla_blocks_wait_for_their_owned_pages_bytes(dma_interpret, pos):
         "bfloat16", 1.6e-2, pos)
 
 
+@pytest.mark.parametrize("layout", ["runs", "none", "mixed"])
+def test_mla_run_blocks_match_their_pages_scattered(
+        page_runs, layout, pos=(0, 5, 512, 513, 1700, 2060)):
+    """The latent kernel at the published widths over tables whose blocks
+    of 32 pages are runs of the pool (one copy a set bit of a block's
+    owned count: a whole block ONE copy), are none (a copy a page), or
+    every other one, with the slots of the contract test above (a table
+    of 129 entries, its last block one page): the output is BIT-equal to
+    that of the same pages scattered, and matches the contract."""
+    H, W, R, psz, mp = 32, 640, 512, 16, 129
+    pos = np.array(pos, np.int32)
+    B = len(pos)
+    r = np.random.default_rng(8)
+    f = lambda *s: np.asarray(jnp.asarray(  # noqa: E731
+        r.standard_normal(s), jnp.bfloat16), np.float32)
+    q, new, pages = f(B, H, W), f(B, W), f(2, B * mp, psz, W)
+    tables = page_runs.tables(B, mp, 32, layout)
+    moved, (spread,) = page_runs.scatter(tables, [pages], seed=4)
+    outs = [np.asarray(paged_pallas.paged_mla_attention(
+        jnp.asarray(q, jnp.bfloat16), jnp.asarray(new, jnp.bfloat16),
+        jnp.asarray(p, jnp.bfloat16), jnp.asarray(t), jnp.asarray(pos),
+        layer=1, latent=R, scale=192 ** -0.5), np.float32)
+        for t, p in ((tables, pages), (moved, spread))]
+    np.testing.assert_array_equal(*outs)
+    np.testing.assert_allclose(
+        outs[0], _einsum_latent(q, new, pages[1], tables, pos, R,
+                                192 ** -0.5), atol=1.6e-2, rtol=1.6e-2)
+
+
+def test_mla_run_blocks_wait_for_their_bytes(dma_interpret, page_runs):
+    """The same over run tables under the interpreter that models each
+    copy and its semaphore: a run block's copies, a set bit of its
+    owned count, land before its waits release, and no read races one."""
+    test_mla_run_blocks_match_their_pages_scattered(page_runs, "runs")
+
+
 @pytest.mark.parametrize("change,word", [
     (dict(row_width=576), "mla_row_lanes"), (dict(page_size=12), "page_align"),
     (dict(kv_quant="int8"), "mla_kv_quant")],
@@ -557,7 +593,7 @@ def test_a_block_is_as_many_pages_as_its_rows_bytes_ask(request, monkeypatch,
         row_bytes = eng.pool.kv_array.shape[-1] * 4
         psz, P = PSZ, eng._kv_block_pages
         assert (row_bytes, P) == (512, 32)
-        want, waits, other, dispatch = [], [], [], eng._dispatch
+        want, waits, runs, other, dispatch = [], [], [], [], eng._dispatch
 
         def spy(k, kill, *a):
             pos = np.where(eng._active & ~kill, eng._pos, 0)
@@ -566,7 +602,10 @@ def test_a_block_is_as_many_pages_as_its_rows_bytes_ask(request, monkeypatch,
                 psz, row_bytes, 1)
             want.append(int(np.asarray(walk[2]).sum()))
             # one pool: a wait a set bit of each block's owned pages
-            waits.append(int(np.bitwise_count(np.asarray(walk[6])).sum()))
+            word = np.asarray(walk[6])
+            waits.append(int(np.bitwise_count(
+                word & paged_pallas.COUNT_MASK).sum()))
+            runs.append(int(((word >> paged_pallas.RUN_SHIFT) > 0).sum()))
             other.append(int(paged_pallas.live_blocks(pos, psz, 16).sum()))
             return dispatch(k, kill, *a)
 
@@ -577,6 +616,7 @@ def test_a_block_is_as_many_pages_as_its_rows_bytes_ask(request, monkeypatch,
                  if ev["name"] == "serve/launch"]
         assert want and [a["kv_blocks_live"] for a in stats] == want
         assert [a["kv_block_waits"] for a in stats] == waits
+        assert [a["kv_block_runs"] for a in stats] == runs
         assert all(a["kv_block_tokens"] == P * psz == 256 for a in stats)
         assert want != other             # two blocks of 256, not three of 128
     finally:

@@ -6,6 +6,7 @@ program equivalence, the Pallas fast path in interpret mode, and the
 metrics_summary key schema bench dashboards depend on."""
 
 import dataclasses
+import heapq
 
 import jax
 import jax.numpy as jnp
@@ -50,17 +51,19 @@ def _greedy(rid, prompt, max_new=6):
 
 def _check_allocator(alloc: PageAllocator, live):
     """Reference-model invariants: refcounts equal slot references
-    exactly, free/in-use/radix sets are consistent, nothing leaks."""
+    exactly, free/in-use/radix sets are consistent, nothing leaks, and
+    the extents' free counts, bitmasks, heap and buckets say what the
+    free pages do."""
     counts = np.zeros_like(alloc.ref)
     for claim, _pos in live.values():
         for p in claim.pages:
             counts[p] += 1
     assert (counts == alloc.ref).all(), "refcount drift vs live claims"
-    free = list(alloc._free)
-    assert len(set(free)) == len(free), "double-freed page"
+    free = set(np.flatnonzero(alloc._is_free).tolist())
+    assert alloc.pages_free == len(free)
     used = {p for claim, _ in live.values() for p in claim.pages}
-    assert not (set(free) & used), "page simultaneously free and mapped"
-    assert not (set(free) & set(alloc.page_node)), "cached page on free list"
+    assert not (free & used), "page simultaneously free and mapped"
+    assert not (free & set(alloc.page_node)), "cached page on free list"
     leaked = [p for p in range(alloc.n_pages)
               if p not in free and alloc.ref[p] == 0
               and p not in alloc.page_node]
@@ -73,20 +76,40 @@ def _check_allocator(alloc: PageAllocator, live):
         assert int(p) in alloc.page_node or any(
             p in (s for pair in [c.cow] for s, _ in pair)
             for c, _ in live.values()), f"untracked shared page {p}"
+    P = alloc.block
+    whole = set()
+    for e, m in enumerate(alloc._ext_mask):
+        bits = alloc._is_free[e * P:(e + 1) * P]
+        assert m == sum(1 << int(i) for i in np.flatnonzero(bits))
+        n = m.bit_count()
+        if n == P:
+            whole.add(e)
+        else:
+            assert e in alloc._part[n]
+    assert sorted(alloc._whole) == sorted(whole) == sorted(set(alloc._whole))
+    assert alloc._n_whole == len(whole)
+    assert sum(map(len, alloc._part)) + len(whole) == len(alloc._ext_mask)
 
 
-def test_page_allocator_fuzz():
-    """A few hundred seeded random acquire/advance/release ops against
-    the reference model: refcounts, no double-map, no leaks, claimed
-    prefixes byte-identical to the prompts that registered them."""
-    rng = np.random.default_rng(42)
+def _fuzz(seed: int, blocks, steps: int = 400):
+    """Seeded random acquire/advance/release ops, the same on one
+    allocator a block size in ``blocks``, against the reference model:
+    refcounts, no double-map, no leaks, claimed prefixes byte-identical
+    to the prompts that registered them; and an acquire succeeds exactly
+    when the rule says (the pages the claim needs beyond its cached
+    prefix, one more for a copy-on-write split, against the free pages
+    and the refcount-0 radix pages it does not claim), with the same
+    outcome, free count and evictions whatever the block: where the
+    pages go never moves admission."""
+    rng = np.random.default_rng(seed)
     psz = 4
-    alloc = PageAllocator(n_pages=20, page_size=psz, prefix_cache=True)
+    allocs = [PageAllocator(n_pages=20, page_size=psz, prefix_cache=True,
+                            block_pages=b) for b in blocks]
     seen = []           # past prompts, replayed verbatim for full hits
-    live = {}           # id -> (claim, simulated next-write pos)
-    content = {}        # phys page -> token bytes (set at registration)
+    lives = [{} for _ in allocs]    # id -> (claim, next-write pos)
+    contents = [{} for _ in allocs]  # phys page -> token bytes
     next_id = 0
-    for step in range(400):
+    for step in range(steps):
         op = rng.choice(["acquire", "advance", "release"],
                         p=[0.45, 0.3, 0.25])
         if op == "acquire":
@@ -101,49 +124,156 @@ def test_page_allocator_fuzz():
                 seen.append(prompt)
             P = int(prompt.size)
             cap = int(rng.integers(1, 9))
-            can = alloc.can_acquire(prompt, cap)
-            claim = alloc.acquire(prompt, cap)
-            assert (claim is not None) == can, \
-                "can_acquire disagreed with acquire"
+            outcomes = []
+            for alloc, live, content in zip(allocs, lives, contents):
+                chain = alloc.radix.lookup(prompt, psz, touch=False)
+                need = (alloc.n_pages_for(P, cap) - len(chain)
+                        + (bool(chain) and len(chain) * psz == P))
+                claimed = {n.page for n in chain}
+                held = ({p for c, _ in live.values() for p in c.pages}
+                        | set(alloc.page_node))
+                reclaim = sum(1 for p in alloc.page_node
+                              if alloc.ref[p] == 0 and p not in claimed)
+                rule = need <= alloc.n_pages - len(held) + reclaim
+                can = alloc.can_acquire(prompt, cap)
+                claim = alloc.acquire(prompt, cap)
+                assert (claim is not None) == can == rule, \
+                    "acquire disagreed with the admission rule"
+                outcomes.append(claim and (claim.claimed_tokens,
+                                           len(claim.pages), len(claim.cow)))
+                if claim is None:
+                    continue
+                assert claim.claimed_tokens % psz == 0
+                assert claim.claimed_tokens <= P
+                # claimed pages must hold exactly the prompt's prefix bytes
+                for g in range(claim.claimed_tokens // psz):
+                    want = prompt[g * psz:(g + 1) * psz].tobytes()
+                    got_page = claim.pages[g]
+                    if claim.cow and g == claim.claimed_tokens // psz - 1:
+                        got_page = claim.cow[0][0]  # COW source held them
+                    assert content[got_page] == want, "stale prefix claim"
+                assert len(claim.pages) == alloc.n_pages_for(P, cap)
+                alloc.register(claim, P - 1)
+                live[next_id] = (claim, P - 1)
+            assert len(set(outcomes)) == 1, outcomes
+            next_id += outcomes[0] is not None
+        elif op == "advance" and lives[0]:
+            cid = int(rng.choice(list(lives[0])))
+            step_by = int(rng.integers(1, 5))
+            for alloc, live in zip(allocs, lives):
+                claim, pos = live[cid]
+                alloc.register(claim, pos + step_by)
+                live[cid] = (claim, pos + step_by)
+        elif op == "release" and lives[0]:
+            cid = int(rng.choice(list(lives[0])))
+            for alloc, live in zip(allocs, lives):
+                alloc.release(live.pop(cid)[0])
+        for alloc, live, content in zip(allocs, lives, contents):
+            # sync the content shadow with registrations/evictions
+            for claim, _pos in live.values():
+                for g in range(claim.next_reg):
+                    p = claim.pages[g]
+                    if p in alloc.page_node:
+                        content[p] = claim.prompt[g * psz:(g + 1) * psz]\
+                            .tobytes()
+            for p in list(content):
+                if p not in alloc.page_node:
+                    del content[p]
+            _check_allocator(alloc, live)
+        assert len({(a.pages_free, a.evictions, a.prefix_hits,
+                     a.cow_copies) for a in allocs}) == 1
+    for alloc in allocs:
+        assert alloc.prefix_hits > 0, "fuzz never exercised a prefix hit"
+        assert alloc.evictions > 0, "fuzz never exercised eviction"
+        assert alloc.cow_copies > 0, "fuzz never exercised copy-on-write"
+
+
+def test_page_allocator_fuzz():
+    """A few hundred seeded random ops against the reference model
+    (``_fuzz``), pages handed out one at a time."""
+    _fuzz(42, (1,))
+
+
+@pytest.mark.parametrize("seed", [42, 5, 1234])
+def test_page_allocator_fuzz_in_blocks(seed):
+    """The same ops on allocators whose blocks are 1, 2, 3, 4 and 8
+    pages (an extent of 3 leaves the pool's last one short), in
+    lockstep: each holds to the reference model, and every acquire
+    succeeds or fails as on the others and as the rule says."""
+    _fuzz(seed, (1, 2, 3, 4, 8))
+
+
+def test_allocator_hands_a_request_its_blocks_as_runs():
+    """A block the request fills whole is a whole aligned extent, the
+    request's last part of a block one run beside another request's
+    part, and a prefix claim's copy-on-write split starts the run of
+    the pages after it; ``ops.paged_pallas.block_runs`` reads each
+    block's run back."""
+    from replicatinggpt_tpu.ops.paged_pallas import block_runs
+    alloc = PageAllocator(n_pages=32, page_size=4, block_pages=4)
+    a = alloc.acquire(np.arange(20, dtype=np.int32), cap=9)   # 7 pages
+    assert a.pages == [0, 1, 2, 3, 4, 5, 6]
+    b = alloc.acquire(np.arange(1, 9, dtype=np.int32), cap=1)  # 2 pages
+    assert b.pages == [8, 9]
+    c = alloc.acquire(np.arange(2, 6, dtype=np.int32), cap=1)  # 1 page
+    assert c.pages == [7]            # the fullest extent with room
+    assert block_runs(a.pages, 4).tolist() == [4, 3]
+    alloc.release(a)
+    assert alloc.pages_free == 32 - 3
+    alloc.register(b, 8)             # b's two prompt pages: the radix
+    d = alloc.acquire(np.arange(1, 9, dtype=np.int32), cap=7)  # 4 pages
+    assert d.claimed_tokens == 8 and d.cow == [(9, d.pages[1])]
+    assert d.pages[0] == 8 and block_runs(d.pages[1:], 4).tolist() == [3]
+
+
+def _lognormal(rng, n, median, sigma, lo, hi):
+    return np.clip(np.round(rng.lognormal(np.log(median), sigma, n)),
+                   lo, hi).astype(int)
+
+
+def test_allocator_runs_survive_ten_turnovers_of_the_pool():
+    """The serve-decode-joyai cell's pool on the host: 256 slots over
+    40,960 pages of 16, blocks of 32 (its latent kernel's), the closed
+    loop's lengths (prompts lognormal median 512, sigma 1.0, 16-3,072;
+    answers median 1,024, sigma 0.7, 64-4,096), each request's whole
+    budget reserved at admission, admitted first come first served as
+    pages allow (99% of them reserved), each finishing after its answer.
+    Over the tenth turnover of the pool, at least 85% of the live
+    blocks (a block holding a position under its slot's) own one run of
+    the pool, by the kernel's rule (``block_runs``); a LIFO free list
+    left about 5%."""
+    from replicatinggpt_tpu.ops.paged_pallas import block_runs
+    rng = np.random.default_rng(0)
+    n_pages, psz, P, slots, N = 40_960, 16, 32, 256, 5000
+    alloc = PageAllocator(n_pages, psz, prefix_cache=False, block_pages=P)
+    prompts = _lognormal(rng, N, 512, 1.0, 16, 3072)
+    answers = _lognormal(rng, N, 1024, 0.7, 64, 4096)
+    live, ends, t, nxt, freed, shares, reserved = {}, [], 0, 0, 0, [], []
+    while freed < 10 * n_pages:
+        while len(live) < slots:
+            prompt = np.zeros(prompts[nxt], np.int32)
+            claim = alloc.acquire(prompt, int(answers[nxt]))
             if claim is None:
-                continue
-            assert claim.claimed_tokens % psz == 0
-            assert claim.claimed_tokens <= P
-            # claimed pages must hold exactly the prompt's prefix bytes
-            for g in range(claim.claimed_tokens // psz):
-                want = prompt[g * psz:(g + 1) * psz].tobytes()
-                got_page = claim.pages[g]
-                if claim.cow and g == claim.claimed_tokens // psz - 1:
-                    got_page = claim.cow[0][0]   # COW source held the bytes
-                assert content[got_page] == want, "stale prefix claim"
-            assert len(claim.pages) == alloc.n_pages_for(P, cap)
-            alloc.register(claim, P - 1)
-            live[next_id] = (claim, P - 1)
-            next_id += 1
-        elif op == "advance" and live:
-            cid = int(rng.choice(list(live)))
-            claim, pos = live[cid]
-            pos += int(rng.integers(1, 5))
-            alloc.register(claim, pos)
-            live[cid] = (claim, pos)
-        elif op == "release" and live:
-            cid = int(rng.choice(list(live)))
-            claim, _ = live.pop(cid)
-            alloc.release(claim)
-        # sync the content shadow with registrations/evictions
-        for claim, _pos in live.values():
-            for g in range(claim.next_reg):
-                p = claim.pages[g]
-                if p in alloc.page_node:
-                    content[p] = claim.prompt[g * psz:(g + 1) * psz]\
-                        .tobytes()
-        for p in list(content):
-            if p not in alloc.page_node:
-                del content[p]
-        _check_allocator(alloc, live)
-    assert alloc.prefix_hits > 0, "fuzz never exercised a prefix hit"
-    assert alloc.evictions > 0, "fuzz never exercised eviction"
-    assert alloc.cow_copies > 0, "fuzz never exercised copy-on-write"
+                break
+            live[nxt] = (t, prompts[nxt], block_runs(claim.pages, P), claim)
+            heapq.heappush(ends, (t + answers[nxt], nxt))
+            nxt += 1
+        reserved.append(alloc.pages_in_use / n_pages)
+        t, done = heapq.heappop(ends)
+        claim = live.pop(done)[3]
+        alloc.release(claim)
+        freed += len(claim.pages)
+        if freed > 9 * n_pages:
+            runs = blocks = 0
+            for t0, n_prompt, run, _ in live.values():
+                pages = -(-(n_prompt - 1 + t - t0) // psz)
+                full, rest = divmod(pages, P)
+                runs += (run[:full] == P).sum() + (rest > 0
+                                                  and run[full] >= rest)
+                blocks += full + (rest > 0)
+            shares.append(runs / blocks)
+    assert np.mean(reserved) > 0.99
+    assert np.mean(shares) >= 0.85, np.mean(shares)
 
 
 def test_allocator_rejects_when_exhausted_and_recovers():
@@ -534,6 +664,143 @@ def test_paged_pallas_kernel_matches_gather_reference(psz, mp, pos):
                            split(va), jnp.asarray(pos))
     ref = np.asarray(ref).transpose(0, 2, 1, 3).reshape(B, C)
     np.testing.assert_allclose(np.asarray(out), ref, atol=1e-5, rtol=1e-5)
+
+
+# tables whose blocks are runs of the pool, or not (``page_runs``), and
+# the same pages scattered: (W, page, table length, positions, layout).
+# Under ``lane_tile_blocks`` pages of 16 are blocks of 8, pages of 8 of 16;
+# a table of 20 pages of 16 ends in a short block of 4
+RUN_CASES = {
+    "all-runs": (1, 16, 24, [384, 384, 0, 17], "runs"),
+    "none": (1, 16, 24, [384, 200, 0, 17], "none"),
+    "mixed": (1, 16, 24, [384, 383, 129, 40], "mixed"),
+    "short-last-block": (1, 16, 20, [320, 300, 129, 3], "runs"),
+    "w8-mixed": (8, 8, 20, [150, 128, 3, 0], "mixed"),
+}
+
+
+@pytest.mark.parametrize("kind", ["plain", "int8", "shard-mask"])
+@pytest.mark.parametrize("case", list(RUN_CASES))
+def test_run_blocks_match_their_pages_scattered(lane_tile_blocks, page_runs,
+                                                case, kind):
+    """A block whose owned pages are one run of the pool is started as a
+    copy a set bit of their count (``_walk_blocks``), any other a copy a
+    page: the output is BIT-equal to that of the same pages scattered
+    over the pool, whose blocks are not runs, and matches the gather
+    reference. Every block a run, none, every other, a table's short
+    last block and a window of 8 rows; a plain pool, an int8 one with its
+    page scales, and a shard's owned subset (the 'data' halves of the
+    pool, each a call of its own, ``fold=False``), where a run of the
+    table splits between the halves (the tables rotated by 4 pages) and
+    a half's part of a block starts past its first page. The walk marks
+    as runs the blocks the rule says."""
+    from test_pallas_routes import LAYER, N_LAYERS, _window_ref
+
+    from replicatinggpt_tpu.ops import paged_pallas as pp
+    from replicatinggpt_tpu.quant.kv import quantize_rows
+    W, psz, mp, pos, layout = RUN_CASES[case]
+    pos = np.array(pos, np.int32)
+    H, D = 2, 32
+    B, C, N = len(pos), H * D, len(pos) * mp
+    rng = np.random.default_rng(9)
+    f = lambda *s: rng.standard_normal(s).astype(np.float32)  # noqa: E731
+    q, kn, vn = f(B, W, C), f(B, W, C), f(B, W, C)
+    kp, vp = f(N_LAYERS, N, psz, C), f(N_LAYERS, N, psz, C)
+    P, _ = pp._walk_shape(mp, psz, C * 4, 2)
+    tables = page_runs.tables(B, mp, P, layout)
+    pools, extra = [kp, vp], {}
+    if kind == "int8":
+        (kq, ks), (vq, vs) = (quantize_rows(jnp.array(a), "int8", H, "page")
+                              for a in (kp, vp))
+        pools = [kq, vq, ks, vs]
+    owned = [None]
+    if kind == "shard-mask":
+        tables = (tables + 4) % N
+        prefix = np.asarray(pp.gqa_owned_pages(
+            jnp.array(pos), jnp.zeros_like(pos), mp, psz, 0))
+        owned = [prefix & (tables < N // 2), prefix & (tables >= N // 2)]
+    moved, spread = page_runs.scatter(tables, pools, seed=5)
+
+    def call(tab, arrays, own):
+        if kind == "int8":
+            extra.update(k_scales=jnp.array(arrays[2]),
+                         v_scales=jnp.array(arrays[3]))
+        out = pp.paged_window_attention(
+            *map(jnp.array, (q, kn, vn, arrays[0], arrays[1], tab, pos)),
+            n_head=H, layer=LAYER, fold=own is None,
+            owned=None if own is None else jnp.array(own), **extra)
+        return [np.asarray(o) for o in (out if own is not None else [out])]
+
+    late = False    # a run that starts past its block's first page
+    for own in owned:
+        walk = pp.window_walk(jnp.array(tables), jnp.array(pos), psz,
+                              C * pools[0].dtype.itemsize, 2,
+                              None if own is None else jnp.array(own))
+        words = np.asarray(walk[6])
+        assert (words >> pp.RUN_SHIFT).tolist() \
+            == page_runs.words(np.asarray(walk[0]), P)
+        count = words & pp.COUNT_MASK
+        first = words >> pp.RUN_SHIFT
+        if own is None and layout == "runs":
+            assert (first[count > 0] == 1).all()
+        elif own is None and layout == "none":
+            assert not first[count > 1].any()
+        elif own is None:
+            assert first[count > 1].any() and not first[count > 1].all()
+        late = late or (first > 1).any()
+        for a, b in zip(call(tables, pools, own), call(moved, spread, own)):
+            np.testing.assert_array_equal(a, b)
+    if kind == "shard-mask" and case == "all-runs":
+        assert late          # a half's part of a run past the block's first
+    if kind == "plain":
+        np.testing.assert_allclose(
+            call(tables, pools, None)[0],
+            _window_ref(q, kn, vn, kp[LAYER], vp[LAYER], tables, pos, H),
+            atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("case", ["mixed", "short-last-block"])
+def test_sharded_run_blocks_match_their_pages_scattered(lane_tile_blocks,
+                                                        page_runs, case):
+    """The ``shard_map`` wrapper on the 2x2 (data, model) mesh over run
+    tables rotated so that runs cross the 'data' halves of the pool (a
+    shard's part of a run is a run: the shard-mask case above reads the
+    walk): the merged output is bit-equal to that of the same pages
+    scattered, each within its shard's half."""
+    from test_pallas_routes import LAYER, N_LAYERS, _window_ref
+
+    from replicatinggpt_tpu.ops import paged_pallas as pp
+    from replicatinggpt_tpu.parallel.mesh import make_serve_mesh
+    W, psz, mp, pos, layout = RUN_CASES[case]
+    H, C, B = 2, 64, len(pos)
+    N = B * mp
+    rng = np.random.default_rng(4)
+    f = lambda *s: rng.standard_normal(s).astype(np.float32)  # noqa: E731
+    q, kn, vn = f(B, W, C), f(B, W, C), f(B, W, C)
+    kp, vp = f(N_LAYERS, N, psz, C), f(N_LAYERS, N, psz, C)
+    P, _ = pp._walk_shape(mp, psz, C * 4, 2)
+    tables = (page_runs.tables(B, mp, P, layout) + 4) % N
+    moved, (kp2, vp2) = page_runs.scatter(tables, [kp, vp], seed=6,
+                                          parts=2)
+    mesh = make_serve_mesh(2, 2)
+    outs = [np.asarray(pp.sharded_paged_window_attention(
+        *map(jnp.array, (q, kn, vn, k, v, t, pos)), n_head=H, mesh=mesh,
+        layer=LAYER)) for t, k, v in ((tables, kp, vp), (moved, kp2, vp2))]
+    np.testing.assert_array_equal(*outs)
+    np.testing.assert_allclose(
+        outs[0], _window_ref(q, kn, vn, kp[LAYER], vp[LAYER], tables,
+                             np.array(pos), H), atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("case", ["mixed", "short-last-block", "w8-mixed"])
+def test_run_blocks_wait_for_their_bytes(dma_interpret, lane_tile_blocks,
+                                         page_runs, case):
+    """A run block's few copies under the interpreter that models each
+    copy and its semaphore: its waits, a set bit of its owned count as
+    before, release exactly when the copies' rows have landed, and no
+    read races a copy."""
+    test_run_blocks_match_their_pages_scattered(lane_tile_blocks,
+                                                page_runs, case, "plain")
 
 
 def test_paged_kernel_engine_greedy_parity(params, monkeypatch):

@@ -251,8 +251,17 @@ def test_blocked_walk_matches_gather_reference(lane_tile_blocks, walk_name,
     assert table.shape == (len(pos), nb * P)
     assert (own[:, :mp] == np.asarray(owned)).all() and not own[:, mp:].any()
     assert (table[:, :mp][own[:, :mp]] == tables[own[:, :mp]]).all()
-    # a block's owned pages, counted: what its waits add up to
-    assert n_owned.tolist() == own.reshape(-1, P).sum(-1).tolist()
+    # a block's owned pages, counted: what its waits add up to; above the
+    # count, where they are one run of the pool, the first's index + 1
+    assert (n_owned & pp.COUNT_MASK).tolist() \
+        == own.reshape(-1, P).sum(-1).tolist()
+    runs = [0] * len(n_owned)
+    for i, blk in enumerate(table.reshape(-1, P)):
+        idx = np.flatnonzero(blk >= 0)
+        if idx.size and (np.diff(idx) == 1).all() \
+                and (np.diff(blk[idx]) == 1).all():
+            runs[i] = int(idx[0]) + 1
+    assert (n_owned >> pp.RUN_SHIFT).tolist() == runs
     # a block is live if it owns a page: a slot's loop takes its own live
     # blocks in rising order, and the live blocks hand the double buffer
     # on to one another, slot after slot
@@ -578,8 +587,10 @@ def test_a_block_is_waited_by_its_bytes_not_a_page_at_a_time(geometry):
     DMA wait inside a page loop (a return to one wait a page, 32 a
     latent block, fails here); a block's waits sit in the block loop, one
     a pool a bit of its owned count (``P.bit_length()``, of which a whole
-    block of a power of two takes one); the copies still start in the
-    page loops."""
+    block of a power of two takes one); a block whose pages are a run
+    starts its copies there too, one a pool a bit of the count, and any
+    other block one a page in the page loops (each start is emitted
+    twice: a call's first block and the block ahead)."""
     from collections import Counter
 
     from replicatinggpt_tpu.ops import paged_pallas as pp
@@ -605,6 +616,7 @@ def test_a_block_is_waited_by_its_bytes_not_a_page_at_a_time(geometry):
         args = _abstract(((B, 1, H * D), bf), row, row, pool, pool) + tail
     ops = Counter(_dma_ops(_kernel_body(fn, *args)))
     assert ops == {("dma_wait", 1): pools * P.bit_length(),
+                   ("dma_start", 1): 2 * pools * P.bit_length(),
                    ("dma_start", 2): 2 * pools}, (geometry, ops)
 
 
@@ -645,9 +657,11 @@ def test_launch_stats_count_the_blocks_the_device_mask_owns(
     block covers, and ``kv_blocks_live`` = the turns
     of the slots' loops: the blocks that hold an owned page in the mask
     the kernel is handed on the device (rebuilt here from what the launch
-    uploads: tables, positions, the live slots), and ``kv_block_waits`` =
+    uploads: tables, positions, the live slots), ``kv_block_waits`` =
     the DMA waits those turns make: one a pool (K and V) a set bit of a
-    block's owned-page count in the same walk."""
+    block's owned-page count in the same walk, and ``kv_block_runs`` =
+    the live blocks that walk marks as runs of the pool (the allocator
+    hands each request's blocks out as runs: all of them here)."""
     from replicatinggpt_tpu.ops import paged_pallas as pp
     from replicatinggpt_tpu.utils.telemetry import Telemetry
     cfg = dataclasses.replace(CFG, block_size=256)
@@ -661,7 +675,7 @@ def test_launch_stats_count_the_blocks_the_device_mask_owns(
     P = pp.block_pages(psz, mp, cfg.n_embd * 4, 2)
     assert (psz, mp, P) == (8, 32, 16)
     assert eng._kv_block_pages == P           # the walk's block, not a stat
-    want, waits, dispatch = [], [], eng._dispatch
+    want, waits, runs, dispatch = [], [], [], eng._dispatch
 
     def spy(k, kill, *a):
         pos = jnp.asarray(np.where(eng._active & ~kill, eng._pos, 0),
@@ -670,7 +684,9 @@ def test_launch_stats_count_the_blocks_the_device_mask_owns(
         walk = pp._blocked_walk(
             jnp.asarray(eng.pool.tables), owned, psz, cfg.n_embd * 4, 2)
         want.append(int(np.asarray(walk[2]).sum()))  # the loops' iterations
-        waits.append(2 * int(np.bitwise_count(np.asarray(walk[6])).sum()))
+        word = np.asarray(walk[6])
+        waits.append(2 * int(np.bitwise_count(word & pp.COUNT_MASK).sum()))
+        runs.append(int(((word >> pp.RUN_SHIFT) > 0).sum()))
         return dispatch(k, kill, *a)
 
     eng._dispatch = spy
@@ -689,6 +705,7 @@ def test_launch_stats_count_the_blocks_the_device_mask_owns(
     # 16 pages of 8 a block: 1 to 5 waits a pool a block, one when whole
     assert [a["kv_block_waits"] for a in stats] == waits
     assert all(2 * n <= w <= 10 * n for n, w in zip(want, waits))
+    assert [a["kv_block_runs"] for a in stats] == runs == want
     assert all(a["kv_blocks_grid"] == 3 and "kv_block_pages" not in a
                and a["kv_block_tokens"] == P * psz == 128 for a in stats)
 

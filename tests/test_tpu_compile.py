@@ -237,14 +237,7 @@ def test_joyai_decode_step_carries_its_scopes(mosaic, one_chip):
     for the described v5e on the Pallas route: the latent kernel is the
     program's one Pallas kernel, and the scopes its projections,
     absorption, scatter, experts and head run under are on the ops."""
-    import dataclasses
-    from replicatinggpt_tpu.models import joyai_llm_flash
-    cfg = dataclasses.replace(
-        get_config("joyai-llm-flash").model, n_layer=3,
-        mlp_layer_types=("dense", "sparse", "sparse"), n_experts=256,
-        experts_held=tuple(range(8)), vocab_size=4096)
-    text = _lower_decode_window(one_chip, cfg, joyai_llm_flash, 16, 1024,
-                                512).compile().as_text()
+    text = _decode_text(one_chip, "joyai-llm-flash")
     assert _kernel_names(text) and all(
         n.startswith("paged_mla_attention") for n in _kernel_names(text))
     op_names = re.findall(r'op_name="([^"]*)"', text)
@@ -254,6 +247,58 @@ def test_joyai_decode_step_carries_its_scopes(mosaic, one_chip):
             scope
     assert not any(re.search(r"(^|/)(attn_swa|attn_global|short_conv)(/|$)",
                              n) for n in op_names)
+
+
+_DECODE_TEXT = {}
+
+
+def _decode_text(one_chip, preset):
+    """Optimized HLO of a decode window for the described v5e, compiled
+    once a module (``_lower_decode_window``): JoyAI-LLM-Flash's first
+    three layers (dense, sparse, sparse, 8 experts held) at 16 slots over
+    1,024 pages, or gpt2-large's served tree cut to three layers in ONE
+    scan (a traced layer index, as its 36 run) at 96 slots over 3,072."""
+    import dataclasses
+    from replicatinggpt_tpu.models import gpt, joyai_llm_flash
+    if preset not in _DECODE_TEXT:
+        cfg = get_config(preset).model
+        if preset == "joyai-llm-flash":
+            cfg = dataclasses.replace(
+                cfg, n_layer=3, mlp_layer_types=("dense", "sparse", "sparse"),
+                n_experts=256, experts_held=tuple(range(8)), vocab_size=4096)
+            low = _lower_decode_window(one_chip, cfg, joyai_llm_flash, 16,
+                                       1024, 512)
+        else:
+            cfg = dataclasses.replace(cfg, n_layer=3, scan_layers=True,
+                                      decode_cache_layout="packed")
+            low = _lower_decode_window(one_chip, cfg, gpt, 96, 3072, 64,
+                                       served=gpt.SERVE_CAST_LEAVES)
+        _DECODE_TEXT[preset] = low.compile().as_text()
+    return _DECODE_TEXT[preset]
+
+
+@pytest.mark.parametrize("preset,pool", [
+    ("gpt2-large", (3, 3072, 16, 1280)), ("joyai-llm-flash", (1, 1024, 16, 640))])
+def test_decode_program_copies_no_pool(mosaic, one_chip, preset, pool):
+    """The paged kernels take a stacked pool as rows, (layers, n_pages *
+    page, width), so that a run of a block's pages is ONE slice of it
+    (``_pool_operands``): in the optimized decode window of gpt2-large
+    (K and V, layers in a scan) and of JoyAI-LLM-Flash (a latent pool a
+    layer) for the described v5e that view is a ``bitcast`` of the pool,
+    and no instruction copies a pool, in either shape (a copy of one
+    layer's pool once cost gpt2-large 27.96 ms a launch)."""
+    text = _decode_text(one_chip, preset)
+    layers, pages, page, width = pool
+    shapes = {",".join(map(str, pool)): "pool",
+              f"{layers},{pages * page},{width}": "rows"}
+    seen = {}
+    for line in text.splitlines():
+        m = _HLO_RESULT.match(line)
+        if m and m.group(2) in shapes:
+            seen.setdefault(shapes[m.group(2)], set()).add(m.group(3))
+            assert m.group(3) not in ("copy", "copy-start"), line
+    assert seen["rows"] == {"bitcast"}, seen
+    assert "parameter" in seen["pool"] or "get-tuple-element" in seen["pool"]
 
 
 def test_olmo_hybrid_decode_updates_the_state_in_place(mosaic, one_chip):

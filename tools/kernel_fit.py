@@ -5,7 +5,7 @@ of the pool, what a live token and a live block cost, and a least-squares
 fit of a constant plus a cost a live block a layer.
 
     python3 tools/kernel_fit.py [--tree DIR] [--family NAME ...] [--save DIR]
-                                [--block-tokens N ...]
+                                [--block-tokens N ...] [--tables runs]
     python3 tools/kernel_fit.py --compare DIR_A DIR_B
 
 ``--tree`` imports the package of another checkout (a parent commit
@@ -23,7 +23,11 @@ tokens (the walk's block size replaced inside this process: the sweep
 that sized ``ops.paged_pallas.BLOCK_BYTES``). ``--save`` keeps each
 family's output at its densest fill but one (bf16 bits) for
 ``--compare``, which prints the largest difference between two trees'
-outputs.
+outputs. A slot's table holds random pages of the pool (an aged pool
+under a free list), or with ``--tables runs`` slot b's pages b * table ..
+in order, wrapped at the pool's end: every block one run of the pool, as
+``serve.pages.PageAllocator`` hands them out (a ring's table is that
+always).
 """
 
 from __future__ import annotations
@@ -85,7 +89,7 @@ def _block_pages(pp, row_bytes, tiles, n_table):
         return pp.block_pages(PAGE, n_table, row_bytes)
 
 
-def fit_family(name, save, block_tokens=None):
+def fit_family(name, save, block_tokens=None, runs=False):
     import jax
     from replicatinggpt_tpu.ops import paged_pallas as pp
 
@@ -95,12 +99,12 @@ def fit_family(name, save, block_tokens=None):
                           min(-(-block_tokens // page_size), n_table))
     jax.clear_caches()      # the kernels' traces hold a block
     try:
-        _fit(pp, name, save, block_tokens)
+        _fit(pp, name, save, block_tokens, runs)
     finally:
         pp.block_pages = real
 
 
-def _fit(pp, name, save, block_tokens):
+def _fit(pp, name, save, block_tokens, runs):
     import jax
     import jax.numpy as jnp
 
@@ -126,8 +130,8 @@ def _fit(pp, name, save, block_tokens):
                                                else (1, width)), bf)
                 for k in keys[3:4 if latent else 5])
     rng = np.random.default_rng(0)
-    if window:
-        tables = np.arange(B * mp, dtype=np.int32).reshape(B, mp)
+    if window or runs:
+        tables = (np.arange(B * mp, dtype=np.int32) % N).reshape(B, mp)
     else:
         tables = rng.integers(0, N, (B, mp)).astype(np.int32)
 
@@ -220,6 +224,7 @@ def main(argv=None) -> int:
     ap.add_argument("--save")
     ap.add_argument("--block-tokens", type=int, nargs="*", default=[None])
     ap.add_argument("--compare", nargs=2)
+    ap.add_argument("--tables", choices=("random", "runs"), default="random")
     args = ap.parse_args(argv)
     if args.compare:
         compare(*args.compare)
@@ -227,7 +232,8 @@ def main(argv=None) -> int:
     sys.path.insert(0, os.path.abspath(args.tree))
     for name in args.family:    # each family times its own launches
         for tokens in args.block_tokens or [None]:
-            fit_family(name, args.save, tokens)  # graftlint: disable=GL004
+            fit_family(name, args.save, tokens,  # graftlint: disable=GL004
+                       args.tables == "runs")
     return 0
 
 
