@@ -1295,44 +1295,6 @@ def bench_kernel(args) -> None:
         fwd_bwd_lap(lambda x: g2(*x), qkv2), repeats=repeats, inner=inner)
     log(f"unpacked 124M-ish shapes: {results['unpacked_BH192_T1024_D64']}")
 
-    # streamed head-group (packed long-T) vs the unpacked streamed family
-    # including its layout round trip — the end-to-end-relevant A/B for
-    # sequences past GROUP_STRIP_BYTES (longctx-bench shapes: H=4, D=64)
-    if args.kernel_longt:
-        Tl, Hl, Dl = args.kernel_longt, 4, 64
-        Cl = Hl * Dl
-        from replicatinggpt_tpu.ops.flash_pallas import \
-            packed_group_stream_supported
-        # the family override below bypasses the envelope gate, and the
-        # pallas grid would silently truncate an unaligned T
-        assert packed_group_stream_supported(Tl, Cl, Hl, 2), \
-            f"--kernel-longt must be a multiple of 128, got {Tl}"
-        qkv3 = jax.random.normal(jax.random.PRNGKey(7), (1, Tl, 3 * Cl),
-                                 jnp.bfloat16)
-        gp = jax.jit(jax.value_and_grad(lambda q: jnp.sum(
-            pallas_flash_attention_packed(q, Hl, family="group_stream")
-            .astype(jnp.float32) ** 2)))
-        jax.device_get(gp(qkv3)[0])
-        results[f"group_stream_T{Tl}_H4_D64"] = _repeat_median(
-            fwd_bwd_lap(gp, qkv3), repeats=repeats, inner=inner)
-        log(f"group_stream T={Tl}: {results[f'group_stream_T{Tl}_H4_D64']}")
-
-        def unpacked_from_qkv(qkv):
-            q, k, v = jnp.split(qkv, 3, -1)
-            B_, T_ = qkv.shape[:2]
-            q, k, v = (t.reshape(B_, T_, Hl, Dl).transpose(0, 2, 1, 3)
-                       for t in (q, k, v))
-            o = pallas_flash_attention(q, k, v)
-            o = o.transpose(0, 2, 1, 3).reshape(B_, T_, Cl)
-            return jnp.sum(o.astype(jnp.float32) ** 2)
-
-        gu = jax.jit(jax.value_and_grad(unpacked_from_qkv))
-        jax.device_get(gu(qkv3)[0])
-        results[f"unpacked_stream_T{Tl}_H4_D64"] = _repeat_median(
-            fwd_bwd_lap(gu, qkv3), repeats=repeats, inner=inner)
-        log(f"unpacked+layout T={Tl}: "
-            f"{results[f'unpacked_stream_T{Tl}_H4_D64']}")
-
     key = ("packed_char_B64_T256_H6_D64"
            if "packed_char_B64_T256_H6_D64" in results
            else "unpacked_BH192_T1024_D64")
@@ -1681,10 +1643,6 @@ def main() -> None:
                         "spread reported; >= 5 for defensible claims)")
     p.add_argument("--kernel-inner", type=int, default=20,
                    help="--mode kernel: dispatched iterations per lap")
-    p.add_argument("--kernel-longt", type=int, default=0,
-                   help="--mode kernel: also A/B the streamed head-group "
-                        "(packed) family vs the unpacked streamed family "
-                        "+ layout round trip at this T (0 = off)")
     p.add_argument("--batch-size", type=int, default=64)
     p.add_argument("--steps", type=int, default=30)
     p.add_argument("--warmup", type=int, default=5)

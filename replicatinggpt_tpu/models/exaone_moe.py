@@ -16,7 +16,8 @@ holds: what expert parallelism asks of a chip. The router scores all
 ``cfg.n_experts``, the top-k and its weights are those of the whole model,
 and the layer sums only the chosen experts it holds, plus the shared expert
 once. Nothing stands in for the absent chips: their part of ``y`` is left
-out, here and in the plain reference alike.
+out, here and in the plain reference (``chipbench/reference_exaone_moe.py``)
+alike.
 
 Parameters are a LIST of per-layer dicts (the layers differ in kind, and one
 float32 draw of the stacked experts would not fit a chip), made leaf by

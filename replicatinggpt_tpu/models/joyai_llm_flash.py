@@ -2,9 +2,10 @@
 DeepSeek-V3 lineage's latent attention and experts), serve-only.
 
 Pre-norm residual blocks, RMSNorm everywhere, no bias in any projection.
-A layer maps h (T, C) by ``reference_joyai_llm_flash.py``'s equations:
-``a = rms(h)``; the query through a rank-``q_lora_rank`` bottleneck, ``q =
-rms(a q_a) q_b`` as H heads of ``[q_nope | q_rope]``; ONE latent a token,
+A layer maps h (T, C) by the equations of
+``chipbench/reference_joyai_llm_flash.py``: ``a = rms(h)``; the query
+through a rank-``q_lora_rank`` bottleneck, ``q = rms(a q_a) q_b`` as H
+heads of ``[q_nope | q_rope]``; ONE latent a token,
 ``[c | k_rope] = a kv_a``, ``c = rms(c)``; ``q_rope`` and ``k_rope`` (every
 head shares the one) turned by INTERLEAVED RoPE (the pair (2i, 2i+1) by
 ``pos * theta ** (-2i / qk_rope_head_dim)``); ``[k_nope | v]_h = c kv_b``;

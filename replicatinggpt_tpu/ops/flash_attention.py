@@ -8,6 +8,12 @@ reference, GPT1.py:114-116) allocates.
 Current implementation: a Pallas TPU kernel (blockwise online-softmax) with
 an XLA-SDPA fallback on non-TPU backends / unsupported shapes. The kernel
 lives in :mod:`.flash_pallas`.
+
+``packed_qkv_attention`` reads the fused (B, T, 3C) QKV projection
+directly: the packed-resident family while (T, 3C) fits PACKED_QKV_BYTES,
+the head-group family while a (T, W) strip fits GROUP_STRIP_BYTES. Past
+both it returns None and the model takes the split-heads path
+(``flash_attention``: resident, then streamed past STREAM_KV_BYTES).
 """
 
 from __future__ import annotations
@@ -57,15 +63,8 @@ def packed_envelope_ok(qkv: jnp.ndarray, n_head: int) -> bool:
     from . import flash_pallas as fp
     _, T, C3 = qkv.shape
     itemsize = jnp.dtype(qkv.dtype).itemsize
-    # group_stream joins the envelope only behind its hardware-validation
-    # gate (fp.GROUP_STREAM_AUTOROUTE) — read dynamically so flipping the
-    # gate (a chip run validating the family, or a test) takes effect
-    # here too
     return (fp.packed_supported(T, C3 // 3, n_head, itemsize)
-            or fp.packed_group_supported(T, C3 // 3, n_head, itemsize)
-            or (fp.GROUP_STREAM_AUTOROUTE
-                and fp.packed_group_stream_supported(T, C3 // 3, n_head,
-                                                     itemsize)))
+            or fp.packed_group_supported(T, C3 // 3, n_head, itemsize))
 
 
 def packed_qkv_attention(qkv: jnp.ndarray, n_head: int, *,

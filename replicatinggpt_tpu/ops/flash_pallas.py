@@ -33,6 +33,17 @@ dropout mask keys off absolute positions, so their outputs are
 bit-identical: measured exactly equal on v5e hardware, and
 test_stream_dropout_matches_resident asserts exact equality in
 interpret mode.
+
+Why each family exists (the GPT presets in config.py, run through the
+routing predicates at their widths and block_size):
+  packed resident (_flash_packed)       char-gpt, gpt2-shakespeare,
+                                        o200k-shakespeare (T=256, C=384)
+  head-group (_flash_packed_group)      gpt2-small, gpt2-medium, gpt2-large
+  split-heads resident (_flash)         gpt2-xl (25 heads pair into no
+                                        128-lane group)
+  split-heads streamed (_flash_stream)  K/V past STREAM_KV_BYTES a head
+                                        (--block-size past 16,384 at D=64)
+  ring chunk (pallas_flash_chunk)       --attention ring on a sequence axis
 """
 
 from __future__ import annotations
@@ -2271,569 +2282,13 @@ _flash_packed_group.defvjp(_flash_packed_group_fwd_rule,
                            _flash_packed_group_bwd_rule)
 
 
-# ---------------------------------------------------------------------------
-# streamed head-group family: the packed layout past GROUP_STRIP_BYTES
-#
-# The group family above still holds one (T, W) K/V strip resident per
-# (b, g) program, capping it at T <= 2048 (W=128 bf16) — past that, the
-# packed path fell back to the unpacked streamed family and long-context
-# runs paid the (B,T,H,D)<->(B,H,T,D) layout round trips again. This
-# family combines the two existing techniques: the kv axis joins the
-# pallas grid with the online-softmax state carried in VMEM scratch
-# (exactly the streamed family, _fwd_kernel_stream) while the q/k/v
-# operands stay W-wide last-dim BlockSpec strips of the untouched
-# (B, T, 3C) array (exactly the group family). VMEM is O(block*W)
-# regardless of T, so packed long-T is bounded by HBM only.
-#
-# Per-sub-head m/l state rides the (block_q, W) scratch broadcast across
-# each sub-head's D-column slice (the D-narrow analogue of the unpacked
-# stream family's LANES-broadcast stats); dq accumulates across kv grid
-# steps in a (block_q, W) scratch, dk/dv across q grid steps in
-# (block_k, W) scratches — the dq/dkv kernel split of the streamed
-# family, since a kv-major fused dq scratch would be (T, W) f32 and
-# grow with T again. Tile math and the bh = b*H + g*hpg + s dropout
-# counter are shared with every other family: outputs are bit-identical
-# (asserted in tests/test_flash_attention.py group_stream section).
-# Causal with block_q == block_k (the default) takes the
-# scalar-prefetched triangular tile map (further below) — masked tiles'
-# fetches and grid steps disappear, as in the unpacked tri kernels; the
-# rectangular grid remains for non-causal / unequal-block calls, where
-# pl.when skips masked tiles' matmuls but not their fetches.
-# ---------------------------------------------------------------------------
-
-
-# Auto-route gate for the streamed head-group family. False keeps the
-# family OPT-IN (family="group_stream") and off the production routing —
-# both the family=None dispatch below and ops.flash_attention's
-# packed_envelope_ok read it. Flip to True only once a chip run shows
-# the family bit-equal to the unpacked streamed family at T=4096 and
-# T=32768 (it compiles for a described v5e; it has never RUN on one):
-# this codebase has already shipped a (T,)-stats layout that interpret
-# mode accepted and Mosaic rejected, so interpret-mode proof alone must
-# not put a kernel family on the default path.
-GROUP_STREAM_AUTOROUTE = False
-
-
-def packed_group_stream_supported(T: int, C: int, n_head: int,
-                                  itemsize: int) -> bool:
-    """Envelope for the streamed head-group family: lane-aligned groups
-    and block-divisible T — no residency bound (state is O(block*W))."""
-    del itemsize
-    return (_group_geometry(C, n_head) is not None
-            and T >= 128 and T % 128 == 0)
-
-
-def _fwd_kernel_group_stream(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-                             acc_ref, m_ref, l_ref, *, scale, causal,
-                             n_head, head_dim, heads_per_group, seq_len,
-                             block_q, block_k, dropout_rate):
-    b = pl.program_id(0)
-    g = pl.program_id(1)
-    j = pl.program_id(2)
-    kb = pl.program_id(3)
-    D, hpg = head_dim, heads_per_group
-    n_kv = seq_len // block_k
-    q_first = j * block_q
-    k_first = kb * block_k
-    last_kb = (((j + 1) * block_q - 1) // block_k) if causal else n_kv - 1
-
-    @pl.when(kb == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    needed = (k_first <= q_first + block_q - 1) if causal else kb >= 0
-
-    @pl.when(needed)
-    def _update():
-        for s in range(hpg):
-            cols = slice(s * D, (s + 1) * D)
-            acc, m_new, l_new = _fwd_tile(
-                q_ref[:, cols], k_ref[:, cols], v_ref[:, cols],
-                acc_ref[:, cols], m_ref[:, cols][:, :1],
-                l_ref[:, cols][:, :1], scale=scale, causal=causal,
-                q_first=q_first, k_first=k_first, block_q=block_q,
-                block_k=block_k, seed=seed_ref[0],
-                bh=b * n_head + g * hpg + s, dropout_rate=dropout_rate)
-            acc_ref[:, cols] = acc
-            m_ref[:, cols] = jnp.broadcast_to(m_new, (block_q, D))
-            l_ref[:, cols] = jnp.broadcast_to(l_new, (block_q, D))
-
-    @pl.when(kb == last_kb)
-    def _finalize():
-        lses = []
-        for s in range(hpg):
-            cols = slice(s * D, (s + 1) * D)
-            m = m_ref[:, cols][:, :1]
-            l = jnp.maximum(l_ref[:, cols][:, :1], 1e-30)
-            o_ref[:, cols] = (acc_ref[:, cols] / l).astype(o_ref.dtype)
-            lses.append(m + jnp.log(l))
-        lse_ref[...] = jnp.concatenate(lses, axis=1)
-
-
-def _group_fwd_stream(qkv, seed, scale, causal, n_head, block_q, block_k,
-                      dropout_rate):
-    B, T, C3 = qkv.shape
-    C = C3 // 3
-    D, hpg, W, G = _group_geometry(C, n_head)
-    kernel = functools.partial(
-        _fwd_kernel_group_stream, scale=scale, causal=causal,
-        n_head=n_head, head_dim=D, heads_per_group=hpg, seq_len=T,
-        block_q=block_q, block_k=block_k, dropout_rate=dropout_rate)
-    kw = {"compiler_params": _compiler_params(3, 4)}
-    o, lse = pl.pallas_call(
-        kernel,
-        grid=(B, G, T // block_q, T // block_k),
-        in_specs=[
-            _smem_spec(),
-            _vmem_spec((None, block_q, W), lambda b, g, j, kb: (b, j, g)),
-            _vmem_spec((None, block_k, W),
-                       lambda b, g, j, kb: (b, kb, G + g)),
-            _vmem_spec((None, block_k, W),
-                       lambda b, g, j, kb: (b, kb, 2 * G + g)),
-        ],
-        out_specs=[
-            _vmem_spec((None, block_q, W), lambda b, g, j, kb: (b, j, g)),
-            _vmem_spec((None, None, block_q, hpg),
-                       lambda b, g, j, kb: (b, g, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, T, C), qkv.dtype),
-            jax.ShapeDtypeStruct((B, G, T, hpg), jnp.float32),
-        ],
-        scratch_shapes=[_scratch((block_q, W)), _scratch((block_q, W)),
-                        _scratch((block_q, W))],
-        name="flash_group_stream_fwd",
-        interpret=_interpret_mode(),
-        **kw,
-    )(seed, qkv, qkv, qkv)
-    lse_c = lse.transpose(0, 1, 3, 2).reshape(B, n_head, T)
-    return o, lse_c
-
-
-def _bwd_dq_kernel_group_stream(seed_ref, q_ref, k_ref, v_ref, do_ref,
-                                lse_ref, delta_ref, dq_ref, dq_acc_ref, *,
-                                scale, causal, n_head, head_dim,
-                                heads_per_group, seq_len, block_q, block_k,
-                                dropout_rate):
-    b = pl.program_id(0)
-    g = pl.program_id(1)
-    j = pl.program_id(2)
-    kb = pl.program_id(3)
-    D, hpg = head_dim, heads_per_group
-    n_kv = seq_len // block_k
-    q_first = j * block_q
-    k_first = kb * block_k
-    last_kb = (((j + 1) * block_q - 1) // block_k) if causal else n_kv - 1
-
-    @pl.when(kb == 0)
-    def _init():
-        dq_acc_ref[...] = jnp.zeros_like(dq_acc_ref)
-
-    needed = (k_first <= q_first + block_q - 1) if causal else kb >= 0
-
-    @pl.when(needed)
-    def _update():
-        for s in range(hpg):
-            cols = slice(s * D, (s + 1) * D)
-            dq_acc_ref[:, cols] = dq_acc_ref[:, cols] + _dq_tile(
-                q_ref[:, cols], k_ref[:, cols], v_ref[:, cols],
-                do_ref[:, cols], lse_ref[:, s:s + 1],
-                delta_ref[:, s:s + 1], scale=scale, causal=causal,
-                q_first=q_first, k_first=k_first, block_q=block_q,
-                block_k=block_k, seed=seed_ref[0],
-                bh=b * n_head + g * hpg + s, dropout_rate=dropout_rate)
-
-    @pl.when(kb == last_kb)
-    def _finalize():
-        dq_ref[...] = dq_acc_ref[...].astype(dq_ref.dtype)
-
-
-def _bwd_dkv_kernel_group_stream(seed_ref, q_ref, k_ref, v_ref, do_ref,
-                                 lse_ref, delta_ref, dk_ref, dv_ref,
-                                 dk_acc_ref, dv_acc_ref, *, scale, causal,
-                                 n_head, head_dim, heads_per_group, seq_len,
-                                 block_q, block_k, dropout_rate):
-    b = pl.program_id(0)
-    g = pl.program_id(1)
-    kb = pl.program_id(2)
-    jb = pl.program_id(3)
-    D, hpg = head_dim, heads_per_group
-    n_q = seq_len // block_q
-    k_first = kb * block_k
-    q_first = jb * block_q
-
-    @pl.when(jb == 0)
-    def _init():
-        dk_acc_ref[...] = jnp.zeros_like(dk_acc_ref)
-        dv_acc_ref[...] = jnp.zeros_like(dv_acc_ref)
-
-    needed = (q_first + block_q - 1 >= k_first) if causal else jb >= 0
-
-    @pl.when(needed)
-    def _update():
-        for s in range(hpg):
-            cols = slice(s * D, (s + 1) * D)
-            dk_c, dv_c, _ = _dkv_tile(
-                q_ref[:, cols], k_ref[:, cols], v_ref[:, cols],
-                do_ref[:, cols], lse_ref[:, s:s + 1],
-                delta_ref[:, s:s + 1], scale=scale, causal=causal,
-                q_first=q_first, k_first=k_first, block_q=block_q,
-                block_k=block_k, seed=seed_ref[0],
-                bh=b * n_head + g * hpg + s, dropout_rate=dropout_rate)
-            dk_acc_ref[:, cols] = dk_acc_ref[:, cols] + dk_c
-            dv_acc_ref[:, cols] = dv_acc_ref[:, cols] + dv_c
-
-    @pl.when(jb == n_q - 1)
-    def _finalize():
-        dk_ref[...] = dk_acc_ref[...].astype(dk_ref.dtype)
-        dv_ref[...] = dv_acc_ref[...].astype(dv_ref.dtype)
-
-
-def _group_bwd_stream(qkv, do, lse_c, delta_c, seed, scale, causal, n_head,
-                      block_q, block_k, dropout_rate):
-    B, T, C3 = qkv.shape
-    C = C3 // 3
-    D, hpg, W, G = _group_geometry(C, n_head)
-    lse4 = _group_stats(lse_c, hpg)
-    delta4 = _group_stats(delta_c, hpg)
-    common = dict(scale=scale, causal=causal, n_head=n_head, head_dim=D,
-                  heads_per_group=hpg, seq_len=T, block_q=block_q,
-                  block_k=block_k, dropout_rate=dropout_rate)
-    kw = {"compiler_params": _compiler_params(3, 4)}
-    qs = lambda blk: _vmem_spec((None, block_q, W),
-                                lambda b, g, j, kb: (b, j, blk(g)))
-    ks = lambda blk: _vmem_spec((None, block_k, W),
-                                lambda b, g, j, kb: (b, kb, blk(g)))
-    stat_q = _vmem_spec((None, None, block_q, hpg),
-                        lambda b, g, j, kb: (b, g, j, 0))
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel_group_stream, **common),
-        grid=(B, G, T // block_q, T // block_k),
-        in_specs=[_smem_spec(), qs(lambda g: g), ks(lambda g: G + g),
-                  ks(lambda g: 2 * G + g), qs(lambda g: g), stat_q, stat_q],
-        out_specs=qs(lambda g: g),
-        out_shape=jax.ShapeDtypeStruct((B, T, C), qkv.dtype),
-        scratch_shapes=[_scratch((block_q, W))],
-        name="flash_group_stream_bwd_dq",
-        interpret=_interpret_mode(),
-        **kw,
-    )(seed, qkv, qkv, qkv, do, lse4, delta4)
-
-    # kv-major grid: q/do/stat maps swap roles (kb outer, jb carried)
-    qs2 = lambda blk: _vmem_spec((None, block_q, W),
-                                 lambda b, g, kb, jb: (b, jb, blk(g)))
-    ks2 = lambda blk: _vmem_spec((None, block_k, W),
-                                 lambda b, g, kb, jb: (b, kb, blk(g)))
-    stat_q2 = _vmem_spec((None, None, block_q, hpg),
-                         lambda b, g, kb, jb: (b, g, jb, 0))
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel_group_stream, **common),
-        grid=(B, G, T // block_k, T // block_q),
-        in_specs=[_smem_spec(), qs2(lambda g: g), ks2(lambda g: G + g),
-                  ks2(lambda g: 2 * G + g), qs2(lambda g: g), stat_q2,
-                  stat_q2],
-        out_specs=[ks2(lambda g: g), ks2(lambda g: g)],
-        out_shape=[jax.ShapeDtypeStruct((B, T, C), qkv.dtype)] * 2,
-        scratch_shapes=[_scratch((block_k, W)), _scratch((block_k, W))],
-        name="flash_group_stream_bwd_dkv",
-        interpret=_interpret_mode(),
-        **kw,
-    )(seed, qkv, qkv, qkv, do, lse4, delta4)
-    return jnp.concatenate([dq, dk, dv], axis=-1)
-
-
-# --- triangular causal grid for the streamed group family ------------------
-#
-# Same optimization as the unpacked tri kernels above: the rectangular
-# (B, G, n_q, n_kv) grid fetches K/V strips for every tile including the
-# ~half causal masking discards. For causal with block_q == block_k the
-# tile axis flattens to the lower triangle via the scalar-prefetched
-# (2, M) tile map — fetches and grid steps for masked tiles disappear.
-
-
-def _fwd_kernel_group_tri(tmap_ref, seed_ref, q_ref, k_ref, v_ref, o_ref,
-                          lse_ref, acc_ref, m_ref, l_ref, *, scale, n_head,
-                          head_dim, heads_per_group, block, dropout_rate):
-    b = pl.program_id(0)
-    g = pl.program_id(1)
-    t = pl.program_id(2)
-    j = tmap_ref[0, t]
-    kb = tmap_ref[1, t]
-    D, hpg = head_dim, heads_per_group
-
-    @pl.when(kb == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    for s in range(hpg):
-        cols = slice(s * D, (s + 1) * D)
-        acc, m_new, l_new = _fwd_tile(
-            q_ref[:, cols], k_ref[:, cols], v_ref[:, cols],
-            acc_ref[:, cols], m_ref[:, cols][:, :1], l_ref[:, cols][:, :1],
-            scale=scale, causal=True, q_first=j * block, k_first=kb * block,
-            block_q=block, block_k=block, seed=seed_ref[0],
-            bh=b * n_head + g * hpg + s, dropout_rate=dropout_rate)
-        acc_ref[:, cols] = acc
-        m_ref[:, cols] = jnp.broadcast_to(m_new, (block, D))
-        l_ref[:, cols] = jnp.broadcast_to(l_new, (block, D))
-
-    @pl.when(kb == j)
-    def _finalize():
-        lses = []
-        for s in range(hpg):
-            cols = slice(s * D, (s + 1) * D)
-            m = m_ref[:, cols][:, :1]
-            l = jnp.maximum(l_ref[:, cols][:, :1], 1e-30)
-            o_ref[:, cols] = (acc_ref[:, cols] / l).astype(o_ref.dtype)
-            lses.append(m + jnp.log(l))
-        lse_ref[...] = jnp.concatenate(lses, axis=1)
-
-
-def _group_fwd_tri(qkv, seed, scale, n_head, block, dropout_rate):
-    B, T, C3 = qkv.shape
-    C = C3 // 3
-    D, hpg, W, G = _group_geometry(C, n_head)
-    n = T // block
-    tmap = jnp.asarray(_tri_tile_map(n, kv_major=False))
-    kernel = functools.partial(
-        _fwd_kernel_group_tri, scale=scale, n_head=n_head, head_dim=D,
-        heads_per_group=hpg, block=block, dropout_rate=dropout_rate)
-    kw = {"compiler_params": _compiler_params(2, 3)}
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, G, tmap.shape[1]),
-        in_specs=[
-            _vmem_spec((None, block, W),
-                       lambda b, g, t, tm, sd: (b, tm[0, t], g)),
-            _vmem_spec((None, block, W),
-                       lambda b, g, t, tm, sd: (b, tm[1, t], G + g)),
-            _vmem_spec((None, block, W),
-                       lambda b, g, t, tm, sd: (b, tm[1, t], 2 * G + g)),
-        ],
-        out_specs=[
-            _vmem_spec((None, block, W),
-                       lambda b, g, t, tm, sd: (b, tm[0, t], g)),
-            _vmem_spec((None, None, block, hpg),
-                       lambda b, g, t, tm, sd: (b, g, tm[0, t], 0)),
-        ],
-        scratch_shapes=[_scratch((block, W)), _scratch((block, W)),
-                        _scratch((block, W))],
-    )
-    o, lse = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((B, T, C), qkv.dtype),
-            jax.ShapeDtypeStruct((B, G, T, hpg), jnp.float32),
-        ],
-        name="flash_group_tri_fwd",
-        interpret=_interpret_mode(),
-        **kw,
-    )(tmap, seed, qkv, qkv, qkv)
-    lse_c = lse.transpose(0, 1, 3, 2).reshape(B, n_head, T)
-    return o, lse_c
-
-
-def _bwd_dq_kernel_group_tri(tmap_ref, seed_ref, q_ref, k_ref, v_ref,
-                             do_ref, lse_ref, delta_ref, dq_ref, dq_acc_ref,
-                             *, scale, n_head, head_dim, heads_per_group,
-                             block, dropout_rate):
-    b = pl.program_id(0)
-    g = pl.program_id(1)
-    t = pl.program_id(2)
-    j = tmap_ref[0, t]
-    kb = tmap_ref[1, t]
-    D, hpg = head_dim, heads_per_group
-
-    @pl.when(kb == 0)
-    def _init():
-        dq_acc_ref[...] = jnp.zeros_like(dq_acc_ref)
-
-    for s in range(hpg):
-        cols = slice(s * D, (s + 1) * D)
-        dq_acc_ref[:, cols] = dq_acc_ref[:, cols] + _dq_tile(
-            q_ref[:, cols], k_ref[:, cols], v_ref[:, cols], do_ref[:, cols],
-            lse_ref[:, s:s + 1], delta_ref[:, s:s + 1], scale=scale,
-            causal=True, q_first=j * block, k_first=kb * block,
-            block_q=block, block_k=block, seed=seed_ref[0],
-            bh=b * n_head + g * hpg + s, dropout_rate=dropout_rate)
-
-    @pl.when(kb == j)
-    def _finalize():
-        dq_ref[...] = dq_acc_ref[...].astype(dq_ref.dtype)
-
-
-def _bwd_dkv_kernel_group_tri(tmap_ref, seed_ref, q_ref, k_ref, v_ref,
-                              do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-                              dk_acc_ref, dv_acc_ref, *, scale, n_head,
-                              head_dim, heads_per_group, block, n_q,
-                              dropout_rate):
-    b = pl.program_id(0)
-    g = pl.program_id(1)
-    t = pl.program_id(2)
-    kb = tmap_ref[0, t]
-    jb = tmap_ref[1, t]
-    D, hpg = head_dim, heads_per_group
-
-    @pl.when(jb == kb)
-    def _init():
-        dk_acc_ref[...] = jnp.zeros_like(dk_acc_ref)
-        dv_acc_ref[...] = jnp.zeros_like(dv_acc_ref)
-
-    for s in range(hpg):
-        cols = slice(s * D, (s + 1) * D)
-        dk_c, dv_c, _ = _dkv_tile(
-            q_ref[:, cols], k_ref[:, cols], v_ref[:, cols], do_ref[:, cols],
-            lse_ref[:, s:s + 1], delta_ref[:, s:s + 1], scale=scale,
-            causal=True, q_first=jb * block, k_first=kb * block,
-            block_q=block, block_k=block, seed=seed_ref[0],
-            bh=b * n_head + g * hpg + s, dropout_rate=dropout_rate)
-        dk_acc_ref[:, cols] = dk_acc_ref[:, cols] + dk_c
-        dv_acc_ref[:, cols] = dv_acc_ref[:, cols] + dv_c
-
-    @pl.when(jb == n_q - 1)
-    def _finalize():
-        dk_ref[...] = dk_acc_ref[...].astype(dk_ref.dtype)
-        dv_ref[...] = dv_acc_ref[...].astype(dv_ref.dtype)
-
-
-def _group_bwd_tri(qkv, do, lse_c, delta_c, seed, scale, n_head, block,
-                   dropout_rate):
-    B, T, C3 = qkv.shape
-    C = C3 // 3
-    D, hpg, W, G = _group_geometry(C, n_head)
-    n = T // block
-    lse4 = _group_stats(lse_c, hpg)
-    delta4 = _group_stats(delta_c, hpg)
-    common = dict(scale=scale, n_head=n_head, head_dim=D,
-                  heads_per_group=hpg, block=block,
-                  dropout_rate=dropout_rate)
-    kw = {"compiler_params": _compiler_params(2, 3)}
-
-    tmap_q = jnp.asarray(_tri_tile_map(n, kv_major=False))
-    # tm[0] = q-block (carried), tm[1] = kv-block
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel_group_tri, **common),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(B, G, tmap_q.shape[1]),
-            in_specs=[
-                _vmem_spec((None, block, W),
-                           lambda b, g, t, tm, sd: (b, tm[0, t], g)),
-                _vmem_spec((None, block, W),
-                           lambda b, g, t, tm, sd: (b, tm[1, t], G + g)),
-                _vmem_spec((None, block, W),
-                           lambda b, g, t, tm, sd: (b, tm[1, t], 2 * G + g)),
-                _vmem_spec((None, block, W),
-                           lambda b, g, t, tm, sd: (b, tm[0, t], g)),
-                _vmem_spec((None, None, block, hpg),
-                           lambda b, g, t, tm, sd: (b, g, tm[0, t], 0)),
-                _vmem_spec((None, None, block, hpg),
-                           lambda b, g, t, tm, sd: (b, g, tm[0, t], 0)),
-            ],
-            out_specs=_vmem_spec((None, block, W),
-                                 lambda b, g, t, tm, sd: (b, tm[0, t], g)),
-            scratch_shapes=[_scratch((block, W))],
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, T, C), qkv.dtype),
-        name="flash_group_tri_bwd_dq",
-        interpret=_interpret_mode(),
-        **kw,
-    )(tmap_q, seed, qkv, qkv, qkv, do, lse4, delta4)
-
-    tmap_kv = jnp.asarray(_tri_tile_map(n, kv_major=True))
-    # tm[0] = kv-block (carried), tm[1] = q-block
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel_group_tri, n_q=n, **common),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(B, G, tmap_kv.shape[1]),
-            in_specs=[
-                _vmem_spec((None, block, W),
-                           lambda b, g, t, tm, sd: (b, tm[1, t], g)),
-                _vmem_spec((None, block, W),
-                           lambda b, g, t, tm, sd: (b, tm[0, t], G + g)),
-                _vmem_spec((None, block, W),
-                           lambda b, g, t, tm, sd: (b, tm[0, t], 2 * G + g)),
-                _vmem_spec((None, block, W),
-                           lambda b, g, t, tm, sd: (b, tm[1, t], g)),
-                _vmem_spec((None, None, block, hpg),
-                           lambda b, g, t, tm, sd: (b, g, tm[1, t], 0)),
-                _vmem_spec((None, None, block, hpg),
-                           lambda b, g, t, tm, sd: (b, g, tm[1, t], 0)),
-            ],
-            out_specs=[
-                _vmem_spec((None, block, W),
-                           lambda b, g, t, tm, sd: (b, tm[0, t], g)),
-                _vmem_spec((None, block, W),
-                           lambda b, g, t, tm, sd: (b, tm[0, t], g)),
-            ],
-            scratch_shapes=[_scratch((block, W)), _scratch((block, W))],
-        ),
-        out_shape=[jax.ShapeDtypeStruct((B, T, C), qkv.dtype)] * 2,
-        name="flash_group_tri_bwd_dkv",
-        interpret=_interpret_mode(),
-        **kw,
-    )(tmap_kv, seed, qkv, qkv, qkv, do, lse4, delta4)
-    return jnp.concatenate([dq, dk, dv], axis=-1)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5, 6, 7))
-def _flash_packed_group_stream(qkv, seed, scale, causal, n_head, block_q,
-                               block_k, dropout_rate):
-    if _tri_eligible(causal, block_q, block_k):
-        o, _ = _group_fwd_tri(qkv, seed, scale, n_head, block_q,
-                              dropout_rate)
-    else:
-        o, _ = _group_fwd_stream(qkv, seed, scale, causal, n_head, block_q,
-                                 block_k, dropout_rate)
-    return o
-
-
-def _flash_packed_group_stream_fwd_rule(qkv, seed, scale, causal, n_head,
-                                        block_q, block_k, dropout_rate):
-    if _tri_eligible(causal, block_q, block_k):
-        o, lse_c = _group_fwd_tri(qkv, seed, scale, n_head, block_q,
-                                  dropout_rate)
-    else:
-        o, lse_c = _group_fwd_stream(qkv, seed, scale, causal, n_head,
-                                     block_q, block_k, dropout_rate)
-    return o, (qkv, seed, o, lse_c)
-
-
-def _flash_packed_group_stream_bwd_rule(scale, causal, n_head, block_q,
-                                        block_k, dropout_rate, residuals, g):
-    qkv, seed, o, lse_c = residuals
-    B, T, C = o.shape
-    D = C // n_head
-    delta_c = (g.astype(jnp.float32) * o.astype(jnp.float32)).reshape(
-        B, T, n_head, D).sum(-1).transpose(0, 2, 1)
-    if _tri_eligible(causal, block_q, block_k):
-        dqkv = _group_bwd_tri(qkv, g.astype(qkv.dtype), lse_c, delta_c,
-                              seed, scale, n_head, block_q, dropout_rate)
-    else:
-        dqkv = _group_bwd_stream(qkv, g.astype(qkv.dtype), lse_c, delta_c,
-                                 seed, scale, causal, n_head, block_q,
-                                 block_k, dropout_rate)
-    return dqkv, None
-
-
-_flash_packed_group_stream.defvjp(_flash_packed_group_stream_fwd_rule,
-                                  _flash_packed_group_stream_bwd_rule)
-
-
 def pallas_flash_attention_packed(qkv: jnp.ndarray, n_head: int, *,
                                   scale: Optional[float] = None,
                                   causal: bool = True,
                                   block_q: Optional[int] = None,
                                   block_k: Optional[int] = None,
                                   dropout_rate: float = 0.0,
-                                  dropout_rng: Optional[jax.Array] = None,
-                                  family: Optional[str] = None
+                                  dropout_rng: Optional[jax.Array] = None
                                   ) -> jnp.ndarray:
     """Packed-heads flash attention. qkv: (B, T, 3C) — the fused QKV
     projection output, untouched. Returns the merged (B, T, C) attention
@@ -2842,13 +2297,10 @@ def pallas_flash_attention_packed(qkv: jnp.ndarray, n_head: int, *,
     ``pallas_flash_attention`` on the same logical q/k/v.
 
     Routes by residency: the fully-resident family while (T, 3C) fits
-    PACKED_QKV_BYTES (short-T/many-head, e.g. char-GPT), the head-group
-    family while (T, W) strips fit GROUP_STRIP_BYTES (GPT-2-scale
-    T=1024), and the streamed head-group family past that (long-T:
-    state in VMEM scratch, T bounded by HBM only). ``family``
-    ('resident' | 'group' | 'group_stream') overrides the routing — for
-    parity tests and for benchmarking the families against each other
-    on shapes both support."""
+    PACKED_QKV_BYTES (short-T/many-head, e.g. char-GPT), then the
+    head-group family while (T, W) strips fit GROUP_STRIP_BYTES
+    (GPT-2-scale T=1024). Past both, callers take the split-heads
+    families (``pallas_flash_attention``)."""
     B, T, C3 = qkv.shape
     C = C3 // 3
     D = C // n_head
@@ -2856,26 +2308,13 @@ def pallas_flash_attention_packed(qkv: jnp.ndarray, n_head: int, *,
     block_q = _block_for(T, block_q)
     block_k = _block_for(T, block_k)
     itemsize = jnp.dtype(qkv.dtype).itemsize
-    if family is None:
-        family = ("resident" if packed_supported(T, C, n_head, itemsize)
-                  else "group" if packed_group_supported(T, C, n_head,
-                                                        itemsize)
-                  else "group_stream" if (
-                      GROUP_STREAM_AUTOROUTE
-                      and packed_group_stream_supported(T, C, n_head,
-                                                        itemsize))
-                  else None)
-    if family == "resident":
-        return _flash_packed(qkv, seed, scale, bool(causal), n_head,
-                             block_q, block_k, rate)
-    if family in ("group", "group_stream"):
-        if _group_geometry(C, n_head) is None:
-            raise ValueError(f"no lane-aligned head groups for C={C}, "
-                             f"n_head={n_head}")
-        fn = (_flash_packed_group if family == "group"
-              else _flash_packed_group_stream)
-        return fn(qkv, seed, scale, bool(causal), n_head, block_q, block_k,
-                  rate)
-    raise ValueError(
-        f"packed families do not support T={T}, C={C}, n_head={n_head}; "
-        "gate callers on ops.flash_attention.packed_envelope_ok")
+    if packed_supported(T, C, n_head, itemsize):
+        fn = _flash_packed
+    elif packed_group_supported(T, C, n_head, itemsize):
+        fn = _flash_packed_group
+    else:
+        raise ValueError(
+            f"packed families do not support T={T}, C={C}, "
+            f"n_head={n_head}; gate callers on "
+            "ops.flash_attention.packed_envelope_ok")
+    return fn(qkv, seed, scale, bool(causal), n_head, block_q, block_k, rate)

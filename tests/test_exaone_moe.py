@@ -14,7 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from replicatinggpt_tpu import reference_exaone_moe as ref
+from chipbench import reference_exaone_moe as ref
 from replicatinggpt_tpu.config import get_config
 from replicatinggpt_tpu.models import exaone_moe as xm, layers
 from replicatinggpt_tpu.models.families import family, serve_refusals

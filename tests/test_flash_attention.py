@@ -559,21 +559,49 @@ def test_model_block_routes_packed(monkeypatch):
 # --- packed head-group family (GPT-2-scale shapes past the resident bound) --
 
 
-def test_group_fwd_bit_identical_to_unpacked():
+@pytest.fixture
+def group_family(monkeypatch):
+    """Close the resident family's envelope, so the packed entry's own
+    router takes the head-group family at the tests' small shapes."""
+    import replicatinggpt_tpu.ops.flash_pallas as fp
+    monkeypatch.setattr(fp, "PACKED_QKV_BYTES", 0)
+
+
+# (B, T, block_q, block_k, causal): one tile at the auto block, then the
+# several tiles, unequal tiles and non-causal grid gpt2-medium's T=1024
+# in 512-row tiles runs
+GROUP_TILINGS = {
+    "one-tile": (2, 128, None, None, True),
+    "multi-tile": (1, 256, 128, 128, True),
+    "unequal": (1, 256, 128, 256, True),
+    "noncausal-multi-tile": (1, 256, 128, 128, False),
+}
+
+
+def _group_tiling(*ids):
+    return pytest.mark.parametrize(
+        "B,T,block_q,block_k,causal", [GROUP_TILINGS[i] for i in ids],
+        ids=ids)
+
+
+@_group_tiling("one-tile", "multi-tile", "unequal", "noncausal-multi-tile")
+def test_group_fwd_bit_identical_to_unpacked(group_family, B, T, block_q,
+                                             block_k, causal):
     """hpg=4 (D=32): four sub-heads lane-sliced per 128-wide strip."""
     from replicatinggpt_tpu.ops.flash_pallas import \
         pallas_flash_attention_packed
     H, D = 4, 32
-    qkv, C = _packed_inputs(B=2, T=128, H=H, D=D, seed=21)
-    B, T = qkv.shape[:2]
+    qkv, C = _packed_inputs(B=B, T=T, H=H, D=D, seed=21)
+    blocks = dict(block_q=block_q, block_k=block_k, causal=causal)
     q, k, v = jnp.split(qkv, 3, -1)
-    ref = pallas_flash_attention(_heads(q, H), _heads(k, H), _heads(v, H))
+    ref = pallas_flash_attention(_heads(q, H), _heads(k, H), _heads(v, H),
+                                 **blocks)
     ref = ref.transpose(0, 2, 1, 3).reshape(B, T, C)
-    got = pallas_flash_attention_packed(qkv, H, family="group")
+    got = pallas_flash_attention_packed(qkv, H, **blocks)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
 
 
-def test_group_fwd_single_head_groups():
+def test_group_fwd_single_head_groups(group_family):
     """hpg=1 (D=128): strip == head, no in-kernel sub-head loop."""
     from replicatinggpt_tpu.ops.flash_pallas import \
         pallas_flash_attention_packed
@@ -583,53 +611,59 @@ def test_group_fwd_single_head_groups():
     q, k, v = jnp.split(qkv, 3, -1)
     ref = pallas_flash_attention(_heads(q, H), _heads(k, H), _heads(v, H))
     ref = ref.transpose(0, 2, 1, 3).reshape(B, T, C)
-    got = pallas_flash_attention_packed(qkv, H, family="group")
+    got = pallas_flash_attention_packed(qkv, H)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
 
 
-def test_group_matches_resident_packed():
+def test_group_matches_resident_packed(monkeypatch):
     """Both packed families on the same in-envelope shape must agree
     exactly (same tile math, same bh counter stream)."""
-    from replicatinggpt_tpu.ops.flash_pallas import \
-        pallas_flash_attention_packed
+    import replicatinggpt_tpu.ops.flash_pallas as fp
     H = 6
     qkv, _ = _packed_inputs(B=2, T=256, H=H, D=64, seed=23)
-    res = pallas_flash_attention_packed(qkv, H, family="resident")
-    grp = pallas_flash_attention_packed(qkv, H, family="group")
+    res = fp.pallas_flash_attention_packed(qkv, H)
+    monkeypatch.setattr(fp, "PACKED_QKV_BYTES", 0)
+    grp = fp.pallas_flash_attention_packed(qkv, H)
     np.testing.assert_array_equal(np.asarray(grp), np.asarray(res))
 
 
-def test_group_dropout_bit_identical_to_unpacked():
+@_group_tiling("one-tile", "multi-tile", "unequal")
+def test_group_dropout_bit_identical_to_unpacked(group_family, B, T,
+                                                 block_q, block_k, causal):
     """Sub-head s of group g keys dropout off bh = b*H + g*hpg + s — the
     global head counter — so masks must equal the unpacked family's."""
     from replicatinggpt_tpu.ops.flash_pallas import \
         pallas_flash_attention_packed
     H, D = 4, 32
-    qkv, C = _packed_inputs(B=2, T=128, H=H, D=D, seed=24)
-    B, T = qkv.shape[:2]
+    qkv, C = _packed_inputs(B=B, T=T, H=H, D=D, seed=24)
     rng = jax.random.PRNGKey(9)
-    got = pallas_flash_attention_packed(qkv, H, family="group",
-                                        dropout_rate=0.2, dropout_rng=rng)
+    kw = dict(block_q=block_q, block_k=block_k, causal=causal,
+              dropout_rate=0.2, dropout_rng=rng)
+    got = pallas_flash_attention_packed(qkv, H, **kw)
     q, k, v = (_heads(t, H) for t in jnp.split(qkv, 3, -1))
-    ref = pallas_flash_attention(q, k, v, dropout_rate=0.2, dropout_rng=rng)
+    ref = pallas_flash_attention(q, k, v, **kw)
     ref = ref.transpose(0, 2, 1, 3).reshape(B, T, C)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
 
 
-def test_group_grads_match_unpacked():
+@pytest.mark.parametrize("block_q,block_k", [(None, None), (128, 128),
+                                             (128, 256)],
+                         ids=["one-tile", "multi-tile", "unequal"])
+def test_group_grads_match_unpacked(group_family, block_q, block_k):
     from replicatinggpt_tpu.ops.flash_pallas import \
         pallas_flash_attention_packed
     H, D = 4, 32
     qkv, C = _packed_inputs(B=1, T=256, H=H, D=D, seed=25)
     B, T = qkv.shape[:2]
+    blocks = dict(block_q=block_q, block_k=block_k)
 
     def loss_group(qkv):
-        o = pallas_flash_attention_packed(qkv, H, family="group")
+        o = pallas_flash_attention_packed(qkv, H, **blocks)
         return jnp.sum(o ** 2)
 
     def loss_unpacked(qkv):
         q, k, v = (_heads(t, H) for t in jnp.split(qkv, 3, -1))
-        o = pallas_flash_attention(q, k, v)
+        o = pallas_flash_attention(q, k, v, **blocks)
         return jnp.sum(o.transpose(0, 2, 1, 3).reshape(B, T, C) ** 2)
 
     gp = jax.grad(loss_group)(qkv)
@@ -638,23 +672,24 @@ def test_group_grads_match_unpacked():
                                rtol=2e-4)
 
 
-def test_group_grads_with_dropout_match_unpacked():
+@pytest.mark.parametrize("T,block", [(128, None), (256, 128)],
+                         ids=["one-tile", "multi-tile"])
+def test_group_grads_with_dropout_match_unpacked(group_family, T, block):
     from replicatinggpt_tpu.ops.flash_pallas import \
         pallas_flash_attention_packed
     H, D = 2, 64
-    qkv, C = _packed_inputs(B=1, T=128, H=H, D=D, seed=26)
-    B, T = qkv.shape[:2]
-    rng = jax.random.PRNGKey(15)
+    qkv, C = _packed_inputs(B=1, T=T, H=H, D=D, seed=26)
+    B = qkv.shape[0]
+    kw = dict(block_q=block, block_k=block, dropout_rate=0.25,
+              dropout_rng=jax.random.PRNGKey(15))
 
     def loss_group(qkv):
-        o = pallas_flash_attention_packed(qkv, H, family="group",
-                                          dropout_rate=0.25, dropout_rng=rng)
+        o = pallas_flash_attention_packed(qkv, H, **kw)
         return jnp.sum(o ** 2)
 
     def loss_unpacked(qkv):
         q, k, v = (_heads(t, H) for t in jnp.split(qkv, 3, -1))
-        o = pallas_flash_attention(q, k, v, dropout_rate=0.25,
-                                   dropout_rng=rng)
+        o = pallas_flash_attention(q, k, v, **kw)
         return jnp.sum(o.transpose(0, 2, 1, 3).reshape(B, T, C) ** 2)
 
     gp = jax.grad(loss_group)(qkv)
@@ -678,191 +713,11 @@ def test_group_supported_envelope():
     assert not packed_group_supported(t_max + 128, 768, 12, 2)
 
 
-# --- streamed head-group family (packed long-T past GROUP_STRIP_BYTES) -----
-
-
-def test_group_stream_fwd_bit_identical_to_group():
-    """Same strips, kv axis moved to the grid with scratch state: must
-    reproduce the resident group family exactly (shared tile math,
-    shared bh counter stream)."""
-    from replicatinggpt_tpu.ops.flash_pallas import \
-        pallas_flash_attention_packed
-    H, D = 4, 32
-    qkv, C = _packed_inputs(B=2, T=256, H=H, D=D, seed=31)
-    grp = pallas_flash_attention_packed(qkv, H, family="group")
-    strm = pallas_flash_attention_packed(qkv, H, family="group_stream")
-    np.testing.assert_array_equal(np.asarray(strm), np.asarray(grp))
-
-
-def test_group_stream_fwd_matches_unpacked():
-    from replicatinggpt_tpu.ops.flash_pallas import \
-        pallas_flash_attention_packed
-    H, D = 2, 64
-    qkv, C = _packed_inputs(B=1, T=128, H=H, D=D, seed=32)
-    B, T = qkv.shape[:2]
-    q, k, v = jnp.split(qkv, 3, -1)
-    ref = pallas_flash_attention(_heads(q, H), _heads(k, H), _heads(v, H))
-    ref = ref.transpose(0, 2, 1, 3).reshape(B, T, C)
-    got = pallas_flash_attention_packed(qkv, H, family="group_stream")
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
-
-
-def test_group_stream_dropout_bit_identical_to_unpacked():
-    from replicatinggpt_tpu.ops.flash_pallas import \
-        pallas_flash_attention_packed
-    H, D = 4, 32
-    qkv, C = _packed_inputs(B=2, T=128, H=H, D=D, seed=33)
-    B, T = qkv.shape[:2]
-    rng = jax.random.PRNGKey(29)
-    got = pallas_flash_attention_packed(qkv, H, family="group_stream",
-                                        dropout_rate=0.2, dropout_rng=rng)
-    q, k, v = (_heads(t, H) for t in jnp.split(qkv, 3, -1))
-    ref = pallas_flash_attention(q, k, v, dropout_rate=0.2, dropout_rng=rng)
-    ref = ref.transpose(0, 2, 1, 3).reshape(B, T, C)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
-
-
-@pytest.mark.slow
-def test_group_stream_grads_match_unpacked():
-    from replicatinggpt_tpu.ops.flash_pallas import \
-        pallas_flash_attention_packed
-    H, D = 4, 32
-    qkv, C = _packed_inputs(B=1, T=256, H=H, D=D, seed=34)
-    B, T = qkv.shape[:2]
-
-    def loss_stream(qkv):
-        o = pallas_flash_attention_packed(qkv, H, family="group_stream")
-        return jnp.sum(o ** 2)
-
-    def loss_unpacked(qkv):
-        q, k, v = (_heads(t, H) for t in jnp.split(qkv, 3, -1))
-        o = pallas_flash_attention(q, k, v)
-        return jnp.sum(o.transpose(0, 2, 1, 3).reshape(B, T, C) ** 2)
-
-    gs = jax.grad(loss_stream)(qkv)
-    gu = jax.grad(loss_unpacked)(qkv)
-    np.testing.assert_allclose(np.asarray(gs), np.asarray(gu), atol=2e-4,
-                               rtol=2e-4)
-
-
-def test_group_stream_grads_with_dropout_match_group():
-    """The two group families' backwards recompute the same dropout
-    masks from the same counters — grads must agree exactly."""
-    from replicatinggpt_tpu.ops.flash_pallas import \
-        pallas_flash_attention_packed
-    H, D = 2, 64
-    qkv, C = _packed_inputs(B=1, T=128, H=H, D=D, seed=35)
-    rng = jax.random.PRNGKey(41)
-
-    def loss(qkv, family):
-        o = pallas_flash_attention_packed(qkv, H, family=family,
-                                          dropout_rate=0.25,
-                                          dropout_rng=rng)
-        return jnp.sum(o ** 2)
-
-    gs = jax.grad(lambda x: loss(x, "group_stream"))(qkv)
-    gg = jax.grad(lambda x: loss(x, "group"))(qkv)
-    np.testing.assert_array_equal(np.asarray(gs), np.asarray(gg))
-
-
-def test_group_stream_tri_multiblock_matches_unpacked():
-    """Explicit block=128 at T=512 -> a 4x4 lower triangle (10 tiles) on
-    the scalar-prefetched tile map; auto blocks would pick 512 and
-    collapse the map to one tile, leaving the carried-state path
-    untested. Bit-parity vs the unpacked kernel at the same tiles."""
-    from replicatinggpt_tpu.ops.flash_pallas import \
-        pallas_flash_attention_packed
-    H, D = 4, 32
-    qkv, C = _packed_inputs(B=1, T=512, H=H, D=D, seed=38)
-    B, T = qkv.shape[:2]
-    got = pallas_flash_attention_packed(qkv, H, family="group_stream",
-                                        block_q=128, block_k=128)
-    q, k, v = (_heads(t, H) for t in jnp.split(qkv, 3, -1))
-    ref = pallas_flash_attention(q, k, v, block_q=128, block_k=128)
-    ref = ref.transpose(0, 2, 1, 3).reshape(B, T, C)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
-
-
-@pytest.mark.slow
-def test_group_stream_tri_multiblock_grads_with_dropout():
-    """Multi-block triangular backward (dq carried over kv steps, dk/dv
-    over q steps) with the in-kernel dropout stream, vs the unpacked
-    kernel at the same tiles."""
-    from replicatinggpt_tpu.ops.flash_pallas import \
-        pallas_flash_attention_packed
-    H, D = 2, 64
-    qkv, C = _packed_inputs(B=1, T=384, H=H, D=D, seed=39)
-    B, T = qkv.shape[:2]
-    rng = jax.random.PRNGKey(53)
-
-    def loss_tri(qkv):
-        o = pallas_flash_attention_packed(qkv, H, family="group_stream",
-                                          block_q=128, block_k=128,
-                                          dropout_rate=0.25,
-                                          dropout_rng=rng)
-        return jnp.sum(o ** 2)
-
-    def loss_unpacked(qkv):
-        q, k, v = (_heads(t, H) for t in jnp.split(qkv, 3, -1))
-        o = pallas_flash_attention(q, k, v, block_q=128, block_k=128,
-                                   dropout_rate=0.25, dropout_rng=rng)
-        return jnp.sum(o.transpose(0, 2, 1, 3).reshape(B, T, C) ** 2)
-
-    gt = jax.grad(loss_tri)(qkv)
-    gu = jax.grad(loss_unpacked)(qkv)
-    np.testing.assert_allclose(np.asarray(gt), np.asarray(gu), atol=2e-4,
-                               rtol=2e-4)
-
-
-def test_group_stream_rect_unequal_blocks():
-    """block_q != block_k keeps the rectangular grid (the triangular
-    tile map needs equal blocks); with identical tile sizes the unpacked
-    kernel runs the same update sequence, so outputs are bit-equal."""
-    from replicatinggpt_tpu.ops.flash_pallas import \
-        pallas_flash_attention_packed
-    H, D = 4, 32
-    qkv, C = _packed_inputs(B=1, T=256, H=H, D=D, seed=36)
-    B, T = qkv.shape[:2]
-    got = pallas_flash_attention_packed(qkv, H, family="group_stream",
-                                        block_q=128, block_k=64)
-    q, k, v = (_heads(t, H) for t in jnp.split(qkv, 3, -1))
-    ref = pallas_flash_attention(q, k, v, block_q=128, block_k=64)
-    ref = ref.transpose(0, 2, 1, 3).reshape(B, T, C)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
-
-
-def test_group_stream_rect_grads_match_unpacked():
-    """Backward through the rectangular streamed-group grid (forced via
-    unequal blocks) against the unpacked kernel at the same tile
-    sizes."""
-    from replicatinggpt_tpu.ops.flash_pallas import \
-        pallas_flash_attention_packed
-    H, D = 2, 64
-    qkv, C = _packed_inputs(B=1, T=128, H=H, D=D, seed=37)
-    B, T = qkv.shape[:2]
-
-    def loss_rect(qkv):
-        o = pallas_flash_attention_packed(qkv, H, family="group_stream",
-                                          block_q=128, block_k=64)
-        return jnp.sum(o ** 2)
-
-    def loss_unpacked(qkv):
-        q, k, v = (_heads(t, H) for t in jnp.split(qkv, 3, -1))
-        o = pallas_flash_attention(q, k, v, block_q=128, block_k=64)
-        return jnp.sum(o.transpose(0, 2, 1, 3).reshape(B, T, C) ** 2)
-
-    gr = jax.grad(loss_rect)(qkv)
-    gu = jax.grad(loss_unpacked)(qkv)
-    np.testing.assert_allclose(np.asarray(gr), np.asarray(gu), atol=2e-4,
-                               rtol=2e-4)
-
-
-def test_model_block_routes_group_stream_past_strip_bound(monkeypatch):
-    """forward() end-to-end through the packed AUTO routing when both
-    residency bounds exclude the other families: the streamed group
-    family must be selected and produce the split-path logits. Bounds
-    are shrunk instead of using a real >2048-token model so the test
-    stays in the fast tier."""
+def test_model_block_routes_split_heads_past_strip_bound(monkeypatch):
+    """forward() with both packed residency bounds closed must call
+    neither packed family and produce the split-path logits (the path
+    long sequences take). Bounds are shrunk instead of using a real
+    >2048-token model so the test stays in the fast tier."""
     import replicatinggpt_tpu.ops.flash_attention as fa
     import replicatinggpt_tpu.ops.flash_pallas as fp
     from replicatinggpt_tpu.config import ModelConfig
@@ -876,64 +731,53 @@ def test_model_block_routes_group_stream_past_strip_bound(monkeypatch):
     ref, _ = forward(params, x, mcfg)  # CPU backend -> split path
 
     calls = []
-    orig = fp._flash_packed_group_stream
 
-    def spy(*a, **kw):
-        calls.append(True)
-        return orig(*a, **kw)
+    def spy(name):
+        def fn(*a, **kw):
+            calls.append(name)
+            raise AssertionError(f"{name} routed past its bound")
+        return fn
 
     monkeypatch.setattr(fp, "PACKED_QKV_BYTES", 1)
     monkeypatch.setattr(fp, "GROUP_STRIP_BYTES", 1)
-    monkeypatch.setattr(fp, "GROUP_STREAM_AUTOROUTE", True)
-    monkeypatch.setattr(fp, "_flash_packed_group_stream", spy)
+    monkeypatch.setattr(fp, "_flash_packed", spy("resident"))
+    monkeypatch.setattr(fp, "_flash_packed_group", spy("group"))
     monkeypatch.setattr(fa, "_packed_backend_ok", lambda: True)
     got, _ = forward(params, x, mcfg)
-    assert calls, "streamed group family was not routed"
+    assert not calls, calls
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=2e-4,
                                rtol=2e-4)
 
 
-def test_group_stream_envelope_and_routing(monkeypatch):
-    """Past GROUP_STRIP_BYTES the entry must route group_stream once its
-    hardware-validation gate is open; the envelope gate in
-    ops.flash_attention must agree."""
-    import replicatinggpt_tpu.ops.flash_pallas as fp
-    from replicatinggpt_tpu.ops.flash_attention import packed_envelope_ok
-    from replicatinggpt_tpu.ops.flash_pallas import (
-        packed_group_stream_supported, packed_group_supported)
-    # 124M shapes at T=4096: group is off-envelope, stream is on
-    assert not packed_group_supported(4096, 768, 12, 2)
-    assert packed_group_stream_supported(4096, 768, 12, 2)
-    # longctx bench shapes (T=32k, C=256, H=4 -> D=64)
-    assert packed_group_stream_supported(32768, 256, 4, 2)
-    # geometry failures still excluded
-    assert not packed_group_stream_supported(4096, 1600, 25, 2)
-    assert not packed_group_stream_supported(192, 768, 12, 2)
-    import replicatinggpt_tpu.ops.flash_attention as fa
-    monkeypatch.setattr(fa, "_packed_backend_ok", lambda: True)
-    qkv = jnp.zeros((1, 4096, 3 * 768), jnp.bfloat16)
-    monkeypatch.setattr(fp, "GROUP_STREAM_AUTOROUTE", True)
-    assert packed_envelope_ok(qkv, 12)
-
-
-def test_group_stream_gated_out_of_autoroute_by_default(monkeypatch):
-    """Until a chip run proves the family's parity on real Mosaic,
-    group_stream must stay opt-in: with the gate at its shipped default
-    the envelope excludes group_stream-only shapes (callers fall back to
-    the hardware-proven unpacked streamed family) and the family=None
-    entry refuses rather than silently picking it."""
+@pytest.mark.parametrize("T,C,H,inside", [
+    (2048, 768, 12, True),     # the last T a 128-lane bf16 strip holds
+    (2176, 768, 12, False),    # one tile past GROUP_STRIP_BYTES
+    (4096, 768, 12, False),
+    (1024, 1600, 25, False),   # gpt2-xl: 25 heads form no 128-lane group
+], ids=["strip-edge", "past-strip-edge", "long-T", "no-groups"])
+def test_packed_envelope_edges(monkeypatch, T, C, H, inside):
+    """The envelope gate, the group predicate and the entry's router
+    agree at the strip bound: inside, the entry takes the head-group
+    family; outside, the gate is closed and the entry refuses."""
     import replicatinggpt_tpu.ops.flash_attention as fa
     import replicatinggpt_tpu.ops.flash_pallas as fp
-    from replicatinggpt_tpu.ops.flash_attention import packed_envelope_ok
-    assert fp.GROUP_STREAM_AUTOROUTE is False  # shipped default
     monkeypatch.setattr(fa, "_packed_backend_ok", lambda: True)
-    # T=4096 @ 124M widths: only group_stream covers it -> envelope closed
-    qkv = jnp.zeros((1, 4096, 3 * 768), jnp.bfloat16)
-    assert not packed_envelope_ok(qkv, 12)
-    with pytest.raises(ValueError, match="packed families"):
-        fp.pallas_flash_attention_packed(qkv, 12)
-    # explicit opt-in still addresses the family (envelope fn agrees)
-    assert fp.packed_group_stream_supported(4096, 768, 12, 2)
+    calls = []
+
+    def group_stub(qkv, *args):
+        calls.append(qkv.shape)
+        return qkv[..., :C]
+
+    monkeypatch.setattr(fp, "_flash_packed_group", group_stub)
+    qkv = jnp.zeros((1, T, 3 * C), jnp.bfloat16)
+    assert fp.packed_group_supported(T, C, H, 2) is inside
+    assert fa.packed_envelope_ok(qkv, H) is inside
+    if inside:
+        fp.pallas_flash_attention_packed(qkv, H)
+        assert calls == [qkv.shape]
+    else:
+        with pytest.raises(ValueError, match="packed families"):
+            fp.pallas_flash_attention_packed(qkv, H)
 
 
 def test_packed_entry_routes_group_past_resident_bound():

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from replicatinggpt_tpu import reference_joyai_llm_flash as ref
+from chipbench import reference_joyai_llm_flash as ref
 from replicatinggpt_tpu.config import get_config
 from replicatinggpt_tpu.models import joyai_llm_flash as jm
 from replicatinggpt_tpu.models import layers

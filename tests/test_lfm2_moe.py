@@ -15,7 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from replicatinggpt_tpu import reference_lfm2_moe as ref
+from chipbench import reference_lfm2_moe as ref
 from replicatinggpt_tpu.config import get_config
 from replicatinggpt_tpu.models import lfm2_moe as lm
 from replicatinggpt_tpu.ops import paged_pallas

@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from replicatinggpt_tpu import reference_olmo_hybrid as ref
+from chipbench import reference_olmo_hybrid as ref
 from replicatinggpt_tpu.config import get_config
 from replicatinggpt_tpu.models import olmo_hybrid as oh
 from replicatinggpt_tpu.models.families import family, serve_refusals

@@ -15,7 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from replicatinggpt_tpu import reference_exaone_moe, reference_lfm2_moe
+from chipbench import reference_exaone_moe, reference_lfm2_moe
 from replicatinggpt_tpu.config import get_config
 from replicatinggpt_tpu.models import exaone_moe, layers, lfm2_moe
 from replicatinggpt_tpu.models.families import family, serve_refusals
